@@ -1000,23 +1000,41 @@ class ControlStore:
         }
 
     def _health_loop(self) -> None:
+        last_tick = time.monotonic()
         while not self._stopped.wait(config.health_check_period_s):
+            now = time.monotonic()
+            late = now - last_tick - float(config.health_check_period_s)
+            last_tick = now
             if self._recovering:
                 continue  # reconciliation window: agents get time to return
-            now = time.monotonic()
-            dead = []
-            with self._lock:
-                for nid, n in self._nodes.items():
-                    if n["alive"] and now - n["last_heartbeat"] > config.health_check_timeout_s:
-                        dead.append(nid)
-                n_dead = sum(
-                    1 for n in self._nodes.values() if not n["alive"]
-                ) + len(dead)
-            if core_metrics.ENABLED:
-                core_metrics.cluster_nodes_dead.set(float(n_dead))
-            for nid in dead:
-                logger.warning("node %s missed heartbeats; marking dead", nid[:8])
-                self._mark_node_dead(nid, "heartbeat timeout")
+            self._health_check(now, late)
+
+    def _health_check(self, now: float, late: float) -> None:
+        """One liveness pass. ``late`` is how long past its period this
+        loop woke: for that long the head process could not run — or the
+        whole machine could not (a TPU runtime coming up stalls a v5e
+        host for up to ten seconds, PERF.md) — and heartbeats sent
+        meanwhile are still in their sockets. Silence the store could
+        not have heard is not a missed heartbeat, so it does not count."""
+        dead = []
+        with self._lock:
+            for nid, n in self._nodes.items():
+                if not n["alive"]:
+                    continue
+                if late > 1.0:
+                    self._apply("node_runtime", nid, {
+                        "last_heartbeat": n["last_heartbeat"] + late,
+                    })
+                if now - n["last_heartbeat"] > config.health_check_timeout_s:
+                    dead.append(nid)
+            n_dead = sum(
+                1 for n in self._nodes.values() if not n["alive"]
+            ) + len(dead)
+        if core_metrics.ENABLED:
+            core_metrics.cluster_nodes_dead.set(float(n_dead))
+        for nid in dead:
+            logger.warning("node %s missed heartbeats; marking dead", nid[:8])
+            self._mark_node_dead(nid, "heartbeat timeout")
 
     def _mark_node_dead(self, node_id: str, reason: str,
                         only_if_unreconciled: bool = False) -> None:

@@ -12,10 +12,12 @@ impl="ring":      blockwise ring attention over the mesh "cp" axis
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 
 def attention(
@@ -29,14 +31,34 @@ def attention(
     if impl == "reference":
         return _reference_attention(q, k, v, causal)
     if impl == "flash":
-        from ray_tpu.ops.flash_attention import flash_attention
-
-        return flash_attention(q, k, v, causal=causal)
+        return _flash_per_shard(q, k, v, causal)
     if impl == "ring":
         from ray_tpu.ops.ring_attention import ring_attention
 
         return ring_attention(q, k, v, axis_name=axis_name or "cp", causal=causal)
     raise ValueError(f"unknown attention impl {impl!r}")
+
+
+def _flash_per_shard(q, k, v, causal):
+    """The flash kernel on each device's own shard.
+
+    A Pallas call is opaque to the SPMD partitioner: left bare under a
+    mesh, its operands are all-gathered and every chip computes the
+    whole batch. So under a context mesh (the step is jitted inside
+    ``jax.set_mesh(mesh)``) the kernel is shard_mapped: batch over the
+    data axes, heads over tp, nothing to communicate. With no context
+    mesh (one device) it is called as is."""
+    from ray_tpu.ops.flash_attention import flash_attention
+
+    fn = functools.partial(flash_attention, causal=causal)
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.size == 1:
+        return fn(q, k, v)
+    batch = tuple(a for a in ("dcn", "dp", "fsdp") if a in mesh.axis_names)
+    spec = P(batch or None, None, "tp" if "tp" in mesh.axis_names else None, None)
+    return jax.shard_map(
+        fn, in_specs=(spec, spec, spec), out_specs=spec, check_vma=False,
+    )(q, k, v)
 
 
 def _reference_attention(q, k, v, causal):

@@ -39,9 +39,21 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 def _interpret() -> bool:
-    # CPU has no Mosaic backend: run kernels in interpret mode so the same
-    # code is testable on the virtual host mesh (SURVEY.md §4 takeaway).
-    return jax.default_backend() == "cpu"
+    """Interpret mode, only where the platform is EXPLICITLY the CPU
+    (tests and cpu workers run under JAX_PLATFORMS=cpu). CPU has no
+    Mosaic backend, so the same code stays testable on the virtual host
+    mesh; but a process that merely ended up on the CPU — no chip found,
+    backend fell back — must not run the interpreter under a TPU run's
+    name."""
+    if jax.default_backend() != "cpu":
+        return False
+    if jax.config.jax_platforms != "cpu":
+        raise RuntimeError(
+            "flash attention found no TPU backend "
+            f"(jax_platforms={jax.config.jax_platforms!r}); the Pallas "
+            "interpreter runs only under an explicit JAX_PLATFORMS=cpu"
+        )
+    return True
 
 
 _NEG_INF = -1e30
@@ -236,9 +248,7 @@ def _unfold(x, B, H):  # [B*H, T, D] -> [B, T, H, D]
 
 
 def _params():
-    from ray_tpu.ops.jax_compat import pallas_tpu_compiler_params_cls
-
-    return pallas_tpu_compiler_params_cls()(
+    return pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"),
     )
 

@@ -128,12 +128,12 @@ def moe_block_sharded(
     """shard_map wrapper: batch over ep (tokens sharded), experts over ep."""
     from jax.sharding import PartitionSpec as P
 
-    from ray_tpu.ops.jax_compat import shard_map_unchecked
-
     fn = functools.partial(
         moe_block, capacity=capacity, axis_name=ep_axis, top_k=top_k
     )
-    return shard_map_unchecked(
+    # check_vma off: the checker cannot prove the replication of the
+    # all_to_all dispatch/combine pair
+    return jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(
@@ -143,4 +143,5 @@ def moe_block_sharded(
             P(ep_axis, None, None),
         ),
         out_specs=P(ep_axis, None),
+        check_vma=False,
     )(x, wg, w_in, w_out)

@@ -89,7 +89,12 @@ def build_mesh(
         else:
             dev_array = mesh_utils.create_device_mesh(shape, devices=devices)
     except (ValueError, AssertionError):
-        # CPU meshes / odd shapes: plain reshape keeps semantics
+        if devices[0].platform == "tpu":
+            # a real topology was refused: reshaping the device list
+            # instead would lay mesh neighbours on distant chips and
+            # never say so
+            raise
+        # virtual CPU devices have no topology: plain reshape
         dev_array = np.array(devices).reshape(shape)
     return Mesh(dev_array, axis_names)
 

@@ -108,6 +108,9 @@ class ServeController:
                 "drain_deadline_s": None,  # per-deployment override
                 "last_decision": None,  # last up/down autoscale decision
                 "last_signals": None,  # most recent Signals.describe()
+                # why a replica died in its constructor; fails ready()
+                # and stops further starts until the next deploy()
+                "start_error": None,
                 "next_replica": next_replica,
                 "deleting": False,
             }
@@ -255,6 +258,13 @@ class ServeController:
             with self._lock:
                 dep = self._deployments.get(name)
                 if dep is not None:
+                    if dep["start_error"]:
+                        # a replica's constructor raised: waiting out
+                        # the caller's deadline would hide why
+                        raise RuntimeError(
+                            f"deployment {name!r}: replica failed to "
+                            f"start: {dep['start_error']}"
+                        )
                     healthy = sum(
                         1 for r in dep["replicas"].values() if r["healthy"]
                     )
@@ -295,7 +305,11 @@ class ServeController:
         from ray_tpu.utils.config import config
 
         last_autoscale = 0.0
-        while not self._stop.wait(RECONCILE_PERIOD_S):
+        while not self._stop.wait(
+            # a replica under construction is probed often: ready()
+            # returns when the probe first succeeds
+            0.1 if self._any_starting() else RECONCILE_PERIOD_S
+        ):
             try:
                 self._check_health()
                 now = time.monotonic()
@@ -309,6 +323,16 @@ class ServeController:
             except Exception:  # noqa: BLE001 — keep the loop alive
                 logger.exception("serve reconcile iteration failed")
 
+    def _any_starting(self) -> bool:
+        with self._lock:
+            # not healthy = still in its constructor: a replica that
+            # fails a health check leaves the record at once
+            return any(
+                not rec["healthy"]
+                for dep in self._deployments.values()
+                for rec in dep["replicas"].values()
+            )
+
     def _check_health(self) -> None:
         """Probe replicas; collect queue stats; drop dead ones.
 
@@ -319,7 +343,10 @@ class ServeController:
         RPC timeouts tolerate several misses; a dead worker (connection
         refused / actor lookup failure) removes the replica immediately."""
         from ray_tpu.core import worker as worker_mod
-        from ray_tpu.core.exceptions import ActorDiedError
+        from ray_tpu.core.exceptions import (
+            ActorDiedError,
+            ActorUnavailableError,
+        )
         from ray_tpu.utils.rpc import RpcConnectionError, RpcError
 
         w = worker_mod.global_worker()
@@ -339,9 +366,13 @@ class ServeController:
             )
         for dep, rid, rec, draining in probes:
             dead = False
+            # under construction: not in the table, not ready, and a
+            # constructor that takes long (loading a model) is no miss
+            starting = not draining and not rec["healthy"]
             try:
                 addr = w._resolve_actor_address(
-                    rec["handle"]._actor_id, timeout_s=5.0
+                    rec["handle"]._actor_id,
+                    timeout_s=0.2 if starting else 5.0,
                 )
                 stats = w.workers.get(addr).call(
                     "actor_queue_stats", timeout_s=5.0
@@ -361,8 +392,11 @@ class ServeController:
                         rec["healthy"] = True
                         self._version += 1
                 continue
-            except ActorDiedError:
+            except ActorDiedError as e:
                 dead = True  # control plane confirms death: remove now
+                if starting:
+                    with self._lock:
+                        dep["start_error"] = str(e)
             except RpcConnectionError:
                 # connection loss is ambiguous (worker rebinding, network
                 # blip, or real death) — weigh it heavier than a timeout
@@ -375,7 +409,9 @@ class ServeController:
                 with self._lock:
                     rec["probe_misses"] = rec.get("probe_misses", 0) + 3
                     dead = rec["probe_misses"] >= 6
-            except Exception:  # noqa: BLE001 — slow or dying
+            except Exception as e:  # noqa: BLE001 — slow or dying
+                if starting and isinstance(e, ActorUnavailableError):
+                    continue  # still in its constructor
                 with self._lock:
                     rec["probe_misses"] = rec.get("probe_misses", 0) + 1
                     dead = rec["probe_misses"] >= 6  # ~30s unresponsive
@@ -543,8 +579,9 @@ class ServeController:
                     self._version += 1
                     current += 1
                     logger.info("replica %s un-drained (scale-up)", rid)
-            for _ in range(current, target):
-                self._start_replica(dep)
+            if not dep["start_error"]:
+                for _ in range(current, target):
+                    self._start_replica(dep)
             if deleting:
                 # teardown is not a drain: delete_deployment means stop
                 # now, streams included (old behavior)
@@ -651,9 +688,10 @@ class ServeController:
                         handle._actor_id, handle._class_name,
                         handle._method_meta,
                     ),
-                    "healthy": True,
+                    # under construction; joins the table at its first
+                    # answered probe
+                    "healthy": False,
                 }
-                self._version += 1
         if stale:
             self._kill_silently(handle)
             return

@@ -316,9 +316,11 @@ class PrefillServer:
     rows back through the caller's channel handle."""
 
     def __init__(self, models, max_engines_per_replica: int = 2):
+        from ray_tpu.accelerators.tpu import require_leased_platform
         from ray_tpu.serve import multiplex
         from ray_tpu.serve.openai.ingress import _normalize_models
 
+        require_leased_platform()
         self._models = _normalize_models(models)
         self._engines = multiplex.make_multiplexer(
             lambda model: self._load_engine(model),

@@ -195,8 +195,11 @@ class LLMServer:
     def __init__(self, config: LLMConfig):
         import jax
 
+        from ray_tpu.accelerators.tpu import require_leased_platform
         from ray_tpu.models import gpt2
 
+        require_leased_platform()
+        t_load = time.monotonic()
         self.cfg = config
         self.model_cfg = gpt2.CONFIGS[config.model_id]
         if config.checkpoint_path:
@@ -262,9 +265,55 @@ class LLMServer:
             self._paged = False
             self._prefix_pool = None
             target = self._engine_loop_recompute
+        # start-up hand-off: the engine thread allocates its KV pool,
+        # reports where it landed and only then takes requests; a
+        # failure on the way (out of device memory, no such platform)
+        # raises HERE instead of leaving callers to time out
+        self._started = threading.Event()
+        self._start_error: Optional[BaseException] = None
+        self._devices: List[Dict[str, Any]] = []
         threading.Thread(
-            target=target, name="llm-engine", daemon=True
+            target=self._run_engine, args=(target,), name="llm-engine",
+            daemon=True,
         ).start()
+        self._started.wait()
+        if self._start_error is not None:
+            raise RuntimeError(
+                f"engine {config.model_id!r} failed to start: "
+                f"{type(self._start_error).__name__}: {self._start_error}"
+            ) from self._start_error
+        self._load_s = round(time.monotonic() - t_load, 2)
+
+    def _run_engine(self, loop) -> None:
+        try:
+            loop()
+        except BaseException as e:  # noqa: BLE001 — reported to __init__
+            self._start_error = e
+            self._started.set()
+            raise
+
+    def _engine_started(self, *pool) -> None:
+        """Called by an engine loop once its allocations exist, before
+        its first round: waits for them (device allocation is
+        asynchronous), records the devices holding the parameters and
+        the KV pool, and releases __init__."""
+        import jax
+
+        jax.block_until_ready(pool)
+        held = {
+            d for a in (*jax.tree.leaves(self.params), *pool)
+            for d in a.devices()
+        }
+        self._devices = [
+            {
+                "platform": d.platform, "device_kind": d.device_kind,
+                "id": d.id, "coords": getattr(d, "coords", None),
+                # the chip id the node agent started this process with
+                "leased_chips": os.environ.get("TPU_VISIBLE_CHIPS"),
+            }
+            for d in sorted(held, key=lambda d: d.id)
+        ]
+        self._started.set()
 
     # -- request path ---------------------------------------------------
 
@@ -353,6 +402,11 @@ class LLMServer:
             "max_batch": mx,
             "mean_batch": sum(sizes) / len(sizes) if sizes else 0,
             "occupied": self._occupied,
+            # devices holding the parameters and the KV pool, and the
+            # seconds it took to put them there (weights + pool, before
+            # any request compiled anything)
+            "devices": self._devices,
+            "load_s": self._load_s,
             "prefix": (
                 self._prefix_pool.stats() if self._prefix_pool else None
             ),
@@ -825,6 +879,7 @@ class LLMServer:
             else:
                 harvest(rec, True)
 
+        self._engine_started(cache_k, cache_v)
         while not self._stop.is_set():
             try:
                 one_round()
@@ -1399,6 +1454,7 @@ class LLMServer:
             else:
                 harvest(rec, True)
 
+        self._engine_started(cache_k, cache_v)
         while not self._stop.is_set():
             try:
                 one_round()
@@ -1452,6 +1508,7 @@ class LLMServer:
             return lastl[:, : mcfg.vocab_size]
 
         next_logits = jax.jit(next_logits)
+        self._engine_started()
         while not self._stop.is_set():
             batch = self._take_batch()
             if not batch:
@@ -1523,6 +1580,30 @@ class LLMServer:
             r.event.set()
 
 
+def _replica_resources(
+    ray_actor_options: Optional[Dict[str, float]] = None,
+) -> Dict[str, float]:
+    """What one engine-hosting replica is leased. The caller's
+    ``ray_actor_options`` if given; otherwise one TPU chip — unless
+    JAX_PLATFORMS explicitly keeps this program off the TPU (tier-1 and
+    CPU-only users say ``cpu``), the only way to get CPU replicas. A
+    cluster with no chip fails here, by name, instead of leaving the
+    lease pending until the ready timeout."""
+    import ray_tpu
+    from ray_tpu.accelerators.tpu import tpu_allowed_by_env
+
+    if ray_actor_options is not None:
+        return ray_actor_options
+    if not tpu_allowed_by_env(os.environ):
+        return {"CPU": 1.0}
+    if not ray_tpu.cluster_resources().get("TPU"):
+        raise RuntimeError(
+            "an LLM replica takes one TPU chip and this cluster has none; "
+            "set JAX_PLATFORMS=cpu to serve from the CPU"
+        )
+    return {"CPU": 1.0, "TPU": 1.0}
+
+
 def build_llm_deployment(config: Optional[LLMConfig] = None) -> Any:
     """Deployment for an LLM server (parity: serve.llm build_llm_deployment)."""
     config = config or LLMConfig()
@@ -1532,6 +1613,7 @@ def build_llm_deployment(config: Optional[LLMConfig] = None) -> Any:
         num_replicas=config.num_replicas,
         route_prefix=config.route_prefix,
         max_concurrency=config.max_concurrency,
+        ray_actor_options=_replica_resources(),
     )
     return dep.bind(config)
 
@@ -1566,6 +1648,11 @@ def deploy(
     replicas already holding the requested model; the OpenAI ``user``
     field pins a session to one replica's warm KV slots.
 
+    Each replica is leased one TPU chip (``ray_actor_options`` overrides;
+    ``JAX_PLATFORMS=cpu`` in the caller's environment is the explicit way
+    to CPU replicas), and a replica whose constructor fails — leased a
+    chip, found another platform — fails this call at once.
+
     ``disaggregated=True`` additionally runs a ``<name>-prefill``
     deployment (serve/kv_transfer.py): ingress replicas send every
     prompt there for prefill and import the KV rows over an RpcChannel,
@@ -1575,6 +1662,7 @@ def deploy(
     Returns the DeploymentHandle."""
     from ray_tpu.serve.openai.ingress import build_openai_deployment
 
+    ray_actor_options = _replica_resources(ray_actor_options)
     prefill_name = None
     if disaggregated:
         from ray_tpu.serve.kv_transfer import PrefillServer
@@ -1586,6 +1674,7 @@ def deploy(
             num_replicas=prefill_replicas,
             route_prefix=None,  # internal tier: no HTTP surface
             max_concurrency=max_concurrency,
+            ray_actor_options=ray_actor_options,
         ).bind(models, max_engines_per_replica=max_engines_per_replica)
         serve.run(
             prefill_dep, wait_ready=wait_ready,

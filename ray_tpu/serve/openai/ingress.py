@@ -71,8 +71,13 @@ class OpenAIServer:
     def __init__(self, models, tokenizer: Optional[str] = None,
                  max_engines_per_replica: int = 2,
                  prefill_deployment: Optional[str] = None):
+        from ray_tpu.accelerators.tpu import require_leased_platform
         from ray_tpu.serve import multiplex
 
+        # a replica that was leased a chip and cannot see it must not
+        # come up: engines load lazily, and the first request would
+        # otherwise be answered from whatever platform JAX fell back to
+        require_leased_platform()
         self._models = _normalize_models(models)
         self._tokenizer_name = tokenizer
         # disaggregated serving: name of the prefill-tier deployment

@@ -18,7 +18,10 @@ class ScalingConfig:
     use_tpu: bool = False
     # resources per worker (one worker = one HOST driving all its chips)
     resources_per_worker: Optional[Dict[str, float]] = None
-    tpu_chips_per_worker: int = 0
+    # use_tpu only. None = every chip of the worker's host (resolved from
+    # the cluster at fit()); 0 = the TPU backend's process-group wiring
+    # with no chip leased, for clusters that have none.
+    tpu_chips_per_worker: Optional[int] = None
     topology: Optional[str] = None  # e.g. "v5e-16" → slice-aware placement
     placement_strategy: str = "PACK"
     # Elastic bounds (parity: reference ElasticScalingPolicy,
@@ -44,8 +47,14 @@ class ScalingConfig:
 
     def worker_resources(self) -> Dict[str, float]:
         res = dict(self.resources_per_worker or {})
-        if self.use_tpu and self.tpu_chips_per_worker:
-            res.setdefault("TPU", float(self.tpu_chips_per_worker))
+        if self.use_tpu:
+            if self.tpu_chips_per_worker is None:
+                raise ValueError(
+                    "tpu_chips_per_worker is unresolved: the trainer fills "
+                    "it in from the cluster before workers are placed"
+                )
+            if self.tpu_chips_per_worker:
+                res.setdefault("TPU", float(self.tpu_chips_per_worker))
         res.setdefault("CPU", 1.0)
         return res
 

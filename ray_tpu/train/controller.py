@@ -50,7 +50,7 @@ class TrainController:
         train_fn_blob: bytes,
         train_loop_config: Optional[Dict[str, Any]],
         use_tpu: bool,
-        chips_per_worker: int,
+        chips_per_worker: Optional[int],
         dataset_blobs: Optional[List[bytes]] = None,
     ) -> Dict[str, Any]:
         attempt = 0
@@ -228,7 +228,7 @@ class TrainController:
                 return "resize"
 
     def _bootstrap_backend(self, wg: WorkerGroup, group_name: str,
-                           use_tpu: bool, chips_per_worker: int,
+                           use_tpu: bool, chips_per_worker: Optional[int],
                            n: Optional[int] = None) -> None:
         """JaxBackend equivalent (reference train/v2/jax/config.py:31-165):
         CPU mode fakes a per-worker host mesh; TPU mode wires
@@ -241,7 +241,7 @@ class TrainController:
                     "JAX_PLATFORMS": "cpu",
                     "XLA_FLAGS": (
                         f"--xla_force_host_platform_device_count="
-                        f"{max(1, chips_per_worker)}"
+                        f"{chips_per_worker or 1}"
                     ),
                 }
                 for _ in range(n)

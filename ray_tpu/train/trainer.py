@@ -6,6 +6,7 @@ data_parallel_trainer.py:157) and JaxTrainer (train/v2/jax/jax_trainer.py:20).
 
 from __future__ import annotations
 
+import dataclasses
 import os
 import time
 from typing import Any, Callable, Dict, Optional
@@ -79,6 +80,11 @@ class DataParallelTrainer:
                 "elastic scaling with datasets= is not supported yet: "
                 "dataset shards are split at the initial world size"
             )
+        scaling = self.scaling_config
+        if scaling.use_tpu and scaling.tpu_chips_per_worker is None:
+            scaling = dataclasses.replace(
+                scaling, tpu_chips_per_worker=_chips_per_tpu_host()
+            )
         run_dir = self._run_dir()
         cc = self.run_config.checkpoint_config
         # Pin the controller to the driver's node (reference v2 runs the
@@ -93,7 +99,7 @@ class DataParallelTrainer:
                 soft=True,
             ),
         ).remote(
-            self.scaling_config,
+            scaling,
             run_dir,
             self.run_config.failure_config.max_failures,
             cc.num_to_keep,
@@ -105,8 +111,8 @@ class DataParallelTrainer:
                 controller.run.remote(
                     serialization.dumps_function(self._train_fn),
                     self._train_loop_config,
-                    self.scaling_config.use_tpu,
-                    self.scaling_config.tpu_chips_per_worker,
+                    scaling.use_tpu,
+                    scaling.tpu_chips_per_worker,
                     self._dataset_blobs(),
                 ),
             )
@@ -125,14 +131,25 @@ class DataParallelTrainer:
         return Result(metrics=metrics, checkpoint=ckpt, error=error, path=run_dir)
 
 
+def _chips_per_tpu_host() -> int:
+    """Chips a use_tpu worker takes when the caller named no count: all
+    of its host's. No TPU node at all is an error here, not a quiet run
+    on cpu workers."""
+    chips = max(
+        (int(n["resources_total"].get("TPU", 0)) for n in ray_tpu.nodes()
+         if n.get("alive", True)),
+        default=0,
+    )
+    if not chips:
+        raise RuntimeError(
+            "use_tpu=True but no node of this cluster has a TPU chip; "
+            "say use_tpu=False to train on the CPU"
+        )
+    return chips
+
+
 class JaxTrainer(DataParallelTrainer):
     """SPMD JAX training: one worker per host, a mesh over all chips.
 
     Parity: reference JaxTrainer (TPU-only, _validate_scaling_config
     train/v2/jax/jax_trainer.py:162)."""
-
-    def __init__(self, *args, **kwargs):
-        super().__init__(*args, **kwargs)
-        sc = self.scaling_config
-        if sc.use_tpu and not sc.tpu_chips_per_worker:
-            sc.tpu_chips_per_worker = 1

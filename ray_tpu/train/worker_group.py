@@ -60,7 +60,8 @@ class TrainWorker:
         # _setup_jax_distributed_environment, train/v2/jax/config.py:31).
         # RT_XLA_* arrive via apply_env() on this actor; the dynamic flags
         # re-read the process env on each access.
-        if config.xla_group:
+        # One process is its own JAX runtime: no coordinator to meet.
+        if config.xla_group and int(config.xla_world) > 1:
             from ray_tpu.collective.xla_group import initialize_xla_group
 
             initialize_xla_group(
@@ -68,6 +69,10 @@ class TrainWorker:
                 int(config.xla_rank),
                 int(config.xla_world),
             )
+        # a worker that was leased chips computes on them or not at all
+        from ray_tpu.accelerators.tpu import require_leased_platform
+
+        require_leased_platform()
 
         train_fn = serialization.loads(train_fn_blob)
         restore = (

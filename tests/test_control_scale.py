@@ -227,6 +227,46 @@ def test_bulk_register_bad_spec_does_not_poison_batch():
         cs.stop()
 
 
+def test_health_check_does_not_count_a_stall_of_its_own():
+    """The time the health loop itself could not run (the head process
+    or the whole machine was stalled) is not silence from the nodes: a
+    node whose last heartbeat is older than the timeout only because of
+    such a stall stays alive; real silence still kills it."""
+    import time
+
+    from ray_tpu.utils.config import config
+
+    cs = ControlStore("sessN" + "0" * 26)
+    cs.start()
+    try:
+        client = RpcClient(cs.address, name="hb")
+        for nid in ("a" * 32, "b" * 32):
+            client.call("register_node", node_info={
+                "node_id": nid, "address": "127.0.0.1:1",
+                "resources_total": {"CPU": 1.0}, "labels": {},
+                "object_store_capacity": 0,
+            })
+        timeout = float(config.health_check_timeout_s)
+        now = time.monotonic()
+        with cs._lock:
+            for n in cs._nodes.values():
+                n["last_heartbeat"] = now - timeout - 2.0
+        # the loop woke 9.5 s late: that much of the silence is its own
+        cs._health_check(now, late=9.5)
+        alive = {n["node_id"]: n["alive"] for n in client.call("get_nodes")}
+        assert alive == {"a" * 32: True, "b" * 32: True}
+        # on time and still silent past the timeout: dead
+        with cs._lock:
+            cs._nodes["a" * 32]["last_heartbeat"] = now - timeout - 2.0
+        cs._health_check(now, late=0.0)
+        alive = {n["node_id"]: n.get("alive") for n in client.call("get_nodes")}
+        assert alive.get("a" * 32) in (False, None), alive
+        assert alive["b" * 32] is True
+        client.close()
+    finally:
+        cs.stop()
+
+
 # -- tier-1 smoke: batched register + parallel kill-drain ---------------
 
 

@@ -275,6 +275,41 @@ def test_redeploy_replaces_code(rt):
     serve.delete("ver")
 
 
+def test_replica_constructor_failure_fails_run_at_once(rt):
+    """A replica is ready once its constructor has returned, and a
+    constructor that raises fails serve.run with the reason — not a
+    ready() that says yes and requests that time out later."""
+
+    @serve.deployment(num_replicas=1)
+    class Broken:
+        def __init__(self):
+            raise RuntimeError("leased a chip, found platform 'cpu'")
+
+        def __call__(self, req):
+            return "never"
+
+    t0 = time.monotonic()
+    with pytest.raises(Exception, match="found platform 'cpu'"):
+        serve.run(Broken.bind(), ready_timeout_s=60)
+    assert time.monotonic() - t0 < 30
+    serve.delete("Broken")
+
+    @serve.deployment(num_replicas=1)
+    class Slow:
+        def __init__(self):
+            time.sleep(1.5)  # a model load: not ready until it returns
+            self.loaded = True
+
+        def __call__(self, req):
+            return self.loaded
+
+    t0 = time.monotonic()
+    handle = serve.run(Slow.bind())
+    assert time.monotonic() - t0 >= 1.5
+    assert handle.remote(None).result(timeout_s=30) is True
+    serve.delete("Slow")
+
+
 def test_llm_deployment_batched_generation(rt):
     """Serve-LLM-lite: a GPT-2 deployment decodes token requests, greedy
     decoding is deterministic, and concurrent requests coalesce into
