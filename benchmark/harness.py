@@ -1,0 +1,128 @@
+"""What every generator needs around the program: where the compile
+cache is, the cluster's counters as plain numbers, the phases of set-up.
+
+The process that runs this never initialises a JAX backend: a chip
+belongs to one process at a time, and the processes that compute are the
+workers the node agent leases chips to.
+"""
+
+from __future__ import annotations
+
+import os
+import shutil
+import sys
+import time
+from typing import Any, Dict, Optional
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+TRACE_DIR = os.path.join(ROOT, ".bench_trace")
+
+
+def say(msg: str) -> None:
+    print(f"[bench] {msg}", flush=True)
+
+
+def prepare_env() -> None:
+    """Keep what the program writes under this run's own TMPDIR (the
+    program's default is the fixed /tmp/ray_tpu, which two checkouts
+    would share)."""
+    tmp = os.environ.get("TMPDIR") or "/tmp"
+    os.environ.setdefault("RT_TEMP_DIR", os.path.join(tmp, f"rt_bench_{os.getuid()}"))
+
+
+def compile_cache_dir() -> str:
+    from ray_tpu.accelerators import tpu as tpu_mod
+
+    return os.environ.get(tpu_mod.COMPILE_CACHE_ENV) or tpu_mod.DEFAULT_COMPILE_CACHE_DIR
+
+
+def cache_names() -> set:
+    """The files of the compile cache: JAX names an entry after the
+    program (``jit_prefill_paged-<key>``), so a name gained inside a
+    window says what compiled there."""
+    return {f for _, _, files in os.walk(compile_cache_dir()) for f in files}
+
+
+def cache_entries() -> int:
+    return len(cache_names())
+
+
+def fresh_trace_dir() -> str:
+    shutil.rmtree(TRACE_DIR, ignore_errors=True)
+    os.makedirs(TRACE_DIR, exist_ok=True)
+    return TRACE_DIR
+
+
+def require_platform(platform: str, chips: int) -> None:
+    """Exit non-zero, printing no result, unless this machine can give
+    the cell its chips. A configuration that says ``"platform": "cpu"``
+    (the rehearsal's) runs on the CPU and nowhere else."""
+    from ray_tpu.accelerators import tpu as tpu_mod
+
+    allowed = tpu_mod.tpu_allowed_by_env(os.environ)
+    if platform == "cpu":
+        if allowed:
+            sys.exit("benchmark: a rehearsal configuration runs only under "
+                     "an explicit JAX_PLATFORMS=cpu")
+        return
+    if not allowed:
+        sys.exit(
+            f"benchmark: this cell wants {chips} TPU chip(s), but JAX_PLATFORMS="
+            f"{os.environ.get('JAX_PLATFORMS')!r} keeps the program off them; "
+            "no cell of BENCHMARK.json runs on the CPU"
+        )
+    found = tpu_mod.TPUAcceleratorManager.get_current_node_num_accelerators()
+    if found < chips:
+        sys.exit(f"benchmark: this cell wants {chips} TPU chip(s) and this "
+                 f"machine exposes {found}")
+
+
+def counters() -> Dict[str, Dict[str, float]]:
+    """The cluster's metrics as ``{name: {"value"|"sum","count": x}}``,
+    series of one metric added together."""
+    from ray_tpu import state
+
+    out: Dict[str, Dict[str, float]] = {}
+    for name, m in state.cluster_metrics().items():
+        if m["kind"] == "histogram":
+            out[name] = {
+                "sum": sum(s["sum"] for s in m["series"].values()),
+                "count": sum(s["count"] for s in m["series"].values()),
+            }
+        else:
+            out[name] = {"value": float(sum(m["series"].values()))}
+    return out
+
+
+class Phases:
+    """Set-up phases on the host's clock, printed as they end."""
+
+    def __init__(self, t_process_start: float):
+        self.t0 = t_process_start
+        self.marks: Dict[str, float] = {}
+        self._last = t_process_start
+
+    def mark(self, name: str, now: Optional[float] = None) -> float:
+        now = time.time() if now is None else now
+        self.marks[name] = now - self._last
+        say(f"set-up: {name} {now - self._last:.2f}s (at {now - self.t0:.2f}s)")
+        self._last = now
+        return self.marks[name]
+
+    def set(self, name: str, seconds: Any) -> None:
+        if seconds is not None:
+            self.marks[name] = float(seconds)
+            say(f"set-up: {name} {float(seconds):.2f}s")
+
+
+def with_memory(device: Dict[str, Any]) -> Dict[str, Any]:
+    """Add the peak on the fullest chip, and its limit, to a device
+    report, both from the allocator: the larger of its peak in use and
+    its peak reserved. (A running program's temporaries are reserved, not
+    "in use": a train step holds 1.9 GB in use and 14.5 GB reserved.)"""
+    peaks = [max(m.get("peak_bytes_in_use", 0), m.get("peak_bytes_reserved", 0))
+             for m in device["memory"]] or [0]
+    limits = [m.get("bytes_limit", 0) for m in device["memory"]] or [0]
+    device["memory_peak_bytes"] = int(max(peaks))
+    device["memory_limit_bytes"] = int(max(limits))
+    return device

@@ -1,0 +1,161 @@
+"""Each reader on observations made by hand: what it reads, and that it
+returns nothing where there is nothing to read."""
+
+from types import SimpleNamespace
+
+import pytest
+
+from benchmark.readers import (
+    compiles, counter_ratio, decode_roofline, decode_step, device_idle, exposed,
+    gauge_share, gen_lag, hbm_used, itl, observed, step_ms, sync_rate, token_rate,
+    tpot, trace_time, train_mfu, ttft,
+)
+
+TPU = SimpleNamespace(platform="tpu")
+CPU = SimpleNamespace(platform="cpu")
+XL = {"n_embd": 1600, "n_layer": 48, "n_head": 25, "n_positions": 1024, "vocab_size": 50257}
+
+
+def rec(i, due, events, kind="load", sent=None, usage=None):
+    return {"i": i, "kind": kind, "due": due, "sent": due if sent is None else sent,
+            "events": events, "usage": usage}
+
+
+def serve_obs():
+    records = [
+        rec(0, 10.0, [(10.5, 1), (10.6, 1), (10.7, 2)], usage={"prompt_tokens": 100, "completion_tokens": 4}),
+        rec(1, 11.0, [(12.0, 1), (12.3, 1)], sent=11.002, usage={"prompt_tokens": 200, "completion_tokens": 2}),
+        rec(2, 12.0, []),  # never showed text
+        rec(3, 5.0, [(5.1, 1)]),  # due before the window
+        rec(4, 10.0, [(10.2, 1)], kind="probe"),
+    ]
+    return {"records": records, "t0": 10.0, "t1": 20.0, "traffic": {"request_timeout_s": 120}}
+
+
+def test_ttft_counts_due_turns_and_ranks_failures_largest():
+    obs = serve_obs()
+    good, failed = ttft.samples(obs)
+    assert good == pytest.approx([500.0, 1000.0]) and failed == 1
+    assert ttft.read(obs, {"q": 0.5}, TPU) == pytest.approx(1000.0)
+    assert ttft.read(obs, {"q": 1.0}, TPU) == 120000.0
+    obs["records"] = obs["records"][:2]
+    assert ttft.read(obs, {"q": 0.5}, TPU) == pytest.approx(750.0)
+
+
+def test_gen_lag_itl_tpot_and_rate_read_the_window_only():
+    obs = serve_obs()
+    assert gen_lag.read(obs, {"q": 1.0}, TPU) == pytest.approx(2.0)
+    assert itl.read(obs, {"q": 1.0}, TPU) == pytest.approx(300.0)
+    # pooled over tokens: (0.2 + 0.3) s over (3 + 1) tokens after the first
+    assert tpot.read(obs, {}, TPU) == pytest.approx(125.0)
+    # every token after the first round instant (10.2) up to the last (12.3)
+    assert token_rate.read(obs, {}, TPU) == pytest.approx(6 / (12.3 - 10.2))
+
+
+def counters(before, after):
+    def snap(d):
+        return {k: ({"sum": v[0], "count": v[1]} if isinstance(v, tuple) else {"value": v})
+                for k, v in d.items()}
+    return {"before": snap(before), "after": snap(after)}
+
+
+def test_counter_ratio_reads_deltas():
+    obs = {"counters": counters(
+        {"rt_serve_batch_fill": (100.0, 10), "hits": 5.0, "misses": 5.0},
+        {"rt_serve_batch_fill": (340.0, 20), "hits": 35.0, "misses": 15.0},
+    )}
+    fill = {"num": [["rt_serve_batch_fill", "sum"]], "den": [["rt_serve_batch_fill", "count"]]}
+    assert counter_ratio.read(obs, fill, TPU) == pytest.approx(24.0)
+    share = {"num": [["hits", "value"]], "den": [["hits", "value"], ["misses", "value"]],
+             "scale": 100}
+    assert counter_ratio.read(obs, share, TPU) == pytest.approx(75.0)
+    assert counter_ratio.read(obs, {"num": [["x", "value"]], "den": [["y", "value"]]}, TPU) is None
+    assert counter_ratio.read({}, fill, TPU) is None
+
+
+def test_gauge_share_is_the_mean_of_the_samples():
+    samples = [{"occ": {"value": 90.0}, "tot": {"value": 96.0}},
+               {"occ": {"value": 96.0}, "tot": {"value": 96.0}}, {}]
+    obs = {"counters": {"samples": samples}}
+    assert gauge_share.read(obs, {"num": "occ", "den": "tot"}, TPU) == pytest.approx(
+        (93.75 + 100.0) / 2
+    )
+    assert gauge_share.read({"counters": {"samples": []}}, {"num": "occ", "den": "tot"}, TPU) is None
+
+
+def traced():
+    return {
+        "busy_s": 3.9, "window_s": 4.0,
+        "modules": {"jit_decode_paged_and_sample": 3.0, "jit_decode_multi_paged": 0.5,
+                    "jit_prefill_paged": 0.4},
+        "module_calls": {"jit_decode_paged_and_sample": 12.0, "jit_decode_multi_paged": 1.0,
+                         "jit_prefill_paged": 8.0},
+        "ops": {"closed_call bf16[384,1024,64] 3in": 0.2}, "op_calls": {"closed_call bf16[384,1024,64] 3in": 100.0},
+        "host_calls": {"PjRtCompile": 2.0},
+        "exposed_s": {"all-reduce": 0.032},
+    }
+
+
+def test_trace_time_device_idle_and_exposed():
+    obs = {"trace": traced(), "traced_steps": 16}
+    per_call = {"line": "modules", "match": "^jit_prefill_paged$", "per": "call", "scale": 1000}
+    assert trace_time.read(obs, per_call, TPU) == pytest.approx(50.0)
+    assert trace_time.read(obs, {"line": "ops", "match": "^closed_call", "per": "window"}, TPU) == 0.2
+    assert trace_time.read(obs, {"line": "modules", "match": "^nothing$"}, TPU) is None
+    assert trace_time.read({}, per_call, TPU) is None
+    assert device_idle.read(obs, {}, TPU) == pytest.approx(2.5)
+    assert device_idle.read({}, {}, TPU) is None
+    assert exposed.read(obs, {"collective": "all-reduce"}, TPU) == pytest.approx(2.0)
+    assert exposed.read({"trace": traced()}, {"collective": "all-reduce"}, TPU) is None
+
+
+def test_decode_step_and_roofline_by_hand():
+    # 4 s of counters: 2,000 tokens of which 80 were first tokens, rows 24
+    # a round: 80 token-steps, 20 a second; decode ran 3.5 of 4 s
+    tc = counters(
+        {"rt_serve_tokens_generated_total": 0.0, "rt_serve_ttft_s": (0.0, 0),
+         "rt_serve_batch_fill": (0.0, 0)},
+        {"rt_serve_tokens_generated_total": 2000.0, "rt_serve_ttft_s": (40.0, 80),
+         "rt_serve_batch_fill": (1920.0, 80)},
+    )
+    tc["seconds"] = 4.0
+    obs = {"trace": traced(), "trace_counters": tc, "model": XL,
+           "device": {"kind": "TPU v5 lite"},
+           "records": [rec(0, 0, [], usage={"prompt_tokens": 100, "completion_tokens": 100})]}
+    args = {"match": "^jit_decode_(paged_and_sample|multi_paged)$"}
+    assert decode_step.token_steps_per_s(obs) == pytest.approx(20.0)
+    assert decode_step.read(obs, args, TPU) == pytest.approx(1000 * (3.5 / 4.0) / 20.0)
+    from benchmark import peaks
+
+    assert decode_roofline.read(obs, args, TPU) == pytest.approx(
+        peaks.decode_roofline(0.04375, XL, 24.0, 150.0, "TPU v5 lite")
+    )
+    assert decode_step.read({"trace": traced()}, args, TPU) is None
+
+
+def test_compiles_counts_cache_entries_and_compile_events():
+    obs = {"cache_entries": {"t0": 75, "t1": 76}, "trace": traced()}
+    assert compiles.read(obs, {}, TPU) == 3.0  # one entry, two PjRtCompile events
+    assert compiles.read({"cache_entries": {"t0": 5, "t1": 5}}, {}, TPU) == 0.0
+
+
+def test_train_readers():
+    stamps = [100.0 + 2.0 * i for i in range(11)]
+    stamps[6:] = [s + 1.0 for s in stamps[6:]]  # one slow sync
+    obs = {"syncs": stamps, "tokens_per_sync": 262144, "steps_per_sync": 8,
+           "model": {"n_embd": 768, "n_layer": 12, "n_head": 12, "n_positions": 1024,
+                     "vocab_size": 50257},
+           "device": {"kind": "TPU v5 lite", "count": 1, "memory_peak_bytes": 15e9,
+                      "memory_limit_bytes": 16e9}, "setup_s": 25.5}
+    # the rate is over all the time between the first and last sync, the
+    # slow one included; the step and the MFU are at the median reading
+    assert sync_rate.read(obs, {}, TPU) == pytest.approx(262144 * 10 / 21.0)
+    assert step_ms.read(obs, {}, TPU) == pytest.approx(250.0)
+    assert step_ms.read({"syncs": [1.0], "steps_per_sync": 8}, {}, TPU) is None
+    assert train_mfu.read(obs, {}, TPU) == pytest.approx(49.31, abs=0.01)
+    assert train_mfu.read(obs, {}, CPU) is None
+    assert hbm_used.read(obs, {}, TPU) == pytest.approx(93.75)
+    assert hbm_used.read(obs, {}, CPU) is None
+    assert observed.read(obs, {"key": "setup_s"}, TPU) == 25.5
+    assert observed.read(obs, {"key": "missing"}, TPU) is None
+    assert sync_rate.read({"syncs": [1.0]}, {}, TPU) is None
