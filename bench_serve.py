@@ -59,8 +59,8 @@ Legs (``--leg``):
   regime where per-chunk host overhead, not arrival jitter, sets ITL.
   Arms run async/sync/async/sync; each records client ITL p50/p95 +
   aggregate tokens/s plus the server-side
-  ``rt_serve_decode_host_gap_s`` delta (host time the device sat idle
-  between dispatches — the gap the one-step lookahead hides).
+  ``rt_serve_engine_round_host_s`` delta (the engine thread's own time
+  per round, which the one-step lookahead runs under the device).
 
 Every run appends one row to BENCH_SERVE.json.
 
@@ -428,8 +428,8 @@ def _asyncdecode_leg(args, host_meta):
     pool sizes; only RT_SERVE_ASYNC_DECODE flips (carried per-arm on the
     pickled LLMConfig, so no env coordination with replicas). Reports
     client-side ITL p50/p95 + aggregate tokens/s and the server-side
-    rt_serve_decode_host_gap_s delta — the host time the device sat
-    idle, which the lookahead exists to hide."""
+    rt_serve_engine_round_host_s delta — the engine thread's own time
+    per round, which the lookahead exists to hide."""
     import ray_tpu
     from ray_tpu import serve, state
     from ray_tpu.serve import llm as serve_llm
@@ -463,12 +463,12 @@ def _asyncdecode_leg(args, host_meta):
                     _stream_one(host, port, n, 4, args.timeout)
 
             mx0 = state.cluster_metrics()
-            g0c, g0s = _hist_totals(mx0, "rt_serve_decode_host_gap_s")
+            g0c, g0s = _hist_totals(mx0, "rt_serve_engine_round_host_s")
             results, clients, hung, wall_s = _run_closed_window(
                 host, port, args
             )
             mx1 = state.cluster_metrics()
-            g1c, g1s = _hist_totals(mx1, "rt_serve_decode_host_gap_s")
+            g1c, g1s = _hist_totals(mx1, "rt_serve_engine_round_host_s")
 
             ok = [r for r in results if r.get("ok")]
             itls = sorted(g for r in ok for g in r["itls"])
@@ -486,11 +486,11 @@ def _asyncdecode_leg(args, host_meta):
                 "tokens_per_s": round(tokens / wall_s, 1),
                 "itl_p50_ms": round(itl50 * 1e3, 2) if itl50 else None,
                 "itl_p95_ms": round(itl95 * 1e3, 2) if itl95 else None,
-                "host_gap_mean_ms": (
+                "host_ms_mean": (
                     round(gap_mean * 1e3, 3) if gap_mean is not None
                     else None
                 ),
-                "host_gap_dispatches": round(g1c - g0c, 0),
+                "host_rounds": round(g1c - g0c, 0),
             })
             print(json.dumps({"arm_done": arms[-1]}), flush=True)
 
@@ -503,7 +503,7 @@ def _asyncdecode_leg(args, host_meta):
 
         summary = {}
         for key in ("tokens_per_s", "itl_p50_ms", "itl_p95_ms",
-                    "host_gap_mean_ms"):
+                    "host_ms_mean"):
             a, s = mean_of("async", key), mean_of("sync", key)
             summary[key] = {
                 "async": round(a, 3) if a is not None else None,

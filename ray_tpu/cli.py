@@ -240,13 +240,12 @@ def _render_top(mx: dict, reqs: dict, qps: Optional[dict],
         row(dep)["itl_p50_ms"] = ms(
             _hist_quantile(h["bounds"], h["buckets"], 0.5)
         )
-    for dep, h in hist_by_tag("rt_serve_decode_host_gap_s", "deployment").items():
-        # host time the device sat idle between decode dispatches: ~0
-        # when the async decode pipeline keeps a lookahead chunk in
-        # flight, the per-chunk Python overhead when it does not
-        row(dep)["host_gap_p95_ms"] = ms(
-            _hist_quantile(h["bounds"], h["buckets"], 0.95)
-        )
+    for dep, h in hist_by_tag("rt_serve_engine_round_host_s",
+                              "deployment").items():
+        # mean engine-thread time per round in its own code (admit,
+        # prefill build, dispatch, harvest), device syncs excluded
+        if h["count"]:
+            row(dep)["host_ms"] = ms(h["sum"] / h["count"])
     for dep, h in hist_by_tag("rt_serve_batch_fill", "deployment").items():
         if h["count"]:
             row(dep)["batch_fill"] = f"{h['sum'] / h['count']:.1f}"
@@ -284,7 +283,7 @@ def _render_top(mx: dict, reqs: dict, qps: Optional[dict],
             f"{qps.get(dep, 0.0):.1f}" if qps is not None else "-"
         )
     columns = ["deployment", "replicas", "reqs", "qps", "ttft_p50_ms",
-               "ttft_p95_ms", "itl_p50_ms", "host_gap_p95_ms", "tokens",
+               "ttft_p95_ms", "itl_p50_ms", "host_ms", "tokens",
                "kv_slots", "kv_pages", "queued", "shed", "batch_fill",
                "cache_hit",
                "page_hit", "last_scale"]

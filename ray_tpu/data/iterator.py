@@ -16,6 +16,7 @@ from typing import TYPE_CHECKING, Dict, Iterator, Optional
 import numpy as np
 
 from ray_tpu.data.block import Block, build_batches
+from ray_tpu.observability import tracing
 
 if TYPE_CHECKING:
     from ray_tpu.data.dataset import Dataset
@@ -63,7 +64,10 @@ class DataIterator:
         t.start()
         try:
             while True:
-                item = q.get()
+                # the Train path's one boundary inside the program: an
+                # input stall shows in the profiler's trace by this name
+                with tracing.span("rt/data/wait_block"):
+                    item = q.get()
                 if item is done:
                     if error:
                         raise error[0]

@@ -133,12 +133,79 @@ def _build() -> dict:
             boundaries=_LATENCY_BOUNDS,
             tag_keys=("deployment",),
         ),
-        "serve_decode_host_gap_s": Histogram(
-            "rt_serve_decode_host_gap_s",
-            "host time between consecutive decode dispatches while the "
-            "device sat idle with work available; ~0 when the async "
-            "decode pipeline keeps a lookahead chunk in flight",
+        # the paged engine's own account of itself (serve/llm.py
+        # _engine_loop_paged): a request's waits, the engine thread's
+        # time per round, and counts stamped where the work happens.
+        # Sum and count are what is read; the buckets are coarse.
+        "serve_engine_queue_wait_s": Histogram(
+            "rt_serve_engine_queue_wait_s",
+            "time a request waits in the engine's queue, enqueue to "
+            "pages reserved, observed at admission",
             boundaries=_LATENCY_BOUNDS,
+            tag_keys=("deployment",),
+        ),
+        "serve_engine_page_wait_s": Histogram(
+            "rt_serve_engine_page_wait_s",
+            "part of the queue wait spent refused for KV pages (first "
+            "refusal to admission); 0 for a request never refused, so "
+            "sum/count is the mean over all admitted",
+            boundaries=_LATENCY_BOUNDS,
+            tag_keys=("deployment",),
+        ),
+        "serve_engine_first_token_s": Histogram(
+            "rt_serve_engine_first_token_s",
+            "time from pages reserved to the first token sampled "
+            "(prefill, and the decode chunks queued ahead of it)",
+            boundaries=_LATENCY_BOUNDS,
+            tag_keys=("deployment",),
+        ),
+        "serve_engine_round_host_s": Histogram(
+            "rt_serve_engine_round_host_s",
+            "engine-thread time per working round in its own code "
+            "(admit, prefill build, dispatch, harvest); device syncs and "
+            "idle waits excluded",
+            boundaries=_LATENCY_BOUNDS,
+            tag_keys=("deployment",),
+        ),
+        "serve_engine_round_blocked_s": Histogram(
+            "rt_serve_engine_round_blocked_s",
+            "engine-thread time per working round blocked on the device "
+            "(first-token sample, harvest of the in-flight chunk)",
+            boundaries=_LATENCY_BOUNDS,
+            tag_keys=("deployment",),
+        ),
+        "serve_decode_steps": Counter(
+            "rt_serve_decode_steps_total",
+            "decode token-steps executed (K per harvested chunk)",
+            tag_keys=("deployment",),
+        ),
+        "serve_decode_row_steps": Counter(
+            "rt_serve_decode_row_steps_total",
+            "decode token-steps times live rows (= tokens generated by "
+            "decode, first tokens excluded)",
+            tag_keys=("deployment",),
+        ),
+        "serve_prompt_tokens": Counter(
+            "rt_serve_prompt_tokens_total",
+            "prompt tokens of admitted requests (once per admission)",
+            tag_keys=("deployment",),
+        ),
+        "serve_prefix_tokens_reused": Counter(
+            "rt_serve_prefix_tokens_reused_total",
+            "prompt tokens of admitted requests served from resident "
+            "prefix pages (once per admission, not per attempt)",
+            tag_keys=("deployment",),
+        ),
+        "serve_prefill_tokens": Counter(
+            "rt_serve_prefill_tokens_total",
+            "prompt tokens computed by prefill calls (padding excluded)",
+            tag_keys=("deployment",),
+        ),
+        "serve_prefill_width": Histogram(
+            "rt_serve_prefill_width",
+            "padded token width of each prefill call (sum = padded "
+            "tokens computed, count = calls)",
+            boundaries=(16, 32, 64, 128, 256, 512, 1024),
             tag_keys=("deployment",),
         ),
         "serve_tokens_generated": Counter(
@@ -173,16 +240,6 @@ def _build() -> dict:
             "rt_serve_prefix_cache_misses_total",
             "prompt prefix blocks that had to be prefilled (not resident)",
             tag_keys=("deployment",),
-        ),
-        "serve_prefix_cache_evictions": Counter(
-            "rt_serve_prefix_cache_evictions_total",
-            "prefix blocks LRU-evicted from the engine block pool",
-            tag_keys=("deployment",),
-        ),
-        "serve_prefix_blocks_resident": Gauge(
-            "rt_serve_prefix_blocks_resident",
-            "prefix KV blocks currently resident in this engine's pool",
-            tag_keys=("deployment", "node"),
         ),
         # paged KV pool (serve/prefix_cache.PagedKVPool): one page pool
         # holds generation AND prefix KV; occupied counts pages pinned

@@ -22,6 +22,11 @@ On top of the task lifecycle, the same ring carries:
 - collective spans (``"type": "collective"``) — one per host collective
   op, so the bytes counters in core_metrics get a timeline counterpart.
 
+Beside the ring, ``span()`` puts a named host span into the profiler's
+own trace (``jax.profiler.TraceAnnotation``), on the same clock as the
+device operations; the ``ts_us`` argument of an ``rt/engine/round`` span
+relates that clock to the ring's.
+
 Timestamps: every stamp uses ``now_us()`` — a per-process wall-clock
 anchor recorded ONCE at import plus a monotonic delta — so intra-run
 ordering (and cross-pid joins within one run) survives NTP steps
@@ -37,7 +42,9 @@ Import discipline: only ``ray_tpu.utils.*`` imports allowed here.
 
 from __future__ import annotations
 
+import contextlib
 import os
+import sys
 import time
 import uuid
 from typing import Any, Dict, Optional
@@ -60,6 +67,11 @@ PROXY = "proxy"
 ROUTER = "router"
 REPLICA = "replica"
 ENGINE = "engine"
+# phases of the engine's leg, children of its span, tiling it: enqueued
+# -> pages reserved -> first token sampled -> done
+ENGINE_QUEUE = "engine.queue"
+ENGINE_PREFILL = "engine.prefill"
+ENGINE_DECODE = "engine.decode"
 PREFILL = "prefill"
 TRANSFER = "transfer"
 
@@ -85,6 +97,23 @@ def set_enabled(on: bool) -> None:
     global ENABLED
     ENABLED = bool(on)
     config.set("trace_events", bool(on))
+
+
+_NO_SPAN = contextlib.nullcontext()
+
+
+def span(name: str, **args: Any):
+    """Context manager: a host span named ``name`` in the profiler's
+    trace when this process has jax loaded, nothing otherwise. Never
+    imports jax (proxy, router and load generators stay off it). With no
+    profiler session open a ``TraceAnnotation`` is one flag check; with
+    one open the span lands in the host plane of the same ``.xplane.pb``
+    as the device operations."""
+    if ENABLED:
+        jax = sys.modules.get("jax")
+        if jax is not None:
+            return jax.profiler.TraceAnnotation(name, **args)
+    return _NO_SPAN
 
 
 def new_trace_id() -> str:
@@ -122,11 +151,14 @@ def request_span(
     ts_us: int,
     dur_us: int,
     worker_address: str = "",
+    parent: Optional[str] = None,
     **extra: Any,
 ) -> Dict[str, Any]:
     """Build one request span (proxy/router/replica/engine leg of a
-    serve request). ``ts_us`` comes from ``now_us()`` taken at span
-    start; extras (e.g. queue_us, status) ride along untyped."""
+    serve request, or a phase of the engine's leg). ``ts_us`` comes from
+    ``now_us()`` taken at span start; ``parent`` names the component
+    whose span of the same trace encloses this one; extras (e.g.
+    queue_us, status) ride along untyped."""
     evt = {
         "type": "request",
         "trace_id": trace_id,
@@ -137,6 +169,8 @@ def request_span(
         "worker": worker_address,
         "pid": os.getpid(),
     }
+    if parent is not None:
+        evt["parent"] = parent
     if extra:
         evt.update(extra)
     return evt

@@ -355,7 +355,7 @@ class PrefillServer:
         if tracing.ENABLED and trace_id:
             tracing.emit(tracing.request_span(
                 trace_id, tracing.PREFILL, model, t0u,
-                tracing.now_us() - t0u,
+                tracing.now_us() - t0u, parent=tracing.REPLICA,
                 tokens=shipment["prompt_len"],
                 cached=shipment["cached_tokens"] > 0,
                 kv_bytes=nbytes,
@@ -427,7 +427,7 @@ def prefill_remote(deployment: str, model: str, eng_req: Dict[str, Any],
         if tracing.ENABLED and trace_id:
             tracing.emit(tracing.request_span(
                 trace_id, tracing.TRANSFER, model, t0u,
-                tracing.now_us() - t0u,
+                tracing.now_us() - t0u, parent=tracing.REPLICA,
                 kv_bytes=int(ack.get("kv_bytes", 0)),
             ))
         return {

@@ -71,7 +71,7 @@ class LLMConfig:
 class _Request:
     __slots__ = ("prompt", "max_new", "temperature", "event", "result",
                  "error", "token_q", "cancelled", "trace_id", "t_enqueue",
-                 "t0_us", "kv_import")
+                 "t_refused", "t0_us", "kv_import")
 
     def __init__(self, prompt, max_new, temperature, stream=False):
         self.prompt = prompt
@@ -90,6 +90,9 @@ class _Request:
         # stamps for the engine span and the TTFT histogram
         self.trace_id: Optional[str] = None
         self.t_enqueue: Optional[float] = None
+        # paged engine: first time admission refused this request for
+        # want of KV pages (None if it never was)
+        self.t_refused: Optional[float] = None
         self.t0_us = 0
         # set when the consumer abandoned the request (client disconnect
         # mid-stream): the engine frees the KV slot at the next round
@@ -136,7 +139,7 @@ class _PagedSeq:
     __slots__ = ("req", "prompt", "pages", "released", "digests", "n_hit",
                  "table", "cached_tokens", "prefill_pos", "length",
                  "produced", "last_token", "t_last", "ttft_us", "active",
-                 "budget_left")
+                 "budget_left", "t_admit", "t_first")
 
     def __init__(self, req: _Request, prompt: List[int]):
         self.req = req
@@ -158,6 +161,11 @@ class _PagedSeq:
         self.last_token = 0
         self.t_last: Optional[float] = None
         self.ttft_us = 0
+        # monotonic stamps of the request's phases (with the enqueue and
+        # first-refusal stamps on the request): pages reserved, first
+        # token sampled. A handful per request, never per token.
+        self.t_admit: Optional[float] = None
+        self.t_first: Optional[float] = None
         self.active = False  # prefill complete, decoding
         # decode steps this sequence may still be dispatched for;
         # decremented AT DISPATCH (not harvest) so the pipelined loop
@@ -352,7 +360,9 @@ class LLMServer:
         if core_metrics.ENABLED or tracing.ENABLED:
             req.t_enqueue = time.monotonic()
             if tracing.ENABLED and req.trace_id:
-                req.t0_us = tracing.now_us()
+                # one reading for both clocks: the engine span and its
+                # phases then tile exactly
+                req.t0_us = tracing.mono_us(req.t_enqueue)
         with self._lock:
             self._queue.append(req)
         self._work.set()
@@ -491,10 +501,6 @@ class LLMServer:
         # the pipeline is drained
         async_mode = self._async_decode
         inflight: Optional[_Chunk] = None
-        # monotonic stamp of the moment the device ran dry with work
-        # still active; the next dispatch observes the span as
-        # rt_serve_decode_host_gap_s (0 when a lookahead kept it busy)
-        gap_start: Optional[float] = None
 
         def _bucket(n: int, cap: int) -> int:
             # next power of two: one compile per bucket, and a short
@@ -652,7 +658,7 @@ class LLMServer:
                 tracing.emit(tracing.request_span(
                     s.req.trace_id, tracing.ENGINE, self.cfg.model_id,
                     s.req.t0_us, tracing.now_us() - s.req.t0_us,
-                    tokens=len(s.req.result),
+                    parent=tracing.REPLICA, tokens=len(s.req.result),
                     cached=s.cached, ttft_us=s.ttft_us,
                 ))
             s.req.event.set()
@@ -682,21 +688,15 @@ class LLMServer:
                     if fin:
                         self._fail_request(s.req, e)
 
-        def harvest(rec: _Chunk, drained: bool) -> None:
+        def harvest(rec: _Chunk) -> None:
             """Materialize a dispatched chunk's tokens and run all its
             host bookkeeping: fan-out, SSE queue puts, metric stamps,
             completions. In async mode this executes while the NEXT
             chunk (already dispatched) keeps the device busy —
             np.asarray is the only sync point."""
-            nonlocal gap_start
             toks = np.asarray(rec.toks_dev)
             if toks.ndim == 1:
                 toks = toks[None]  # [1, S]
-            if drained and core_metrics.ENABLED:
-                # no younger chunk in flight: the device just ran dry
-                # and stays dry until the next dispatch — that span is
-                # the host gap the async pipeline exists to hide
-                gap_start = time.monotonic()
             n_new = rec.n_steps
             live = [r for r in rec.rows if r[0] not in rec.dropped]
             if core_metrics.ENABLED:
@@ -734,7 +734,7 @@ class LLMServer:
                     lengths[i] = s.length
 
         def dispatch(active: List[int], waiting: bool) -> _Chunk:
-            nonlocal cache_k, cache_v, dev_state, step_no, gap_start
+            nonlocal cache_k, cache_v, dev_state, step_no
             if dev_state is None:
                 dev_state = (
                     jnp.asarray(last), jnp.asarray(lengths),
@@ -763,13 +763,6 @@ class LLMServer:
                 K = max(1, min(8, min(
                     slots[i].budget_left for i in active
                 )))
-            if core_metrics.ENABLED:
-                core_metrics.serve_decode_host_gap_s.observe(
-                    (time.monotonic() - gap_start)
-                    if gap_start is not None else 0.0,
-                    tags={"deployment": self.cfg.model_id},
-                )
-            gap_start = None
             self._record_step(len(active))
             if K > 1:
                 toks_dev, d_last2, d_len, cache_k, cache_v = (
@@ -807,7 +800,7 @@ class LLMServer:
             """One continuous-batching round: reap/admit -> dispatch the
             next chunk -> harvest the previous one (async lookahead) or
             this one (sync)."""
-            nonlocal cache_k, cache_v, dev_state, inflight, gap_start
+            nonlocal cache_k, cache_v, dev_state, inflight
             if cache_k is None:  # rebuild after a poisoned (donated) round
                 cache_k, cache_v = dec.init_cache(mcfg, S, T_max)
                 dev_state = None
@@ -862,10 +855,9 @@ class LLMServer:
                     # drain the lookahead before idling: its tokens are
                     # real and its pending finishes must complete
                     rec, inflight = inflight, None
-                    harvest(rec, True)
+                    harvest(rec)
                 elif not admitted:
                     self._work.wait(timeout=0.5)
-                gap_start = None
                 return
             with self._lock:
                 waiting = bool(self._queue)
@@ -875,9 +867,9 @@ class LLMServer:
                 # chunk N's host bookkeeping underneath it
                 prev, inflight = inflight, rec
                 if prev is not None:
-                    harvest(prev, False)
+                    harvest(prev)
             else:
-                harvest(rec, True)
+                harvest(rec)
 
         self._engine_started(cache_k, cache_v)
         while not self._stop.is_set():
@@ -892,7 +884,6 @@ class LLMServer:
                 fail_inflight(e)
                 dev_state = None
                 dirty.clear()
-                gap_start = None
                 # prefill/decode donate the caches (donate_argnums): an
                 # exception raised after dispatch leaves cache_k/cache_v
                 # pointing at deleted buffers on TPU, so every later round
@@ -990,10 +981,23 @@ class LLMServer:
         # the pipeline is drained
         async_mode = self._async_decode
         inflight: Optional[_Chunk] = None
-        # monotonic stamp of the moment the device ran dry with work
-        # still active; the next dispatch observes the span as
-        # rt_serve_decode_host_gap_s (0 when a lookahead kept it busy)
-        gap_start: Optional[float] = None
+        dep_tags = {"deployment": self.cfg.model_id}
+        # seconds of the current round this thread spent blocked on the
+        # device (rt_serve_engine_round_blocked_s)
+        blocked_s = 0.0
+
+        def sync(name: str, fn, *args):
+            """Run a call that blocks on the device, under its span, and
+            add its wall time to the round's blocked seconds."""
+            nonlocal blocked_s
+            with tracing.span(name):
+                if not core_metrics.ENABLED:
+                    return fn(*args)
+                t = time.monotonic()
+                try:
+                    return fn(*args)
+                finally:
+                    blocked_s += time.monotonic() - t
 
         def _bucket(n: int, cap: int) -> int:
             p = 16
@@ -1044,17 +1048,21 @@ class LLMServer:
             temps[i] = max(s.req.temperature, 1e-6)
             greedy[i] = s.req.temperature <= 0
             dirty.add(i)
-            if tracing.ENABLED and s.req.t0_us:
-                s.ttft_us = tracing.now_us() - s.req.t0_us
-            if core_metrics.ENABLED:
-                now = time.monotonic()
-                s.t_last = now
-                dep_tags = {"deployment": self.cfg.model_id}
-                if s.req.t_enqueue is not None:
-                    core_metrics.serve_ttft_s.observe(
-                        now - s.req.t_enqueue, tags=dep_tags
-                    )
-                core_metrics.serve_tokens_generated.inc(tags=dep_tags)
+            if core_metrics.ENABLED or tracing.ENABLED:
+                now = s.t_first = time.monotonic()
+                if tracing.ENABLED and s.req.t0_us:
+                    s.ttft_us = tracing.mono_us(now) - s.req.t0_us
+                if core_metrics.ENABLED:
+                    s.t_last = now
+                    if s.req.t_enqueue is not None:
+                        core_metrics.serve_ttft_s.observe(
+                            now - s.req.t_enqueue, tags=dep_tags
+                        )
+                    if s.t_admit is not None:
+                        core_metrics.serve_engine_first_token_s.observe(
+                            now - s.t_admit, tags=dep_tags
+                        )
+                    core_metrics.serve_tokens_generated.inc(tags=dep_tags)
             if s.req.token_q is not None and s.req.max_new >= 1:
                 # zero-token completions must not leak the sampled-but-
                 # unrequested first token into the stream
@@ -1088,7 +1096,7 @@ class LLMServer:
                 pool.copies += nblk
                 if core_metrics.ENABLED:
                     core_metrics.serve_kv_block_copies.inc(
-                        nblk, tags={"deployment": self.cfg.model_id}
+                        nblk, tags=dep_tags
                     )
                 for j in range(first_pg, min(n // B, len(s.digests))):
                     pool.seal(s.digests[j], int(s.pages[j]))
@@ -1126,6 +1134,8 @@ class LLMServer:
             new_pages = pool.alloc(n_pages - len(hit_pages))
             if new_pages is None:
                 pool.release_pages(hit_pages)
+                if req.t_enqueue is not None and req.t_refused is None:
+                    req.t_refused = time.monotonic()
                 return False
             s = _PagedSeq(req, prompt)
             s.pages = hit_pages + new_pages
@@ -1137,6 +1147,28 @@ class LLMServer:
             row[: len(s.pages)] = s.pages
             s.table = row
             seqs[i] = s
+            if core_metrics.ENABLED or tracing.ENABLED:
+                s.t_admit = time.monotonic()
+            if core_metrics.ENABLED:
+                # per admission, not per attempt: a request refused for
+                # pages is matched again every round until it fits, and
+                # only the attempt that admits it counts here
+                core_metrics.serve_prompt_tokens.inc(
+                    len(prompt), tags=dep_tags
+                )
+                if s.cached_tokens:
+                    core_metrics.serve_prefix_tokens_reused.inc(
+                        s.cached_tokens, tags=dep_tags
+                    )
+                if req.t_enqueue is not None:
+                    core_metrics.serve_engine_queue_wait_s.observe(
+                        s.t_admit - req.t_enqueue, tags=dep_tags
+                    )
+                    core_metrics.serve_engine_page_wait_s.observe(
+                        s.t_admit - req.t_refused
+                        if req.t_refused is not None else 0.0,
+                        tags=dep_tags,
+                    )
             try:
                 if req.kv_import is not None:
                     import_kv(i, s, req.kv_import)
@@ -1169,13 +1201,21 @@ class LLMServer:
                     n = min(len(s.prompt) - start, budget)
                     width = _bucket(n, max_pages * B - start)
                     n = min(n, width)
-                    tok = np.zeros((1, width), np.int32)
-                    tok[0, :n] = s.prompt[start : start + n]
-                    logits, cache_k, cache_v = dec.prefill_paged(
-                        mcfg, self.params, jnp.asarray(tok),
-                        jnp.int32(start), jnp.int32(n),
-                        cache_k, cache_v, jnp.asarray(s.table),
-                    )
+                    with tracing.span("rt/engine/prefill"):
+                        tok = np.zeros((1, width), np.int32)
+                        tok[0, :n] = s.prompt[start : start + n]
+                        logits, cache_k, cache_v = dec.prefill_paged(
+                            mcfg, self.params, jnp.asarray(tok),
+                            jnp.int32(start), jnp.int32(n),
+                            cache_k, cache_v, jnp.asarray(s.table),
+                        )
+                    if core_metrics.ENABLED:
+                        core_metrics.serve_prefill_tokens.inc(
+                            n, tags=dep_tags
+                        )
+                        core_metrics.serve_prefill_width.observe(
+                            width, tags=dep_tags
+                        )
                     s.prefill_pos = start + n
                     budget -= n
                 if s.prefill_pos >= len(s.prompt) and logits is not None:
@@ -1185,18 +1225,43 @@ class LLMServer:
                     n_full = len(s.prompt) // B
                     for j in range(s.n_hit, min(n_full, len(s.digests))):
                         pool.seal(s.digests[j], int(s.pages[j]))
-                    first = self._sample_one(logits, s.req.temperature)
+                    first = sync(
+                        "rt/engine/first_token_sync", self._sample_one,
+                        logits, s.req.temperature,
+                    )
                     activate(i, s, int(first), len(s.prompt))
 
         def complete(s: _PagedSeq) -> None:
             s.req.result = s.produced[: s.req.max_new]
             if tracing.ENABLED and s.req.trace_id and s.req.t0_us:
+                tid, dep = s.req.trace_id, self.cfg.model_id
+                t0, end = s.req.t0_us, tracing.now_us()
                 tracing.emit(tracing.request_span(
-                    s.req.trace_id, tracing.ENGINE, self.cfg.model_id,
-                    s.req.t0_us, tracing.now_us() - s.req.t0_us,
-                    tokens=len(s.req.result),
+                    tid, tracing.ENGINE, dep, t0, end - t0,
+                    parent=tracing.REPLICA, tokens=len(s.req.result),
                     cached=s.cached_tokens > 0, ttft_us=s.ttft_us,
                 ))
+                if s.t_admit is not None and s.t_first is not None:
+                    # the request's phases, tiling the span above
+                    adm = tracing.mono_us(s.t_admit)
+                    first = tracing.mono_us(s.t_first)
+                    refused = s.req.t_refused
+                    tracing.emit(tracing.request_span(
+                        tid, tracing.ENGINE_QUEUE, dep, t0, adm - t0,
+                        parent=tracing.ENGINE,
+                        page_wait_us=adm - tracing.mono_us(refused)
+                        if refused is not None else 0,
+                    ))
+                    tracing.emit(tracing.request_span(
+                        tid, tracing.ENGINE_PREFILL, dep, adm, first - adm,
+                        parent=tracing.ENGINE,
+                        cached_tokens=s.cached_tokens,
+                        prompt_tokens=len(s.prompt),
+                    ))
+                    tracing.emit(tracing.request_span(
+                        tid, tracing.ENGINE_DECODE, dep, first, end - first,
+                        parent=tracing.ENGINE, tokens=len(s.req.result),
+                    ))
             s.req.event.set()
             if s.req.token_q is not None:
                 s.req.token_q.put(None)  # end of stream
@@ -1227,26 +1292,29 @@ class LLMServer:
                     if fin:
                         self._fail_request(s.req, e)
 
-        def harvest(rec: _Chunk, drained: bool) -> None:
+        def harvest(rec: _Chunk) -> None:
             """Materialize a dispatched chunk's tokens and run all its
             host bookkeeping: fan-out, SSE queue puts, metric stamps,
             completions, deferred page frees. In async mode this
             executes while the NEXT chunk (already dispatched) keeps
             the device busy — np.asarray is the only sync point."""
-            nonlocal gap_start
-            toks = np.asarray(rec.toks_dev)
+            toks = sync("rt/engine/harvest_sync", np.asarray, rec.toks_dev)
+            with tracing.span("rt/engine/harvest"):
+                deliver(rec, toks)
+
+        def deliver(rec: _Chunk, toks) -> None:
             if toks.ndim == 1:
                 toks = toks[None]  # [1, S]
-            if drained and core_metrics.ENABLED:
-                # no younger chunk in flight: the device just ran dry
-                # and stays dry until the next dispatch — that span is
-                # the host gap the async pipeline exists to hide
-                gap_start = time.monotonic()
             n_new = rec.n_steps
             live = [r for r in rec.rows if r[0] not in rec.dropped]
             if core_metrics.ENABLED:
                 now = time.monotonic()
-                dep_tags = {"deployment": self.cfg.model_id}
+                # counted here, not at dispatch: the chunk's steps have
+                # now run on the device
+                core_metrics.serve_decode_steps.inc(n_new, tags=dep_tags)
+                core_metrics.serve_decode_row_steps.inc(
+                    n_new * len(live), tags=dep_tags
+                )
                 core_metrics.serve_tokens_generated.inc(
                     n_new * len(live), tags=dep_tags
                 )
@@ -1282,9 +1350,8 @@ class LLMServer:
                 pool.release_pages(rec.free_after)
                 rec.free_after = []
 
-        def dispatch(active: List[int], waiting: bool,
-                     prefilling: bool) -> _Chunk:
-            nonlocal cache_k, cache_v, dev_state, step_no, gap_start
+        def dispatch(active: List[int], K: int) -> _Chunk:
+            nonlocal cache_k, cache_v, dev_state, step_no
             if dev_state is None:
                 dev_state = (
                     jnp.asarray(last), jnp.asarray(lengths),
@@ -1306,22 +1373,6 @@ class LLMServer:
                 )
                 dirty.clear()
             d_last, d_len, d_temps, d_greedy, d_tables = dev_state
-            # Chunk size: single-step while requests wait for admission
-            # OR any sequence is mid-prefill (the next prefill chunk
-            # must interleave after ONE decode step, or ITL for live
-            # streams would stretch by the whole chunk).
-            K = 1
-            if not waiting and not prefilling:
-                K = max(1, min(8, min(
-                    seqs[i].budget_left for i in active
-                )))
-            if core_metrics.ENABLED:
-                core_metrics.serve_decode_host_gap_s.observe(
-                    (time.monotonic() - gap_start)
-                    if gap_start is not None else 0.0,
-                    tags={"deployment": self.cfg.model_id},
-                )
-            gap_start = None
             self._record_step_paged(len(active), pool.stats())
             if K > 1:
                 toks_dev, d_last2, d_len, cache_k, cache_v = (
@@ -1359,26 +1410,11 @@ class LLMServer:
                     retire(i, rec)
             return rec
 
-        def one_round() -> None:
-            nonlocal cache_k, cache_v, dev_state, inflight, gap_start
-            if cache_k is None:
-                # rebuild after a poisoned (donated) round. The pool's
-                # sealed pages pointed into the deleted cache, so ALL
-                # pool metadata resets with it (the BlockPool kept host
-                # copies and could survive this; the page pool cannot)
-                cache_k, cache_v = dec.init_paged_cache(mcfg, n_phys, B)
-                pool.reset()
-                dev_state = None
-                dirty.clear()
-            # consume the wake flag BEFORE the queue/cancel scans: a
-            # set() landing after the scans stays pending for the idle
-            # wait below, so an idle engine can never sleep through a
-            # request that arrived between scan and wait (the old
-            # wait-then-clear order could eat exactly that wakeup — up
-            # to 500 ms of TTFT on an idle engine)
-            self._work.clear()
-            # reap abandoned requests: their pages go back to the pool
-            # instead of decoding to max_new for nobody
+        def admit_waiting() -> bool:
+            """Reap abandoned requests (their pages go back to the pool
+            instead of decoding to max_new for nobody), then admit
+            queued ones into free rows until the queue or the pool runs
+            out. True if any was admitted."""
             for i in range(S):
                 s = seqs[i]
                 if s is not None and s.req.cancelled:
@@ -1414,6 +1450,30 @@ class LLMServer:
                         self._queue.appendleft(req)
                     break
                 admitted = True
+            return admitted
+
+        def run_round() -> bool:
+            """The round itself; False if all it did was park in the
+            idle wait."""
+            nonlocal cache_k, cache_v, dev_state, inflight
+            if cache_k is None:
+                # rebuild after a poisoned (donated) round. The pool's
+                # sealed pages pointed into the deleted cache, so ALL
+                # pool metadata resets with it (the BlockPool kept host
+                # copies and could survive this; the page pool cannot)
+                cache_k, cache_v = dec.init_paged_cache(mcfg, n_phys, B)
+                pool.reset()
+                dev_state = None
+                dirty.clear()
+            # consume the wake flag BEFORE the queue/cancel scans: a
+            # set() landing after the scans stays pending for the idle
+            # wait below, so an idle engine can never sleep through a
+            # request that arrived between scan and wait (the old
+            # wait-then-clear order could eat exactly that wakeup — up
+            # to 500 ms of TTFT on an idle engine)
+            self._work.clear()
+            with tracing.span("rt/engine/admit"):
+                admitted = admit_waiting()
             run_prefill()
             prefilling = any(
                 s is not None and not s.active for s in seqs
@@ -1437,22 +1497,62 @@ class LLMServer:
                     # drain the lookahead before idling: its tokens are
                     # real and its pending finishes must complete
                     rec, inflight = inflight, None
-                    harvest(rec, True)
+                    harvest(rec)
                 elif not admitted and not prefilling:
-                    self._work.wait(timeout=0.5)
-                gap_start = None
-                return
+                    with tracing.span("rt/engine/idle"):
+                        self._work.wait(timeout=0.5)
+                    return False
+                return True
             with self._lock:
                 waiting = bool(self._queue)
-            rec = dispatch(active, waiting, prefilling)
+            # Chunk size: single-step while requests wait for admission
+            # OR any sequence is mid-prefill (the next prefill chunk
+            # must interleave after ONE decode step, or ITL for live
+            # streams would stretch by the whole chunk).
+            K = 1
+            if not waiting and not prefilling:
+                K = max(1, min(8, min(
+                    seqs[i].budget_left for i in active
+                )))
+            with tracing.span("rt/engine/dispatch", k=K, rows=len(active)):
+                rec = dispatch(active, K)
             if async_mode:
                 # one-step lookahead: chunk N+1 is on the device; run
                 # chunk N's host bookkeeping underneath it
                 prev, inflight = inflight, rec
                 if prev is not None:
-                    harvest(prev, False)
+                    harvest(prev)
             else:
-                harvest(rec, True)
+                harvest(rec)
+            return True
+
+        def one_round() -> None:
+            """One round under its span, and the engine thread's account
+            of it: seconds in its own code and seconds blocked on the
+            device. A round that only parked in the idle wait is in
+            neither, so over a window host + blocked + idle is the
+            window."""
+            nonlocal blocked_s
+            timed = core_metrics.ENABLED
+            t_round = time.monotonic() if timed else 0.0
+            blocked_s = 0.0
+            with tracing.span(
+                "rt/engine/round",
+                # the chunk in flight as the round starts, whose harvest
+                # this round blocks on; ts_us is the ring's clock at the
+                # span's start, which fixes the offset between the two
+                k=inflight.n_steps if inflight is not None else 0,
+                rows=len(inflight.rows) if inflight is not None else 0,
+                ts_us=tracing.now_us() if tracing.ENABLED else 0,
+            ):
+                worked = run_round()
+            if core_metrics.ENABLED and timed and worked:
+                core_metrics.serve_engine_round_blocked_s.observe(
+                    blocked_s, tags=dep_tags
+                )
+                core_metrics.serve_engine_round_host_s.observe(
+                    time.monotonic() - t_round - blocked_s, tags=dep_tags
+                )
 
         self._engine_started(cache_k, cache_v)
         while not self._stop.is_set():
@@ -1468,7 +1568,6 @@ class LLMServer:
                 fail_inflight(e)
                 dev_state = None
                 dirty.clear()
-                gap_start = None
                 # prefill/decode/write donate the caches: an exception
                 # after dispatch leaves them deleted — mark for rebuild
                 # (done inside the next round's try, with a pool.reset

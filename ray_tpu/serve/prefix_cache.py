@@ -25,7 +25,6 @@ doubles as bench_core's A/B lever at runtime).
 from __future__ import annotations
 
 import hashlib
-import os
 import threading
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
@@ -95,7 +94,6 @@ class BlockPool:
         self.hits = 0
         self.misses = 0
         self.evictions = 0
-        self._node_tag = f"pid{os.getpid()}"
         with _POOLS_LOCK:
             _POOLS[id(self)] = self
 
@@ -154,7 +152,6 @@ class BlockPool:
             self._tick += 1
             blk.tick = self._tick
             self._evict_locked()
-            self._publish_resident_locked()
 
     def release(self, digests: Sequence[str]) -> None:
         """Drop the caller's refs (request left its slot); newly
@@ -168,7 +165,6 @@ class BlockPool:
                 if blk is not None and blk.refs > 0:
                     blk.refs -= 1
             self._evict_locked()
-            self._publish_resident_locked()
 
     # -- maintenance ---------------------------------------------------
 
@@ -184,17 +180,6 @@ class BlockPool:
                 return  # everything pinned by in-flight requests
             del self._blocks[victim.digest]
             self.evictions += 1
-            if core_metrics.ENABLED:
-                core_metrics.serve_prefix_cache_evictions.inc(
-                    tags={"deployment": self.model_id}
-                )
-
-    def _publish_resident_locked(self) -> None:
-        if core_metrics.ENABLED:
-            core_metrics.serve_prefix_blocks_resident.set(
-                len(self._blocks),
-                tags={"deployment": self.model_id, "node": self._node_tag},
-            )
 
     def resident(self) -> int:
         with self._lock:
@@ -222,7 +207,6 @@ class BlockPool:
         with self._lock:
             self._blocks.clear()
             self._closed = True
-            self._publish_resident_locked()
         with _POOLS_LOCK:
             _POOLS.pop(id(self), None)
 
@@ -289,7 +273,6 @@ class PagedKVPool:
         # (KV-import page writes; a prefix hit must contribute ZERO) —
         # incremented by the engine next to each device copy it issues
         self.copies = 0
-        self._node_tag = f"pid{os.getpid()}"
         with _POOLS_LOCK:
             _POOLS[id(self)] = self
 
@@ -330,10 +313,6 @@ class PagedKVPool:
         victim.digest = None
         self._free.append(victim.idx)
         self.evictions += 1
-        if core_metrics.ENABLED:
-            core_metrics.serve_prefix_cache_evictions.inc(
-                tags={"deployment": self.model_id}
-            )
         return True
 
     # -- prefix matching / sealing ------------------------------------
@@ -390,7 +369,6 @@ class PagedKVPool:
             self._sealed[digest] = page
             self._tick += 1
             pg.tick = self._tick
-            self._publish_resident_locked()
             return True
 
     # -- release / maintenance ----------------------------------------
@@ -408,7 +386,6 @@ class PagedKVPool:
                     pg.refs -= 1
                 if pg.refs == 0 and pg.digest is None and not self._closed:
                     self._free.append(idx)
-            self._publish_resident_locked()
 
     def reset(self) -> None:
         """Drop ALL metadata (poisoned engine round rebuilt the device
@@ -425,14 +402,6 @@ class PagedKVPool:
             self._sealed.clear()
             self._free = list(range(self.num_pages - 1, 0, -1))
             self._tick = 0
-            self._publish_resident_locked()
-
-    def _publish_resident_locked(self) -> None:
-        if core_metrics.ENABLED:
-            core_metrics.serve_prefix_blocks_resident.set(
-                len(self._sealed),
-                tags={"deployment": self.model_id, "node": self._node_tag},
-            )
 
     # -- introspection -------------------------------------------------
 
@@ -480,7 +449,6 @@ class PagedKVPool:
             self._sealed.clear()
             self._free = []
             self._closed = True
-            self._publish_resident_locked()
         with _POOLS_LOCK:
             _POOLS.pop(id(self), None)
 

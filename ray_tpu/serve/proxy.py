@@ -154,7 +154,8 @@ class ServeProxy:
             if tracing.ENABLED:
                 tracing.emit(tracing.request_span(
                     trace[0], tracing.ROUTER, deployment, trace[2],
-                    tracing.now_us() - trace[2], replica=rid,
+                    tracing.now_us() - trace[2], parent=tracing.PROXY,
+                    replica=rid,
                 ))
         from ray_tpu.core import worker as worker_mod
 

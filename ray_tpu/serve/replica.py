@@ -72,7 +72,7 @@ class ServeReplica:
         if trace_id and tracing.ENABLED:
             tracing.emit(tracing.request_span(
                 trace_id, tracing.REPLICA, self.deployment_name,
-                t0_us, tracing.now_us() - t0_us,
+                t0_us, tracing.now_us() - t0_us, parent=tracing.ROUTER,
             ))
 
     def handle_request(self, payload: Any, *, method: Optional[str] = None):

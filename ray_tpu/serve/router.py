@@ -306,7 +306,8 @@ class Router:
         if tid and tracing.ENABLED:
             tracing.emit(tracing.request_span(
                 tid, tracing.ROUTER, deployment, t0u,
-                tracing.now_us() - t0u, replica=rid,
+                tracing.now_us() - t0u, parent=tracing.PROXY,
+                replica=rid,
             ))
         gen = None
         exhausted = False
@@ -364,7 +365,8 @@ class Router:
             if tid and tracing.ENABLED:
                 tracing.emit(tracing.request_span(
                     tid, tracing.ROUTER, deployment, t0u,
-                    tracing.now_us() - t0u, replica=rid,
+                    tracing.now_us() - t0u, parent=tracing.PROXY,
+                    replica=rid,
                 ))
             try:
                 return ray_tpu.get(ref, timeout=remaining)
@@ -418,7 +420,8 @@ class Router:
             if tid and tracing.ENABLED:
                 tracing.emit(tracing.request_span(
                     tid, tracing.ROUTER, deployment, t0u,
-                    tracing.now_us() - t0u, replica=rid,
+                    tracing.now_us() - t0u, parent=tracing.PROXY,
+                    replica=rid,
                 ))
             addr = None
             try:

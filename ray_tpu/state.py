@@ -231,8 +231,9 @@ def timeline(address: Optional[str] = None,
             # serve request leg: one slice per component, joined below
             # into a cross-pid flow by trace id
             args = {"trace_id": e["trace_id"]}
-            for k in ("queue_us", "status", "model", "cached", "ttft_us",
-                      "tokens", "kv_bytes"):
+            for k in ("parent", "queue_us", "status", "model", "cached",
+                      "ttft_us", "tokens", "kv_bytes", "page_wait_us",
+                      "cached_tokens", "prompt_tokens"):
                 if k in e:
                     args[k] = e[k]
             trace.append({
@@ -395,12 +396,17 @@ def timeline(address: Optional[str] = None,
             "pid": exec_e["worker"], "tid": exec_e.get("pid", 0),
         })
     # request flow: one arrow chain per trace id, hop by hop through the
-    # components in time order (proxy → router → replica → engine),
-    # binding each step to the enclosing component slice
+    # components (proxy → router → replica → engine → its phases): a
+    # span follows the one its ``parent`` names, so the chain nests by
+    # parentage even where two processes' clocks disagree, and runs in
+    # time order among siblings and spans that name no parent
     for trace_id, spans in request_spans.items():
         if len(spans) < 2:
             continue
-        spans = sorted(spans, key=lambda s: s["ts_us"])
+        parents = {s["component"]: s.get("parent") for s in spans}
+        spans = sorted(
+            spans, key=lambda s: (_span_depth(s, parents), s["ts_us"])
+        )
         flow = {"name": "request", "cat": "request_flow", "id": trace_id}
         for i, s in enumerate(spans):
             ph = "s" if i == 0 else ("f" if i == len(spans) - 1 else "t")
@@ -417,6 +423,16 @@ def timeline(address: Optional[str] = None,
             json.dump(trace, f)
         return out_path
     return trace
+
+
+def _span_depth(span: Dict[str, Any],
+                parents: Dict[str, Optional[str]]) -> int:
+    """How many spans of the same trace enclose ``span``, following the
+    ``parent`` each names (bounded, so a cycle cannot hang it)."""
+    d, p = 0, span.get("parent")
+    while p in parents and d < len(parents):
+        d, p = d + 1, parents[p]
+    return d
 
 
 def _percentiles(values: List[float]) -> Dict[str, float]:
@@ -480,9 +496,12 @@ def request_summary(address: Optional[str] = None) -> Dict[str, Any]:
     replica assignment), and execution (replica span), each as
     p50/p95/p99/mean/max seconds. Engine spans additionally split
     time-to-first-token by prefix-cache outcome (ttft_cached_s vs
-    ttft_cold_s), and disaggregated deployments contribute prefill_s /
-    transfer_s legs, so a hot-vs-cold or remote-prefill regression is
-    visible without raw span spelunking."""
+    ttft_cold_s), the paged engine's phase spans give engine_queue_s
+    (enqueue to pages reserved), page_wait_s (the part of it refused for
+    pages) and admit_to_first_s (pages reserved to first token), and
+    disaggregated deployments contribute prefill_s / transfer_s legs, so
+    a hot-vs-cold, queueing or remote-prefill regression is visible
+    without raw span spelunking."""
     events, dropped = _collect_task_events(address, types=["request"])
     per_dep: Dict[str, Dict[str, List[float]]] = {}
     for e in events:
@@ -504,6 +523,13 @@ def request_summary(address: Optional[str] = None) -> Dict[str, Any]:
             if ttft_us:
                 key = "ttft_cached_s" if e.get("cached") else "ttft_cold_s"
                 rec.setdefault(key, []).append(ttft_us / 1e6)
+        elif comp == "engine.queue":
+            rec.setdefault("engine_queue_s", []).append(dur_s)
+            rec.setdefault("page_wait_s", []).append(
+                e.get("page_wait_us", 0) / 1e6
+            )
+        elif comp == "engine.prefill":
+            rec.setdefault("admit_to_first_s", []).append(dur_s)
         elif comp == "prefill":
             rec.setdefault("prefill_s", []).append(dur_s)
         elif comp == "transfer":

@@ -186,6 +186,31 @@ def test_iter_epochs(rt):
         assert sum(len(b["id"]) for b in ep) == 64
 
 
+def test_prefetched_blocks_wait_under_a_named_span(rt, monkeypatch):
+    """The consumer's wait for the prefetch thread is the Train path's
+    one boundary inside the program: every block (and the end marker) is
+    taken under ``rt/data/wait_block``, and nothing else is."""
+    import contextlib
+
+    from ray_tpu.observability import tracing
+
+    entered = []
+
+    @contextlib.contextmanager
+    def span(name, **args):
+        entered.append(name)
+        yield
+
+    monkeypatch.setattr(tracing, "span", span)
+    ds = rtd.range(64, parallelism=4)
+    batches = list(ds.iterator().iter_batches(batch_size=16, prefetch_batches=2))
+    assert sum(len(b["id"]) for b in batches) == 64
+    assert entered == ["rt/data/wait_block"] * 5  # four blocks and the end
+    entered.clear()
+    list(ds.iterator().iter_batches(batch_size=16, prefetch_batches=0))
+    assert entered == []  # no prefetch thread, nothing to wait for
+
+
 def test_train_dataset_shards(rt, tmp_path):
     """datasets= flows to workers; each rank consumes a disjoint shard and
     together the shards cover the whole dataset exactly once (parity:

@@ -1,0 +1,311 @@
+"""The paged engine's account of itself (tier-1, CPU, gpt2-tiny, in
+process): the counters stamped where the work happens add up, a finished
+request's phases tile its engine span, the ``rt/engine/*`` spans land in
+the profiler's trace on one clock with the ring's, and with both
+switches off nothing is stamped at all."""
+
+import glob
+import os
+import subprocess
+import sys
+import threading
+import time
+
+import pytest
+
+from ray_tpu.observability import core_metrics, tracing
+from ray_tpu.utils.config import config
+
+B = 16  # page tokens in these tests
+SHARED = list(range(100, 100 + 2 * B))  # two full pages every "C" prompt shares
+MAX_NEW = 80
+SPANS = ("round", "admit", "prefill", "first_token_sync", "dispatch", "harvest_sync",
+         "harvest", "idle")
+SERIES = (
+    "serve_engine_queue_wait_s", "serve_engine_page_wait_s", "serve_engine_first_token_s",
+    "serve_engine_round_host_s", "serve_engine_round_blocked_s", "serve_decode_steps",
+    "serve_decode_row_steps", "serve_prompt_tokens", "serve_prefix_tokens_reused",
+    "serve_prefill_tokens", "serve_prefill_width", "serve_tokens_generated", "serve_ttft_s",
+    "serve_prefix_cache_hits", "serve_batch_fill",
+)
+
+
+def totals():
+    """Every series the engine stamps, summed over its tags: a counter's
+    value, a histogram's (sum, count)."""
+    out = {}
+    for key in SERIES:
+        snap = getattr(core_metrics, key).snapshot()
+        if snap["kind"] == "histogram":
+            out[key] = (sum(s["sum"] for s in snap["series"].values()),
+                        sum(s["count"] for s in snap["series"].values()))
+        else:
+            out[key] = sum(snap["series"].values())
+    return out
+
+
+def delta(before, after, key):
+    a, b = before[key], after[key]
+    return (b[0] - a[0], b[1] - a[1]) if isinstance(a, tuple) else b - a
+
+
+@pytest.fixture(scope="module")
+def srv():
+    """A paged engine whose pool holds one request of eight pages and
+    six pages more: two requests that share two resident pages fit, a
+    third is refused until one of them ends."""
+    import jax
+
+    jax.config.update("jax_platforms", "cpu")
+    from ray_tpu.serve.llm import LLMConfig, LLMServer
+
+    keep = {k: getattr(config, k) for k in ("serve_prefix_block_tokens", "serve_kv_pool_pages")}
+    config.set("serve_prefix_block_tokens", B)
+    config.set("serve_kv_pool_pages", 14)
+    try:
+        server = LLMServer(LLMConfig(model_id="gpt2-tiny", max_batch_size=4, paged_kv=True))
+    finally:
+        for k, v in keep.items():
+            config.set(k, v)
+    yield server
+    server.unload()
+
+
+@pytest.fixture
+def ring(monkeypatch):
+    """What the engine would append to its worker's ring (no worker
+    exists in this process)."""
+    events = []
+    monkeypatch.setattr(tracing, "emit", events.append)
+    return events
+
+
+def ask(server, prompt, stream=False, trace_id=None, max_new=MAX_NEW):
+    body = {"prompt_tokens": prompt, "max_new_tokens": max_new, "stream": stream}
+    if trace_id:
+        body["trace_id"] = trace_id
+    out = server(body)
+    if stream:
+        return [ev["token"] for ev in out]
+    return out["tokens"]
+
+
+def ask_together(server, jobs):
+    """Each of ``jobs`` (prompt, stream, trace_id) from a thread of its
+    own, started in order; the tokens each got."""
+    got = [None] * len(jobs)
+
+    def one(i, job):
+        got[i] = ask(server, *job)
+
+    threads = [threading.Thread(target=one, args=(i, j)) for i, j in enumerate(jobs)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=120)
+    assert all(g is not None for g in got)
+    return got
+
+
+def tail(seed):
+    return [(seed * 7 + i) % 90 for i in range(8)]
+
+
+@pytest.fixture(scope="module")
+def mix(srv):
+    """The traffic every identity below is read from: one request alone
+    (it seals the shared pages), three that share its prefix at once,
+    streamed and unary (the third is refused for pages while two hold
+    the pool), then one unshared alone. Counter snapshots around each
+    phase, and what the ring was given."""
+    events = []
+    real_emit, tracing.emit = tracing.emit, events.append
+    try:
+        snaps = [totals()]
+        wall = [time.monotonic()]
+        tokens = [ask(srv, SHARED + tail(1), trace_id="alone")]
+        snaps.append(totals())
+        tokens += ask_together(srv, [
+            (SHARED + tail(2), True, "c1"), (SHARED + tail(3), False, "c2"),
+            (SHARED + tail(4), True, "c3"),
+        ])
+        snaps.append(totals())
+        tokens.append(ask(srv, [200 + i % 50 for i in range(40)], stream=True, trace_id="u1"))
+        deadline = time.monotonic() + 10
+        while len([e for e in events if e["component"] == "engine"]) < 5:
+            assert time.monotonic() < deadline, events
+            time.sleep(0.01)
+        time.sleep(0.05)  # the last round's own stamps
+        snaps.append(totals())
+        wall.append(time.monotonic())
+    finally:
+        tracing.emit = real_emit
+    return {"snaps": snaps, "tokens": tokens, "events": events, "wall": wall[1] - wall[0]}
+
+
+def test_decode_steps_count_what_decode_generated(mix):
+    first, last = mix["snaps"][0], mix["snaps"][-1]
+    assert all(len(t) == MAX_NEW for t in mix["tokens"])
+    generated = delta(first, last, "serve_tokens_generated")
+    firsts = delta(first, last, "serve_ttft_s")[1]
+    assert generated == 5 * MAX_NEW and firsts == 5
+    assert delta(first, last, "serve_decode_row_steps") == generated - firsts
+    # alone, one row: every token-step is one row-step
+    alone = (mix["snaps"][0], mix["snaps"][1])
+    assert delta(*alone, "serve_decode_steps") == MAX_NEW - 1
+    assert delta(*alone, "serve_decode_row_steps") == MAX_NEW - 1
+    # together, rows share steps: fewer steps than row-steps, and the
+    # steps per dispatch (what decode_k_mean reads) are between 1 and 8
+    shared = (mix["snaps"][1], mix["snaps"][2])
+    steps = delta(*shared, "serve_decode_steps")
+    assert MAX_NEW - 1 <= steps < delta(*shared, "serve_decode_row_steps") == 3 * (MAX_NEW - 1)
+    dispatches = delta(*shared, "serve_batch_fill")[1]
+    assert 1.0 <= steps / dispatches <= 8.0
+
+
+def test_prompt_and_reused_tokens_count_admissions_not_attempts(mix):
+    first, last = mix["snaps"][0], mix["snaps"][-1]
+    assert delta(first, last, "serve_prompt_tokens") == 5 * 40
+    # three admitted requests found the two shared pages resident
+    assert delta(first, last, "serve_prefix_tokens_reused") == 3 * len(SHARED)
+    # the old counter counts pages per attempt: the refused request
+    # matched its two pages again in every round it was refused
+    assert delta(first, last, "serve_prefix_cache_hits") * B > 3 * len(SHARED)
+
+
+def test_prefill_tokens_and_padded_width(mix):
+    first, last = mix["snaps"][0], mix["snaps"][-1]
+    useful = delta(first, last, "serve_prefill_tokens")
+    padded, calls = delta(first, last, "serve_prefill_width")
+    # 40 and 40 whole prompts, three tails of 8 behind the shared pages;
+    # padded to 64, 64 and three times 16
+    assert useful == 40 + 40 + 3 * 8 and calls == 5
+    assert padded == 64 + 64 + 3 * 16 and useful <= padded
+
+
+def test_waits_are_observed_once_per_admission(mix):
+    snaps = mix["snaps"]
+    queue_s, admitted = delta(snaps[0], snaps[-1], "serve_engine_queue_wait_s")
+    assert admitted == 5 and queue_s > 0
+    assert delta(snaps[0], snaps[-1], "serve_engine_page_wait_s")[1] == 5
+    assert delta(snaps[0], snaps[-1], "serve_engine_first_token_s")[1] == 5
+    # a request alone in a roomy pool is never refused ...
+    assert delta(snaps[0], snaps[1], "serve_engine_page_wait_s") == (0.0, 1)
+    assert delta(snaps[2], snaps[3], "serve_engine_page_wait_s") == (0.0, 1)
+    # ... the third of three is, and waits about as long as one of them lasts
+    page_s, n = delta(snaps[1], snaps[2], "serve_engine_page_wait_s")
+    assert n == 3 and page_s > 0
+    assert page_s <= delta(snaps[1], snaps[2], "serve_engine_queue_wait_s")[0]
+
+
+def test_round_time_splits_into_host_and_blocked(mix):
+    first, last = mix["snaps"][0], mix["snaps"][-1]
+    host_s, rounds = delta(first, last, "serve_engine_round_host_s")
+    blocked_s, blocked_rounds = delta(first, last, "serve_engine_round_blocked_s")
+    assert rounds == blocked_rounds > 0
+    assert host_s > 0 and blocked_s > 0
+    # idle waits belong to neither: together they fit in the wall time
+    assert host_s + blocked_s <= mix["wall"]
+
+
+def test_phases_tile_the_engine_span(mix):
+    by_trace = {}
+    for e in mix["events"]:
+        assert e["type"] == "request"
+        by_trace.setdefault(e["trace_id"], {})[e["component"]] = e
+    assert set(by_trace) == {"alone", "c1", "c2", "c3", "u1"}
+    refused = 0
+    for tid, spans in by_trace.items():
+        assert set(spans) == {"engine", "engine.queue", "engine.prefill", "engine.decode"}, tid
+        eng = spans["engine"]
+        assert eng["parent"] == "replica"
+        phases = [spans["engine.queue"], spans["engine.prefill"], spans["engine.decode"]]
+        assert all(p["parent"] == "engine" and p["trace_id"] == tid for p in phases)
+        edges = [eng["ts_us"]] + [p["ts_us"] + p["dur_us"] for p in phases]
+        assert [p["ts_us"] for p in phases] == edges[:3]  # each starts where the last ended
+        assert abs(edges[-1] - (eng["ts_us"] + eng["dur_us"])) <= 1000
+        assert all(p["dur_us"] >= 0 for p in phases)
+        assert spans["engine.prefill"]["prompt_tokens"] == 40
+        assert spans["engine.prefill"]["cached_tokens"] == (len(SHARED) if tid[0] == "c" else 0)
+        assert spans["engine.decode"]["tokens"] == MAX_NEW
+        page_wait = spans["engine.queue"]["page_wait_us"]
+        assert 0 <= page_wait <= spans["engine.queue"]["dur_us"]
+        refused += page_wait > 0
+    assert refused == 1
+
+
+def test_spans_land_in_the_profilers_trace_on_one_clock(srv, tmp_path):
+    import jax
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        ask(srv, [9, 8, 7, 6], max_new=20)
+        time.sleep(0.7)  # the engine parks in its idle wait
+    finally:
+        jax.profiler.stop_trace()
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)[-1]
+    events = [
+        ev for plane in ProfileData.from_file(path).planes for line in plane.lines
+        for ev in line.events if ev.name.startswith("rt/engine/")
+    ]
+    assert {ev.name for ev in events} == {f"rt/engine/{n}" for n in SPANS}
+    rounds = [ev for ev in events if ev.name == "rt/engine/round"]
+    assert len(rounds) >= 3
+    # ring clock (us) against trace clock (ns): one offset for every round
+    offsets = [dict(ev.stats)["ts_us"] * 1000 - ev.start_ns for ev in rounds]
+    assert max(offsets) - min(offsets) < 1e6, offsets
+    stats = dict(next(ev for ev in events if ev.name == "rt/engine/dispatch").stats)
+    assert 1 <= stats["k"] <= 8 and stats["rows"] == 1
+
+
+def test_switched_off_nothing_is_stamped(srv, ring, monkeypatch):
+    import jax
+
+    built = []
+
+    class Counting:
+        def __init__(self, *a, **kw):
+            built.append(a)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Counting)
+    tracing.set_enabled(False)
+    core_metrics.set_enabled(False)
+    try:
+        before = totals()
+        out = ask(srv, SHARED + tail(9), trace_id="off", max_new=12)
+        time.sleep(0.05)
+        assert len(out) == 12
+        assert totals() == before
+        assert ring == [] and built == []
+    finally:
+        tracing.set_enabled(True)
+        core_metrics.set_enabled(True)
+    # and on again, both are stamped again
+    ask(srv, SHARED + tail(10), trace_id="on", max_new=12)
+    deadline = time.monotonic() + 5
+    while not ring and time.monotonic() < deadline:
+        time.sleep(0.01)
+    assert {e["component"] for e in ring} >= {"engine", "engine.queue"} and built
+
+
+def test_span_without_jax_imports_no_jax():
+    code = (
+        "import sys\n"
+        "from ray_tpu.observability import tracing\n"
+        "assert tracing.ENABLED\n"
+        "with tracing.span('rt/engine/round', k=1, ts_us=tracing.now_us()) as s:\n"
+        "    pass\n"
+        "assert 'jax' not in sys.modules, 'jax was imported'\n"
+        "print('ok')\n"
+    )
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    proc = subprocess.run([sys.executable, "-c", code], capture_output=True, text=True,
+                          timeout=120, cwd=root)
+    assert proc.returncode == 0 and proc.stdout.strip() == "ok", proc.stderr[-2000:]

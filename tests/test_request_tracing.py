@@ -195,6 +195,64 @@ def test_request_summary_rolls_up_percentiles(front):
     assert entry["e2e_s"]["max"] >= entry["exec_s"]["p50"]
 
 
+def test_engine_phases_nest_under_the_engine_span(front):
+    """The engine's leg carries its phases as children: queue, prefill
+    and decode slices with parent "engine" under one trace id, inside
+    the engine slice, every leg naming the leg that encloses it; the
+    flow still has one step per span; and request_summary() rolls the
+    phases up under the engine's deployment name."""
+    addr = front
+    tid = "feedfacecafe0002"
+    st, events = _stream_chat(addr, {
+        "model": MODEL, "max_tokens": 8, "temperature": 0, "user": "erin",
+        "stream": True,
+        "messages": [{"role": "user", "content": "phase me"}],
+    }, headers={tracing.TRACE_HEADER: tid})
+    assert st == 200 and events[-1] == "[DONE]"
+    want = {"proxy", "router", "replica", "engine", "engine.queue",
+            "engine.prefill", "engine.decode"}
+    deadline = time.monotonic() + 30
+    by_comp = {}
+    while time.monotonic() < deadline and not want <= set(by_comp):
+        trace = state.timeline()
+        by_comp = {
+            ev["name"].split(":")[0]: ev for ev in _request_slices(trace, tid)
+        }
+        time.sleep(0.3)
+    assert want <= set(by_comp), sorted(by_comp)
+    parents = {c: ev["args"].get("parent") for c, ev in by_comp.items()}
+    assert parents == {
+        "proxy": None, "router": "proxy", "replica": "router",
+        "engine": "replica", "engine.queue": "engine",
+        "engine.prefill": "engine", "engine.decode": "engine",
+    }
+    eng = by_comp["engine"]
+    phases = [by_comp[c] for c in
+              ("engine.queue", "engine.prefill", "engine.decode")]
+    assert phases[0]["ts"] == eng["ts"]
+    for a, b in zip(phases, phases[1:]):
+        assert abs(a["ts"] + a["dur"] - b["ts"]) <= 1000
+    assert abs(phases[-1]["ts"] + phases[-1]["dur"]
+               - eng["ts"] - eng["dur"]) <= 1000
+    assert by_comp["engine.prefill"]["args"]["prompt_tokens"] > 0
+    assert by_comp["engine.decode"]["args"]["tokens"] == 8
+    # the flow follows parentage: one step per span, the engine's
+    # phases after every leg that encloses them
+    flow = sorted(
+        (ev for ev in trace
+         if ev.get("cat") == "request_flow" and ev.get("id") == tid),
+        key=lambda e: "stf".index(e["ph"]),
+    )
+    assert len(flow) == len(by_comp)
+    assert flow[0]["ph"] == "s" and flow[0]["ts"] == by_comp["proxy"]["ts"]
+    assert flow[-1]["ph"] == "f" and flow[-1]["ts"] == phases[-1]["ts"]
+    entry = state.request_summary()["deployments"]["gpt2-tiny"]
+    for split in ("engine_queue_s", "page_wait_s", "admit_to_first_s"):
+        assert entry[split]["p50"] >= 0.0 and entry[split]["max"] < 60.0
+    # a roomy pool refuses nobody
+    assert entry["page_wait_s"]["max"] == 0.0
+
+
 def test_trace_minted_when_client_sends_none(front):
     """Without an x-rt-trace-id header the proxy mints one, and the
     downstream legs still join on it."""
