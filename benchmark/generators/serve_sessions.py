@@ -266,7 +266,9 @@ def run(ctx) -> Dict[str, Any]:
             if rec["error"]:
                 raise RuntimeError(f"warm request failed: {rec['error']}")
         # the row-update program, one compile per number of changed rows
-        say(f"row updates warmed for {on_replica('bench_warm_rows', timeout=600)}")
+        warmed = on_replica("bench_warm_rows",
+                            {"family": ctx.family, "model": cfg["model"]}, timeout=600)
+        say(f"row updates warmed for {warmed}")
         phases.mark("engine_load_and_programs")
         stats = on_replica("engine_stats")
         obs["engine_load_s"] = stats.get("load_s")
@@ -343,7 +345,8 @@ def run(ctx) -> Dict[str, Any]:
         # window, so that the window meets the replica as the traffic left it
         t_check = time.monotonic()
         obs["reference"] = on_replica(
-            "bench_check", {"model": cfg["model"], "seed": ctx.seed, **cfg["check"]},
+            "bench_check",
+            {"family": ctx.family, "model": cfg["model"], "seed": ctx.seed, **cfg["check"]},
             timeout=900,
         )
         say(f"reference check took {time.monotonic() - t_check:.2f}s")
@@ -434,7 +437,8 @@ def _check(obs: Dict[str, Any], before: Dict[str, Any], after: Dict[str, Any]) -
     ref, tol = obs["reference"], float(obs["check"]["logit_tolerance"])
     worst = max(ref["prefill_max_abs"], ref["decode_max_abs"])
     obs["notes"].append(
-        f"against the plain float32 reference, {ref['rows']} rows of {ref['prompt_lens']} "
+        f"against the plain float32 reference (family {ref.get('family')}), "
+        f"{ref['rows']} rows of {ref['prompt_lens']} "
         f"prompt tokens and {ref['decode_steps']} decode steps: max |logit difference| "
         f"{ref['prefill_max_abs']:.4g} (prefill) {ref['decode_max_abs']:.4g} (decode), "
         f"reference logits' spread {ref['reference_logit_std']:.3g}, tolerance {tol:g}"

@@ -8,6 +8,10 @@ workers the node agent leases chips to.
 
 from __future__ import annotations
 
+import functools
+import importlib
+import importlib.util
+import json
 import os
 import shutil
 import sys
@@ -20,6 +24,50 @@ TRACE_DIR = os.path.join(ROOT, ".bench_trace")
 
 def say(msg: str) -> None:
     print(f"[bench] {msg}", flush=True)
+
+
+def load_json(path: str) -> Any:
+    with open(path) as f:
+        return json.load(f)
+
+
+def find(bench: Dict[str, Any], kind: str, name: str, ext: str = ".json") -> str:
+    """``<path>/<kind>/<name><ext>`` under the first of ``paths`` that has it."""
+    tried = []
+    for p in bench["paths"]:
+        path = os.path.join(ROOT, p, kind, name + ext)
+        if os.path.exists(path):
+            return path
+        tried.append(path)
+    raise FileNotFoundError(f"no {kind} file for {name!r}: tried {tried}")
+
+
+def module(bench: Dict[str, Any], kind: str, name: str):
+    """The generator or reader ``name``, from whichever of ``paths`` holds it."""
+    path = find(bench, kind, name, ".py")
+    rel = os.path.relpath(path, ROOT)[: -len(".py")]
+    return importlib.import_module(rel.replace(os.sep, "."))
+
+
+@functools.lru_cache(maxsize=None)
+def _module_at(path: str):
+    name = "bench_family_" + os.path.basename(path)[: -len(".py")]
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def family(path: Optional[str]):
+    """The family adapter in the file at ``path``, which is what
+    ``find(bench, "families", <the configuration's family>, ".py")`` gave
+    the process that resolved the cell; None where the configuration names
+    no family. Loaded from the file and not by a dotted name, because the
+    replica's process is handed the path and imports ``benchmark`` from its
+    own PYTHONPATH, which in a copied tree is another tree than the one the
+    cell was resolved in. What a family file provides is listed in
+    ``benchmark/families/gpt2.py``."""
+    return _module_at(path) if path else None
 
 
 def prepare_env() -> None:

@@ -38,17 +38,6 @@ def gpt2_matmul_params(model: Dict[str, int]) -> int:
     return l * (4 * d * d + 2 * 4 * d * d) + v * d
 
 
-def gpt2_params(model: Dict[str, int]) -> int:
-    """All parameters at the published sizes (1,557.6 M for gpt2-xl,
-    124.4 M for gpt2)."""
-    d, l = model["n_embd"], model["n_layer"]
-    per_layer = 12 * d * d + 13 * d  # kernels, biases, two layer norms
-    return (
-        model["vocab_size"] * d + model["n_positions"] * d
-        + l * per_layer + 2 * d
-    )
-
-
 def train_flops_per_token(model: Dict[str, int]) -> float:
     """6 N: forward and backward through the matrix multiplications.
     Attention's own products and anything recomputed are left out, so
@@ -65,22 +54,12 @@ def mfu(tokens_per_s: float, model: Dict[str, int], chips: int,
     )
 
 
-def decode_step_bytes(model: Dict[str, int], rows: float,
-                      mean_context: float) -> float:
-    """Bytes one decode step has to read: every weight once in bf16, and
-    the live K and V of the active rows (bf16, every layer)."""
-    weights = 2.0 * gpt2_params(model)
-    kv = rows * mean_context * 2 * model["n_layer"] * model["n_embd"] * 2.0
-    return weights + kv
-
-
-def decode_roofline(step_s: float, model: Dict[str, int], rows: float,
-                    mean_context: float, device_kind: str) -> float:
+def decode_roofline(step_s: float, step_bytes: float, device_kind: str) -> float:
     """Least time a decode step could take, which memory bandwidth sets
-    (one token a row: ~2 FLOPs a weight byte), over the time it took."""
-    least = decode_step_bytes(model, rows, mean_context) / peak(device_kind)[
-        "hbm_bytes_per_s"
-    ]
+    (one token a row: ~2 FLOPs a weight byte), over the time it took.
+    ``step_bytes`` is what the step has to read, by the family's own
+    ``decode_step_bytes`` (``benchmark/families/<family>.py``)."""
+    least = step_bytes / peak(device_kind)["hbm_bytes_per_s"]
     return 100.0 * least / step_s
 
 

@@ -1,15 +1,18 @@
 """The decode step's share of its roofline, in percent: the bytes a step
-has to read (weights once in bf16, live K/V of the active rows) over the
-chip's memory bandwidth, divided by the device time of a token-step."""
+has to read (by the family's ``decode_step_bytes``: weights once, live K/V
+of the active rows) over the chip's memory bandwidth, divided by the
+device time of a token-step. A family that counts no bytes reads nothing."""
 
-from benchmark import peaks
+from benchmark import harness, peaks
 from benchmark.readers import counter_ratio, decode_step
 
 
 def read(obs, args, ctx):
+    step_bytes = getattr(harness.family(getattr(ctx, "family", None)),
+                         "decode_step_bytes", None)
     step_ms = decode_step.read(obs, args, ctx)
     tc = obs.get("trace_counters")
-    if not step_ms or not tc:
+    if step_bytes is None or not step_ms or not tc:
         return None
     fill_n = counter_ratio.delta(tc, [["rt_serve_batch_fill", "count"]])
     rows = counter_ratio.delta(tc, [["rt_serve_batch_fill", "sum"]]) / fill_n
@@ -21,5 +24,5 @@ def read(obs, args, ctx):
         for r in done
     ) / len(done)
     return peaks.decode_roofline(
-        step_ms / 1000.0, obs["model"], rows, context, obs["device"]["kind"]
+        step_ms / 1000.0, step_bytes(obs["model"], rows, context), obs["device"]["kind"]
     )

@@ -1,10 +1,9 @@
 """The program against the plain reference, logits compared.
 
-Serving: a seeded prompt is prefilled through ``prefill_paged`` into the
-paged cache and the seeded continuation is decoded through it one token a
-step (``_decode_paged_impl``, the body of both decode programs), two rows
-of different lengths side by side; every step's logits are held against
-the reference's full forward pass over the same sequence. Training: one
+Serving: the family's ``compare_serve`` (``benchmark/families/<family>.py``):
+a seeded prompt prefilled into the paged cache and the seeded continuation
+decoded through it, every step's logits held against the reference's full
+forward pass over the same sequence. Training (GPT-2's by name): one
 optimizer step on seeded sequences from the initial parameters, program
 against reference: the loss before it, the gradient the optimizer was
 given, and the loss after it. Logits, losses and gradients, not sampled
@@ -12,7 +11,7 @@ tokens: with random weights the largest logit changes on rounding.
 
 ``python3 benchmark/run.py --check <config>`` runs it at the
 configuration's published sizes in this process, on whatever device JAX
-finds, outside any run; the tests run it at gpt2-tiny on the CPU.
+finds, outside any run; the tests run it at the tiny preset on the CPU.
 """
 
 from __future__ import annotations
@@ -20,73 +19,10 @@ from __future__ import annotations
 import dataclasses
 import json
 import os
-from typing import Any, Dict, List, Sequence
+from typing import Any, Dict, List
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 ADAM_B1 = 0.9  # compare_step reads the gradient back from Adam's first moment
-
-
-def compare_serve(mcfg, model: Dict[str, int], params, seed: int,
-                  prompt_lens: Sequence[int], steps: int,
-                  page_tokens: int = 64) -> Dict[str, Any]:
-    """Max |program - reference| over the logits of the prefill's last
-    position and of every decode step, with the reference logits' own
-    spread for scale."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from benchmark.reference import gpt2_ref
-    from ray_tpu.models import gpt2_decode as dec
-
-    rows = len(prompt_lens)
-    max_pages = -(-mcfg.n_positions // page_tokens)
-    need = [-(-(p + steps) // page_tokens) for p in prompt_lens]
-    cache_k, cache_v = dec.init_paged_cache(mcfg, 1 + sum(need), page_tokens)
-    tables = np.zeros((rows, max_pages), np.int32)
-    nxt = 1  # page 0 is the scratch page
-    for r, n in enumerate(need):
-        tables[r, :n] = np.arange(nxt, nxt + n)
-        nxt += n
-    rng = np.random.default_rng([seed, 23])
-    seqs = [rng.integers(0, mcfg.vocab_size, p + steps, dtype=np.int32)
-            for p in prompt_lens]
-    reference = jax.jit(lambda p, t: gpt2_ref.forward(p, t, model))
-    want = [np.asarray(reference(params, jnp.asarray(s)[None])[0]) for s in seqs]
-
-    def bucket(n: int) -> int:
-        p = 16
-        while p < n:
-            p *= 2
-        return p
-
-    prefill_err: List[float] = []
-    for r, p in enumerate(prompt_lens):
-        tok = np.zeros((1, bucket(p)), np.int32)
-        tok[0, :p] = seqs[r][:p]
-        logits, cache_k, cache_v = dec.prefill_paged(
-            mcfg, params, jnp.asarray(tok), jnp.int32(0), jnp.int32(p),
-            cache_k, cache_v, jnp.asarray(tables[r]),
-        )
-        prefill_err.append(float(np.abs(np.asarray(logits) - want[r][p - 1]).max()))
-    step = jax.jit(dec._decode_paged_impl, static_argnums=(0,))
-    decode_err: List[float] = []
-    for i in range(steps):
-        last = jnp.asarray([seqs[r][p + i] for r, p in enumerate(prompt_lens)])
-        lens = jnp.asarray([p + i for p in prompt_lens], jnp.int32)
-        logits, cache_k, cache_v = step(
-            mcfg, params, last, lens, cache_k, cache_v, jnp.asarray(tables)
-        )
-        got = np.asarray(logits)
-        decode_err.append(max(
-            float(np.abs(got[r] - want[r][p + i]).max())
-            for r, p in enumerate(prompt_lens)
-        ))
-    return {
-        "prefill_max_abs": max(prefill_err), "decode_max_abs": max(decode_err),
-        "reference_logit_std": float(np.std(want[0])),
-        "rows": rows, "prompt_lens": list(prompt_lens), "decode_steps": steps,
-    }
 
 
 def reference_step(params, sequences, model: Dict[str, int], opt):
@@ -175,11 +111,10 @@ def step_problems(got: Dict[str, float], tolerance: Dict[str, float]) -> List[st
 def main(bench: Dict[str, Any], config_name: str, seed: int) -> int:
     import jax
 
-    from ray_tpu.models import gpt2
+    from benchmark import harness
 
     entry = next(c for c in bench["configs"] if c["name"] == config_name)
-    with open(os.path.join(ROOT, entry["file"])) as f:
-        cfg = json.load(f)
+    cfg = harness.load_json(os.path.join(ROOT, entry["file"]))
     device = jax.devices()[0]
     out: Dict[str, Any] = {"config": config_name, "device": {
         "platform": device.platform, "kind": device.device_kind,
@@ -187,6 +122,8 @@ def main(bench: Dict[str, Any], config_name: str, seed: int) -> int:
     if "train" in cfg:
         import numpy as np
         import optax
+
+        from ray_tpu.models import gpt2
 
         tc = cfg["train"]
         mcfg = dataclasses.replace(
@@ -210,10 +147,10 @@ def main(bench: Dict[str, Any], config_name: str, seed: int) -> int:
         out["problems"] = step_problems(got, cfg["reference_tolerance"])
         out["ok"] = not out["problems"]
     else:
-        mcfg = gpt2.CONFIGS[cfg["model_id"]]
-        params = gpt2.init(jax.random.PRNGKey(0), mcfg)  # the engine's own weights
+        fam = harness.family(harness.find(bench, "families", cfg["family"], ".py"))
+        mcfg, params = fam.serve_params(cfg["model_id"])  # the engine's own weights
         chk = cfg["check"]
-        out.update(compare_serve(
+        out.update(fam.compare_serve(
             mcfg, cfg["model"], params, seed,
             prompt_lens=chk["prompt_lens"], steps=int(chk["decode_steps"]),
         ))

@@ -3,15 +3,19 @@
 Only that process can trace the chip or read its memory, and the program
 has no entry for either. So the front door is deployed through
 ``serve.llm.deploy`` as ever, with this subclass standing in for
-``OpenAIServer``: it adds four methods and changes none.
+``OpenAIServer``: it adds four methods and changes none. What depends on the
+model's family comes from the family file the payload names
+(``harness.family``).
 """
 
 from __future__ import annotations
 
 import contextlib
+import os
 import time
 from typing import Any, Dict
 
+from benchmark import harness
 from ray_tpu.serve.openai import ingress
 
 
@@ -42,9 +46,10 @@ def trace_for(trace_dir: str, seconds: float) -> str:
     return trace_dir
 
 
-def engine_shapes(engine) -> Dict[str, int]:
+def engine_shapes(engine, context: int) -> Dict[str, int]:
     """Decode rows and page-table width of a loaded paged engine, as
-    ``_engine_loop_paged`` works them out from its pool."""
+    ``_engine_loop_paged`` works them out from its pool and from the
+    positions a sequence may hold (the family's ``context(model)``)."""
     from ray_tpu.utils.config import config as rtcfg
 
     pool = engine._prefix_pool
@@ -53,34 +58,9 @@ def engine_shapes(engine) -> Dict[str, int]:
     )
     return {
         "rows": max(1, min(rows, pool.num_pages - 1)),
-        "max_pages": -(-engine.model_cfg.n_positions // pool.page_tokens),
+        "max_pages": -(-context // pool.page_tokens),
         "page_tokens": pool.page_tokens,
     }
-
-
-def warm_row_updates(rows: int, max_pages: int) -> None:
-    """Compile (or load from the cache) the engine's row-update program
-    for every number of changed rows, 1 to ``rows``: it is jitted on the
-    number of rows admitted or retired since the last dispatch, so a count
-    first met inside a window would compile there. Same shapes and types
-    as ``_engine_loop_paged`` passes; should they drift from the engine's,
-    the programs compile inside the window after all, the compile cache
-    grows there, and the run is not ``correct``."""
-    import jax.numpy as jnp
-    import numpy as np
-
-    from ray_tpu.models import gpt2_decode as dec
-
-    host = (np.zeros((rows,), np.int32), np.zeros((rows,), np.int32),
-            np.zeros((rows,), np.float32), np.ones((rows,), bool),
-            np.zeros((rows, max_pages), np.int32))
-    for n in range(1, rows + 1):
-        idx = np.arange(n, dtype=np.int32)
-        out = dec.update_rows_paged(
-            *(jnp.asarray(a) for a in host), jnp.asarray(idx),
-            *(jnp.asarray(a[idx]) for a in host),
-        )
-    out[0].block_until_ready()
 
 
 class BenchOpenAIServer(ingress.OpenAIServer):
@@ -94,23 +74,26 @@ class BenchOpenAIServer(ingress.OpenAIServer):
                 return engine
         raise RuntimeError("no engine is loaded on this replica yet")
 
-    def bench_warm_rows(self, _payload: Any = None) -> Dict[str, int]:
-        shapes = engine_shapes(self._bench_engine())
-        warm_row_updates(shapes["rows"], shapes["max_pages"])
+    def bench_warm_rows(self, payload: Dict[str, Any]) -> Dict[str, int]:
+        """``payload``: the path of the configuration's family file and
+        its ``model``."""
+        fam = harness.family(payload["family"])
+        shapes = engine_shapes(self._bench_engine(), fam.context(payload["model"]))
+        fam.warm_row_updates(shapes["rows"], shapes["max_pages"])
         return shapes
 
     def bench_check(self, payload: Dict[str, Any]) -> Dict[str, Any]:
         """The engine's prefill and decode programs, on the engine's own
         parameters and a paged cache of its own making, against the plain
-        reference: see ``reference.check.compare_serve``."""
-        from benchmark.reference import check
-
+        reference: the family's ``compare_serve``. The answer names the
+        family file it went through."""
         engine = self._bench_engine()
-        return check.compare_serve(
+        out = harness.family(payload["family"]).compare_serve(
             engine.model_cfg, payload["model"], engine.params, int(payload["seed"]),
             prompt_lens=payload["prompt_lens"], steps=int(payload["decode_steps"]),
-            page_tokens=engine_shapes(engine)["page_tokens"],
+            page_tokens=engine._prefix_pool.page_tokens,
         )
+        return {**out, "family": os.path.basename(payload["family"])[: -len(".py")]}
 
     def bench_trace(self, payload: Dict[str, Any]) -> str:
         return trace_for(payload["dir"], float(payload["seconds"]))

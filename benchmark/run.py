@@ -5,7 +5,8 @@
 A new process each time. Everything that belongs to one configuration,
 one traffic mix or one metric is a file found by its name:
 
-    configs/<config>.json      the entry's ``file``: sizes, deployment
+    configs/<config>.json      the entry's ``file``: sizes, deployment, family
+    families/<family>.py       what the harness asks of a model (families/gpt2.py)
     traffic/<traffic>.json     generator name and parameters
     metrics/<metric>.json      unit, reader name and arguments
     generators/<name>.py       ``run(ctx) -> observations``
@@ -31,7 +32,6 @@ import time
 T_PROCESS_START = time.time()
 
 import argparse  # noqa: E402
-import importlib  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import sys  # noqa: E402
@@ -42,31 +42,11 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
+from benchmark import harness  # noqa: E402
+from benchmark.harness import find, load_json, module  # noqa: E402
+
 # sources a CPU run may not report under: they describe the device
 DEVICE_SOURCES = ("device_trace",)
-
-
-def load_json(path: str) -> Any:
-    with open(path) as f:
-        return json.load(f)
-
-
-def find(bench: Dict[str, Any], kind: str, name: str, ext: str = ".json") -> str:
-    """``<path>/<kind>/<name><ext>`` under the first of ``paths`` that has it."""
-    tried = []
-    for p in bench["paths"]:
-        path = os.path.join(ROOT, p, kind, name + ext)
-        if os.path.exists(path):
-            return path
-        tried.append(path)
-    raise FileNotFoundError(f"no {kind} file for {name!r}: tried {tried}")
-
-
-def module(bench: Dict[str, Any], kind: str, name: str):
-    """The generator or reader ``name``, from whichever of ``paths`` holds it."""
-    path = find(bench, kind, name, ".py")
-    rel = os.path.relpath(path, ROOT)[: -len(".py")]
-    return importlib.import_module(rel.replace(os.sep, "."))
 
 
 def resolve(bench: Dict[str, Any], workload: str, trace: bool) -> Dict[str, Any]:
@@ -86,7 +66,9 @@ def resolve(bench: Dict[str, Any], workload: str, trace: bool) -> Dict[str, Any]
             sys.exit(f"benchmark: metric {m['name']} is {m['unit']!r} in "
                      f"BENCHMARK.json and {spec['unit']!r} in its file")
         metrics.append({**m, "reader": spec["reader"], "args": spec.get("args", {})})
-    return {"cell": cell, "config": config, "traffic": traffic, "metrics": metrics}
+    fam = config.get("family")
+    return {"cell": cell, "config": config, "traffic": traffic, "metrics": metrics,
+            "family": find(bench, "families", fam, ".py") if fam else None}
 
 
 def read_metrics(bench, plan, obs, ctx) -> Dict[str, Dict[str, Any]]:
@@ -151,11 +133,10 @@ def main(argv: Optional[List[str]] = None) -> int:
         print(json.dumps({
             "workload": plan["cell"], "config": plan["config"],
             "traffic": plan["traffic"], "generator": generator.__name__,
+            "family": plan["family"] and os.path.relpath(plan["family"], ROOT),
             "metrics": {m["name"]: readers[m["reader"]].__name__ for m in plan["metrics"]},
         }))
         return 0
-
-    from benchmark import harness
 
     harness.prepare_env()  # before the program is imported: it reads its settings then
     platform = plan["config"].get("platform", "tpu")
@@ -165,6 +146,7 @@ def main(argv: Optional[List[str]] = None) -> int:
         cell=plan["cell"], config=plan["config"], traffic=plan["traffic"],
         seed=int(args.seed), seconds=seconds, trace=bool(args.trace),
         platform=platform, phases=harness.Phases(T_PROCESS_START), root=ROOT,
+        family=plan["family"],
     )
     harness.say(f"cell {args.workload}: seed {ctx.seed}, {seconds:g}s window, "
                 f"trace {args.trace}, compile cache {harness.compile_cache_dir()} "

@@ -1,8 +1,9 @@
 """The program's own spans in one trace: how often and how long each
 ``rt/`` span ran in the traced window, and the device's idle seconds by
-the innermost such span that overlaps each gap most (``breakdown`` names
-gaps by the innermost host event of any kind, which is often a Python
-frame with a line number).
+the innermost such span that overlaps each gap most, which is how
+``breakdown`` names a gap too (``trace.attribute_gaps``, since PR 27); a
+gap that no such span overlaps is "unattributed" here and goes to the
+innermost host event of any kind there.
 
     python3 benchmark/tools/span_gaps.py <trace-dir> [<prefix>]
 """
@@ -35,7 +36,7 @@ def reduce_spans(tr, prefix: str = "rt/"):
     for p in planes:
         idle = trace_mod.subtract([win], trace_mod.busy_intervals(p, win))
         idle = [g for g in idle if g[1] - g[0] >= 20_000]
-        for name, s in trace_mod.attribute_gaps(idle, spans).items():
+        for name, s in trace_mod.attribute_gaps(idle, spans, prefer=prefix).items():
             gaps[name] = gaps.get(name, 0.0) + s / len(planes)
     return {
         "window_s": (win[1] - win[0]) * 1e-9,

@@ -222,10 +222,18 @@ def op_totals(trace: Dict[str, Any], line: str,
     return seconds, calls
 
 
-def attribute_gaps(gaps: List[Interval], host: List[List[Any]]) -> Dict[str, float]:
+PROGRAM_SPANS = "rt/"  # the program's own spans (``tracing.span``)
+
+
+def attribute_gaps(gaps: List[Interval], host: List[List[Any]],
+                   prefer: str = PROGRAM_SPANS) -> Dict[str, float]:
     """Seconds of device idleness by what the host was doing: each gap
     goes to the host event that overlaps it most (the shorter event
-    where two overlap it equally, so the innermost span wins)."""
+    where two overlap it equally, so the innermost span wins). Where one
+    of the program's own spans (a name that begins with ``prefer``)
+    overlaps the gap, the gap goes to the innermost of those and not to a
+    Python frame inside it: a frame's name carries a line number
+    (``$llm.py:1455 run_round``) and changes with any edit above it."""
     named = sorted(
         (ev for ev in host
          if not ev[0].startswith(_WRAPPERS) and not _WAITING.search(ev[0])),
@@ -235,6 +243,7 @@ def attribute_gaps(gaps: List[Interval], host: List[List[Any]]) -> Dict[str, flo
     out: Dict[str, float] = {}
     for a, b in gaps:
         best, best_key = "unattributed", (0.0, 0.0)
+        span, span_key = None, (0.0, 0.0)  # the best of the preferred
         hi = bisect.bisect_left(starts, b)
         # events that began before the gap ended, nearest first; the
         # scan is bounded, so a span that began very long ago is missed
@@ -242,7 +251,9 @@ def attribute_gaps(gaps: List[Interval], host: List[List[Any]]) -> Dict[str, flo
             ov = min(b, start + dur) - max(a, start)
             if ov > 0 and (ov, -dur) > best_key:
                 best, best_key = name, (ov, -dur)
-        out[best] = out.get(best, 0.0) + (b - a) * 1e-9
+            if ov > 0 and (ov, -dur) > span_key and name.startswith(prefer):
+                span, span_key = name, (ov, -dur)
+        out[span or best] = out.get(span or best, 0.0) + (b - a) * 1e-9
     return out
 
 

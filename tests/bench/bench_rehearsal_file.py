@@ -1,9 +1,13 @@
 """The rehearsal's BENCHMARK.json: the real one with its cells,
-configurations and traffic mixes renamed to the gpt2-tiny ones under
-``tests/bench/`` (``rehearsal.json`` says which) and a 3 s window. Every
-metric, bound and layer is the real file's, so the rehearsal cannot
-drift from the contract it rehearses."""
+configurations and traffic mixes renamed to the tiny ones under
+``tests/bench/`` and a 3 s window. ``rehearsal.json`` says which, and so
+does every ``rehearsal-*.json`` beside it, so that a later PR's cell
+rehearses by a file of its own; a cell that no file names is left out of
+the rehearsal, with the configurations and metrics only it used. Every
+metric, bound and layer is the real file's, so the rehearsal cannot drift
+from the contract it rehearses."""
 
+import glob
 import json
 import os
 
@@ -11,22 +15,40 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(HERE))
 
 
+def load(path):
+    with open(path) as f:
+        return json.load(f)
+
+
+def names():
+    merged = load(os.path.join(HERE, "rehearsal.json"))
+    for path in sorted(glob.glob(os.path.join(HERE, "rehearsal-*.json"))):
+        for kind, renamed in load(path).items():
+            merged[kind].update(renamed)
+    return merged
+
+
 def build():
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    with open(os.path.join(HERE, "rehearsal.json")) as f:
-        names = json.load(f)
-    bench["run_seconds"] = names["run_seconds"]
+    bench = load(os.path.join(ROOT, "BENCHMARK.json"))
+    to = names()
+    bench["run_seconds"] = to["run_seconds"]
+    bench["workloads"] = [w for w in bench["workloads"] if w["name"] in to["workloads"]]
+    used = {w["config"] for w in bench["workloads"]}
+    bench["configs"] = [c for c in bench["configs"] if c["name"] in used]
     for c in bench["configs"]:
-        c["name"] = names["configs"][c["name"]]
+        c["name"] = to["configs"][c["name"]]
         c.update(source="tests", file=f"tests/bench/configs/{c['name']}.json",
                  why="CPU rehearsal")
+        c["reduced"] = load(os.path.join(ROOT, c["file"]))["reduced"]
     for w in bench["workloads"]:
-        w.update(why=f"CPU rehearsal of {w['name']}", name=names["workloads"][w["name"]],
-                 config=names["configs"][w["config"]], traffic=names["traffic"][w["traffic"]])
-    for m in bench["end_to_end"] + bench["per_layer"]:
-        if "workloads" in m:
-            m["workloads"] = [names["workloads"][w] for w in m["workloads"]]
+        w.update(why=f"CPU rehearsal of {w['name']}", name=to["workloads"][w["name"]],
+                 config=to["configs"][w["config"]], traffic=to["traffic"][w["traffic"]])
+    for group in ("end_to_end", "per_layer"):
+        for m in bench[group]:
+            if "workloads" in m:
+                m["workloads"] = [to["workloads"][w] for w in m["workloads"]
+                                  if w in to["workloads"]]
+        bench[group] = [m for m in bench[group] if m.get("workloads", True)]
     return bench
 
 

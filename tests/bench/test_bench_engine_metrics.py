@@ -8,7 +8,7 @@ from types import SimpleNamespace
 
 import pytest
 
-from benchmark import run
+from benchmark import harness
 from benchmark.readers import decode_step_counted
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
@@ -76,9 +76,9 @@ def bench():
 
 def through_its_reader(name, obs):
     b = bench()
-    with open(run.find(b, "metrics", name)) as f:
+    with open(harness.find(b, "metrics", name)) as f:
         spec = json.load(f)
-    return spec, run.module(b, "readers", spec["reader"]).read(obs, spec.get("args", {}), TPU)
+    return spec, harness.module(b, "readers", spec["reader"]).read(obs, spec.get("args", {}), TPU)
 
 
 @pytest.mark.parametrize("name", sorted(EXPECTED))

@@ -3,6 +3,7 @@
 import pytest
 
 from benchmark import peaks
+from benchmark.families import gpt2 as family
 
 XL = {"n_embd": 1600, "n_layer": 48, "n_head": 25, "n_positions": 1024, "vocab_size": 50257}
 SMALL = {"n_embd": 768, "n_layer": 12, "n_head": 12, "n_positions": 1024, "vocab_size": 50257}
@@ -10,8 +11,8 @@ V5E = "TPU v5 lite"
 
 
 def test_parameter_counts_are_the_published_ones():
-    assert peaks.gpt2_params(SMALL) == 124_439_808
-    assert peaks.gpt2_params(XL) == 1_557_611_200
+    assert family.params_count(SMALL) == 124_439_808
+    assert family.params_count(XL) == 1_557_611_200
     # 12 d^2 a layer and the tied head
     assert peaks.gpt2_matmul_params(SMALL) == 12 * 12 * 768 * 768 + 50257 * 768
 
@@ -33,15 +34,12 @@ def test_mfu_by_hand():
 def test_decode_roofline_by_hand():
     # weights 2 x 1,557,611,200 = 3.115 GB; K and V of 24 rows at 150
     # tokens: 24 x 150 x 2 x 48 x 1600 x 2 = 1.106 GB; 4.221 GB / 819 GB/s
-    assert peaks.decode_step_bytes(XL, 24, 150) == pytest.approx(
-        3_115_222_400 + 1_105_920_000
-    )
+    step_bytes = family.decode_step_bytes(XL, 24, 150)
+    assert step_bytes == pytest.approx(3_115_222_400 + 1_105_920_000)
     least = (3_115_222_400 + 1_105_920_000) / 819e9
     assert least == pytest.approx(5.154e-3, rel=1e-3)
-    assert peaks.decode_roofline(0.262, XL, 24, 150, V5E) == pytest.approx(
-        100 * least / 0.262
-    )
-    assert peaks.decode_roofline(0.262, XL, 24, 150, V5E) == pytest.approx(1.967, abs=0.01)
+    assert peaks.decode_roofline(0.262, step_bytes, V5E) == pytest.approx(100 * least / 0.262)
+    assert peaks.decode_roofline(0.262, step_bytes, V5E) == pytest.approx(1.967, abs=0.01)
 
 
 def test_flash_costs_by_hand():

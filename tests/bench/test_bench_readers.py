@@ -1,18 +1,21 @@
 """Each reader on observations made by hand: what it reads, and that it
 returns nothing where there is nothing to read."""
 
+import os
 from types import SimpleNamespace
 
 import pytest
 
+from benchmark import harness, peaks
 from benchmark.readers import (
     compiles, counter_ratio, decode_roofline, decode_step, device_idle, exposed,
     gauge_share, gen_lag, hbm_used, itl, observed, step_ms, sync_rate, token_rate,
     tpot, trace_time, train_mfu, ttft,
 )
 
-TPU = SimpleNamespace(platform="tpu")
-CPU = SimpleNamespace(platform="cpu")
+GPT2 = os.path.join(os.path.dirname(peaks.__file__), "families", "gpt2.py")
+TPU = SimpleNamespace(platform="tpu", family=GPT2)
+CPU = SimpleNamespace(platform="cpu", family=GPT2)
 XL = {"n_embd": 1600, "n_layer": 48, "n_head": 25, "n_positions": 1024, "vocab_size": 50257}
 
 
@@ -109,7 +112,7 @@ def test_trace_time_device_idle_and_exposed():
     assert exposed.read({"trace": traced()}, {"collective": "all-reduce"}, TPU) is None
 
 
-def test_decode_step_and_roofline_by_hand():
+def decode_obs():
     # 4 s of counters: 2,000 tokens of which 80 were first tokens, rows 24
     # a round: 80 token-steps, 20 a second; decode ran 3.5 of 4 s
     tc = counters(
@@ -122,15 +125,33 @@ def test_decode_step_and_roofline_by_hand():
     obs = {"trace": traced(), "trace_counters": tc, "model": XL,
            "device": {"kind": "TPU v5 lite"},
            "records": [rec(0, 0, [], usage={"prompt_tokens": 100, "completion_tokens": 100})]}
-    args = {"match": "^jit_decode_(paged_and_sample|multi_paged)$"}
+    return obs, {"match": "^jit_decode_(paged_and_sample|multi_paged)$"}
+
+
+def test_decode_step_and_roofline_by_hand():
+    obs, args = decode_obs()
     assert decode_step.token_steps_per_s(obs) == pytest.approx(20.0)
     assert decode_step.read(obs, args, TPU) == pytest.approx(1000 * (3.5 / 4.0) / 20.0)
-    from benchmark import peaks
-
+    # 2 x 1,557,611,200 bytes of weights, K and V of 24 rows at 150 tokens
+    step_bytes = harness.family(GPT2).decode_step_bytes(XL, 24.0, 150.0)
+    assert step_bytes == pytest.approx(3_115_222_400 + 1_105_920_000)
     assert decode_roofline.read(obs, args, TPU) == pytest.approx(
-        peaks.decode_roofline(0.04375, XL, 24.0, 150.0, "TPU v5 lite")
+        peaks.decode_roofline(0.04375, step_bytes, "TPU v5 lite")
     )
+    assert decode_roofline.read(obs, args, TPU) == pytest.approx(11.78, abs=0.01)
     assert decode_step.read({"trace": traced()}, args, TPU) is None
+
+
+def test_decode_roofline_reads_nothing_where_the_family_counts_no_bytes(tmp_path):
+    """Nothing, not 0: a family file without ``decode_step_bytes``, and a
+    configuration that names no family, leave the metric out of the line."""
+    obs, args = decode_obs()
+    bare = tmp_path / "bare.py"
+    bare.write_text("def context(model):\n    return 128\n")
+    assert harness.family(str(bare)).context({}) == 128
+    assert decode_roofline.read(obs, args, SimpleNamespace(platform="tpu", family=str(bare))) is None
+    assert decode_roofline.read(obs, args, SimpleNamespace(platform="tpu", family=None)) is None
+    assert decode_roofline.read(obs, args, SimpleNamespace(platform="tpu")) is None
 
 
 def test_compiles_counts_cache_entries_and_compile_events():

@@ -65,10 +65,10 @@ def test_the_reference_is_causal_and_reads_only_the_published_vocabulary(tiny):
 def test_prefill_then_decode_through_the_paged_cache_matches_the_full_forward(
     tiny, dtype, tolerance
 ):
-    from benchmark.reference import check
+    from benchmark.families import gpt2 as family
 
     cfg, params = tiny[dtype]
-    got = check.compare_serve(cfg, MODEL, params, seed=5, prompt_lens=[70, 33], steps=12)
+    got = family.compare_serve(cfg, MODEL, params, seed=5, prompt_lens=[70, 33], steps=12)
     assert got["prefill_max_abs"] < tolerance
     assert got["decode_max_abs"] < tolerance
     assert got["reference_logit_std"] > 0.05  # the logits are not all alike
@@ -82,7 +82,7 @@ def test_a_wrong_cache_row_is_caught(tiny):
     tolerance."""
     import numpy as np
 
-    from benchmark.reference import check
+    from benchmark.families import gpt2 as family
     from ray_tpu.models import gpt2_decode as dec
 
     cfg, params = tiny["float32"]
@@ -93,7 +93,7 @@ def test_a_wrong_cache_row_is_caught(tiny):
 
     dec._decode_paged_impl = swapped
     try:
-        got = check.compare_serve(cfg, MODEL, params, seed=5, prompt_lens=[70, 33], steps=4)
+        got = family.compare_serve(cfg, MODEL, params, seed=5, prompt_lens=[70, 33], steps=4)
     finally:
         dec._decode_paged_impl = real
     assert got["decode_max_abs"] > 100 * 5e-6

@@ -66,6 +66,34 @@ def test_gaps_go_to_what_the_dispatching_thread_was_doing():
     assert "$queue.py:154 get" not in gaps and "bench/window" not in gaps
 
 
+def test_a_gap_goes_to_the_programs_innermost_span_not_to_a_frame_with_a_line_number():
+    """``$llm.py:1455 run_round`` renames on any edit above that line;
+    ``rt/engine/round`` does not. Where no ``rt/`` span overlaps the gap,
+    the innermost host event of any kind still names it."""
+    host = [["rt/engine/round", 0, 10 * MS], ["$llm.py:1455 run_round", 0.5 * MS, 9 * MS],
+            ["rt/engine/dispatch", 6 * MS, 2 * MS], ["$llm.py:1353 dispatch", 6.1 * MS, 1.8 * MS],
+            ["jnp.asarray", 6.2 * MS, 0.5 * MS], ["$tail.py:7 flush", 12 * MS, 2 * MS]]
+    gaps = [(1 * MS, 2 * MS), (6.2 * MS, 6.7 * MS), (12.5 * MS, 13 * MS), (20 * MS, 21 * MS)]
+    assert tr.attribute_gaps(gaps, host) == {
+        "rt/engine/round": pytest.approx(0.001), "rt/engine/dispatch": pytest.approx(0.0005),
+        "$tail.py:7 flush": pytest.approx(0.0005), "unattributed": pytest.approx(0.001),
+    }
+    # with no preference, the innermost event of any kind, as before PR 27
+    assert set(tr.attribute_gaps(gaps[:2], host, prefer="none/")) == {
+        "$llm.py:1455 run_round", "jnp.asarray"}
+    # the tool beside the benchmark names gaps through the same function
+    from benchmark.tools import span_gaps
+
+    trace = hand_made()
+    trace["planes"][1]["lines"][0]["events"] += [
+        ["rt/engine/harvest_sync", 3.8 * MS, 1.4 * MS], ["$llm.py:1400 harvest", 3.9 * MS, 1.2 * MS]]
+    assert dict(tr.reduce(trace)["idle_gaps"])["rt/engine/harvest_sync"] == pytest.approx(0.001)
+    got = span_gaps.reduce_spans(trace)
+    assert got["idle_s_by_span"] == {"rt/engine/harvest_sync": pytest.approx(0.001),
+                                     "unattributed": pytest.approx(0.001)}
+    assert got["spans"]["rt/engine/harvest_sync"] == {"count": 1, "ms": pytest.approx(1.4)}
+
+
 def test_the_window_falls_back_to_the_devices_extent():
     t = hand_made()
     t["planes"][1]["lines"][0]["events"] = t["planes"][1]["lines"][0]["events"][1:]

@@ -126,10 +126,10 @@ def test_text_is_exactly_as_long_as_asked():
         assert len(traffic.text(random.Random(n), n).encode()) == n
 
 
-def _prefill_widths(start, total, context=1024, chunk=512):
+def _prefill_widths(start, total, context, chunk):
     """The widths the paged engine pads a lone prompt's prefill to, from
     ``start`` cached tokens on: the next power of two from 16, at most
-    what is left of the context, ``chunk`` tokens a round (the rule of
+    what is left of the ``context``, ``chunk`` tokens a round (the rule of
     ``serve/llm.py`` ``_engine_loop_paged``, copied)."""
     out, pos = [], start
     while pos < total:
@@ -143,26 +143,37 @@ def _prefill_widths(start, total, context=1024, chunk=512):
     return out
 
 
-def serving_mixes():
-    """Every serving mix of the benchmark, a later PR's too."""
-    import glob
-
-    names = []
-    for path in sorted(glob.glob(os.path.join(ROOT, "benchmark", "traffic", "*.json"))):
-        with open(path) as f:
-            if json.load(f).get("generator") == "serve_sessions":
-                names.append(os.path.basename(path)[: -len(".json")])
-    return names
+def benchmark_file():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        return json.load(f)
 
 
-@pytest.mark.parametrize("name", serving_mixes())
-def test_set_up_warms_every_prefill_width_a_turn_can_meet(name):
+def serving_cells():
+    """Every cell of BENCHMARK.json whose mix goes to an endpoint, a later
+    PR's too, whatever its generator: (cell, mix, configuration file)."""
+    bench = benchmark_file()
+    files = {c["name"]: c["file"] for c in bench["configs"]}
+    return [pytest.param(w["traffic"], files[w["config"]], id=w["name"])
+            for w in bench["workloads"] if "endpoint" in mix(w["traffic"])]
+
+
+@pytest.mark.parametrize("name,config_file", serving_cells())
+def test_set_up_warms_every_prefill_width_a_turn_can_meet(name, config_file):
     """A width first met inside the window compiles there (the driver's
     first chat run in a new checkout did: a tail of 274 tokens behind
-    nine cached pages is padded to 1,024 - 576 = 448)."""
+    nine cached pages is padded to 1,024 - 576 = 448). The context is the
+    cell's own configuration's, by its family, and the round's budget the
+    program's."""
+    from benchmark import harness
     from benchmark.generators import serve_sessions
+    from ray_tpu.utils.config import config as rtcfg
 
+    cfg = harness.load_json(os.path.join(ROOT, config_file))
+    family = harness.family(harness.find(benchmark_file(), "families", cfg["family"], ".py"))
+    context = family.context(cfg["model"])
+    chunk = int(rtcfg.serve_prefill_chunk_tokens)
     tr = mix(name)
+    assert tr["context_limit"] <= context
     page = int(tr["warm"].get("page_tokens", 64))
     chat = tr["endpoint"].endswith("/chat/completions")
     warmed, seen = set(), []
@@ -171,7 +182,7 @@ def test_set_up_warms_every_prefill_width_a_turn_can_meet(name):
         # whole pages of an earlier prompt that this one begins with
         cached = max((min(len(os.path.commonprefix([text, t])), len(text) - 1)
                       // page * page for t in seen), default=0)
-        warmed.update(_prefill_widths(cached, len(text)))
+        warmed.update(_prefill_widths(cached, len(text), context, chunk))
         seen.append(text)
     system = int(tr.get("system_prompt_tokens", 0))
     for script in traffic.session_pool(tr):
@@ -182,5 +193,5 @@ def test_set_up_warms_every_prefill_width_a_turn_can_meet(name):
                 starts.add(script[k - 1]["prompt_tokens"] // page * page)
             for start in starts:
                 start = min(start, (total - 1) // page * page)
-                assert set(_prefill_widths(start, total)) <= warmed, (k, turn, start)
+                assert set(_prefill_widths(start, total, context, chunk)) <= warmed, (k, turn, start)
     assert {448} <= warmed or name != "chat-sessions"
