@@ -443,11 +443,16 @@ def _decode_paged_impl(cfg: GPT2Config, params, last_tokens, lengths,
                        cache_k, cache_v, page_tables):
     """One token for every sequence over paged KV: [S] last tokens at
     virtual positions ``lengths`` scatter their new K/V through
-    ``page_tables`` [S, MaxPages] and attend over their gathered rows.
-    Returns logits [S, vocab] and the updated pools."""
+    ``page_tables`` [S, MaxPages] and attend over the pool layer as it
+    lies, every position of every page, under a mask that says which
+    positions a row owns. No row's context is gathered: the work grows
+    with the pool, not with S * MaxPages, and an engine's pool is the
+    smaller of the two (on the chip it won at every S down to 1 against
+    a pool of 97 pages; PERF.md, PR 30). Returns logits [S, vocab] and
+    the updated pools."""
     dt = cfg.dtype
     S = last_tokens.shape[0]
-    B = cache_k.shape[2]
+    N, B = cache_k.shape[1], cache_k.shape[2]
     max_pages = page_tables.shape[1]
     T = max_pages * B
     W = params["wpe"].shape[0]
@@ -457,9 +462,21 @@ def _decode_paged_impl(cfg: GPT2Config, params, last_tokens, lengths,
         + params["wpe"].astype(dt)[jnp.clip(pos, 0, W - 1)][:, None]
     )  # [S, 1, D]
     rows = jnp.arange(S)
-    mask = jnp.arange(T)[None] <= pos[:, None]  # attend 0..pos
     page_of = page_tables[rows, pos // B]  # [S]
     off = pos % B
+    # col[s, n]: the column at which page n stands in row s's table, -1
+    # where it does not. Every unused table entry names page 0 (scratch),
+    # so page 0 is nobody's; a prefix page shared by several rows is
+    # owned by each of them.
+    col = jnp.full((S, N), -1, jnp.int32)
+    col = col.at[rows[:, None], page_tables].set(
+        jnp.arange(max_pages, dtype=jnp.int32)[None]
+    )
+    col = col.at[:, 0].set(-1)
+    # position b of page n is virtual position col * B + b: attend 0..pos
+    virt = col[:, :, None] * B + jnp.arange(B)[None, None]  # [S, N, B]
+    mask = (col[:, :, None] >= 0) & (virt <= pos[:, None, None])
+    mask = mask.reshape(S, N * B)
 
     def body(layer_idx, carry):
         x, ck, cv = carry
@@ -473,20 +490,21 @@ def _decode_paged_impl(cfg: GPT2Config, params, last_tokens, lengths,
         q, k, v = _qkv(h, layer, cfg)  # [S, 1, H, Dh]
         # in-place scatter of the new token's K/V through the tables
         # (inactive rows have zero tables: their junk lands in the
-        # scratch page)
+        # scratch page, and owning nothing they attend uniformly over
+        # the pool's finite contents)
         ck = ck.at[layer_idx, page_of, off].set(k[:, 0].astype(dt))
         cv = cv.at[layer_idx, page_of, off].set(v[:, 0].astype(dt))
         ck_l = jax.lax.dynamic_index_in_dim(
             ck, layer_idx, axis=0, keepdims=False
-        )[page_tables].reshape(S, T, cfg.n_head, cfg.head_dim)
+        ).reshape(N * B, cfg.n_head, cfg.head_dim)
         cv_l = jax.lax.dynamic_index_in_dim(
             cv, layer_idx, axis=0, keepdims=False
-        )[page_tables].reshape(S, T, cfg.n_head, cfg.head_dim)
+        ).reshape(N * B, cfg.n_head, cfg.head_dim)
         scale = 1.0 / (cfg.head_dim ** 0.5)
-        scores = jnp.einsum("shn,sthn->sht", q[:, 0], ck_l) * scale
+        scores = jnp.einsum("shn,thn->sht", q[:, 0], ck_l) * scale
         scores = jnp.where(mask[:, None, :], scores, -1e30)
         probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(dt)
-        att = jnp.einsum("sht,sthn->shn", probs, cv_l)[:, None]
+        att = jnp.einsum("sht,thn->shn", probs, cv_l)[:, None]
         x = _proj_mlp(x, att, layer, cfg)
         return x, ck, cv
 

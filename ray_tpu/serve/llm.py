@@ -417,6 +417,9 @@ class LLMServer:
             # any request compiled anything)
             "devices": self._devices,
             "load_s": self._load_s,
+            # what the paged decode programs attend over: the page pool
+            # itself under an ownership mask (gpt2_decode), no row gather
+            "decode_attention": "pool" if self._paged else None,
             "prefix": (
                 self._prefix_pool.stats() if self._prefix_pool else None
             ),

@@ -136,6 +136,13 @@ def _req(prompt, max_new=8, **extra):
             "temperature": 0.0, **extra}
 
 
+def test_engine_says_which_decode_attention_it_runs(paged_engine, slot_engine):
+    """A paged engine's decode programs attend over the pool itself;
+    the slot engine has no pool to say anything about."""
+    assert paged_engine.batch_stats()["decode_attention"] == "pool"
+    assert slot_engine.batch_stats()["decode_attention"] is None
+
+
 def test_kill_switch_paged_vs_slot_bitwise(paged_engine, slot_engine):
     """RT_SERVE_PAGED_KV=0 restores pre-PR behavior: both engines share
     the weights recipe, so at temperature=0 the paged engine's page-
