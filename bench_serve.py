@@ -41,34 +41,12 @@ Legs (``--leg``):
   surplus must shed CLEANLY — instant unary 429/503 + Retry-After,
   counted client-side (``shed_503``/``shed_429``) and server-side
   (``rt_serve_shed_total`` delta), with zero client hangs.
-- ``pagedkv``: interleaved same-day A/B of the paged KV engine against
-  the pre-paged slot engine (``RT_SERVE_PAGED_KV=0`` semantics, flipped
-  per-arm via ``LLMConfig(paged_kv=...)`` so no env churn) at MATCHED
-  memory — the paged pool auto-sizes to exactly the slot cache's element
-  count. Arms run paged/slot/paged/slot, each a fresh redeploy + its own
-  identically-seeded Poisson window, so drift affects both engines
-  equally. Each arm records client goodput + tokens/s plus the
-  server-side ``rt_serve_batch_fill`` histogram delta (mean fill — the
-  page-based-admission shift) and the ``rt_serve_kv_block_copies_total``
-  delta (paged prefix hits must not copy).
-- ``asyncdecode``: interleaved same-day A/B of the async decode
-  pipeline (``RT_SERVE_ASYNC_DECODE``, flipped per-arm via
-  ``LLMConfig(async_decode=...)``) on a CLOSED-batch steady leg: a
-  fixed pool of ``max_batch_size * replicas`` clients each issues
-  back-to-back streams, holding batch fill at the pool size — the
-  regime where per-chunk host overhead, not arrival jitter, sets ITL.
-  Arms run async/sync/async/sync; each records client ITL p50/p95 +
-  aggregate tokens/s plus the server-side
-  ``rt_serve_engine_round_host_s`` delta (the engine thread's own time
-  per round, which the one-step lookahead runs under the device).
 
 Every run appends one row to BENCH_SERVE.json.
 
 Run: python bench_serve.py --rate 30 --duration 20
      python bench_serve.py --leg swing --rate 2 --duration 60
      python bench_serve.py --leg overload --rate 3 --duration 15
-     python bench_serve.py --leg pagedkv --rate 30 --duration 15
-     python bench_serve.py --leg asyncdecode --duration 15
 """
 
 import argparse
@@ -175,21 +153,6 @@ def _sum_ttft_hist(mx):
     return bounds, (buckets or []), count
 
 
-def _batch_fill_totals(mx):
-    """(count, sum) of rt_serve_batch_fill summed across series."""
-    m = mx.get("rt_serve_batch_fill") or {}
-    cnt = sm = 0.0
-    for h in (m.get("series") or {}).values():
-        cnt += float(h.get("count", 0.0))
-        sm += float(h.get("sum", 0.0))
-    return cnt, sm
-
-
-def _counter_total(mx, name):
-    m = mx.get(name) or {}
-    return float(sum((m.get("series") or {}).values()))
-
-
 def _append_row(path, row):
     doc = {"schema": 1, "rows": []}
     if os.path.exists(path):
@@ -202,335 +165,6 @@ def _append_row(path, row):
     with open(path, "w") as f:
         json.dump(doc, f, indent=2)
         f.write("\n")
-
-
-def _run_arm_window(host, port, args):
-    """One open-loop Poisson window with a per-arm re-seeded RNG, so
-    every A/B arm replays the identical arrival schedule + prompt mix."""
-    rng = random.Random(args.seed)
-    arrivals = []
-    t = 0.0
-    while True:
-        t += rng.expovariate(args.rate)
-        if t >= args.duration:
-            break
-        arrivals.append(t)
-    results = []
-    lock = threading.Lock()
-    inflight = threading.Semaphore(args.max_inflight)
-    shed = 0
-    threads = []
-
-    def worker(prompt_len):
-        try:
-            rec = _stream_one(
-                host, port, prompt_len, args.max_tokens, args.timeout
-            )
-        finally:
-            inflight.release()
-        with lock:
-            results.append(rec)
-
-    t0 = time.perf_counter()
-    for at in arrivals:
-        delay = at - (time.perf_counter() - t0)
-        if delay > 0:
-            time.sleep(delay)
-        if not inflight.acquire(blocking=False):
-            shed += 1
-            continue
-        th = threading.Thread(
-            target=worker,
-            args=(_sample_prompt_len(
-                rng, args.prompt_median, args.prompt_sigma, args.prompt_cap,
-            ),),
-            daemon=True,
-        )
-        th.start()
-        threads.append(th)
-    hung = 0
-    for th in threads:
-        th.join(timeout=args.timeout + 30)
-        hung += th.is_alive()
-    wall_s = time.perf_counter() - t0
-    return results, len(arrivals), shed, hung, wall_s
-
-
-def _pagedkv_leg(args, host_meta):
-    """Interleaved paged-vs-slot A/B. Redeploying the same deployment
-    name swaps the engine (the controller replaces replicas in place and
-    the /v1 route survives), and metric deltas are taken strictly inside
-    each arm's replica lifetime, so histogram sums never go backwards
-    under the merge."""
-    import ray_tpu
-    from ray_tpu import serve, state
-    from ray_tpu.serve import llm as serve_llm
-
-    order = [("paged", True), ("slot", False), ("paged", True),
-             ("slot", False)]
-    ray_tpu.init(num_cpus=max(8, args.replicas * 2))
-    serve.start(http_port=0)
-    arms = []
-    try:
-        for i, (label, paged) in enumerate(order):
-            serve_llm.deploy(
-                {MODEL: serve_llm.LLMConfig(
-                    model_id="gpt2-tiny",
-                    max_batch_size=args.max_batch_size,
-                    paged_kv=paged,
-                )},
-                name=DEPLOYMENT, route_prefix="/v1",
-                num_replicas=args.replicas,
-            )
-            deadline = time.monotonic() + 60
-            addrs = []
-            while time.monotonic() < deadline and not addrs:
-                addrs = serve.proxy_addresses()
-                time.sleep(0.2)
-            assert addrs, "no HTTP proxy came up"
-            host, port = addrs[0].rsplit(":", 1)
-            port = int(port)
-            for n in (8, args.prompt_median, args.prompt_median * 4):
-                for _ in range(args.replicas):
-                    _stream_one(host, port, n, 4, args.timeout)
-
-            mx0 = state.cluster_metrics()
-            c0, s0 = _batch_fill_totals(mx0)
-            cp0 = _counter_total(mx0, "rt_serve_kv_block_copies_total")
-            results, scheduled, shed, hung, wall_s = _run_arm_window(
-                host, port, args
-            )
-            mx1 = state.cluster_metrics()
-            c1, s1 = _batch_fill_totals(mx1)
-            cp1 = _counter_total(mx1, "rt_serve_kv_block_copies_total")
-
-            ok = [r for r in results if r.get("ok")]
-            ttfts = sorted(r["ttft"] for r in ok)
-            itls = sorted(g for r in ok for g in r["itls"])
-            tokens = sum(r["tokens"] for r in ok)
-            fill = (s1 - s0) / (c1 - c0) if c1 > c0 else None
-            p95 = _percentile(ttfts, 0.95)
-            itl95 = _percentile(itls, 0.95)
-            arms.append({
-                "arm": i,
-                "engine": label,
-                "scheduled": scheduled,
-                "requests_ok": len(ok),
-                "errors": len(results) - len(ok),
-                "shed_client": shed,
-                "hung_clients": hung,
-                "goodput_rps": round(len(ok) / wall_s, 2),
-                "tokens_per_s": round(tokens / wall_s, 1),
-                "batch_fill_mean": (
-                    round(fill, 3) if fill is not None else None
-                ),
-                "ttft_p95_ms": round(p95 * 1e3, 1) if p95 else None,
-                "itl_p95_ms": round(itl95 * 1e3, 2) if itl95 else None,
-                "kv_block_copies": max(0.0, round(cp1 - cp0, 0)),
-            })
-            print(json.dumps({"arm_done": arms[-1]}), flush=True)
-
-        def mean_of(engine, key):
-            vals = [
-                a[key] for a in arms
-                if a["engine"] == engine and a[key] is not None
-            ]
-            return sum(vals) / len(vals) if vals else None
-
-        summary = {}
-        for key in ("goodput_rps", "tokens_per_s", "batch_fill_mean"):
-            p, s = mean_of("paged", key), mean_of("slot", key)
-            summary[key] = {
-                "paged": round(p, 3) if p is not None else None,
-                "slot": round(s, 3) if s is not None else None,
-                "ratio": round(p / s, 3) if p and s else None,
-            }
-        summary["kv_block_copies"] = {
-            "paged": mean_of("paged", "kv_block_copies"),
-            "slot": None,  # slot engine doesn't publish the counter
-        }
-        row = {
-            "ts": time.strftime("%Y-%m-%dT%H:%M:%SZ", time.gmtime()),
-            "host": host_meta,
-            "leg": "pagedkv",
-            "rate_rps": args.rate,
-            "duration_s": args.duration,
-            "replicas": args.replicas,
-            "max_batch_size": args.max_batch_size,
-            "max_tokens": args.max_tokens,
-            "prompt": {"median": args.prompt_median,
-                       "sigma": args.prompt_sigma, "cap": args.prompt_cap},
-            "arms": arms,
-            "summary": summary,
-        }
-        print(json.dumps(row, indent=2))
-        _append_row(args.out, row)
-        assert all(a["requests_ok"] for a in arms), "an arm served nothing"
-        print(json.dumps({"ok": True, "summary": summary}))
-        return 0
-    finally:
-        serve.shutdown()
-        ray_tpu.shutdown()
-
-
-def _hist_totals(mx, name):
-    """(count, sum) of a histogram summed across series."""
-    m = mx.get(name) or {}
-    cnt = sm = 0.0
-    for h in (m.get("series") or {}).values():
-        cnt += float(h.get("count", 0.0))
-        sm += float(h.get("sum", 0.0))
-    return cnt, sm
-
-
-def _run_closed_window(host, port, args):
-    """Closed-batch steady load: a fixed pool of clients, each issuing
-    back-to-back SSE requests for the duration. Per-client re-seeded
-    RNGs make every A/B arm replay the identical prompt mix, and the
-    closed loop holds batch fill at the pool size — the regime where
-    per-chunk host overhead (not arrival jitter) sets ITL."""
-    clients = args.max_batch_size * args.replicas
-    results = []
-    lock = threading.Lock()
-    t_end = time.perf_counter() + args.duration
-
-    def worker(wid):
-        rng = random.Random(args.seed * 1000 + wid)
-        while time.perf_counter() < t_end:
-            rec = _stream_one(
-                host, port,
-                _sample_prompt_len(
-                    rng, args.prompt_median, args.prompt_sigma,
-                    args.prompt_cap,
-                ),
-                args.max_tokens, args.timeout,
-            )
-            with lock:
-                results.append(rec)
-
-    t0 = time.perf_counter()
-    threads = [
-        threading.Thread(target=worker, args=(w,), daemon=True)
-        for w in range(clients)
-    ]
-    for th in threads:
-        th.start()
-    hung = 0
-    for th in threads:
-        th.join(timeout=args.duration + args.timeout + 30)
-        hung += th.is_alive()
-    return results, clients, hung, time.perf_counter() - t0
-
-
-def _asyncdecode_leg(args, host_meta):
-    """Interleaved async-vs-sync decode pipeline A/B on the closed-batch
-    steady leg. Both arms run the paged engine with matched batch and
-    pool sizes; only RT_SERVE_ASYNC_DECODE flips (carried per-arm on the
-    pickled LLMConfig, so no env coordination with replicas). Reports
-    client-side ITL p50/p95 + aggregate tokens/s and the server-side
-    rt_serve_engine_round_host_s delta — the engine thread's own time
-    per round, which the lookahead exists to hide."""
-    import ray_tpu
-    from ray_tpu import serve, state
-    from ray_tpu.serve import llm as serve_llm
-
-    order = [("async", True), ("sync", False), ("async", True),
-             ("sync", False)]
-    ray_tpu.init(num_cpus=max(8, args.replicas * 2))
-    serve.start(http_port=0)
-    arms = []
-    try:
-        for i, (label, async_on) in enumerate(order):
-            serve_llm.deploy(
-                {MODEL: serve_llm.LLMConfig(
-                    model_id="gpt2-tiny",
-                    max_batch_size=args.max_batch_size,
-                    paged_kv=True, async_decode=async_on,
-                )},
-                name=DEPLOYMENT, route_prefix="/v1",
-                num_replicas=args.replicas,
-            )
-            deadline = time.monotonic() + 60
-            addrs = []
-            while time.monotonic() < deadline and not addrs:
-                addrs = serve.proxy_addresses()
-                time.sleep(0.2)
-            assert addrs, "no HTTP proxy came up"
-            host, port = addrs[0].rsplit(":", 1)
-            port = int(port)
-            for n in (8, args.prompt_median, args.prompt_median * 4):
-                for _ in range(args.replicas):
-                    _stream_one(host, port, n, 4, args.timeout)
-
-            mx0 = state.cluster_metrics()
-            g0c, g0s = _hist_totals(mx0, "rt_serve_engine_round_host_s")
-            results, clients, hung, wall_s = _run_closed_window(
-                host, port, args
-            )
-            mx1 = state.cluster_metrics()
-            g1c, g1s = _hist_totals(mx1, "rt_serve_engine_round_host_s")
-
-            ok = [r for r in results if r.get("ok")]
-            itls = sorted(g for r in ok for g in r["itls"])
-            tokens = sum(r["tokens"] for r in ok)
-            itl50 = _percentile(itls, 0.5)
-            itl95 = _percentile(itls, 0.95)
-            gap_mean = (g1s - g0s) / (g1c - g0c) if g1c > g0c else None
-            arms.append({
-                "arm": i,
-                "pipeline": label,
-                "clients": clients,
-                "requests_ok": len(ok),
-                "errors": len(results) - len(ok),
-                "hung_clients": hung,
-                "tokens_per_s": round(tokens / wall_s, 1),
-                "itl_p50_ms": round(itl50 * 1e3, 2) if itl50 else None,
-                "itl_p95_ms": round(itl95 * 1e3, 2) if itl95 else None,
-                "host_ms_mean": (
-                    round(gap_mean * 1e3, 3) if gap_mean is not None
-                    else None
-                ),
-                "host_rounds": round(g1c - g0c, 0),
-            })
-            print(json.dumps({"arm_done": arms[-1]}), flush=True)
-
-        def mean_of(pipeline, key):
-            vals = [
-                a[key] for a in arms
-                if a["pipeline"] == pipeline and a[key] is not None
-            ]
-            return sum(vals) / len(vals) if vals else None
-
-        summary = {}
-        for key in ("tokens_per_s", "itl_p50_ms", "itl_p95_ms",
-                    "host_ms_mean"):
-            a, s = mean_of("async", key), mean_of("sync", key)
-            summary[key] = {
-                "async": round(a, 3) if a is not None else None,
-                "sync": round(s, 3) if s is not None else None,
-                "ratio": round(a / s, 3) if a and s else None,
-            }
-        row = {
-            "ts": time.strftime("%Y-%m-%dT%H:%M:%SZ", time.gmtime()),
-            "host": host_meta,
-            "leg": "asyncdecode",
-            "duration_s": args.duration,
-            "replicas": args.replicas,
-            "max_batch_size": args.max_batch_size,
-            "max_tokens": args.max_tokens,
-            "prompt": {"median": args.prompt_median,
-                       "sigma": args.prompt_sigma, "cap": args.prompt_cap},
-            "arms": arms,
-            "summary": summary,
-        }
-        print(json.dumps(row, indent=2))
-        _append_row(args.out, row)
-        assert all(a["requests_ok"] for a in arms), "an arm served nothing"
-        print(json.dumps({"ok": True, "summary": summary}))
-        return 0
-    finally:
-        serve.shutdown()
-        ray_tpu.shutdown()
 
 
 def _autoscale_sampler(stop, out, deployment):
@@ -561,15 +195,11 @@ def _autoscale_sampler(stop, out, deployment):
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--leg",
-                    choices=("steady", "swing", "overload", "pagedkv",
-                             "asyncdecode"),
+                    choices=("steady", "swing", "overload"),
                     default="steady",
                     help="load shape: one rate, a 10x swing against an "
-                         "autoscaling deployment, sustained overload "
-                         "against a tight admission bound, an "
-                         "interleaved paged-vs-slot KV engine A/B, or "
-                         "a closed-batch async-vs-sync decode pipeline "
-                         "A/B")
+                         "autoscaling deployment, or sustained overload "
+                         "against a tight admission bound")
     ap.add_argument("--rate", type=float, default=30.0,
                     help="mean arrival rate, requests/s (Poisson); the "
                          "swing/overload legs burst at 10x this")
@@ -623,11 +253,6 @@ def main() -> int:
     }
     if swept.get("killed") or swept.get("removed"):
         print(json.dumps({"swept_stale_runtime": swept}), flush=True)
-
-    if args.leg == "pagedkv":
-        return _pagedkv_leg(args, host_meta)
-    if args.leg == "asyncdecode":
-        return _asyncdecode_leg(args, host_meta)
 
     rng = random.Random(args.seed)
     ray_tpu.init(num_cpus=max(8, args.replicas * 2))

@@ -217,9 +217,7 @@ def _render_top(mx: dict, reqs: dict, qps: Optional[dict],
     for dep, v in by_tag("rt_serve_tokens_generated_total",
                          "deployment").items():
         row(dep)["tokens"] = int(v)
-    for dep, v in by_tag("rt_serve_kv_slots_occupied", "deployment").items():
-        row(dep)["kv_slots"] = f"{v:g}"
-    # paged engines: occupied/total pages + sealed prefix residents
+    # occupied/total pages + sealed prefix residents
     pg_occ = by_tag("rt_serve_kv_pages_occupied", "deployment")
     pg_tot = by_tag("rt_serve_kv_pages_total", "deployment")
     pg_res = by_tag("rt_serve_kv_pages_prefix_resident", "deployment")
@@ -284,7 +282,7 @@ def _render_top(mx: dict, reqs: dict, qps: Optional[dict],
         )
     columns = ["deployment", "replicas", "reqs", "qps", "ttft_p50_ms",
                "ttft_p95_ms", "itl_p50_ms", "host_ms", "tokens",
-               "kv_slots", "kv_pages", "queued", "shed", "batch_fill",
+               "kv_pages", "queued", "shed", "batch_fill",
                "cache_hit",
                "page_hit", "last_scale"]
     if hist is not None:

@@ -2,18 +2,26 @@
 
 Parity role: the engine tier the reference delegates to vLLM
 (/root/reference/python/ray/llm/_internal/serve/engines/vllm/) — here a
-native JAX engine: a prefill/decode split over a slot-based static-shape
-KV cache, so generating token N costs one single-token forward over
-cached K/V instead of re-running the whole prefix (the round-3 engine
-recomputed O(N·T·model) per generation).
+native JAX engine: a prefill/decode split over a paged, static-shape KV
+pool, so generating token N costs one single-token forward over cached
+K/V instead of re-running the whole prefix.
 
-TPU-first shape discipline: the cache is ``[L, S, T_max, H, Dh]`` with a
-fixed slot count S — every jitted function has static shapes, admission
-of a new request into a free slot is a ``dynamic_update_slice`` row
-write, and the decode step runs all S slots batched whether or not each
-is active (masked), which is exactly the static-batch regime the MXU
-wants. Continuous batching lives OUTSIDE jit (the engine loop admits
-requests between steps; serve/llm.py drives it).
+vLLM-style paged attention at the jnp level: physical KV pages
+``[L, N_pages, B, H, Dh]`` in HBM, per-sequence page tables
+``[S, MaxPages]`` mapping virtual position p to physical row
+(table[p // B], p % B). A prefix-cache hit points the table at pages
+another sequence already wrote (zero copies); admission reserves
+ceil(tokens/B) pages up front so tables never change mid-flight.
+Page 0 is reserved scratch: inactive rows carry all-zero tables and
+length 0, so their junk scatters land there and the jitted step needs
+no validity branch.
+
+TPU-first shape discipline: the pool, the row count S and the table
+width are fixed — every jitted function has static shapes, and the
+decode step runs all S rows batched whether or not each is active
+(masked), which is exactly the static-batch regime the MXU wants.
+Continuous batching lives OUTSIDE jit (the engine loop admits requests
+between steps; serve/llm.py drives it).
 """
 
 from __future__ import annotations
@@ -26,12 +34,6 @@ import jax.numpy as jnp
 
 from ray_tpu.models import gpt2
 from ray_tpu.models.gpt2 import GPT2Config, _layernorm
-
-
-def init_cache(cfg: GPT2Config, slots: int, t_max: int):
-    """(k, v) caches: [n_layer, S, T_max, H, Dh] in the compute dtype."""
-    shape = (cfg.n_layer, slots, t_max, cfg.n_head, cfg.head_dim)
-    return jnp.zeros(shape, cfg.dtype), jnp.zeros(shape, cfg.dtype)
 
 
 def _qkv(h, layer, cfg: GPT2Config):
@@ -63,212 +65,6 @@ def _proj_mlp(x, att, layer, cfg: GPT2Config):
     return x + h
 
 
-@partial(jax.jit, static_argnums=(0,), donate_argnums=(4, 5))
-def prefill(cfg: GPT2Config, params, tokens, length, cache_k, cache_v,
-            slot):
-    """Run the full prompt ([1, P] right-padded) through the model,
-    writing each layer's K/V into cache row ``slot``; return the last
-    real position's logits [vocab] and the updated caches.
-
-    fori_loop (not scan) over layers so the cache updates are IN-PLACE
-    dynamic_update_slices on the donated carry — a scan would stack
-    fresh [L, S, T, H, Dh] cache outputs, copying the whole cache per
-    call (measured 300x slower at gpt2-small)."""
-    dt = cfg.dtype
-    P = tokens.shape[1]
-    x = params["wte"].astype(dt)[tokens] + params["wpe"].astype(dt)[:P][None]
-    causal = jnp.tril(jnp.ones((P, P), bool))
-
-    def body(layer_idx, carry):
-        x, ck, cv = carry
-        layer = jax.tree_util.tree_map(
-            lambda a: jax.lax.dynamic_index_in_dim(
-                a, layer_idx, axis=0, keepdims=False
-            ),
-            params["blocks"],
-        )
-        h = _layernorm(x, layer["ln1"]["scale"], layer["ln1"]["bias"])
-        q, k, v = _qkv(h, layer, cfg)
-        # causal self-attention over the prompt itself
-        scale = 1.0 / (cfg.head_dim ** 0.5)
-        scores = jnp.einsum("bthn,bshn->bhts", q, k) * scale
-        scores = jnp.where(causal[None, None], scores, -1e30)
-        probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(dt)
-        att = jnp.einsum("bhts,bshn->bthn", probs, v)
-        x = _proj_mlp(x, att, layer, cfg)
-        # park this layer's prompt K/V in the slot's cache row (in place)
-        ck = jax.lax.dynamic_update_slice(
-            ck, k.astype(dt)[None], (layer_idx, slot, 0, 0, 0)
-        )
-        cv = jax.lax.dynamic_update_slice(
-            cv, v.astype(dt)[None], (layer_idx, slot, 0, 0, 0)
-        )
-        return x, ck, cv
-
-    x, cache_k, cache_v = jax.lax.fori_loop(
-        0, cfg.n_layer, body, (x, cache_k, cache_v)
-    )
-    x = _layernorm(x, params["ln_f"]["scale"], params["ln_f"]["bias"])
-    last = jax.lax.dynamic_index_in_dim(
-        x[0], jnp.maximum(length - 1, 0), axis=0, keepdims=False
-    )
-    logits = jnp.einsum(
-        "d,vd->v", last.astype(dt), params["wte"].astype(dt),
-        preferred_element_type=jnp.float32,
-    )
-    return logits[: cfg.vocab_size], cache_k, cache_v
-
-
-@partial(jax.jit, donate_argnums=(2, 3))
-def write_prefix(prefix_k, prefix_v, cache_k, cache_v, slot):
-    """Copy precomputed prefix K/V ``[L, C, H, Dh]`` into cache row
-    ``slot`` (positions 0..C-1) — the admission path for a prefix-cache
-    hit or a disaggregated KV import: the slot starts life already
-    holding C tokens of context without running a single prefill flop.
-
-    C must be one of a small set of sizes (block multiples from the
-    prefix pool, pow-2 padded lengths from kv_transfer) so the jit
-    bucket count stays bounded like prefill's P buckets."""
-    ck = jax.lax.dynamic_update_slice(
-        cache_k, prefix_k.astype(cache_k.dtype)[:, None], (0, slot, 0, 0, 0)
-    )
-    cv = jax.lax.dynamic_update_slice(
-        cache_v, prefix_v.astype(cache_v.dtype)[:, None], (0, slot, 0, 0, 0)
-    )
-    return ck, cv
-
-
-@partial(jax.jit, static_argnums=(0,), donate_argnums=(5, 6))
-def prefill_extend(cfg: GPT2Config, params, tokens, start, length, cache_k,
-                   cache_v, slot):
-    """Prefill ONLY the uncached tail of a prompt: ``tokens`` [1, P]
-    (right-padded, ``length`` real) are positions start..start+P-1, and
-    cache row ``slot`` already holds K/V for positions 0..start-1
-    (written by :func:`write_prefix`). Writes the tail's K/V at offset
-    ``start``, attends the tail over prefix+tail, and returns the last
-    real tail position's logits [vocab] plus the updated caches.
-
-    The caller guarantees start + P <= T_max (dynamic_update_slice would
-    silently clamp the write offset otherwise)."""
-    dt = cfg.dtype
-    P = tokens.shape[1]
-    T = cache_k.shape[2]
-    pos = start + jnp.arange(P)
-    x = (
-        params["wte"].astype(dt)[tokens]
-        + params["wpe"].astype(dt)[jnp.clip(pos, 0, T - 1)][None]
-    )
-    # tail position start+i may attend every cached position 0..start+i
-    mask = jnp.arange(T)[None] <= pos[:, None]  # [P, T]
-
-    def body(layer_idx, carry):
-        x, ck, cv = carry
-        layer = jax.tree_util.tree_map(
-            lambda a: jax.lax.dynamic_index_in_dim(
-                a, layer_idx, axis=0, keepdims=False
-            ),
-            params["blocks"],
-        )
-        h = _layernorm(x, layer["ln1"]["scale"], layer["ln1"]["bias"])
-        q, k, v = _qkv(h, layer, cfg)  # [1, P, H, Dh]
-        # park the tail's K/V after the prefix (in place on the donated
-        # carry), then attend over the whole row so the tail sees the
-        # cached prefix it never recomputed
-        ck = jax.lax.dynamic_update_slice(
-            ck, k.astype(dt)[None], (layer_idx, slot, start, 0, 0)
-        )
-        cv = jax.lax.dynamic_update_slice(
-            cv, v.astype(dt)[None], (layer_idx, slot, start, 0, 0)
-        )
-        ck_l = jax.lax.dynamic_slice(
-            ck, (layer_idx, slot, 0, 0, 0),
-            (1, 1, T, cfg.n_head, cfg.head_dim),
-        )[:, 0]  # [1, T, H, Dh]
-        cv_l = jax.lax.dynamic_slice(
-            cv, (layer_idx, slot, 0, 0, 0),
-            (1, 1, T, cfg.n_head, cfg.head_dim),
-        )[:, 0]
-        scale = 1.0 / (cfg.head_dim ** 0.5)
-        scores = jnp.einsum("bthn,bshn->bhts", q, ck_l) * scale
-        scores = jnp.where(mask[None, None], scores, -1e30)
-        probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(dt)
-        att = jnp.einsum("bhts,bshn->bthn", probs, cv_l)
-        x = _proj_mlp(x, att, layer, cfg)
-        return x, ck, cv
-
-    x, cache_k, cache_v = jax.lax.fori_loop(
-        0, cfg.n_layer, body, (x, cache_k, cache_v)
-    )
-    x = _layernorm(x, params["ln_f"]["scale"], params["ln_f"]["bias"])
-    last = jax.lax.dynamic_index_in_dim(
-        x[0], jnp.maximum(length - 1, 0), axis=0, keepdims=False
-    )
-    logits = jnp.einsum(
-        "d,vd->v", last.astype(dt), params["wte"].astype(dt),
-        preferred_element_type=jnp.float32,
-    )
-    return logits[: cfg.vocab_size], cache_k, cache_v
-
-
-def _decode_step_impl(cfg: GPT2Config, params, last_tokens, lengths, cache_k,
-                      cache_v):
-    """One token for every slot: [S] last tokens at positions ``lengths``
-    attend over their cached prefixes. Returns logits [S, vocab] and the
-    updated caches (new K/V scattered at position ``lengths``)."""
-    dt = cfg.dtype
-    S = last_tokens.shape[0]
-    T = cache_k.shape[2]
-    pos = jnp.clip(lengths, 0, T - 1)
-    x = (
-        params["wte"].astype(dt)[last_tokens][:, None]
-        + params["wpe"].astype(dt)[pos][:, None]
-    )  # [S, 1, D]
-    rows = jnp.arange(S)
-    mask = jnp.arange(T)[None] <= pos[:, None]  # attend 0..pos
-
-    def body(layer_idx, carry):
-        x, ck, cv = carry
-        layer = jax.tree_util.tree_map(
-            lambda a: jax.lax.dynamic_index_in_dim(
-                a, layer_idx, axis=0, keepdims=False
-            ),
-            params["blocks"],
-        )
-        h = _layernorm(x, layer["ln1"]["scale"], layer["ln1"]["bias"])
-        q, k, v = _qkv(h, layer, cfg)  # [S, 1, H, Dh]
-        # in-place scatter of the new token's K/V rows on the donated carry
-        ck = ck.at[layer_idx, rows, pos].set(k[:, 0].astype(dt))
-        cv = cv.at[layer_idx, rows, pos].set(v[:, 0].astype(dt))
-        ck_l = jax.lax.dynamic_index_in_dim(
-            ck, layer_idx, axis=0, keepdims=False
-        )  # [S, T, H, Dh]
-        cv_l = jax.lax.dynamic_index_in_dim(
-            cv, layer_idx, axis=0, keepdims=False
-        )
-        scale = 1.0 / (cfg.head_dim ** 0.5)
-        scores = jnp.einsum("shn,sthn->sht", q[:, 0], ck_l) * scale
-        scores = jnp.where(mask[:, None, :], scores, -1e30)
-        probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(dt)
-        att = jnp.einsum("sht,sthn->shn", probs, cv_l)[:, None]
-        x = _proj_mlp(x, att, layer, cfg)
-        return x, ck, cv
-
-    x, cache_k, cache_v = jax.lax.fori_loop(
-        0, cfg.n_layer, body, (x, cache_k, cache_v)
-    )
-    x = _layernorm(x, params["ln_f"]["scale"], params["ln_f"]["bias"])
-    logits = jnp.einsum(
-        "sd,vd->sv", x[:, 0].astype(dt), params["wte"].astype(dt),
-        preferred_element_type=jnp.float32,
-    )
-    return logits[:, : cfg.vocab_size], cache_k, cache_v
-
-
-decode_step = partial(jax.jit, static_argnums=(0,), donate_argnums=(4, 5))(
-    _decode_step_impl
-)
-
-
 def sample(logits, temps, greedy_mask, rng):
     """Per-row temperature/greedy sampling. logits [S, V]."""
     greedy = jnp.argmax(logits, axis=-1)
@@ -278,14 +74,17 @@ def sample(logits, temps, greedy_mask, rng):
     return jnp.where(greedy_mask, greedy, sampled).astype(jnp.int32)
 
 
-@partial(jax.jit, donate_argnums=(1, 2, 3))
-def update_rows(last_tokens, lengths, temps, greedy_mask, rows, row_last,
-                row_len, row_temps, row_greedy):
+@partial(jax.jit, donate_argnums=(1, 2, 3, 4))
+def update_rows_paged(last_tokens, lengths, temps, greedy_mask,
+                      page_tables, rows, row_last, row_len, row_temps,
+                      row_greedy, row_tables):
     """Incremental decode-state update: write admission/retirement
     values into ``rows`` of the device-resident step state WITHOUT
-    re-uploading the full arrays — the async decode pipeline's
-    steady-state churn path (one small scatter per array instead of
-    five host->device transfers at every admit/retire).
+    re-uploading the full arrays — the decode pipeline's steady-state
+    churn path (one small scatter per array instead of five
+    host->device transfers at every admit/retire). A retired row's
+    page table goes all-zero so its junk scatters land in the scratch
+    page; an admitted row brings its freshly reserved table.
 
     ``last_tokens`` is deliberately NOT donated: in the single-step
     decode regime it aliases the chunk's token output, which the host
@@ -295,55 +94,8 @@ def update_rows(last_tokens, lengths, temps, greedy_mask, rows, row_last,
         lengths.at[rows].set(row_len),
         temps.at[rows].set(row_temps),
         greedy_mask.at[rows].set(row_greedy),
-    )
-
-
-@partial(jax.jit, donate_argnums=(1, 2, 3, 4))
-def update_rows_paged(last_tokens, lengths, temps, greedy_mask,
-                      page_tables, rows, row_last, row_len, row_temps,
-                      row_greedy, row_tables):
-    """Paged twin of :func:`update_rows`: also rewrites the changed
-    sequences' page-table rows (a retired row's table goes all-zero so
-    its junk scatters land in the scratch page; an admitted row brings
-    its freshly reserved table). Same donation caveat on
-    ``last_tokens``."""
-    return (
-        last_tokens.at[rows].set(row_last),
-        lengths.at[rows].set(row_len),
-        temps.at[rows].set(row_temps),
-        greedy_mask.at[rows].set(row_greedy),
         page_tables.at[rows].set(row_tables),
     )
-
-
-@partial(jax.jit, static_argnums=(0,), donate_argnums=(4, 5))
-def decode_and_sample(cfg: GPT2Config, params, last_tokens, lengths,
-                      cache_k, cache_v, temps, greedy_mask, rng_base, step):
-    """decode_step + sample (+ RNG fold + cursor bump) fused into ONE
-    dispatch — on a remote/tunneled chip the per-call round trip dominates
-    single-token decode, so the serving loop pays exactly one dispatch +
-    one token sync per step. Returns (next_tokens, next_lengths, k, v):
-    the engine feeds them straight back in without re-uploading."""
-    logits, cache_k, cache_v = _decode_step_impl(
-        cfg, params, last_tokens, lengths, cache_k, cache_v
-    )
-    rng = jax.random.fold_in(rng_base, step)
-    nxt = sample(logits, temps, greedy_mask, rng)
-    return nxt, lengths + 1, cache_k, cache_v
-
-
-# -- paged KV cache (one pool for generation + prefix pages) -----------
-#
-# vLLM-style paged attention at the jnp level: physical KV pages
-# ``[L, N_pages, B, H, Dh]`` in HBM, per-sequence page tables
-# ``[S, MaxPages]`` mapping virtual position p to physical row
-# (table[p // B], p % B). A prefix-cache hit points the table at pages
-# another sequence already wrote (zero copies); admission reserves
-# ceil(tokens/B) pages up front so tables never change mid-flight.
-# Page 0 is reserved scratch: inactive rows carry all-zero tables and
-# length 0, so their junk scatters land there and the jitted step needs
-# no validity branch (same masked-static-batch regime as the slot
-# kernels above).
 
 
 def init_paged_cache(cfg: GPT2Config, num_pages: int, page_tokens: int):
@@ -379,7 +131,12 @@ def prefill_paged(cfg: GPT2Config, params, tokens, start, length, cache_k,
     The caller guarantees start + P <= MaxPages * B (bucket the chunk
     width against that cap); positions past the sequence's reserved
     pages hit table entries that are 0 = the scratch page, so padding
-    scatters are harmless exactly like prefill_extend's padded tail."""
+    scatters are harmless.
+
+    fori_loop (not scan) over layers, here and in the decode step, so
+    the cache updates are IN-PLACE on the donated carry — a scan would
+    stack fresh per-layer cache outputs, copying the whole pool per
+    call (measured 300x slower at gpt2-small)."""
     dt = cfg.dtype
     P = tokens.shape[1]
     B = cache_k.shape[2]
@@ -523,8 +280,10 @@ def _decode_paged_impl(cfg: GPT2Config, params, last_tokens, lengths,
 def decode_paged_and_sample(cfg: GPT2Config, params, last_tokens, lengths,
                             cache_k, cache_v, page_tables, temps,
                             greedy_mask, rng_base, step):
-    """Paged twin of :func:`decode_and_sample`: decode + sample (+ RNG
-    fold + cursor bump) fused into ONE dispatch."""
+    """Decode + sample (+ RNG fold + cursor bump) fused into ONE
+    dispatch — the serving loop pays exactly one dispatch + one token
+    sync per step. Returns (next_tokens, next_lengths, k, v): the engine
+    feeds them straight back in without re-uploading."""
     logits, cache_k, cache_v = _decode_paged_impl(
         cfg, params, last_tokens, lengths, cache_k, cache_v, page_tables
     )
@@ -537,10 +296,12 @@ def decode_paged_and_sample(cfg: GPT2Config, params, last_tokens, lengths,
 def decode_multi_paged(cfg: GPT2Config, params, last_tokens, lengths,
                        cache_k, cache_v, page_tables, temps, greedy_mask,
                        rng_base, n_steps: int, step0):
-    """Paged twin of :func:`decode_multi`: ``n_steps`` tokens per
-    sequence in ONE dispatch, page-table scatter recomputed per step
-    on device (the tables themselves are fixed — admission reserved
-    every page up front)."""
+    """``n_steps`` tokens per sequence in ONE dispatch (fori_loop on
+    device), page-table scatter recomputed per step (the tables
+    themselves are fixed — admission reserved every page up front).
+    The engine picks K from the active rows' remaining budgets and drops
+    to K=1 whenever requests are waiting for admission (continuous
+    batching latency stays one step)."""
     S = last_tokens.shape[0]
     toks0 = jnp.zeros((n_steps, S), jnp.int32)
 
@@ -549,33 +310,6 @@ def decode_multi_paged(cfg: GPT2Config, params, last_tokens, lengths,
         logits, ck, cv = _decode_paged_impl(
             cfg, params, last, lens, ck, cv, page_tables
         )
-        rng = jax.random.fold_in(rng_base, step0 + i)
-        nxt = sample(logits, temps, greedy_mask, rng)
-        toks = jax.lax.dynamic_update_index_in_dim(toks, nxt, i, axis=0)
-        return nxt, lens + 1, ck, cv, toks
-
-    last, lens, cache_k, cache_v, toks = jax.lax.fori_loop(
-        0, n_steps, body, (last_tokens, lengths, cache_k, cache_v, toks0)
-    )
-    return toks, last, lens, cache_k, cache_v
-
-
-@partial(jax.jit, static_argnums=(0, 9), donate_argnums=(4, 5))
-def decode_multi(cfg: GPT2Config, params, last_tokens, lengths, cache_k,
-                 cache_v, temps, greedy_mask, rng_base, n_steps: int,
-                 step0):
-    """Generate ``n_steps`` tokens per slot in ONE dispatch (fori_loop on
-    device). On a remote/tunneled chip each dispatch costs a full network
-    round trip, so chunking K tokens per call multiplies serving
-    throughput by ~K; the engine picks K from the active slots' remaining
-    budgets and drops to K=1 whenever requests are waiting for admission
-    (continuous batching latency stays one step)."""
-    S = last_tokens.shape[0]
-    toks0 = jnp.zeros((n_steps, S), jnp.int32)
-
-    def body(i, carry):
-        last, lens, ck, cv, toks = carry
-        logits, ck, cv = _decode_step_impl(cfg, params, last, lens, ck, cv)
         rng = jax.random.fold_in(rng_base, step0 + i)
         nxt = sample(logits, temps, greedy_mask, rng)
         toks = jax.lax.dynamic_update_index_in_dim(toks, nxt, i, axis=0)

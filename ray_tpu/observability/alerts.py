@@ -119,18 +119,19 @@ def default_rules() -> List[Rule]:
             long_window_s=float(config.alerts_burn_long_s),
             factor=float(config.alerts_burn_factor),
         ),
-        # Router/engine backlog: requests waiting for a KV slot.
+        # Router/engine backlog: requests waiting for a decode row and
+        # its KV pages.
         Rule(
             name="serve_queue_deep", kind="threshold",
             metric="rt_serve_queued_requests", op=">",
             threshold=float(config.alerts_queue_depth_max),
             window_s=max(for_s, 10.0), agg="avg", for_s=for_s,
         ),
-        # KV saturation: occupied/total slot ratio across engines.
+        # KV saturation: occupied/total page ratio across engines.
         Rule(
             name="serve_kv_occupancy", kind="threshold",
-            metric="rt_serve_kv_slots_occupied",
-            denominator="rt_serve_kv_slots_total", op=">",
+            metric="rt_serve_kv_pages_occupied",
+            denominator="rt_serve_kv_pages_total", op=">",
             threshold=float(config.alerts_kv_occupancy_frac),
             window_s=max(for_s, 10.0), agg="avg", for_s=for_s,
         ),

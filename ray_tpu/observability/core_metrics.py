@@ -213,20 +213,15 @@ def _build() -> dict:
             "tokens generated by the LLM engine",
             tag_keys=("deployment",),
         ),
-        "serve_kv_slots_occupied": Gauge(
-            "rt_serve_kv_slots_occupied",
-            "KV-cache slots currently holding an in-flight request, per "
-            "engine process",
-            tag_keys=("deployment", "node"),
-        ),
         "serve_queued_requests": Gauge(
             "rt_serve_queued_requests",
-            "requests waiting for a KV slot in this engine process",
+            "requests waiting for a decode row and KV pages in this "
+            "engine process",
             tag_keys=("deployment", "node"),
         ),
         "serve_batch_fill": Histogram(
             "rt_serve_batch_fill",
-            "occupied KV slots per continuous-batching decode round",
+            "live decode rows per continuous-batching decode round",
             boundaries=_BATCH_BOUNDS,
             tag_keys=("deployment",),
         ),
@@ -243,11 +238,9 @@ def _build() -> dict:
         ),
         # paged KV pool (serve/prefix_cache.PagedKVPool): one page pool
         # holds generation AND prefix KV; occupied counts pages pinned
-        # by live requests or resident as sealed prefix blocks. The
-        # paged engine ALSO publishes these numbers under the legacy
-        # rt_serve_kv_slots_{occupied,total} names (alias for one
-        # release) so the serve_kv_occupancy alert rule and older
-        # dashboards keep evaluating.
+        # by live requests or resident as sealed prefix blocks; total
+        # beside it so the occupancy RATIO is computable by the alert
+        # engine without knowing every deployment's pool size
         "serve_kv_pages_total": Gauge(
             "rt_serve_kv_pages_total",
             "KV page-pool capacity (pages) per engine process",
@@ -367,14 +360,6 @@ def _build() -> dict:
             "rt_task_stalls_total",
             "tasks flagged by the stall watchdog (ran past "
             "task_stall_dump_s without finishing)",
-        ),
-        # total KV capacity next to rt_serve_kv_slots_occupied so the
-        # occupancy RATIO is computable by the alert engine without
-        # knowing every deployment's max_batch_size
-        "serve_kv_slots_total": Gauge(
-            "rt_serve_kv_slots_total",
-            "KV-cache slot capacity (max_batch_size) per engine process",
-            tag_keys=("deployment", "node"),
         ),
         # -- serving control loop (serve/autoscale/) --
         "serve_shed": Counter(

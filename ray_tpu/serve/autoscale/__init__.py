@@ -4,7 +4,7 @@ PR 15 built the sensing half (metrics history, SLO burn-rate alerts,
 ``bench_serve.py``); this package closes the loop:
 
 - ``policy``: the SLO-driven autoscaling policy. ``SignalCollector``
-  reads windowed TTFT p95 / KV-slot occupancy / queue depth from the
+  reads windowed TTFT p95 / KV-page occupancy / queue depth from the
   head's metrics history plus the burn-rate alert state; ``SLOPolicy``
   turns those into replica-count decisions with hysteresis, cooldowns
   and min/max bounds. Consumed by ``serve/controller.py:_autoscale``.

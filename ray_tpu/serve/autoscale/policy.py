@@ -5,7 +5,7 @@ driven by the signals that actually predict SLO violation:
 
 - windowed TTFT p95 from the head's metrics history (the serving
   north-star, same series the burn-rate alert watches),
-- KV-slot occupancy (occupied/total) and queue-depth gauges,
+- KV-page occupancy (occupied/total) and queue-depth gauges,
 - the ``serve_ttft_p95_burn`` alert state itself — firing is a
   scale-up hint even when raw counts look tame.
 
@@ -314,25 +314,13 @@ class SignalCollector:
         window_s = float(config.serve_autoscale_window_s)
         model_ids = list(model_ids)
         ttft = self.hist_p95("rt_serve_ttft_s", name, model_ids, window_s)
-        # KV signal: page occupancy (paged engine) preferred — pages
-        # track actual KV bytes pinned, where slot occupancy saturated
-        # at "every slot holds a request" even with most rows unused.
-        # Slot gauges remain the fallback for RT_SERVE_PAGED_KV=0
-        # engines (the paged engine also aliases its page numbers onto
-        # the slot names for one release, so either branch works).
+        # KV signal: page occupancy — pages track the KV bytes pinned
         occupied = self.gauge_avg(
             "rt_serve_kv_pages_occupied", name, model_ids, window_s
         )
         total = self.gauge_avg(
             "rt_serve_kv_pages_total", name, model_ids, window_s
         )
-        if occupied is None or not total:
-            occupied = self.gauge_avg(
-                "rt_serve_kv_slots_occupied", name, model_ids, window_s
-            )
-            total = self.gauge_avg(
-                "rt_serve_kv_slots_total", name, model_ids, window_s
-            )
         occupancy = None
         if occupied is not None and total:
             occupancy = occupied / total

@@ -24,7 +24,7 @@ Handlers are plain callables (run in the pool, NOT on the loop):
 A client disconnect mid-stream CLOSES the handler's generator (on the
 pool), so producers can release held resources — the serve LLM path
 relies on this to cancel the replica-side stream and free its engine
-KV slot.
+KV pages.
 
 Fast path: an optional ``fast_handler`` runs ON THE EVENT LOOP before
 the pool dispatch. It must never block; it returns None (take the pool
@@ -264,7 +264,7 @@ class AioHttpServer:
         The (blocking) generator advances on the pool, the writes on the
         loop. Returns False when the client disconnected mid-stream —
         the generator is CLOSED either way (its finally blocks release
-        producer resources, e.g. the LLM engine's KV slot)."""
+        producer resources, e.g. the LLM engine's KV pages)."""
         writer.write(
             b"HTTP/1.1 %d %s\r\n"
             b"Content-Type: %s\r\n"

@@ -54,13 +54,9 @@ class PrefillEngine:
     checkpoint), so at temperature=0 the first token and KV rows are
     exactly what the monolithic engine would have produced.
 
-    With the paged pool on (RT_SERVE_PAGED_KV, the engine default) the
-    prefill tier runs on the SAME PagedKVPool + paged kernels as the
-    decode engine — prefix KV and working KV live in one device pool
-    and the shipment is a gather of the sequence's pages, eliminating
-    the third KV representation disagg used to maintain (slot row +
-    host BlockPool + wire tensors). The slot/BlockPool path survives
-    behind the kill switch."""
+    The prefill tier runs on the SAME PagedKVPool + paged kernels as
+    the decode engine — prefix KV and working KV live in one device
+    pool and the shipment is a gather of the sequence's pages."""
 
     def __init__(self, cfg) -> None:
         import jax
@@ -79,34 +75,25 @@ class PrefillEngine:
         else:
             self.params = gpt2.init(jax.random.PRNGKey(0), self.model_cfg)
         self._rng = jax.random.PRNGKey(1)
-        self._paged = (
-            bool(cfg.paged_kv)
-            if getattr(cfg, "paged_kv", None) is not None
-            else bool(config.serve_paged_kv)
+        B = int(config.serve_prefix_block_tokens)
+        max_pages = -(-self.model_cfg.n_positions // B)
+        # resident-prefix capacity (serve_prefix_pool_blocks), plus one
+        # full working reservation (+ the scratch page 0), so alloc can
+        # always cover a prompt by evicting LRU residents
+        self._pool = prefix_cache.PagedKVPool(
+            cfg.model_id,
+            num_pages=int(config.serve_prefix_pool_blocks) + max_pages + 1,
+            page_tokens=B,
         )
-        if self._paged:
-            B = int(config.serve_prefix_block_tokens)
-            max_pages = -(-self.model_cfg.n_positions // B)
-            # resident-prefix capacity matching BlockPool's budget, plus
-            # one full working reservation (+ the scratch page 0), so
-            # alloc can always cover a prompt by evicting LRU residents
-            self._pool = prefix_cache.PagedKVPool(
-                cfg.model_id,
-                num_pages=(
-                    int(config.serve_prefix_pool_blocks) + max_pages + 1
-                ),
-                page_tokens=B,
-            )
-        else:
-            self._pool = prefix_cache.BlockPool(cfg.model_id)
         self._lock = threading.Lock()
-        # slot path: [L, 1, T, H, Dh]; paged path: [L, N, B, H, Dh]
-        self._cache_k = self._cache_v = None  # lazy
+        self._cache_k = self._cache_v = None  # [L, N, B, H, Dh], lazy
 
     def prefill(self, prompt_tokens: List[int],
                 temperature: float) -> Dict[str, Any]:
-        """Run (prefix-cache-aware) prefill of the prompt into the
-        engine's single KV row, sample the first token, and return the
+        """Prefix-cache-aware prefill of the prompt: match resident
+        prefix pages (refcount bump, zero copies), prefill only the tail
+        into freshly allocated pages, seal the new full blocks, sample
+        the first token, and gather the sequence's pages into the host
         shipment dict the decode engine's ``kv_import`` path expects."""
         import jax.numpy as jnp
         import numpy as np
@@ -118,6 +105,9 @@ class PrefillEngine:
         mcfg = self.model_cfg
         T_max = mcfg.n_positions
         prompt = list(prompt_tokens)[-(T_max - 1):] or [0]
+        pool = self._pool
+        B = pool.page_tokens
+        max_pages = -(-T_max // B)
 
         def bucket(n: int, cap: int) -> int:
             p = 16
@@ -125,101 +115,6 @@ class PrefillEngine:
                 p *= 2
             return min(p, cap)
 
-        if self._paged:
-            return self._prefill_paged(prompt, temperature, bucket)
-
-        with self._lock:
-            if self._cache_k is None:
-                self._cache_k, self._cache_v = dec.init_cache(mcfg, 1, T_max)
-            pool = self._pool if config.serve_prefix_cache else None
-            held: List[str] = []
-            digests: List[str] = []
-            cached = 0
-            try:
-                if pool is not None:
-                    digests = prefix_cache.hash_blocks(
-                        prompt, pool.block_tokens
-                    )
-                    held, ks, vs = pool.match(
-                        digests, max_tokens=len(prompt) - 1
-                    )
-                    cached = len(held) * pool.block_tokens
-                slot = jnp.int32(0)
-                if cached:
-                    self._cache_k, self._cache_v = dec.write_prefix(
-                        jnp.asarray(np.concatenate(ks, axis=1)),
-                        jnp.asarray(np.concatenate(vs, axis=1)),
-                        self._cache_k, self._cache_v, slot,
-                    )
-                    tail = prompt[cached:]
-                    tok = np.zeros(
-                        (1, bucket(len(tail), T_max - cached)), np.int32
-                    )
-                    tok[0, : len(tail)] = tail
-                    logits, self._cache_k, self._cache_v = dec.prefill_extend(
-                        mcfg, self.params, jnp.asarray(tok),
-                        jnp.int32(cached), jnp.int32(len(tail)),
-                        self._cache_k, self._cache_v, slot,
-                    )
-                else:
-                    tok = np.zeros((1, bucket(len(prompt), T_max)), np.int32)
-                    tok[0, : len(prompt)] = prompt
-                    logits, self._cache_k, self._cache_v = dec.prefill(
-                        mcfg, self.params, jnp.asarray(tok),
-                        jnp.int32(len(prompt)), self._cache_k, self._cache_v,
-                        slot,
-                    )
-                first = self._sample_one(logits, temperature)
-                # host copy of the freshly-filled row; the shipment (and
-                # the pool blocks) slice it
-                row_k = np.asarray(self._cache_k[:, 0])
-                row_v = np.asarray(self._cache_v[:, 0])
-                if pool is not None and len(digests) > len(held):
-                    B = pool.block_tokens
-                    for j in range(len(held), len(digests)):
-                        pool.insert(
-                            digests[j],
-                            row_k[:, j * B:(j + 1) * B].copy(),
-                            row_v[:, j * B:(j + 1) * B].copy(),
-                        )
-                    held = list(digests)
-            except Exception:
-                # prefill/write donate the caches: a post-dispatch error
-                # leaves them deleted — rebuild lazily next call
-                self._cache_k = self._cache_v = None
-                raise
-            finally:
-                if pool is not None and held:
-                    pool.release(held)
-        n = len(prompt)
-        return {
-            "k": np.ascontiguousarray(row_k[:, :n]),
-            "v": np.ascontiguousarray(row_v[:, :n]),
-            "first_token": first,
-            "prompt_len": n,
-            "cached_tokens": cached,
-        }
-
-    def _prefill_paged(self, prompt: List[int], temperature: float,
-                       bucket) -> Dict[str, Any]:
-        """Paged-pool prefill: match resident prefix pages (refcount
-        bump, zero copies), prefill only the tail into freshly
-        allocated pages, seal the new full blocks, and gather the
-        sequence's pages into the host shipment. Wire format is
-        IDENTICAL to the slot path — the decode side never knows which
-        engine produced the rows."""
-        import jax.numpy as jnp
-        import numpy as np
-
-        from ray_tpu.models import gpt2_decode as dec
-        from ray_tpu.serve import prefix_cache
-        from ray_tpu.utils.config import config
-
-        mcfg = self.model_cfg
-        T_max = mcfg.n_positions
-        pool = self._pool
-        B = pool.page_tokens
-        max_pages = -(-T_max // B)
         with self._lock:
             if self._cache_k is None:
                 self._cache_k, self._cache_v = dec.init_paged_cache(

@@ -4,12 +4,12 @@ Parity: the reference serve.llm stack (python/ray/serve/llm — deployment
 + engine wrapper + OpenAI-ish request shape) whose engine tier is vLLM
 (/root/reference/python/ray/llm/_internal/serve/engines/vllm/). Here the
 engine is native JAX (models/gpt2_decode.py): a prefill/decode split
-over a slot-based static-shape KV cache with CONTINUOUS BATCHING — new
-requests are admitted into free slots between decode steps, so a long
-generation never blocks short ones and every decode step runs all
-occupied slots in one jitted call. Generating N tokens costs N
-single-token forwards over cached K/V, not N full-prefix recomputes
-(the round-3 engine's O(N·T·model) flaw).
+over one paged, static-shape KV pool with CONTINUOUS BATCHING — new
+requests are admitted into free decode rows between decode steps as
+long as the pool has pages for them, so a long generation never blocks
+short ones and every decode step runs all live rows in one jitted
+call. Generating N tokens costs N single-token forwards over cached
+K/V, not N full-prefix recomputes.
 
 Token-level API (this image has no tokenizer vocab files): requests are
 {"prompt_tokens": [int], "max_new_tokens": N, "temperature": T};
@@ -36,36 +36,42 @@ class LLMConfig:
         model_id: str = "gpt2-tiny",
         num_replicas: int = 1,
         max_batch_size: int = 8,
-        batch_wait_timeout_s: float = 0.02,
         max_new_tokens_cap: int = 256,
         checkpoint_path: Optional[str] = None,
         route_prefix: Optional[str] = "/llm",
         max_concurrency: int = 16,
-        engine: str = "kv",  # "kv" (cached decode) | "recompute" (legacy)
-        paged_kv: Optional[bool] = None,  # None = RT_SERVE_PAGED_KV
-        async_decode: Optional[bool] = None,  # None = RT_SERVE_ASYNC_DECODE
+        engine: str = "kv",
+        paged_kv: Optional[bool] = None,
+        async_decode: Optional[bool] = None,
     ):
         self.model_id = model_id
         self.num_replicas = num_replicas
         self.max_batch_size = max_batch_size
-        self.batch_wait_timeout_s = batch_wait_timeout_s
         self.max_new_tokens_cap = max_new_tokens_cap
         self.checkpoint_path = checkpoint_path
         self.route_prefix = route_prefix
         self.max_concurrency = max_concurrency
-        if engine not in ("kv", "recompute"):
-            raise ValueError(f"unknown engine {engine!r}")
-        self.engine = engine
-        # Paged KV pool vs legacy slot cache for the kv engine. An
-        # explicit bool here overrides the RT_SERVE_PAGED_KV env flag —
-        # the config field travels in the pickled deployment spec, so
-        # bench_serve's interleaved A/B arms can pick their engine
-        # without touching replica-process environments.
-        self.paged_kv = paged_kv
-        # Async decode pipeline (one-step lookahead): an explicit bool
-        # overrides RT_SERVE_ASYNC_DECODE the same way, so bench_serve's
-        # asyncdecode leg can A/B it per arm through the pickled spec.
-        self.async_decode = async_decode
+        # The last three keywords select nothing: there is one engine,
+        # the paged asynchronous one. They are still accepted because
+        # the benchmark's configuration files pass them (ROADMAP D11(o)
+        # drops them there, then here), and a value that asks for a
+        # path that is gone is refused by name rather than ignored.
+        if engine != "kv":
+            raise ValueError(
+                f"engine={engine!r}: the only engine is 'kv', the paged "
+                f"KV engine (the 'recompute' engine was removed in PR 31)"
+            )
+        if paged_kv is not None and not paged_kv:
+            raise ValueError(
+                "paged_kv=False: the slot KV engine was removed (PR 31); "
+                "the only engine keeps its KV in the page pool"
+            )
+        if async_decode is not None and not async_decode:
+            raise ValueError(
+                "async_decode=False: the synchronous decode loop was "
+                "removed (PR 31); the engine always dispatches one chunk "
+                "ahead of its harvest"
+            )
 
 
 class _Request:
@@ -95,7 +101,7 @@ class _Request:
         self.t_refused: Optional[float] = None
         self.t0_us = 0
         # set when the consumer abandoned the request (client disconnect
-        # mid-stream): the engine frees the KV slot at the next round
+        # mid-stream): the engine frees its KV pages at the next round
         # instead of decoding to max_new for nobody
         self.cancelled = False
         # streaming consumers read tokens here as the engine produces
@@ -174,37 +180,28 @@ class _PagedSeq:
         self.budget_left = 0
 
 
-class _Slot:
-    """One occupied KV-cache row: the request it serves + its cursor."""
+def _upload(*host_arrays):
+    """Device copies of the engine's long-lived host mirrors. On the CPU
+    backend ``jnp.asarray`` of a 64-byte-aligned NumPy array SHARES its
+    memory, and the loop writes those mirrors again (``retire()`` zeroes
+    a row at dispatch) before the dispatched program has read them; a
+    TPU copies on transfer, so only the copy here differs by platform."""
+    import jax.numpy as jnp
 
-    __slots__ = ("req", "length", "produced", "last_token", "t_last",
-                 "pool", "pool_refs", "cached", "ttft_us", "budget_left")
-
-    def __init__(self, req: _Request, length: int, first_token: int):
-        self.req = req
-        self.length = length          # tokens currently in the cache row
-        self.produced = [first_token]
-        self.last_token = first_token
-        self.t_last: Optional[float] = None  # last token delivery stamp
-        # prefix-cache bookkeeping: block refs this slot holds in the
-        # engine's BlockPool (released when the request leaves the slot),
-        # and whether admission skipped any prefill work (cache hit or
-        # disaggregated KV import) — tags the engine span's TTFT split
-        self.pool = None
-        self.pool_refs: List[str] = []
-        self.cached = False
-        self.ttft_us = 0
-        self.budget_left = 0  # see _PagedSeq.budget_left
+    return tuple(jnp.array(a) for a in host_arrays)
 
 
 class LLMServer:
-    """The deployment callable: continuous-batched KV-cached decode."""
+    """The deployment callable: continuous-batched decode over one paged
+    KV pool, one chunk dispatched ahead of its harvest."""
 
     def __init__(self, config: LLMConfig):
         import jax
 
         from ray_tpu.accelerators.tpu import require_leased_platform
         from ray_tpu.models import gpt2
+        from ray_tpu.serve import prefix_cache
+        from ray_tpu.utils.config import config as rtcfg
 
         require_leased_platform()
         t_load = time.monotonic()
@@ -225,54 +222,23 @@ class LLMServer:
         self._batch_sizes = collections.deque(maxlen=1000)
         self._total_batches = 0
         self._max_batch_seen = 0
-        self._occupied = 0  # KV slots held after the last engine round
+        self._occupied = 0  # decode rows live after the last engine round
         # per-process gauge label (the cluster merge keeps the latest
         # value PER SERIES; distinct tags keep every engine process)
         self._node_tag = f"pid{os.getpid()}"
         self._stop = threading.Event()
-        if config.engine == "kv":
-            from ray_tpu.serve import prefix_cache
-            from ray_tpu.utils.config import config as rtcfg
-
-            self._paged = (
-                bool(config.paged_kv) if config.paged_kv is not None
-                else bool(rtcfg.serve_paged_kv)
-            )
-            # one-step lookahead pipeline; RT_SERVE_ASYNC_DECODE=0 (or
-            # async_decode=False in the spec) restores the synchronous
-            # dispatch->harvest loop
-            self._async_decode = (
-                bool(config.async_decode)
-                if config.async_decode is not None
-                else bool(rtcfg.serve_async_decode)
-            )
-            if self._paged:
-                # ONE page pool holds generation and prefix KV. Default
-                # size is MATCHED MEMORY with the slot engine: the slot
-                # cache is [L, S, T_max, H, Dh]; S*ceil(T_max/B) pages
-                # of B tokens hold the same element count (+1 reserved
-                # scratch page that inactive rows scatter into).
-                B = int(rtcfg.serve_prefix_block_tokens)
-                max_pages = -(-self.model_cfg.n_positions // B)
-                pool_pages = int(rtcfg.serve_kv_pool_pages) or (
-                    config.max_batch_size * max_pages
-                )
-                self._prefix_pool = prefix_cache.PagedKVPool(
-                    config.model_id, num_pages=pool_pages + 1,
-                    page_tokens=B,
-                )
-                target = self._engine_loop_paged
-            else:
-                # legacy slot engine (RT_SERVE_PAGED_KV=0 kill switch):
-                # block pool always exists for a kv engine; the
-                # RT_SERVE_PREFIX_CACHE kill switch is checked per
-                # admission so it doubles as a runtime A/B lever
-                self._prefix_pool = prefix_cache.BlockPool(config.model_id)
-                target = self._engine_loop_kv
-        else:
-            self._paged = False
-            self._prefix_pool = None
-            target = self._engine_loop_recompute
+        # ONE page pool holds generation and prefix KV. Default size:
+        # max_batch_size full-length sequences, S*ceil(T_max/B) pages of
+        # B tokens (+1 reserved scratch page that inactive rows scatter
+        # into).
+        B = int(rtcfg.serve_prefix_block_tokens)
+        max_pages = -(-self.model_cfg.n_positions // B)
+        pool_pages = int(rtcfg.serve_kv_pool_pages) or (
+            config.max_batch_size * max_pages
+        )
+        self._prefix_pool = prefix_cache.PagedKVPool(
+            config.model_id, num_pages=pool_pages + 1, page_tokens=B,
+        )
         # start-up hand-off: the engine thread allocates its KV pool,
         # reports where it landed and only then takes requests; a
         # failure on the way (out of device memory, no such platform)
@@ -281,8 +247,7 @@ class LLMServer:
         self._start_error: Optional[BaseException] = None
         self._devices: List[Dict[str, Any]] = []
         threading.Thread(
-            target=self._run_engine, args=(target,), name="llm-engine",
-            daemon=True,
+            target=self._run_engine, name="llm-engine", daemon=True,
         ).start()
         self._started.wait()
         if self._start_error is not None:
@@ -292,16 +257,16 @@ class LLMServer:
             ) from self._start_error
         self._load_s = round(time.monotonic() - t_load, 2)
 
-    def _run_engine(self, loop) -> None:
+    def _run_engine(self) -> None:
         try:
-            loop()
+            self._engine_loop_paged()
         except BaseException as e:  # noqa: BLE001 — reported to __init__
             self._start_error = e
             self._started.set()
             raise
 
     def _engine_started(self, *pool) -> None:
-        """Called by an engine loop once its allocations exist, before
+        """Called by the engine loop once its allocations exist, before
         its first round: waits for them (device allocation is
         asynchronous), records the devices holding the parameters and
         the KV pool, and releases __init__."""
@@ -353,10 +318,6 @@ class LLMServer:
 
     def __call__(self, request: Any):
         req = self._parse(request)
-        if req.token_q is not None and self.cfg.engine != "kv":
-            # validate BEFORE enqueue: the engine would otherwise decode a
-            # request whose caller already got the ValueError
-            raise ValueError("stream=True requires the kv engine")
         if core_metrics.ENABLED or tracing.ENABLED:
             req.t_enqueue = time.monotonic()
             if tracing.ENABLED and req.trace_id:
@@ -379,7 +340,7 @@ class LLMServer:
         decoded token as its step completes; parity: vLLM's streaming
         generate in the reference's serve.llm engine). Closing the
         generator before exhaustion — the client disconnected — cancels
-        the request so the engine frees its KV slot."""
+        the request so the engine frees its KV pages."""
         import queue as queue_mod
 
         produced = 0
@@ -400,7 +361,7 @@ class LLMServer:
         finally:
             if not done:
                 req.cancelled = True
-                self._work.set()  # wake the engine to reap the slot
+                self._work.set()  # wake the engine to reap the row
 
     def batch_stats(self, _payload=None) -> Dict[str, Any]:
         with self._lock:
@@ -417,12 +378,11 @@ class LLMServer:
             # any request compiled anything)
             "devices": self._devices,
             "load_s": self._load_s,
-            # what the paged decode programs attend over: the page pool
-            # itself under an ownership mask (gpt2_decode), no row gather
-            "decode_attention": "pool" if self._paged else None,
-            "prefix": (
-                self._prefix_pool.stats() if self._prefix_pool else None
-            ),
+            # what the decode programs attend over: the page pool itself
+            # under an ownership mask (gpt2_decode), no row gather. Kept
+            # so a reader of two trees' numbers can tell which body ran.
+            "decode_attention": "pool",
+            "prefix": self._prefix_pool.stats(),
         }
 
     def unload(self) -> None:
@@ -440,11 +400,10 @@ class LLMServer:
             if req is None:
                 break
             self._fail_request(req, err)
-        # the prefix-block pool dies with the engine: close() drops every
-        # resident block regardless of refcounts (in-flight slots fail in
-        # the loop's exit path; their refs would otherwise strand blocks)
-        if self._prefix_pool is not None:
-            self._prefix_pool.close()
+        # the page pool dies with the engine: close() drops every
+        # resident page regardless of refcounts (in-flight sequences fail
+        # in the loop's exit path; their pins would otherwise strand pages)
+        self._prefix_pool.close()
 
     @staticmethod
     def _fail_request(req: "_Request", err: BaseException) -> None:
@@ -453,457 +412,7 @@ class LLMServer:
         if req.token_q is not None:
             req.token_q.put(None)
 
-    def _record_step(self, occupancy: int) -> None:
-        with self._lock:
-            self._batch_sizes.append(occupancy)
-            self._total_batches += 1
-            self._max_batch_seen = max(self._max_batch_seen, occupancy)
-            queued = len(self._queue)
-        if core_metrics.ENABLED:
-            dep = self.cfg.model_id
-            core_metrics.serve_batch_fill.observe(
-                occupancy, tags={"deployment": dep}
-            )
-            ntags = {"deployment": dep, "node": self._node_tag}
-            core_metrics.serve_kv_slots_occupied.set(occupancy, tags=ntags)
-            core_metrics.serve_kv_slots_total.set(
-                self.cfg.max_batch_size, tags=ntags
-            )
-            core_metrics.serve_queued_requests.set(queued, tags=ntags)
-
-    # -- KV engine (continuous batching over cache slots) ---------------
-
-    def _engine_loop_kv(self) -> None:
-        import jax
-        import jax.numpy as jnp
-        import numpy as np
-
-        from ray_tpu.models import gpt2_decode as dec
-        from ray_tpu.serve import prefix_cache
-        from ray_tpu.utils.config import config
-
-        mcfg = self.model_cfg
-        S = self.cfg.max_batch_size
-        T_max = mcfg.n_positions
-        cache_k, cache_v = dec.init_cache(mcfg, S, T_max)
-        slots: List[Optional[_Slot]] = [None] * S
-        last = np.zeros((S,), np.int32)
-        lengths = np.zeros((S,), np.int32)
-        temps = np.zeros((S,), np.float32)
-        greedy = np.ones((S,), bool)
-        # device-resident copies of the step state: fully uploaded only
-        # at (re)build; admissions/retirements push JUST their rows via
-        # dec.update_rows, so steady-state churn never stalls the
-        # pipeline behind four host->device transfers
-        dev_state = None  # (last, lengths, temps, greedy) on device
-        dirty: set = set()  # rows whose host state must reach the device
-        rng_base = self._rng
-        step_no = 0
-        # async decode pipeline (RT_SERVE_ASYNC_DECODE): at most ONE
-        # dispatched-but-unharvested chunk; None in sync mode or when
-        # the pipeline is drained
-        async_mode = self._async_decode
-        inflight: Optional[_Chunk] = None
-
-        def _bucket(n: int, cap: int) -> int:
-            # next power of two: one compile per bucket, and a short
-            # prompt doesn't pay a full T_max-wide prefill
-            p = 16
-            while p < n:
-                p *= 2
-            return min(p, cap)
-
-        def admit(i: int, req: _Request) -> None:
-            nonlocal cache_k, cache_v
-            prompt = req.prompt[-(T_max - 1):]
-            pool = self._prefix_pool if config.serve_prefix_cache else None
-            held: List[str] = []
-            digests: List[str] = []
-            cached = 0
-            try:
-                if req.kv_import is not None:
-                    # disaggregated decode: the prefill deployment already
-                    # computed this prompt's KV rows and first token —
-                    # import them and skip prefill entirely
-                    imp = req.kv_import
-                    n = min(int(imp["prompt_len"]), T_max - 1)
-                    C = _bucket(n, T_max)
-                    L, H, Dh = mcfg.n_layer, mcfg.n_head, mcfg.head_dim
-                    pk = np.zeros((L, C, H, Dh), np.float32)
-                    pv = np.zeros((L, C, H, Dh), np.float32)
-                    pk[:, :n] = np.asarray(imp["k"])[:, :n]
-                    pv[:, :n] = np.asarray(imp["v"])[:, :n]
-                    cache_k, cache_v = dec.write_prefix(
-                        jnp.asarray(pk), jnp.asarray(pv),
-                        cache_k, cache_v, jnp.int32(i),
-                    )
-                    first = int(imp["first_token"])
-                    prompt_len = n
-                    cached = n
-                else:
-                    if pool is not None:
-                        digests = prefix_cache.hash_blocks(
-                            prompt, pool.block_tokens
-                        )
-                        # keep >=1 prompt token uncached: the tail
-                        # prefill produces the first-token logits
-                        held, ks, vs = pool.match(
-                            digests, max_tokens=len(prompt) - 1
-                        )
-                        cached = len(held) * pool.block_tokens
-                    if cached:
-                        cache_k, cache_v = dec.write_prefix(
-                            jnp.asarray(np.concatenate(ks, axis=1)),
-                            jnp.asarray(np.concatenate(vs, axis=1)),
-                            cache_k, cache_v, jnp.int32(i),
-                        )
-                        tail = prompt[cached:]
-                        tok = np.zeros(
-                            (1, _bucket(len(tail), T_max - cached)), np.int32
-                        )
-                        tok[0, : len(tail)] = tail
-                        logits, cache_k, cache_v = dec.prefill_extend(
-                            mcfg, self.params, jnp.asarray(tok),
-                            jnp.int32(cached), jnp.int32(len(tail)),
-                            cache_k, cache_v, jnp.int32(i),
-                        )
-                    else:
-                        tok = np.zeros(
-                            (1, _bucket(len(prompt), T_max)), np.int32
-                        )
-                        tok[0, : len(prompt)] = prompt
-                        logits, cache_k, cache_v = dec.prefill(
-                            mcfg, self.params, jnp.asarray(tok),
-                            jnp.int32(len(prompt)), cache_k, cache_v,
-                            jnp.int32(i),
-                        )
-                    first = int(self._sample_one(logits, req.temperature))
-                    prompt_len = len(prompt)
-                    if pool is not None and len(digests) > len(held):
-                        # park the blocks this request just prefilled for
-                        # the next shared-prefix request (host copies of
-                        # the slot's fresh K/V rows)
-                        row_k = np.asarray(cache_k[:, i])
-                        row_v = np.asarray(cache_v[:, i])
-                        B = pool.block_tokens
-                        for j in range(len(held), len(digests)):
-                            pool.insert(
-                                digests[j],
-                                row_k[:, j * B:(j + 1) * B].copy(),
-                                row_v[:, j * B:(j + 1) * B].copy(),
-                            )
-                        held = list(digests)
-            except Exception as e:  # noqa: BLE001
-                if pool is not None and held:
-                    pool.release(held)
-                req.error = e
-                req.event.set()
-                if req.token_q is not None:
-                    req.token_q.put(None)
-                # prefill donates the caches too: a post-dispatch failure
-                # here deleted them, so every slot's state is garbage —
-                # propagate so the outer handler fails in-flight requests
-                # and marks the caches for rebuild (this request's error
-                # is already set; fail_inflight won't see it in slots)
-                raise
-            slot = _Slot(req, prompt_len, first)
-            slot.pool = pool
-            slot.pool_refs = held
-            slot.cached = cached > 0
-            slots[i] = slot
-            if tracing.ENABLED and req.t0_us:
-                slot.ttft_us = tracing.now_us() - req.t0_us
-            if core_metrics.ENABLED:
-                now = time.monotonic()
-                slot.t_last = now
-                dep_tags = {"deployment": self.cfg.model_id}
-                if req.t_enqueue is not None:
-                    core_metrics.serve_ttft_s.observe(
-                        now - req.t_enqueue, tags=dep_tags
-                    )
-                core_metrics.serve_tokens_generated.inc(tags=dep_tags)
-            if req.token_q is not None and req.max_new >= 1:
-                # zero-token completions must not leak the sampled-but-
-                # unrequested first token into the stream
-                req.token_q.put(first)
-            slot.budget_left = min(req.max_new - 1, T_max - 1 - prompt_len)
-            last[i] = first
-            lengths[i] = prompt_len
-            temps[i] = max(req.temperature, 1e-6)
-            greedy[i] = req.temperature <= 0
-            dirty.add(i)
-
-        def release_refs(s: _Slot) -> None:
-            # the request is leaving its slot: drop its prefix-block refs
-            # (blocks stay resident, just become LRU-evictable)
-            if s.pool is not None and s.pool_refs:
-                s.pool.release(s.pool_refs)
-                s.pool_refs = []
-
-        def retire(i: int) -> None:
-            """Row i leaves the decode batch: zero its host state so the
-            next dispatch's incremental row push parks it on junk-safe
-            values (length 0 => the junk token scatters at position 0 of
-            a free row, overwritten by the next admission's prefill —
-            which the device executes after any in-flight chunk)."""
-            s = slots[i]
-            slots[i] = None
-            release_refs(s)
-            last[i] = 0
-            lengths[i] = 0
-            temps[i] = 1e-6
-            greedy[i] = True
-            dirty.add(i)
-
-        def complete(s: _Slot) -> None:
-            s.req.result = s.produced[: s.req.max_new]
-            if tracing.ENABLED and s.req.trace_id and s.req.t0_us:
-                tracing.emit(tracing.request_span(
-                    s.req.trace_id, tracing.ENGINE, self.cfg.model_id,
-                    s.req.t0_us, tracing.now_us() - s.req.t0_us,
-                    parent=tracing.REPLICA, tokens=len(s.req.result),
-                    cached=s.cached, ttft_us=s.ttft_us,
-                ))
-            s.req.event.set()
-            if s.req.token_q is not None:
-                s.req.token_q.put(None)  # end of stream
-
-        def finish(i: int) -> None:
-            s = slots[i]
-            retire(i)
-            complete(s)
-
-        def fail_inflight(e: BaseException) -> None:
-            # One poisoned round must not turn the replica into a black
-            # hole (the guard the legacy _batch_loop had): fail every
-            # occupied slot's request — including rows whose finish was
-            # scheduled at dispatch but whose chunk never harvested —
-            # and keep serving.
-            nonlocal inflight
-            for i in range(S):
-                if slots[i] is not None:
-                    s = slots[i]
-                    retire(i)
-                    self._fail_request(s.req, e)
-            if inflight is not None:
-                rec, inflight = inflight, None
-                for _i, s, fin in rec.rows:
-                    if fin:
-                        self._fail_request(s.req, e)
-
-        def harvest(rec: _Chunk) -> None:
-            """Materialize a dispatched chunk's tokens and run all its
-            host bookkeeping: fan-out, SSE queue puts, metric stamps,
-            completions. In async mode this executes while the NEXT
-            chunk (already dispatched) keeps the device busy —
-            np.asarray is the only sync point."""
-            toks = np.asarray(rec.toks_dev)
-            if toks.ndim == 1:
-                toks = toks[None]  # [1, S]
-            n_new = rec.n_steps
-            live = [r for r in rec.rows if r[0] not in rec.dropped]
-            if core_metrics.ENABLED:
-                # every live row receives exactly n_steps tokens (the
-                # chunk was bounded by the minimum remaining budget)
-                now = time.monotonic()
-                dep_tags = {"deployment": self.cfg.model_id}
-                core_metrics.serve_tokens_generated.inc(
-                    n_new * len(live), tags=dep_tags
-                )
-                for _i, s, _fin in live:
-                    if s.t_last is not None:
-                        core_metrics.serve_inter_token_s.observe(
-                            (now - s.t_last) / n_new, tags=dep_tags
-                        )
-                    s.t_last = now
-            for k in range(n_new):
-                for i, s, _fin in live:
-                    s.length += 1
-                    s.last_token = int(toks[k, i])
-                    s.produced.append(s.last_token)
-                    if (
-                        s.req.token_q is not None
-                        and not s.req.cancelled
-                        and len(s.produced) > 1  # first token sent at admit
-                        and len(s.produced) <= s.req.max_new
-                    ):
-                        s.req.token_q.put(s.last_token)
-            for i, s, fin in live:
-                if fin:
-                    complete(s)
-                elif slots[i] is s:
-                    # keep the host mirror accurate for full rebuilds
-                    last[i] = s.last_token
-                    lengths[i] = s.length
-
-        def dispatch(active: List[int], waiting: bool) -> _Chunk:
-            nonlocal cache_k, cache_v, dev_state, step_no
-            if dev_state is None:
-                dev_state = (
-                    jnp.asarray(last), jnp.asarray(lengths),
-                    jnp.asarray(temps), jnp.asarray(greedy),
-                )
-                dirty.clear()
-            elif dirty:
-                # incremental dev_state: scatter ONLY the changed rows
-                # (admits/retires) into the device-resident step state
-                # instead of re-uploading all four arrays
-                idx = np.asarray(sorted(dirty), np.int32)
-                d_last, d_len, d_temps, d_greedy = dev_state
-                dev_state = dec.update_rows(
-                    d_last, d_len, d_temps, d_greedy,
-                    jnp.asarray(idx), jnp.asarray(last[idx]),
-                    jnp.asarray(lengths[idx]), jnp.asarray(temps[idx]),
-                    jnp.asarray(greedy[idx]),
-                )
-                dirty.clear()
-            d_last, d_len, d_temps, d_greedy = dev_state
-            # Chunk size: as many tokens as every active slot still
-            # needs (bounded), but single-step whenever requests are
-            # waiting so admission latency stays one step.
-            K = 1
-            if not waiting:
-                K = max(1, min(8, min(
-                    slots[i].budget_left for i in active
-                )))
-            self._record_step(len(active))
-            if K > 1:
-                toks_dev, d_last2, d_len, cache_k, cache_v = (
-                    dec.decode_multi(
-                        mcfg, self.params, d_last, d_len, cache_k,
-                        cache_v, d_temps, d_greedy, rng_base, K, step_no,
-                    )
-                )
-                step_no += K
-                dev_state = (d_last2, d_len, d_temps, d_greedy)
-            else:
-                step_no += 1
-                toks_dev, d_len, cache_k, cache_v = dec.decode_and_sample(
-                    mcfg, self.params, d_last, d_len, cache_k, cache_v,
-                    d_temps, d_greedy, rng_base, step_no,
-                )
-                dev_state = (toks_dev, d_len, d_temps, d_greedy)
-            rec = _Chunk(toks_dev, K)
-            for i in active:
-                s = slots[i]
-                s.budget_left -= K
-                fin = s.budget_left <= 0
-                rec.rows.append((i, s, fin))
-                rec.by_row[i] = s
-                if fin:
-                    # deterministic finish (budgets, not token values,
-                    # end generations here): the row leaves the batch
-                    # AT DISPATCH so the next chunk never includes it
-                    # and its slot is immediately reusable; token
-                    # fan-out and completion happen at harvest
-                    retire(i)
-            return rec
-
-        def one_round() -> None:
-            """One continuous-batching round: reap/admit -> dispatch the
-            next chunk -> harvest the previous one (async lookahead) or
-            this one (sync)."""
-            nonlocal cache_k, cache_v, dev_state, inflight
-            if cache_k is None:  # rebuild after a poisoned (donated) round
-                cache_k, cache_v = dec.init_cache(mcfg, S, T_max)
-                dev_state = None
-                dirty.clear()
-            # consume the wake flag BEFORE the queue/cancel scans: a
-            # set() landing after the scans stays pending for the idle
-            # wait below, so an idle engine can never sleep through a
-            # request that arrived between scan and wait (the old
-            # wait-then-clear order could eat exactly that wakeup — up
-            # to 500 ms of TTFT on an idle engine)
-            self._work.clear()
-            # reap abandoned requests (client disconnected mid-stream):
-            # their KV rows go back to the free pool instead of decoding
-            # to max_new for nobody
-            for i in range(S):
-                s = slots[i]
-                if s is not None and s.req.cancelled:
-                    if (
-                        inflight is not None
-                        and inflight.by_row.get(i) is s
-                    ):
-                        # mid-lookahead cancel: the in-flight chunk's
-                        # tokens for this row drop at harvest
-                        inflight.dropped.add(i)
-                    retire(i)
-                    s.req.event.set()
-            # admit new requests into free slots (continuous batching)
-            admitted = False
-            for i in range(S):
-                if slots[i] is not None:
-                    continue
-                while True:
-                    with self._lock:
-                        req = self._queue.popleft() if self._queue else None
-                    if req is None or not req.cancelled:
-                        break
-                    req.event.set()  # cancelled while queued: never admit
-                if req is None:
-                    break
-                admit(i, req)
-                admitted = True
-            active = [i for i in range(S) if slots[i] is not None]
-            # single-token answers (or 0-token asks) finish immediately
-            for i in list(active):
-                s = slots[i]
-                if len(s.produced) >= s.req.max_new or s.length >= T_max - 1:
-                    finish(i)
-            active = [i for i in range(S) if slots[i] is not None]
-            self._occupied = len(active)
-            if not active:
-                if inflight is not None:
-                    # drain the lookahead before idling: its tokens are
-                    # real and its pending finishes must complete
-                    rec, inflight = inflight, None
-                    harvest(rec)
-                elif not admitted:
-                    self._work.wait(timeout=0.5)
-                return
-            with self._lock:
-                waiting = bool(self._queue)
-            rec = dispatch(active, waiting)
-            if async_mode:
-                # one-step lookahead: chunk N+1 is on the device; run
-                # chunk N's host bookkeeping underneath it
-                prev, inflight = inflight, rec
-                if prev is not None:
-                    harvest(prev)
-            else:
-                harvest(rec)
-
-        self._engine_started(cache_k, cache_v)
-        while not self._stop.is_set():
-            try:
-                one_round()
-            except Exception as e:  # noqa: BLE001 — engine must survive
-                import logging
-
-                logging.getLogger(__name__).exception(
-                    "kv engine round failed; failing in-flight requests"
-                )
-                fail_inflight(e)
-                dev_state = None
-                dirty.clear()
-                # prefill/decode donate the caches (donate_argnums): an
-                # exception raised after dispatch leaves cache_k/cache_v
-                # pointing at deleted buffers on TPU, so every later round
-                # would fail too — mark them for rebuild (done inside the
-                # next round's try so a failing rebuild — same OOM/device
-                # error — can't kill the engine thread)
-                cache_k = cache_v = None
-                time.sleep(0.05)  # don't hot-spin on a persistent fault
-        # stopped (unload): in-flight slots must fail NOW, not strand
-        # their callers until the 300s wait times out (unload() drains
-        # the queue; slots are this thread's to fail)
-        fail_inflight(
-            RuntimeError(f"engine {self.cfg.model_id!r} was unloaded")
-        )
-        self._occupied = 0
-
-    # -- paged KV engine (one refcounted page pool, chunked prefill) -----
+    # -- the engine (one refcounted page pool, chunked prefill) ---------
 
     def _record_step_paged(self, fill: int, pst: Dict[str, int]) -> None:
         with self._lock:
@@ -926,22 +435,13 @@ class LLMServer:
             core_metrics.serve_kv_pages_prefix_resident.set(
                 pst["prefix_resident"], tags=ntags
             )
-            # one-release aliases: page occupancy published under the
-            # slot-gauge names keeps the serve_kv_occupancy alert rule
-            # and pre-paged dashboards evaluating unchanged
-            core_metrics.serve_kv_slots_occupied.set(
-                pst["pages_occupied"], tags=ntags
-            )
-            core_metrics.serve_kv_slots_total.set(
-                pst["pages_total"], tags=ntags
-            )
             core_metrics.serve_queued_requests.set(queued, tags=ntags)
 
     def _engine_loop_paged(self) -> None:
         """Continuous batching over ONE paged KV pool: generation and
         prefix pages coexist, a prefix hit is a refcount bump (zero
         copies), admission is page-granular (free pages, not free
-        slots), and long prompts prefill in RT_SERVE_PREFILL_CHUNK_TOKENS
+        rows), and long prompts prefill in RT_SERVE_PREFILL_CHUNK_TOKENS
         chunks interleaved with decode so in-flight streams keep a
         bounded ITL."""
         import jax
@@ -959,8 +459,8 @@ class LLMServer:
         max_pages = -(-T_max // B)  # page-table width per sequence
         n_phys = pool.num_pages
         # decode rows: page-granular admission packs more short
-        # sequences than the slot engine had slots, bounded by the pool
-        # itself (every live sequence pins >= 1 page)
+        # sequences than max_batch_size full-length ones, bounded by the
+        # pool itself (every live sequence pins >= 1 page)
         S = int(config.serve_paged_max_seqs) or min(
             pool.num_pages - 1, 4 * self.cfg.max_batch_size
         )
@@ -979,10 +479,8 @@ class LLMServer:
         dirty: set = set()  # rows whose host state must reach the device
         rng_base = self._rng
         step_no = 0
-        # async decode pipeline (RT_SERVE_ASYNC_DECODE): at most ONE
-        # dispatched-but-unharvested chunk; None in sync mode or when
-        # the pipeline is drained
-        async_mode = self._async_decode
+        # the decode pipeline: at most ONE dispatched-but-unharvested
+        # chunk; None when the pipeline is drained
         inflight: Optional[_Chunk] = None
         dep_tags = {"deployment": self.cfg.model_id}
         # seconds of the current round this thread spent blocked on the
@@ -1146,6 +644,8 @@ class LLMServer:
             s.n_hit = len(hit_pages)
             s.cached_tokens = len(hit_pages) * B
             s.prefill_pos = s.cached_tokens
+            # never written after this line: run_prefill hands it to the
+            # device as it is (see _upload for what a shared write does)
             row = np.zeros((max_pages,), np.int32)
             row[: len(s.pages)] = s.pages
             s.table = row
@@ -1298,9 +798,9 @@ class LLMServer:
         def harvest(rec: _Chunk) -> None:
             """Materialize a dispatched chunk's tokens and run all its
             host bookkeeping: fan-out, SSE queue puts, metric stamps,
-            completions, deferred page frees. In async mode this
-            executes while the NEXT chunk (already dispatched) keeps
-            the device busy — np.asarray is the only sync point."""
+            completions, deferred page frees. This executes while the
+            NEXT chunk (already dispatched) keeps the device busy —
+            np.asarray is the only sync point."""
             toks = sync("rt/engine/harvest_sync", np.asarray, rec.toks_dev)
             with tracing.span("rt/engine/harvest"):
                 deliver(rec, toks)
@@ -1356,16 +856,13 @@ class LLMServer:
         def dispatch(active: List[int], K: int) -> _Chunk:
             nonlocal cache_k, cache_v, dev_state, step_no
             if dev_state is None:
-                dev_state = (
-                    jnp.asarray(last), jnp.asarray(lengths),
-                    jnp.asarray(temps), jnp.asarray(greedy),
-                    jnp.asarray(tables),
-                )
+                dev_state = _upload(last, lengths, temps, greedy, tables)
                 dirty.clear()
             elif dirty:
                 # incremental dev_state: scatter ONLY the changed rows
                 # (admits/retires) into the device-resident step state
-                # instead of re-uploading all five arrays
+                # instead of re-uploading all five arrays (a fancy index
+                # is a fresh array: nothing below shares a mirror)
                 idx = np.asarray(sorted(dirty), np.int32)
                 d_last, d_len, d_temps, d_greedy, d_tables = dev_state
                 dev_state = dec.update_rows_paged(
@@ -1462,8 +959,7 @@ class LLMServer:
             if cache_k is None:
                 # rebuild after a poisoned (donated) round. The pool's
                 # sealed pages pointed into the deleted cache, so ALL
-                # pool metadata resets with it (the BlockPool kept host
-                # copies and could survive this; the page pool cannot)
+                # pool metadata resets with it
                 cache_k, cache_v = dec.init_paged_cache(mcfg, n_phys, B)
                 pool.reset()
                 dev_state = None
@@ -1519,14 +1015,11 @@ class LLMServer:
                 )))
             with tracing.span("rt/engine/dispatch", k=K, rows=len(active)):
                 rec = dispatch(active, K)
-            if async_mode:
-                # one-step lookahead: chunk N+1 is on the device; run
-                # chunk N's host bookkeeping underneath it
-                prev, inflight = inflight, rec
-                if prev is not None:
-                    harvest(prev)
-            else:
-                harvest(rec)
+            # one-step lookahead: chunk N+1 is on the device; run chunk
+            # N's host bookkeeping underneath it
+            prev, inflight = inflight, rec
+            if prev is not None:
+                harvest(prev)
             return True
 
         def one_round() -> None:
@@ -1590,96 +1083,6 @@ class LLMServer:
             return int(jnp.argmax(logits))
         self._rng, sub = jax.random.split(self._rng)
         return int(jax.random.categorical(sub, logits / temperature))
-
-    # -- legacy engine (full-prefix recompute; kept for comparison) ------
-
-    def _engine_loop_recompute(self) -> None:
-        import jax
-        import jax.numpy as jnp
-
-        from ray_tpu.models import gpt2
-
-        mcfg = self.model_cfg
-
-        def next_logits(params, tokens, lengths):
-            logits = gpt2.forward(params, tokens, mcfg)
-            idx = jnp.maximum(lengths - 1, 0)
-            lastl = jnp.take_along_axis(
-                logits, idx[:, None, None], axis=1
-            )[:, 0, :]
-            return lastl[:, : mcfg.vocab_size]
-
-        next_logits = jax.jit(next_logits)
-        self._engine_started()
-        while not self._stop.is_set():
-            batch = self._take_batch()
-            if not batch:
-                continue
-            try:
-                self._generate_recompute(batch, next_logits)
-            except Exception as e:  # noqa: BLE001 — fail this batch only
-                for r in batch:
-                    r.error = e
-                    r.event.set()
-
-    def _take_batch(self) -> List[_Request]:
-        deadline = time.monotonic() + self.cfg.batch_wait_timeout_s
-        while not self._stop.is_set():
-            with self._lock:
-                if len(self._queue) >= self.cfg.max_batch_size or (
-                    self._queue and time.monotonic() >= deadline
-                ):
-                    batch = []
-                    while self._queue and len(batch) < self.cfg.max_batch_size:
-                        batch.append(self._queue.popleft())
-                    return batch
-                if not self._queue:
-                    deadline = time.monotonic() + self.cfg.batch_wait_timeout_s
-            time.sleep(0.002)
-        return []
-
-    def _generate_recompute(self, batch: List[_Request], next_logits) -> None:
-        import jax
-        import jax.numpy as jnp
-        import numpy as np
-
-        self._record_step(len(batch))
-        B = len(batch)
-        max_new = max(r.max_new for r in batch)
-        max_prompt = max(len(r.prompt) for r in batch)
-        total = min(max_prompt + max_new, self.model_cfg.n_positions)
-        tokens = np.zeros((B, total), np.int32)
-        lengths = np.zeros((B,), np.int32)
-        for i, r in enumerate(batch):
-            p = r.prompt[-self.model_cfg.n_positions:]
-            tokens[i, : len(p)] = p
-            lengths[i] = len(p)
-        tokens = jnp.asarray(tokens)
-        lengths = jnp.asarray(lengths)
-        outs: List[List[int]] = [[] for _ in range(B)]
-        for _ in range(max_new):
-            logits = next_logits(self.params, tokens, lengths)
-            greedy = jnp.argmax(logits, axis=-1)
-            self._rng, sub = jax.random.split(self._rng)
-            temps = jnp.asarray(
-                [max(r.temperature, 1e-6) for r in batch], jnp.float32
-            )
-            sampled = jax.random.categorical(sub, logits / temps[:, None])
-            use_greedy = jnp.asarray([r.temperature <= 0 for r in batch])
-            nxt = jnp.where(use_greedy, greedy, sampled).astype(jnp.int32)
-            nxt_np = np.asarray(nxt)
-            len_np = np.asarray(lengths)
-            for i, r in enumerate(batch):
-                if len(outs[i]) < r.max_new and len_np[i] < total:
-                    outs[i].append(int(nxt_np[i]))
-            can = lengths < total
-            tokens = tokens.at[
-                jnp.arange(B), jnp.minimum(lengths, total - 1)
-            ].set(jnp.where(can, nxt, tokens[jnp.arange(B), total - 1]))
-            lengths = jnp.minimum(lengths + 1, total)
-        for i, r in enumerate(batch):
-            r.result = outs[i][: r.max_new]
-            r.event.set()
 
 
 def _replica_resources(
@@ -1748,7 +1151,7 @@ def deploy(
     kwargs-dict}``. Each replica loads engines lazily per model
     (LRU-bounded at ``max_engines_per_replica``) and the router prefers
     replicas already holding the requested model; the OpenAI ``user``
-    field pins a session to one replica's warm KV slots.
+    field pins a session to one replica's warm prefix pages.
 
     Each replica is leased one TPU chip (``ray_actor_options`` overrides;
     ``JAX_PLATFORMS=cpu`` in the caller's environment is the explicit way

@@ -13,7 +13,7 @@ LRU-bounded), so the OpenAI ``model`` field doubles as the multiplexed
 model id: the controller's replica stats report loaded engines, the
 router prefers replicas already holding the model, and the session key
 (OpenAI ``user``) rendezvous-pins a conversation to one replica's warm
-KV slots.
+KV pages.
 
 Concurrency: requests execute on the hosting worker's RPC dispatcher
 threads (direct path) or the replica's executor threads; the engine's
@@ -262,7 +262,7 @@ class OpenAIServer:
                            req: CompletionRequest, tok) -> Iterator[bytes]:
         """SSE chunks for /v1/completions. Closing the generator (client
         disconnect) closes the engine stream, which cancels the request
-        and frees its KV slot."""
+        and frees its KV pages."""
         rid = protocol._new_id("cmpl")
         created = int(time.time())
         n_prompt = len(eng_req["prompt_tokens"])
@@ -301,7 +301,7 @@ class OpenAIServer:
                 ))
                 yield protocol.SSE_DONE
             finally:
-                eng_gen.close()  # disconnect mid-stream frees the KV slot
+                eng_gen.close()  # disconnect mid-stream frees the KV pages
 
         return gen()
 

@@ -344,7 +344,7 @@ class Probe:
     without fully interpreting it: whether the response streams (the
     stream flag lives in the JSON body, not the query string), which
     model it targets (multiplex warm-engine affinity), the session
-    key (same `user` sticks to the replica holding its warm KV slots)
+    key (same `user` sticks to the replica holding its warm KV pages)
     and the prefix hint (requests sharing leading prompt text land on
     the replica whose engine holds those prefix KV blocks)."""
 

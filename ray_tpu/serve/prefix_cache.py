@@ -1,22 +1,22 @@
-"""Block-granular prefix KV cache for the LLM engine.
+"""Page-granular prefix KV cache for the LLM engine.
 
-vLLM-style automatic prefix caching ported to the slot-cache engine
+vLLM-style automatic prefix caching for the paged engine
 (serve/llm.py): prompts are chopped into fixed-size token blocks, each
 block is identified by a CHAIN hash (its own tokens + the parent
 block's digest, so a digest names an entire prefix, not just 64 loose
-tokens), and the host-side K/V for every full block a request prefills
-is parked in a per-engine refcounted pool. The next request sharing
-that prefix copies the matched blocks straight into its slot
-(gpt2_decode.write_prefix) and prefills only the uncached tail
-(gpt2_decode.prefill_extend) — TTFT stops paying for the shared system
-prompt.
+tokens), and every full block a request prefills stays where prefill
+wrote it — a page of the engine's device pool, sealed under its digest
+in a per-engine refcounted allocator. The next request sharing that
+prefix points its page table at the matched pages (a refcount bump, no
+copy) and prefills only the uncached tail (gpt2_decode.prefill_paged) —
+TTFT stops paying for the shared system prompt.
 
-Lifecycle contract: ``match`` and ``insert`` both leave the caller
-holding ONE ref per returned/inserted digest; the engine releases them
-when the request leaves its slot (finish/cancel/fail/unload). Only
-refcount-0 blocks are LRU-evictable; ``close()`` drops everything
-regardless of refcounts — a multiplex eviction must not strand
-resident blocks (the pool is gone with the engine).
+Lifecycle contract: ``alloc`` and ``match_pages`` both leave the caller
+holding ONE ref per returned page; the engine releases them when the
+request leaves it (finish/cancel/fail/unload). Only refcount-0 sealed
+pages are LRU-evictable; ``close()`` drops everything regardless of
+refcounts — a multiplex eviction must not strand resident pages (the
+pool is gone with the engine).
 
 Kill switch: RT_SERVE_PREFIX_CACHE=0 (checked at admission, so it
 doubles as bench_core's A/B lever at runtime).
@@ -34,8 +34,7 @@ from ray_tpu.observability import core_metrics
 from ray_tpu.utils.config import config
 
 # Live pools in this process (engine model_id -> pool), for unload
-# accounting and tests. An engine owns at most one pool (BlockPool for
-# the slot engine, PagedKVPool for the paged engine).
+# accounting and tests. An engine owns one pool.
 _POOLS: Dict[int, Any] = {}
 _POOLS_LOCK = threading.Lock()
 
@@ -64,153 +63,6 @@ def hash_blocks(tokens: Sequence[int], block_tokens: int) -> List[str]:
     return out
 
 
-class _Block:
-    __slots__ = ("digest", "k", "v", "refs", "tick")
-
-    def __init__(self, digest: str, k: np.ndarray, v: np.ndarray):
-        self.digest = digest
-        self.k = k  # [L, B, H, Dh] host copy, engine compute dtype
-        self.v = v
-        self.refs = 0
-        self.tick = 0
-
-
-class BlockPool:
-    """Refcounted, LRU-evicted pool of prefix KV blocks for one engine."""
-
-    def __init__(self, model_id: str, block_tokens: Optional[int] = None,
-                 max_blocks: Optional[int] = None):
-        self.model_id = model_id
-        self.block_tokens = int(
-            block_tokens or config.serve_prefix_block_tokens
-        )
-        self.max_blocks = int(max_blocks or config.serve_prefix_pool_blocks)
-        self._lock = threading.Lock()
-        self._blocks: Dict[str, _Block] = {}
-        self._tick = 0
-        self._closed = False
-        # plain counters independent of the metrics kill switch, for
-        # engine stats()/bench assertions
-        self.hits = 0
-        self.misses = 0
-        self.evictions = 0
-        with _POOLS_LOCK:
-            _POOLS[id(self)] = self
-
-    # -- lookup / insert / release ------------------------------------
-
-    def match(
-        self, digests: Sequence[str], max_tokens: int
-    ) -> Tuple[List[str], List[np.ndarray], List[np.ndarray]]:
-        """Longest resident chain prefix of ``digests``, capped so at
-        most ``max_tokens`` tokens come from cache (the engine keeps at
-        least one prompt token for the tail prefill — a fully-cached
-        prompt would have nothing to produce first-token logits from).
-        Increfs every matched block; caller must release()."""
-        cap = max(0, int(max_tokens)) // self.block_tokens
-        held: List[str] = []
-        ks: List[np.ndarray] = []
-        vs: List[np.ndarray] = []
-        with self._lock:
-            if not self._closed:
-                for d in digests[:cap]:
-                    blk = self._blocks.get(d)
-                    if blk is None:
-                        break
-                    blk.refs += 1
-                    self._tick += 1
-                    blk.tick = self._tick
-                    held.append(d)
-                    ks.append(blk.k)
-                    vs.append(blk.v)
-            hits = len(held)
-            misses = len(digests) - hits
-            self.hits += hits
-            self.misses += misses
-            if core_metrics.ENABLED:
-                tags = {"deployment": self.model_id}
-                if hits:
-                    core_metrics.serve_prefix_cache_hits.inc(hits, tags=tags)
-                if misses:
-                    core_metrics.serve_prefix_cache_misses.inc(
-                        misses, tags=tags
-                    )
-        return held, ks, vs
-
-    def insert(self, digest: str, k: np.ndarray, v: np.ndarray) -> None:
-        """Park one block's host K/V ``[L, B, H, Dh]``; a block already
-        resident is just touched (re-insert after a capped match). The
-        caller holds one ref either way until release()."""
-        with self._lock:
-            if self._closed:
-                return
-            blk = self._blocks.get(digest)
-            if blk is None:
-                blk = _Block(digest, k, v)
-                self._blocks[digest] = blk
-            blk.refs += 1
-            self._tick += 1
-            blk.tick = self._tick
-            self._evict_locked()
-
-    def release(self, digests: Sequence[str]) -> None:
-        """Drop the caller's refs (request left its slot); newly
-        refcount-0 blocks become LRU-evictable but stay resident —
-        that residency IS the cache."""
-        if not digests:
-            return
-        with self._lock:
-            for d in digests:
-                blk = self._blocks.get(d)
-                if blk is not None and blk.refs > 0:
-                    blk.refs -= 1
-            self._evict_locked()
-
-    # -- maintenance ---------------------------------------------------
-
-    def _evict_locked(self) -> None:
-        while len(self._blocks) > self.max_blocks:
-            victim = None
-            for blk in self._blocks.values():
-                if blk.refs == 0 and (
-                    victim is None or blk.tick < victim.tick
-                ):
-                    victim = blk
-            if victim is None:
-                return  # everything pinned by in-flight requests
-            del self._blocks[victim.digest]
-            self.evictions += 1
-
-    def resident(self) -> int:
-        with self._lock:
-            return len(self._blocks)
-
-    def ref_count(self, digest: str) -> int:
-        with self._lock:
-            blk = self._blocks.get(digest)
-            return blk.refs if blk is not None else 0
-
-    def stats(self) -> Dict[str, int]:
-        with self._lock:
-            return {
-                "blocks": len(self._blocks),
-                "block_tokens": self.block_tokens,
-                "hits": self.hits,
-                "misses": self.misses,
-                "evictions": self.evictions,
-            }
-
-    def close(self) -> None:
-        """Unconditionally drop every block (engine unload/eviction):
-        outstanding refs die with the engine's slots, so honoring them
-        would strand the blocks forever."""
-        with self._lock:
-            self._blocks.clear()
-            self._closed = True
-        with _POOLS_LOCK:
-            _POOLS.pop(id(self), None)
-
-
 class _Page:
     """Metadata for one device-resident KV page. The page's K/V content
     lives in the engine's paged device cache (gpt2_decode.init_paged_cache
@@ -230,9 +82,9 @@ class _Page:
 class PagedKVPool:
     """Refcounted allocator over ONE device-resident page pool shared by
     generation KV and prefix KV (vLLM-style paged attention, metadata
-    side). Unlike :class:`BlockPool` it holds NO host tensor copies —
-    a prefix hit is a refcount bump on pages already sitting in the
-    device cache, zero block copies.
+    side). It holds NO host tensor copies — a prefix hit is a refcount
+    bump on pages already sitting in the device cache, zero block
+    copies.
 
     Page 0 is a reserved scratch page, never allocated: inactive decode
     rows scatter their junk K/V there (their page tables are all-zero),
@@ -388,10 +240,8 @@ class PagedKVPool:
                     self._free.append(idx)
 
     def reset(self) -> None:
-        """Drop ALL metadata (poisoned engine round rebuilt the device
-        cache with zeros, so every sealed page's content is gone — the
-        BlockPool could survive this because it held host copies; this
-        pool cannot)."""
+        """Drop ALL metadata (a poisoned engine round rebuilt the device
+        cache with zeros, so every sealed page's content is gone)."""
         with self._lock:
             if self._closed:
                 return
@@ -410,7 +260,7 @@ class PagedKVPool:
             return len(self._free)
 
     def resident(self) -> int:
-        """Sealed prefix pages resident (BlockPool-compatible name)."""
+        """Sealed prefix pages resident."""
         with self._lock:
             return len(self._sealed)
 

@@ -383,7 +383,7 @@ class ServeProxy:
                        probe: "oai.Probe", trace=None):
         """SSE response: each yielded ``data: {...}\\n\\n`` event is one
         chunk; closing the connection closes this generator, which
-        cancels the replica-side stream and frees the engine's KV slot.
+        cancels the replica-side stream and frees the request's KV pages.
         The proxy span closes when the generator does, so its duration
         covers the whole stream (the e2e number request_summary rolls
         up)."""

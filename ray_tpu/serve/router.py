@@ -13,7 +13,7 @@ Affinity tiers on top of pow-2:
   - ``session_key``: rendezvous (highest-random-weight) hashing pins a
     session to ONE replica while the replica set is stable — the serve
     LLM path uses the OpenAI ``user`` field so a conversation keeps
-    hitting the replica whose KV slots hold its prefix. Replica death
+    hitting the replica whose KV pages hold its prefix. Replica death
     re-pins only the sessions that lived on the dead replica (the HRW
     property), unlike mod-N hashing which reshuffles everyone.
 
@@ -297,7 +297,7 @@ class Router:
         exhausted or abandoned; an ABANDONED stream (the HTTP client
         disconnected and the proxy closed this generator) cancels the
         replica-side task so the deployment's generator unwinds and the
-        LLM engine frees the request's KV slot."""
+        LLM engine frees the request's KV pages."""
         tid = _trace_id_of(payload) if tracing.ENABLED else None
         t0u = tracing.now_us() if tid else 0
         rid, handle = self.choose_replica(

@@ -268,30 +268,28 @@ config.define("temp_dir", "/tmp/ray_tpu")
 config.define("trace_events", True)
 config.define("observability_enabled", True)
 # Prefix KV caching (serve/prefix_cache.py): content-hashed prompt
-# prefix blocks are kept in a refcounted, LRU-evicted per-engine pool
-# and copied into a slot at admission instead of re-running prefill
-# over them. RT_SERVE_PREFIX_CACHE=0 is the kill switch (and the A/B
+# prefix blocks stay resident as sealed pages of the engine's
+# refcounted, LRU-evicted page pool, and a request sharing the prefix
+# pins them at admission instead of re-running prefill over them.
+# RT_SERVE_PREFIX_CACHE=0 is the kill switch (and the A/B
 # lever for bench_core's TTFT rows): every admission pays full prefill.
 config.define("serve_prefix_cache", True)
 # Tokens per prefix block: the unit of hashing, refcounting and reuse.
 # Must be uniform across replicas of a deployment (the router's
 # prefix-hash hint assumes one block geometry).
 config.define("serve_prefix_block_tokens", 64)
-# Max resident blocks per engine pool; refcount-0 blocks evict LRU
-# beyond this.
+# Resident prefix pages the disaggregated prefill tier's pool holds
+# beyond one working sequence (serve/kv_transfer.PrefillEngine);
+# refcount-0 sealed pages evict LRU beyond this.
 config.define("serve_prefix_pool_blocks", 512)
-# Paged KV pool (serve/llm.py + prefix_cache.PagedKVPool): the engine's
+# The engine's page pool (serve/llm.py + prefix_cache.PagedKVPool):
 # generation KV and the prefix cache share ONE block-granular refcounted
-# page pool — a prefix hit is a refcount bump (zero block copies),
-# eviction is global LRU over pages not pinned by a live request, and
-# continuous batching admits by free PAGES instead of free slots.
-# RT_SERVE_PAGED_KV=0 is the kill switch: the engine reverts to the
-# pre-paged slot cache + copy-based BlockPool (and the A/B lever for
-# bench_serve's pagedkv leg). Page size inherits
-# serve_prefix_block_tokens so page identity == prefix-block identity.
-config.define("serve_paged_kv", True)
-# Total pages in the engine pool; 0 = auto-size to MATCHED MEMORY with
-# the slot engine (max_batch_size x ceil(n_positions/page_tokens)).
+# pool — a prefix hit is a refcount bump (zero block copies), eviction
+# is global LRU over pages not pinned by a live request, and continuous
+# batching admits by free PAGES. Page size is serve_prefix_block_tokens,
+# so page identity == prefix-block identity.
+# Total pages in the engine pool; 0 = auto-size to max_batch_size
+# full-length sequences (max_batch_size x ceil(n_positions/page_tokens)).
 config.define("serve_kv_pool_pages", 0)
 # Max concurrent sequences the paged engine decodes per step (the
 # static batch width of the jitted decode); 0 = auto
@@ -302,18 +300,8 @@ config.define("serve_paged_max_seqs", 0)
 # with decode steps (bounding in-flight streams' ITL and per-step
 # memory). 0 = unchunked (a prompt prefills in one round).
 config.define("serve_prefill_chunk_tokens", 512)
-# Async decode pipeline (serve/llm.py): the engine dispatches decode
-# chunk N+1 from chunk N's device-resident outputs BEFORE materializing
-# chunk N's tokens on the host, so token fan-out, SSE queue puts,
-# metrics stamps and the admission scan overlap with device compute
-# (one-step lookahead). Page frees are deferred by one step so an
-# in-flight chunk never reads freed pages. RT_SERVE_ASYNC_DECODE=0 is
-# the kill switch (and the A/B lever for bench_serve's asyncdecode
-# leg): the engine harvests every chunk synchronously before the next
-# dispatch, exactly the pre-pipeline loop.
-config.define("serve_async_decode", True)
 # Disaggregated prefill/decode (serve/kv_transfer.py): the ingress
-# calls a separate prefill deployment which ships the slot's KV rows
+# calls a separate prefill deployment which ships the prompt's KV rows
 # back over an RpcChannel (zero-copy multiseg frames); the local engine
 # imports them and only decodes. RT_SERVE_DISAGG=0 is the kill switch —
 # every request prefills in the decode replica even when a prefill
@@ -361,7 +349,7 @@ config.define("alerts_ttft_budget", 0.05)
 config.define("alerts_burn_short_s", 60.0)
 config.define("alerts_burn_long_s", 300.0)
 config.define("alerts_burn_factor", 1.0)
-# Threshold rules: sustained router/engine queue depth, KV-slot
+# Threshold rules: sustained router/engine queue depth, KV-page
 # occupancy ratio (occupied/total), and the for-duration both must hold
 # before firing.
 config.define("alerts_queue_depth_max", 64.0)
@@ -404,7 +392,7 @@ config.define("serve_autoscale_down_cooldown_s", 15.0)
 # hint; below target*low_frac counts toward sustained-ok.
 config.define("serve_autoscale_ttft_high_frac", 0.8)
 config.define("serve_autoscale_ttft_low_frac", 0.4)
-# KV-slot occupancy (occupied/total) watermarks.
+# KV-page occupancy (occupied/total) watermarks.
 config.define("serve_autoscale_kv_high_frac", 0.85)
 config.define("serve_autoscale_kv_low_frac", 0.5)
 # Session-aware drain: a scale-down victim stops taking new sessions

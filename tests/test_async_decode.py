@@ -1,12 +1,12 @@
-"""Async decode pipeline (RT_SERVE_ASYNC_DECODE): the engine dispatches
-decode chunk N+1 from chunk N's device-resident outputs before
-materializing chunk N's tokens, so host bookkeeping (fan-out, SSE puts,
-metrics, reaping, admission) overlaps device compute.
+"""The decode pipeline: the engine dispatches decode chunk N+1 from
+chunk N's device-resident outputs before materializing chunk N's
+tokens, so host bookkeeping (fan-out, SSE puts, metrics, reaping,
+admission) overlaps device compute.
 
-Pins the PR's contracts:
-  * temp=0 generations are BITWISE identical async-on vs async-off
-    (unary and SSE, paged and slot engines) — the lookahead reorders
-    WHEN the host sees tokens, never which tokens the device samples;
+Pins its contracts:
+  * temp=0 generations are the full forward's greedy tokens, unary and
+    SSE — the lookahead reorders WHEN the host sees tokens, never which
+    tokens the device samples;
   * cancellation landing while a lookahead chunk is in flight drops
     that chunk's tokens on the host and returns every page (deferred
     one step, so the in-flight chunk never scatters into freed pages);
@@ -22,33 +22,25 @@ import time
 
 import numpy as np
 import pytest
+from _llm_reference import engine_reference
 
 
-def _mk(paged: bool, async_on: bool, batch: int = 4):
+def _mk(batch: int = 4):
     import jax
 
     jax.config.update("jax_platforms", "cpu")
     from ray_tpu.serve.llm import LLMConfig, LLMServer
 
-    return LLMServer(LLMConfig(
-        model_id="gpt2-tiny", max_batch_size=batch, paged_kv=paged,
-        async_decode=async_on,
-    ))
+    return LLMServer(LLMConfig(model_id="gpt2-tiny", max_batch_size=batch))
 
 
 @pytest.fixture(scope="module")
-def engines():
-    """All four engine variants, torn down together: (paged, async) ->
-    server. Module-scoped — each holds a tiny CPU model."""
-    servers = {
-        (paged, async_on): _mk(paged, async_on)
-        for paged in (True, False)
-        for async_on in (True, False)
-    }
-    yield servers
-    for srv in servers.values():
-        srv._stop.set()
-        srv._work.set()
+def engine():
+    """Module-scoped — it holds a tiny CPU model."""
+    srv = _mk()
+    yield srv
+    srv._stop.set()
+    srv._work.set()
 
 
 def _req(prompt, max_new=24, **extra):
@@ -57,40 +49,34 @@ def _req(prompt, max_new=24, **extra):
 
 
 # ---------------------------------------------------------------------------
-# parity: async on/off is invisible at temp=0
+# parity: the lookahead is invisible at temp=0
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("paged", [True, False],
-                         ids=["paged", "slot"])
-def test_async_vs_sync_unary_bitwise(engines, paged):
-    """The lookahead must not change a single sampled token: same
-    step_no/rng discipline, same chunk sizes, same finish budgets —
-    short, block-spanning, and window-filling prompts."""
+def test_pipelined_unary_matches_full_forward(engine):
+    """The lookahead must not change a single sampled token: whatever
+    chunk sizes and finish budgets the loop chose, the answer is the
+    full forward's — short, block-spanning, and window-filling
+    prompts."""
     rng = np.random.RandomState(41)
     for n in (10, 64, 127):
         prompt = [int(t) for t in rng.randint(0, 256, n)]
-        a = engines[(paged, True)](_req(prompt))["tokens"]
-        s = engines[(paged, False)](_req(prompt))["tokens"]
-        assert a == s, f"async != sync (paged={paged}, prompt len {n})"
+        want = engine_reference(engine, prompt, 24)
+        assert len(want) == min(24, 128 - n)
+        assert engine(_req(prompt))["tokens"] == want, (
+            f"engine != full forward (prompt len {n})"
+        )
 
 
-@pytest.mark.parametrize("paged", [True, False],
-                         ids=["paged", "slot"])
-def test_async_vs_sync_sse_stream_bitwise(engines, paged):
-    """SSE rides the pipeline: the streamed token sequence (fan-out now
-    happens one chunk AFTER dispatch in async mode) matches the sync
-    stream and the unary result exactly, and the stream terminates."""
+def test_pipelined_sse_stream_matches_full_forward(engine):
+    """SSE rides the pipeline: the streamed token sequence (fan-out
+    happens one chunk AFTER dispatch) matches the unary result and the
+    full forward exactly, and the stream terminates."""
     rng = np.random.RandomState(42)
     prompt = [int(t) for t in rng.randint(0, 256, 33)]
-
-    def collect(srv):
-        return [ev["token"] for ev in srv(_req(prompt, stream=True))]
-
-    a = collect(engines[(paged, True)])
-    s = collect(engines[(paged, False)])
-    u = engines[(paged, True)](_req(prompt))["tokens"]
-    assert a == s == u
+    a = [ev["token"] for ev in engine(_req(prompt, stream=True))]
+    u = engine(_req(prompt))["tokens"]
+    assert a == u == engine_reference(engine, prompt, 24)
     assert len(a) == 24
 
 
@@ -99,12 +85,12 @@ def test_async_vs_sync_sse_stream_bitwise(engines, paged):
 # ---------------------------------------------------------------------------
 
 
-def test_mid_lookahead_cancel_returns_pages(engines):
+def test_mid_lookahead_cancel_returns_pages(engine):
     """Closing a stream while a lookahead chunk is in flight marks the
     row dropped: its remaining tokens never reach the queue, its pages
     free via the deferred path once the chunk harvests, and occupancy
     returns to idle — no rt_serve_kv_pages_occupied leak."""
-    srv = engines[(True, True)]
+    srv = engine
     pool = srv._prefix_pool
     idle_occ = pool.stats()["pages_occupied"]
     gen = srv(_req([7] * 40, max_new=100, stream=True))
@@ -137,7 +123,7 @@ def test_mid_lookahead_exception_fails_and_recovers(monkeypatch):
     deferred-free + pool-reset path, and leave the engine serving."""
     from ray_tpu.models import gpt2_decode
 
-    srv = _mk(paged=True, async_on=True)
+    srv = _mk()
     try:
         srv(_req([3] * 20, max_new=4))  # warm the compile caches
         pool = srv._prefix_pool
@@ -190,14 +176,12 @@ def test_mid_lookahead_exception_fails_and_recovers(monkeypatch):
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("paged", [True, False],
-                         ids=["paged", "slot"])
-def test_idle_arrival_ttft_no_half_second_mode(engines, paged):
+def test_idle_arrival_ttft_no_half_second_mode(engine):
     """The engine consumes the wake flag BEFORE scanning the queue, so
     a request arriving while it sleeps in _work.wait(0.5) always wakes
     it immediately. The old wait-then-clear order could eat the set()
     and park a fresh arrival for the full 500 ms timeout."""
-    srv = engines[(paged, True)]
+    srv = engine
     prompt = [11] * 12
     srv(_req(prompt, max_new=2))  # warm compile caches
     lat = []
@@ -211,11 +195,11 @@ def test_idle_arrival_ttft_no_half_second_mode(engines, paged):
     )
 
 
-def test_concurrent_streams_all_complete(engines):
+def test_concurrent_streams_all_complete(engine):
     """Batched async decode under churn: several concurrent streams of
     unequal lengths all run to completion with the right token counts
     (staggered finishes exercise retire-at-dispatch + deferred frees)."""
-    srv = engines[(True, True)]
+    srv = engine
     out = {}
 
     def run(tag, n, m):

@@ -63,7 +63,7 @@ def srv():
     config.set("serve_prefix_block_tokens", B)
     config.set("serve_kv_pool_pages", 14)
     try:
-        server = LLMServer(LLMConfig(model_id="gpt2-tiny", max_batch_size=4, paged_kv=True))
+        server = LLMServer(LLMConfig(model_id="gpt2-tiny", max_batch_size=4))
     finally:
         for k, v in keep.items():
             config.set(k, v)

@@ -1,12 +1,13 @@
-"""KV-cache decode engine (models/gpt2_decode.py + serve/llm.py kv loop).
+"""KV-cache decode engine (models/gpt2_decode.py + serve/llm.py's loop).
 
 Parity model: the engine-level tests vLLM supplies for the reference's
-serve.llm — prefill/decode equivalence, slot isolation, continuous
+serve.llm — prefill/decode equivalence, row isolation, continuous
 batching.
 """
 
 import numpy as np
 import pytest
+from _llm_reference import greedy_reference
 
 
 @pytest.fixture(scope="module")
@@ -21,23 +22,38 @@ def tiny():
     return cfg, params
 
 
-def _greedy_reference(cfg, params, prompt, n):
-    import jax
+def _paged(cfg, S, rows, pages_per_row, page_tokens=16):
+    """A pool and the page tables of ``S`` decode rows, of which
+    ``rows`` are live on disjoint pages; the others keep all-zero tables
+    and scatter into page 0, the scratch page."""
+    from ray_tpu.models import gpt2_decode as dec
+
+    max_pages = -(-cfg.n_positions // page_tokens)
+    ck, cv = dec.init_paged_cache(
+        cfg, 1 + len(rows) * pages_per_row, page_tokens
+    )
+    tables = np.zeros((S, max_pages), np.int32)
+    for j, r in enumerate(rows):
+        first = 1 + j * pages_per_row
+        tables[r, :pages_per_row] = np.arange(first, first + pages_per_row)
+    return ck, cv, tables
+
+
+def _prefill(cfg, params, prompt, ck, cv, table):
     import jax.numpy as jnp
 
-    from ray_tpu.models import gpt2
+    from ray_tpu.models import gpt2_decode as dec
 
-    seq = list(prompt)
-    out = []
-    for _ in range(n):
-        logits = gpt2.forward(params, jnp.asarray([seq], jnp.int32), cfg)
-        nxt = int(jnp.argmax(logits[0, len(seq) - 1, : cfg.vocab_size]))
-        out.append(nxt)
-        seq.append(nxt)
-    return out
+    tok = np.zeros((1, 16), np.int32)
+    tok[0, : len(prompt)] = prompt
+    return dec.prefill_paged(
+        cfg, params, jnp.asarray(tok), jnp.int32(0), jnp.int32(len(prompt)),
+        ck, cv, jnp.asarray(table),
+    )
 
 
-def test_kv_decode_matches_full_forward(tiny):
+def test_paged_decode_matches_full_forward(tiny):
+    import jax
     import jax.numpy as jnp
 
     from ray_tpu.models import gpt2_decode as dec
@@ -45,24 +61,21 @@ def test_kv_decode_matches_full_forward(tiny):
     cfg, params = tiny
     rng = np.random.RandomState(7)
     prompt = list(rng.randint(0, cfg.vocab_size, 12))
-    ref = _greedy_reference(cfg, params, prompt, 6)
+    ref = greedy_reference(cfg, params, prompt, 6)
 
-    S, T_max = 4, 64
-    ck, cv = dec.init_cache(cfg, S, T_max)
-    tok = np.zeros((1, 16), np.int32)
-    tok[0, : len(prompt)] = prompt
-    logits0, ck, cv = dec.prefill(
-        cfg, params, jnp.asarray(tok), jnp.int32(len(prompt)), ck, cv,
-        jnp.int32(1),
-    )
+    S = 4
+    ck, cv, tables = _paged(cfg, S, rows=[1], pages_per_row=2)
+    logits0, ck, cv = _prefill(cfg, params, prompt, ck, cv, tables[1])
     out = [int(jnp.argmax(logits0))]
     last = np.zeros((S,), np.int32)
     lengths = np.zeros((S,), np.int32)
     last[1] = out[0]
     lengths[1] = len(prompt)
+    step = jax.jit(dec._decode_paged_impl, static_argnums=(0,))
     for _ in range(5):
-        logits, ck, cv = dec.decode_step(
-            cfg, params, jnp.asarray(last), jnp.asarray(lengths), ck, cv
+        logits, ck, cv = step(
+            cfg, params, jnp.array(last), jnp.array(lengths), ck, cv,
+            jnp.array(tables),
         )
         nxt = int(jnp.argmax(logits[1]))
         out.append(nxt)
@@ -71,9 +84,11 @@ def test_kv_decode_matches_full_forward(tiny):
     assert out == ref
 
 
-def test_kv_slots_are_isolated(tiny):
-    """Two different prompts decoding in different slots of one cache
-    must each match their own single-sequence reference."""
+def test_paged_rows_are_isolated(tiny):
+    """Two different prompts decoding in different rows of one pool, on
+    disjoint pages, must each match their own single-sequence
+    reference."""
+    import jax
     import jax.numpy as jnp
 
     from ray_tpu.models import gpt2_decode as dec
@@ -82,33 +97,30 @@ def test_kv_slots_are_isolated(tiny):
     rng = np.random.RandomState(11)
     prompts = [list(rng.randint(0, cfg.vocab_size, 9)),
                list(rng.randint(0, cfg.vocab_size, 14))]
-    refs = [_greedy_reference(cfg, params, p, 4) for p in prompts]
+    refs = [greedy_reference(cfg, params, p, 4) for p in prompts]
 
-    S, T_max = 3, 64
-    ck, cv = dec.init_cache(cfg, S, T_max)
+    S = 3  # row 1 stays empty between the two
+    ck, cv, tables = _paged(cfg, S, rows=[0, 2], pages_per_row=2)
     last = np.zeros((S,), np.int32)
     lengths = np.zeros((S,), np.int32)
     outs = {0: [], 2: []}
-    for slot, p in zip((0, 2), prompts):
-        tok = np.zeros((1, 16), np.int32)
-        tok[0, : len(p)] = p
-        logits0, ck, cv = dec.prefill(
-            cfg, params, jnp.asarray(tok), jnp.int32(len(p)), ck, cv,
-            jnp.int32(slot),
-        )
+    for row, p in zip((0, 2), prompts):
+        logits0, ck, cv = _prefill(cfg, params, p, ck, cv, tables[row])
         first = int(jnp.argmax(logits0))
-        outs[slot].append(first)
-        last[slot] = first
-        lengths[slot] = len(p)
+        outs[row].append(first)
+        last[row] = first
+        lengths[row] = len(p)
+    step = jax.jit(dec._decode_paged_impl, static_argnums=(0,))
     for _ in range(3):
-        logits, ck, cv = dec.decode_step(
-            cfg, params, jnp.asarray(last), jnp.asarray(lengths), ck, cv
+        logits, ck, cv = step(
+            cfg, params, jnp.array(last), jnp.array(lengths), ck, cv,
+            jnp.array(tables),
         )
-        for slot in (0, 2):
-            nxt = int(jnp.argmax(logits[slot]))
-            outs[slot].append(nxt)
-            last[slot] = nxt
-            lengths[slot] += 1
+        for row in (0, 2):
+            nxt = int(jnp.argmax(logits[row]))
+            outs[row].append(nxt)
+            last[row] = nxt
+            lengths[row] += 1
     assert outs[0] == refs[0]
     assert outs[2] == refs[1]
 
@@ -145,3 +157,23 @@ def test_kv_engine_continuous_batching():
     stats = srv.batch_stats()
     assert stats["max_batch"] >= 2, stats
     srv._stop.set()
+
+
+@pytest.mark.parametrize("kwargs, removed", [
+    ({"engine": "recompute"}, "recompute"),
+    ({"paged_kv": False}, "slot KV engine"),
+    ({"async_decode": False}, "synchronous decode loop"),
+])
+def test_config_refuses_the_removed_engines_by_name(kwargs, removed):
+    """The three keywords that once chose an engine select nothing now:
+    the values that name the only engine are accepted (the benchmark's
+    configuration files pass them), and a value that asks for a path
+    that was removed raises, naming it, instead of being ignored."""
+    from ray_tpu.serve.llm import LLMConfig
+
+    cfg = LLMConfig(model_id="gpt2-tiny", engine="kv", paged_kv=True,
+                    async_decode=True)
+    assert not {"engine", "paged_kv", "async_decode"} & set(vars(cfg))
+    (key, value), = kwargs.items()
+    with pytest.raises(ValueError, match=f"{key}={value!r}.*{removed}"):
+        LLMConfig(model_id="gpt2-tiny", **kwargs)

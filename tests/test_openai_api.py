@@ -318,34 +318,44 @@ def test_client_disconnect_mid_stream_keeps_serving(front):
 # ---------------------------------------------------------------------------
 
 
+def _throttle_decode(monkeypatch, seconds=0.05):
+    """Slow down the two decode programs the engine dispatches, so that
+    a cancellation or an unload provably lands mid-generation. Returns
+    the count of throttled dispatches: a test asserts it moved, or the
+    throttle sits on programs the engine no longer calls."""
+    from ray_tpu.models import gpt2_decode
+
+    entered = {"n": 0}
+
+    def slow(real):
+        def wrapped(*a, **kw):
+            entered["n"] += 1
+            time.sleep(seconds)
+            return real(*a, **kw)
+        return wrapped
+
+    for name in ("decode_multi_paged", "decode_paged_and_sample"):
+        monkeypatch.setattr(
+            gpt2_decode, name, slow(getattr(gpt2_decode, name))
+        )
+    return entered
+
+
 def test_engine_stream_close_frees_kv_slot(monkeypatch):
     """Unit-level pin of the cancellation chain: closing _stream_tokens
-    marks the request cancelled and the engine reaps its slot at the
+    marks the request cancelled and the engine reaps its row at the
     next round instead of decoding to max_new for nobody. The decode
     step is throttled so cancellation provably lands mid-generation."""
-    from ray_tpu.models import gpt2_decode
     from ray_tpu.serve.llm import LLMConfig, LLMServer
 
-    real_multi = gpt2_decode.decode_multi
-    real_single = gpt2_decode.decode_and_sample
-
-    def slow_multi(*a, **kw):
-        time.sleep(0.05)
-        return real_multi(*a, **kw)
-
-    def slow_single(*a, **kw):
-        time.sleep(0.05)
-        return real_single(*a, **kw)
-
-    monkeypatch.setattr(gpt2_decode, "decode_multi", slow_multi)
-    monkeypatch.setattr(gpt2_decode, "decode_and_sample", slow_single)
-
+    throttled = _throttle_decode(monkeypatch)
     server = LLMServer(LLMConfig(model_id="gpt2-tiny", max_batch_size=2))
     try:
         gen = server({"prompt_tokens": [1, 2, 3], "max_new_tokens": 120,
                       "temperature": 0.0, "stream": True})
         seen = [next(gen) for _ in range(3)]
         assert [s["index"] for s in seen] == [0, 1, 2]
+        assert throttled["n"] >= 1, "the engine did not call the throttle"
         rounds_at_close = server.batch_stats()["batches"]
         gen.close()  # client went away
 
@@ -369,23 +379,15 @@ def test_engine_stream_close_frees_kv_slot(monkeypatch):
 def test_engine_unload_fails_inflight_requests(monkeypatch):
     """Evicting an engine (multiplex LRU) must FAIL in-flight streams
     immediately — not strand their consumers until the 300s timeout."""
-    from ray_tpu.models import gpt2_decode
     from ray_tpu.serve.llm import LLMConfig, LLMServer
 
-    real_multi = gpt2_decode.decode_multi
-    real_single = gpt2_decode.decode_and_sample
-    monkeypatch.setattr(
-        gpt2_decode, "decode_multi",
-        lambda *a, **kw: (time.sleep(0.05), real_multi(*a, **kw))[1],
-    )
-    monkeypatch.setattr(
-        gpt2_decode, "decode_and_sample",
-        lambda *a, **kw: (time.sleep(0.05), real_single(*a, **kw))[1],
-    )
+    throttled = _throttle_decode(monkeypatch)
     server = LLMServer(LLMConfig(model_id="gpt2-tiny", max_batch_size=2))
     gen = server({"prompt_tokens": [1, 2], "max_new_tokens": 120,
                   "temperature": 0.0, "stream": True})
-    next(gen)  # request admitted into a KV slot
+    next(gen)  # request admitted into a decode row
+    next(gen)  # ... and a throttled decode chunk has been dispatched
+    assert throttled["n"] >= 1, "the engine did not call the throttle"
     t0 = time.monotonic()
     server.unload()
     with pytest.raises(RuntimeError, match="unloaded"):

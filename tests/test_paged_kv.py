@@ -1,9 +1,9 @@
 """One paged KV pool (serve/prefix_cache.PagedKVPool + the paged engine
 in serve/llm.py): the allocator contract (scratch page 0, all-or-nothing
 alloc, refcount pins, seal-no-copy, global LRU over unpinned sealed
-pages), and the serving guarantees the tentpole promises — bitwise
-identity at temperature=0 against the RT_SERVE_PAGED_KV=0 slot engine,
-hit-vs-cold and chunked-vs-unchunked, disagg import vs monolithic; a
+pages), and the serving guarantees the engine promises — the full
+forward's greedy tokens at temperature=0, bitwise identity hit-vs-cold
+and chunked-vs-unchunked, disagg import vs monolithic; a
 prefix hit is a refcount bump with ZERO block copies; admission is
 page-granular (oversize fails fast, pressure defers in FIFO order);
 pages are released exactly once under cancel/unload races; and chunked
@@ -15,6 +15,7 @@ import time
 
 import numpy as np
 import pytest
+from _llm_reference import engine_reference
 
 from ray_tpu.serve.prefix_cache import PagedKVPool
 
@@ -99,6 +100,52 @@ def test_pool_reset_and_close_drop_everything():
 
 
 # ---------------------------------------------------------------------------
+# the loop's uploads (no engine)
+# ---------------------------------------------------------------------------
+
+
+def _aligned(shape, dtype, align=64):
+    """A NumPy array whose data pointer is ``align``-byte aligned: the
+    case in which the CPU backend shares memory instead of copying.
+    NumPy's own allocator gives 16 bytes, so a plain array is shared in
+    some processes and copied in others."""
+    n = int(np.prod(shape)) * np.dtype(dtype).itemsize
+    buf = np.zeros(n + align, np.uint8)
+    off = (-buf.ctypes.data) % align
+    out = buf[off:off + n].view(dtype).reshape(shape)
+    assert out.ctypes.data % align == 0
+    return out
+
+
+def test_upload_never_shares_the_loops_host_mirrors():
+    """The engine loop zeroes a row of ``tables``/``lengths``/``last``
+    when a request retires at dispatch, before the dispatched program
+    has run; a device array that shared the mirror's memory would then
+    read a zeroed page table (the ``[x, 0, 0, ...]`` answers). What
+    ``_upload`` returns must not change when its inputs are written."""
+    import jax
+
+    jax.config.update("jax_platforms", "cpu")
+    from ray_tpu.serve.llm import _upload
+
+    S, max_pages = 24, 16
+    host = [
+        _aligned((S,), np.int32), _aligned((S,), np.int32),
+        _aligned((S,), np.float32), _aligned((S,), bool),
+        _aligned((S, max_pages), np.int32),
+    ]
+    for a in host:
+        a[...] = 1
+    dev = _upload(*host)
+    for a in host:
+        a[...] = 0  # what retire() does to a row, to all of them
+    for d, a in zip(dev, host):
+        got = np.asarray(d)
+        assert got.shape == a.shape and got.dtype == a.dtype
+        assert got.all(), f"{a.dtype}{a.shape} was shared, not copied"
+
+
+# ---------------------------------------------------------------------------
 # engine-level: bitwise identity, zero-copy hits, admission, releases
 # ---------------------------------------------------------------------------
 
@@ -111,21 +158,7 @@ def paged_engine():
     from ray_tpu.serve.llm import LLMConfig, LLMServer
 
     srv = LLMServer(LLMConfig(
-        model_id="gpt2-tiny", max_batch_size=4, paged_kv=True,
-    ))
-    yield srv
-    srv._stop.set()
-
-
-@pytest.fixture(scope="module")
-def slot_engine():
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
-    from ray_tpu.serve.llm import LLMConfig, LLMServer
-
-    srv = LLMServer(LLMConfig(
-        model_id="gpt2-tiny", max_batch_size=4, paged_kv=False,
+        model_id="gpt2-tiny", max_batch_size=4,
     ))
     yield srv
     srv._stop.set()
@@ -136,32 +169,30 @@ def _req(prompt, max_new=8, **extra):
             "temperature": 0.0, **extra}
 
 
-def test_engine_says_which_decode_attention_it_runs(paged_engine, slot_engine):
-    """A paged engine's decode programs attend over the pool itself;
-    the slot engine has no pool to say anything about."""
+def test_engine_says_which_decode_attention_it_runs(paged_engine):
+    """The engine's decode programs attend over the pool itself."""
     assert paged_engine.batch_stats()["decode_attention"] == "pool"
-    assert slot_engine.batch_stats()["decode_attention"] is None
 
 
-def test_kill_switch_paged_vs_slot_bitwise(paged_engine, slot_engine):
-    """RT_SERVE_PAGED_KV=0 restores pre-PR behavior: both engines share
-    the weights recipe, so at temperature=0 the paged engine's page-
-    table gather/scatter must generate EXACTLY the slot engine's tokens
-    — short, block-spanning, and window-filling prompts."""
+@pytest.mark.parametrize("n", [10, 64, 100, 127])
+def test_paged_engine_matches_full_forward(paged_engine, n):
+    """At temperature=0 the engine's page-table scatter and pool
+    attention must generate EXACTLY the full forward's greedy tokens —
+    short, block-spanning and window-filling prompts (the 127-token one
+    leaves room for a single token)."""
     rng = np.random.RandomState(31)
-    for n in (10, 64, 100, 127):
-        prompt = [int(t) for t in rng.randint(0, 256, n)]
-        assert (
-            paged_engine(_req(prompt))["tokens"]
-            == slot_engine(_req(prompt))["tokens"]
-        ), f"paged != slot at prompt len {n}"
+    prompt = [int(t) for t in rng.randint(0, 256, 127)][:n]
+    want = engine_reference(paged_engine, prompt, 8)
+    assert len(want) == min(8, 128 - n)
+    assert paged_engine(_req(prompt))["tokens"] == want, (
+        f"engine != full forward at prompt len {n}"
+    )
 
 
 def test_prefix_hit_is_bitwise_and_copies_nothing(paged_engine):
     """The acceptance property: a repeat prompt admits from resident
     pages (refcount bump), generates the cold answer bit for bit, and
-    the pool's block-copy counter does not move — the slot engine paid
-    a host->slot copy per matched block here."""
+    the pool's block-copy counter does not move."""
     pool = paged_engine._prefix_pool
     rng = np.random.RandomState(32)
     prompt = [int(t) for t in rng.randint(0, 256, 100)]
@@ -207,7 +238,7 @@ def test_disagg_import_matches_monolithic_and_seals(paged_engine):
 
     rng = np.random.RandomState(34)
     prompt = [int(t) for t in rng.randint(0, 256, 100)]
-    pre = PrefillEngine(LLMConfig(model_id="gpt2-tiny", paged_kv=True))
+    pre = PrefillEngine(LLMConfig(model_id="gpt2-tiny"))
     try:
         ship1 = pre.prefill(prompt, 0.0)
         ship2 = pre.prefill(prompt, 0.0)
@@ -234,10 +265,10 @@ def test_disagg_import_matches_monolithic_and_seals(paged_engine):
     assert c2 - c1 == 1, (c1, c2)
 
 
-def test_page_gauges_and_slot_aliases(paged_engine):
-    """Satellite: rt_serve_kv_pages_* gauges exist, and the paged
-    engine aliases its page numbers onto the legacy slot-gauge names so
-    the serve_kv_occupancy alert rule keeps evaluating unchanged."""
+def test_page_gauges_are_published_and_slot_gauges_are_not(paged_engine):
+    """rt_serve_kv_pages_* gauges carry the engine's KV occupancy (the
+    serve_kv_occupancy alert rule, ``rt top`` and the autoscale policy
+    read them); the slot engine's gauge names went with it."""
     from ray_tpu.utils import metrics as umetrics
 
     paged_engine(_req([3, 1, 4], max_new=2))
@@ -245,10 +276,11 @@ def test_page_gauges_and_slot_aliases(paged_engine):
     for name in ("rt_serve_kv_pages_total", "rt_serve_kv_pages_occupied",
                  "rt_serve_kv_pages_prefix_resident"):
         assert snap.get(name, {}).get("series"), f"{name} not published"
-    pages = snap["rt_serve_kv_pages_total"]["series"]
-    slots = snap["rt_serve_kv_slots_total"]["series"]
-    for key, val in pages.items():
-        assert slots.get(key) == val, (key, val, slots.get(key))
+    pool = paged_engine._prefix_pool.stats()
+    assert pool["pages_total"] in snap["rt_serve_kv_pages_total"][
+        "series"].values()
+    for name in ("rt_serve_kv_slots_occupied", "rt_serve_kv_slots_total"):
+        assert name not in snap, f"{name} is still exported"
 
 
 def test_page_admission_defers_under_pressure_and_fails_oversize():
@@ -262,7 +294,7 @@ def test_page_admission_defers_under_pressure_and_fails_oversize():
     config.set("serve_kv_pool_pages", 2)
     try:
         srv = LLMServer(LLMConfig(
-            model_id="gpt2-tiny", max_batch_size=4, paged_kv=True,
+            model_id="gpt2-tiny", max_batch_size=4,
         ))
     finally:
         config.set("serve_kv_pool_pages", 0)
@@ -295,7 +327,7 @@ def test_page_admission_defers_under_pressure_and_fails_oversize():
     config.set("serve_kv_pool_pages", 1)
     try:
         tiny = LLMServer(LLMConfig(
-            model_id="gpt2-tiny", max_batch_size=4, paged_kv=True,
+            model_id="gpt2-tiny", max_batch_size=4,
         ))
     finally:
         config.set("serve_kv_pool_pages", 0)
@@ -316,7 +348,7 @@ def test_pages_released_exactly_once_under_cancel_and_unload():
     from ray_tpu.serve.llm import LLMConfig, LLMServer
 
     srv = LLMServer(LLMConfig(
-        model_id="gpt2-tiny", max_batch_size=4, paged_kv=True,
+        model_id="gpt2-tiny", max_batch_size=4,
     ))
     pool = srv._prefix_pool
     handout = collections.Counter()
@@ -390,7 +422,7 @@ def test_chunked_prefill_keeps_live_stream_producing():
     srv = None
     try:
         srv = LLMServer(LLMConfig(
-            model_id="gpt2-tiny-long", max_batch_size=4, paged_kv=True,
+            model_id="gpt2-tiny-long", max_batch_size=4,
         ))
         rng = np.random.RandomState(37)
         short = [int(t) for t in rng.randint(0, 256, 16)]

@@ -1,9 +1,10 @@
 """Prefix KV cache (serve/prefix_cache.py + the engine's prefix-aware
 admission): chain-hash determinism (including across processes — the
-router's affinity hint and multi-replica pools depend on it), the
-refcount/LRU pool contract, and the serving guarantee: admitting a
-request from cached blocks produces bitwise-identical generations at
-temperature=0, under slot churn, and with the kill switch flipped."""
+router's affinity hint and multi-replica pools depend on it) and the
+serving guarantee: admitting a request from cached pages produces
+bitwise-identical generations at temperature=0, under row churn, and
+with the kill switch flipped. The pool's own refcount/LRU contract is
+held by tests/test_paged_kv.py."""
 
 import json
 import subprocess
@@ -12,8 +13,9 @@ import threading
 
 import numpy as np
 import pytest
+from _llm_reference import engine_reference
 
-from ray_tpu.serve.prefix_cache import BlockPool, hash_blocks
+from ray_tpu.serve.prefix_cache import hash_blocks
 
 
 # ---------------------------------------------------------------------------
@@ -55,75 +57,6 @@ def test_hash_blocks_deterministic_across_processes():
 
 
 # ---------------------------------------------------------------------------
-# block pool: refcounts + LRU
-# ---------------------------------------------------------------------------
-
-
-def _blk(i):
-    k = np.full((2, 4, 2, 2), i, np.float32)
-    return k, -k
-
-
-def test_pool_match_increfs_and_caps():
-    pool = BlockPool("m", block_tokens=4, max_blocks=8)
-    for d in ("a", "b"):
-        pool.insert(d, *_blk(1))
-    pool.release(["a", "b"])
-    held, ks, vs = pool.match(["a", "b", "x"], max_tokens=100)
-    assert held == ["a", "b"] and len(ks) == 2
-    assert pool.ref_count("a") == pool.ref_count("b") == 1
-    # chain walk stops at the first absent digest
-    held2, _, _ = pool.match(["a", "x", "b"], max_tokens=100)
-    assert held2 == ["a"] and pool.ref_count("a") == 2
-    # the cap: fewer than block_tokens usable tokens -> nothing matched
-    assert pool.match(["a"], max_tokens=3)[0] == []
-    pool.release(["a", "b"])
-    pool.release(["a"])
-    assert pool.ref_count("a") == 0
-    st = pool.stats()
-    assert st["hits"] == 3 and st["misses"] == 4
-    pool.close()
-
-
-def test_pool_lru_eviction_prefers_oldest_unreferenced():
-    pool = BlockPool("m", block_tokens=4, max_blocks=2)
-    for d in ("a", "b"):
-        pool.insert(d, *_blk(1))
-    pool.release(["a", "b"])
-    pool.match(["b"], max_tokens=100)  # touch b: a is now LRU
-    pool.release(["b"])
-    pool.insert("c", *_blk(2))
-    assert pool.resident() == 2
-    assert pool.ref_count("a") == 0 and pool.match(["a"], 100)[0] == []
-    assert pool.match(["b"], 100)[0] == ["b"]  # survived: recently used
-    assert pool.stats()["evictions"] == 1
-    pool.close()
-
-
-def test_pool_pinned_blocks_survive_overflow():
-    """Refs pin blocks: a pool over capacity with every block in use by
-    in-flight slots evicts nothing (and recovers once refs drop)."""
-    pool = BlockPool("m", block_tokens=4, max_blocks=2)
-    for d in ("a", "b", "c", "d"):
-        pool.insert(d, *_blk(1))  # all held: caller keeps one ref each
-    assert pool.resident() == 4 and pool.stats()["evictions"] == 0
-    pool.release(["a", "b", "c", "d"])
-    assert pool.resident() == 2  # drained back to capacity, LRU-first
-    assert pool.match(["d"], 100)[0] == ["d"]
-    pool.close()
-
-
-def test_pool_close_drops_everything_despite_refs():
-    pool = BlockPool("m", block_tokens=4, max_blocks=8)
-    pool.insert("a", *_blk(1))  # ref held
-    pool.close()
-    assert pool.resident() == 0
-    # closed pools neither match nor re-admit
-    pool.insert("b", *_blk(2))
-    assert pool.resident() == 0 and pool.match(["a"], 100)[0] == []
-
-
-# ---------------------------------------------------------------------------
 # engine-level: cached admission == cold prefill, bit for bit
 # ---------------------------------------------------------------------------
 
@@ -140,36 +73,37 @@ def engine():
     srv._stop.set()
 
 
-def test_cached_vs_cold_generations_bitwise_identical(engine):
-    """The acceptance property: a prompt admitted from pooled blocks +
+@pytest.mark.parametrize("n", [100, 128, 65])
+def test_cached_vs_cold_generations_bitwise_identical(engine, n):
+    """The acceptance property: a prompt admitted from pooled pages +
     tail prefill generates EXACTLY the tokens full prefill generates at
-    temperature=0 — including a block-aligned prompt (capped match) and
-    with the kill switch off."""
+    temperature=0, which are the full forward's — including a
+    block-aligned prompt (capped match) and with the kill switch off."""
     from ray_tpu.utils.config import config
 
-    rng = np.random.RandomState(11)
-    for n in (100, 128, 65):
-        prompt = [int(t) for t in rng.randint(0, 256, n)]
-        req = {"prompt_tokens": prompt, "max_new_tokens": 8,
-               "temperature": 0.0}
-        pool = engine._prefix_pool
-        h0 = pool.stats()["hits"]
-        cold = engine(req)["tokens"]
-        hot = engine(req)["tokens"]
-        assert hot == cold
-        assert pool.stats()["hits"] > h0  # second pass came from cache
-        config.set("serve_prefix_cache", False)
-        try:
-            off = engine(req)["tokens"]
-        finally:
-            config.set("serve_prefix_cache", True)
-        assert off == cold
+    rng = np.random.RandomState(11 + n)
+    prompt = [int(t) for t in rng.randint(0, 256, n)]
+    req = {"prompt_tokens": prompt, "max_new_tokens": 8,
+           "temperature": 0.0}
+    pool = engine._prefix_pool
+    h0 = pool.stats()["hits"]
+    cold = engine(req)["tokens"]
+    assert cold == engine_reference(engine, prompt, 8)
+    hot = engine(req)["tokens"]
+    assert hot == cold
+    assert pool.stats()["hits"] > h0  # second pass came from cache
+    config.set("serve_prefix_cache", False)
+    try:
+        off = engine(req)["tokens"]
+    finally:
+        config.set("serve_prefix_cache", True)
+    assert off == cold
 
 
 def test_refcounts_drain_under_slot_churn(engine):
-    """Concurrent requests sharing a prefix churn through the KV slots;
-    when they all finish, every pooled block's refcount is back to 0
-    (nothing leaks pins) and the shared blocks are still resident."""
+    """Concurrent requests sharing a prefix churn through the decode
+    rows; when they all finish, every page's refcount is back to 0
+    (nothing leaks pins) and the shared pages are still resident."""
     rng = np.random.RandomState(12)
     shared = [int(t) for t in rng.randint(0, 256, 64)]
     solo = {}
@@ -193,11 +127,6 @@ def test_refcounts_drain_under_slot_churn(engine):
     pool = engine._prefix_pool
     assert pool.resident() > 0
     with pool._lock:
-        if hasattr(pool, "_pages"):  # PagedKVPool (paged engine default)
-            assert all(p.refs == 0 for p in pool._pages), {
-                p.idx: p.refs for p in pool._pages if p.refs
-            }
-        else:  # BlockPool (RT_SERVE_PAGED_KV=0 slot engine)
-            assert all(b.refs == 0 for b in pool._blocks.values()), {
-                b.digest: b.refs for b in pool._blocks.values() if b.refs
-            }
+        assert all(p.refs == 0 for p in pool._pages), {
+            p.idx: p.refs for p in pool._pages if p.refs
+        }

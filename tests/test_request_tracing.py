@@ -161,7 +161,7 @@ def test_llm_serving_metrics_populated(front):
     )
     tokens = mx.get("rt_serve_tokens_generated_total", {}).get("series", {})
     assert sum(tokens.values()) >= 8
-    assert mx.get("rt_serve_kv_slots_occupied", {}).get("series"), mx.keys()
+    assert mx.get("rt_serve_kv_pages_occupied", {}).get("series"), mx.keys()
     assert mx.get("rt_serve_queued_requests", {}).get("series")
     fill = mx.get("rt_serve_batch_fill", {}).get("series", {})
     assert any(s["count"] >= 1 for s in fill.values())

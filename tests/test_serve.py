@@ -317,7 +317,7 @@ def test_llm_deployment_batched_generation(rt):
     from ray_tpu.serve.llm import LLMConfig, build_llm_deployment
 
     app = build_llm_deployment(LLMConfig(
-        model_id="gpt2-tiny", max_batch_size=8, batch_wait_timeout_s=0.05,
+        model_id="gpt2-tiny", max_batch_size=8,
     ))
     handle = serve.run(app)
     req = {"prompt_tokens": [1, 2, 3], "max_new_tokens": 5}
