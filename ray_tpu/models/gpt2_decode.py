@@ -22,6 +22,13 @@ decode step runs all S rows batched whether or not each is active
 (masked), which is exactly the static-batch regime the MXU wants.
 Continuous batching lives OUTSIDE jit (the engine loop admits requests
 between steps; serve/llm.py drives it).
+
+Weights: an engine holds them in the type it computes in.
+``load_serving_params`` casts once, at load (``serving_params`` is the
+rule), so no program converts the stack per call. The ``.astype(dt)`` at
+every use below stay all the same: on a leaf already in ``cfg.dtype``
+they are nothing, and the benchmark's ``--check`` hands these programs
+the float32 tree of ``gpt2.init``, which must give the same numbers.
 """
 
 from __future__ import annotations
@@ -34,6 +41,61 @@ import jax.numpy as jnp
 
 from ray_tpu.models import gpt2
 from ray_tpu.models.gpt2 import GPT2Config, _layernorm
+
+
+def serving_params(cfg: GPT2Config, params):
+    """``params`` as a serving engine stores them: the leaves the programs
+    below cast at their use (``wte``, ``wpe``, every kernel and bias under
+    ``blocks.attn`` and ``blocks.mlp``) in ``cfg.dtype``, once. ``ln1``,
+    ``ln2`` and ``ln_f`` stay as they are: ``_layernorm`` multiplies them
+    in float32, so casting them would be a lower precision. Every product
+    already reads ``leaf.astype(cfg.dtype)``; this stores what that
+    returns, so the programs' outputs are the same bits."""
+    def cast(tree):
+        return jax.tree.map(lambda a: jnp.asarray(a, cfg.dtype), tree)
+
+    blocks = params["blocks"]
+    return {
+        **params,
+        "wte": cast(params["wte"]),
+        "wpe": cast(params["wpe"]),
+        "blocks": {**blocks, "attn": cast(blocks["attn"]),
+                   "mlp": cast(blocks["mlp"])},
+    }
+
+
+def compile_init(cfg: GPT2Config, key):
+    """``gpt2.init`` and the cast as one program, so that the
+    ``param_dtype`` tree never exists on the device. Compiled without
+    XLA's algebraic simplifier: it folds ``(erf_inv(u) * sqrt(2)) * std``
+    into one constant, which moves a float32 value in fourteen by an ulp
+    and, once rounded, a few weights in a million; without it the
+    program returns eager ``gpt2.init``'s values to the bit."""
+    init = jax.jit(lambda k: serving_params(cfg, gpt2.init(k, cfg)))
+    return init.lower(key).compile(
+        compiler_options={"xla_disable_hlo_passes": "algsimp"}
+    )
+
+
+def load_serving_params(cfg: GPT2Config, checkpoint_path=None):
+    """The weights of an engine of ``cfg``, on the device, as
+    ``serving_params`` stores them: a pickled tree from
+    ``checkpoint_path`` cast leaf by leaf, else ``gpt2.init`` from
+    ``PRNGKey(0)`` (``compile_init``)."""
+    if checkpoint_path:
+        import pickle
+
+        with open(checkpoint_path, "rb") as f:
+            held = serving_params(cfg, pickle.load(f))
+        # the leaves the cast left alone may still be the pickle's NumPy
+        return jax.tree.map(jnp.asarray, held)
+    key = jax.random.PRNGKey(0)
+    return compile_init(cfg, key)(key)
+
+
+def params_bytes(params) -> int:
+    """Bytes the tree's leaves hold."""
+    return sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(params))
 
 
 def _qkv(h, layer, cfg: GPT2Config):

@@ -62,18 +62,13 @@ class PrefillEngine:
         import jax
 
         from ray_tpu.models import gpt2
+        from ray_tpu.models import gpt2_decode as dec
         from ray_tpu.serve import prefix_cache
         from ray_tpu.utils.config import config
 
         self.cfg = cfg
         self.model_cfg = gpt2.CONFIGS[cfg.model_id]
-        if cfg.checkpoint_path:
-            import pickle
-
-            with open(cfg.checkpoint_path, "rb") as f:
-                self.params = pickle.load(f)
-        else:
-            self.params = gpt2.init(jax.random.PRNGKey(0), self.model_cfg)
+        self.params = dec.load_serving_params(self.model_cfg, cfg.checkpoint_path)
         self._rng = jax.random.PRNGKey(1)
         B = int(config.serve_prefix_block_tokens)
         max_pages = -(-self.model_cfg.n_positions // B)

@@ -193,13 +193,22 @@ def _upload(*host_arrays):
 
 class LLMServer:
     """The deployment callable: continuous-batched decode over one paged
-    KV pool, one chunk dispatched ahead of its harvest."""
+    KV pool, one chunk dispatched ahead of its harvest.
+
+    ``params`` are held on the device in the type the programs compute
+    in (``model_cfg.dtype``), cast once at load by
+    ``gpt2_decode.load_serving_params``; ``ln1``, ``ln2`` and ``ln_f``
+    keep ``param_dtype``. The programs still cast at every use, which
+    costs nothing here and lets the benchmark's ``--check`` hand them
+    the float32 tree; ``batch_stats()["weights_bytes"]`` says what the
+    tree holds."""
 
     def __init__(self, config: LLMConfig):
         import jax
 
         from ray_tpu.accelerators.tpu import require_leased_platform
         from ray_tpu.models import gpt2
+        from ray_tpu.models import gpt2_decode as dec
         from ray_tpu.serve import prefix_cache
         from ray_tpu.utils.config import config as rtcfg
 
@@ -207,13 +216,9 @@ class LLMServer:
         t_load = time.monotonic()
         self.cfg = config
         self.model_cfg = gpt2.CONFIGS[config.model_id]
-        if config.checkpoint_path:
-            import pickle
-
-            with open(config.checkpoint_path, "rb") as f:
-                self.params = pickle.load(f)
-        else:
-            self.params = gpt2.init(jax.random.PRNGKey(0), self.model_cfg)
+        self.params = dec.load_serving_params(
+            self.model_cfg, config.checkpoint_path
+        )
         self._rng = jax.random.PRNGKey(1)
 
         self._queue: collections.deque = collections.deque()
@@ -364,6 +369,8 @@ class LLMServer:
                 self._work.set()  # wake the engine to reap the row
 
     def batch_stats(self, _payload=None) -> Dict[str, Any]:
+        from ray_tpu.models import gpt2_decode as dec
+
         with self._lock:
             sizes = list(self._batch_sizes)
             total = self._total_batches
@@ -378,6 +385,10 @@ class LLMServer:
             # any request compiled anything)
             "devices": self._devices,
             "load_s": self._load_s,
+            # bytes the parameters hold on the device: half of what
+            # ``param_dtype`` would take where the engine computes in
+            # bfloat16, so a regression to float32 shows without a trace
+            "weights_bytes": dec.params_bytes(self.params),
             # what the decode programs attend over: the page pool itself
             # under an ownership mask (gpt2_decode), no row gather. Kept
             # so a reader of two trees' numbers can tell which body ran.
