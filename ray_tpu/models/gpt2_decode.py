@@ -6,14 +6,16 @@ native JAX engine: a prefill/decode split over a paged, static-shape KV
 pool, so generating token N costs one single-token forward over cached
 K/V instead of re-running the whole prefix.
 
-vLLM-style paged attention at the jnp level: physical KV pages
-``[L, N_pages, B, H, Dh]`` in HBM, per-sequence page tables
-``[S, MaxPages]`` mapping virtual position p to physical row
-(table[p // B], p % B). A prefix-cache hit points the table at pages
-another sequence already wrote (zero copies); admission reserves
+vLLM-style paged attention at the jnp level: physical KV pages of B
+positions in HBM (``PagePool``: ``[L, H * Dh, N_pages * B]``, positions
+minor; ``init_paged_cache`` decides the stored shape and only this file
+knows it), per-sequence page tables ``[S, MaxPages]`` mapping virtual
+position p to physical position table[p // B] * B + p % B. A
+prefix-cache hit points the table at pages another sequence already
+wrote (zero copies); admission reserves
 ceil(tokens/B) pages up front so tables never change mid-flight.
 Page 0 is reserved scratch: inactive rows carry all-zero tables and
-length 0, so their junk scatters land there and the jitted step needs
+length 0, so their junk writes land there and the jitted step needs
 no validity branch.
 
 TPU-first shape discipline: the pool, the row count S and the table
@@ -33,6 +35,7 @@ the float32 tree of ``gpt2.init``, which must give the same numbers.
 
 from __future__ import annotations
 
+import dataclasses
 from functools import partial
 from typing import Any, Dict, Tuple
 
@@ -160,21 +163,112 @@ def update_rows_paged(last_tokens, lengths, temps, greedy_mask,
     )
 
 
+@partial(jax.tree_util.register_dataclass, data_fields=["data"],
+         meta_fields=["page_tokens"])
+@dataclasses.dataclass(frozen=True)
+class PagePool:
+    """One page pool, K or V, as the device stores it: ``data``
+    [n_layer, H * Dh, N_pages * B] in the compute dtype, positions minor
+    (page n holds positions n * B .. n * B + B - 1), and B beside it,
+    which that shape cannot say.
+
+    The shape decides the layout the device keeps an array in between
+    calls, and the layer loops read a layer with positions in lanes:
+    both attention products are emitted that way. Stored
+    ``[L, N, B, H, Dh]`` (the TPU's default for minor dimensions 25 x 64
+    is page-minor) every program relaid both pools whole on the way in
+    and again on the way out, 27 ms of a 45 ms step at gpt2-xl; stored
+    ``[L, N, B, H * Dh]`` the loops transposed each layer instead,
+    23 ms a step (PERF.md, PR 35). In this shape a program reads the
+    pool as it lies and writes it in place, ``_write_layer``."""
+
+    data: jax.Array
+    page_tokens: int
+
+
 def init_paged_cache(cfg: GPT2Config, num_pages: int, page_tokens: int):
-    """(k, v) page pools: [n_layer, N_pages, B, H, Dh], compute dtype."""
-    shape = (cfg.n_layer, num_pages, page_tokens, cfg.n_head, cfg.head_dim)
-    return jnp.zeros(shape, cfg.dtype), jnp.zeros(shape, cfg.dtype)
+    """(k, v) page pools, zeroed: the one place that decides the shape a
+    pool is stored in (``PagePool``)."""
+    shape = (cfg.n_layer, cfg.n_head * cfg.head_dim, num_pages * page_tokens)
+    return (PagePool(jnp.zeros(shape, cfg.dtype), page_tokens),
+            PagePool(jnp.zeros(shape, cfg.dtype), page_tokens))
+
+
+def _writers(phys, n_positions: int, dtype):
+    """Who writes where, for ``_write_layer``: ``phys`` [P] is the
+    physical position each of P new K/V rows goes to. Returns ``sel``
+    [P, n_positions], one where row p writes position t, and ``hit``
+    [n_positions], true where any row does. Rows that name the same
+    position (junk headed for the scratch page) are settled here, one
+    writer a position, so the product below copies and never sums."""
+    P = phys.shape[0]
+    rows = jnp.arange(P, dtype=jnp.int32)
+    writer = jnp.full((n_positions,), -1, jnp.int32).at[phys].set(rows)
+    return (writer[None, :] == rows[:, None]).astype(dtype), writer >= 0
+
+
+def _write_layer(data, layer_idx, new, sel, hit):
+    """Write ``new`` [P, H, Dh] into layer ``layer_idx`` of ``data`` at
+    the positions ``_writers`` settled, in place on the donated carry.
+    Returns the pool and the written layer [H * Dh, N * B].
+
+    Not ``data.at[layer_idx, :, phys].set(new)``: the compiler does not
+    scatter along a minor dimension in place, it relays the whole pool
+    to width-minor for the scatter and back (the four whole-pool copies
+    again, and a transposition of the layer in every turn of the loop;
+    measured, PERF.md PR 35). The layer is read whole for the attention
+    anyway; this rewrites it through a select and puts it back, one
+    more pass over 20 MB a layer at gpt2-xl instead of a relay. ``sel``
+    holds one 1 a written position, so the product copies a value and
+    sums nothing (a row that is not finite would reach the other rows'
+    new positions through it, where a scatter kept rows apart)."""
+    new = new.reshape(new.shape[0], -1).astype(data.dtype)
+    old = jax.lax.dynamic_index_in_dim(data, layer_idx, 0, keepdims=False)
+    layer = jnp.where(hit[None], jnp.einsum("pd,pt->dt", new, sel), old)
+    return jax.lax.dynamic_update_index_in_dim(data, layer, layer_idx, 0), layer
 
 
 @partial(jax.jit, donate_argnums=(2, 3))
 def write_pages(k_blocks, v_blocks, cache_k, cache_v, pages):
     """Batched page import (disaggregated KV shipment): write
     ``k_blocks``/``v_blocks`` [L, n, B, H, Dh] into physical pages
-    ``pages`` [n] of the pool. The ONLY block-copy path left in the
-    paged engine — prefix hits bump refcounts instead."""
-    ck = cache_k.at[:, pages].set(k_blocks.astype(cache_k.dtype))
-    cv = cache_v.at[:, pages].set(v_blocks.astype(cache_v.dtype))
-    return ck, cv
+    ``pages`` [n] of the pools, converting those few pages to the stored
+    shape. The ONLY block-copy path left in the paged engine — prefix
+    hits bump refcounts instead."""
+    B = cache_k.page_tokens
+
+    def put(pool, blocks):
+        L, n = blocks.shape[:2]
+        # [L, n, B, H, Dh] -> n pages of [L, H * Dh, B]
+        blocks = blocks.reshape(L, n, B, -1).transpose(1, 0, 3, 2)
+        data = pool.data
+        for j in range(n):
+            data = jax.lax.dynamic_update_slice_in_dim(
+                data, blocks[j].astype(data.dtype), pages[j] * B, axis=2
+            )
+        return PagePool(data, B)
+
+    return put(cache_k, k_blocks), put(cache_v, v_blocks)
+
+
+@partial(jax.jit, static_argnums=(0,))
+def read_pages(cfg: GPT2Config, cache_k, cache_v, pages):
+    """Batched page export, ``write_pages``' inverse: physical pages
+    ``pages`` [n] of the pools as ``(k, v)`` blocks [L, n, B, H, Dh].
+    Converts those few pages, not the pool, and donates nothing: the
+    exporter goes on serving from its pools."""
+    B = cache_k.page_tokens
+
+    def get(pool):
+        got = jnp.stack([
+            jax.lax.dynamic_slice_in_dim(pool.data, pages[j] * B, B, axis=2)
+            for j in range(pages.shape[0])
+        ])  # [n, L, H * Dh, B]
+        return got.transpose(1, 0, 3, 2).reshape(
+            got.shape[1], got.shape[0], B, cfg.n_head, cfg.head_dim
+        )
+
+    return get(cache_k), get(cache_v)
 
 
 @partial(jax.jit, static_argnums=(0,), donate_argnums=(5, 6))
@@ -186,14 +280,15 @@ def prefill_paged(cfg: GPT2Config, params, tokens, start, length, cache_k,
     [MaxPages]; pages holding positions 0..start-1 are already written
     (a prefix hit, a KV import, or this sequence's previous chunk —
     chunked prefill is just repeated calls with advancing ``start``).
-    Scatters the chunk's K/V through the page table, attends the chunk
-    over the whole gathered row, and returns the last real position's
-    logits [vocab] plus the updated pools.
+    Writes the chunk's K/V through the page table, attends the chunk
+    over the sequence's own pages (one layer's ``MaxPages`` pages taken
+    through the table, never the pool), and returns the last real
+    position's logits [vocab] plus the updated pools.
 
     The caller guarantees start + P <= MaxPages * B (bucket the chunk
     width against that cap); positions past the sequence's reserved
     pages hit table entries that are 0 = the scratch page, so padding
-    scatters are harmless.
+    writes are harmless.
 
     fori_loop (not scan) over layers, here and in the decode step, so
     the cache updates are IN-PLACE on the donated carry — a scan would
@@ -201,7 +296,7 @@ def prefill_paged(cfg: GPT2Config, params, tokens, start, length, cache_k,
     call (measured 300x slower at gpt2-small)."""
     dt = cfg.dtype
     P = tokens.shape[1]
-    B = cache_k.shape[2]
+    B = cache_k.page_tokens
     max_pages = page_table.shape[0]
     T = max_pages * B  # virtual row width
     W = params["wpe"].shape[0]
@@ -213,7 +308,16 @@ def prefill_paged(cfg: GPT2Config, params, tokens, start, length, cache_k,
     # chunk position start+i may attend every written position 0..start+i
     mask = jnp.arange(T)[None] <= pos[:, None]  # [P, T]
     page_of = page_table[jnp.clip(pos // B, 0, max_pages - 1)]  # [P]
-    off = pos % B
+    sel, hit = _writers(page_of * B + pos % B, cache_k.data.shape[2], dt)
+
+    def row_of(layer):
+        """The sequence's pages of a written layer [H * Dh, N * B], in
+        table order: [H, Dh, T]. A slice a page, so that the layer is
+        not relaid for a gather."""
+        return jnp.concatenate([
+            jax.lax.dynamic_slice_in_dim(layer, page_table[j] * B, B, axis=1)
+            for j in range(max_pages)
+        ], axis=1).reshape(cfg.n_head, cfg.head_dim, T)
 
     def body(layer_idx, carry):
         x, ck, cv = carry
@@ -225,28 +329,23 @@ def prefill_paged(cfg: GPT2Config, params, tokens, start, length, cache_k,
         )
         h = _layernorm(x, layer["ln1"]["scale"], layer["ln1"]["bias"])
         q, k, v = _qkv(h, layer, cfg)  # [1, P, H, Dh]
-        # scatter the chunk's K/V through the page table (in place on
-        # the donated carry), then gather the whole virtual row so the
+        # write the chunk's K/V through the page table (in place on
+        # the donated carry), then take the sequence's pages so the
         # chunk sees prefix pages it never computed
-        ck = ck.at[layer_idx, page_of, off].set(k[0].astype(dt))
-        cv = cv.at[layer_idx, page_of, off].set(v[0].astype(dt))
-        ck_l = jax.lax.dynamic_index_in_dim(
-            ck, layer_idx, axis=0, keepdims=False
-        )[page_table].reshape(T, cfg.n_head, cfg.head_dim)[None]
-        cv_l = jax.lax.dynamic_index_in_dim(
-            cv, layer_idx, axis=0, keepdims=False
-        )[page_table].reshape(T, cfg.n_head, cfg.head_dim)[None]
+        ck, k_l = _write_layer(ck, layer_idx, k[0], sel, hit)
+        cv, v_l = _write_layer(cv, layer_idx, v[0], sel, hit)
         scale = 1.0 / (cfg.head_dim ** 0.5)
-        scores = jnp.einsum("bthn,bshn->bhts", q, ck_l) * scale
+        scores = jnp.einsum("bthn,hns->bhts", q, row_of(k_l)) * scale
         scores = jnp.where(mask[None, None], scores, -1e30)
         probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(dt)
-        att = jnp.einsum("bhts,bshn->bthn", probs, cv_l)
+        att = jnp.einsum("bhts,hns->bthn", probs, row_of(v_l))
         x = _proj_mlp(x, att, layer, cfg)
         return x, ck, cv
 
-    x, cache_k, cache_v = jax.lax.fori_loop(
-        0, cfg.n_layer, body, (x, cache_k, cache_v)
+    x, data_k, data_v = jax.lax.fori_loop(
+        0, cfg.n_layer, body, (x, cache_k.data, cache_v.data)
     )
+    cache_k, cache_v = PagePool(data_k, B), PagePool(data_v, B)
     x = _layernorm(x, params["ln_f"]["scale"], params["ln_f"]["bias"])
     last = jax.lax.dynamic_index_in_dim(
         x[0], jnp.maximum(length - 1, 0), axis=0, keepdims=False
@@ -261,7 +360,7 @@ def prefill_paged(cfg: GPT2Config, params, tokens, start, length, cache_k,
 def _decode_paged_impl(cfg: GPT2Config, params, last_tokens, lengths,
                        cache_k, cache_v, page_tables):
     """One token for every sequence over paged KV: [S] last tokens at
-    virtual positions ``lengths`` scatter their new K/V through
+    virtual positions ``lengths`` write their new K/V through
     ``page_tables`` [S, MaxPages] and attend over the pool layer as it
     lies, every position of every page, under a mask that says which
     positions a row owns. No row's context is gathered: the work grows
@@ -271,7 +370,8 @@ def _decode_paged_impl(cfg: GPT2Config, params, last_tokens, lengths,
     the updated pools."""
     dt = cfg.dtype
     S = last_tokens.shape[0]
-    N, B = cache_k.shape[1], cache_k.shape[2]
+    B = cache_k.page_tokens
+    N = cache_k.data.shape[2] // B
     max_pages = page_tables.shape[1]
     T = max_pages * B
     W = params["wpe"].shape[0]
@@ -282,7 +382,7 @@ def _decode_paged_impl(cfg: GPT2Config, params, last_tokens, lengths,
     )  # [S, 1, D]
     rows = jnp.arange(S)
     page_of = page_tables[rows, pos // B]  # [S]
-    off = pos % B
+    sel, hit = _writers(page_of * B + pos % B, N * B, dt)
     # col[s, n]: the column at which page n stands in row s's table, -1
     # where it does not. Every unused table entry names page 0 (scratch),
     # so page 0 is nobody's; a prefix page shared by several rows is
@@ -307,29 +407,26 @@ def _decode_paged_impl(cfg: GPT2Config, params, last_tokens, lengths,
         )
         h = _layernorm(x, layer["ln1"]["scale"], layer["ln1"]["bias"])
         q, k, v = _qkv(h, layer, cfg)  # [S, 1, H, Dh]
-        # in-place scatter of the new token's K/V through the tables
+        # in-place write of the new token's K/V through the tables
         # (inactive rows have zero tables: their junk lands in the
         # scratch page, and owning nothing they attend uniformly over
         # the pool's finite contents)
-        ck = ck.at[layer_idx, page_of, off].set(k[:, 0].astype(dt))
-        cv = cv.at[layer_idx, page_of, off].set(v[:, 0].astype(dt))
-        ck_l = jax.lax.dynamic_index_in_dim(
-            ck, layer_idx, axis=0, keepdims=False
-        ).reshape(N * B, cfg.n_head, cfg.head_dim)
-        cv_l = jax.lax.dynamic_index_in_dim(
-            cv, layer_idx, axis=0, keepdims=False
-        ).reshape(N * B, cfg.n_head, cfg.head_dim)
+        ck, k_l = _write_layer(ck, layer_idx, k[:, 0], sel, hit)
+        cv, v_l = _write_layer(cv, layer_idx, v[:, 0], sel, hit)
+        k_l = k_l.reshape(cfg.n_head, cfg.head_dim, N * B)
+        v_l = v_l.reshape(cfg.n_head, cfg.head_dim, N * B)
         scale = 1.0 / (cfg.head_dim ** 0.5)
-        scores = jnp.einsum("shn,thn->sht", q[:, 0], ck_l) * scale
+        scores = jnp.einsum("shn,hnt->sht", q[:, 0], k_l) * scale
         scores = jnp.where(mask[:, None, :], scores, -1e30)
         probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(dt)
-        att = jnp.einsum("sht,thn->shn", probs, cv_l)[:, None]
+        att = jnp.einsum("sht,hnt->shn", probs, v_l)[:, None]
         x = _proj_mlp(x, att, layer, cfg)
         return x, ck, cv
 
-    x, cache_k, cache_v = jax.lax.fori_loop(
-        0, cfg.n_layer, body, (x, cache_k, cache_v)
+    x, data_k, data_v = jax.lax.fori_loop(
+        0, cfg.n_layer, body, (x, cache_k.data, cache_v.data)
     )
+    cache_k, cache_v = PagePool(data_k, B), PagePool(data_v, B)
     x = _layernorm(x, params["ln_f"]["scale"], params["ln_f"]["bias"])
     logits = jnp.einsum(
         "sd,vd->sv", x[:, 0].astype(dt), params["wte"].astype(dt),
@@ -354,18 +451,33 @@ def decode_paged_and_sample(cfg: GPT2Config, params, last_tokens, lengths,
     return nxt, lengths + 1, cache_k, cache_v
 
 
-@partial(jax.jit, static_argnums=(0, 10), donate_argnums=(4, 5))
+# the most steps one K-chunk dispatch runs: the engine's K rule caps K
+# here, and ``decode_multi_paged`` returns this many rows of tokens
+MAX_DECODE_CHUNK = 8
+
+
+@partial(jax.jit, static_argnums=(0,), donate_argnums=(4, 5))
 def decode_multi_paged(cfg: GPT2Config, params, last_tokens, lengths,
                        cache_k, cache_v, page_tables, temps, greedy_mask,
-                       rng_base, n_steps: int, step0):
+                       rng_base, n_steps, step0):
     """``n_steps`` tokens per sequence in ONE dispatch (fori_loop on
-    device), page-table scatter recomputed per step (the tables
+    device), the page-table write recomputed per step (the tables
     themselves are fixed — admission reserved every page up front).
     The engine picks K from the active rows' remaining budgets and drops
     to K=1 whenever requests are waiting for admission (continuous
-    batching latency stays one step)."""
+    batching latency stays one step).
+
+    ``n_steps`` (at most ``MAX_DECODE_CHUNK``) is an argument of the
+    program, not part of its signature: one program runs every K, and
+    the tokens come back as ``[MAX_DECODE_CHUNK, S]`` with the first
+    ``n_steps`` rows written. A program a K was seven compiles where
+    one does, and a K first met under load compiled there, stalling
+    every stream for seconds: once the step was fast enough for an
+    offline batch to empty the queue between generations, every K from
+    2 to 7 turned up in a cell that had warmed 1 and 8 (PERF.md,
+    PR 35)."""
     S = last_tokens.shape[0]
-    toks0 = jnp.zeros((n_steps, S), jnp.int32)
+    toks0 = jnp.zeros((MAX_DECODE_CHUNK, S), jnp.int32)
 
     def body(i, carry):
         last, lens, ck, cv, toks = carry

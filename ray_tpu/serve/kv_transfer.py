@@ -81,7 +81,7 @@ class PrefillEngine:
             page_tokens=B,
         )
         self._lock = threading.Lock()
-        self._cache_k = self._cache_v = None  # [L, N, B, H, Dh], lazy
+        self._cache_k = self._cache_v = None  # init_paged_cache's, lazy
 
     def prefill(self, prompt_tokens: List[int],
                 temperature: float) -> Dict[str, Any]:
@@ -152,16 +152,19 @@ class PrefillEngine:
                 )
                 first = self._sample_one(logits, temperature)
                 # shipment = gather of this sequence's pages (device
-                # gather + ONE host copy; no per-block host pool copies)
+                # gather + ONE host copy; no per-block host pool copies),
+                # in the wire's [L, T, H, Dh] whatever shape the pool
+                # is stored in
                 n = len(prompt)
-                row_k = np.asarray(
-                    self._cache_k[:, jnp.asarray(table[:n_pages])]
-                ).reshape(mcfg.n_layer, n_pages * B, mcfg.n_head,
-                          mcfg.head_dim)
-                row_v = np.asarray(
-                    self._cache_v[:, jnp.asarray(table[:n_pages])]
-                ).reshape(mcfg.n_layer, n_pages * B, mcfg.n_head,
-                          mcfg.head_dim)
+                row_k, row_v = (
+                    np.asarray(blocks).reshape(
+                        mcfg.n_layer, n_pages * B, mcfg.n_head, mcfg.head_dim
+                    )
+                    for blocks in dec.read_pages(
+                        mcfg, self._cache_k, self._cache_v,
+                        jnp.asarray(table[:n_pages]),
+                    )
+                )
                 n_full = n // B
                 for j in range(len(held_pages), min(n_full, len(digests))):
                     pool.seal(digests[j], int(pages[j]))

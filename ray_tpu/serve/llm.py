@@ -127,7 +127,9 @@ class _Chunk:
                  "free_after")
 
     def __init__(self, toks_dev, n_steps: int):
-        self.toks_dev = toks_dev  # [K, S] (or [S] when K == 1) on device
+        # on device: [S] when K == 1, else [MAX_DECODE_CHUNK, S] with
+        # the first K rows written
+        self.toks_dev = toks_dev
         self.n_steps = n_steps
         self.rows: List[tuple] = []  # (row, seq, finish_pending)
         self.by_row: Dict[int, Any] = {}
@@ -251,6 +253,8 @@ class LLMServer:
         self._started = threading.Event()
         self._start_error: Optional[BaseException] = None
         self._devices: List[Dict[str, Any]] = []
+        self._kv_pool_shape: List[int] = []
+        self._kv_pool_bytes = 0
         threading.Thread(
             target=self._run_engine, name="llm-engine", daemon=True,
         ).start()
@@ -278,6 +282,7 @@ class LLMServer:
         import jax
 
         jax.block_until_ready(pool)
+        pool = jax.tree.leaves(pool)
         held = {
             d for a in (*jax.tree.leaves(self.params), *pool)
             for d in a.devices()
@@ -291,6 +296,10 @@ class LLMServer:
             }
             for d in sorted(held, key=lambda d: d.id)
         ]
+        # the pools as init_paged_cache stored them and as the device
+        # holds them, tiling's padding included
+        self._kv_pool_shape = list(pool[0].shape)
+        self._kv_pool_bytes = sum(a.on_device_size_in_bytes() for a in pool)
         self._started.set()
 
     # -- request path ---------------------------------------------------
@@ -393,6 +402,11 @@ class LLMServer:
             # under an ownership mask (gpt2_decode), no row gather. Kept
             # so a reader of two trees' numbers can tell which body ran.
             "decode_attention": "pool",
+            # the shape one page pool is stored in and the bytes the
+            # device holds for both: a pool that went back to a padded
+            # or relaid form shows here without a trace
+            "kv_pool_shape": self._kv_pool_shape,
+            "kv_pool_bytes": self._kv_pool_bytes,
             "prefix": self._prefix_pool.stats(),
         }
 
@@ -512,10 +526,19 @@ class LLMServer:
                     blocked_s += time.monotonic() - t
 
         def _bucket(n: int, cap: int) -> int:
+            """Width of a prefill call for ``n`` tokens with ``cap``
+            positions of context left: the next power of two, and where
+            that would pass the context the power of two below it
+            (run_prefill's loop takes the rest in a further call). Every
+            width is a power of two: ``min(p, cap)`` made 192, 320 and
+            384 out of tails deep in a context, programs no warm-up
+            names, compiled under load where they were first met."""
             p = 16
             while p < n:
                 p *= 2
-            return min(p, cap)
+            while p > cap:
+                p //= 2
+            return p
 
         def take_pages(s: _PagedSeq) -> List[int]:
             # a sequence's pages leave it EXACTLY once, however many of
@@ -1021,7 +1044,7 @@ class LLMServer:
             # streams would stretch by the whole chunk).
             K = 1
             if not waiting and not prefilling:
-                K = max(1, min(8, min(
+                K = max(1, min(dec.MAX_DECODE_CHUNK, min(
                     seqs[i].budget_left for i in active
                 )))
             with tracing.span("rt/engine/dispatch", k=K, rows=len(active)):

@@ -65,8 +65,9 @@ def hash_blocks(tokens: Sequence[int], block_tokens: int) -> List[str]:
 
 class _Page:
     """Metadata for one device-resident KV page. The page's K/V content
-    lives in the engine's paged device cache (gpt2_decode.init_paged_cache
-    row ``idx``); the pool only tracks who may read it."""
+    lives in the engine's paged device cache (page ``idx`` of the pools
+    ``gpt2_decode.init_paged_cache`` returns, in the shape that function
+    stores them in); the pool only tracks who may read it."""
 
     __slots__ = ("idx", "refs", "digest", "tick")
 

@@ -159,6 +159,120 @@ def test_kv_engine_continuous_batching():
     srv._stop.set()
 
 
+def test_engine_reports_the_pool_it_holds():
+    """``batch_stats()`` names the shape one page pool is stored in and
+    the bytes the device holds for both: what ``init_paged_cache``
+    returns for the engine's page count, so that a pool that went back
+    to a padded or relaid form shows without a trace."""
+    import jax
+
+    jax.config.update("jax_platforms", "cpu")
+    from ray_tpu.models import gpt2_decode as dec
+    from ray_tpu.serve.llm import LLMConfig, LLMServer
+
+    srv = LLMServer(LLMConfig(model_id="gpt2-tiny", max_batch_size=2))
+    try:
+        pool = srv._prefix_pool
+        ck, cv = dec.init_paged_cache(
+            srv.model_cfg, pool.num_pages, pool.page_tokens
+        )
+        stats = srv.batch_stats()
+        held = jax.tree.leaves((ck, cv))
+        assert stats["kv_pool_shape"] == list(held[0].shape)
+        assert stats["kv_pool_bytes"] == sum(
+            a.on_device_size_in_bytes() for a in held
+        )
+        # on the CPU nothing is tiled: the bytes are those of two pools
+        # of L x pages x page_tokens x width elements
+        mcfg = srv.model_cfg
+        assert stats["kv_pool_bytes"] == (
+            2 * mcfg.n_layer * pool.num_pages * pool.page_tokens
+            * mcfg.n_head * mcfg.head_dim * held[0].dtype.itemsize
+        )
+    finally:
+        srv._stop.set()
+
+
+def test_exported_kv_imported_by_the_engine_decodes_to_the_reference():
+    """A prompt's KV exported by ``PrefillEngine`` (``read_pages`` of its
+    pages, shipped as ``[L, T, H, Dh]`` rows) and imported by the engine
+    (``write_pages``) decodes to the full forward's greedy tokens: the
+    wire's form is the same whatever shape either side stores its pool
+    in."""
+    import jax
+
+    jax.config.update("jax_platforms", "cpu")
+    from _llm_reference import engine_reference
+
+    from ray_tpu.serve.kv_transfer import PrefillEngine
+    from ray_tpu.serve.llm import LLMConfig, LLMServer
+
+    rng = np.random.RandomState(35)
+    prompt = [int(t) for t in rng.randint(0, 256, 75)]
+    pre = PrefillEngine(LLMConfig(model_id="gpt2-tiny"))
+    try:
+        ship = pre.prefill(prompt, 0.0)
+    finally:
+        pre._pool.close()
+    mcfg = pre.model_cfg
+    assert ship["k"].shape == (mcfg.n_layer, 75, mcfg.n_head, mcfg.head_dim)
+    assert ship["v"].shape == ship["k"].shape
+    srv = LLMServer(LLMConfig(model_id="gpt2-tiny", max_batch_size=2))
+    try:
+        ref = engine_reference(srv, prompt, 10)
+        assert ship["first_token"] == ref[0]
+        c0 = srv._prefix_pool.stats()["copies"]
+        out = srv({"prompt_tokens": prompt, "max_new_tokens": 10,
+                   "temperature": 0.0, "kv_import": dict(ship)})
+        assert srv._prefix_pool.stats()["copies"] > c0  # it was imported
+        assert out["tokens"] == ref
+    finally:
+        srv._stop.set()
+
+
+def test_every_prefill_width_is_a_power_of_two(monkeypatch):
+    """A tail deep in a context (80 tokens cached of 128, 40 to prefill:
+    the next power of two, 64, would pass the 48 positions left) is
+    prefilled in calls of 32 and 16, not in one of 48: a width that is
+    no power of two is a program no warm-up names, compiled under load
+    where it is first met. The answer is the full forward's."""
+    import jax
+
+    jax.config.update("jax_platforms", "cpu")
+    from _llm_reference import engine_reference
+
+    from ray_tpu.models import gpt2_decode as dec
+    from ray_tpu.serve.llm import LLMConfig, LLMServer
+    from ray_tpu.utils.config import config
+
+    keep = config.serve_prefix_block_tokens
+    config.set("serve_prefix_block_tokens", 16)
+    try:
+        srv = LLMServer(LLMConfig(model_id="gpt2-tiny", max_batch_size=2))
+    finally:
+        config.set("serve_prefix_block_tokens", keep)
+    widths = []
+    real = dec.prefill_paged
+
+    def seen(cfg, params, tokens, start, *rest):
+        widths.append((int(start), tokens.shape[1]))
+        return real(cfg, params, tokens, start, *rest)
+
+    monkeypatch.setattr(dec, "prefill_paged", seen)
+    rng = np.random.RandomState(36)
+    prompt = [int(t) for t in rng.randint(0, 256, 120)]
+    try:
+        srv({"prompt_tokens": prompt[:80], "max_new_tokens": 2,
+             "temperature": 0.0})
+        del widths[:]
+        out = srv({"prompt_tokens": prompt, "max_new_tokens": 6,
+                   "temperature": 0.0})
+        assert widths == [(80, 32), (112, 16)]
+        assert out["tokens"] == engine_reference(srv, prompt, 6)
+    finally:
+        srv._stop.set()
+
+
 @pytest.mark.parametrize("kwargs, removed", [
     ({"engine": "recompute"}, "recompute"),
     ({"paged_kv": False}, "slot KV engine"),

@@ -5,9 +5,21 @@ what the program did before and is kept below as the reference: same
 logits for live rows, same pools outside the scratch page, over the
 table shapes an engine produces; and a structural guard that the
 per-row gather (a tensor of rows x max_pages x page_tokens positions of
-K/V) cannot come back into the lowered program unnoticed."""
+K/V) cannot come back into the lowered program unnoticed.
+
+The pools are stored in the shape ``init_paged_cache`` decides
+(``gpt2_decode.PagePool``: positions minor, so that the device keeps
+them in the layout the layer loops use), and nothing here knows it. The
+reference below keeps the plain ``[L, N, B, H, Dh]``; ``_stored`` and
+``_plain`` convert between the two through ``write_pages`` and
+``read_pages``, as an engine does. Held here
+too: prefill at ``start > 0`` over pages another row wrote and K-chunk
+decode of rows sharing them, against the full forward; the two page
+functions' round trip; and, compiled for a described v5e at gpt2-xl's
+serving shapes, that no serving program copies a whole pool."""
 
 import re
+import signal
 
 import jax
 import jax.numpy as jnp
@@ -53,6 +65,27 @@ def _gather_reference(cfg, params, last_tokens, lengths, cache_k, cache_v,
     return logits[:, : cfg.vocab_size], cache_k, cache_v
 
 
+def _stored(cfg, plain_k, plain_v):
+    """Plain ``[L, N, B, H, Dh]`` arrays as the pools ``init_paged_cache``
+    would hold them."""
+    n, b = plain_k.shape[1:3]
+    ck, cv = dec.init_paged_cache(cfg, n, b)
+    return dec.write_pages(jnp.asarray(plain_k), jnp.asarray(plain_v), ck, cv,
+                           jnp.arange(n))
+
+
+def _plain(cfg, ck, cv, n):
+    """Stored pools of ``n`` pages as float32 ``[L, N, B, H, Dh]`` arrays,
+    through ``read_pages`` of every page."""
+    return tuple(np.asarray(a, np.float32)
+                 for a in dec.read_pages(cfg, ck, cv, jnp.arange(n)))
+
+
+def _form(pools):
+    """Shape and type of every array the pools hold."""
+    return jax.tree.map(lambda a: (a.shape, a.dtype), pools)
+
+
 def _tables(rows_pages):
     t = np.zeros((len(rows_pages), MAX_PAGES), np.int32)
     for r, pages in enumerate(rows_pages):
@@ -93,10 +126,18 @@ def test_pool_form_equals_the_per_row_gather(case, params):
         pool[:, :, 4:] *= 1e4
     ck, cv = (jnp.asarray(p, CFG.dtype) for p in pool)
     last = jnp.asarray(rng.integers(0, CFG.vocab_size, S), jnp.int32)
-    args = (CFG, params, last, jnp.asarray(lengths, jnp.int32), ck, cv,
-            jnp.asarray(tables))
-    got, gk, gv = jax.jit(dec._decode_paged_impl, static_argnums=0)(*args)
-    want, wk, wv = jax.jit(_gather_reference, static_argnums=0)(*args)
+    head = (CFG, params, last, jnp.asarray(lengths, jnp.int32))
+    sk, sv = _stored(CFG, ck, cv)
+    form = _form((sk, sv))
+    got, gk, gv = jax.jit(dec._decode_paged_impl, static_argnums=0)(
+        *head, sk, sv, jnp.asarray(tables)
+    )
+    # the pools come back as they went in
+    assert _form((gk, gv)) == form
+    gk, gv = _plain(CFG, gk, gv, num_pages)
+    want, wk, wv = jax.jit(_gather_reference, static_argnums=0)(
+        *head, ck, cv, jnp.asarray(tables)
+    )
     live = [r for r, pages in enumerate(rows_pages) if pages]
     got, want = np.asarray(got), np.asarray(want)
     assert np.isfinite(got).all()
@@ -135,9 +176,172 @@ def test_lowered_decode_holds_no_per_row_gather():
         cfg, p, sds((S,), jnp.int32), sds((S,), jnp.int32), ck, cv,
         sds((S, mp), jnp.int32),
     ).as_text()
-    assert f"{N}x{Bx}x{cfg.n_head}x{cfg.head_dim}" in text  # the pool is there
-    gathered = re.findall(
-        rf"tensor<(?:{S * mp}x{Bx}|{S}x{mp * Bx}|{S}x{mp}x{Bx})x{cfg.n_head}x{cfg.head_dim}x\w+>",
-        text,
+    pool = "x".join(map(str, jax.tree.leaves(ck)[0].shape))
+    assert f"tensor<{pool}x" in text  # the pool is there
+    # every row's context of one layer is this many elements of K or V,
+    # in whatever order a gather would lay them
+    row_contexts = S * mp * Bx * cfg.n_head * cfg.head_dim
+    gathered = [
+        t for t in set(re.findall(r"tensor<((?:\d+x)+)\w+>", text))
+        if np.prod([int(d) for d in t.split("x")[:-1]]) == row_contexts
+    ]
+    assert not gathered, sorted(gathered)
+
+
+def test_write_pages_then_read_pages_returns_the_blocks_to_the_bit():
+    """The two functions that convert between the wire's blocks
+    ``[L, n, B, H, Dh]`` and the stored pool are each other's inverse,
+    touch the named pages only, and hand back the stored shape."""
+    rng = np.random.default_rng(5)
+    N, pages = 7, np.asarray([5, 1, 3], np.int32)
+    ck, cv = dec.init_paged_cache(CFG, N, B)
+    form = _form((ck, cv))
+    blocks = rng.normal(0, 1, (2, CFG.n_layer, len(pages), B, CFG.n_head,
+                               CFG.head_dim)).astype(np.float32)
+    kb, vb = (jnp.asarray(b, CFG.dtype) for b in blocks)
+    ck, cv = dec.write_pages(kb, vb, ck, cv, jnp.asarray(pages))
+    assert _form((ck, cv)) == form
+    rk, rv = dec.read_pages(CFG, ck, cv, jnp.asarray(pages))
+    assert rk.shape == kb.shape and rk.dtype == CFG.dtype
+    np.testing.assert_array_equal(np.asarray(rk, np.float32),
+                                  np.asarray(kb, np.float32))
+    np.testing.assert_array_equal(np.asarray(rv, np.float32),
+                                  np.asarray(vb, np.float32))
+    others = np.setdiff1d(np.arange(N), pages)
+    for plain in _plain(CFG, ck, cv, N):
+        assert not plain[:, others].any()
+
+
+@pytest.mark.parametrize("K", [1, 4, 8])
+def test_tail_prefill_over_shared_pages_then_k_chunks(K, params):
+    """The chat cell's path: row A prefills a whole prompt; row B, whose
+    prompt starts with the same two pages, points its table at A's and
+    prefills only its tail at ``start = 16``; both then decode in chunks
+    of K steps (``decode_multi_paged``) beside an inactive row. B's
+    prefill logits are the full forward's at its last position, and
+    every token of both rows is the full forward's greedy token."""
+    from _llm_reference import greedy_reference
+
+    rng = np.random.default_rng(40 + K)
+    n_new = 8
+    prefix = [int(t) for t in rng.integers(0, CFG.vocab_size, 2 * B)]
+    prompt_a = prefix + [int(t) for t in rng.integers(0, CFG.vocab_size, 5)]
+    prompt_b = prefix + [int(t) for t in rng.integers(0, CFG.vocab_size, 9)]
+    # pages: 1, 2 shared; A goes on in 3, 4; B in 5, 6; row 1 is inactive
+    tables = _tables([[1, 2, 3, 4], [], [1, 2, 5, 6]])
+    ck, cv = dec.init_paged_cache(CFG, 7, B)
+
+    def prefill(tokens, start, width, ck, cv, table):
+        tok = np.zeros((1, width), np.int32)
+        tok[0, : len(tokens)] = tokens
+        return dec.prefill_paged(
+            CFG, params, jnp.asarray(tok), jnp.int32(start),
+            jnp.int32(len(tokens)), ck, cv, jnp.asarray(table),
+        )
+
+    la, ck, cv = prefill(prompt_a, 0, 32, ck, cv, tables[0])
+    lb, ck, cv = prefill(prompt_b[2 * B:], 2 * B, 16, ck, cv, tables[2])
+    tok = np.zeros((1, CFG.n_positions), np.int32)
+    tok[0, : len(prompt_b)] = prompt_b
+    full = gpt2.forward(params, jnp.asarray(tok), CFG)[0, len(prompt_b) - 1]
+    want = np.asarray(full[: CFG.vocab_size], np.float32)
+    assert 0.05 < want.std() < 0.5
+    assert np.abs(np.asarray(lb, np.float32) - want).max() < 2e-2
+    firsts = [int(jnp.argmax(la)), 0, int(jnp.argmax(lb))]
+    out = {0: [firsts[0]], 2: [firsts[2]]}
+    last = jnp.asarray(firsts, jnp.int32)
+    lens = jnp.asarray([len(prompt_a), 0, len(prompt_b)], jnp.int32)
+    S = 3
+    for c in range(n_new // K):
+        toks, last, lens, ck, cv = dec.decode_multi_paged(
+            CFG, params, last, lens, ck, cv, jnp.asarray(tables),
+            jnp.zeros((S,), jnp.float32), jnp.ones((S,), bool),
+            jax.random.PRNGKey(0), K, jnp.int32(c * K),
+        )
+        for row in out:
+            out[row] += [int(t) for t in np.asarray(toks)[:K, row]]
+        assert toks.shape == (dec.MAX_DECODE_CHUNK, S)
+        assert not np.asarray(toks)[K:].any()  # only K rows were written
+        # the inactive row owns nothing and must stay where it was
+        last = last.at[1].set(0)
+        lens = lens.at[1].set(0)
+    assert out[0] == greedy_reference(CFG, params, prompt_a, 1 + n_new)
+    assert out[2] == greedy_reference(CFG, params, prompt_b, 1 + n_new)
+
+
+# -- the compiled programs, for a chip that is described and not attached --
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here, or another process holds it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def time_limit():
+    """The three compiles take under a minute together on the CPU; a
+    compiler that hangs fails this test instead of the whole run."""
+    def over(signum, frame):
+        raise TimeoutError("compiling the serving programs took over 300 s")
+
+    old = signal.signal(signal.SIGALRM, over)
+    signal.alarm(300)
+    yield
+    signal.alarm(0)
+    signal.signal(signal.SIGALRM, old)
+
+
+def test_compiled_serving_programs_copy_no_whole_pool(one_chip, time_limit):
+    """At gpt2-xl's serving shapes (S 24, 97 pages of 64, the weights as
+    an engine holds them) none of the three programs, as the v5e's
+    compiler writes them, holds a ``copy`` whose result is a whole page
+    pool: the pools enter in the layout the layer loops scatter into and
+    read. Stored ``[L, N, B, H, Dh]`` each held four (two pools relaid
+    on the way in, two on the way out: 27 ms of a 45 ms step on the
+    chip, PERF.md PR 35) and 4.88 GB of temporaries for them. Nor a
+    copy of one layer of a pool, which is what the decode loops held
+    with the width minor (23 ms a step)."""
+    cfg = gpt2.CONFIGS["gpt2-xl"]
+    S, N, Bx, mp = 24, 97, 64, 16
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    stored = jax.eval_shape(lambda: dec.init_paged_cache(cfg, N, Bx))[0]
+    pool = jax.tree.map(lambda a: sds(a.shape, a.dtype), stored)
+    whole = jax.tree.leaves(stored)[0].shape
+    tree = jax.eval_shape(
+        lambda: dec.serving_params(cfg, gpt2.init(jax.random.PRNGKey(0), cfg))
     )
-    assert not gathered, sorted(set(gathered))
+    p = jax.tree.map(lambda a: sds(a.shape, a.dtype), tree)
+    rows = (sds((S,), jnp.int32), sds((S,), jnp.int32))
+    tail = (sds((S, mp), jnp.int32), sds((S,), jnp.float32), sds((S,), jnp.bool_),
+            sds((2,), jnp.uint32))
+    i32 = sds((), jnp.int32)
+    programs = {
+        "decode_paged_and_sample": dec.decode_paged_and_sample.lower(
+            cfg, p, *rows, pool, pool, *tail, i32),
+        "decode_multi_paged": dec.decode_multi_paged.lower(
+            cfg, p, *rows, pool, pool, *tail, i32, i32),
+        "prefill_paged": dec.prefill_paged.lower(
+            cfg, p, sds((1, 128), jnp.int32), i32, i32, pool, pool,
+            sds((mp,), jnp.int32)),
+    }
+    dims = ",".join(map(str, whole))
+    layer = ",".join(map(str, whole[1:]))
+    for name, lowered in programs.items():
+        compiled = lowered.compile()
+        text = compiled.as_text()
+        assert f"[{dims}]" in text, name  # the pool is there under that shape
+        copies = re.findall(rf"= \w+\[(?:{dims}|1,{layer}|{layer})\]\S* copy\(", text)
+        assert not copies, (name, copies)
+        if name != "decode_multi_paged":  # its fc_out relay is 0.98 GB, known
+            temps = compiled.memory_analysis().temp_size_in_bytes
+            assert temps < 0.5e9, (name, temps)

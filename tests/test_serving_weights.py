@@ -95,7 +95,8 @@ def test_programs_give_the_same_bits_on_the_held_tree(jax_cpu, tiny, prompt_len)
                 jnp.asarray(table)[None],
             )
             out.append(np.asarray(logits))
-        return out, np.asarray(ck, np.float32), np.asarray(cv, np.float32)
+        k, v = dec.read_pages(cfg, ck, cv, jnp.arange(1 + n))
+        return out, np.asarray(k, np.float32), np.asarray(v, np.float32)
 
     (la, ka, va), (lb, kb, vb) = run(init), run(held)
     for a, b in zip(la, lb):

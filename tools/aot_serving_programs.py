@@ -5,10 +5,12 @@ Compiles ``decode_paged_and_sample``, ``decode_multi_paged`` and
 the float32 tree ``gpt2.init`` returns and once with the tree an engine
 holds (``gpt2_decode.serving_params``), and prints for each: operations
 with ``remat`` in their name (and how often the text says the word),
-copies of a whole page pool, whole kernel stacks written anew (a
-``convert`` of a float32 parameter to the compute type, or a ``copy`` to
-another layout), and ``memory_analysis()``'s
-arguments and temporaries. Also the loader's own program
+copies of a whole page pool and of one layer of it (a relay to another
+layout: the pool's shape is ``init_paged_cache``'s, and its layout at the
+program's entry is printed beside them), whole kernel stacks written anew
+(a ``convert`` of a float32 parameter to the compute type, or a ``copy``
+to another layout), and ``memory_analysis()``'s arguments and
+temporaries. Also the loader's own program
 (``load_serving_params``'s init and cast), whose temporaries are what a
 load holds beyond the weights.
 
@@ -16,7 +18,7 @@ The text names the operations the chip's trace will show (PERF.md, PR 30
 and PR 32); it says nothing about time. Run here, on the CPU:
 
     JAX_PLATFORMS=cpu python tools/aot_serving_programs.py [--model gpt2-xl]
-        [--max-batch-size 6] [--page-tokens 64] [--k 4] [--prefill 128]
+        [--max-batch-size 6] [--page-tokens 64] [--prefill 128]
 """
 
 from __future__ import annotations
@@ -38,13 +40,28 @@ from ray_tpu.models import gpt2
 from ray_tpu.models import gpt2_decode as dec
 
 
-def report(name: str, compiled, pool_shape: str, stacks) -> None:
+def copies_of(ops, shape) -> int:
+    """``copy`` operations in ``ops`` whose result has ``shape``, in any
+    element type and layout."""
+    dims = ",".join(map(str, shape))
+    return sum(bool(re.search(rf"= \w+\[{dims}\]\S* copy\(", ln)) for ln in ops)
+
+
+def entry_layouts(text: str, shape) -> list:
+    """The layouts the compiled program takes arguments of ``shape`` in,
+    from its ``entry_computation_layout``."""
+    dims = ",".join(map(str, shape))
+    head = re.search(r"entry_computation_layout=\{\((.*?)\)->", text)
+    found = re.findall(rf"\w+\[{dims}\](\{{[^}}]*\}})", head.group(1)) if head else []
+    return sorted(set(found))
+
+
+def report(name: str, compiled, pool, stacks) -> None:
     text = compiled.as_text()
     ops = [ln for ln in text.splitlines() if re.match(r"\s*(ROOT )?%\S+ = ", ln)]
     remat = sum("remat" in ln.split(" = ")[0] for ln in ops)
-    pool_copies = sum(
-        bool(re.search(rf"= bf16\[{pool_shape}\]\S* copy\(", ln)) for ln in ops
-    )
+    pool_copies = copies_of(ops, pool)
+    layer_copies = copies_of(ops, (1,) + tuple(pool[1:])) + copies_of(ops, pool[1:])
     rewritten = sum(
         bool(re.search(rf"^\s*%(convert|copy)[.\d]* = bf16\[{s}\]", ln))
         for ln in ops for s in stacks
@@ -52,9 +69,11 @@ def report(name: str, compiled, pool_shape: str, stacks) -> None:
     mem = compiled.memory_analysis()
     print(
         f"{name:38s} remat ops {remat:2d} ({text.count('remat'):2d} mentions)  "
-        f"whole-pool copies {pool_copies:2d}  kernel stacks rewritten {rewritten:2d}  "
+        f"whole-pool copies {pool_copies:2d}  pool-layer copies {layer_copies:2d}  "
+        f"kernel stacks rewritten {rewritten:2d}  "
         f"arguments {mem.argument_size_in_bytes / 1e9:5.2f} GB  "
-        f"temporaries {mem.temp_size_in_bytes / 1e9:5.2f} GB"
+        f"temporaries {mem.temp_size_in_bytes / 1e9:5.2f} GB  "
+        f"pools enter as {' '.join(entry_layouts(text, pool)) or '-'}"
     )
 
 
@@ -63,7 +82,6 @@ def main() -> None:
     ap.add_argument("--model", default="gpt2-xl")
     ap.add_argument("--max-batch-size", type=int, default=6)
     ap.add_argument("--page-tokens", type=int, default=64)
-    ap.add_argument("--k", type=int, default=4, help="steps of the K-chunk program")
     ap.add_argument("--prefill", type=int, default=128, help="prefill width")
     args = ap.parse_args()
 
@@ -80,8 +98,10 @@ def main() -> None:
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
 
-    pool = sds((cfg.n_layer, n_pages, B, cfg.n_head, cfg.head_dim), cfg.dtype)
-    pool_shape = ",".join(map(str, pool.shape))
+    # the stored shape is init_paged_cache's to decide, not this tool's
+    stored = jax.eval_shape(lambda: dec.init_paged_cache(cfg, n_pages, B))[0]
+    pool = jax.tree.map(lambda a: sds(a.shape, a.dtype), stored)
+    pool_shape = tuple(jax.tree.leaves(stored)[0].shape)
     d, f, L = cfg.d_model, cfg.d_ff, cfg.n_layer
     h, hd = cfg.n_head, cfg.head_dim
     stacks = [f"{L},{d},{f}", f"{L},{f},{d}", f"{L},{d},3,{h},{hd}", f"{L},{h},{hd},{d}"]
@@ -93,7 +113,8 @@ def main() -> None:
 
     as_init = jax.eval_shape(lambda: gpt2.init(jax.random.PRNGKey(0), cfg))
     as_held = jax.eval_shape(lambda p: dec.serving_params(cfg, p), as_init)
-    print(f"{args.model}: rows {S}, pool {n_pages} x {B}, on {topo.devices[0].device_kind}")
+    print(f"{args.model}: rows {S}, pool {n_pages} x {B} stored as "
+          f"{list(pool_shape)}, on {topo.devices[0].device_kind}")
     for label, tree in (("float32 tree", as_init), ("serving_params", as_held)):
         params = jax.tree.map(lambda a: sds(a.shape, a.dtype), tree)
         print(f"-- {label}: {dec.params_bytes(tree) / 1e9:.2f} GB")
@@ -101,8 +122,8 @@ def main() -> None:
             "decode_paged_and_sample": dec.decode_paged_and_sample.lower(
                 cfg, params, *rows, pool, pool, tables, *sampling, key, i32
             ),
-            f"decode_multi_paged (K={args.k})": dec.decode_multi_paged.lower(
-                cfg, params, *rows, pool, pool, tables, *sampling, key, args.k, i32
+            "decode_multi_paged (any K)": dec.decode_multi_paged.lower(
+                cfg, params, *rows, pool, pool, tables, *sampling, key, i32, i32
             ),
             f"prefill_paged (P={args.prefill})": dec.prefill_paged.lower(
                 cfg, params, sds((1, args.prefill), jnp.int32), i32, i32, pool, pool,
