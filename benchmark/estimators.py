@@ -1,17 +1,16 @@
 """Estimators over client-side event streams and trainer sync stamps.
 
 A token stream is a list of ``(t, n_tokens, request)`` sorted by ``t``:
-one entry per SSE event that carried text. Rates and per-token times are
-read between instants the system itself marks (the arrival of a decode
-round's tokens, a trainer's sync), never over the nominal window, so the
-window's edges do not enter, and over all the work between the first and
-the last instant, stalls included: ``aligned_rate``, ``pooled_tpot``.
+one entry per SSE event that carried text. ``plain_rate`` is the tokens
+that arrived inside the window over its seconds, all the work and all the
+time of the window (``serve_tok_s``); ``pooled_tpot`` pools every token
+after a request's first over every request seen in the window, stalls
+included (``tpot_ms``); a trainer's rate is read between its own syncs.
 
-``plain_rate``, ``slice_rate`` and ``slice_tpot`` are read by no metric.
-Every serving run prints them beside the readings above (ISSUE 23 asked
-for the comparison; PERF.md section 2 has what it showed: the median of
-slices drops the slices in which prefill stalls decoding, so it reads
-5% high and spreads more).
+``slice_tpot`` is read by no metric. Every serving run prints it beside
+the pooled reading (ISSUE 23 asked for the comparison; PERF.md section 2
+has what it showed: a median of slices drops the slices in which prefill
+stalls decoding, so it reads low in time and spreads more).
 """
 
 from __future__ import annotations
@@ -78,37 +77,8 @@ def spread(values: Sequence[float]) -> Optional[float]:
     return (q3 - q1) / med if med else None
 
 
-def round_instants(stream: Stream, gap_s: float) -> List[Tuple[float, int]]:
-    """Cluster a token stream into round instants: events closer than
-    ``gap_s`` to their predecessor belong to the same arrival. Returns
-    ``(time of the cluster's first event, tokens in the cluster)``."""
-    out: List[List[float]] = []
-    prev = None
-    for t, n, _ in stream:
-        if prev is None or t - prev > gap_s:
-            out.append([t, 0])
-        out[-1][1] += n
-        prev = t
-    return [(t, int(n)) for t, n in out]
-
-
 def _in(stream: Stream, a: float, b: float) -> List[Tuple[float, int, int]]:
     return [e for e in stream if a <= e[0] < b]
-
-
-def aligned_rate(stream: Stream, a: float, b: float,
-                 gap_s: float) -> Optional[float]:
-    """Tokens per second between the first and the last round instant in
-    [a, b): the tokens that arrived after the first instant up to and
-    including the last, over the time between the two. Where the events
-    never pause for ``gap_s`` every event is its own instant."""
-    evs = _in(stream, a, b)
-    inst = round_instants(evs, gap_s)
-    if len(inst) < 2:
-        inst = round_instants(evs, 0.0)
-    if len(inst) < 2 or inst[-1][0] <= inst[0][0]:
-        return None
-    return sum(n for _, n in inst[1:]) / (inst[-1][0] - inst[0][0])
 
 
 def slices(t0: float, t1: float, n: int) -> List[Tuple[float, float]]:
@@ -125,15 +95,9 @@ def median_of_slices(readings: Sequence[Optional[float]]) -> Optional[float]:
     return float(statistics.median(got))
 
 
-def slice_rate(stream: Stream, t0: float, t1: float, n_slices: int,
-               gap_s: float) -> Optional[float]:
-    return median_of_slices(
-        [aligned_rate(stream, a, b, gap_s) for a, b in slices(t0, t1, n_slices)]
-    )
-
-
 def plain_rate(stream: Stream, t0: float, t1: float) -> float:
-    """The whole-window reading the estimators are shown beside."""
+    """Tokens that arrived in [t0, t1) over its seconds: everything the
+    window saw, over all of its time."""
     return sum(n for _, n, _ in _in(stream, t0, t1)) / (t1 - t0)
 
 

@@ -307,3 +307,11 @@ def _check(obs: Dict[str, Any], m: Dict[str, Any], cfg: Dict[str, Any]) -> None:
                                f"{entries['t1']} entries inside the window: something compiled there")
     obs["attempted"] = len(m["losses"])
     obs["failed"] = len(bad)
+    obs["compared"] = {
+        "losses_outside_the_band": [len(bad), 0],
+        "loss_gap": [abs(ref["loss_program"] - ref["loss_reference"]), tol["loss"]],
+        "grad_rel_gap": [ref["grad_rel_error"], tol["grad"]],
+        "loss_after_gap": [abs(ref["loss_after_program"] - ref["loss_after_reference"]),
+                           tol["loss_after"]],
+        "cache_entries_gained": [entries["t1"] - entries["t0"], 0],
+    }

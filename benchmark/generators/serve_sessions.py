@@ -369,11 +369,10 @@ def run(ctx) -> Dict[str, Any]:
 
 
 def _readings(obs: Dict[str, Any]) -> List[str]:
-    """The plain whole-window readings beside the estimators, so that a
-    reader sees the estimator move the spread and not the level."""
+    """The whole-window readings, the sliced ones beside them, and what
+    says whether the window was an ordinary one."""
     stream = estimators.stream_of(obs["records"])
     t0, t1 = obs["t0"], obs["t1"]
-    gap_s = float(obs["traffic"].get("round_gap_ms", 30)) / 1000.0
     due = [r for r in obs["records"] if r["kind"] == "load" and t0 <= r["due"] < t1]
 
     def ms(x):
@@ -382,16 +381,13 @@ def _readings(obs: Dict[str, Any]) -> List[str]:
     mem = (obs["device"].get("memory") or [{}])[0]
     return [
         f"allocator { {k: v for k, v in mem.items() if 'bytes' in k} }",
-        f"tokens/s: plain (tokens in window / seconds) {estimators.plain_rate(stream, t0, t1):.4f}, "
-        f"aligned whole window {estimators.aligned_rate(stream, t0, t1, gap_s)}, "
-        f"median of 10 slices {estimators.slice_rate(stream, t0, t1, 10, gap_s)}",
+        f"tokens/s: plain (tokens in window / seconds) {estimators.plain_rate(stream, t0, t1):.4f}",
         f"ms per token after the first: pooled whole window "
         f"{ms(estimators.pooled_tpot(stream, t0, t1))}, median of 10 slices "
         f"{ms(estimators.slice_tpot(stream, t0, t1, 10))}",
         "tokens/s by slice: " + " ".join(
-            "none" if r is None else f"{r:.2f}" for r in (
-                estimators.aligned_rate(stream, a, b, gap_s)
-                for a, b in estimators.slices(t0, t1, 10))),
+            f"{estimators.plain_rate(stream, a, b):.2f}"
+            for a, b in estimators.slices(t0, t1, 10)),
         f"{len(due)} turns due in the window, {sum(1 for r in due if r['events'])} "
         f"showed text; {len(stream)} text events",
         "longest pause between token arrivals in the window: {:.3f}s, {:.1f}s after it opened"
@@ -452,3 +448,10 @@ def _check(obs: Dict[str, Any], before: Dict[str, Any], after: Dict[str, Any]) -
                         f"({', '.join(n[:48] for n in entries.get('gained', [])[:6])})")
     obs["attempted"] = len(obs["records"])
     obs["failed"] = failed
+    obs["compared"] = {
+        "requests_failed": [failed, 0],
+        "probe_changed": [int(not a or a != b), 0],
+        "prefill_logit_gap": [ref["prefill_max_abs"], tol],
+        "decode_logit_gap": [ref["decode_max_abs"], tol],
+        "cache_entries_gained": [entries["t1"] - entries["t0"], 0],
+    }

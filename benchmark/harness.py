@@ -8,7 +8,9 @@ workers the node agent leases chips to.
 
 from __future__ import annotations
 
+import errno
 import functools
+import glob
 import importlib
 import importlib.util
 import json
@@ -16,10 +18,15 @@ import os
 import shutil
 import sys
 import time
-from typing import Any, Dict, Optional
+from typing import Any, Callable, Dict, Iterable, List, Optional
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TRACE_DIR = os.path.join(ROOT, ".bench_trace")
+# the longest a run waits for its chips, at its start and again at its
+# end: a four-chip worker is gone 14-19 s after its SIGTERM, and 36 s was
+# seen once (PERF.md section 6, PR 44)
+CHIP_WAIT_LIMIT_S = 90.0
+CHIP_WAIT_STEP_S = 0.5
 
 
 def say(msg: str) -> None:
@@ -123,6 +130,85 @@ def require_platform(platform: str, chips: int) -> None:
     if found < chips:
         sys.exit(f"benchmark: this cell wants {chips} TPU chip(s) and this "
                  f"machine exposes {found}")
+
+
+def chip_files() -> List[str]:
+    """The device files of this machine's chips: the list
+    ``TPUAcceleratorManager.get_current_node_num_accelerators`` counts."""
+    return sorted(glob.glob("/dev/accel*") + glob.glob("/dev/vfio/[0-9]*"))
+
+
+def own_files() -> set:
+    """What this process itself holds open, by path."""
+    held = set()
+    for fd in os.listdir("/proc/self/fd"):
+        try:
+            held.add(os.readlink(f"/proc/self/fd/{fd}"))
+        except OSError:
+            pass  # the descriptor that listed the directory is gone again
+    return held
+
+
+def open_and_close(path: str) -> None:
+    os.close(os.open(path, os.O_RDWR))
+
+
+def wait_for_chips(
+    platform: str, when: str, *,
+    files: Optional[Iterable[str]] = None, held: Optional[Iterable[str]] = None,
+    opener: Callable[[str], None] = open_and_close,
+    clock: Callable[[], float] = time.monotonic,
+    sleep: Callable[[float], None] = time.sleep,
+    limit_s: float = CHIP_WAIT_LIMIT_S,
+) -> float:
+    """Wait until every chip of this machine can be opened, and return
+    the seconds that took by the clock. The process that held a chip
+    before this run (a four-chip train worker) keeps its device file
+    closed to everyone for 10-19 s after it was told to go, nothing in
+    ``/proc/*/fd`` shows it, and a program that meets ``EBUSY`` there
+    fails: only an ``open()`` can tell, so each file is opened and closed
+    at once, and one that is busy is tried again every half second up to
+    ``limit_s`` (a one-chip worker on its way out makes the ``open()``
+    itself block for seconds instead). The wait is the machine's state
+    and no part of any metric; when it runs out that is said and the run
+    goes on. A rehearsal on the CPU opens nothing, a file this process
+    holds itself is skipped, and a file that refuses for another reason
+    than ``EBUSY`` is named and left alone (a wait changes nothing
+    there)."""
+    if platform != "tpu":
+        return 0.0
+    held = own_files() if held is None else set(held)
+    left = []
+    for path in chip_files() if files is None else files:
+        if path in held:
+            say(f"chips {when}: {path} is held by this process itself, not probed")
+        else:
+            left.append(path)
+    n, t_start = len(left), clock()
+    busy: Dict[str, float] = {}
+    while left:
+        for path in list(left):
+            try:
+                opener(path)
+            except OSError as e:
+                if e.errno == errno.EBUSY:
+                    busy[path] = clock() - t_start
+                    continue
+                say(f"chips {when}: {path} cannot be probed ({e.strerror or e})")
+            left.remove(path)
+        if not left or clock() - t_start >= limit_s:
+            break
+        sleep(CHIP_WAIT_STEP_S)
+    # by the clock, not by the sleeps: on one chip an open() that meets a
+    # process still letting go blocks for seconds and then succeeds
+    waited = clock() - t_start
+    if not busy:
+        say(f"chips {when}: {n} device file(s) probed, none busy, waited {waited:.2f}s")
+        return waited
+    seen = ", ".join(f"{p} last refused at {t:.2f}s" for p, t in sorted(busy.items()))
+    say(f"chips {when}: waited {waited:.2f}s ({seen})"
+        + (f"; STILL BUSY after the limit of {limit_s:g}s: {', '.join(left)}" if left else ""))
+    return waited
 
 
 def counters() -> Dict[str, Dict[str, float]]:

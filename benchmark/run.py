@@ -15,9 +15,17 @@ one traffic mix or one metric is a file found by its name:
 looked for under each directory of ``paths``; a new cell, configuration,
 mix or metric needs no edit here. The last line of standard output is
 one JSON object: ``correct``, ``attempted``, ``failed``, ``metrics``,
-``device`` and, traced, ``breakdown``. Earlier lines say what set-up
-spent its time on and give the plain whole-window readings beside the
-estimators.
+``device``, traced ``breakdown``, and last ``compared``: every number
+``correct`` was decided from, beside its limit. Earlier lines say what
+set-up spent its time on and give the whole-window readings with the
+sliced ones beside them.
+
+A cell on the TPU waits for its chips before it starts and until they are
+free again before it exits (``harness.wait_for_chips``): the run before
+this one may still be letting go of them, and whatever runs next, an
+older tree or an older benchmark, has to find them free. Neither wait is
+part of any metric; the closing one speaks on standard error, after the
+result line, and standard error ends with the numbers compared.
 
     --dry           resolve the cell and print the plan; run nothing
     --check <cfg>   compare the program with the plain reference at the
@@ -32,6 +40,7 @@ import time
 T_PROCESS_START = time.time()
 
 import argparse  # noqa: E402
+import contextlib  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import sys  # noqa: E402
@@ -105,6 +114,9 @@ def result_line(obs, metrics, traced: bool) -> Dict[str, Any]:
     if traced and tr:
         device["busy_s"], device["window_s"] = tr["busy_s"], tr["window_s"]
         line["breakdown"] = {"device_ops": tr["device_ops"], "idle_gaps": tr["idle_gaps"]}
+    # what `correct` compared, each number beside its limit; the last key
+    line["compared"] = {name: {"value": float(v), "limit": float(limit)}
+                        for name, (v, limit) in obs.get("compared", {}).items()}
     return line
 
 
@@ -141,11 +153,31 @@ def main(argv: Optional[List[str]] = None) -> int:
     harness.prepare_env()  # before the program is imported: it reads its settings then
     platform = plan["config"].get("platform", "tpu")
     harness.require_platform(platform, int(plan["cell"]["chips"]))
+    waited = harness.wait_for_chips(platform, "before the run")
+    last_words: List[str] = []
+    try:
+        last_words = run_cell(bench, plan, generator, args, platform, waited)
+        return 0
+    finally:
+        # also after a run that failed; on standard error, because the
+        # result line stays the last of standard output
+        with contextlib.redirect_stdout(sys.stderr):
+            harness.wait_for_chips(platform, "after the run")
+            for words in last_words:
+                print(words, flush=True)
+
+
+def run_cell(bench, plan, generator, args, platform: str, waited: float) -> List[str]:
+    """The run itself, down to its result line. Set-up is counted from
+    the process's start less the seconds it waited for the chips: they
+    are the machine's state, not the program's set-up. Returns what
+    standard error is to end with: ``correct`` and every number it was
+    decided from, beside its limit."""
     seconds = float(args.seconds if args.seconds is not None else bench["run_seconds"])
     ctx = SimpleNamespace(
         cell=plan["cell"], config=plan["config"], traffic=plan["traffic"],
         seed=int(args.seed), seconds=seconds, trace=bool(args.trace),
-        platform=platform, phases=harness.Phases(T_PROCESS_START), root=ROOT,
+        platform=platform, phases=harness.Phases(T_PROCESS_START + waited), root=ROOT,
         family=plan["family"],
     )
     harness.say(f"cell {args.workload}: seed {ctx.seed}, {seconds:g}s window, "
@@ -163,8 +195,11 @@ def main(argv: Optional[List[str]] = None) -> int:
         sys.exit(f"benchmark: the cell wants {plan['cell']['chips']} x {platform}, "
                  f"the program computed on {obs['device']}")
     metrics = read_metrics(bench, plan, obs, ctx)
-    print(json.dumps(result_line(obs, metrics, ctx.trace)), flush=True)
-    return 0
+    line = result_line(obs, metrics, ctx.trace)
+    print(json.dumps(line), flush=True)
+    return [f"correct: {str(line['correct']).lower()}",
+            *(f"compared: {name} {c['value']!r} limit {c['limit']!r}"
+              for name, c in line["compared"].items())]
 
 
 if __name__ == "__main__":

@@ -7,61 +7,44 @@ import pytest
 
 from benchmark import estimators as est
 
-ROUND_S, ROWS = 0.262, 24
+ROUND_S, ROWS = 0.012, 21
 
 
-def rounds(t_first, n_rounds, stall_at=None, stall_s=0.0):
-    """24-token rounds every 262 ms, each row's event a few hundred
-    microseconds after the last; optionally one stall."""
-    stream, t = [], t_first
-    for i in range(n_rounds):
-        if i == stall_at:
-            t += stall_s
-        stream += [(t + 0.0003 * r, 1, r) for r in range(ROWS)]
-        t += ROUND_S
+def rounds(t_first, seconds, chunk_every=25, k=4):
+    """A 12 ms round of 21 rows, one token a row, each row's event some
+    tens of microseconds after the last; every ``chunk_every``-th dispatch
+    is a chunk of ``k`` steps, whose tokens arrive together after ``k``
+    rounds' time: the stream pauses 48 ms now and then and its rate is
+    the same throughout."""
+    stream, t, i = [], t_first, 0
+    while t < t_first + seconds:
+        steps = k if i % chunk_every == chunk_every - 1 else 1
+        t += steps * ROUND_S
+        stream += [(t + 0.00002 * r, steps, r) for r in range(ROWS)]
+        i += 1
     return stream
 
 
-@pytest.mark.parametrize("phase", [0.0, 0.031, 0.077, 0.13, 0.19, 0.2619, 0.5, 1.234])
-def test_rate_is_the_same_whatever_the_windows_phase(phase):
-    stream = rounds(100.0, 400)
+@pytest.mark.parametrize("phase", [0.0, 0.0031, 0.0077, 0.013, 0.019, 0.0479, 0.5, 1.234])
+def test_plain_rate_of_a_short_round_is_the_true_rate_whatever_the_windows_phase(phase):
+    stream = rounds(100.0, 60.0)
     t0 = 110.0 + phase
-    got = est.slice_rate(stream, t0, t0 + 45.0, 10, 0.03)
-    assert got == pytest.approx(ROWS / ROUND_S, rel=1e-3)
-    assert est.aligned_rate(stream, t0, t0 + 45.0, 0.03) == pytest.approx(
-        ROWS / ROUND_S, rel=1e-3
+    # an edge cuts one chunk's 84 tokens of 78,750 at the most
+    assert est.plain_rate(stream, t0, t0 + 45.0) == pytest.approx(ROWS / ROUND_S, rel=2e-3)
+
+
+def test_plain_rate_counts_a_stall_as_the_time_it_took():
+    calm = rounds(100.0, 60.0)
+    stalled = [(t + 2.0 if t > 130.0 else t, n, r) for t, n, r in calm]
+    assert est.plain_rate(stalled, 110.0, 155.0) == pytest.approx(
+        est.plain_rate(calm, 110.0, 155.0) * 43.0 / 45.0, rel=2e-3
     )
-
-
-def test_plain_reading_moves_with_the_phase_and_the_estimator_does_not():
-    stream = rounds(100.0, 400)
-    plain = [est.plain_rate(stream, 110.0 + p, 122.0 + p) for p in (0.0, 0.05, 0.2)]
-    sliced = [est.slice_rate(stream, 110.0 + p, 122.0 + p, 4, 0.03) for p in (0.0, 0.05, 0.2)]
-    assert max(plain) - min(plain) > 1.0  # a whole round at an edge
-    assert max(sliced) - min(sliced) < 1e-6
-
-
-def test_one_stall_does_not_move_the_median_of_slices():
-    calm = est.slice_rate(rounds(100.0, 400), 110.0, 155.0, 10, 0.03)
-    stalled = rounds(100.0, 400, stall_at=100, stall_s=2.0)
-    assert est.slice_rate(stalled, 110.0, 155.0, 10, 0.03) == pytest.approx(calm, rel=1e-3)
-    # the whole-window readings both see it
-    assert est.aligned_rate(stalled, 110.0, 155.0, 0.03) < 0.97 * calm
-
-
-def test_round_instants_cluster_by_gap_and_fall_back_to_events():
-    stream = rounds(0.0, 3)
-    inst = est.round_instants(stream, 0.03)
-    assert [n for _, n in inst] == [24, 24, 24]
-    assert inst[1][0] - inst[0][0] == pytest.approx(ROUND_S)
-    # a stream that never pauses: every event its own instant
-    dense = [(0.001 * i, 1, 0) for i in range(101)]
-    assert est.aligned_rate(dense, 0.0, 1.0, 0.03) == pytest.approx(1000.0)
+    assert est.plain_rate([], 110.0, 155.0) == 0.0
 
 
 def test_too_few_slices_with_a_reading_give_nothing():
-    stream = rounds(100.0, 3)
-    assert est.slice_rate(stream, 100.0, 145.0, 10, 0.03) is None
+    stream = [(100.0 + 0.05 * i, 1, 0) for i in range(40)]  # two seconds of 45
+    assert est.slice_tpot(stream, 100.0, 145.0, 10) is None
     assert est.median_of_slices([1.0, None, 3.0, 5.0]) == 3.0
 
 

@@ -51,8 +51,19 @@ def test_gen_lag_itl_tpot_and_rate_read_the_window_only():
     assert itl.read(obs, {"q": 1.0}, TPU) == pytest.approx(300.0)
     # pooled over tokens: (0.2 + 0.3) s over (3 + 1) tokens after the first
     assert tpot.read(obs, {}, TPU) == pytest.approx(125.0)
-    # every token after the first round instant (10.2) up to the last (12.3)
-    assert token_rate.read(obs, {}, TPU) == pytest.approx(6 / (12.3 - 10.2))
+    # every token that arrived in the window, over its ten seconds
+    assert token_rate.read(obs, {}, TPU) == pytest.approx(7 / 10.0)
+
+
+def test_token_rate_is_the_plain_rate_the_generator_prints():
+    from benchmark import estimators
+    from benchmark.generators import serve_sessions
+
+    obs = {**serve_obs(), "device": {}}
+    plain = estimators.plain_rate(estimators.stream_of(obs["records"]), obs["t0"], obs["t1"])
+    assert token_rate.read(obs, {}, TPU) == plain
+    printed = next(n for n in serve_sessions._readings(obs) if n.startswith("tokens/s: plain"))
+    assert printed.endswith(f"{plain:.4f}")
 
 
 def counters(before, after):

@@ -17,7 +17,7 @@ import pytest
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(HERE))
-KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+KEYS = {"correct", "attempted", "failed", "metrics", "device", "compared"}
 DEVICE_ONLY = {"decode_step_ms.chat", "prefill_ms", "device_idle.chat", "device_idle.train",
                "flash_fwd_roofline", "flash_bwd_roofline", "train_mfu", "hbm_used.train"}
 
@@ -69,6 +69,10 @@ def test_the_last_line_is_one_json_object_with_the_contracts_keys(decode, chat, 
         assert result["device"]["platform"] == "cpu" and result["device"]["count"] == 1
         for m in result["metrics"].values():
             assert set(m) == {"value", "unit"} and isinstance(m["value"], float)
+        # what `correct` was decided from comes last, each number beside its limit
+        assert list(result)[-1] == "compared" and len(result["compared"]) == 5
+        for c in result["compared"].values():
+            assert set(c) == {"value", "limit"} and c["value"] <= c["limit"]
 
 
 def test_untraced_runs_report_the_cells_end_to_end_metrics(decode):
