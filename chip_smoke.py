@@ -473,12 +473,12 @@ def _train_loop(cfg: Dict[str, Any]) -> None:
         ).lower(params, opt_state, tokens).compile()
         compile_s = time.monotonic() - t0
     hlo = step.as_text()
-    # Mosaic kernels appear as tpu_custom_call; their bf16 [B*H, T, Dh]
+    # Mosaic kernels appear as tpu_custom_call; their bf16 [B, T, H*Dh]
     # operands show whether each chip got its own shard of the batch
     kernel_lines = [l for l in hlo.splitlines() if "tpu_custom_call" in l]
     kernel_rows = sorted({
         int(m.group(1)) for l in kernel_lines
-        for m in re.finditer(r"bf16\[(\d+),%d,%d\]" % (seq, mcfg.head_dim), l)
+        for m in re.finditer(r"bf16\[(\d+),%d,%d\]" % (seq, mcfg.n_head * mcfg.head_dim), l)
     })
 
     params, opt_state, loss = step(params, opt_state, tokens)  # warm-up
@@ -500,7 +500,7 @@ def _train_loop(cfg: Dict[str, Any]) -> None:
         "flash_interpret": interpret,
         "mosaic_custom_calls": len(kernel_lines),
         "mosaic_operand_rows": kernel_rows,
-        "rows_per_chip": cfg["batch_per_chip"] * mcfg.n_head,
+        "rows_per_chip": cfg["batch_per_chip"],
         "hlo_all_gathers": len(re.findall(r"\ball-gather(-start)?\(", hlo)),
         "hlo_all_reduces": len(re.findall(r"\ball-reduce(-start)?\(", hlo)),
         "batch": batch, "seq": seq, "vocab_size": mcfg.vocab_size,

@@ -146,19 +146,29 @@ def _block(x, layer, cfg: GPT2Config):
     """One pre-LN transformer block (body of the layer scan)."""
     dt = cfg.dtype
     h = _layernorm(x, layer["ln1"]["scale"], layer["ln1"]["bias"])
+    # heads stay merged into one minor dimension of H*Dh through the
+    # projections (a [.., H, Dh] result with Dh 64 minor makes XLA lay the
+    # array out positions-minor, and attention then pays a transposition
+    # of q, k, v and the output each way); the split into heads is a
+    # reshape of that minor dimension, which the flash kernel undoes
+    B, T, _ = h.shape
+    w_qkv = layer["attn"]["qkv"]["kernel"].astype(dt)   # [D, 3, H, Dh]
+    D, _, H, Dh = w_qkv.shape
     qkv = (
-        jnp.einsum("btd,dchn->btchn", h, layer["attn"]["qkv"]["kernel"].astype(dt))
-        + layer["attn"]["qkv"]["bias"].astype(dt)
+        jnp.einsum("btd,dcf->btcf", h, w_qkv.reshape(D, 3, H * Dh))
+        + layer["attn"]["qkv"]["bias"].astype(dt).reshape(3, H * Dh)
     )
-    q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]  # [B,T,H,Dh]
+    q, k, v = (qkv[:, :, c].reshape(B, T, H, Dh) for c in range(3))
     att = attention_op(
         q, k, v, causal=True, impl=cfg.attn_impl, axis_name=cfg.cp_axis
     )
     # checkpointable under the "dots+attn" remat policy: saving the
     # attention output avoids re-running the flash kernel in the backward
     att = jax.ad_checkpoint.checkpoint_name(att, "attn_out")
+    w_proj = layer["attn"]["proj"]["kernel"].astype(dt)  # [H, Dh, D]
     att = (
-        jnp.einsum("bthn,hnd->btd", att, layer["attn"]["proj"]["kernel"].astype(dt))
+        jnp.einsum("btf,fd->btd", att.reshape(B, T, H * Dh),
+                   w_proj.reshape(H * Dh, D))
         + layer["attn"]["proj"]["bias"].astype(dt)
     )
     x = x + att

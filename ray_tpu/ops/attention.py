@@ -12,7 +12,6 @@ impl="ring":      blockwise ring attention over the mesh "cp" axis
 
 from __future__ import annotations
 
-import functools
 from typing import Optional
 
 import jax
@@ -50,15 +49,24 @@ def _flash_per_shard(q, k, v, causal):
     mesh (one device) it is called as is."""
     from ray_tpu.ops.flash_attention import flash_attention
 
-    fn = functools.partial(flash_attention, causal=causal)
     mesh = jax.sharding.get_abstract_mesh()
     if mesh.empty or mesh.size == 1:
-        return fn(q, k, v)
+        return flash_attention(q, k, v, causal)
+    # shards cross the boundary as [b, T, h*Dh], the kernel's own view: a
+    # [.., h, Dh] array standing between the model's reshape and the
+    # kernel's would be laid out positions-minor and copied each way
+    B, T, H, Dh = q.shape
+
+    def per_shard(*qkv):
+        out = flash_attention(*(x.reshape(*x.shape[:2], -1, Dh) for x in qkv), causal)
+        return out.reshape(*out.shape[:2], -1)
+
     batch = tuple(a for a in ("dcn", "dp", "fsdp") if a in mesh.axis_names)
-    spec = P(batch or None, None, "tp" if "tp" in mesh.axis_names else None, None)
-    return jax.shard_map(
-        fn, in_specs=(spec, spec, spec), out_specs=spec, check_vma=False,
-    )(q, k, v)
+    spec = P(batch or None, None, "tp" if "tp" in mesh.axis_names else None)
+    out = jax.shard_map(
+        per_shard, in_specs=(spec, spec, spec), out_specs=spec, check_vma=False,
+    )(*(x.reshape(B, -1, H * Dh) for x in (q, k, v)))
+    return out.reshape(B, T, H, Dh)
 
 
 def _reference_attention(q, k, v, causal):

@@ -345,3 +345,57 @@ def test_compiled_serving_programs_copy_no_whole_pool(one_chip, time_limit):
         if name != "decode_multi_paged":  # its fc_out relay is 0.98 GB, known
             temps = compiled.memory_analysis().temp_size_in_bytes
             assert temps < 0.5e9, (name, temps)
+
+
+@pytest.mark.parametrize(
+    "shape", [(32, 1024, 12, 64), (4, 1024, 25, 64)], ids=["two-heads-a-block", "whole-row-of-25"]
+)
+def test_compiled_flash_kernels_fit_the_chip(one_chip, time_limit, shape, monkeypatch):
+    """The four flash calls as the v5e's compiler takes them, at the
+    training cells' shape and at gpt2-xl's 25 heads of 64, whose blocks
+    are the whole 1600-lane row: Mosaic accepts the tiles each kernel cuts
+    and the blocks fit the VMEM a kernel is given, which the interpreter
+    cannot tell."""
+    from ray_tpu.ops import flash_attention as fa
+
+    monkeypatch.setattr(fa, "_interpret", lambda: False)
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    text = jax.jit(jax.grad(
+        lambda q, k, v: fa.flash_attention(q, k, v, True).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2),
+    )).lower(x, x, x).compile().as_text()
+    assert len(re.findall(r'custom_call_target="tpu_custom_call"', text)) == 4
+
+
+def test_compiled_train_step_copies_no_attention_operand(one_chip, time_limit, monkeypatch):
+    """Two layers of the training cells' step at their widths (batch 32,
+    1024 positions, 12 heads of 64), as the v5e's compiler writes it: no
+    ``copy`` of an array the size of q stands around the flash kernels.
+    With q, k, v as ``[B, T, H, Dh]`` results of the projection the
+    compiler lays them out positions-minor (Dh 64 fills half a tile) and
+    copies each operand and result of every kernel, 8% of the step on the
+    chip (PERF.md, PR 45); the projections keep ``H*Dh`` merged so that
+    it does not."""
+    import dataclasses
+
+    import optax
+
+    from ray_tpu.ops import flash_attention as fa
+
+    monkeypatch.setattr(fa, "_interpret", lambda: False)
+    cfg = dataclasses.replace(
+        gpt2.CONFIGS["gpt2-small"], n_layer=2, attn_impl="flash", remat=False,
+        scan_unroll=2, loss_impl="fused", loss_chunk=256,
+    )
+    opt = optax.adamw(3e-4)
+    params = jax.eval_shape(lambda: gpt2.init(jax.random.PRNGKey(0), cfg))
+    state = jax.eval_shape(opt.init, params)
+    put = lambda tree: jax.tree.map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+    tokens = jax.ShapeDtypeStruct((32, cfg.n_positions + 1), jnp.int32, sharding=one_chip)
+    text = jax.jit(gpt2.make_train_step(cfg, opt)).lower(
+        put(params), put(state), tokens).compile().as_text()
+    assert len(re.findall(r'custom_call_target="tpu_custom_call"', text)) == 8
+    q = r"32,1024,768|32,1024,12,64|32,12,1024,64|384,1024,64"
+    copies = re.findall(rf"= (?:bf16|f32)\[(?:{q})\]\S* copy\(", text)
+    assert not copies, copies
