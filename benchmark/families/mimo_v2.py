@@ -1,0 +1,304 @@
+"""MiMo-V2 (``ray_tpu.models.mimo_v2``), as the harness sees it: the names
+``benchmark/families/gpt2.py`` lists, for the serving side, and the
+functions that count the expert layer's operations and bytes.
+
+The configuration file's ``model`` block holds the published config's
+keys and no other. ``n_routed_experts`` and ``vocab_size`` there are what
+this chip holds of a layer. How many experts the router still scores (the
+published ``n_routed_experts``) has no key of the source's left to stand
+under, so the program's preset says it (``routed_over``).
+"""
+
+from __future__ import annotations
+
+from typing import Any, Dict, List, Sequence
+
+from benchmark.families.gpt2 import warm_row_updates  # noqa: F401 — the engine's
+# row-update program is one program for every model (``update_rows_paged``)
+
+# what the program implements and has no switch for, under the published
+# config's keys; a configuration file that says otherwise is not this program's
+IMPLEMENTS: Dict[str, Any] = {
+    "attention_bias": False, "attention_chunk_size": 128,
+    "attention_projection_layout": "fused_qkv",
+    "add_full_attention_sink_bias": False, "add_swa_attention_sink_bias": True,
+    "hidden_act": "silu", "hybrid_block_size": None, "model_type": "mimo_v2",
+    "n_group": 1, "n_shared_experts": None, "norm_topk_prob": True,
+    "rope_scaling": {"rope_type": "default", "type": "default"},
+    "routed_scaling_factor": None, "scoring_func": "sigmoid",
+    "tie_word_embeddings": False, "topk_group": 1, "topk_method": "noaux_tc",
+}
+BYTES = 2.0  # bfloat16, weights and cache
+
+
+def program_sizes(model_id: str) -> Dict[str, Any]:
+    from ray_tpu.models import mimo_v2
+
+    c = mimo_v2.CONFIGS[model_id]
+    return {
+        **IMPLEMENTS,
+        "attention_value_scale": c.attention_value_scale,
+        "swa_num_key_value_heads": c.swa_num_key_value_heads,
+        "swa_num_attention_heads": c.num_attention_heads,
+        "swa_head_dim": c.head_dim, "swa_v_head_dim": c.v_head_dim,
+        "head_dim": c.head_dim, "hidden_size": c.hidden_size,
+        "hybrid_layer_pattern": list(c.hybrid_layer_pattern),
+        "intermediate_size": c.intermediate_size,
+        "layernorm_epsilon": c.layernorm_epsilon,
+        "max_position_embeddings": c.max_position_embeddings,
+        "moe_intermediate_size": c.moe_intermediate_size,
+        "moe_layer_freq": list(c.moe_layer_freq),
+        "n_routed_experts": c.n_routed_experts,
+        "num_attention_heads": c.num_attention_heads,
+        "num_experts_per_tok": c.num_experts_per_tok,
+        "num_hidden_layers": c.n_layer,
+        "num_key_value_heads": c.num_key_value_heads,
+        "partial_rotary_factor": c.partial_rotary_factor,
+        "rope_theta": c.rope_theta, "sliding_window": c.sliding_window,
+        "sliding_window_size": c.sliding_window,
+        "swa_rope_theta": c.swa_rope_theta, "v_head_dim": c.v_head_dim,
+        "vocab_size": c.vocab_size,
+    }
+
+
+def routed_over(model: Dict[str, Any]) -> int:
+    """The experts the router scores, for the preset whose sizes ``model``
+    holds (``MiMoV2Config.router_experts``: 256 where 16 are held)."""
+    from ray_tpu.models import mimo_v2
+
+    for model_id, c in mimo_v2.CONFIGS.items():
+        if program_sizes(model_id) == model:
+            return int(c.router_experts)
+    raise KeyError("no preset of ray_tpu.models.mimo_v2 has these sizes")
+
+
+def context(model: Dict[str, Any]) -> int:
+    return int(model["max_position_embeddings"])
+
+
+def serve_params(model_id: str):
+    """What ``--check`` compares outside any run: the engine's own stored
+    weights (``load_serving_params`` from ``PRNGKey(0)``)."""
+    from ray_tpu.models import mimo_v2
+
+    mcfg = mimo_v2.CONFIGS[model_id]
+    return mcfg, mimo_v2.load_serving_params(mcfg)
+
+
+def lower_precision(params):
+    """The control that sets the check's tolerance from above: the stored
+    weights rounded to float8_e4m3fn, the nearest precision below the
+    bfloat16 they are held in, and held in bfloat16 again so that the
+    same programs run. Held against the float32 reference of the
+    unrounded weights it has to come out as not correct."""
+    import jax
+    import jax.numpy as jnp
+
+    return jax.tree.map(
+        lambda a: a.astype(jnp.float8_e4m3fn).astype(a.dtype) if a.dtype == jnp.bfloat16 else a,
+        params)
+
+
+def _bucket(n: int) -> int:
+    p = 16
+    while p < n:
+        p *= 2
+    return p
+
+
+# Two scores in [0.5, 1), where the chosen experts' lie, that differ by
+# less than this are one bfloat16 number: bfloat16 keeps 8 bits, so its
+# numbers there are 2**-8 apart. Every token the program ranked the other
+# way had a margin of a third of this or less: 0.00009-0.00118 in 13 such
+# tokens at the published widths (my chip run, PR 46), 0.00014-0.00165 in
+# 9 at the tiny preset.
+TIE = 2.0 ** -8
+
+
+def token_gaps(mcfg, model: Dict[str, Any], params, seed: int,
+               prompt_lens: Sequence[int], steps: int,
+               page_tokens: int = 64, chunk: int = 512,
+               served=None) -> List[Dict[str, Any]]:
+    """Seeded prompts are prefilled through ``prefill_paged``, ``chunk``
+    tokens a call as the engine does (so a longer prompt meets a chunk at
+    ``start > 0``), each into its own decode row, and the seeded
+    continuations are decoded side by side one token a step
+    (``_decode_paged_impl``, the body of both decode programs), rows of
+    unequal length, past the window and across pages. The logits of every
+    prefill call's last position and of every row at every decode step are
+    held against the reference's full forward pass over the same sequence.
+    The programs run on ``served`` where it is given (the control,
+    ``lower_precision``) and on ``params`` otherwise; the reference always
+    on ``params``.
+
+    One entry a compared token: ``phase``, ``row``, ``position``, ``gap``
+    (max |program - reference| over its logits), ``margin`` (the
+    reference's selection margin at that position,
+    ``mimo_v2_ref.selection_margin``) and ``reference_std``."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmark.reference import mimo_v2_ref
+    from ray_tpu.models import mimo_v2 as dec
+
+    rows = len(prompt_lens)
+    max_pages = -(-context(model) // page_tokens)
+    need = [-(-(p + steps) // page_tokens) for p in prompt_lens]
+    cache_k, cache_v = dec.init_paged_cache(mcfg, 1 + sum(need), page_tokens, rows)
+    tables = np.zeros((rows, max_pages), np.int32)
+    nxt = 1  # page 0 is the scratch page
+    for r, n in enumerate(need):
+        tables[r, :n] = np.arange(nxt, nxt + n)
+        nxt += n
+    rng = np.random.default_rng([seed, 23])
+    seqs = [rng.integers(0, int(model["vocab_size"]), p + steps, dtype=np.int32)
+            for p in prompt_lens]
+    want, margin = [], []
+    for s in seqs:
+        logits, closest = mimo_v2_ref.forward(params, jnp.asarray(s), model, margins=True)
+        want.append(np.asarray(logits))
+        margin.append(np.asarray(closest))
+    std = float(np.std(want[0]))
+    params = params if served is None else served
+
+    def entry(phase, r, position, got):
+        return {"phase": phase, "row": r, "position": position,
+                "gap": float(np.abs(got - want[r][position]).max()),
+                "margin": float(margin[r][position]), "reference_std": std}
+
+    out: List[Dict[str, Any]] = []
+    for r, p in enumerate(prompt_lens):
+        start = 0
+        while start < p:
+            n = min(p - start, chunk)
+            tok = np.zeros((1, _bucket(n)), np.int32)
+            tok[0, :n] = seqs[r][start:start + n]
+            logits, cache_k, cache_v = dec.prefill_paged(
+                mcfg, params, jnp.asarray(tok), jnp.int32(start), jnp.int32(n),
+                cache_k, cache_v, jnp.asarray(tables[r]), np.int32(r),
+            )
+            start += n
+            out.append(entry("prefill", r, start - 1, np.asarray(logits)))
+    step = jax.jit(dec._decode_paged_impl, static_argnums=(0,))
+    for i in range(steps):
+        last = jnp.asarray([seqs[r][p + i] for r, p in enumerate(prompt_lens)])
+        lens = jnp.asarray([p + i for p in prompt_lens], jnp.int32)
+        logits, cache_k, cache_v, _ = step(
+            mcfg, params, last, lens, cache_k, cache_v, jnp.asarray(tables)
+        )
+        got = np.asarray(logits)
+        out += [entry("decode", r, p + i, got[r]) for r, p in enumerate(prompt_lens)]
+    return out
+
+
+def compare_serve(mcfg, model: Dict[str, Any], params, seed: int,
+                  prompt_lens: Sequence[int], steps: int,
+                  page_tokens: int = 64, chunk: int = 512,
+                  served=None) -> Dict[str, Any]:
+    """``token_gaps``, and under ``prefill_max_abs`` and ``decode_max_abs``
+    the LARGEST gap of each phase's tokens, as GPT-2's family gives it,
+    over the tokens whose selection margin is ``TIE`` or more.
+
+    The others are counted (``tokens_tied``) and their largest gap rides
+    along (``tied_worst``), unjudged: where the reference's own scores of
+    two experts, one of them held here, lie within bfloat16's rounding of
+    each other, the program ranks them one way and the float32 reference
+    the other, rightly both, and that token's logits then differ by an
+    expert's whole output (0.7 to 2.0 where every other token's gap is
+    0.01 to 0.04: PERF.md, PR 46), as much as if the model were wrong.
+    Which tokens those are is the reference's to say, from its own
+    scores, before the program is looked at. A fault in one row, one
+    chunk or one ring moves that row's other tokens and is held to the
+    maximum. A phase whose every token is tied is judged on all of them.
+    With the reference logits' own spread for scale: the six keys
+    ``serve_sessions._check`` reads, and the count."""
+    tokens = token_gaps(mcfg, model, params, seed, prompt_lens, steps, page_tokens, chunk,
+                        served)
+
+    def largest(phase):
+        all_ = [t for t in tokens if t["phase"] == phase]
+        judged = [t for t in all_ if t["margin"] >= TIE] or all_
+        return max(t["gap"] for t in judged)
+
+    tied = [t["gap"] for t in tokens if t["margin"] < TIE]
+    return {
+        "prefill_max_abs": largest("prefill"), "decode_max_abs": largest("decode"),
+        "tokens_compared": len(tokens), "tokens_tied": len(tied),
+        "tied_worst": max(tied, default=0.0),
+        "reference_logit_std": tokens[0]["reference_std"],
+        "rows": len(prompt_lens), "prompt_lens": list(prompt_lens), "decode_steps": steps,
+    }
+
+
+# -- operations and bytes -------------------------------------------------
+
+
+def _layers(model: Dict[str, Any]):
+    return list(zip(model["hybrid_layer_pattern"], model["moe_layer_freq"]))
+
+
+def expert_params(model: Dict[str, Any]) -> int:
+    """One expert: gate, up and down kernels."""
+    return 3 * int(model["hidden_size"]) * int(model["moe_intermediate_size"])
+
+
+def params_outside_experts(model: Dict[str, Any]) -> int:
+    """Every weight a decode step reads whole: attention, the dense FFN,
+    the routers, the norms and the head. Not the embedding (a step gathers
+    its rows' vectors, not the table) and not the experts."""
+    d, h = int(model["hidden_size"]), int(model["num_attention_heads"])
+    dk, dv = int(model["head_dim"]), int(model["v_head_dim"])
+    total = int(model["vocab_size"]) * d + d
+    for window, experts_here in _layers(model):
+        hkv = int(model["swa_num_key_value_heads" if window else "num_key_value_heads"])
+        total += d * h * dk + d * hkv * (dk + dv) + h * dv * d + 2 * d
+        total += h if window else 0
+        if experts_here:
+            total += d * routed_over(model) + routed_over(model)
+        else:
+            total += 3 * d * int(model["intermediate_size"])
+    return total
+
+
+def params_count(model: Dict[str, Any]) -> int:
+    """All parameters the chip holds (3,430 M for the cut of
+    ``mimo-v2.5-serve``)."""
+    moe_layers = sum(e for _, e in _layers(model))
+    return (params_outside_experts(model) + int(model["vocab_size"]) * int(model["hidden_size"])
+            + moe_layers * int(model["n_routed_experts"]) * expert_params(model))
+
+
+def expected_experts_hit(model: Dict[str, Any], rows: float) -> float:
+    """Distinct held experts that ``rows`` tokens reach in one layer under
+    even routing: held * (1 - (1 - k / routed) ** rows)."""
+    p = float(model["num_experts_per_tok"]) / routed_over(model)
+    return float(model["n_routed_experts"]) * (1.0 - (1.0 - p) ** rows)
+
+
+def decode_step_bytes(model: Dict[str, Any], rows: float,
+                      mean_context: float) -> float:
+    """Bytes one decode step has to read and no more: the weights outside
+    the experts once, the rows' embedding vectors, the weights of the
+    distinct held experts the rows are expected to reach, the live K/V of
+    full layers and the window's K/V of window layers."""
+    dk, dv = int(model["head_dim"]), int(model["v_head_dim"])
+    total = BYTES * (params_outside_experts(model) + rows * int(model["hidden_size"]))
+    for window, experts_here in _layers(model):
+        if experts_here:
+            total += BYTES * expected_experts_hit(model, rows) * expert_params(model)
+        if window:
+            seen = min(mean_context, float(model["sliding_window"]))
+            total += BYTES * rows * seen * int(model["swa_num_key_value_heads"]) * (dk + dv)
+        else:
+            total += BYTES * rows * mean_context * int(model["num_key_value_heads"]) * (dk + dv)
+    return total
+
+
+def moe_cost(model: Dict[str, Any], experts_hit: float,
+             assignments: float) -> Dict[str, float]:
+    """What the expert layers have to do for the work counted, whatever
+    implements them: the weights of every expert hit read once a
+    layer-step, and 2 x 3 x hidden x width operations a token-expert pair."""
+    return {"bytes": BYTES * experts_hit * expert_params(model),
+            "flops": 2.0 * assignments * expert_params(model)}

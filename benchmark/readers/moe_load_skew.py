@@ -1,0 +1,18 @@
+"""The fullest held expert's tokens over the mean held expert's, over the
+window: ``{"fullest": counter, "pairs": counter}``. The fullest is summed
+over expert layers and decode steps, the pairs over the held experts too,
+so the mean takes the experts held (the configuration's
+``n_routed_experts``). Nothing where the counters are absent or still."""
+
+from benchmark.readers import counter_ratio
+
+
+def read(obs, args, ctx):
+    counters = obs.get("counters")
+    held = (obs.get("model") or {}).get("n_routed_experts")
+    if not counters or not held:
+        return None
+    pairs = counter_ratio.delta(counters, [[args["pairs"], "value"]])
+    if pairs <= 0:
+        return None
+    return counter_ratio.delta(counters, [[args["fullest"], "value"]]) * float(held) / pairs

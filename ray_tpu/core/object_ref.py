@@ -134,6 +134,14 @@ class ObjectRefGenerator:
         self._task_id = task_id
         self._worker = worker
         self._i = 0
+        # set by the worker when an item of this task, or its end, lands
+        self._landed = worker.stream_event(task_id)
+
+    def __del__(self):
+        try:
+            self._worker.drop_stream_event(self._task_id)
+        except Exception:  # noqa: BLE001 — interpreter shutdown
+            pass
 
     def __iter__(self):
         return self
@@ -156,6 +164,9 @@ class ObjectRefGenerator:
         lost_deadline = None
         err_deadline = None
         while True:
+            # what lands from here on sets the event again: nothing that
+            # arrives between the checks below and the wait is slept through
+            self._landed.clear()
             # Consult the final COUNT before yielding: a retried task can
             # leave stale items from the failed attempt at indices past
             # the final count — those must not be yielded. An Exception
@@ -200,7 +211,32 @@ class ObjectRefGenerator:
                     f"streamed item {self._i} of task "
                     f"{self._task_id.hex()} not available"
                 )
-            _time.sleep(0.005)
+            # woken by this stream's own arrivals; the timeout is for the
+            # deadlines above, which nothing signals
+            self._landed.wait(0.05)
+
+    def ready_refs(self) -> "list[ObjectRef]":
+        """The items that have already arrived, in yield order, without
+        waiting: what a consumer that fell behind can take in one go
+        after ``next_ref`` gave it one. Never past the final count (a
+        retried task's stale items, see ``next_ref``); an error marker
+        is left for ``next_ref`` to raise."""
+        from ray_tpu.core import object_store as os_mod
+        from ray_tpu.utils.ids import ObjectID
+
+        w = self._worker
+        marker = w.memory_store.try_get(w._stream_done_oid(self._task_id))
+        limit = None
+        if not os_mod.is_missing(marker) and not isinstance(marker, Exception):
+            limit = int(marker)
+        refs = []
+        while limit is None or self._i < limit:
+            oid = ObjectID.from_task(self._task_id, self._i)
+            if not w.memory_store.contains(oid):
+                break
+            self._i += 1
+            refs.append(ObjectRef(oid, w.address))
+        return refs
 
     def completed(self) -> bool:
         from ray_tpu.core import object_store as os_mod

@@ -414,6 +414,8 @@ class CoreWorker:
         self.agents = ClientPool("w2agent")
 
         self.memory_store = MemoryStore()
+        # task id -> the event its streaming consumer waits on (stream_event)
+        self._stream_events: Dict[str, threading.Event] = {}
         self.shm = ShmClient()
         # deferred segment reclaim: private segments whose DELETE arrived
         # while live views (arrays a get() returned) still pinned the
@@ -1912,6 +1914,27 @@ class CoreWorker:
     def _stream_done_oid(self, task_id: TaskID) -> ObjectID:
         return ObjectID.from_task(task_id, self._STREAM_DONE_INDEX)
 
+    def stream_event(self, task_id: TaskID) -> threading.Event:
+        """The event a streaming task's consumer waits on between items
+        (``ObjectRefGenerator``): set when an item of that task or its
+        end lands here, so the consumer of one stream wakes for its own
+        arrivals and for nothing else. Hundreds of consumers polling the
+        store every 5 ms instead took the interpreter from the threads
+        that had items to deliver (PERF.md, PR 46)."""
+        key = task_id.hex()
+        evt = self._stream_events.get(key)
+        if evt is None:
+            evt = self._stream_events.setdefault(key, threading.Event())
+        return evt
+
+    def drop_stream_event(self, task_id: TaskID) -> None:
+        self._stream_events.pop(task_id.hex(), None)
+
+    def _stream_landed(self, task_id_hex: str) -> None:
+        evt = self._stream_events.get(task_id_hex)
+        if evt is not None:
+            evt.set()
+
     def _drop_stale_stream_items(self, spec: TaskSpec, count: int) -> None:
         """A retried streaming task can leave items from a longer failed
         attempt at indices >= the final count; the generator (correctly)
@@ -1940,13 +1963,15 @@ class CoreWorker:
         self._release_arg_pins(spec.task_id.hex())
         if spec.num_returns == -1:
             self.memory_store.put(self._stream_done_oid(spec.task_id), err)
+            self._stream_landed(spec.task_id.hex())
             return
         for i in range(spec.num_returns):
             self.memory_store.put(ObjectID.from_task(spec.task_id, i), err)
 
     def rpc_stream_item(self, conn, task_id_hex: str, index: int, payload):
         """Owner side: one streamed generator item landed (in-order
-        oneway pushes from the executor)."""
+        calls from the executor, which yields its next item on the
+        reply)."""
         oid = ObjectID.from_task(TaskID.from_hex(task_id_hex), index)
         kind, data = payload
         if kind == "frame":
@@ -1954,6 +1979,7 @@ class CoreWorker:
         else:
             path, size, agent_addr = data
             self.memory_store.put(oid, PlasmaValue(path, size, agent_addr))
+        self._stream_landed(task_id_hex)
         return True
 
     def _store_task_reply(self, spec: TaskSpec, reply: Dict[str, Any]) -> None:
@@ -1980,6 +2006,7 @@ class CoreWorker:
             # for item i even after seeing the count); store the count
             count = reply["returns"][0][1]
             self.memory_store.put(self._stream_done_oid(spec.task_id), count)
+            self._stream_landed(spec.task_id.hex())
             self._drop_stale_stream_items(spec, int(count))
             return
         if reply["status"] == "ok":
@@ -2215,6 +2242,7 @@ class CoreWorker:
             # streaming: the error marker rides the done-slot, raised by
             # the ObjectRefGenerator after the produced prefix is consumed
             self.memory_store.put(self._stream_done_oid(spec.task_id), e)
+            self._stream_landed(spec.task_id.hex())
             return
         for i in range(spec.num_returns):
             self.memory_store.put(ObjectID.from_task(spec.task_id, i), e)
@@ -2754,8 +2782,9 @@ class CoreWorker:
         as it is produced (reference: streaming generators,
         task_manager's dynamic returns) — the consumer's
         ObjectRefGenerator sees item i long before the task finishes.
-        Items ride in-order oneway RPCs; big items go through plasma and
-        only their marker travels."""
+        Items ride in-order calls, each answered before the next is
+        yielded; big items go through plasma and only their marker
+        travels."""
         owner = self.workers.get(spec.owner_address)
         count = 0
         for value in result:
@@ -2768,7 +2797,16 @@ class CoreWorker:
             else:
                 payload = ("frame", serialization.maybe_frame(
                     serialization.pack_parts(meta, views)))
-            owner.call_oneway(
+            # The generator resumes when the owner has this item, so it
+            # is never ahead of the process that consumes it by more than
+            # the item on the wire: a producer faster than its owner keeps
+            # to the owner's pace instead of filling the owner's store or
+            # a socket. A serving replica batches by that (serve/llm.py
+            # _stream_tokens: the tokens made while the last event was on
+            # its way leave as one), so the events a second are what the
+            # proxy takes; pushed one way its rate swung between the two
+            # processes' paces by the second (PERF.md, PR 46).
+            owner.call(
                 "stream_item", task_id_hex=spec.task_id.hex(),
                 index=count, payload=payload,
             )

@@ -46,6 +46,16 @@ from ray_tpu.models import gpt2
 from ray_tpu.models.gpt2 import GPT2Config, _layernorm
 
 
+# what the engine asks of a decode module beside its programs
+# (``models/__init__.py``): sealed prefix pages may be matched, pages may
+# be shipped between replicas, and a decode program counts nothing
+# beside its tokens
+PREFIX_CACHE = True
+KV_TRANSFER = True
+STEP_COUNTERS = ()
+DECODE_ATTENTION = "pool"  # the pool as it lies, under an ownership mask
+
+
 def serving_params(cfg: GPT2Config, params):
     """``params`` as a serving engine stores them: the leaves the programs
     below cast at their use (``wte``, ``wpe``, every kernel and bias under
@@ -186,12 +196,22 @@ class PagePool:
     page_tokens: int
 
 
-def init_paged_cache(cfg: GPT2Config, num_pages: int, page_tokens: int):
+def init_paged_cache(cfg: GPT2Config, num_pages: int, page_tokens: int,
+                     rows: int = 1):
     """(k, v) page pools, zeroed: the one place that decides the shape a
-    pool is stored in (``PagePool``)."""
+    pool is stored in (``PagePool``). Every layer is paged, so the pools
+    do not depend on the engine's decode ``rows``."""
     shape = (cfg.n_layer, cfg.n_head * cfg.head_dim, num_pages * page_tokens)
     return (PagePool(jnp.zeros(shape, cfg.dtype), page_tokens),
             PagePool(jnp.zeros(shape, cfg.dtype), page_tokens))
+
+
+def cache_layout(cfg: GPT2Config, cache_k: PagePool, cache_v: PagePool) -> Dict[str, Any]:
+    """The shape one pool is stored in and the bytes both hold on the
+    device, tiling's padding included; every layer is a full one."""
+    return {"shape": list(cache_k.data.shape),
+            "bytes": {"full": cache_k.data.on_device_size_in_bytes()
+                      + cache_v.data.on_device_size_in_bytes(), "window": 0}}
 
 
 def _writers(phys, n_positions: int, dtype):
@@ -273,7 +293,7 @@ def read_pages(cfg: GPT2Config, cache_k, cache_v, pages):
 
 @partial(jax.jit, static_argnums=(0,), donate_argnums=(5, 6))
 def prefill_paged(cfg: GPT2Config, params, tokens, start, length, cache_k,
-                  cache_v, page_table):
+                  cache_v, page_table, row=None):
     """Prefill one CHUNK of a prompt into paged KV: ``tokens`` [1, P]
     (right-padded, ``length`` real) are virtual positions
     start..start+P-1 of the sequence whose page table is ``page_table``
@@ -283,7 +303,9 @@ def prefill_paged(cfg: GPT2Config, params, tokens, start, length, cache_k,
     Writes the chunk's K/V through the page table, attends the chunk
     over the sequence's own pages (one layer's ``MaxPages`` pages taken
     through the table, never the pool), and returns the last real
-    position's logits [vocab] plus the updated pools.
+    position's logits [vocab] plus the updated pools. ``row``, the decode
+    row the sequence was admitted to, is for models that keep state a row
+    (``models/mimo_v2.py``); every layer here is paged and it is not read.
 
     The caller guarantees start + P <= MaxPages * B (bucket the chunk
     width against that cap); positions past the sequence's reserved

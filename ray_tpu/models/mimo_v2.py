@@ -1,0 +1,573 @@
+"""MiMo-V2 (``model_type: mimo_v2``) for the serving engine: the language
+model of https://huggingface.co/XiaomiMiMo/MiMo-V2.5, as one chip of an
+expert-parallel deployment holds it, with its paged decode.
+
+The block, pre-norm and without a bias anywhere:
+
+    x += Attn(rmsnorm(x));  x += FFN(rmsnorm(x));  logits = W_head rmsnorm(x)
+
+- Layers are of three kinds, by ``hybrid_layer_pattern`` (0 full, 1 window
+  attention) and ``moe_layer_freq`` (0 dense, 1 experts), so the layers are
+  a Python list and the programs unroll over it: GPT-2's loop over one
+  stacked block does not carry over.
+- Attention: 64 query heads of 192 over 4 (full) or 8 (window) K/V heads,
+  K of 192 and V of 128, V scaled by ``attention_value_scale``; rotary
+  positions (rotate-half) on the leading ``int(192 * 0.334) = 64``
+  dimensions with base ``rope_theta`` (full) or ``swa_rope_theta``
+  (window). Window layers see the last ``sliding_window`` positions, the
+  current one among them, and a learned logit a head (the sink) joins the
+  softmax's denominator and nothing else.
+- FFN: a SwiGLU, dense in layer 0, else ``ops.moe.expert_layer``: sigmoid
+  scores over all ``router_experts``, top ``num_experts_per_tok`` of score
+  plus selection bias, gates renormalised; this chip computes the part of
+  the sum its ``n_routed_experts`` held experts give.
+- The embedding and the untied head hold ``vocab_size`` rows: the chip's
+  slice of the vocabulary, over which logits and sampling run.
+
+The cache has a spec a layer (``cache_spec``): a full layer's K and V are
+paged like GPT-2's, ``[pages, B, kv_heads * size]`` with a sequence's
+pages named by its page table; a window layer's are a ring a decode row,
+``[rows, sliding_window, kv_heads * size]``, position p in slot
+``p % sliding_window``, so that its bytes and its reads are the window's
+however long the row grows. Decode reads a row's own pages in full layers
+(a loop over page-table columns that stops at the longest row) and the
+ring in window layers. A prefix hit would have to restore the rings, which
+nothing does yet: ``PREFIX_CACHE`` is False and the engine refuses hits by
+name, as it refuses KV transfer (``KV_TRANSFER``).
+
+The programs are the engine's interface, under the names GPT-2's have
+(``models/__init__.py``), and a step's ``ops.moe.STATS`` ride beside its
+tokens.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from functools import partial
+from typing import Any, Dict, List, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from ray_tpu.models.gpt2_decode import (  # noqa: F401 — the engine's interface
+    params_bytes, sample, update_rows_paged,
+)
+from ray_tpu.ops import moe
+
+PREFIX_CACHE = False   # a hit would have to restore the window layers' rings
+KV_TRANSFER = False    # no write_pages / read_pages: a shipment is pages of one shape
+DECODE_ATTENTION = "own_pages_and_rings"
+MAX_DECODE_CHUNK = 8
+# what a decode program counts beside its tokens, summed over its expert
+# layers and steps (the engine adds them to ``rt_serve_moe_*_total``)
+STEP_COUNTERS = tuple(f"moe_{name}" for name in moe.STATS)
+
+
+@dataclasses.dataclass(frozen=True)
+class MiMoV2Config:
+    """The published config's keys, under their names. ``n_routed_experts``
+    and ``vocab_size`` are what this chip holds; ``router_experts`` is the
+    published count the router scores, ``first_expert`` the first held."""
+
+    vocab_size: int = 152576
+    max_position_embeddings: int = 1048576
+    hidden_size: int = 4096
+    num_attention_heads: int = 64
+    head_dim: int = 192
+    v_head_dim: int = 128
+    num_key_value_heads: int = 4
+    swa_num_key_value_heads: int = 8
+    sliding_window: int = 128
+    rope_theta: float = 10_000_000.0
+    swa_rope_theta: float = 10_000.0
+    partial_rotary_factor: float = 0.334
+    attention_value_scale: float = 0.707
+    intermediate_size: int = 16384
+    moe_intermediate_size: int = 2048
+    n_routed_experts: int = 256
+    router_experts: int = 256
+    first_expert: int = 0
+    num_experts_per_tok: int = 8
+    hybrid_layer_pattern: Tuple[int, ...] = (0, 1, 1, 1, 1, 0)
+    moe_layer_freq: Tuple[int, ...] = (0, 1, 1, 1, 1, 1)
+    layernorm_epsilon: float = 1e-5
+    dtype: Any = jnp.bfloat16  # compute type, and the stored weights'
+
+    # what the engine asks of any model's config
+    @property
+    def n_positions(self) -> int:
+        return self.max_position_embeddings
+
+    @property
+    def n_layer(self) -> int:
+        return len(self.hybrid_layer_pattern)
+
+    @property
+    def rotary_dim(self) -> int:
+        return int(self.head_dim * self.partial_rotary_factor)
+
+    def kv_heads(self, layer: int) -> int:
+        return (self.swa_num_key_value_heads if self.hybrid_layer_pattern[layer]
+                else self.num_key_value_heads)
+
+
+# the chip's share of a 16-chip deployment (benchmark/configs/mimo-v2.5-serve.json):
+# 16 of 256 experts, an eighth of the vocabulary, the leading dense layer
+# and one whole period
+CONFIGS: Dict[str, MiMoV2Config] = {
+    "mimo-v2.5": MiMoV2Config(
+        vocab_size=19072, max_position_embeddings=4096, n_routed_experts=16,
+        hybrid_layer_pattern=(0, 1, 1, 1, 1, 1, 0),
+        moe_layer_freq=(0, 1, 1, 1, 1, 1, 1),
+    ),
+    # the CPU tests' preset: every mechanism, no published width
+    "mimo-v2-tiny": MiMoV2Config(
+        vocab_size=256, max_position_embeddings=256, hidden_size=64,
+        num_attention_heads=4, head_dim=24, v_head_dim=16,
+        num_key_value_heads=1, swa_num_key_value_heads=2, sliding_window=16,
+        intermediate_size=128, moe_intermediate_size=32, n_routed_experts=4,
+        router_experts=16, num_experts_per_tok=4,
+        hybrid_layer_pattern=(0, 1, 1, 0), moe_layer_freq=(0, 1, 1, 1),
+    ),
+}
+
+
+# -- parameters -----------------------------------------------------------
+
+
+@partial(jax.jit, static_argnums=(1, 2, 3))
+def _normal(key, shape, std, dtype):
+    return (std * jax.random.normal(key, shape, jnp.float32)).astype(dtype)
+
+
+def init(key, cfg: MiMoV2Config):
+    """Seeded weights in the type the programs compute in, a leaf a
+    program (the float32 draw of the largest leaf, 16 experts' gate
+    kernels, is 0.5 GB and gone before the next). Scales are chosen so
+    that every sublayer moves the residual stream by about its own size;
+    the selection bias and the sinks are drawn non-zero, so that a program
+    that drops them does not agree with the reference."""
+    dt = cfg.dtype
+    D, H, Dk, Dv = cfg.hidden_size, cfg.num_attention_heads, cfg.head_dim, cfg.v_head_dim
+    F, Fm, El, E = (cfg.intermediate_size, cfg.moe_intermediate_size,
+                    cfg.n_routed_experts, cfg.router_experts)
+    keys = iter(jax.random.split(key, 16 * cfg.n_layer + 4))
+
+    def w(shape, fan_in, gain=1.0, dtype=dt):
+        return _normal(next(keys), tuple(shape), gain / fan_in ** 0.5, dtype)
+
+    layers: List[Dict[str, Any]] = []
+    for l in range(cfg.n_layer):
+        Hkv = cfg.kv_heads(l)
+        attn = {"wq": w((D, H * Dk), D), "wk": w((D, Hkv * Dk), D),
+                "wv": w((D, Hkv * Dv), D), "wo": w((H * Dv, D), H * Dv)}
+        if cfg.hybrid_layer_pattern[l]:
+            attn["sink"] = _normal(next(keys), (H,), 1.0, jnp.float32)
+        layer = {"norm1": jnp.ones((D,), jnp.float32),
+                 "norm2": jnp.ones((D,), jnp.float32), "attn": attn}
+        if cfg.moe_layer_freq[l]:
+            layer["moe"] = {
+                "router": w((D, E), D, dtype=jnp.float32),
+                # small beside the scores' own spread (0.2): a trained
+                # bias balances the experts, one drawn at random skews
+                # them, and at 0.1 a quarter of the held experts took most
+                # of the tokens (PERF.md, PR 46)
+                "bias": _normal(next(keys), (E,), 0.02, jnp.float32),
+                "gate": w((El, D, Fm), D), "up": w((El, D, Fm), D),
+                # an expert's output at the size of the other sublayers':
+                # a token takes an eighth of each of eight
+                "down": w((El, Fm, D), Fm, gain=4.0),
+            }
+        else:
+            layer["mlp"] = {"gate": w((D, F), D), "up": w((D, F), D),
+                            "down": w((F, D), F, gain=2.0)}
+        layers.append(layer)
+    return {"embed": w((cfg.vocab_size, D), 1.0), "layers": layers,
+            "norm_f": jnp.ones((D,), jnp.float32),
+            "head": w((cfg.vocab_size, D), D)}
+
+
+def load_serving_params(cfg: MiMoV2Config, checkpoint_path=None):
+    """The weights of an engine of ``cfg``, on the device, in ``cfg.dtype``
+    (the norms, the sinks and the router in float32): a pickled tree of
+    ``init``'s layout cast leaf by leaf, else ``init`` from ``PRNGKey(0)``."""
+    if checkpoint_path:
+        import pickle
+
+        with open(checkpoint_path, "rb") as f:
+            tree = pickle.load(f)
+        like = jax.eval_shape(lambda: init(jax.random.PRNGKey(0), cfg))
+        return jax.tree.map(lambda a, s: jnp.asarray(a, s.dtype), tree, like)
+    return init(jax.random.PRNGKey(0), cfg)
+
+
+# -- the cache ------------------------------------------------------------
+
+
+def cache_spec(cfg: MiMoV2Config) -> List[Dict[str, Any]]:
+    """What one layer keeps a position: its kind, K/V heads and sizes."""
+    return [{"kind": "window" if cfg.hybrid_layer_pattern[l] else "full",
+             "kv_heads": cfg.kv_heads(l), "k_size": cfg.head_dim,
+             "v_size": cfg.v_head_dim} for l in range(cfg.n_layer)]
+
+
+@partial(jax.tree_util.register_dataclass, data_fields=["layers"],
+         meta_fields=["page_tokens"])
+@dataclasses.dataclass(frozen=True)
+class LayerCache:
+    """K or V of every layer, an array a layer by ``cache_spec``: a full
+    layer's ``[pages, B, kv_heads * size]``, a window layer's ``[rows,
+    sliding_window, kv_heads * size]``; and B beside them. The heads stay
+    merged in the last dimension at rest: split into ``[.., kv_heads,
+    192]`` the tiling pads 4 heads to 8 and 192 to 256, and every program
+    relaid the whole pool on the way in (0.49 s of a traced 4 s; PERF.md,
+    PR 46). A page is taken whole and split after it is taken."""
+
+    layers: Tuple[jax.Array, ...]
+    page_tokens: int
+
+
+def init_paged_cache(cfg: MiMoV2Config, num_pages: int, page_tokens: int,
+                     rows: int = 1):
+    """(k, v) caches, zeroed, for ``rows`` decode rows over ``num_pages``
+    pages: the one place that decides the stored shapes."""
+    def make(size_key):
+        return LayerCache(tuple(
+            jnp.zeros(
+                (rows, cfg.sliding_window, s["kv_heads"] * s[size_key])
+                if s["kind"] == "window"
+                else (num_pages, page_tokens, s["kv_heads"] * s[size_key]),
+                cfg.dtype)
+            for s in cache_spec(cfg)), page_tokens)
+
+    return make("k_size"), make("v_size")
+
+
+def cache_layout(cfg: MiMoV2Config, cache_k: LayerCache, cache_v: LayerCache) -> Dict[str, Any]:
+    """The stored shape of every layer's K and the bytes both caches hold
+    on the device, by kind (``batch_stats()["kv_pool_shape"]`` and the
+    ``rt_serve_kv_*_bytes`` gauges)."""
+    spec = cache_spec(cfg)
+    held = {"full": 0, "window": 0}
+    for s, k, v in zip(spec, cache_k.layers, cache_v.layers):
+        held[s["kind"]] += k.on_device_size_in_bytes() + v.on_device_size_in_bytes()
+    return {"shape": [[s["kind"], *k.shape] for s, k in zip(spec, cache_k.layers)],
+            "bytes": held}
+
+
+# -- the block ------------------------------------------------------------
+
+
+def _rmsnorm(x, scale, eps):
+    x32 = x.astype(jnp.float32)
+    y = x32 * lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
+    return y * scale.astype(jnp.float32)
+
+
+def _rope(x, pos, rotary_dim: int, theta: float):
+    """Rotate-half on the leading ``rotary_dim`` dimensions of ``x``
+    [..., heads, size] at positions ``pos`` [...]; the rest pass."""
+    half = rotary_dim // 2
+    inv = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / rotary_dim)
+    ang = pos.astype(jnp.float32)[..., None, None] * inv  # [..., 1, half]
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    x32 = x.astype(jnp.float32)
+    a, b, rest = x32[..., :half], x32[..., half:rotary_dim], x32[..., rotary_dim:]
+    return jnp.concatenate(
+        [a * cos - b * sin, b * cos + a * sin, rest], axis=-1
+    ).astype(x.dtype)
+
+
+def _qkv(cfg: MiMoV2Config, l: int, attn, h, pos):
+    """h [T, D] at positions ``pos`` [T] -> q [T, H, Dk], k [T, Hkv, Dk],
+    v [T, Hkv, Dv], rotated and scaled."""
+    dt = cfg.dtype
+    T = h.shape[0]
+    window = bool(cfg.hybrid_layer_pattern[l])
+    theta = cfg.swa_rope_theta if window else cfg.rope_theta
+    q = (h @ attn["wq"].astype(dt)).reshape(T, cfg.num_attention_heads, cfg.head_dim)
+    k = (h @ attn["wk"].astype(dt)).reshape(T, cfg.kv_heads(l), cfg.head_dim)
+    v = (h @ attn["wv"].astype(dt)).reshape(T, cfg.kv_heads(l), cfg.v_head_dim)
+    q = _rope(q, pos, cfg.rotary_dim, theta)
+    k = _rope(k, pos, cfg.rotary_dim, theta)
+    return q, k, (v.astype(jnp.float32) * cfg.attention_value_scale).astype(dt)
+
+
+def _softmax_update(carry, scores, values, visible):
+    """One block of an online softmax. ``scores`` [..., Q, T] float32,
+    ``values`` broadcastable to [..., T, Dv], ``visible`` [..., Q, T]."""
+    m, den, acc = carry
+    scores = jnp.where(visible, scores, -1e30)
+    m_new = jnp.maximum(m, scores.max(-1))
+    scale = jnp.exp(m - m_new)
+    p = jnp.where(visible, jnp.exp(scores - m_new[..., None]), 0.0)
+    acc = acc * scale[..., None] + jnp.einsum(
+        "rhgqt,rthv->rhgqv", p.astype(values.dtype), values,
+        preferred_element_type=jnp.float32,
+    )
+    return m_new, den * scale + p.sum(-1), acc
+
+
+def _finish(carry, sink=None):
+    """The softmax's quotient, [R, Hkv, G, Q, Dv] -> [R, Q, H * Dv]. A
+    ``sink`` [Hkv, G], one learned logit a head, joins the denominator
+    and nothing else."""
+    m, den, acc = carry
+    if sink is not None:
+        s = sink.astype(jnp.float32)[None, :, :, None]
+        m_new = jnp.maximum(m, s)
+        scale = jnp.exp(m - m_new)
+        den, acc = den * scale + jnp.exp(s - m_new), acc * scale[..., None]
+    out = acc / den[..., None]
+    R, Hkv, G, Q, Dv = out.shape
+    return out.transpose(0, 3, 1, 2, 4).reshape(R, Q, Hkv * G * Dv)
+
+
+def _start(R, Hkv, G, Q, Dv):
+    return (jnp.full((R, Hkv, G, Q), -1e30, jnp.float32),
+            jnp.zeros((R, Hkv, G, Q), jnp.float32),
+            jnp.zeros((R, Hkv, G, Q, Dv), jnp.float32))
+
+
+def _scores(q, k, kv_heads: int):
+    """q [R, Q, H, Dk] against k [R, T, Hkv, Dk] -> [R, Hkv, G, Q, T]
+    float32: query head h reads K/V head ``h // (H / Hkv)``."""
+    R, Q, H, Dk = q.shape
+    qg = q.reshape(R, Q, kv_heads, H // kv_heads, Dk)
+    return jnp.einsum("rqhgd,rthd->rhgqt", qg, k,
+                      preferred_element_type=jnp.float32) * Dk ** -0.5
+
+
+def _pages_a_turn(max_pages: int, wanted: int) -> int:
+    while max_pages % wanted:
+        wanted //= 2
+    return wanted
+
+
+def _paged_attend(q, k_pool, v_pool, tables, q_pos, kv_heads: int,
+                  wanted: int):
+    """Causal attention of ``q`` [R, Q, H, Dk] at positions ``q_pos`` [R, Q]
+    over each row's own pages of a full layer (``tables`` [R, MaxPages]):
+    a loop over page-table columns, a few at a time, that stops behind the
+    last position any query sees, so a step reads the live context and
+    neither the table's width nor the pool. Returns [R, Q, H * Dv]."""
+    B = k_pool.shape[1]
+    R, Q, H, Dk = q.shape
+    Dv = v_pool.shape[2] // kv_heads
+    C = _pages_a_turn(tables.shape[1], wanted)
+    span = C * B
+
+    def turn(j, carry):
+        pages = lax.dynamic_slice_in_dim(tables, j * C, C, axis=1)  # [R, C]
+        kc = k_pool[pages].reshape(R, span, kv_heads, Dk)
+        vc = v_pool[pages].reshape(R, span, kv_heads, Dv)
+        kv_pos = j * span + jnp.arange(span)
+        visible = kv_pos[None, None, :] <= q_pos[:, :, None]  # [R, Q, T]
+        return _softmax_update(carry, _scores(q, kc, kv_heads), vc,
+                               visible[:, None, None])
+
+    carry = lax.fori_loop(0, jnp.max(q_pos) // span + 1, turn,
+                          _start(R, kv_heads, H // kv_heads, Q, Dv))
+    return _finish(carry)
+
+
+def _ring_positions(upto, size: int):
+    """The position each slot of a ring holds once positions 0 .. ``upto``
+    - 1 are written: for slot r the largest p < ``upto`` with p % size ==
+    r, negative where there is none. ``upto`` [...] -> [..., size]."""
+    last = upto[..., None] - 1
+    return last - (last - jnp.arange(size)) % size
+
+
+def _ffn(cfg: MiMoV2Config, layer, h, live):
+    """(the block's second half on h [T, D], float32; its expert counts)."""
+    dt = cfg.dtype
+    if "moe" in layer:
+        return moe.expert_layer(h, layer["moe"], first=cfg.first_expert,
+                                top_k=cfg.num_experts_per_tok, live=live)
+    mlp = layer["mlp"]
+    g = jnp.dot(h, mlp["gate"].astype(dt), preferred_element_type=jnp.float32)
+    u = jnp.dot(h, mlp["up"].astype(dt), preferred_element_type=jnp.float32)
+    y = jnp.dot((jax.nn.silu(g) * u).astype(dt), mlp["down"].astype(dt),
+                preferred_element_type=jnp.float32)
+    return y, jnp.zeros((len(moe.STATS),), jnp.int32)
+
+
+def _rest_of_block(cfg: MiMoV2Config, layer, x, att, live):
+    """x [T, D] float32 and the heads' output att [T, H * Dv] -> the
+    block's output and its expert counts."""
+    dt = cfg.dtype
+    x = x + jnp.dot(att.astype(dt), layer["attn"]["wo"].astype(dt),
+                    preferred_element_type=jnp.float32)
+    h = _rmsnorm(x, layer["norm2"], cfg.layernorm_epsilon).astype(dt)
+    y, stats = _ffn(cfg, layer, h, live)
+    return x + y, stats
+
+
+def _logits(cfg: MiMoV2Config, params, x):
+    h = _rmsnorm(x, params["norm_f"], cfg.layernorm_epsilon).astype(cfg.dtype)
+    return jnp.dot(h, params["head"].astype(cfg.dtype).T,
+                   preferred_element_type=jnp.float32)
+
+
+# -- the programs ---------------------------------------------------------
+
+
+@partial(jax.jit, static_argnums=(0,), donate_argnums=(5, 6))
+def prefill_paged(cfg: MiMoV2Config, params, tokens, start, length, cache_k,
+                  cache_v, page_table, row=0):
+    """Prefill one chunk of a prompt: ``tokens`` [1, P] (right-padded,
+    ``length`` real) are positions start .. start + P - 1 of the sequence
+    in decode row ``row`` whose page table is ``page_table`` [MaxPages];
+    what lies before ``start`` is already cached (this sequence's earlier
+    chunk). Full layers write the chunk through the page table and attend
+    over the sequence's own pages; window layers attend over the row's
+    ring as the earlier chunk left it and the chunk itself, then put the
+    chunk's last ``sliding_window`` real positions into the ring. Returns
+    the last real position's logits [vocab] and the caches.
+
+    The caller guarantees start + P <= MaxPages * B; padded positions land
+    in pages the row has reserved and not yet reached, or in the scratch
+    page, and in no ring."""
+    dt = cfg.dtype
+    P = tokens.shape[1]
+    B = cache_k.page_tokens
+    W = cfg.sliding_window
+    max_pages = page_table.shape[0]
+    pos = start + jnp.arange(P)
+    live = jnp.arange(P) < length
+    x = params["embed"].astype(dt)[tokens[0]].astype(jnp.float32)  # [P, D]
+    page_of = page_table[jnp.clip(pos // B, 0, max_pages - 1)]
+    # the ring before this chunk, and after it: slot r held position
+    # ``before[r]`` and takes the chunk's ``after[r] - start`` where that
+    # is one of the chunk's own
+    before = _ring_positions(start, W)
+    after = _ring_positions(start + length, W)
+    takes = (after >= start) & (length > 0)
+    ks, vs = list(cache_k.layers), list(cache_v.layers)
+    for l, layer in enumerate(params["layers"]):
+        Hkv = cfg.kv_heads(l)
+        h = _rmsnorm(x, layer["norm1"], cfg.layernorm_epsilon).astype(dt)
+        q, k, v = _qkv(cfg, l, layer["attn"], h, pos)
+        if cfg.hybrid_layer_pattern[l]:
+            old_k = lax.dynamic_index_in_dim(ks[l], row, 0, keepdims=False)
+            old_v = lax.dynamic_index_in_dim(vs[l], row, 0, keepdims=False)
+            keys = jnp.concatenate([old_k.reshape(W, Hkv, -1), k])
+            vals = jnp.concatenate([old_v.reshape(W, Hkv, -1), v])
+            k_pos = jnp.concatenate([before, pos])
+            gap = pos[:, None] - k_pos[None, :]
+            visible = (k_pos >= 0)[None, :] & (gap >= 0) & (gap < W)
+            carry = _softmax_update(
+                _start(1, Hkv, q.shape[1] // Hkv, P, cfg.v_head_dim),
+                _scores(q[None], keys[None], Hkv), vals[None],
+                visible[None, None, None],
+            )
+            att = _finish(carry, layer["attn"]["sink"].reshape(Hkv, -1))[0]
+            src = jnp.clip(after - start, 0, P - 1)
+            new_k = jnp.where(takes[:, None], k.reshape(P, -1)[src], old_k)
+            new_v = jnp.where(takes[:, None], v.reshape(P, -1)[src], old_v)
+            ks[l] = lax.dynamic_update_index_in_dim(ks[l], new_k, row, 0)
+            vs[l] = lax.dynamic_update_index_in_dim(vs[l], new_v, row, 0)
+        else:
+            ks[l] = ks[l].at[page_of, pos % B].set(k.reshape(P, -1))
+            vs[l] = vs[l].at[page_of, pos % B].set(v.reshape(P, -1))
+            att = _paged_attend(q[None], ks[l], vs[l], page_table[None],
+                                pos[None], Hkv, 8)[0]
+        x, _ = _rest_of_block(cfg, layer, x, att, live)
+    last = lax.dynamic_index_in_dim(x, jnp.maximum(length - 1, 0), 0,
+                                    keepdims=True)
+    return (_logits(cfg, params, last)[0], LayerCache(tuple(ks), B),
+            LayerCache(tuple(vs), B))
+
+
+def _decode_paged_impl(cfg: MiMoV2Config, params, last_tokens, lengths,
+                       cache_k, cache_v, page_tables):
+    """One token for every row: [S] last tokens at positions ``lengths``
+    write their K/V (full layers through ``page_tables`` [S, MaxPages],
+    window layers into their row's ring) and attend, full layers over the
+    row's own pages, window layers over the ring. A row of length 0 is
+    nobody's: its full-layer write lands in the scratch page, it writes no
+    ring, and the experts do not see it. Returns logits [S, vocab], the
+    caches and the step's expert counts (``ops.moe.STATS``)."""
+    dt = cfg.dtype
+    S = last_tokens.shape[0]
+    B = cache_k.page_tokens
+    W = cfg.sliding_window
+    T = page_tables.shape[1] * B
+    pos = jnp.clip(lengths, 0, T - 1)
+    live = lengths > 0
+    rows = jnp.arange(S)
+    x = params["embed"].astype(dt)[last_tokens].astype(jnp.float32)  # [S, D]
+    page_of = page_tables[rows, pos // B]
+    slot = jnp.where(live, pos % W, W)  # W is no slot: the write is dropped
+    in_ring = _ring_positions(pos + 1, W) >= 0  # [S, W]
+    ks, vs = list(cache_k.layers), list(cache_v.layers)
+    stats = jnp.zeros((len(moe.STATS),), jnp.int32)
+    for l, layer in enumerate(params["layers"]):
+        Hkv = cfg.kv_heads(l)
+        h = _rmsnorm(x, layer["norm1"], cfg.layernorm_epsilon).astype(dt)
+        q, k, v = _qkv(cfg, l, layer["attn"], h, pos)
+        if cfg.hybrid_layer_pattern[l]:
+            ks[l] = ks[l].at[rows, slot].set(k.reshape(S, -1), mode="drop")
+            vs[l] = vs[l].at[rows, slot].set(v.reshape(S, -1), mode="drop")
+            carry = _softmax_update(
+                _start(S, Hkv, q.shape[1] // Hkv, 1, cfg.v_head_dim),
+                _scores(q[:, None], ks[l].reshape(S, W, Hkv, -1), Hkv),
+                vs[l].reshape(S, W, Hkv, -1), in_ring[:, None, None, None],
+            )
+            att = _finish(carry, layer["attn"]["sink"].reshape(Hkv, -1))[:, 0]
+        else:
+            ks[l] = ks[l].at[page_of, pos % B].set(k.reshape(S, -1))
+            vs[l] = vs[l].at[page_of, pos % B].set(v.reshape(S, -1))
+            att = _paged_attend(q[:, None], ks[l], vs[l], page_tables,
+                                pos[:, None], Hkv, 4)[:, 0]
+        x, counted = _rest_of_block(cfg, layer, x, att, live)
+        stats = stats + counted
+    return (_logits(cfg, params, x), LayerCache(tuple(ks), B),
+            LayerCache(tuple(vs), B), stats)
+
+
+@partial(jax.jit, static_argnums=(0,), donate_argnums=(4, 5))
+def decode_paged_and_sample(cfg: MiMoV2Config, params, last_tokens, lengths,
+                            cache_k, cache_v, page_tables, temps,
+                            greedy_mask, rng_base, step):
+    """Decode, sample, fold the RNG and bump the cursor in one dispatch.
+    Returns (next tokens, next lengths, k, v, expert counts)."""
+    logits, cache_k, cache_v, stats = _decode_paged_impl(
+        cfg, params, last_tokens, lengths, cache_k, cache_v, page_tables
+    )
+    rng = jax.random.fold_in(rng_base, step)
+    nxt = sample(logits, temps, greedy_mask, rng)
+    # a row that had no length has none after the step either: it stays
+    # nobody's until the engine writes a sequence into it
+    return nxt, jnp.where(lengths > 0, lengths + 1, 0), cache_k, cache_v, stats
+
+
+@partial(jax.jit, static_argnums=(0,), donate_argnums=(4, 5))
+def decode_multi_paged(cfg: MiMoV2Config, params, last_tokens, lengths,
+                       cache_k, cache_v, page_tables, temps, greedy_mask,
+                       rng_base, n_steps, step0):
+    """``n_steps`` (at most ``MAX_DECODE_CHUNK``) tokens a row in one
+    dispatch; one program runs every ``n_steps``. Returns (tokens
+    [MAX_DECODE_CHUNK, S] with the first ``n_steps`` rows written, last
+    tokens, lengths, k, v, expert counts)."""
+    S = last_tokens.shape[0]
+
+    def body(i, carry):
+        last, lens, ck, cv, toks, stats = carry
+        logits, ck, cv, counted = _decode_paged_impl(
+            cfg, params, last, lens, ck, cv, page_tables
+        )
+        rng = jax.random.fold_in(rng_base, step0 + i)
+        nxt = sample(logits, temps, greedy_mask, rng)
+        toks = lax.dynamic_update_index_in_dim(toks, nxt, i, axis=0)
+        return nxt, jnp.where(lens > 0, lens + 1, 0), ck, cv, toks, stats + counted
+
+    last, lens, cache_k, cache_v, toks, stats = lax.fori_loop(
+        0, n_steps, body,
+        (last_tokens, lengths, cache_k, cache_v,
+         jnp.zeros((MAX_DECODE_CHUNK, S), jnp.int32),
+         jnp.zeros((len(moe.STATS),), jnp.int32)),
+    )
+    return toks, last, lens, cache_k, cache_v, stats

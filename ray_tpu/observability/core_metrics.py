@@ -257,6 +257,52 @@ def _build() -> dict:
             "sealed prefix pages resident in this engine's page pool",
             tag_keys=("deployment", "node"),
         ),
+        # what the cache holds by kind of layer: a full layer's pages, a
+        # window layer's ring a decode row (models/mimo_v2.py); a model
+        # whose every layer is paged reads 0 under ``window``
+        "serve_kv_full_bytes": Gauge(
+            "rt_serve_kv_full_bytes",
+            "bytes of K and V the device holds for paged full-attention "
+            "layers, per engine process",
+            tag_keys=("deployment", "node"),
+        ),
+        "serve_kv_window_bytes": Gauge(
+            "rt_serve_kv_window_bytes",
+            "bytes of K and V the device holds for window-attention "
+            "layers (a ring a decode row), per engine process",
+            tag_keys=("deployment", "node"),
+        ),
+        "serve_prefix_refused": Counter(
+            "rt_serve_prefix_refused_total",
+            "admissions whose prefix match was refused by name because "
+            "the model's cache keeps state that pages do not hold",
+            tag_keys=("deployment",),
+        ),
+        # the expert layer (ops/moe.py), counted on the device beside the
+        # sampled tokens by the decode programs and added at harvest
+        "serve_moe_assignments": Counter(
+            "rt_serve_moe_assignments_total",
+            "token-expert pairs of decode steps that landed on experts "
+            "held by this engine",
+            tag_keys=("deployment",),
+        ),
+        "serve_moe_expert_steps": Counter(
+            "rt_serve_moe_expert_steps_total",
+            "held experts x expert layers x decode steps",
+            tag_keys=("deployment",),
+        ),
+        "serve_moe_experts_hit": Counter(
+            "rt_serve_moe_experts_hit_total",
+            "held experts that got at least one token, summed over expert "
+            "layers and decode steps",
+            tag_keys=("deployment",),
+        ),
+        "serve_moe_max_load": Counter(
+            "rt_serve_moe_max_load_total",
+            "tokens of the fullest held expert, summed over expert layers "
+            "and decode steps",
+            tag_keys=("deployment",),
+        ),
         "serve_kv_block_copies": Counter(
             "rt_serve_kv_block_copies_total",
             "KV block copies performed at admission (prefix-pool copy "

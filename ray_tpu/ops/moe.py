@@ -1,147 +1,128 @@
-"""Mixture-of-Experts block — expert parallelism over the mesh "ep" axis.
+"""Mixture-of-experts layer as one chip of an expert-parallel deployment
+runs it: told which experts it holds, it routes every token over all the
+experts there are, drops none at any imbalance, and computes the part of
+the result that its own experts give.
 
-GShard-style top-k routing with static capacity (TPU-first: fixed
-shapes, no data-dependent control flow — over-capacity tokens drop, the
-standard accelerator MoE trade), expert weights sharded over "ep", and
-token exchange via lax.all_to_all on the ICI mesh axis.
+    y = sum over (chosen experts that are held here) of g_e * E_e(x)
 
-The reference has no native MoE (SURVEY.md §2.4 EP row: vLLM passthrough
-only) — this is a capability-parity addition like ring attention.
+with ``E_e`` a SwiGLU and the gates ``g_e`` normalised over ALL the chosen
+experts, held or not: the shares of all chips add up to the whole layer
+(``tests/test_moe.py``). What the absent experts would add is left out,
+and on one chip the layer runs without its exchange; nothing here stands
+in for the absent chips.
 
-Layout (under shard_map over the "ep" axis, n = axis size):
-  x        [Bl, D]            local token shard
-  wg       [D, E]             router (replicated)
-  w_in     [El, D, F]         this device's experts (E = n * El)
-  w_out    [El, F, D]
-dispatch:  [Bl, E, C] one-hot -> all_to_all -> experts run [El, n*C, D]
-combine:   reverse all_to_all -> weighted sum back into [Bl, D].
+Routing is the sigmoid kind (``noaux_tc``): scores ``s = sigmoid(W_r x)``
+in float32, the top ``k`` of ``s + b`` chosen (``b`` a selection bias that
+takes no part in the gate), gates ``s_e / sum of the chosen s``.
+
+The product is grouped, not one-hot: the token-expert pairs that landed
+here are sorted by expert and multiplied by ``jax.lax.ragged_dot``, which
+reads an expert's weights once for all its rows and reads no weight of an
+expert that got none. There is no capacity: the sorted buffer has room for
+every pair that can land here (``tokens * min(k, held)``), and is worked
+through in blocks of ``block`` rows by a loop that stops after the last
+pair, because ``ragged_dot``'s time goes with the rows it is handed, not
+with the rows its groups cover (2.6 ms for 1,024 rows of which 64 were
+grouped, 1.3 ms for 64 rows, 16 experts of 4096 x 2048; PERF.md, PR 46).
 """
 
 from __future__ import annotations
 
-import functools
-from typing import Optional, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
+# what ``expert_layer`` counts, in the order of its fourth result
+STATS = ("assignments", "expert_steps", "experts_hit", "max_load")
 
-def router_dispatch(
-    x: jax.Array,          # [B, D]
-    wg: jax.Array,         # [D, E]
-    capacity: int,
-    top_k: int = 2,
+
+def route(x: jax.Array, router: jax.Array, bias: jax.Array,
+          top_k: int) -> Tuple[jax.Array, jax.Array]:
+    """Sigmoid routing of ``x`` [T, D] over ``router`` [D, E] in float32:
+    (chosen experts [T, k], their gates [T, k]). The bias moves the choice
+    and not the gate; the gates of one token sum to one."""
+    scores = jax.nn.sigmoid(jnp.dot(
+        x.astype(jnp.float32), router.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST,
+    ))
+    _, chosen = lax.top_k(scores + bias.astype(jnp.float32), top_k)
+    picked = jnp.take_along_axis(scores, chosen, axis=1)
+    return chosen, picked / picked.sum(-1, keepdims=True)
+
+
+def _block_rows(tokens: int, top_k: int, held: int, routed: int) -> int:
+    """Rows one turn of the loop multiplies: twice the pairs that land
+    here under even routing, as a power of two from 128, and no more than
+    can land here at all."""
+    most = tokens * min(top_k, held)
+    expected = tokens * top_k * held / routed
+    rows = 128
+    while rows < 2 * expected:
+        rows *= 2
+    return min(rows, most)
+
+
+def expert_layer(
+    x: jax.Array,                      # [T, D]
+    weights: Dict[str, jax.Array],     # router [D, E], bias [E], gate/up [El, D, F], down [El, F, D]
+    *,
+    first: int,                        # the first expert held here
+    top_k: int,
+    live: Optional[jax.Array] = None,  # [T] bool: rows that are real tokens
 ) -> Tuple[jax.Array, jax.Array]:
-    """Compute (dispatch [B, E, C] float, combine [B, E, C] float).
+    """The held experts' part of the layer for ``x``, float32 [T, D], and
+    what it counted (``STATS``, int32 [4]): token-expert pairs that landed
+    on held experts, held experts, held experts that got a pair, and the
+    fullest held expert's pairs. Rows that are not ``live`` are routed
+    nowhere and count nowhere."""
+    T, D = x.shape
+    held = weights["gate"].shape[0]
+    routed = weights["router"].shape[1]
+    with jax.named_scope("moe_experts"):
+        chosen, gates = route(x, weights["router"], weights["bias"], top_k)
+        local = chosen - first
+        here = (local >= 0) & (local < held)
+        if live is not None:
+            here &= live[:, None]
+        # pairs by expert, those for other chips' experts last
+        group = jnp.where(here, local, held).reshape(-1)
+        rows = _block_rows(T, top_k, held, routed)
+        # room for every pair that can land here, in whole blocks
+        most = -(-T * min(top_k, held) // rows) * rows
+        order = jnp.argsort(group, stable=True)[:most]
+        order = jnp.pad(order, (0, most - order.shape[0]))
+        token = order // top_k
+        sizes = jnp.zeros((held + 1,), jnp.int32).at[group].add(1)[:held]
+        ends = jnp.cumsum(sizes)
+        starts = ends - sizes
+        n_pairs = ends[-1]
+        xs = x[token]                                           # [most, D]
+        ws = jnp.where(jnp.arange(most) < n_pairs,
+                       gates.reshape(-1)[order], 0.0)
 
-    Top-k gating with position-in-expert assignment by cumulative count;
-    tokens beyond an expert's capacity C are dropped (their combine
-    weights are zero), matching GShard/Switch semantics."""
-    B, D = x.shape
-    E = wg.shape[1]
-    gates = jax.nn.softmax(
-        x.astype(jnp.float32) @ wg.astype(jnp.float32), axis=-1
-    )  # [B, E]
-    topv, topi = lax.top_k(gates, top_k)  # [B, K]
-    # renormalize the selected gates
-    topv = topv / jnp.maximum(topv.sum(-1, keepdims=True), 1e-9)
+        def block(i, y):
+            lo = i * rows
+            part = jnp.clip(ends, lo, lo + rows) - jnp.clip(starts, lo, lo + rows)
+            xb = lax.dynamic_slice_in_dim(xs, lo, rows)
+            g = lax.ragged_dot(xb, weights["gate"], part,
+                               preferred_element_type=jnp.float32)
+            u = lax.ragged_dot(xb, weights["up"], part,
+                               preferred_element_type=jnp.float32)
+            h = (jax.nn.silu(g) * u).astype(x.dtype)
+            out = lax.ragged_dot(h, weights["down"], part,
+                                 preferred_element_type=jnp.float32)
+            wb = lax.dynamic_slice_in_dim(ws, lo, rows)
+            # rows past the last pair belong to no group: whatever the
+            # product left there is not added
+            out = jnp.where((wb > 0)[:, None], out * wb[:, None], 0.0)
+            return y.at[lax.dynamic_slice_in_dim(token, lo, rows)].add(out)
 
-    dispatch = jnp.zeros((B, E, capacity), jnp.float32)
-    combine = jnp.zeros((B, E, capacity), jnp.float32)
-    # fill counts per expert across the k choices in priority order
-    fill = jnp.zeros((E,), jnp.int32)
-    for k in range(top_k):
-        e_k = topi[:, k]                      # [B]
-        onehot = jax.nn.one_hot(e_k, E, dtype=jnp.int32)  # [B, E]
-        pos_in_e = (jnp.cumsum(onehot, axis=0) - onehot) + fill[None]  # [B, E]
-        pos = jnp.sum(pos_in_e * onehot, axis=1)          # [B]
-        keep = pos < capacity
-        pos_oh = jax.nn.one_hot(pos, capacity, dtype=jnp.float32)
-        sel = onehot.astype(jnp.float32) * keep[:, None]
-        dispatch = dispatch + sel[:, :, None] * pos_oh[:, None, :]
-        combine = combine + (
-            sel * topv[:, k][:, None]
-        )[:, :, None] * pos_oh[:, None, :]
-        fill = fill + jnp.sum(onehot * keep[:, None].astype(jnp.int32), axis=0)
-    return dispatch, combine
-
-
-def moe_block_local(x, wg, w_in, w_out, capacity: int, top_k: int = 2):
-    """Single-device MoE (numerics oracle): all experts local."""
-    dispatch, combine = router_dispatch(x, wg, capacity, top_k)
-    expert_in = jnp.einsum("bec,bd->ecd", dispatch, x.astype(jnp.float32))
-    h = jax.nn.gelu(jnp.einsum("ecd,edf->ecf", expert_in, w_in))
-    out = jnp.einsum("ecf,efd->ecd", h, w_out)
-    return jnp.einsum("bec,ecd->bd", combine, out).astype(x.dtype)
-
-
-def moe_block(
-    x: jax.Array,        # local [Bl, D]
-    wg: jax.Array,       # [D, E] replicated
-    w_in: jax.Array,     # local experts [El, D, F]
-    w_out: jax.Array,    # [El, F, D]
-    capacity: int,
-    axis_name: str = "ep",
-    top_k: int = 2,
-) -> jax.Array:
-    """Expert-parallel MoE under shard_map: dispatch/combine all_to_all
-    over `axis_name` (ICI), experts sharded across it."""
-    n = lax.psum(1, axis_name)
-    Bl, D = x.shape
-    El = w_in.shape[0]
-    E = n * El
-    dispatch, combine = router_dispatch(x, wg, capacity, top_k)  # [Bl,E,C]
-    C = capacity
-    # tokens for each expert, grouped by owning device
-    expert_in = jnp.einsum(
-        "bec,bd->ecd", dispatch, x.astype(jnp.float32)
-    )  # [E, C, D]
-    expert_in = expert_in.reshape(n, El, C, D)
-    # all_to_all: device r sends expert_in[p] to device p; receives its
-    # own experts' tokens from every peer -> [n, El, C, D]
-    recv = lax.all_to_all(expert_in, axis_name, split_axis=0, concat_axis=0,
-                          tiled=False)
-    recv = recv.reshape(n, El, C, D).transpose(1, 0, 2, 3).reshape(
-        El, n * C, D
-    )
-    h = jax.nn.gelu(jnp.einsum("etd,edf->etf", recv, w_in))
-    out = jnp.einsum("etf,efd->etd", h, w_out)  # [El, n*C, D]
-    # reverse exchange: send each peer its tokens' outputs back
-    out = out.reshape(El, n, C, D).transpose(1, 0, 2, 3)  # [n, El, C, D]
-    back = lax.all_to_all(out, axis_name, split_axis=0, concat_axis=0,
-                          tiled=False)
-    back = back.reshape(E, C, D)
-    return jnp.einsum("bec,ecd->bd", combine, back).astype(x.dtype)
-
-
-def moe_block_sharded(
-    x: jax.Array,        # global [B, D]
-    wg: jax.Array,       # [D, E]
-    w_in: jax.Array,     # [E, D, F]
-    w_out: jax.Array,    # [E, F, D]
-    mesh,
-    capacity: int,
-    ep_axis: str = "ep",
-    top_k: int = 2,
-) -> jax.Array:
-    """shard_map wrapper: batch over ep (tokens sharded), experts over ep."""
-    from jax.sharding import PartitionSpec as P
-
-    fn = functools.partial(
-        moe_block, capacity=capacity, axis_name=ep_axis, top_k=top_k
-    )
-    # check_vma off: the checker cannot prove the replication of the
-    # all_to_all dispatch/combine pair
-    return jax.shard_map(
-        fn,
-        mesh=mesh,
-        in_specs=(
-            P(ep_axis, None),       # tokens sharded over ep
-            P(None, None),          # router replicated
-            P(ep_axis, None, None),  # experts sharded over ep
-            P(ep_axis, None, None),
-        ),
-        out_specs=P(ep_axis, None),
-        check_vma=False,
-    )(x, wg, w_in, w_out)
+        y = lax.fori_loop(0, -(-n_pairs // rows), block,
+                          jnp.zeros((T, D), jnp.float32))
+        stats = jnp.stack([
+            n_pairs, jnp.int32(held), jnp.sum(sizes > 0, dtype=jnp.int32),
+            jnp.max(sizes),
+        ]).astype(jnp.int32)
+    return y, stats

@@ -61,13 +61,18 @@ class PrefillEngine:
     def __init__(self, cfg) -> None:
         import jax
 
-        from ray_tpu.models import gpt2
-        from ray_tpu.models import gpt2_decode as dec
+        from ray_tpu import models
         from ray_tpu.serve import prefix_cache
         from ray_tpu.utils.config import config
 
         self.cfg = cfg
-        self.model_cfg = gpt2.CONFIGS[cfg.model_id]
+        self.model_cfg, self._dec = models.resolve(cfg.model_id)
+        if not self._dec.KV_TRANSFER:
+            raise RuntimeError(
+                f"model {cfg.model_id!r} has no KV transfer: its cache has a "
+                f"spec a layer, and a shipment is pages of one shape"
+            )
+        dec = self._dec
         self.params = dec.load_serving_params(self.model_cfg, cfg.checkpoint_path)
         self._rng = jax.random.PRNGKey(1)
         B = int(config.serve_prefix_block_tokens)
@@ -93,10 +98,10 @@ class PrefillEngine:
         import jax.numpy as jnp
         import numpy as np
 
-        from ray_tpu.models import gpt2_decode as dec
         from ray_tpu.serve import prefix_cache
         from ray_tpu.utils.config import config
 
+        dec = self._dec
         mcfg = self.model_cfg
         T_max = mcfg.n_positions
         prompt = list(prompt_tokens)[-(T_max - 1):] or [0]

@@ -3,7 +3,10 @@
 Parity: the reference serve.llm stack (python/ray/serve/llm — deployment
 + engine wrapper + OpenAI-ish request shape) whose engine tier is vLLM
 (/root/reference/python/ray/llm/_internal/serve/engines/vllm/). Here the
-engine is native JAX (models/gpt2_decode.py): a prefill/decode split
+engine is native JAX and serves any registered model: it finds a model's
+config and its decode programs by ``model_id`` (``models.resolve``;
+``models/gpt2_decode.py`` and ``models/mimo_v2.py`` implement the
+interface): a prefill/decode split
 over one paged, static-shape KV pool with CONTINUOUS BATCHING — new
 requests are admitted into free decode rows between decode steps as
 long as the pool has pages for them, so a long generation never blocks
@@ -14,8 +17,8 @@ K/V, not N full-prefix recomputes.
 Token-level API (this image has no tokenizer vocab files): requests are
 {"prompt_tokens": [int], "max_new_tokens": N, "temperature": T};
 responses are {"tokens": [int]}. Weights are randomly initialized unless
-a checkpoint path of gpt2.init-compatible arrays is given — the serving
-machinery, not the text quality, is the parity surface.
+a checkpoint path of arrays in the layout of the model's own ``init`` is
+given — the serving machinery, not the text quality, is the parity surface.
 """
 
 from __future__ import annotations
@@ -123,13 +126,16 @@ class _Chunk:
     the host), and pages whose free is deferred until this chunk — the
     last one that can scatter into them — has completed."""
 
-    __slots__ = ("toks_dev", "n_steps", "rows", "by_row", "dropped",
-                 "free_after")
+    __slots__ = ("toks_dev", "counted_dev", "n_steps", "rows", "by_row",
+                 "dropped", "free_after")
 
-    def __init__(self, toks_dev, n_steps: int):
+    def __init__(self, toks_dev, n_steps: int, counted_dev=None):
         # on device: [S] when K == 1, else [MAX_DECODE_CHUNK, S] with
         # the first K rows written
         self.toks_dev = toks_dev
+        # on device: what the chunk's steps counted beside their tokens
+        # (the decode module's STEP_COUNTERS), None where it counts nothing
+        self.counted_dev = counted_dev
         self.n_steps = n_steps
         self.rows: List[tuple] = []  # (row, seq, finish_pending)
         self.by_row: Dict[int, Any] = {}
@@ -197,28 +203,26 @@ class LLMServer:
     """The deployment callable: continuous-batched decode over one paged
     KV pool, one chunk dispatched ahead of its harvest.
 
-    ``params`` are held on the device in the type the programs compute
-    in (``model_cfg.dtype``), cast once at load by
-    ``gpt2_decode.load_serving_params``; ``ln1``, ``ln2`` and ``ln_f``
-    keep ``param_dtype``. The programs still cast at every use, which
-    costs nothing here and lets the benchmark's ``--check`` hand them
-    the float32 tree; ``batch_stats()["weights_bytes"]`` says what the
-    tree holds."""
+    The model's config and its decode programs are found by
+    ``config.model_id`` (``models.resolve``), and only that model's
+    modules are imported. ``params`` are held on the device in the type
+    the programs compute in (``model_cfg.dtype``), cast once at load by
+    the decode module's ``load_serving_params`` (norms stay float32);
+    ``batch_stats()["weights_bytes"]`` says what the tree holds."""
 
     def __init__(self, config: LLMConfig):
         import jax
 
+        from ray_tpu import models
         from ray_tpu.accelerators.tpu import require_leased_platform
-        from ray_tpu.models import gpt2
-        from ray_tpu.models import gpt2_decode as dec
         from ray_tpu.serve import prefix_cache
         from ray_tpu.utils.config import config as rtcfg
 
         require_leased_platform()
         t_load = time.monotonic()
         self.cfg = config
-        self.model_cfg = gpt2.CONFIGS[config.model_id]
-        self.params = dec.load_serving_params(
+        self.model_cfg, self._dec = models.resolve(config.model_id)
+        self.params = self._dec.load_serving_params(
             self.model_cfg, config.checkpoint_path
         )
         self._rng = jax.random.PRNGKey(1)
@@ -253,8 +257,11 @@ class LLMServer:
         self._started = threading.Event()
         self._start_error: Optional[BaseException] = None
         self._devices: List[Dict[str, Any]] = []
-        self._kv_pool_shape: List[int] = []
+        self._kv_pool_shape: List[Any] = []
         self._kv_pool_bytes = 0
+        self._kv_bytes_by_kind: Dict[str, int] = {}
+        # held by the one stream that is sending an event (_stream_tokens)
+        self._stream_turn = threading.Lock()
         threading.Thread(
             target=self._run_engine, name="llm-engine", daemon=True,
         ).start()
@@ -274,15 +281,15 @@ class LLMServer:
             self._started.set()
             raise
 
-    def _engine_started(self, *pool) -> None:
+    def _engine_started(self, cache_k, cache_v) -> None:
         """Called by the engine loop once its allocations exist, before
         its first round: waits for them (device allocation is
         asynchronous), records the devices holding the parameters and
         the KV pool, and releases __init__."""
         import jax
 
-        jax.block_until_ready(pool)
-        pool = jax.tree.leaves(pool)
+        jax.block_until_ready((cache_k, cache_v))
+        pool = jax.tree.leaves((cache_k, cache_v))
         held = {
             d for a in (*jax.tree.leaves(self.params), *pool)
             for d in a.devices()
@@ -296,10 +303,17 @@ class LLMServer:
             }
             for d in sorted(held, key=lambda d: d.id)
         ]
-        # the pools as init_paged_cache stored them and as the device
-        # holds them, tiling's padding included
-        self._kv_pool_shape = list(pool[0].shape)
-        self._kv_pool_bytes = sum(a.on_device_size_in_bytes() for a in pool)
+        # the caches as init_paged_cache stored them and as the device
+        # holds them, tiling's padding included, by the kind of layer
+        # (paged full layers, a window layer's ring a row)
+        layout = self._dec.cache_layout(self.model_cfg, cache_k, cache_v)
+        self._kv_pool_shape = layout["shape"]
+        self._kv_bytes_by_kind = layout["bytes"]
+        self._kv_pool_bytes = sum(layout["bytes"].values())
+        if core_metrics.ENABLED:
+            ntags = {"deployment": self.cfg.model_id, "node": self._node_tag}
+            core_metrics.serve_kv_full_bytes.set(layout["bytes"]["full"], tags=ntags)
+            core_metrics.serve_kv_window_bytes.set(layout["bytes"]["window"], tags=ntags)
         self._started.set()
 
     # -- request path ---------------------------------------------------
@@ -352,7 +366,9 @@ class LLMServer:
     def _stream_tokens(self, req: "_Request"):
         """Token-by-token generator (continuous batching pushes each
         decoded token as its step completes; parity: vLLM's streaming
-        generate in the reference's serve.llm engine). Closing the
+        generate in the reference's serve.llm engine). An item's ``more``
+        says that the next token had already been produced when this one
+        was taken. Closing the
         generator before exhaustion — the client disconnected — cancels
         the request so the engine frees its KV pages."""
         import queue as queue_mod
@@ -365,21 +381,50 @@ class LLMServer:
                     tok = req.token_q.get(timeout=300)
                 except queue_mod.Empty:
                     raise TimeoutError("generation stalled") from None
-                if tok is None:
-                    done = True
-                    if req.error is not None:
-                        raise req.error
-                    return
-                produced += 1
-                yield {"token": int(tok), "index": produced - 1}
+                # One stream at a time from here until the proxy has its
+                # event (the lock is held across the yields, while the
+                # consumer builds the event and the worker sends it and
+                # waits for the owner's reply, core/worker.py
+                # _stream_returns): the other rows' threads wait here, not
+                # runnable, and their tokens pile up in their queues
+                # meanwhile, so the events a second are what the proxy
+                # takes and the tokens an event what the engine makes in
+                # between. With every row's
+                # thread runnable at every step the engine thread waits
+                # for the interpreter, steps slow down, every token gets
+                # an event of its own and the replica settles at a third
+                # of its rate; with coalesced sends that never block it
+                # outran the proxy by 30 s (PERF.md, PR 46). A consumer
+                # that stops pulling without closing holds the turn, and
+                # one thread pulling two streams in turn would wait for
+                # itself: the serving path drives each from its own
+                # thread and only ever waits for the proxy's reply.
+                with self._stream_turn:
+                    # what the engine produced while this thread waited (a
+                    # chunk of K steps, or its turn) comes with it;
+                    # ``more`` tells the consumer that the next token is
+                    # there already, so it sends them on as one event
+                    ready = [tok]
+                    while ready[-1] is not None:
+                        try:
+                            ready.append(req.token_q.get_nowait())
+                        except queue_mod.Empty:
+                            break
+                    for j, tok in enumerate(ready):
+                        if tok is None:
+                            done = True
+                            if req.error is not None:
+                                raise req.error
+                            return
+                        produced += 1
+                        yield {"token": int(tok), "index": produced - 1,
+                               "more": j + 1 < len(ready) and ready[j + 1] is not None}
         finally:
             if not done:
                 req.cancelled = True
                 self._work.set()  # wake the engine to reap the row
 
     def batch_stats(self, _payload=None) -> Dict[str, Any]:
-        from ray_tpu.models import gpt2_decode as dec
-
         with self._lock:
             sizes = list(self._batch_sizes)
             total = self._total_batches
@@ -397,16 +442,21 @@ class LLMServer:
             # bytes the parameters hold on the device: half of what
             # ``param_dtype`` would take where the engine computes in
             # bfloat16, so a regression to float32 shows without a trace
-            "weights_bytes": dec.params_bytes(self.params),
-            # what the decode programs attend over: the page pool itself
-            # under an ownership mask (gpt2_decode), no row gather. Kept
-            # so a reader of two trees' numbers can tell which body ran.
-            "decode_attention": "pool",
-            # the shape one page pool is stored in and the bytes the
-            # device holds for both: a pool that went back to a padded
-            # or relaid form shows here without a trace
+            "weights_bytes": self._dec.params_bytes(self.params),
+            # what the decode programs attend over (the decode module's
+            # word): the page pool itself under an ownership mask, or a
+            # row's own pages and rings. Kept so a reader of two trees'
+            # numbers can tell which body ran.
+            "decode_attention": self._dec.DECODE_ATTENTION,
+            # the shape the cache is stored in (one pool's where every
+            # layer is paged, else [kind, *shape] a layer) and the bytes
+            # the device holds for K and V, in all and by kind of layer:
+            # a pool that went back to a padded or relaid form, or a
+            # window layer that grew with its rows, shows here without a
+            # trace
             "kv_pool_shape": self._kv_pool_shape,
             "kv_pool_bytes": self._kv_pool_bytes,
+            "kv_bytes_by_kind": self._kv_bytes_by_kind,
             "prefix": self._prefix_pool.stats(),
         }
 
@@ -473,10 +523,10 @@ class LLMServer:
         import jax.numpy as jnp
         import numpy as np
 
-        from ray_tpu.models import gpt2_decode as dec
         from ray_tpu.serve import prefix_cache
         from ray_tpu.utils.config import config
 
+        dec = self._dec
         mcfg = self.model_cfg
         T_max = mcfg.n_positions
         pool = self._prefix_pool
@@ -490,7 +540,7 @@ class LLMServer:
             pool.num_pages - 1, 4 * self.cfg.max_batch_size
         )
         S = max(1, min(S, pool.num_pages - 1))
-        cache_k, cache_v = dec.init_paged_cache(mcfg, n_phys, B)
+        cache_k, cache_v = dec.init_paged_cache(mcfg, n_phys, B, S)
         seqs: List[Optional[_PagedSeq]] = [None] * S
         tables = np.zeros((S, max_pages), np.int32)  # 0 rows -> scratch
         last = np.zeros((S,), np.int32)
@@ -647,7 +697,19 @@ class LLMServer:
             the caller requeues the request until pages free up."""
             nonlocal cache_k, cache_v
             prompt = req.prompt[-(T_max - 1):]
+            if req.kv_import is not None and not dec.KV_TRANSFER:
+                self._fail_request(req, RuntimeError(
+                    f"model {self.cfg.model_id!r} takes no KV import: its "
+                    f"cache has a spec a layer that pages cannot carry"
+                ))
+                return True  # consumed (failed); keep admitting
             use_prefix = bool(config.serve_prefix_cache)
+            if use_prefix and not dec.PREFIX_CACHE:
+                # refused by name, never a silent wrong hit: a hit would
+                # have to restore state that pages do not hold
+                use_prefix = False
+                if core_metrics.ENABLED:
+                    core_metrics.serve_prefix_refused.inc(tags=dep_tags)
             total_tokens = min(len(prompt) + req.max_new, T_max)
             n_pages = -(-total_tokens // B)
             if n_pages > pool.num_pages - 1:
@@ -745,6 +807,7 @@ class LLMServer:
                             mcfg, self.params, jnp.asarray(tok),
                             jnp.int32(start), jnp.int32(n),
                             cache_k, cache_v, jnp.asarray(s.table),
+                            np.int32(i),
                         )
                     if core_metrics.ENABLED:
                         core_metrics.serve_prefill_tokens.inc(
@@ -835,7 +898,17 @@ class LLMServer:
             completions, deferred page frees. This executes while the
             NEXT chunk (already dispatched) keeps the device busy —
             np.asarray is the only sync point."""
-            toks = sync("rt/engine/harvest_sync", np.asarray, rec.toks_dev)
+            if rec.counted_dev is None:
+                toks = sync("rt/engine/harvest_sync", np.asarray, rec.toks_dev)
+            else:
+                # the chunk's counts came with its tokens: one sync
+                toks, counted = sync(
+                    "rt/engine/harvest_sync", jax.device_get,
+                    (rec.toks_dev, rec.counted_dev),
+                )
+                if core_metrics.ENABLED:
+                    for name, n in zip(dec.STEP_COUNTERS, counted):
+                        getattr(core_metrics, f"serve_{name}").inc(int(n), tags=dep_tags)
             with tracing.span("rt/engine/harvest"):
                 deliver(rec, toks)
 
@@ -908,8 +981,9 @@ class LLMServer:
                 dirty.clear()
             d_last, d_len, d_temps, d_greedy, d_tables = dev_state
             self._record_step_paged(len(active), pool.stats())
+            # a module with STEP_COUNTERS returns their counts last
             if K > 1:
-                toks_dev, d_last2, d_len, cache_k, cache_v = (
+                toks_dev, d_last2, d_len, cache_k, cache_v, *counted = (
                     dec.decode_multi_paged(
                         mcfg, self.params, d_last, d_len, cache_k,
                         cache_v, d_tables, d_temps, d_greedy, rng_base,
@@ -920,7 +994,7 @@ class LLMServer:
                 dev_state = (d_last2, d_len, d_temps, d_greedy, d_tables)
             else:
                 step_no += 1
-                toks_dev, d_len, cache_k, cache_v = (
+                toks_dev, d_len, cache_k, cache_v, *counted = (
                     dec.decode_paged_and_sample(
                         mcfg, self.params, d_last, d_len, cache_k,
                         cache_v, d_tables, d_temps, d_greedy, rng_base,
@@ -928,7 +1002,7 @@ class LLMServer:
                     )
                 )
                 dev_state = (toks_dev, d_len, d_temps, d_greedy, d_tables)
-            rec = _Chunk(toks_dev, K)
+            rec = _Chunk(toks_dev, K, counted[0] if counted else None)
             for i in active:
                 s = seqs[i]
                 s.budget_left -= K
@@ -994,7 +1068,7 @@ class LLMServer:
                 # rebuild after a poisoned (donated) round. The pool's
                 # sealed pages pointed into the deleted cache, so ALL
                 # pool metadata resets with it
-                cache_k, cache_v = dec.init_paged_cache(mcfg, n_phys, B)
+                cache_k, cache_v = dec.init_paged_cache(mcfg, n_phys, B, S)
                 pool.reset()
                 dev_state = None
                 dirty.clear()

@@ -277,14 +277,18 @@ class OpenAIServer:
                         rid, created, req.model, req.prompt,
                         system_fingerprint=self._fingerprint,
                     ))
+                text = ""
                 for item in eng_gen:
                     produced += 1
-                    text = dec.feed(item["token"])
+                    text += dec.feed(item["token"])
+                    if item.get("more"):
+                        continue  # one event for what was produced by now
                     if text:
                         yield protocol.sse_event(protocol.completion_chunk(
                             rid, created, req.model, text,
                             system_fingerprint=self._fingerprint,
                         ))
+                        text = ""
                 tail = dec.flush()
                 if tail:
                     yield protocol.sse_event(protocol.completion_chunk(
@@ -322,14 +326,18 @@ class OpenAIServer:
                     {"role": "assistant", "content": ""},
                     system_fingerprint=self._fingerprint,
                 ))
+                text = ""
                 for item in eng_gen:
                     produced += 1
-                    text = dec.feed(item["token"])
+                    text += dec.feed(item["token"])
+                    if item.get("more"):
+                        continue  # one event for what was produced by now
                     if text:
                         yield protocol.sse_event(protocol.chat_chunk(
                             rid, created, req.model, {"content": text},
                             system_fingerprint=self._fingerprint,
                         ))
+                        text = ""
                 tail = dec.flush()
                 if tail:
                     yield protocol.sse_event(protocol.chat_chunk(

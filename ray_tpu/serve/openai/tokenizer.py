@@ -3,8 +3,10 @@
 The reference resolves a HuggingFace tokenizer per served model
 (vllm's get_tokenizer); this image ships no vocab files, so the
 default is a deterministic BYTE-LEVEL tokenizer: token i is byte i
-(0..255), which maps exactly onto the gpt2-tiny test config's
-vocab_size=256 and round-trips any UTF-8 text. Real deployments
+(0..255), which lies inside the vocabulary of every registered model
+(the tiny test presets hold exactly 256 ids; a larger model samples ids
+above 255, which decode as ``id & 255``) and round-trips any UTF-8 text.
+Real deployments
 register their tokenizer under the model name::
 
     from ray_tpu.serve.openai import register_tokenizer
@@ -47,7 +49,8 @@ class _ByteIncrementalDecoder:
 
 class ByteTokenizer:
     """Deterministic byte-level tokenizer: token i == byte i. Vocab size
-    256 — exactly the gpt2-tiny test config's vocabulary."""
+    256 — exactly the tiny test presets' vocabulary, and inside every
+    registered model's."""
 
     vocab_size = 256
 

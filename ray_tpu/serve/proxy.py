@@ -60,8 +60,19 @@ class ServeProxy:
         controller = ray_tpu.get_actor(controller_name)
         self._router = Router(controller)
         self._admission = AdmissionController()
+        from ray_tpu.utils.config import config
+
+        # a streamed request holds a pool thread while it waits for its
+        # next item, a request the engine has queued as much as one that
+        # is decoding, so the pool is as wide as admission lets requests
+        # in: with the server's default of 32, 192 streams for an engine
+        # of 128 rows left every thread on a request that was waiting for
+        # a row, for seconds, while the rows' tokens lay undelivered
+        # (PERF.md, PR 46). Threads start as they are needed: up to 32
+        # streams nothing changes.
         self._server = AioHttpServer(
-            self._handle, port=port, fast_handler=self._try_fast
+            self._handle, port=port, fast_handler=self._try_fast,
+            pool_size=max(32, int(config.serve_admission_max_inflight)),
         )
 
     # -- admission control (serve/autoscale/admission.py) ----------------
@@ -394,13 +405,18 @@ class ServeProxy:
 
         def gen():
             try:
-                for item in self._router.call_streaming(
+                # every event that has arrived goes out in one chunk: a
+                # stream this proxy fell behind on (more streams than
+                # pool threads) catches up in one hop and one write; the
+                # events themselves stay one a token
+                for items in self._router.call_streaming_batches(
                     deployment, request, timeout_s=600,
                     model_id=probe.model, session_key=probe.session_key,
                     prefix_hint=hint,
                 ):
-                    yield item if isinstance(item, bytes) else oai.sse_event(
-                        item
+                    yield b"".join(
+                        item if isinstance(item, bytes) else oai.sse_event(item)
+                        for item in items
                     )
             except Exception as e:  # noqa: BLE001 — mid-stream trailer
                 yield oai.sse_error(f"{type(e).__name__}: {e}")

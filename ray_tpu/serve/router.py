@@ -298,6 +298,25 @@ class Router:
         disconnected and the proxy closed this generator) cancels the
         replica-side task so the deployment's generator unwinds and the
         LLM engine frees the request's KV pages."""
+        for items in self.call_streaming_batches(
+            deployment, payload, method=method, timeout_s=timeout_s,
+            model_id=model_id, session_key=session_key,
+            prefix_hint=prefix_hint,
+        ):
+            yield from items
+
+    def call_streaming_batches(self, deployment: str, payload: Any,
+                               method: Optional[str] = None,
+                               timeout_s: float = 60.0,
+                               model_id: Optional[str] = None,
+                               session_key: Optional[str] = None,
+                               prefix_hint: Optional[str] = None):
+        """``call_streaming``, a list at a time: the next item as soon as
+        it is there and, with it, every later one that has already
+        arrived. A consumer that keeps up gets lists of one; one that
+        fell behind (a proxy with more streams than pool threads: each
+        item costs it a thread hop and a write) catches up in one step
+        instead of paying the hop for every item it is behind."""
         tid = _trace_id_of(payload) if tracing.ENABLED else None
         t0u = tracing.now_us() if tid else 0
         rid, handle = self.choose_replica(
@@ -316,7 +335,8 @@ class Router:
                 payload, method=method
             )
             for item_ref in gen:
-                yield ray_tpu.get(item_ref, timeout=timeout_s)
+                refs = [item_ref, *gen.ready_refs()]
+                yield [ray_tpu.get(r, timeout=timeout_s) for r in refs]
             exhausted = True
         finally:
             self.request_finished(rid)

@@ -235,3 +235,80 @@ def test_streaming_actor_method(rt):
     assert ray_tpu.get(next(gen3), timeout=60) == 1
     with _pytest.raises(Exception, match="stream kaboom"):
         ray_tpu.get(next(gen3), timeout=60)
+
+
+def test_a_consumer_that_fell_behind_takes_what_has_arrived_in_one_go(rt):
+    """``ObjectRefGenerator.ready_refs``: after ``next`` gave one item,
+    every later item that has already arrived, in order, without waiting;
+    nothing where none has, never past the end, and an error is left for
+    ``next`` to raise."""
+    import time
+
+    import pytest as _pytest
+
+    import ray_tpu
+
+    @ray_tpu.remote(num_cpus=0)
+    class Gen:
+        @ray_tpu.method(num_returns="streaming")
+        def burst_then_slow(self, n):
+            for i in range(n):
+                yield i
+            time.sleep(1.5)
+            yield n
+
+        @ray_tpu.method(num_returns="streaming")
+        def flaky(self):
+            yield 1
+            raise ValueError("stream kaboom")
+
+    g = Gen.remote()
+    gen = g.burst_then_slow.remote(6)
+    first = ray_tpu.get(next(gen), timeout=60)
+    time.sleep(0.5)  # the consumer falls behind: the burst lands meanwhile
+    rest = [ray_tpu.get(r, timeout=60) for r in gen.ready_refs()]
+    assert [first, *rest] == [0, 1, 2, 3, 4, 5]
+    assert gen.ready_refs() == []  # the seventh is not there yet, and nobody waits
+    assert ray_tpu.get(next(gen), timeout=60) == 6
+    time.sleep(0.3)
+    assert gen.ready_refs() == []  # the stream has ended
+    with _pytest.raises(StopIteration):
+        next(gen)
+    gen = g.flaky.remote()
+    assert ray_tpu.get(next(gen), timeout=60) == 1
+    assert gen.ready_refs() == []
+    with _pytest.raises(Exception, match="stream kaboom"):
+        ray_tpu.get(next(gen), timeout=60)
+
+
+def test_a_streaming_producer_keeps_to_its_owners_pace(rt, monkeypatch):
+    """Each streamed item is answered by the owner before the generator
+    resumes (``CoreWorker._stream_returns``): an owner that takes 50 ms an
+    item holds a producer that yields at once to that pace, and every item
+    still arrives, in order."""
+    import time
+
+    import ray_tpu
+    from ray_tpu.core.worker import global_worker
+
+    @ray_tpu.remote(num_cpus=0)
+    class Gen:
+        @ray_tpu.method(num_returns="streaming")
+        def burst(self, n):
+            t0 = time.monotonic()
+            for i in range(n):
+                yield i
+            yield time.monotonic() - t0
+
+    handlers = global_worker().server._handlers
+    landed = handlers["stream_item"]
+
+    def slow(*args, **kwargs):
+        time.sleep(0.05)
+        return landed(*args, **kwargs)
+
+    monkeypatch.setitem(handlers, "stream_item", slow)
+    g = Gen.remote()
+    got = [ray_tpu.get(r, timeout=60) for r in g.burst.remote(10)]
+    assert got[:10] == list(range(10))
+    assert got[10] >= 0.45  # ten replies waited for; sent one way it was ~0

@@ -285,12 +285,20 @@ def test_history_disabled_with_zero_interval():
     from ray_tpu.observability.history import HistorySampler
     from ray_tpu.utils.config import config
 
+    def samplers():
+        return {t.ident for t in threading.enumerate()
+                if t.name == HistorySampler.THREAD_NAME}
+
+    # a sampler that is there already is an earlier cluster's in this
+    # process (one that was shut down stops on its next tick, one that a
+    # test left running never does): what is held is that THIS cluster
+    # starts none
+    before = samplers()
     config.set("metrics_sample_interval_s", 0)
     try:
         ray_tpu.init(num_cpus=1)
         try:
-            names = [t.name for t in threading.enumerate()]
-            assert HistorySampler.THREAD_NAME not in names
+            assert samplers() <= before
             assert state.metrics_history() == {"enabled": False}
             assert state.alerts() == {"enabled": False, "alerts": []}
         finally:

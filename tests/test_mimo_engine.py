@@ -1,0 +1,208 @@
+"""MiMo-V2 through the one serving engine (serve/llm.py), found by its
+``model_id``: continuous batching over a cache of two kinds, the expert
+layer's counts fetched with the tokens, prefix hits and KV import refused
+by name; and a GPT-2 engine never imports the family.
+"""
+
+import os
+import subprocess
+import sys
+import threading
+
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@pytest.fixture(scope="module")
+def engine():
+    import jax
+
+    jax.config.update("jax_platforms", "cpu")
+    from ray_tpu.serve.llm import LLMConfig, LLMServer
+
+    srv = LLMServer(LLMConfig(model_id="mimo-v2-tiny", max_batch_size=2,
+                              max_new_tokens_cap=64))
+    yield srv
+    srv.unload()
+
+
+def _series(name):
+    """A counter or gauge of this process, its series added together."""
+    from ray_tpu.utils import metrics
+
+    snap = metrics.snapshot_all().get(name)
+    return float(sum(snap["series"].values())) if snap else 0.0
+
+
+def assert_greedy_by_the_reference(srv, prompt, tokens, margin=0.25):
+    """Every generated token is the reference's best, or within ``margin``
+    of it (bfloat16 against float32 on logits whose spread is 1)."""
+    import jax.numpy as jnp
+
+    from benchmark.families import mimo_v2 as family
+    from benchmark.reference import mimo_v2_ref
+
+    model = family.program_sizes("mimo-v2-tiny")
+    seq = list(prompt) + list(tokens)
+    logits = np.asarray(mimo_v2_ref.forward(srv.params, jnp.asarray(seq), model))
+    short = 0
+    for i, tok in enumerate(tokens):
+        at = logits[len(prompt) + i - 1]
+        short += at[tok] < at.max() - margin
+    # a router's tie may move one token's logits by an expert's output
+    assert short <= 1, (short, len(tokens))
+
+
+def test_rows_of_unequal_length_decode_side_by_side_as_the_reference_does(engine):
+    rng = np.random.default_rng(0)
+    prompts = [list(map(int, rng.integers(0, 256, n))) for n in (70, 9, 40, 3)]
+    asks = [24, 30, 17, 33]  # K-chunks of several sizes turn up as rows finish
+    out = [None] * len(prompts)
+
+    def ask(i):
+        out[i] = engine({"prompt_tokens": prompts[i], "max_new_tokens": asks[i]})["tokens"]
+
+    threads = [threading.Thread(target=ask, args=(i,)) for i in range(len(prompts))]
+    [t.start() for t in threads]
+    [t.join(120) for t in threads]
+    for prompt, n, tokens in zip(prompts, asks, out):
+        assert tokens is not None and len(tokens) == n
+        assert all(0 <= t < 256 for t in tokens)
+        assert_greedy_by_the_reference(engine, prompt, tokens)
+    stats = engine.batch_stats()
+    assert stats["max_batch"] >= 3  # they did share decode steps
+    assert stats["prefix"]["pages_occupied"] == 0  # every page came back
+
+
+def test_the_replica_reports_the_cache_by_kind_and_window_rows_stay_bounded(engine):
+    stats = engine.batch_stats()
+    kinds = [s[0] for s in stats["kv_pool_shape"]]
+    assert kinds == ["full", "window", "window", "full"]
+    by_kind = stats["kv_bytes_by_kind"]
+    assert by_kind["window"] > 0 and by_kind["full"] > 0
+    assert stats["kv_pool_bytes"] == by_kind["window"] + by_kind["full"]
+    rows, window = stats["kv_pool_shape"][1][1:3]
+    assert window == 16  # positions a decode row holds in a window layer: the window
+    # 2 layers x K and V x rows x 16 positions x 2 heads x (24 + 16) x bfloat16
+    assert by_kind["window"] >= 2 * rows * 16 * 2 * (24 + 16) * 2
+    before = stats["kv_pool_bytes"]
+    engine({"prompt_tokens": list(range(200)), "max_new_tokens": 40})
+    assert engine.batch_stats()["kv_pool_bytes"] == before
+
+
+def test_expert_counts_come_back_with_the_tokens(engine):
+    from ray_tpu.observability import core_metrics
+
+    if not core_metrics.ENABLED:
+        pytest.skip("observability is off")
+    before = _series("rt_serve_moe_expert_steps_total")
+    engine({"prompt_tokens": [1, 2, 3, 4, 5], "max_new_tokens": 9})
+    after = _series("rt_serve_moe_expert_steps_total")
+    # 8 decode steps x 3 expert layers x 4 held experts
+    assert after - before == 8 * 3 * 4
+    assert _series("rt_serve_moe_assignments_total") > 0
+    assert 0 < _series("rt_serve_moe_experts_hit_total") <= _series("rt_serve_moe_expert_steps_total")
+    assert _series("rt_serve_moe_max_load_total") >= 1
+    assert _series("rt_serve_kv_window_bytes") > 0 and _series("rt_serve_kv_full_bytes") > 0
+
+
+def test_a_prefix_hit_is_refused_by_name_not_served_wrong(engine):
+    """The same 70-token prompt twice: GPT-2 would serve the second from
+    the first's sealed page; here the pages hold only the full layers'
+    part, so nothing is matched, the refusal is counted, and the answer is
+    the same."""
+    prompt = list(range(70))
+    first = engine({"prompt_tokens": prompt, "max_new_tokens": 12})["tokens"]
+    refused = _series("rt_serve_prefix_refused_total")
+    again = engine({"prompt_tokens": prompt, "max_new_tokens": 12})["tokens"]
+    assert again == first
+    assert _series("rt_serve_prefix_refused_total") == refused + 1
+    assert engine.batch_stats()["prefix"]["prefix_resident"] == 0
+
+
+def test_kv_import_and_the_prefill_tier_refuse_the_family_by_name(engine):
+    with pytest.raises(RuntimeError, match="takes no KV import"):
+        engine({"prompt_tokens": [1, 2, 3], "max_new_tokens": 4,
+                "kv_import": {"prompt_len": 3, "first_token": 1, "k": None, "v": None}})
+    from ray_tpu.serve import kv_transfer
+    from ray_tpu.serve.llm import LLMConfig
+
+    with pytest.raises(RuntimeError, match="mimo-v2-tiny.*KV transfer"):
+        kv_transfer.PrefillEngine(LLMConfig(model_id="mimo-v2-tiny"))
+    # and the engine still serves
+    assert len(engine({"prompt_tokens": [5], "max_new_tokens": 3})["tokens"]) == 3
+
+
+def test_a_gpt2_engine_never_imports_the_family():
+    code = (
+        "import sys, jax; jax.config.update('jax_platforms', 'cpu')\n"
+        "from ray_tpu.serve.llm import LLMConfig, LLMServer\n"
+        "srv = LLMServer(LLMConfig(model_id='gpt2-tiny', max_batch_size=2))\n"
+        "assert len(srv({'prompt_tokens': [1, 2, 3], 'max_new_tokens': 4})['tokens']) == 4\n"
+        "loaded = [m for m in sys.modules if 'mimo' in m or m == 'ray_tpu.ops.moe']\n"
+        "assert not loaded, loaded\n"
+        "srv.unload(); print('clean')\n"
+    )
+    env = {**os.environ, "JAX_PLATFORMS": "cpu", "PYTHONPATH": ROOT}
+    proc = subprocess.run([sys.executable, "-c", code], env=env, cwd=ROOT,
+                          capture_output=True, text=True, timeout=300)
+    assert proc.returncode == 0 and "clean" in proc.stdout, proc.stderr[-2000:]
+
+
+def test_an_unknown_model_is_refused_with_the_known_ones():
+    from ray_tpu import models
+
+    with pytest.raises(KeyError, match="gpt2-tiny"):
+        models.resolve("gpt2-nope")
+    with pytest.raises(KeyError, match="families"):
+        models.resolve("llama")
+    cfg, dec = models.resolve("mimo-v2.5")
+    assert cfg.n_layer == 7 and cfg.n_routed_experts == 16 and cfg.router_experts == 256
+    assert dec.PREFIX_CACHE is False and len(dec.STEP_COUNTERS) == 4
+
+
+def test_a_stream_that_fell_behind_takes_what_was_produced_in_one_go(engine):
+    """A consumer that keeps up gets a token an item; one that is away
+    while the engine goes on gets every token produced meanwhile behind
+    the next, each but the last flagged ``more`` (one event at the front
+    door). No wait, no threshold: the tokens are the same."""
+    import time
+
+    ask = {"prompt_tokens": [3, 1, 4], "max_new_tokens": 12, "stream": True}
+    plain = list(engine(ask))
+    assert [it["index"] for it in plain] == list(range(12))
+    assert not plain[-1]["more"]
+    gen = engine(ask)
+    late = [next(gen)]
+    time.sleep(2.0)  # the engine finishes the other eleven meanwhile
+    late += list(gen)
+    assert [it["token"] for it in late] == [it["token"] for it in plain]
+    assert [it["more"] for it in late[1:]] == [True] * 10 + [False]
+
+
+def test_one_stream_sends_at_a_time_and_a_closed_one_lets_go(engine):
+    """The turn (``_stream_turn``) is held from the moment a stream takes
+    its tokens until its consumer comes back for more: another stream's
+    tokens are produced meanwhile and wait in its queue, and come as one
+    batch when the turn is free. A consumer that goes away frees it."""
+    import time
+
+    ask = {"prompt_tokens": [2, 7, 1], "max_new_tokens": 10, "stream": True}
+    held = engine(ask)
+    first = next(held)  # paused at its yield: this stream has the turn
+    assert first["index"] == 0
+    got = []
+    other = threading.Thread(target=lambda: got.extend(engine(ask)))
+    other.start()
+    deadline = time.time() + 60
+    time.sleep(0.5)
+    while engine._occupied and time.time() < deadline:
+        time.sleep(0.1)  # until the engine has finished both requests
+    assert engine._occupied == 0 and got == []
+    held.close()  # the client went away
+    other.join(60)
+    assert [it["index"] for it in got] == list(range(10))
+    assert [it["more"] for it in got] == [True] * 9 + [False]
+    assert not engine._stream_turn.locked()

@@ -1,106 +1,114 @@
-"""MoE expert-parallel tests on the CPU mesh (SURVEY §2.4 EP row —
-capability the reference delegates to vLLM; native here)."""
+"""The expert layer (ops/moe.py): one chip's share of an expert-parallel
+layer, held against the plain reference's ``experts``
+(benchmark/reference/mimo_v2_ref.py), float32 on the CPU.
+
+These replace, one for one, the four tests of the GShard capacity path
+that went with it (PR 46): routing shapes and capacity -> routing and no
+capacity; local routing -> the held experts' part; sharded matches local
+-> the shares add up; differentiable -> imbalance drops nothing.
+"""
 
 import numpy as np
 import pytest
 
 
 @pytest.fixture(scope="module")
-def ep_mesh(cpu_mesh_devices):
-    from ray_tpu.parallel import MeshConfig, build_mesh
-
-    return build_mesh(MeshConfig(dp=2, ep=4))
-
-
-def _setup(E=8, D=16, F=32, B=32, seed=0):
+def layer():
     import jax
     import jax.numpy as jnp
 
-    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
-    x = jax.random.normal(ks[0], (B, D), jnp.float32)
-    wg = jax.random.normal(ks[1], (D, E), jnp.float32) * 0.5
-    w_in = jax.random.normal(ks[2], (E, D, F), jnp.float32) * 0.1
-    w_out = jax.random.normal(ks[3], (E, F, D), jnp.float32) * 0.1
-    return x, wg, w_in, w_out
+    jax.config.update("jax_platforms", "cpu")
+    D, F, E, T, K = 32, 16, 16, 24, 4
+    ks = jax.random.split(jax.random.PRNGKey(3), 6)
+    weights = {
+        "router": jax.random.normal(ks[0], (D, E)) / D ** 0.5,
+        "bias": 0.1 * jax.random.normal(ks[1], (E,)),
+        "gate": jax.random.normal(ks[2], (E, D, F)) / D ** 0.5,
+        "up": jax.random.normal(ks[3], (E, D, F)) / D ** 0.5,
+        "down": jax.random.normal(ks[4], (E, F, D)) / F ** 0.5,
+    }
+    x = jax.random.normal(ks[5], (T, D), jnp.float32)
+    model = {"num_experts_per_tok": K, "norm_topk_prob": True}
+    return weights, x, model
 
 
-def test_router_dispatch_shapes_and_capacity(cpu_mesh_devices):
-    import jax.numpy as jnp
-
-    from ray_tpu.ops.moe import router_dispatch
-
-    x, wg, _, _ = _setup(B=16)
-    dispatch, combine = router_dispatch(x, wg, capacity=4, top_k=2)
-    assert dispatch.shape == (16, 8, 4)
-    # every slot holds at most one token
-    assert float(dispatch.sum(axis=0).max()) <= 1.0 + 1e-6
-    # each token occupies at most top_k slots
-    assert float(dispatch.sum(axis=(1, 2)).max()) <= 2.0 + 1e-6
-    # combine weights of each token sum to <= 1 (== 1 when not dropped)
-    s = combine.sum(axis=(1, 2))
-    assert float(s.max()) <= 1.0 + 1e-5
+def share(weights, first, count):
+    return {**weights, **{k: weights[k][first:first + count] for k in ("gate", "up", "down")}}
 
 
-def test_moe_local_routes_to_right_experts(cpu_mesh_devices):
-    """With an identity-ish router forcing one expert, output must equal
-    that expert's FFN applied to the tokens."""
-    import jax.numpy as jnp
-
-    from ray_tpu.ops.moe import moe_block_local
-
-    x, _, w_in, w_out = _setup(B=8)
-    E, D = 8, 16
-    x = jnp.abs(x) + 0.1  # all-positive tokens
-    # router whose expert-3 logit is 10*sum(x) > 0 while others are 0:
-    # expert 3 wins for every token
-    wg = jnp.zeros((D, E)).at[:, 3].set(10.0)
-    out = moe_block_local(x, wg, w_in, w_out, capacity=8, top_k=1)
-    import jax
-
-    expected = jax.nn.gelu(x.astype(jnp.float32) @ w_in[3]) @ w_out[3]
-    np.testing.assert_allclose(
-        np.asarray(out), np.asarray(expected), rtol=1e-4, atol=1e-5
-    )
-
-
-def test_moe_sharded_matches_local(ep_mesh):
-    """Expert-parallel all_to_all path == per-shard local oracle."""
-    import jax.numpy as jnp
-
-    from ray_tpu.ops.moe import moe_block_local, moe_block_sharded
-
-    x, wg, w_in, w_out = _setup(B=32)
-    C = 8
-    out = moe_block_sharded(x, wg, w_in, w_out, ep_mesh, capacity=C)
-    # oracle: same routing/capacity computed per token shard, all experts
-    # local (expert math is per-token, so results must be identical)
-    shards = [
-        moe_block_local(x[i * 8:(i + 1) * 8], wg, w_in, w_out, capacity=C)
-        for i in range(4)
-    ]
-    expected = jnp.concatenate(shards, axis=0)
-    np.testing.assert_allclose(
-        np.asarray(out), np.asarray(expected), rtol=1e-4, atol=1e-5
-    )
-
-
-def test_moe_sharded_differentiable(ep_mesh):
+def test_routing_is_sigmoid_top_k_with_a_bias_that_moves_the_choice_only(layer):
     import jax
     import jax.numpy as jnp
 
-    from ray_tpu.ops.moe import moe_block_sharded
+    from ray_tpu.ops import moe
 
-    x, wg, w_in, w_out = _setup(B=32)
+    weights, x, model = layer
+    chosen, gates = moe.route(x, weights["router"], weights["bias"], 4)
+    s = np.asarray(jax.nn.sigmoid(x @ weights["router"]))
+    want = np.argsort(-(s + np.asarray(weights["bias"])), axis=1)[:, :4]
+    assert np.array_equal(np.sort(np.asarray(chosen), 1), np.sort(want, 1))
+    np.testing.assert_allclose(np.asarray(gates).sum(1), 1.0, rtol=1e-6)
+    picked = np.take_along_axis(s, np.asarray(chosen), 1)
+    np.testing.assert_allclose(np.asarray(gates), picked / picked.sum(1, keepdims=True), rtol=1e-5)
+    # without the bias other experts are chosen for some token
+    plain, _ = moe.route(x, weights["router"], jnp.zeros_like(weights["bias"]), 4)
+    assert not np.array_equal(np.sort(np.asarray(plain), 1), np.sort(np.asarray(chosen), 1))
 
-    def loss(x, wg, w_in, w_out):
-        out = moe_block_sharded(x, wg, w_in, w_out, ep_mesh, capacity=8)
-        return (out.astype(jnp.float32) ** 2).sum()
 
-    val, grads = jax.value_and_grad(loss, argnums=(0, 1, 2, 3))(
-        x, wg, w_in, w_out
-    )
-    assert np.isfinite(float(val))
-    for g in grads:
-        assert bool(jnp.isfinite(g).all())
-    # expert weights actually receive gradient
-    assert float(jnp.abs(grads[2]).sum()) > 0
+@pytest.mark.parametrize("first,count", [(0, 4), (8, 4), (4, 8), (0, 16)])
+def test_the_held_experts_part_is_the_references(layer, first, count):
+    from benchmark.reference import mimo_v2_ref
+    from ray_tpu.ops import moe
+
+    weights, x, model = layer
+    mine = share(weights, first, count)
+    y, stats = moe.expert_layer(x, mine, first=first, top_k=4)
+    want = mimo_v2_ref.experts(x, mine, model, (first, count))
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want), atol=2e-5)
+    chosen, _ = moe.route(x, weights["router"], weights["bias"], 4)
+    here = (np.asarray(chosen) >= first) & (np.asarray(chosen) < first + count)
+    loads = np.bincount(np.asarray(chosen)[here] - first, minlength=count)
+    assert list(np.asarray(stats)) == [here.sum(), count, (loads > 0).sum(), loads.max()]
+
+
+def test_the_shares_of_four_chips_add_up_to_the_uncut_layer(layer):
+    """16 experts as 4 shares of 4: every chip routes over all 16 and
+    normalises over all the chosen, so the four partial results sum to
+    what the reference gives with every expert held."""
+    from benchmark.reference import mimo_v2_ref
+    from ray_tpu.ops import moe
+
+    weights, x, model = layer
+    parts = [moe.expert_layer(x, share(weights, f, 4), first=f, top_k=4) for f in (0, 4, 8, 12)]
+    whole = mimo_v2_ref.experts(x, weights, model, (0, 16))
+    np.testing.assert_allclose(sum(np.asarray(y) for y, _ in parts), np.asarray(whole), atol=5e-5)
+    # every token-expert pair landed on exactly one chip
+    assert sum(int(s[0]) for _, s in parts) == x.shape[0] * 4
+    assert float(np.abs(np.asarray(whole)).max()) > 0.1
+
+
+@pytest.mark.parametrize("tokens", [24, 100, 130])
+def test_no_token_is_dropped_when_every_token_chooses_one_expert(layer, tokens):
+    """A bias that sends every token to expert 2: it gets them all,
+    whatever their number (more than one block of the sorted buffer, and
+    a number that is no power of two)."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark.reference import mimo_v2_ref
+    from ray_tpu.ops import moe
+
+    weights, _, model = layer
+    x = jax.random.normal(jax.random.PRNGKey(tokens), (tokens, weights["router"].shape[0]))
+    crowded = {**weights, "bias": weights["bias"].at[2].set(10.0)}
+    mine = share(crowded, 0, 4)
+    y, stats = moe.expert_layer(x, mine, first=0, top_k=4)
+    assert int(stats[3]) == tokens and int(stats[0]) >= tokens
+    want = mimo_v2_ref.experts(x, mine, model, (0, 4))
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want), atol=5e-5)
+    # rows that are not live are routed nowhere and counted nowhere
+    live = jnp.arange(tokens) < 5
+    y5, stats5 = moe.expert_layer(x, mine, first=0, top_k=4, live=live)
+    assert int(stats5[3]) == 5
+    np.testing.assert_allclose(np.asarray(y5[:5]), np.asarray(want[:5]), atol=5e-5)
+    assert float(np.abs(np.asarray(y5[5:])).max()) == 0.0

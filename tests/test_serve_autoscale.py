@@ -411,6 +411,11 @@ def test_drain_deadline_force_closes(rt):
 
     serve.run(slowtick.bind())
     addr = _proxy_addr(serve)
+    # serve.run returns when the controller counts both replicas healthy;
+    # the proxy learns of the second by its long-poll, a poll step (50 ms)
+    # later at worst, and six streams sent inside that gap all go to the
+    # first replica (6/0: nothing is drained with a stream on it)
+    time.sleep(0.5)
     dones = [threading.Event() for _ in range(6)]
 
     def stream(idx):
