@@ -222,7 +222,8 @@ class LayerCache:
     merged in the last dimension at rest: split into ``[.., kv_heads,
     192]`` the tiling pads 4 heads to 8 and 192 to 256, and every program
     relaid the whole pool on the way in (0.49 s of a traced 4 s; PERF.md,
-    PR 46). A page is taken whole and split after it is taken."""
+    PR 46), and they stay merged through decode's attention too
+    (``_products``): a gathered span of pages is as costly to split."""
 
     layers: Tuple[jax.Array, ...]
     page_tokens: int
@@ -280,8 +281,9 @@ def _rope(x, pos, rotary_dim: int, theta: float):
 
 
 def _qkv(cfg: MiMoV2Config, l: int, attn, h, pos):
-    """h [T, D] at positions ``pos`` [T] -> q [T, H, Dk], k [T, Hkv, Dk],
-    v [T, Hkv, Dv], rotated and scaled."""
+    """h [T, D] at positions ``pos`` [T] -> q [T, H, Dk] and, the heads
+    merged as the caches store them, k [T, Hkv * Dk], v [T, Hkv * Dv];
+    rotated and scaled."""
     dt = cfg.dtype
     T = h.shape[0]
     window = bool(cfg.hybrid_layer_pattern[l])
@@ -291,52 +293,99 @@ def _qkv(cfg: MiMoV2Config, l: int, attn, h, pos):
     v = (h @ attn["wv"].astype(dt)).reshape(T, cfg.kv_heads(l), cfg.v_head_dim)
     q = _rope(q, pos, cfg.rotary_dim, theta)
     k = _rope(k, pos, cfg.rotary_dim, theta)
-    return q, k, (v.astype(jnp.float32) * cfg.attention_value_scale).astype(dt)
+    v = (v.astype(jnp.float32) * cfg.attention_value_scale).astype(dt)
+    return q, k.reshape(T, -1), v.reshape(T, -1)
 
 
-def _softmax_update(carry, scores, values, visible):
-    """One block of an online softmax. ``scores`` [..., Q, T] float32,
-    ``values`` broadcastable to [..., T, Dv], ``visible`` [..., Q, T]."""
+def _products(q, kv_heads: int):
+    """The two products of attention for queries ``q`` [R, Q, H, Dk], as
+    functions of K and V with the heads merged in the minor dimension, as
+    the caches store them: ``scores(k [R, T, Hkv * Dk])`` -> [R, H, Q, T]
+    float32 and ``weighted(p [R, H, Q, T], v [R, T, Hkv * Dv])`` -> [R, H,
+    Q, Dv] float32; query head h reads K/V head ``h // (H / Hkv)``.
+
+    Split into ``[R, T, Hkv, size]`` a gathered span of pages or a ring is
+    relaid whole (4 or 8 heads are no multiple of 8 sublanes, 192 none of
+    128 lanes: every K and V byte a decode step reads written three more
+    times, a fifth of the step; PERF.md, PR 47), so the split is made on
+    the side that is small, and which side that is the queries a row say.
+    One query a row is small beside K and V: each head is spread once over
+    all Hkv * Dk columns, its own numbers in its K/V head's columns and
+    exact zeros in the others, which add exact zeros to a float32 sum; a
+    K/V head's values are a slice of columns (whole lanes at Dv 128), its
+    query heads' probabilities a slice of rows, a product a K/V head. A
+    chunk of queries is not small: Hkv times the operations would show (2
+    to 4 ms of a 512-wide prefill call's 24), and its few keys and values
+    are split."""
+    R, Q, H, Dk = q.shape
+    G = H // kv_heads
+    qg = q.reshape(R, Q, kv_heads, G, Dk)
+    scale = Dk ** -0.5
+    if Q == 1:
+        own = jnp.eye(kv_heads, dtype=q.dtype)[:, None, :, None]
+        spread = (qg[..., None, :] * own).reshape(R, Q, H, kv_heads * Dk)
+        # left to itself the compiler sinks the spreading into a loop over
+        # pages and makes the 12.6 MB anew every turn (0.7 ms a step)
+        spread = lax.optimization_barrier(spread)
+
+        def scores(k):
+            return scale * jnp.einsum("rqhc,rtc->rhqt", spread, k,
+                                      preferred_element_type=jnp.float32)
+
+        def weighted(p, v):
+            Dv = v.shape[2] // kv_heads
+            return jnp.concatenate([
+                jnp.einsum("rgqt,rtv->rgqv", p[:, j * G:(j + 1) * G],
+                           v[:, :, j * Dv:(j + 1) * Dv],
+                           preferred_element_type=jnp.float32)
+                for j in range(kv_heads)], axis=1)
+    else:
+        def scores(k):
+            return scale * jnp.einsum(
+                "rqjgd,rtjd->rjgqt", qg, k.reshape(R, -1, kv_heads, Dk),
+                preferred_element_type=jnp.float32).reshape(R, H, Q, -1)
+
+        def weighted(p, v):
+            T = v.shape[1]
+            return jnp.einsum(
+                "rjgqt,rtjv->rjgqv", p.reshape(R, kv_heads, G, Q, T),
+                v.reshape(R, T, kv_heads, -1),
+                preferred_element_type=jnp.float32).reshape(R, H, Q, -1)
+
+    return scores, weighted
+
+
+def _softmax_update(carry, scores, values, visible, weighted):
+    """One block of an online softmax: ``scores`` [R, H, Q, T] float32 of
+    ``_products``, ``visible`` broadcastable to them, ``values`` [R, T, Hkv
+    * Dv] for ``weighted`` of the same ``_products``."""
     m, den, acc = carry
     scores = jnp.where(visible, scores, -1e30)
     m_new = jnp.maximum(m, scores.max(-1))
     scale = jnp.exp(m - m_new)
     p = jnp.where(visible, jnp.exp(scores - m_new[..., None]), 0.0)
-    acc = acc * scale[..., None] + jnp.einsum(
-        "rhgqt,rthv->rhgqv", p.astype(values.dtype), values,
-        preferred_element_type=jnp.float32,
-    )
+    acc = acc * scale[..., None] + weighted(p.astype(values.dtype), values)
     return m_new, den * scale + p.sum(-1), acc
 
 
 def _finish(carry, sink=None):
-    """The softmax's quotient, [R, Hkv, G, Q, Dv] -> [R, Q, H * Dv]. A
-    ``sink`` [Hkv, G], one learned logit a head, joins the denominator
-    and nothing else."""
+    """The softmax's quotient, [R, H, Q, Dv] -> [R, Q, H * Dv]. A ``sink``
+    [H], one learned logit a head, joins the denominator and nothing else."""
     m, den, acc = carry
     if sink is not None:
-        s = sink.astype(jnp.float32)[None, :, :, None]
+        s = sink.astype(jnp.float32)[None, :, None]
         m_new = jnp.maximum(m, s)
         scale = jnp.exp(m - m_new)
         den, acc = den * scale + jnp.exp(s - m_new), acc * scale[..., None]
     out = acc / den[..., None]
-    R, Hkv, G, Q, Dv = out.shape
-    return out.transpose(0, 3, 1, 2, 4).reshape(R, Q, Hkv * G * Dv)
+    R, H, Q, Dv = out.shape
+    return out.transpose(0, 2, 1, 3).reshape(R, Q, H * Dv)
 
 
-def _start(R, Hkv, G, Q, Dv):
-    return (jnp.full((R, Hkv, G, Q), -1e30, jnp.float32),
-            jnp.zeros((R, Hkv, G, Q), jnp.float32),
-            jnp.zeros((R, Hkv, G, Q, Dv), jnp.float32))
-
-
-def _scores(q, k, kv_heads: int):
-    """q [R, Q, H, Dk] against k [R, T, Hkv, Dk] -> [R, Hkv, G, Q, T]
-    float32: query head h reads K/V head ``h // (H / Hkv)``."""
-    R, Q, H, Dk = q.shape
-    qg = q.reshape(R, Q, kv_heads, H // kv_heads, Dk)
-    return jnp.einsum("rqhgd,rthd->rhgqt", qg, k,
-                      preferred_element_type=jnp.float32) * Dk ** -0.5
+def _start(R, H, Q, Dv):
+    return (jnp.full((R, H, Q), -1e30, jnp.float32),
+            jnp.zeros((R, H, Q), jnp.float32),
+            jnp.zeros((R, H, Q, Dv), jnp.float32))
 
 
 def _pages_a_turn(max_pages: int, wanted: int) -> int:
@@ -351,25 +400,38 @@ def _paged_attend(q, k_pool, v_pool, tables, q_pos, kv_heads: int,
     over each row's own pages of a full layer (``tables`` [R, MaxPages]):
     a loop over page-table columns, a few at a time, that stops behind the
     last position any query sees, so a step reads the live context and
-    neither the table's width nor the pool. Returns [R, Q, H * Dv]."""
+    neither the table's width nor the pool. The gathered pages go into the
+    products as they lie. Returns [R, Q, H * Dv]."""
     B = k_pool.shape[1]
-    R, Q, H, Dk = q.shape
-    Dv = v_pool.shape[2] // kv_heads
+    R, Q, H, _ = q.shape
     C = _pages_a_turn(tables.shape[1], wanted)
     span = C * B
+    scores, weighted = _products(q, kv_heads)
 
     def turn(j, carry):
         pages = lax.dynamic_slice_in_dim(tables, j * C, C, axis=1)  # [R, C]
-        kc = k_pool[pages].reshape(R, span, kv_heads, Dk)
-        vc = v_pool[pages].reshape(R, span, kv_heads, Dv)
+        kc = k_pool[pages].reshape(R, span, -1)
+        vc = v_pool[pages].reshape(R, span, -1)
         kv_pos = j * span + jnp.arange(span)
         visible = kv_pos[None, None, :] <= q_pos[:, :, None]  # [R, Q, T]
-        return _softmax_update(carry, _scores(q, kc, kv_heads), vc,
-                               visible[:, None, None])
+        return _softmax_update(carry, scores(kc), vc, visible[:, None],
+                               weighted)
 
     carry = lax.fori_loop(0, jnp.max(q_pos) // span + 1, turn,
-                          _start(R, kv_heads, H // kv_heads, Q, Dv))
+                          _start(R, H, Q, v_pool.shape[2] // kv_heads))
     return _finish(carry)
+
+
+def _window_attend(q, keys, values, visible, kv_heads: int, sink):
+    """Attention of ``q`` [R, Q, H, Dk] over a window layer's keys and values
+    as they lie, [R, T, Hkv * size] (a ring, or a ring and the chunk behind
+    it), where ``visible`` [R, Q, T], the layer's ``sink`` [H] in the
+    denominator. Returns [R, Q, H * Dv]."""
+    R, Q, H, _ = q.shape
+    scores, weighted = _products(q, kv_heads)
+    carry = _softmax_update(_start(R, H, Q, values.shape[2] // kv_heads),
+                            scores(keys), values, visible[:, None], weighted)
+    return _finish(carry, sink)
 
 
 def _ring_positions(upto, size: int):
@@ -453,25 +515,21 @@ def prefill_paged(cfg: MiMoV2Config, params, tokens, start, length, cache_k,
         if cfg.hybrid_layer_pattern[l]:
             old_k = lax.dynamic_index_in_dim(ks[l], row, 0, keepdims=False)
             old_v = lax.dynamic_index_in_dim(vs[l], row, 0, keepdims=False)
-            keys = jnp.concatenate([old_k.reshape(W, Hkv, -1), k])
-            vals = jnp.concatenate([old_v.reshape(W, Hkv, -1), v])
+            keys = jnp.concatenate([old_k, k])
+            vals = jnp.concatenate([old_v, v])
             k_pos = jnp.concatenate([before, pos])
             gap = pos[:, None] - k_pos[None, :]
             visible = (k_pos >= 0)[None, :] & (gap >= 0) & (gap < W)
-            carry = _softmax_update(
-                _start(1, Hkv, q.shape[1] // Hkv, P, cfg.v_head_dim),
-                _scores(q[None], keys[None], Hkv), vals[None],
-                visible[None, None, None],
-            )
-            att = _finish(carry, layer["attn"]["sink"].reshape(Hkv, -1))[0]
+            att = _window_attend(q[None], keys[None], vals[None], visible[None],
+                                 Hkv, layer["attn"]["sink"])[0]
             src = jnp.clip(after - start, 0, P - 1)
-            new_k = jnp.where(takes[:, None], k.reshape(P, -1)[src], old_k)
-            new_v = jnp.where(takes[:, None], v.reshape(P, -1)[src], old_v)
+            new_k = jnp.where(takes[:, None], k[src], old_k)
+            new_v = jnp.where(takes[:, None], v[src], old_v)
             ks[l] = lax.dynamic_update_index_in_dim(ks[l], new_k, row, 0)
             vs[l] = lax.dynamic_update_index_in_dim(vs[l], new_v, row, 0)
         else:
-            ks[l] = ks[l].at[page_of, pos % B].set(k.reshape(P, -1))
-            vs[l] = vs[l].at[page_of, pos % B].set(v.reshape(P, -1))
+            ks[l] = ks[l].at[page_of, pos % B].set(k)
+            vs[l] = vs[l].at[page_of, pos % B].set(v)
             att = _paged_attend(q[None], ks[l], vs[l], page_table[None],
                                 pos[None], Hkv, 8)[0]
         x, _ = _rest_of_block(cfg, layer, x, att, live)
@@ -509,17 +567,13 @@ def _decode_paged_impl(cfg: MiMoV2Config, params, last_tokens, lengths,
         h = _rmsnorm(x, layer["norm1"], cfg.layernorm_epsilon).astype(dt)
         q, k, v = _qkv(cfg, l, layer["attn"], h, pos)
         if cfg.hybrid_layer_pattern[l]:
-            ks[l] = ks[l].at[rows, slot].set(k.reshape(S, -1), mode="drop")
-            vs[l] = vs[l].at[rows, slot].set(v.reshape(S, -1), mode="drop")
-            carry = _softmax_update(
-                _start(S, Hkv, q.shape[1] // Hkv, 1, cfg.v_head_dim),
-                _scores(q[:, None], ks[l].reshape(S, W, Hkv, -1), Hkv),
-                vs[l].reshape(S, W, Hkv, -1), in_ring[:, None, None, None],
-            )
-            att = _finish(carry, layer["attn"]["sink"].reshape(Hkv, -1))[:, 0]
+            ks[l] = ks[l].at[rows, slot].set(k, mode="drop")
+            vs[l] = vs[l].at[rows, slot].set(v, mode="drop")
+            att = _window_attend(q[:, None], ks[l], vs[l], in_ring[:, None],
+                                 Hkv, layer["attn"]["sink"])[:, 0]
         else:
-            ks[l] = ks[l].at[page_of, pos % B].set(k.reshape(S, -1))
-            vs[l] = vs[l].at[page_of, pos % B].set(v.reshape(S, -1))
+            ks[l] = ks[l].at[page_of, pos % B].set(k)
+            vs[l] = vs[l].at[page_of, pos % B].set(v)
             att = _paged_attend(q[:, None], ks[l], vs[l], page_tables,
                                 pos[:, None], Hkv, 4)[:, 0]
         x, counted = _rest_of_block(cfg, layer, x, att, live)

@@ -318,3 +318,93 @@ def test_the_expected_experts_hit_is_what_the_program_counts(tiny):
     assert family.decode_step_bytes(model, 6, 8) < family.decode_step_bytes(model, 6, 200)
     assert (family.decode_step_bytes(model, 6, 5000) - family.decode_step_bytes(model, 6, 4000)
             == 2.0 * 6 * 1000 * 2 * 1 * (24 + 16))  # only the full layers grow past the window
+
+
+def plain_attention(q, k, v, visible, kv_heads, sink=None):
+    """A softmax a head, written plainly: q [R, Q, H, Dk] over k [R, T, Hkv *
+    Dk] and v [R, T, Hkv * Dv] where ``visible`` [R, Q, T]; query head h
+    reads K/V head ``h // (H / Hkv)``; a ``sink`` [H] joins the denominator
+    alone. Float32 at the highest precision -> [R, Q, H * Dv]."""
+    import jax
+    import jax.numpy as jnp
+
+    q, k, v = (a.astype(jnp.float32) for a in (q, k, v))
+    R, Q, H, Dk = q.shape
+    Dv = v.shape[-1] // kv_heads
+    heads = []
+    for h in range(H):
+        j = h // (H // kv_heads)
+        s = jnp.einsum("rqd,rtd->rqt", q[:, :, h], k[:, :, j * Dk:(j + 1) * Dk],
+                       precision=jax.lax.Precision.HIGHEST) * Dk ** -0.5
+        s = jnp.where(visible, s, -jnp.inf)
+        if sink is not None:
+            s = jnp.concatenate([s, jnp.full((R, Q, 1), sink[h], jnp.float32)], -1)
+        p = jax.nn.softmax(s, axis=-1)[:, :, : k.shape[1]]
+        heads.append(jnp.einsum("rqt,rtv->rqv", p, v[:, :, j * Dv:(j + 1) * Dv],
+                                precision=jax.lax.Precision.HIGHEST))
+    return jnp.stack(heads, axis=2).reshape(R, Q, H * Dv)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("Q", [1, 5])
+@pytest.mark.parametrize("sizes", [(24, 16), (192, 128)], ids=["tiny", "published"])
+@pytest.mark.parametrize("kv_heads", [1, 2, 4, 8])
+def test_attention_over_merged_heads_is_a_softmax_a_head(kv_heads, sizes, Q, dtype):
+    """The two products that take K and V with the heads merged, as the
+    caches store them, against a plain softmax a head: a full layer over a
+    page table (a row of length 0, rows that end mid-page, pages in no
+    order, noise in the pages a row does not own) and a window layer (a
+    sink a head, a ring not yet full), one query a row (q spread over the
+    K/V heads' columns) and a chunk of five (the keys split)."""
+    import jax
+    import jax.numpy as jnp
+
+    jax.config.update("jax_platforms", "cpu")
+    from ray_tpu.models import mimo_v2 as m
+
+    dt = jnp.dtype(dtype)
+    # bfloat16: the probabilities are rounded to 2**-8 before the second
+    # product, on values of size ~1 (the cases read up to 3.0e-3, and
+    # 1.3e-6 in float32)
+    tol = 1e-5 if dtype == "float32" else 1e-2
+    Dk, Dv = sizes
+    H, R, B, max_pages, N = 8, 5, 8, 4, 12
+    rng = np.random.default_rng(kv_heads * 100 + Dk + Q)
+
+    def draw(*shape):
+        return jnp.asarray(rng.normal(0, 1, shape), dt)
+
+    q = draw(R, Q, H, Dk)
+    # full layer: the last query's position a row; 0 is a row of no length
+    # (its table points at the scratch page), 11 and 17 end mid-page, 31
+    # fills the table
+    last = np.asarray([0, 11, 17, 31, 8])
+    q_pos = np.maximum(last[:, None] - np.arange(Q)[::-1][None], 0)
+    tables = np.zeros((R, max_pages), np.int32)
+    free = list(rng.permutation(np.arange(1, N)))
+    for r in range(1, R):
+        for c in range(last[r] // B + 1):
+            tables[r, c] = free.pop()
+    k_pool, v_pool = draw(N, B, kv_heads * Dk), draw(N, B, kv_heads * Dv)
+    got = m._paged_attend(q, k_pool, v_pool, jnp.asarray(tables),
+                          jnp.asarray(q_pos, jnp.int32), kv_heads, 2)
+    T = max_pages * B
+    visible = np.arange(T)[None, None, :] <= q_pos[:, :, None]
+    want = plain_attention(q, k_pool[tables].reshape(R, T, -1),
+                           v_pool[tables].reshape(R, T, -1), visible, kv_heads)
+    assert got.shape == (R, Q, H * Dv) and got.dtype == jnp.float32
+    assert float(jnp.abs(want).max()) > 0.5
+    assert float(jnp.abs(got - want).max()) < tol
+
+    # window layer: a ring of 16 slots a row, as many of them written as the
+    # row is long, each query seeing what its own position allows
+    W = 16
+    ring_k, ring_v = draw(R, W, kv_heads * Dk), draw(R, W, kv_heads * Dv)
+    sink = jnp.asarray(rng.normal(0, 1, (H,)), jnp.float32)
+    held = np.asarray([1, 7, 16, 12, 3])  # slots written; 16 is a full ring
+    seen = np.minimum(held[:, None] - np.arange(Q)[::-1][None], W).clip(1)
+    visible = np.arange(W)[None, None, :] < seen[:, :, None]
+    got = m._window_attend(q, ring_k, ring_v, jnp.asarray(visible), kv_heads, sink)
+    want = plain_attention(q, ring_k, ring_v, visible, kv_heads, sink)
+    assert got.shape == (R, Q, H * Dv)
+    assert float(jnp.abs(got - want).max()) < tol

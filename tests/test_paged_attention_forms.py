@@ -16,7 +16,8 @@ reference below keeps the plain ``[L, N, B, H, Dh]``; ``_stored`` and
 too: prefill at ``start > 0`` over pages another row wrote and K-chunk
 decode of rows sharing them, against the full forward; the two page
 functions' round trip; and, compiled for a described v5e at gpt2-xl's
-serving shapes, that no serving program copies a whole pool."""
+serving shapes, that no serving program copies a whole pool, and at
+MiMo-V2.5's that decode's attention relays no gathered span and no ring."""
 
 import re
 import signal
@@ -345,6 +346,54 @@ def test_compiled_serving_programs_copy_no_whole_pool(one_chip, time_limit):
         if name != "decode_multi_paged":  # its fc_out relay is 0.98 GB, known
             temps = compiled.memory_analysis().temp_size_in_bytes
             assert temps < 0.5e9, (name, temps)
+
+
+@pytest.mark.parametrize("kind", ["full", "window"])
+def test_compiled_mimo_decode_attention_splits_no_cached_heads(one_chip, time_limit, kind):
+    """At ``mimo-reason-decode``'s shapes (128 rows of one query, 64 heads
+    of 192 over 4 K/V heads in a full layer and 8 in a window layer, V of
+    128; pools of 2,049 pages of 64, four pages a turn; rings of 128) a
+    layer's decode attention, as the v5e's compiler writes it, takes K and
+    V with the heads merged as ``init_paged_cache`` stores them: no
+    ``copy``, ``reshape``, ``transpose`` or fusion whose result is a
+    gathered span or a ring split into heads, and no ``copy`` of a ring.
+    Split, 4 or 8 heads are no multiple of 8 sublanes and 192 none of 128
+    lanes, and every K and V byte the step reads was written three more
+    times on the way (``reshape bf16[128,256,4,192]`` alone 13.8% of the
+    step on the chip; PERF.md, PR 47)."""
+    from ray_tpu.models import mimo_v2 as m
+
+    cfg = m.CONFIGS["mimo-v2.5"]
+    S, N, Bx, mp, C = 128, 2049, 64, 64, 4
+    H, Dv, W = cfg.num_attention_heads, cfg.v_head_dim, cfg.sliding_window
+    l = cfg.hybrid_layer_pattern.index(kind == "window")
+    Hkv = cfg.kv_heads(l)
+
+    def sds(shape, dtype=cfg.dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    stored = jax.eval_shape(lambda: m.init_paged_cache(cfg, N, Bx, S))
+    k, v = (sds(c.layers[l].shape) for c in stored)
+    q = sds((S, 1, H, cfg.head_dim))
+    if kind == "full":
+        assert (k.shape, v.shape) == ((N, Bx, 768), (N, Bx, 512))
+        span = C * Bx
+        lowered = jax.jit(m._paged_attend, static_argnums=(5, 6)).lower(
+            q, k, v, sds((S, mp), jnp.int32), sds((S, 1), jnp.int32), Hkv, C)
+    else:
+        assert (k.shape, v.shape) == ((S, W, 1536), (S, W, 1024))
+        span = W
+
+        lowered = jax.jit(m._window_attend, static_argnums=4).lower(
+            q, k, v, sds((S, 1, W), jnp.bool_), Hkv, sds((H,), jnp.float32))
+    text = lowered.compile().as_text()
+    merged = rf"{S},{span},(?:{k.shape[2]}|{v.shape[2]})"
+    assert re.search(rf"= bf16\[{merged}\]", text)  # K and V are there, merged
+    split = rf"{S},{span},{Hkv},(?:{cfg.head_dim}|{Dv})"
+    relays = re.findall(
+        rf"= \w+\[(?:{split})\]\S* (?:copy|reshape|transpose|fusion)\(", text)
+    relays += re.findall(rf"= \w+\[{S},{W},\d+\]\S* copy\(", text)
+    assert not relays, relays
 
 
 @pytest.mark.parametrize(

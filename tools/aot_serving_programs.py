@@ -1,24 +1,36 @@
 """What the TPU's compiler makes of the three serving programs, without a chip.
 
 Compiles ``decode_paged_and_sample``, ``decode_multi_paged`` and
-``prefill_paged`` for a described v5e at a configuration's shapes, once with
-the float32 tree ``gpt2.init`` returns and once with the tree an engine
-holds (``gpt2_decode.serving_params``), and prints for each: operations
-with ``remat`` in their name (and how often the text says the word),
-copies of a whole page pool and of one layer of it (a relay to another
-layout: the pool's shape is ``init_paged_cache``'s, and its layout at the
-program's entry is printed beside them), whole kernel stacks written anew
-(a ``convert`` of a float32 parameter to the compute type, or a ``copy``
-to another layout), and ``memory_analysis()``'s arguments and
-temporaries. Also the loader's own program
-(``load_serving_params``'s init and cast), whose temporaries are what a
-load holds beyond the weights.
+``prefill_paged`` of a served model (``models.resolve``: its config and
+decode module) for a described v5e at the engine's own sizes, and prints
+for each: operations with ``remat`` in their name (and how often the text
+says the word), the relays its family watches for, ``memory_analysis()``'s
+arguments and temporaries, and the layout the first pool enters in.
 
-The text names the operations the chip's trace will show (PERF.md, PR 30
-and PR 32); it says nothing about time. Run here, on the CPU:
+What a family watches for (a relay is an operation that writes an array
+anew in another layout):
+
+- GPT-2 (``gpt2_decode``), on the float32 tree ``gpt2.init`` returns and
+  on the tree an engine holds (``serving_params``): copies of a whole page
+  pool and of one layer of it (the pool's shape is ``init_paged_cache``'s),
+  whole kernel stacks written anew (a ``convert`` of a float32 parameter to
+  the compute type, or a ``copy`` to another layout); and the loader's own
+  program (``load_serving_params``'s init and cast), whose temporaries are
+  what a load holds beyond the weights.
+- MiMo-V2 (``mimo_v2``), a cache an array a layer: copies of a full
+  layer's pool, copies of a window layer's ring, and K or V split into
+  heads (a ``copy``, ``reshape``, ``transpose`` or fusion whose result is
+  ``[.., .., kv_heads, size]``: a gathered span of pages or a ring relaid
+  so that the heads split, which the decode programs hold none of since
+  PR 47 and prefill, whose keys are few, still does).
+
+The text names the operations the chip's trace will show (PERF.md, PR 30,
+PR 32 and PR 47); it says nothing about time. Run here, on the CPU:
 
     JAX_PLATFORMS=cpu python tools/aot_serving_programs.py [--model gpt2-xl]
         [--max-batch-size 6] [--page-tokens 64] [--prefill 128]
+    JAX_PLATFORMS=cpu python tools/aot_serving_programs.py --model mimo-v2.5 \\
+        --max-batch-size 32 --prefill 512
 """
 
 from __future__ import annotations
@@ -36,15 +48,14 @@ import jax.numpy as jnp
 from jax.experimental import topologies
 from jax.sharding import SingleDeviceSharding
 
-from ray_tpu.models import gpt2
-from ray_tpu.models import gpt2_decode as dec
+from ray_tpu import models
 
 
-def copies_of(ops, shape) -> int:
-    """``copy`` operations in ``ops`` whose result has ``shape``, in any
-    element type and layout."""
+def results_of(ops, shape, kinds="copy") -> int:
+    """Operations of ``kinds`` in ``ops`` whose result has ``shape`` (a
+    dimension may be a pattern), in any element type and layout."""
     dims = ",".join(map(str, shape))
-    return sum(bool(re.search(rf"= \w+\[{dims}\]\S* copy\(", ln)) for ln in ops)
+    return sum(bool(re.search(rf"= \w+\[{dims}\]\S* (?:{kinds})\(", ln)) for ln in ops)
 
 
 def entry_layouts(text: str, shape) -> list:
@@ -56,25 +67,68 @@ def entry_layouts(text: str, shape) -> list:
     return sorted(set(found))
 
 
-def report(name: str, compiled, pool, stacks) -> None:
+def report(name: str, compiled, watch, pool) -> None:
+    """One line a program: ``watch`` is (label, count of ``ops``) pairs."""
     text = compiled.as_text()
     ops = [ln for ln in text.splitlines() if re.match(r"\s*(ROOT )?%\S+ = ", ln)]
     remat = sum("remat" in ln.split(" = ")[0] for ln in ops)
-    pool_copies = copies_of(ops, pool)
-    layer_copies = copies_of(ops, (1,) + tuple(pool[1:])) + copies_of(ops, pool[1:])
-    rewritten = sum(
-        bool(re.search(rf"^\s*%(convert|copy)[.\d]* = bf16\[{s}\]", ln))
-        for ln in ops for s in stacks
-    )
     mem = compiled.memory_analysis()
+    counts = "  ".join(f"{label} {count(ops):2d}" for label, count in watch)
     print(
-        f"{name:38s} remat ops {remat:2d} ({text.count('remat'):2d} mentions)  "
-        f"whole-pool copies {pool_copies:2d}  pool-layer copies {layer_copies:2d}  "
-        f"kernel stacks rewritten {rewritten:2d}  "
+        f"{name:38s} remat ops {remat:2d} ({text.count('remat'):2d} mentions)  {counts}  "
         f"arguments {mem.argument_size_in_bytes / 1e9:5.2f} GB  "
         f"temporaries {mem.temp_size_in_bytes / 1e9:5.2f} GB  "
         f"pools enter as {' '.join(entry_layouts(text, pool)) or '-'}"
     )
+
+
+def gpt2_family(cfg, dec, stored_k, key):
+    """GPT-2's trees, what to count in a program's operations, and the
+    loader's program."""
+    from ray_tpu.models import gpt2
+
+    pool = tuple(jax.tree.leaves(stored_k)[0].shape)
+    d, f, L = cfg.d_model, cfg.d_ff, cfg.n_layer
+    h, hd = cfg.n_head, cfg.head_dim
+    stacks = [f"{L},{d},{f}", f"{L},{f},{d}", f"{L},{d},3,{h},{hd}", f"{L},{h},{hd},{d}"]
+    watch = [
+        ("whole-pool copies", lambda ops: results_of(ops, pool)),
+        ("pool-layer copies", lambda ops: results_of(ops, (1,) + pool[1:])
+         + results_of(ops, pool[1:])),
+        ("kernel stacks rewritten", lambda ops: sum(
+            bool(re.search(rf"^\s*%(convert|copy)[.\d]* = bf16\[{s}\]", ln))
+            for ln in ops for s in stacks)),
+    ]
+    as_init = jax.eval_shape(lambda: gpt2.init(jax.random.PRNGKey(0), cfg))
+    as_held = jax.eval_shape(lambda p: dec.serving_params(cfg, p), as_init)
+    loader = ("load_serving_params (init and cast)", lambda: dec.compile_init(cfg, key))
+    return [("float32 tree", as_init), ("serving_params", as_held)], watch, loader
+
+
+def mimo_v2_family(cfg, dec, stored_k, key):
+    """MiMo-V2's tree as an engine holds it and what to count: a cache is
+    an array a layer (``cache_spec``), a pool or a ring."""
+    spec = dec.cache_spec(cfg)
+    # a page of positions or more: q is [rows, kv_heads, group, size] too
+    positions = rf"\d{{{len(str(stored_k.page_tokens))},}}"
+    by_kind = {"full": set(), "window": set()}
+    for s, k in zip(spec, stored_k.layers):
+        for size in (s["k_size"], s["v_size"]):
+            by_kind[s["kind"]].add(k.shape[:2] + (s["kv_heads"] * size,))
+    split = {(r"\d+", positions, s["kv_heads"], size)
+             for s in spec for size in (s["k_size"], s["v_size"])}
+    watch = [
+        ("whole-pool copies", lambda ops: sum(results_of(ops, p) for p in by_kind["full"])),
+        ("ring copies", lambda ops: sum(results_of(ops, r) for r in by_kind["window"])),
+        ("K/V split into heads", lambda ops: sum(
+            results_of(ops, s, "copy|reshape|transpose|fusion") for s in split)),
+    ]
+    as_held = jax.eval_shape(lambda: dec.load_serving_params(cfg))
+    return [("load_serving_params", as_held)], watch, None
+
+
+FAMILIES = {"ray_tpu.models.gpt2_decode": gpt2_family,
+            "ray_tpu.models.mimo_v2": mimo_v2_family}
 
 
 def main() -> None:
@@ -85,7 +139,7 @@ def main() -> None:
     ap.add_argument("--prefill", type=int, default=128, help="prefill width")
     args = ap.parse_args()
 
-    cfg = gpt2.CONFIGS[args.model]
+    cfg, dec = models.resolve(args.model)
     topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     chip = SingleDeviceSharding(topo.devices[0])
 
@@ -98,43 +152,43 @@ def main() -> None:
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
 
-    # the stored shape is init_paged_cache's to decide, not this tool's
-    stored = jax.eval_shape(lambda: dec.init_paged_cache(cfg, n_pages, B))[0]
-    pool = jax.tree.map(lambda a: sds(a.shape, a.dtype), stored)
-    pool_shape = tuple(jax.tree.leaves(stored)[0].shape)
-    d, f, L = cfg.d_model, cfg.d_ff, cfg.n_layer
-    h, hd = cfg.n_head, cfg.head_dim
-    stacks = [f"{L},{d},{f}", f"{L},{f},{d}", f"{L},{d},3,{h},{hd}", f"{L},{h},{hd},{d}"]
+    def on_chip(tree):
+        return jax.tree.map(lambda a: sds(a.shape, a.dtype), tree)
+
+    # the stored shapes are init_paged_cache's to decide, not this tool's
+    stored_k, stored_v = jax.eval_shape(lambda: dec.init_paged_cache(cfg, n_pages, B, S))
+    cache_k, cache_v = on_chip(stored_k), on_chip(stored_v)
+    first_pool = tuple(jax.tree.leaves(stored_k)[0].shape)
     rows = (sds((S,), jnp.int32), sds((S,), jnp.int32))
     tables = sds((S, max_pages), jnp.int32)
     sampling = (sds((S,), jnp.float32), sds((S,), jnp.bool_))
     key = sds((2,), jnp.uint32)
     i32 = sds((), jnp.int32)
 
-    as_init = jax.eval_shape(lambda: gpt2.init(jax.random.PRNGKey(0), cfg))
-    as_held = jax.eval_shape(lambda p: dec.serving_params(cfg, p), as_init)
-    print(f"{args.model}: rows {S}, pool {n_pages} x {B} stored as "
-          f"{list(pool_shape)}, on {topo.devices[0].device_kind}")
-    for label, tree in (("float32 tree", as_init), ("serving_params", as_held)):
-        params = jax.tree.map(lambda a: sds(a.shape, a.dtype), tree)
+    trees, watch, loader = FAMILIES[dec.__name__](cfg, dec, stored_k, key)
+    print(f"{args.model}: rows {S}, pool {n_pages} x {B}, K stored as "
+          f"{sorted({tuple(a.shape) for a in jax.tree.leaves(stored_k)})}, "
+          f"on {topo.devices[0].device_kind}")
+    for label, tree in trees:
+        params = on_chip(tree)
         print(f"-- {label}: {dec.params_bytes(tree) / 1e9:.2f} GB")
         programs = {
             "decode_paged_and_sample": dec.decode_paged_and_sample.lower(
-                cfg, params, *rows, pool, pool, tables, *sampling, key, i32
+                cfg, params, *rows, cache_k, cache_v, tables, *sampling, key, i32
             ),
             "decode_multi_paged (any K)": dec.decode_multi_paged.lower(
-                cfg, params, *rows, pool, pool, tables, *sampling, key, i32, i32
+                cfg, params, *rows, cache_k, cache_v, tables, *sampling, key, i32, i32
             ),
             f"prefill_paged (P={args.prefill})": dec.prefill_paged.lower(
-                cfg, params, sds((1, args.prefill), jnp.int32), i32, i32, pool, pool,
-                sds((max_pages,), jnp.int32),
+                cfg, params, sds((1, args.prefill), jnp.int32), i32, i32, cache_k,
+                cache_v, sds((max_pages,), jnp.int32),
             ),
         }
         for name, lowered in programs.items():
-            report(name, lowered.compile(), pool_shape, stacks)
-    print("-- the loader")
-    report("load_serving_params (init and cast)", dec.compile_init(cfg, key),
-           pool_shape, stacks)
+            report(name, lowered.compile(), watch, first_pool)
+    if loader:
+        print("-- the loader")
+        report(loader[0], loader[1](), watch, first_pool)
 
 
 if __name__ == "__main__":
