@@ -2,8 +2,8 @@
 
 A served model is a config and a decode module, found by ``model_id``
 (``resolve``). The decode module is the engine's interface, the same
-names whichever model implements them (``models/gpt2_decode.py`` and
-``models/mimo_v2.py`` do):
+names whichever model implements them (``models/gpt2_decode.py``,
+``models/mimo_v2.py`` and ``models/deepseek_v3.py`` do):
 
     load_serving_params(cfg, checkpoint_path)   the stored weights
     params_bytes(params)
@@ -33,6 +33,7 @@ from typing import Any, Tuple
 FAMILIES = {
     "gpt2": ("ray_tpu.models.gpt2", "ray_tpu.models.gpt2_decode"),
     "mimo-v2": ("ray_tpu.models.mimo_v2", "ray_tpu.models.mimo_v2"),
+    "kanana-2": ("ray_tpu.models.deepseek_v3", "ray_tpu.models.deepseek_v3"),
 }
 
 

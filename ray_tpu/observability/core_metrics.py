@@ -34,6 +34,9 @@ _LATENCY_BOUNDS = (
     0.01, 0.05, 0.1, 0.5, 1.0, 5.0, 10.0,
 )
 _BATCH_BOUNDS = (1, 2, 4, 8, 16, 32, 64, 128)
+# the kinds of layer a serving cache is made of, a ``serve_kv_<kind>_bytes``
+# gauge each: what a model's ``cache_layout`` names some of
+KV_KINDS = ("full", "window", "latent")
 
 
 def _build() -> dict:
@@ -257,9 +260,11 @@ def _build() -> dict:
             "sealed prefix pages resident in this engine's page pool",
             tag_keys=("deployment", "node"),
         ),
-        # what the cache holds by kind of layer: a full layer's pages, a
-        # window layer's ring a decode row (models/mimo_v2.py); a model
-        # whose every layer is paged reads 0 under ``window``
+        # what the cache holds by kind of layer (``KV_KINDS``): a full
+        # layer's pages, a window layer's ring a decode row
+        # (models/mimo_v2.py), a latent layer's pages
+        # (models/deepseek_v3.py); a model reads 0 under the kinds it has
+        # none of
         "serve_kv_full_bytes": Gauge(
             "rt_serve_kv_full_bytes",
             "bytes of K and V the device holds for paged full-attention "
@@ -270,6 +275,12 @@ def _build() -> dict:
             "rt_serve_kv_window_bytes",
             "bytes of K and V the device holds for window-attention "
             "layers (a ring a decode row), per engine process",
+            tag_keys=("deployment", "node"),
+        ),
+        "serve_kv_latent_bytes": Gauge(
+            "rt_serve_kv_latent_bytes",
+            "bytes of latent rows the device holds for paged latent-"
+            "attention layers (models/deepseek_v3.py), per engine process",
             tag_keys=("deployment", "node"),
         ),
         "serve_prefix_refused": Counter(
@@ -301,6 +312,13 @@ def _build() -> dict:
             "rt_serve_moe_max_load_total",
             "tokens of the fullest held expert, summed over expert layers "
             "and decode steps",
+            tag_keys=("deployment",),
+        ),
+        "serve_mla_context_tokens": Counter(
+            "rt_serve_mla_context_tokens_total",
+            "positions the live rows of decode steps attended over in a "
+            "latent cache, summed over rows and steps (once a step, not a "
+            "layer); counted on the device beside the sampled tokens",
             tag_keys=("deployment",),
         ),
         "serve_kv_block_copies": Counter(

@@ -13,7 +13,8 @@ in for the absent chips.
 
 Routing is the sigmoid kind (``noaux_tc``): scores ``s = sigmoid(W_r x)``
 in float32, the top ``k`` of ``s + b`` chosen (``b`` a selection bias that
-takes no part in the gate), gates ``s_e / sum of the chosen s``.
+takes no part in the gate), gates ``s_e / sum of the chosen s``, times a
+``scale`` where the model has one (``routed_scaling_factor``).
 
 The product is grouped, not one-hot: the token-expert pairs that landed
 here are sorted by expert and multiplied by ``jax.lax.ragged_dot``, which
@@ -39,17 +40,19 @@ STATS = ("assignments", "expert_steps", "experts_hit", "max_load")
 
 
 def route(x: jax.Array, router: jax.Array, bias: jax.Array,
-          top_k: int) -> Tuple[jax.Array, jax.Array]:
+          top_k: int, scale: float = 1.0) -> Tuple[jax.Array, jax.Array]:
     """Sigmoid routing of ``x`` [T, D] over ``router`` [D, E] in float32:
     (chosen experts [T, k], their gates [T, k]). The bias moves the choice
-    and not the gate; the gates of one token sum to one."""
+    and not the gate; the gates of one token sum to ``scale``."""
     scores = jax.nn.sigmoid(jnp.dot(
         x.astype(jnp.float32), router.astype(jnp.float32),
         precision=lax.Precision.HIGHEST,
     ))
     _, chosen = lax.top_k(scores + bias.astype(jnp.float32), top_k)
     picked = jnp.take_along_axis(scores, chosen, axis=1)
-    return chosen, picked / picked.sum(-1, keepdims=True)
+    gates = picked / picked.sum(-1, keepdims=True)
+    # no operation where there is no scale: such a model's program is as it was
+    return chosen, gates if scale == 1.0 else gates * scale
 
 
 def _block_rows(tokens: int, top_k: int, held: int, routed: int) -> int:
@@ -71,6 +74,7 @@ def expert_layer(
     first: int,                        # the first expert held here
     top_k: int,
     live: Optional[jax.Array] = None,  # [T] bool: rows that are real tokens
+    scale: float = 1.0,                # the gates of one token sum to it
 ) -> Tuple[jax.Array, jax.Array]:
     """The held experts' part of the layer for ``x``, float32 [T, D], and
     what it counted (``STATS``, int32 [4]): token-expert pairs that landed
@@ -81,7 +85,7 @@ def expert_layer(
     held = weights["gate"].shape[0]
     routed = weights["router"].shape[1]
     with jax.named_scope("moe_experts"):
-        chosen, gates = route(x, weights["router"], weights["bias"], top_k)
+        chosen, gates = route(x, weights["router"], weights["bias"], top_k, scale)
         local = chosen - first
         here = (local >= 0) & (local < held)
         if live is not None:

@@ -69,8 +69,8 @@ class PrefillEngine:
         self.model_cfg, self._dec = models.resolve(cfg.model_id)
         if not self._dec.KV_TRANSFER:
             raise RuntimeError(
-                f"model {cfg.model_id!r} has no KV transfer: its cache has a "
-                f"spec a layer, and a shipment is pages of one shape"
+                f"model {cfg.model_id!r} has no KV transfer: its cache is not "
+                f"K and V pages of one shape, and a shipment is nothing else"
             )
         dec = self._dec
         self.params = dec.load_serving_params(self.model_cfg, cfg.checkpoint_path)

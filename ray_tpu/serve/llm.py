@@ -305,15 +305,19 @@ class LLMServer:
         ]
         # the caches as init_paged_cache stored them and as the device
         # holds them, tiling's padding included, by the kind of layer
-        # (paged full layers, a window layer's ring a row)
+        # (paged full layers, a window layer's ring a row, paged latent rows)
         layout = self._dec.cache_layout(self.model_cfg, cache_k, cache_v)
         self._kv_pool_shape = layout["shape"]
-        self._kv_bytes_by_kind = layout["bytes"]
+        # every kind there is a gauge for, zero where this model's layout
+        # names none of it
+        self._kv_bytes_by_kind = {
+            kind: int(layout["bytes"].get(kind, 0)) for kind in core_metrics.KV_KINDS
+        }
         self._kv_pool_bytes = sum(layout["bytes"].values())
         if core_metrics.ENABLED:
             ntags = {"deployment": self.cfg.model_id, "node": self._node_tag}
-            core_metrics.serve_kv_full_bytes.set(layout["bytes"]["full"], tags=ntags)
-            core_metrics.serve_kv_window_bytes.set(layout["bytes"]["window"], tags=ntags)
+            for kind, held in self._kv_bytes_by_kind.items():
+                getattr(core_metrics, f"serve_kv_{kind}_bytes").set(held, tags=ntags)
         self._started.set()
 
     # -- request path ---------------------------------------------------
@@ -700,7 +704,8 @@ class LLMServer:
             if req.kv_import is not None and not dec.KV_TRANSFER:
                 self._fail_request(req, RuntimeError(
                     f"model {self.cfg.model_id!r} takes no KV import: its "
-                    f"cache has a spec a layer that pages cannot carry"
+                    f"cache is not K and V pages of one shape, and a shipment "
+                    f"carries nothing else"
                 ))
                 return True  # consumed (failed); keep admitting
             use_prefix = bool(config.serve_prefix_cache)
@@ -800,7 +805,9 @@ class LLMServer:
                     n = min(len(s.prompt) - start, budget)
                     width = _bucket(n, max_pages * B - start)
                     n = min(n, width)
-                    with tracing.span("rt/engine/prefill"):
+                    # start and width say what a traced call was: a cold
+                    # chunk, or a tail behind a prefix
+                    with tracing.span("rt/engine/prefill", start=start, width=width):
                         tok = np.zeros((1, width), np.int32)
                         tok[0, :n] = s.prompt[start : start + n]
                         logits, cache_k, cache_v = dec.prefill_paged(

@@ -1,0 +1,167 @@
+"""The latent family through the one serving engine (serve/llm.py), found by
+its ``model_id``: prefix hits on latent pages, the decode programs' counts
+fetched with the tokens, the cache reported by kind, KV import and the
+prefill tier refused by name; and a GPT-2 engine never imports the family.
+"""
+
+import os
+import subprocess
+import sys
+
+import numpy as np
+import pytest
+from test_mimo_engine import _series
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@pytest.fixture(scope="module")
+def engine():
+    import jax
+
+    jax.config.update("jax_platforms", "cpu")
+    from ray_tpu.serve.llm import LLMConfig, LLMServer
+
+    srv = LLMServer(LLMConfig(model_id="kanana-2-tiny", max_batch_size=2,
+                              max_new_tokens_cap=64))
+    yield srv
+    srv.unload()
+
+
+def assert_greedy_by_the_reference(srv, prompt, tokens, margin=0.25):
+    """Every generated token is the reference's best, or within ``margin``
+    of it (bfloat16 against float32 on logits whose spread is 1)."""
+    import jax.numpy as jnp
+
+    from benchmark.families import deepseek_v3 as family
+    from benchmark.reference import deepseek_v3_ref
+
+    model = family.program_sizes("kanana-2-tiny")
+    seq = list(prompt) + list(tokens)
+    logits = np.asarray(deepseek_v3_ref.forward(srv.params, jnp.asarray(seq), model))
+    short = 0
+    for i, tok in enumerate(tokens):
+        at = logits[len(prompt) + i - 1]
+        short += at[tok] < at.max() - margin
+    # a router's tie may move one token's logits by an expert's output
+    assert short <= 1, (short, len(tokens))
+
+
+def test_a_second_turn_takes_a_prefix_hit_on_latent_pages_and_answers_as_it_does_cold(engine):
+    """A first turn of 150 tokens seals its two whole pages of latent rows;
+    a second turn whose prompt extends the first reuses them (no copy, the
+    tail alone is prefilled) and answers, at temperature 0, what an engine
+    that never saw the first turn answers."""
+    from ray_tpu.serve.llm import LLMConfig, LLMServer
+
+    rng = np.random.default_rng(3)
+    first = list(map(int, rng.integers(0, 256, 150)))
+    reply = engine({"prompt_tokens": first, "max_new_tokens": 10})["tokens"]
+    second = first + reply + list(map(int, rng.integers(0, 256, 30)))
+    reused = _series("rt_serve_prefix_tokens_reused_total")
+    refused = _series("rt_serve_prefix_refused_total")
+    warm = engine({"prompt_tokens": second, "max_new_tokens": 12})["tokens"]
+    assert _series("rt_serve_prefix_tokens_reused_total") - reused == 128
+    assert _series("rt_serve_prefix_refused_total") == refused
+    stats = engine.batch_stats()["prefix"]
+    assert stats["prefix_resident"] >= 2 and stats["pages_occupied"] == stats["prefix_resident"]
+    cold_engine = LLMServer(LLMConfig(model_id="kanana-2-tiny", max_batch_size=2,
+                                      max_new_tokens_cap=64))
+    try:
+        cold = cold_engine({"prompt_tokens": second, "max_new_tokens": 12})["tokens"]
+    finally:
+        cold_engine.unload()
+    assert warm == cold
+    assert_greedy_by_the_reference(engine, second, warm)
+
+
+def test_the_steps_counts_come_back_with_the_tokens(engine):
+    from ray_tpu.observability import core_metrics
+
+    if not core_metrics.ENABLED:
+        pytest.skip("observability is off")
+    steps = _series("rt_serve_moe_expert_steps_total")
+    context = _series("rt_serve_mla_context_tokens_total")
+    engine({"prompt_tokens": [1, 2, 3, 4, 5], "max_new_tokens": 9})
+    # 8 decode steps x 2 expert layers x 16 experts, every one held
+    assert _series("rt_serve_moe_expert_steps_total") - steps == 8 * 2 * 16
+    # the row attends over 6, 7, .. 13 positions: the prompt, what it has
+    # generated, the new position
+    assert _series("rt_serve_mla_context_tokens_total") - context == sum(range(6, 14))
+    assert _series("rt_serve_moe_assignments_total") > 0
+    assert 0 < _series("rt_serve_moe_experts_hit_total") <= _series("rt_serve_moe_expert_steps_total")
+
+
+def test_the_replica_reports_the_cache_by_kind(engine):
+    stats = engine.batch_stats()
+    assert [s[0] for s in stats["kv_pool_shape"]] == ["latent"] * 3
+    pages, page_tokens, width = stats["kv_pool_shape"][0][1:]
+    assert (page_tokens, width) == (64, 128)  # 32 + 8 of a row, and zeros to whole lanes
+    by_kind = stats["kv_bytes_by_kind"]
+    assert by_kind == {"full": 0, "window": 0, "latent": stats["kv_pool_bytes"]}
+    assert by_kind["latent"] >= 3 * pages * 64 * 128 * 2
+    assert _series("rt_serve_kv_latent_bytes") >= by_kind["latent"]
+    assert stats["decode_attention"] == "own_latent_pages"
+
+
+@pytest.mark.parametrize("model_id,kinds", [("gpt2-tiny", {"full"}),
+                                            ("mimo-v2-tiny", {"full", "window"})])
+def test_the_other_families_report_no_latent_bytes(model_id, kinds):
+    import jax
+
+    jax.config.update("jax_platforms", "cpu")
+    from ray_tpu.serve.llm import LLMConfig, LLMServer
+
+    srv = LLMServer(LLMConfig(model_id=model_id, max_batch_size=2))
+    try:
+        by_kind = srv.batch_stats()["kv_bytes_by_kind"]
+    finally:
+        srv.unload()
+    assert set(by_kind) == {"full", "window", "latent"} and by_kind["latent"] == 0
+    assert {k for k, held in by_kind.items() if held} == kinds
+
+
+def test_kv_import_and_the_prefill_tier_refuse_the_family_by_name(engine):
+    with pytest.raises(RuntimeError, match="kanana-2-tiny.*takes no KV import"):
+        engine({"prompt_tokens": [1, 2, 3], "max_new_tokens": 4,
+                "kv_import": {"prompt_len": 3, "first_token": 1, "k": None, "v": None}})
+    from ray_tpu.models import deepseek_v3
+    from ray_tpu.serve import kv_transfer
+    from ray_tpu.serve.llm import LLMConfig
+
+    assert deepseek_v3.KV_TRANSFER is False and deepseek_v3.PREFIX_CACHE is True
+    assert not hasattr(deepseek_v3, "write_pages")
+    with pytest.raises(RuntimeError, match="kanana-2-tiny.*KV transfer"):
+        kv_transfer.PrefillEngine(LLMConfig(model_id="kanana-2-tiny"))
+    # and the engine still serves
+    assert len(engine({"prompt_tokens": [5], "max_new_tokens": 3})["tokens"]) == 3
+
+
+def test_a_gpt2_engine_never_imports_the_family():
+    code = (
+        "import sys, jax; jax.config.update('jax_platforms', 'cpu')\n"
+        "from ray_tpu.serve.llm import LLMConfig, LLMServer\n"
+        "srv = LLMServer(LLMConfig(model_id='gpt2-tiny', max_batch_size=2))\n"
+        "assert len(srv({'prompt_tokens': [1, 2, 3], 'max_new_tokens': 4})['tokens']) == 4\n"
+        "loaded = [m for m in sys.modules if 'deepseek' in m or m == 'ray_tpu.ops.moe']\n"
+        "assert not loaded, loaded\n"
+        "srv.unload(); print('clean')\n"
+    )
+    env = {**os.environ, "JAX_PLATFORMS": "cpu", "PYTHONPATH": ROOT}
+    proc = subprocess.run([sys.executable, "-c", code], env=env, cwd=ROOT,
+                          capture_output=True, text=True, timeout=300)
+    assert proc.returncode == 0 and "clean" in proc.stdout, proc.stderr[-2000:]
+
+
+def test_the_model_is_found_by_its_id_and_by_nothing_else():
+    from ray_tpu import models
+
+    cfg, dec = models.resolve("kanana-2-30b-a3b")
+    assert (cfg.n_layer, cfg.n_routed_experts, cfg.latent_width, cfg.stored_width) == (5, 128, 576, 640)
+    assert dec.__name__ == "ray_tpu.models.deepseek_v3"
+    assert dec.STEP_COUNTERS[-1] == "mla_context_tokens" and len(dec.STEP_COUNTERS) == 5
+    with pytest.raises(KeyError, match="kanana-2-tiny"):
+        models.resolve("kanana-2-nope")
+    with open(os.path.join(ROOT, "ray_tpu/serve/llm.py")) as f:
+        engine_source = f.read()
+    assert "deepseek" not in engine_source and "kanana" not in engine_source

@@ -112,3 +112,50 @@ def test_no_token_is_dropped_when_every_token_chooses_one_expert(layer, tokens):
     assert int(stats5[3]) == 5
     np.testing.assert_allclose(np.asarray(y5[:5]), np.asarray(want[:5]), atol=5e-5)
     assert float(np.abs(np.asarray(y5[5:])).max()) == 0.0
+
+
+@pytest.mark.parametrize("holders", [1, 2, 4, 8])
+def test_routed_shares_and_the_shared_expert_once_add_up_at_the_gates_scale(holders):
+    """128 experts, top 6, gates that sum to ``routed_scaling_factor``
+    2.448, beside a shared expert that every token passes (the layer of
+    ``benchmark/reference/deepseek_v3_ref.py``), split over 1, 2, 4 and 8
+    holders: each holder routes over all 128 and computes its own experts'
+    part, the parts add up, and with the shared expert counted ONCE (not
+    once a holder) they are the uncut reference layer. ``held == routed``
+    gives the whole routed sum in one share."""
+    import jax
+    import jax.numpy as jnp
+
+    jax.config.update("jax_platforms", "cpu")
+    from benchmark.reference import deepseek_v3_ref
+    from ray_tpu.ops import moe
+
+    D, F, E, T, K, scale = 32, 16, 128, 40, 6, 2.448
+    ks = jax.random.split(jax.random.PRNGKey(48), 9)
+    weights = {
+        "router": jax.random.normal(ks[0], (D, E)) / D ** 0.5,
+        "bias": 0.02 * jax.random.normal(ks[1], (E,)),
+        "gate": jax.random.normal(ks[2], (E, D, F)) / D ** 0.5,
+        "up": jax.random.normal(ks[3], (E, D, F)) / D ** 0.5,
+        "down": jax.random.normal(ks[4], (E, F, D)) / F ** 0.5,
+    }
+    shared = [jax.random.normal(ks[5], (D, 2 * F)) / D ** 0.5,
+              jax.random.normal(ks[6], (D, 2 * F)) / D ** 0.5,
+              jax.random.normal(ks[7], (2 * F, D)) / (2 * F) ** 0.5]
+    x = jax.random.normal(ks[8], (T, D), jnp.float32)
+    model = {"num_experts_per_tok": K, "norm_topk_prob": True, "n_routed_experts": E}
+    with jax.default_matmul_precision("highest"):
+        once = deepseek_v3_ref.swiglu(x, *shared)
+        want = scale * deepseek_v3_ref.experts(x, weights, model) + once
+        each = E // holders
+        parts = [moe.expert_layer(x, share(weights, f, each), first=f, top_k=K, scale=scale)
+                 for f in range(0, E, each)]
+        got = sum(y for y, _ in parts) + once
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-4)
+    # every pair landed on exactly one holder; the gates of a token sum to the scale
+    assert sum(int(s[0]) for _, s in parts) == T * K
+    _, gates = moe.route(x, weights["router"], weights["bias"], K, scale)
+    np.testing.assert_allclose(np.asarray(gates).sum(1), scale, rtol=1e-6)
+    # the shared expert counted with every share would be off by (holders - 1) of it
+    assert holders == 1 or float(np.abs(np.asarray(once)).max()) > 0.1
+    assert float(np.abs(np.asarray(want - once)).max()) > 0.1

@@ -396,6 +396,60 @@ def test_compiled_mimo_decode_attention_splits_no_cached_heads(one_chip, time_li
     assert not relays, relays
 
 
+def test_compiled_latent_programs_copy_no_pool_and_decode_builds_no_head(one_chip, time_limit):
+    """At ``kanana-agent-sessions``' shapes (128 rows, five pools of 16,385
+    pages of 64 latent rows, 512 page-table columns, a prefill chunk of
+    512; the weights as an engine holds them) none of the three programs,
+    as the v5e's compiler writes them, holds a ``copy`` of a layer's latent
+    pool (6.7 GB in all: one copy of a layer does not fit beside it, and
+    with the row left 576 wide the compiler laid the pools out pages-minor
+    and a decode step held 20; PERF.md, PR 48), the pools enter in the
+    layout ``init_paged_cache`` wrote, and the decode programs hold no K
+    or V a head of a cached span: decode attends in the latent space."""
+    from ray_tpu.models import deepseek_v3 as m
+
+    cfg = m.CONFIGS["kanana-2-30b-a3b"]
+    S, N, Bx, mp = 128, 16385, 64, 512
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: sds(a.shape, a.dtype), tree)
+
+    stored, none = jax.eval_shape(lambda: m.init_paged_cache(cfg, N, Bx, S))
+    whole = stored.layers[0].shape
+    assert whole == (N, Bx, 640) and not jax.tree.leaves(none)
+    pool, none = on_chip(stored), on_chip(none)
+    p = on_chip(jax.eval_shape(lambda: m.load_serving_params(cfg)))
+    rows = (sds((S,), jnp.int32), sds((S,), jnp.int32))
+    tail = (sds((S, mp), jnp.int32), sds((S,), jnp.float32), sds((S,), jnp.bool_),
+            sds((2,), jnp.uint32))
+    i32 = sds((), jnp.int32)
+    programs = {
+        "decode_paged_and_sample": m.decode_paged_and_sample.lower(
+            cfg, p, *rows, pool, none, *tail, i32),
+        "decode_multi_paged": m.decode_multi_paged.lower(
+            cfg, p, *rows, pool, none, *tail, i32, i32),
+        "prefill_paged": m.prefill_paged.lower(
+            cfg, p, sds((1, 512), jnp.int32), i32, i32, pool, none, sds((mp,), jnp.int32)),
+    }
+    dims = ",".join(map(str, whole))
+    H, sizes = cfg.num_attention_heads, f"(?:{cfg.qk_nope_head_dim}|{cfg.qk_head_dim})"
+    a_head = rf"\d+,(?:\d{{2,}},{H}|{H},\d{{2,}}),{sizes}"
+    for name, lowered in programs.items():
+        compiled = lowered.compile()
+        text = compiled.as_text()
+        entry = re.search(r"entry_computation_layout=\{\((.*?)\)->", text).group(1)
+        assert f"bf16[{dims}]{{2,1,0:" in entry, name  # as written, the row minor
+        copies = re.findall(rf"= \w+\[{dims}\]\S* copy\(", text)
+        assert not copies, (name, copies)
+        assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9, name
+        if name.startswith("decode"):
+            heads = re.findall(rf"= \w+\[{a_head}\]\S* [\w\-]+\(", text)
+            assert not heads, (name, heads[:3])
+
+
 @pytest.mark.parametrize(
     "shape", [(32, 1024, 12, 64), (4, 1024, 25, 64)], ids=["two-heads-a-block", "whole-row-of-25"]
 )

@@ -24,12 +24,22 @@ anew in another layout):
   so that the heads split, which the decode programs hold none of since
   PR 47 and prefill, whose keys are few, still does).
 
+- ``deepseek_v3``, a latent pool a layer: copies of a layer's pool (6 GB
+  in all at the benchmark's sizes: one copy does not fit beside it), and K
+  or V a head of a cached span (any result ``[.., positions, heads, size]``
+  or ``[.., heads, positions, size]`` with a page of positions or more:
+  decode attends in the latent space and may build none; prefill expands a
+  block of pages a turn as ``[positions, heads, size]``, one sequence's and
+  so without the leading dimension, which this count does not look for).
+
 The text names the operations the chip's trace will show (PERF.md, PR 30,
 PR 32 and PR 47); it says nothing about time. Run here, on the CPU:
 
     JAX_PLATFORMS=cpu python tools/aot_serving_programs.py [--model gpt2-xl]
         [--max-batch-size 6] [--page-tokens 64] [--prefill 128]
     JAX_PLATFORMS=cpu python tools/aot_serving_programs.py --model mimo-v2.5 \\
+        --max-batch-size 32 --prefill 512
+    JAX_PLATFORMS=cpu python tools/aot_serving_programs.py --model kanana-2-30b-a3b \\
         --max-batch-size 32 --prefill 512
 """
 
@@ -127,8 +137,25 @@ def mimo_v2_family(cfg, dec, stored_k, key):
     return [("load_serving_params", as_held)], watch, None
 
 
+def deepseek_v3_family(cfg, dec, stored, key):
+    """The latent family's tree as an engine holds it and what to count."""
+    pool = tuple(stored.layers[0].shape)
+    positions = rf"\d{{{len(str(stored.page_tokens))},}}"
+    heads, sizes = cfg.num_attention_heads, {cfg.qk_nope_head_dim, cfg.qk_head_dim, cfg.v_head_dim}
+    a_head = {dims for size in sizes
+              for dims in ((r"\d+", positions, heads, size), (r"\d+", heads, positions, size))}
+    watch = [
+        ("latent-pool copies", lambda ops: results_of(ops, pool)),
+        ("K/V a head of a cached span", lambda ops: sum(
+            results_of(ops, dims, r"[\w\-]+") for dims in a_head)),
+    ]
+    as_held = jax.eval_shape(lambda: dec.load_serving_params(cfg))
+    return [("load_serving_params", as_held)], watch, None
+
+
 FAMILIES = {"ray_tpu.models.gpt2_decode": gpt2_family,
-            "ray_tpu.models.mimo_v2": mimo_v2_family}
+            "ray_tpu.models.mimo_v2": mimo_v2_family,
+            "ray_tpu.models.deepseek_v3": deepseek_v3_family}
 
 
 def main() -> None:
