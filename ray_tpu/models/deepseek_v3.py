@@ -52,16 +52,18 @@ from jax import lax
 from ray_tpu.models.gpt2_decode import (  # noqa: F401 — the engine's interface
     params_bytes, sample, update_rows_paged,
 )
-from ray_tpu.ops import moe
+from ray_tpu.ops import moe, page_loops
 
 PREFIX_CACHE = True    # pages of one kind: a sealed page is all of its positions
 KV_TRANSFER = False    # a shipment of latent pages has no wire format yet
 DECODE_ATTENTION = "own_latent_pages"
 MAX_DECODE_CHUNK = 8
 # what a decode program counts beside its tokens: the expert layers' counts
-# summed over layers and steps, and the positions its live rows attended
-# over summed over steps (once a step, not a layer)
-STEP_COUNTERS = (*(f"moe_{name}" for name in moe.STATS), "mla_context_tokens")
+# summed over layers and steps, the positions its live rows attended over
+# and the positions the attention's loops covered for them, both summed
+# over steps (once a step, not a layer)
+STEP_COUNTERS = (*(f"moe_{name}" for name in moe.STATS), "mla_context_tokens",
+                 "attn_loop_tokens")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -282,12 +284,6 @@ def _queries_and_rows(cfg: DeepseekV3Config, attn, h, pos):
     return q[..., :N], q_rope, rows
 
 
-def _pages_a_turn(max_pages: int, wanted: int) -> int:
-    while max_pages % wanted:
-        wanted //= 2
-    return wanted
-
-
 def _softmax_turn(carry, scores, visible, weighted):
     """One block of an online softmax over the last dimension of
     ``scores`` (float32); ``weighted(p)`` is the block's part of the sum."""
@@ -304,19 +300,19 @@ def _start(shape, width):
             jnp.zeros((*shape, width), jnp.float32))
 
 
-def _absorbed_attend(cfg: DeepseekV3Config, attn, q_nope, q_rope, pool, tables, pos):
+def _absorbed_attend(cfg: DeepseekV3Config, attn, q_nope, q_rope, pool, tables, pos,
+                     loops: page_loops.Loops):
     """Decode's attention, in the latent space: one query a row, ``q_nope``
     [S, H, N] and ``q_rope`` [S, H, R] at positions ``pos`` [S], over each
     row's own pages of ``pool`` (``tables`` [S, MaxPages]). ``W_kb`` goes
     into the query, the pages' rows into both products as they lie, and
-    ``W_vb`` takes the weighted latent to the heads. A loop over page-table
-    columns, a few at a time, that stops behind the longest row. Returns
-    [S, H * V]."""
+    ``W_vb`` takes the weighted latent to the heads. ``loops`` (of ``pos``)
+    are the step's loops over page-table columns, a few a turn, each
+    stopping behind the longest of its own rows. Returns [S, H * V]."""
     dt = cfg.dtype
     S, H, _ = q_nope.shape
     B, W = pool.shape[1], pool.shape[2]
-    C = _pages_a_turn(tables.shape[1], 4)
-    span = C * B
+    span, C = loops.span, loops.span // B
     # a product a head (the CPU's compiler has no such product that widens
     # its result, and both results are wanted in ``dt``)
     q_lat = jnp.einsum("shn,hnc->shc", q_nope, attn["wkb"].astype(dt))
@@ -324,20 +320,31 @@ def _absorbed_attend(cfg: DeepseekV3Config, attn, q_nope, q_rope, pool, tables, 
         [q_lat, q_rope, jnp.zeros((S, H, W - cfg.latent_width), dt)], axis=-1)
     scale = cfg.qk_head_dim ** -0.5
 
-    def turn(j, carry):
-        pages = lax.dynamic_slice_in_dim(tables, j * C, C, axis=1)  # [S, C]
-        rows = pool[pages].reshape(S, span, W)
-        scores = scale * jnp.einsum("shw,stw->sht", q_cat, rows,
-                                    preferred_element_type=jnp.float32)
-        visible = (j * span + jnp.arange(span))[None, :] <= pos[:, None]  # [S, T]
-        return _softmax_turn(
-            carry, scores, visible[:, None],
-            lambda p: jnp.einsum("sht,stw->shw", p.astype(dt), rows,
-                                 preferred_element_type=jnp.float32))
+    def make_turn(own):
+        q, table, at = own  # [n, H, W], [n, MaxPages], [n]
+        n = at.shape[0]
 
-    _, den, acc = lax.fori_loop(0, jnp.max(pos) // span + 1, turn, _start((S, H), W))
-    o_lat = (acc[..., :cfg.kv_lora_rank] / den[..., None]).astype(dt)
-    return jnp.einsum("shc,hcv->shv", o_lat, attn["wvb"].astype(dt)).reshape(S, -1)
+        def turn(j, carry):
+            pages = lax.dynamic_slice_in_dim(table, j * C, C, axis=1)  # [n, C]
+            rows = pool[pages].reshape(n, span, W)
+            scores = scale * jnp.einsum("shw,stw->sht", q, rows,
+                                        preferred_element_type=jnp.float32)
+            visible = (j * span + jnp.arange(span))[None, :] <= at[:, None]  # [n, T]
+            return _softmax_turn(
+                carry, scores, visible[:, None],
+                lambda p: jnp.einsum("sht,stw->shw", p.astype(dt), rows,
+                                     preferred_element_type=jnp.float32))
+
+        return turn
+
+    def finish(carry):
+        _, den, acc = carry
+        o_lat = (acc[..., :cfg.kv_lora_rank] / den[..., None]).astype(dt)
+        return jnp.einsum("shc,hcv->shv", o_lat, attn["wvb"].astype(dt)).reshape(
+            den.shape[0], -1)
+
+    return page_loops.run(loops, (q_cat, tables, pos), make_turn,
+                          lambda n: _start((n, H), W), finish)
 
 
 def _expanded_attend(cfg: DeepseekV3Config, attn, q_nope, q_rope, pool, table, pos):
@@ -350,7 +357,7 @@ def _expanded_attend(cfg: DeepseekV3Config, attn, q_nope, q_rope, pool, table, p
     dt = cfg.dtype
     P, H, _ = q_nope.shape
     B = pool.shape[1]
-    C = _pages_a_turn(table.shape[0], 8)
+    C = page_loops.pages_a_turn(table.shape[0], 8)
     span = C * B
     rank = cfg.kv_lora_rank
     scale = cfg.qk_head_dim ** -0.5
@@ -464,6 +471,7 @@ def _decode_paged_impl(cfg: DeepseekV3Config, params, last_tokens, lengths,
     live = lengths > 0
     x = params["embed"].astype(dt)[last_tokens].astype(jnp.float32)  # [S, D]
     page_of = page_tables[jnp.arange(S), pos // B]
+    loops = page_loops.for_decode(pos, page_tables, B)
     pools = list(cache.layers)
     stats = jnp.zeros((len(moe.STATS),), jnp.int32)
     for l, layer in enumerate(params["layers"]):
@@ -471,12 +479,12 @@ def _decode_paged_impl(cfg: DeepseekV3Config, params, last_tokens, lengths,
         q_nope, q_rope, rows = _queries_and_rows(cfg, layer["attn"], h, pos)
         pools[l] = pools[l].at[page_of, pos % B].set(rows)
         att = _absorbed_attend(cfg, layer["attn"], q_nope, q_rope, pools[l],
-                               page_tables, pos)
+                               page_tables, pos, loops)
         x, counted = _rest_of_block(cfg, layer, x, att, live)
         stats = stats + counted
     context = jnp.sum(jnp.where(live, pos + 1, 0), dtype=jnp.int32)
     return (_logits(cfg, params, x), LatentCache(tuple(pools), B), none,
-            jnp.concatenate([stats, context[None]]))
+            jnp.concatenate([stats, context[None], loops.covered[None]]))
 
 
 @partial(jax.jit, static_argnums=(0,), donate_argnums=(4, 5))

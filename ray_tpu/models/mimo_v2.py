@@ -36,8 +36,8 @@ nothing does yet: ``PREFIX_CACHE`` is False and the engine refuses hits by
 name, as it refuses KV transfer (``KV_TRANSFER``).
 
 The programs are the engine's interface, under the names GPT-2's have
-(``models/__init__.py``), and a step's ``ops.moe.STATS`` ride beside its
-tokens.
+(``models/__init__.py``), and what a step counted rides beside its tokens
+(``STEP_COUNTERS``).
 """
 
 from __future__ import annotations
@@ -53,15 +53,19 @@ from jax import lax
 from ray_tpu.models.gpt2_decode import (  # noqa: F401 — the engine's interface
     params_bytes, sample, update_rows_paged,
 )
-from ray_tpu.ops import moe
+from ray_tpu.ops import moe, page_loops
 
 PREFIX_CACHE = False   # a hit would have to restore the window layers' rings
 KV_TRANSFER = False    # no write_pages / read_pages: a shipment is pages of one shape
 DECODE_ATTENTION = "own_pages_and_rings"
 MAX_DECODE_CHUNK = 8
-# what a decode program counts beside its tokens, summed over its expert
-# layers and steps (the engine adds them to ``rt_serve_moe_*_total``)
-STEP_COUNTERS = tuple(f"moe_{name}" for name in moe.STATS)
+# what a decode program counts beside its tokens: the expert layers' counts
+# summed over layers and steps, the positions its live rows attended over in
+# a full layer and the positions the full layers' loops covered for them,
+# both summed over steps (once a step, not a layer); the engine adds each to
+# its ``rt_serve_<name>_total``
+STEP_COUNTERS = (*(f"moe_{name}" for name in moe.STATS), "attn_context_tokens",
+                 "attn_loop_tokens")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -388,38 +392,38 @@ def _start(R, H, Q, Dv):
             jnp.zeros((R, H, Q, Dv), jnp.float32))
 
 
-def _pages_a_turn(max_pages: int, wanted: int) -> int:
-    while max_pages % wanted:
-        wanted //= 2
-    return wanted
-
-
 def _paged_attend(q, k_pool, v_pool, tables, q_pos, kv_heads: int,
-                  wanted: int):
+                  loops: page_loops.Loops):
     """Causal attention of ``q`` [R, Q, H, Dk] at positions ``q_pos`` [R, Q]
     over each row's own pages of a full layer (``tables`` [R, MaxPages]):
-    a loop over page-table columns, a few at a time, that stops behind the
-    last position any query sees, so a step reads the live context and
-    neither the table's width nor the pool. The gathered pages go into the
-    products as they lie. Returns [R, Q, H * Dv]."""
+    ``loops`` (of the rows' last positions) over page-table columns, a few
+    a turn, each stopping behind the last position any query of its own
+    rows sees, so a step reads the live context and neither the table's
+    width nor the pool. The gathered pages go into the products as they
+    lie. Returns [R, Q, H * Dv]."""
     B = k_pool.shape[1]
-    R, Q, H, _ = q.shape
-    C = _pages_a_turn(tables.shape[1], wanted)
-    span = C * B
-    scores, weighted = _products(q, kv_heads)
+    _, Q, H, _ = q.shape
+    span, C = loops.span, loops.span // B
 
-    def turn(j, carry):
-        pages = lax.dynamic_slice_in_dim(tables, j * C, C, axis=1)  # [R, C]
-        kc = k_pool[pages].reshape(R, span, -1)
-        vc = v_pool[pages].reshape(R, span, -1)
-        kv_pos = j * span + jnp.arange(span)
-        visible = kv_pos[None, None, :] <= q_pos[:, :, None]  # [R, Q, T]
-        return _softmax_update(carry, scores(kc), vc, visible[:, None],
-                               weighted)
+    def make_turn(own):
+        q, table, at = own  # [n, Q, H, Dk], [n, MaxPages], [n, Q]
+        n = at.shape[0]
+        scores, weighted = _products(q, kv_heads)
 
-    carry = lax.fori_loop(0, jnp.max(q_pos) // span + 1, turn,
-                          _start(R, H, Q, v_pool.shape[2] // kv_heads))
-    return _finish(carry)
+        def turn(j, carry):
+            pages = lax.dynamic_slice_in_dim(table, j * C, C, axis=1)  # [n, C]
+            kc = k_pool[pages].reshape(n, span, -1)
+            vc = v_pool[pages].reshape(n, span, -1)
+            kv_pos = j * span + jnp.arange(span)
+            visible = kv_pos[None, None, :] <= at[:, :, None]  # [n, Q, T]
+            return _softmax_update(carry, scores(kc), vc, visible[:, None],
+                                   weighted)
+
+        return turn
+
+    return page_loops.run(
+        loops, (q, tables, q_pos), make_turn,
+        lambda n: _start(n, H, Q, v_pool.shape[2] // kv_heads), _finish)
 
 
 def _window_attend(q, keys, values, visible, kv_heads: int, sink):
@@ -507,6 +511,7 @@ def prefill_paged(cfg: MiMoV2Config, params, tokens, start, length, cache_k,
     before = _ring_positions(start, W)
     after = _ring_positions(start + length, W)
     takes = (after >= start) & (length > 0)
+    loops = page_loops.one_loop(pos[-1:], B * page_loops.pages_a_turn(max_pages, 8))
     ks, vs = list(cache_k.layers), list(cache_v.layers)
     for l, layer in enumerate(params["layers"]):
         Hkv = cfg.kv_heads(l)
@@ -531,7 +536,7 @@ def prefill_paged(cfg: MiMoV2Config, params, tokens, start, length, cache_k,
             ks[l] = ks[l].at[page_of, pos % B].set(k)
             vs[l] = vs[l].at[page_of, pos % B].set(v)
             att = _paged_attend(q[None], ks[l], vs[l], page_table[None],
-                                pos[None], Hkv, 8)[0]
+                                pos[None], Hkv, loops)[0]
         x, _ = _rest_of_block(cfg, layer, x, att, live)
     last = lax.dynamic_index_in_dim(x, jnp.maximum(length - 1, 0), 0,
                                     keepdims=True)
@@ -547,7 +552,7 @@ def _decode_paged_impl(cfg: MiMoV2Config, params, last_tokens, lengths,
     row's own pages, window layers over the ring. A row of length 0 is
     nobody's: its full-layer write lands in the scratch page, it writes no
     ring, and the experts do not see it. Returns logits [S, vocab], the
-    caches and the step's expert counts (``ops.moe.STATS``)."""
+    caches and what the step counted (``STEP_COUNTERS``)."""
     dt = cfg.dtype
     S = last_tokens.shape[0]
     B = cache_k.page_tokens
@@ -560,6 +565,7 @@ def _decode_paged_impl(cfg: MiMoV2Config, params, last_tokens, lengths,
     page_of = page_tables[rows, pos // B]
     slot = jnp.where(live, pos % W, W)  # W is no slot: the write is dropped
     in_ring = _ring_positions(pos + 1, W) >= 0  # [S, W]
+    loops = page_loops.for_decode(pos, page_tables, B)
     ks, vs = list(cache_k.layers), list(cache_v.layers)
     stats = jnp.zeros((len(moe.STATS),), jnp.int32)
     for l, layer in enumerate(params["layers"]):
@@ -575,11 +581,13 @@ def _decode_paged_impl(cfg: MiMoV2Config, params, last_tokens, lengths,
             ks[l] = ks[l].at[page_of, pos % B].set(k)
             vs[l] = vs[l].at[page_of, pos % B].set(v)
             att = _paged_attend(q[:, None], ks[l], vs[l], page_tables,
-                                pos[:, None], Hkv, 4)[:, 0]
+                                pos[:, None], Hkv, loops)[:, 0]
         x, counted = _rest_of_block(cfg, layer, x, att, live)
         stats = stats + counted
+    context = jnp.sum(jnp.where(live, pos + 1, 0), dtype=jnp.int32)
     return (_logits(cfg, params, x), LayerCache(tuple(ks), B),
-            LayerCache(tuple(vs), B), stats)
+            LayerCache(tuple(vs), B),
+            jnp.concatenate([stats, context[None], loops.covered[None]]))
 
 
 @partial(jax.jit, static_argnums=(0,), donate_argnums=(4, 5))
@@ -587,15 +595,15 @@ def decode_paged_and_sample(cfg: MiMoV2Config, params, last_tokens, lengths,
                             cache_k, cache_v, page_tables, temps,
                             greedy_mask, rng_base, step):
     """Decode, sample, fold the RNG and bump the cursor in one dispatch.
-    Returns (next tokens, next lengths, k, v, expert counts)."""
-    logits, cache_k, cache_v, stats = _decode_paged_impl(
+    Returns (next tokens, next lengths, k, v, the step's counts)."""
+    logits, cache_k, cache_v, counted = _decode_paged_impl(
         cfg, params, last_tokens, lengths, cache_k, cache_v, page_tables
     )
     rng = jax.random.fold_in(rng_base, step)
     nxt = sample(logits, temps, greedy_mask, rng)
     # a row that had no length has none after the step either: it stays
     # nobody's until the engine writes a sequence into it
-    return nxt, jnp.where(lengths > 0, lengths + 1, 0), cache_k, cache_v, stats
+    return nxt, jnp.where(lengths > 0, lengths + 1, 0), cache_k, cache_v, counted
 
 
 @partial(jax.jit, static_argnums=(0,), donate_argnums=(4, 5))
@@ -605,23 +613,23 @@ def decode_multi_paged(cfg: MiMoV2Config, params, last_tokens, lengths,
     """``n_steps`` (at most ``MAX_DECODE_CHUNK``) tokens a row in one
     dispatch; one program runs every ``n_steps``. Returns (tokens
     [MAX_DECODE_CHUNK, S] with the first ``n_steps`` rows written, last
-    tokens, lengths, k, v, expert counts)."""
+    tokens, lengths, k, v, the steps' counts)."""
     S = last_tokens.shape[0]
 
     def body(i, carry):
-        last, lens, ck, cv, toks, stats = carry
-        logits, ck, cv, counted = _decode_paged_impl(
+        last, lens, ck, cv, toks, counted = carry
+        logits, ck, cv, step_counted = _decode_paged_impl(
             cfg, params, last, lens, ck, cv, page_tables
         )
         rng = jax.random.fold_in(rng_base, step0 + i)
         nxt = sample(logits, temps, greedy_mask, rng)
         toks = lax.dynamic_update_index_in_dim(toks, nxt, i, axis=0)
-        return nxt, jnp.where(lens > 0, lens + 1, 0), ck, cv, toks, stats + counted
+        return nxt, jnp.where(lens > 0, lens + 1, 0), ck, cv, toks, counted + step_counted
 
-    last, lens, cache_k, cache_v, toks, stats = lax.fori_loop(
+    last, lens, cache_k, cache_v, toks, counted = lax.fori_loop(
         0, n_steps, body,
         (last_tokens, lengths, cache_k, cache_v,
          jnp.zeros((MAX_DECODE_CHUNK, S), jnp.int32),
-         jnp.zeros((len(moe.STATS),), jnp.int32)),
+         jnp.zeros((len(STEP_COUNTERS),), jnp.int32)),
     )
-    return toks, last, lens, cache_k, cache_v, stats
+    return toks, last, lens, cache_k, cache_v, counted

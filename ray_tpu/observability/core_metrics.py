@@ -321,6 +321,22 @@ def _build() -> dict:
             "layer); counted on the device beside the sampled tokens",
             tag_keys=("deployment",),
         ),
+        "serve_attn_context_tokens": Counter(
+            "rt_serve_attn_context_tokens_total",
+            "positions the live rows of decode steps attended over in the "
+            "paged full-attention layers of a model that keeps K and V a "
+            "head, summed over rows and steps (once a step, not a layer); "
+            "counted on the device beside the sampled tokens",
+            tag_keys=("deployment",),
+        ),
+        "serve_attn_loop_tokens": Counter(
+            "rt_serve_attn_loop_tokens_total",
+            "positions the decode steps' loops over page-table columns "
+            "covered: rows of a group x its turns x positions a turn, "
+            "summed over groups and steps (once a step, not a layer); what "
+            "the live rows attended over is the useful part of it",
+            tag_keys=("deployment",),
+        ),
         "serve_kv_block_copies": Counter(
             "rt_serve_kv_block_copies_total",
             "KV block copies performed at admission (prefix-pool copy "

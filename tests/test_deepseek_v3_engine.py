@@ -82,12 +82,19 @@ def test_the_steps_counts_come_back_with_the_tokens(engine):
         pytest.skip("observability is off")
     steps = _series("rt_serve_moe_expert_steps_total")
     context = _series("rt_serve_mla_context_tokens_total")
+    covered = _series("rt_serve_attn_loop_tokens_total")
     engine({"prompt_tokens": [1, 2, 3, 4, 5], "max_new_tokens": 9})
     # 8 decode steps x 2 expert layers x 16 experts, every one held
     assert _series("rt_serve_moe_expert_steps_total") - steps == 8 * 2 * 16
     # the row attends over 6, 7, .. 13 positions: the prompt, what it has
     # generated, the new position
     assert _series("rt_serve_mla_context_tokens_total") - context == sum(range(6, 14))
+    # what the one loop covered for them: 8 steps x the engine's 8 rows (one
+    # live, seven nobody's) x one turn of pages of 64
+    from ray_tpu.ops import page_loops
+
+    turn = 64 * page_loops.DECODE_PAGES
+    assert _series("rt_serve_attn_loop_tokens_total") - covered == 8 * 8 * turn
     assert _series("rt_serve_moe_assignments_total") > 0
     assert 0 < _series("rt_serve_moe_experts_hit_total") <= _series("rt_serve_moe_expert_steps_total")
 
@@ -159,7 +166,8 @@ def test_the_model_is_found_by_its_id_and_by_nothing_else():
     cfg, dec = models.resolve("kanana-2-30b-a3b")
     assert (cfg.n_layer, cfg.n_routed_experts, cfg.latent_width, cfg.stored_width) == (5, 128, 576, 640)
     assert dec.__name__ == "ray_tpu.models.deepseek_v3"
-    assert dec.STEP_COUNTERS[-1] == "mla_context_tokens" and len(dec.STEP_COUNTERS) == 5
+    assert dec.STEP_COUNTERS[-2:] == ("mla_context_tokens", "attn_loop_tokens")
+    assert len(dec.STEP_COUNTERS) == 6
     with pytest.raises(KeyError, match="kanana-2-tiny"):
         models.resolve("kanana-2-nope")
     with open(os.path.join(ROOT, "ray_tpu/serve/llm.py")) as f:

@@ -160,7 +160,8 @@ def test_an_unknown_model_is_refused_with_the_known_ones():
         models.resolve("llama")
     cfg, dec = models.resolve("mimo-v2.5")
     assert cfg.n_layer == 7 and cfg.n_routed_experts == 16 and cfg.router_experts == 256
-    assert dec.PREFIX_CACHE is False and len(dec.STEP_COUNTERS) == 4
+    assert dec.PREFIX_CACHE is False and len(dec.STEP_COUNTERS) == 6
+    assert dec.STEP_COUNTERS[-2:] == ("attn_context_tokens", "attn_loop_tokens")
 
 
 def test_a_stream_that_fell_behind_takes_what_was_produced_in_one_go(engine):

@@ -361,6 +361,7 @@ def test_attention_over_merged_heads_is_a_softmax_a_head(kv_heads, sizes, Q, dty
 
     jax.config.update("jax_platforms", "cpu")
     from ray_tpu.models import mimo_v2 as m
+    from ray_tpu.ops import page_loops
 
     dt = jnp.dtype(dtype)
     # bfloat16: the probabilities are rounded to 2**-8 before the second
@@ -387,7 +388,8 @@ def test_attention_over_merged_heads_is_a_softmax_a_head(kv_heads, sizes, Q, dty
             tables[r, c] = free.pop()
     k_pool, v_pool = draw(N, B, kv_heads * Dk), draw(N, B, kv_heads * Dv)
     got = m._paged_attend(q, k_pool, v_pool, jnp.asarray(tables),
-                          jnp.asarray(q_pos, jnp.int32), kv_heads, 2)
+                          jnp.asarray(q_pos, jnp.int32), kv_heads,
+                          page_loops.by_length(jnp.asarray(last), 2 * B))
     T = max_pages * B
     visible = np.arange(T)[None, None, :] <= q_pos[:, :, None]
     want = plain_attention(q, k_pool[tables].reshape(R, T, -1),
@@ -408,3 +410,98 @@ def test_attention_over_merged_heads_is_a_softmax_a_head(kv_heads, sizes, Q, dty
     want = plain_attention(q, ring_k, ring_v, visible, kv_heads, sink)
     assert got.shape == (R, Q, H * Dv)
     assert float(jnp.abs(got - want).max()) < tol
+
+
+def rows_of_every_length(rng, rows, B, max_pages):
+    """``rows`` rows' last positions, shuffled: 0 (nobody's), 1, B - 1, B,
+    B + 1, several turns of four pages, the table's last position and
+    random ones; their page tables, each row with pages of its own in no
+    order (a row of no length points at the scratch page); and the pages a
+    pool needs for them."""
+    T = max_pages * B
+    special = [0, 1, B - 1, B, B + 1, 3 * 4 * B + 5, 7 * 4 * B, T - 1]
+    pos = np.asarray((special + list(rng.integers(1, T, max(0, rows - len(special)))))[:rows])
+    pos = pos[rng.permutation(rows)]
+    need = np.where(pos > 0, pos // B + 1, 0)
+    free = rng.permutation(np.arange(1, 1 + need.sum()))
+    tables = np.zeros((rows, max_pages), np.int32)
+    for r, at in enumerate(np.cumsum(need) - need):
+        tables[r, :need[r]] = free[at:at + need[r]]
+    return pos, tables, 1 + need.sum()
+
+
+@pytest.mark.parametrize("kv_heads", [1, 4])
+@pytest.mark.parametrize("rows", [3, 16, 32, 40])
+def test_rows_taken_by_length_attend_as_one_loop_and_as_a_softmax_a_head(rows, kv_heads):
+    """A full layer's decode attention with the rows taken by length, a
+    loop a group, against the one loop over all rows (<= 1e-6 in float32: a
+    turn behind a row's length adds exact zeros) and against a plain
+    softmax a head, on rows of every length in shuffled order; the rows
+    permuted give the same rows permuted. Three rows are fewer than two
+    groups and take the one loop, as prefill's one row does."""
+    import jax
+    import jax.numpy as jnp
+
+    jax.config.update("jax_platforms", "cpu")
+    from ray_tpu.models import mimo_v2 as m
+    from ray_tpu.ops import page_loops
+
+    rng = np.random.default_rng(0)
+    H, Dk, Dv, B, max_pages = 8, 24, 16, 8, 64
+    pos, tables, N = rows_of_every_length(rng, rows, B, max_pages)
+    draw = lambda *shape: jnp.asarray(rng.normal(0, 1, shape), jnp.float32)
+    q, k_pool, v_pool = draw(rows, 1, H, Dk), draw(N, B, kv_heads * Dk), draw(N, B, kv_heads * Dv)
+    pos, tables = jnp.asarray(pos, jnp.int32), jnp.asarray(tables)
+    T, span = max_pages * B, 4 * B
+    loops = page_loops.by_length(pos, span)
+    groups = {3: 1, 16: 2, 32: 4, 40: 4}[rows]
+    assert loops.turns.shape == (groups,) and (loops.order is None) == (groups == 1)
+    got = m._paged_attend(q, k_pool, v_pool, tables, pos[:, None], kv_heads, loops)
+    one = m._paged_attend(q, k_pool, v_pool, tables, pos[:, None], kv_heads,
+                          page_loops.one_loop(pos, span))
+    assert float(jnp.abs(got - one).max()) <= 1e-6
+    visible = np.arange(T)[None, None, :] <= np.asarray(pos)[:, None, None]
+    want = plain_attention(q, k_pool[tables].reshape(rows, T, -1),
+                           v_pool[tables].reshape(rows, T, -1), visible, kv_heads)
+    assert float(jnp.abs(want).max()) > 0.5
+    assert float(jnp.abs(got - want).max()) < 1e-5
+    perm = np.random.default_rng(rows).permutation(rows)
+    moved = m._paged_attend(q[perm], k_pool, v_pool, tables[perm], pos[perm][:, None],
+                            kv_heads, page_loops.by_length(pos[perm], span))
+    assert float(jnp.abs(moved - got[perm]).max()) <= 1e-6
+
+
+def test_the_step_counts_what_its_full_layers_loops_covered(tiny):
+    """``attn_loop_tokens`` is rows of a group x its turns x the positions a
+    turn, summed over the groups, written out by hand for 32 rows (four
+    groups of eight, by length), and ``attn_context_tokens`` the live rows'
+    positions, the new one among them; once a step, not a layer."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import mimo_v2 as dec
+    from ray_tpu.ops import page_loops
+
+    cfg, params, _ = tiny
+    B, S = 4, 32
+    turn = B * page_loops.DECODE_PAGES
+    # eight rows nobody holds, then three groups whose longest rows stand
+    # one short of a turn, at a turn and at 250: one turn, two, 250 // turn + 1
+    lens = ([0] * 8 + [1, 2, 3, 4, 5, 6, turn - 2, turn - 1] + [turn] * 8
+            + [turn + 1, 100, 120, 150, 180, 200, 249, 250])
+    long = 250 // turn + 1
+    lens = np.asarray(lens)[np.random.default_rng(2).permutation(S)]
+    tables = np.zeros((S, cfg.n_positions // B), np.int32)
+    at = 1
+    for r, n in enumerate(lens):
+        need = n // B + 1 if n else 0
+        tables[r, :need] = np.arange(at, at + need)
+        at += need
+    out = jax.jit(dec._decode_paged_impl, static_argnums=(0,))(
+        cfg, params, jnp.zeros((S,), jnp.int32), jnp.asarray(lens, jnp.int32),
+        *dec.init_paged_cache(cfg, at, B, S), jnp.asarray(tables))
+    by_name = dict(zip(dec.STEP_COUNTERS, map(int, out[3])))
+    assert by_name["attn_loop_tokens"] == 8 * turn * (1 + 1 + 2 + long)
+    assert by_name["attn_context_tokens"] == int(sum(n + 1 for n in lens if n))
+    assert by_name["attn_loop_tokens"] < 32 * turn * long  # what one loop would have covered
+

@@ -19,6 +19,8 @@ functions' round trip; and, compiled for a described v5e at gpt2-xl's
 serving shapes, that no serving program copies a whole pool, and at
 MiMo-V2.5's that decode's attention relays no gathered span and no ring."""
 
+import json
+import os
 import re
 import signal
 
@@ -28,7 +30,9 @@ import numpy as np
 import pytest
 
 from ray_tpu.models import gpt2, gpt2_decode as dec
+from tools import aot_serving_programs as aot
 
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CFG = gpt2.CONFIGS["gpt2-tiny"]  # n_positions 128: 16 pages of 8
 B = 8
 MAX_PAGES = CFG.n_positions // B
@@ -362,9 +366,10 @@ def test_compiled_mimo_decode_attention_splits_no_cached_heads(one_chip, time_li
     times on the way (``reshape bf16[128,256,4,192]`` alone 13.8% of the
     step on the chip; PERF.md, PR 47)."""
     from ray_tpu.models import mimo_v2 as m
+    from ray_tpu.ops import page_loops
 
     cfg = m.CONFIGS["mimo-v2.5"]
-    S, N, Bx, mp, C = 128, 2049, 64, 64, 4
+    S, N, Bx, mp, C = 128, 2049, 64, 64, page_loops.DECODE_PAGES
     H, Dv, W = cfg.num_attention_heads, cfg.v_head_dim, cfg.sliding_window
     l = cfg.hybrid_layer_pattern.index(kind == "window")
     Hkv = cfg.kv_heads(l)
@@ -378,22 +383,64 @@ def test_compiled_mimo_decode_attention_splits_no_cached_heads(one_chip, time_li
     if kind == "full":
         assert (k.shape, v.shape) == ((N, Bx, 768), (N, Bx, 512))
         span = C * Bx
-        lowered = jax.jit(m._paged_attend, static_argnums=(5, 6)).lower(
-            q, k, v, sds((S, mp), jnp.int32), sds((S, 1), jnp.int32), Hkv, C)
+        rows = S // page_loops.GROUPS  # the rows are taken by length, a loop a group
+
+        def attend(q, k, v, tables, q_pos):
+            return m._paged_attend(q, k, v, tables, q_pos, Hkv,
+                                   page_loops.by_length(q_pos[:, 0], span))
+
+        lowered = jax.jit(attend).lower(
+            q, k, v, sds((S, mp), jnp.int32), sds((S, 1), jnp.int32))
     else:
         assert (k.shape, v.shape) == ((S, W, 1536), (S, W, 1024))
-        span = W
+        span, rows = W, S
 
         lowered = jax.jit(m._window_attend, static_argnums=4).lower(
             q, k, v, sds((S, 1, W), jnp.bool_), Hkv, sds((H,), jnp.float32))
     text = lowered.compile().as_text()
-    merged = rf"{S},{span},(?:{k.shape[2]}|{v.shape[2]})"
+    merged = rf"{rows},{span},(?:{k.shape[2]}|{v.shape[2]})"
     assert re.search(rf"= bf16\[{merged}\]", text)  # K and V are there, merged
-    split = rf"{S},{span},{Hkv},(?:{cfg.head_dim}|{Dv})"
+    split = rf"\d+,{span},{Hkv},(?:{cfg.head_dim}|{Dv})"
     relays = re.findall(
         rf"= \w+\[(?:{split})\]\S* (?:copy|reshape|transpose|fusion)\(", text)
     relays += re.findall(rf"= \w+\[{S},{W},\d+\]\S* copy\(", text)
     assert not relays, relays
+    if kind == "full":
+        # a loop a group, its carry opening with the group's running maximum
+        # a head, and no span gathered for more rows than a group's
+        loops = aot.loops_of(text)
+        assert loops == {f"(s32[],f32[{rows},{H},1],..)": page_loops.GROUPS}, loops
+        wider = [n for n in map(int, re.findall(
+            rf"= bf16\[(\d+),(?:{span},|{Bx},)(?:{k.shape[2]}|{v.shape[2]})\]", text))
+            if n not in (rows, rows * C, N)]
+        assert not wider, wider
+
+
+HLO_LOOPS = {
+    "a group's page loop": (
+        "  %while.27 = (s32[]{:T(128)}, f32[32,32]{1,0:T(8,128)S(1)}, f32[32,32]{1,0:T(8,128)}, "
+        "f32[32,32,640]{2,1,0:T(8,128)}, /*index=5*/s32[32,512]{1,0:T(8,128)}) "
+        "while(%tuple.474), condition=%c, body=%b",
+        {"(s32[],f32[32,32],..)": 1}),
+    "an expert layer's loop": (
+        "  %while.32 = (s32[]{:T(128)}, f32[128,2048]{1,0:T(8,128)}, s32[]{:T(128)}) "
+        "while(%tuple.1), condition=%c, body=%b",
+        {"(s32[],f32[128,2048],..)": 1}),
+    "a loop whose carry opens otherwise, and no loop": (
+        "  %while.1 = (bf16[4]{0}, s32[]) while(%t), condition=%c, body=%b\n"
+        "  %fusion.3 = (s32[], f32[32,32]{1,0}) fusion(%while.27), kind=kLoop",
+        {}),
+}
+
+
+@pytest.mark.parametrize("case", sorted(HLO_LOOPS))
+def test_the_tool_names_a_loop_as_the_trace_does(case):
+    """``tools/aot_serving_programs.loops_of`` reads a compiled program's
+    ``while`` operations by how their carry opens, the name the chip's trace
+    gives them and ``mla_roofline`` matches (``benchmark/metrics/
+    mla_roofline.json``: ``^while \\(s32\\[\\],f32\\[\\d+,32\\],``)."""
+    text, want = HLO_LOOPS[case]
+    assert aot.loops_of(text + "\n" + text) == {k: 2 * v for k, v in want.items()}
 
 
 def test_compiled_latent_programs_copy_no_pool_and_decode_builds_no_head(one_chip, time_limit):
@@ -407,6 +454,7 @@ def test_compiled_latent_programs_copy_no_pool_and_decode_builds_no_head(one_chi
     layout ``init_paged_cache`` wrote, and the decode programs hold no K
     or V a head of a cached span: decode attends in the latent space."""
     from ray_tpu.models import deepseek_v3 as m
+    from ray_tpu.ops import page_loops
 
     cfg = m.CONFIGS["kanana-2-30b-a3b"]
     S, N, Bx, mp = 128, 16385, 64, 512
@@ -448,6 +496,17 @@ def test_compiled_latent_programs_copy_no_pool_and_decode_builds_no_head(one_chi
         if name.startswith("decode"):
             heads = re.findall(rf"= \w+\[{a_head}\]\S* [\w\-]+\(", text)
             assert not heads, (name, heads[:3])
+            # a loop a group a layer, its carry opening with the group's
+            # running maximum a head (what ``mla_roofline`` knows it by), and
+            # no turn's pages gathered for more rows than a group's
+            G, C = page_loops.GROUPS, page_loops.DECODE_PAGES
+            loops = aot.loops_of(text)
+            assert loops.get(f"(s32[],f32[{S // G},{H}],..)") == G * cfg.n_layer, (name, loops)
+            with open(os.path.join(ROOT, "benchmark/metrics/mla_roofline.json")) as f:
+                named = json.load(f)["args"]["ops"]
+            assert re.search(named, f"while (s32[],f32[{S // G},{H}],..) 1in"), named
+            gathered = set(map(int, re.findall(rf"= bf16\[(\d+),{Bx},{whole[2]}\]", text)))
+            assert gathered - {N} == {S // G * C}, (name, gathered)
 
 
 @pytest.mark.parametrize(

@@ -5,7 +5,9 @@ Compiles ``decode_paged_and_sample``, ``decode_multi_paged`` and
 decode module) for a described v5e at the engine's own sizes, and prints
 for each: operations with ``remat`` in their name (and how often the text
 says the word), the relays its family watches for, ``memory_analysis()``'s
-arguments and temporaries, and the layout the first pool enters in.
+arguments and temporaries, the layout the first pool enters in, and the
+loops it holds by how their carry opens (the paged attention's loops over
+page-table columns, a loop a group of rows a layer: ``ops/page_loops.py``).
 
 What a family watches for (a relay is an operation that writes an array
 anew in another layout):
@@ -77,8 +79,24 @@ def entry_layouts(text: str, shape) -> list:
     return sorted(set(found))
 
 
+def loops_of(text: str) -> dict:
+    """The ``while`` operations of a compiled program by how their carry
+    opens, as the trace names them (``(s32[],f32[32,32],..)``: the counter
+    and the first array carried), with how many of each the text holds. A
+    loop over a row's page-table columns carries its running maximum
+    first, ``f32[rows of a group, heads]``."""
+    found = {}
+    for ln in text.splitlines():
+        carry = re.search(r"= \(s32\[\][^,]*, (\w+\[[\d,]*\]).*\) while\(", ln)
+        if carry:
+            name = f"(s32[],{carry.group(1)},..)"
+            found[name] = found.get(name, 0) + 1
+    return found
+
+
 def report(name: str, compiled, watch, pool) -> None:
-    """One line a program: ``watch`` is (label, count of ``ops``) pairs."""
+    """A program's counts (``watch`` is (label, count of ``ops``) pairs) and
+    the loops it holds."""
     text = compiled.as_text()
     ops = [ln for ln in text.splitlines() if re.match(r"\s*(ROOT )?%\S+ = ", ln)]
     remat = sum("remat" in ln.split(" = ")[0] for ln in ops)
@@ -90,6 +108,8 @@ def report(name: str, compiled, watch, pool) -> None:
         f"temporaries {mem.temp_size_in_bytes / 1e9:5.2f} GB  "
         f"pools enter as {' '.join(entry_layouts(text, pool)) or '-'}"
     )
+    print(f"{'':38s} loops  " + ("  ".join(
+        f"{n} x {carry}" for carry, n in sorted(loops_of(text).items())) or "none"))
 
 
 def gpt2_family(cfg, dec, stored_k, key):
