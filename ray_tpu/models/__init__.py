@@ -14,6 +14,16 @@ names whichever model implements them (``models/gpt2_decode.py``,
     update_rows_paged(...)
     write_pages(...), read_pages(...)           only where KV_TRANSFER
     PREFIX_CACHE, KV_TRANSFER                   what the cache can do
+    PREFILL_ROWS                                the rows a prefill call takes:
+                                                (1,), or the row counts the
+                                                engine compiles, and then
+    PREFILL_ROW_WIDTHS                          the widths of a call ([R, P]
+                                                tokens, start, length, row
+                                                [R], page tables [R,
+                                                MaxPages]; logits [R, vocab]
+                                                back); where PREFIX_CACHE, a
+                                                sequence may take several
+                                                rows of one call
     DECODE_ATTENTION                            what decode attends over
     STEP_COUNTERS                               names of what a decode
                                                 program counts beside its

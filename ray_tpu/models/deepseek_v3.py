@@ -58,6 +58,15 @@ PREFIX_CACHE = True    # pages of one kind: a sealed page is all of its position
 KV_TRANSFER = False    # a shipment of latent pages has no wire format yet
 DECODE_ATTENTION = "own_latent_pages"
 MAX_DECODE_CHUNK = 8
+# the rows a prefill call takes: the row counts the engine compiles (a call
+# of fewer rows is padded with rows of length 0) and the widths of a row.
+# ``PREFIX_CACHE`` says besides that a sequence may take several rows of one
+# call. Chosen by a sweep on the chip at the served shapes (PERF.md §6, PR
+# 50): a tail of 600 tokens is 36.5 ms as two rows of 512 and 37.8 ms as
+# three of 256 beside a fourth of no length (47.6 ms as two calls), so a
+# call of two rows would not pay for its three programs' set-up
+PREFILL_ROWS = (1, 4)
+PREFILL_ROW_WIDTHS = (128, 256, 512)
 # what a decode program counts beside its tokens: the expert layers' counts
 # summed over layers and steps, the positions its live rows attended over
 # and the positions the attention's loops covered for them, both summed
@@ -347,13 +356,17 @@ def _absorbed_attend(cfg: DeepseekV3Config, attn, q_nope, q_rope, pool, tables, 
                           lambda n: _start((n, H), W), finish)
 
 
-def _expanded_attend(cfg: DeepseekV3Config, attn, q_nope, q_rope, pool, table, pos):
-    """Prefill's attention, in the expanded space: a chunk's queries,
+def _expanded_attend(cfg: DeepseekV3Config, attn, q_nope, q_rope, pool, table, pos,
+                     last=None):
+    """Prefill's attention, in the expanded space: one row's queries,
     ``q_nope`` [P, H, N] and ``q_rope`` [P, H, R] at positions ``pos`` [P],
     over one sequence's pages of ``pool`` (``table`` [MaxPages]), the prefix
-    behind the chunk and the chunk itself, which is already written. A
-    block of pages a turn is up-projected to a head's K and V and attended;
-    the loop stops behind the chunk's last position. Returns [P, H * V]."""
+    behind the chunk and the chunk itself, which is already written (by
+    this row and by the call's other rows). A block of pages a turn is
+    up-projected to a head's K and V and attended; the loop stops behind
+    ``last``, the row's last real position (the last of ``pos`` where none
+    is given), and makes no turn where that is negative (a row of length
+    0). Returns [P, H * V]."""
     dt = cfg.dtype
     P, H, _ = q_nope.shape
     B = pool.shape[1]
@@ -379,9 +392,11 @@ def _expanded_attend(cfg: DeepseekV3Config, attn, q_nope, q_rope, pool, table, p
             lambda p: jnp.einsum("hpt,thv->hpv", p.astype(dt), v,
                                  preferred_element_type=jnp.float32))
 
-    _, den, acc = lax.fori_loop(0, jnp.max(pos) // span + 1, turn,
+    last = jnp.max(pos) if last is None else last
+    _, den, acc = lax.fori_loop(0, jnp.maximum(last // span + 1, 0), turn,
                                 _start((H, P), cfg.v_head_dim))
-    return (acc / den[..., None]).transpose(1, 0, 2).reshape(P, -1)
+    # a query that saw nothing (a row of length 0) gives zeros, not 0 / 0
+    return (acc / jnp.maximum(den, 1e-30)[..., None]).transpose(1, 0, 2).reshape(P, -1)
 
 
 def _swiglu(dt, mlp, h):
@@ -424,35 +439,53 @@ def _logits(cfg: DeepseekV3Config, params, x):
 @partial(jax.jit, static_argnums=(0,), donate_argnums=(5, 6))
 def prefill_paged(cfg: DeepseekV3Config, params, tokens, start, length, cache,
                   none, page_table, row=0):
-    """Prefill one chunk of a prompt: ``tokens`` [1, P] (right-padded,
-    ``length`` real) are positions start .. start + P - 1 of the sequence
-    whose page table is ``page_table`` [MaxPages]; what lies before
-    ``start`` is already cached (this sequence's earlier chunk, or a
-    prefix another sequence sealed). Every layer writes the chunk's latent
-    rows through the page table and attends, expanded, over the
-    sequence's own pages. ``row`` takes no part. Returns the last real
-    position's logits [vocab] and the caches.
+    """Prefill the rows of one call: ``tokens`` [R, P] (right-padded,
+    ``length`` [R] real) are positions start .. start + P - 1 (``start``
+    [R]) of the sequences whose page tables are ``page_table`` [R,
+    MaxPages]; what lies before a row's ``start`` is already cached (an
+    earlier chunk, a prefix another sequence sealed) or is written by
+    another row of this call: several rows may be consecutive chunks of one
+    sequence, since every layer writes all the rows' latent rows through
+    their page tables first and then attends, expanded, a row at a time over
+    the row's own pages. The rest of the block, the experts among it, runs
+    once on the [R * P, D] tokens of all rows, so a call reads its weights
+    once. A row of length 0 is nobody's: it writes to the scratch page, its
+    attention makes no turn and the experts do not see it. ``row`` takes no
+    part. Returns the last real position's logits of every row [R, vocab]
+    and the caches.
 
-    The caller guarantees start + P <= MaxPages * B; padded positions land
-    in pages the row has reserved and not yet reached, or in the scratch
-    page."""
+    A call of one row may give ``start``, ``length`` and ``row`` as scalars
+    and ``page_table`` as [MaxPages], and gets its logits as [vocab].
+
+    Padded positions are written to the scratch page."""
+    one = page_table.ndim == 1
+    if one:
+        start, length, page_table = (jnp.asarray(a)[None] for a in (start, length, page_table))
     dt = cfg.dtype
-    P = tokens.shape[1]
+    R, P = tokens.shape
     B = cache.page_tokens
-    pos = start + jnp.arange(P)
-    live = jnp.arange(P) < length
-    x = params["embed"].astype(dt)[tokens[0]].astype(jnp.float32)  # [P, D]
-    page_of = page_table[jnp.clip(pos // B, 0, page_table.shape[0] - 1)]
+    pos = start[:, None] + jnp.arange(P)                              # [R, P]
+    live = jnp.arange(P) < length[:, None]
+    last = jnp.where(length > 0, start + length - 1, -1)  # a row's last real position
+    page_of = jnp.take_along_axis(
+        page_table, jnp.clip(pos // B, 0, page_table.shape[1] - 1), axis=1)
+    page_of = jnp.where(live, page_of, 0).reshape(-1)
+    pos, live = pos.reshape(-1), live.reshape(-1)
+    x = params["embed"].astype(dt)[tokens.reshape(-1)].astype(jnp.float32)  # [R * P, D]
     pools = list(cache.layers)
     for l, layer in enumerate(params["layers"]):
         h = _rmsnorm(x, layer["norm1"], cfg.rms_norm_eps).astype(dt)
         q_nope, q_rope, rows = _queries_and_rows(cfg, layer["attn"], h, pos)
         pools[l] = pools[l].at[page_of, pos % B].set(rows)
-        att = _expanded_attend(cfg, layer["attn"], q_nope, q_rope, pools[l],
-                               page_table, pos)
+        att = jnp.concatenate([
+            _expanded_attend(cfg, layer["attn"], q_nope[r * P:(r + 1) * P],
+                             q_rope[r * P:(r + 1) * P], pools[l], page_table[r],
+                             pos[r * P:(r + 1) * P], last[r])
+            for r in range(R)])
         x, _ = _rest_of_block(cfg, layer, x, att, live)
-    last = lax.dynamic_index_in_dim(x, jnp.maximum(length - 1, 0), 0, keepdims=True)
-    return _logits(cfg, params, last)[0], LatentCache(tuple(pools), B), none
+    ends = x.reshape(R, P, -1)[jnp.arange(R), jnp.maximum(length - 1, 0)]
+    logits = _logits(cfg, params, ends)
+    return (logits[0] if one else logits), LatentCache(tuple(pools), B), none
 
 
 def _decode_paged_impl(cfg: DeepseekV3Config, params, last_tokens, lengths,

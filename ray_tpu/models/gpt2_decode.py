@@ -53,6 +53,10 @@ from ray_tpu.models.gpt2 import GPT2Config, _layernorm
 PREFIX_CACHE = True
 KV_TRANSFER = True
 STEP_COUNTERS = ()
+# a prefill call takes one sequence's chunk: the pool layer's select and
+# write-back are built around one row (ROADMAP S11), and the engine keeps
+# this module on its path of one call a sequence and chunk
+PREFILL_ROWS = (1,)
 DECODE_ATTENTION = "pool"  # the pool as it lies, under an ownership mask
 
 

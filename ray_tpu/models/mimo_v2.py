@@ -59,6 +59,19 @@ PREFIX_CACHE = False   # a hit would have to restore the window layers' rings
 KV_TRANSFER = False    # no write_pages / read_pages: a shipment is pages of one shape
 DECODE_ATTENTION = "own_pages_and_rings"
 MAX_DECODE_CHUNK = 8
+# the rows a prefill call takes: the row counts the engine compiles (a call
+# of fewer rows is padded with rows of length 0) and the widths of a row. A
+# window layer's ring belongs to a decode row, so a sequence takes one row
+# of a call (``PREFIX_CACHE`` is False). Chosen by a sweep on the chip at
+# the served shapes (PERF.md §6, PR 50): the dense products are most of a
+# call here, so a row of no length costs more than the weights read once
+# save (two prompts of 300: 54.5 ms as two calls, 42.1 ms as two rows, 66.8
+# ms as two of four rows); a call of four rows is worth a fifth of itself
+# when four prompts wait together (68.3 ms against 84.2), which the served
+# traffic does too rarely (1.14 rows a call) to pay three more programs'
+# set-up
+PREFILL_ROWS = (1, 2)
+PREFILL_ROW_WIDTHS = (128, 256, 512)
 # what a decode program counts beside its tokens: the expert layers' counts
 # summed over layers and steps, the positions its live rows attended over in
 # a full layer and the positions the full layers' loops covered for them,
@@ -483,64 +496,78 @@ def _logits(cfg: MiMoV2Config, params, x):
 @partial(jax.jit, static_argnums=(0,), donate_argnums=(5, 6))
 def prefill_paged(cfg: MiMoV2Config, params, tokens, start, length, cache_k,
                   cache_v, page_table, row=0):
-    """Prefill one chunk of a prompt: ``tokens`` [1, P] (right-padded,
-    ``length`` real) are positions start .. start + P - 1 of the sequence
-    in decode row ``row`` whose page table is ``page_table`` [MaxPages];
-    what lies before ``start`` is already cached (this sequence's earlier
-    chunk). Full layers write the chunk through the page table and attend
-    over the sequence's own pages; window layers attend over the row's
-    ring as the earlier chunk left it and the chunk itself, then put the
-    chunk's last ``sliding_window`` real positions into the ring. Returns
-    the last real position's logits [vocab] and the caches.
+    """Prefill the rows of one call: ``tokens`` [R, P] (right-padded,
+    ``length`` [R] real) are positions start .. start + P - 1 (``start``
+    [R]) of the sequences in decode rows ``row`` [R], no two the same,
+    whose page tables are ``page_table`` [R, MaxPages]; what lies before a
+    row's ``start`` is already cached (this sequence's earlier chunk). Full
+    layers write every row's chunk through its page table and attend over
+    each row's own pages; window layers attend over each row's ring as the
+    earlier chunk left it and the chunk itself, then put the chunk's last
+    ``sliding_window`` real positions into the ring. The rest of the block,
+    the experts among it, runs once on the [R * P, D] tokens of all rows,
+    so a call reads its weights once. A row of length 0 is nobody's: it
+    writes to the scratch page and into no ring, what its queries see is
+    not used and the experts do not see it. Returns the last real
+    position's logits of every row [R, vocab] and the caches.
 
-    The caller guarantees start + P <= MaxPages * B; padded positions land
-    in pages the row has reserved and not yet reached, or in the scratch
-    page, and in no ring."""
+    A call of one row may give ``start``, ``length`` and ``row`` as scalars
+    and ``page_table`` as [MaxPages], and gets its logits as [vocab].
+
+    Padded positions are written to the scratch page and into no ring."""
+    one = page_table.ndim == 1
+    if one:
+        start, length, page_table, row = (
+            jnp.asarray(a)[None] for a in (start, length, page_table, row))
     dt = cfg.dtype
-    P = tokens.shape[1]
+    R, P = tokens.shape
     B = cache_k.page_tokens
     W = cfg.sliding_window
-    max_pages = page_table.shape[0]
-    pos = start + jnp.arange(P)
-    live = jnp.arange(P) < length
-    x = params["embed"].astype(dt)[tokens[0]].astype(jnp.float32)  # [P, D]
-    page_of = page_table[jnp.clip(pos // B, 0, max_pages - 1)]
-    # the ring before this chunk, and after it: slot r held position
-    # ``before[r]`` and takes the chunk's ``after[r] - start`` where that
-    # is one of the chunk's own
-    before = _ring_positions(start, W)
+    max_pages = page_table.shape[1]
+    pos = start[:, None] + jnp.arange(P)                              # [R, P]
+    live = jnp.arange(P) < length[:, None]
+    x = params["embed"].astype(dt)[tokens.reshape(-1)].astype(jnp.float32)  # [R * P, D]
+    page_of = jnp.take_along_axis(page_table, jnp.clip(pos // B, 0, max_pages - 1), axis=1)
+    page_of = jnp.where(live, page_of, 0)
+    # the rings before this call, and after it: slot r of a row's ring held
+    # position ``before[r]`` and takes the chunk's ``after[r] - start``
+    # where that is one of the chunk's own
+    before = _ring_positions(start, W)                                # [R, W]
     after = _ring_positions(start + length, W)
-    takes = (after >= start) & (length > 0)
-    loops = page_loops.one_loop(pos[-1:], B * page_loops.pages_a_turn(max_pages, 8))
+    takes = (after >= start[:, None]) & (length[:, None] > 0)
+    src = jnp.clip(after - start[:, None], 0, P - 1)[..., None]
+    k_pos = jnp.concatenate([before, pos], axis=1)                    # [R, W + P]
+    gap = pos[:, :, None] - k_pos[:, None, :]
+    in_window = (k_pos >= 0)[:, None, :] & (gap >= 0) & (gap < W)     # [R, P, W + P]
+    # one loop over the rows' pages, to the longest row's last real position
+    loops = page_loops.one_loop(
+        jnp.where(length > 0, start + length - 1, 0),
+        B * page_loops.pages_a_turn(max_pages, 8))
     ks, vs = list(cache_k.layers), list(cache_v.layers)
     for l, layer in enumerate(params["layers"]):
         Hkv = cfg.kv_heads(l)
         h = _rmsnorm(x, layer["norm1"], cfg.layernorm_epsilon).astype(dt)
-        q, k, v = _qkv(cfg, l, layer["attn"], h, pos)
+        q, k, v = _qkv(cfg, l, layer["attn"], h, pos.reshape(-1))
+        q, k, v = (a.reshape(R, P, *a.shape[1:]) for a in (q, k, v))
         if cfg.hybrid_layer_pattern[l]:
-            old_k = lax.dynamic_index_in_dim(ks[l], row, 0, keepdims=False)
-            old_v = lax.dynamic_index_in_dim(vs[l], row, 0, keepdims=False)
-            keys = jnp.concatenate([old_k, k])
-            vals = jnp.concatenate([old_v, v])
-            k_pos = jnp.concatenate([before, pos])
-            gap = pos[:, None] - k_pos[None, :]
-            visible = (k_pos >= 0)[None, :] & (gap >= 0) & (gap < W)
-            att = _window_attend(q[None], keys[None], vals[None], visible[None],
-                                 Hkv, layer["attn"]["sink"])[0]
-            src = jnp.clip(after - start, 0, P - 1)
-            new_k = jnp.where(takes[:, None], k[src], old_k)
-            new_v = jnp.where(takes[:, None], v[src], old_v)
-            ks[l] = lax.dynamic_update_index_in_dim(ks[l], new_k, row, 0)
-            vs[l] = lax.dynamic_update_index_in_dim(vs[l], new_v, row, 0)
+            old_k, old_v = ks[l][row], vs[l][row]                     # [R, W, ..]
+            att = _window_attend(q, jnp.concatenate([old_k, k], axis=1),
+                                 jnp.concatenate([old_v, v], axis=1), in_window,
+                                 Hkv, layer["attn"]["sink"])
+            new_k = jnp.where(takes[..., None], jnp.take_along_axis(k, src, axis=1), old_k)
+            new_v = jnp.where(takes[..., None], jnp.take_along_axis(v, src, axis=1), old_v)
+            # behind the last ring where the row has no length: dropped
+            ring = jnp.where(length > 0, row, ks[l].shape[0])
+            ks[l] = ks[l].at[ring].set(new_k, mode="drop")
+            vs[l] = vs[l].at[ring].set(new_v, mode="drop")
         else:
             ks[l] = ks[l].at[page_of, pos % B].set(k)
             vs[l] = vs[l].at[page_of, pos % B].set(v)
-            att = _paged_attend(q[None], ks[l], vs[l], page_table[None],
-                                pos[None], Hkv, loops)[0]
-        x, _ = _rest_of_block(cfg, layer, x, att, live)
-    last = lax.dynamic_index_in_dim(x, jnp.maximum(length - 1, 0), 0,
-                                    keepdims=True)
-    return (_logits(cfg, params, last)[0], LayerCache(tuple(ks), B),
+            att = _paged_attend(q, ks[l], vs[l], page_table, pos, Hkv, loops)
+        x, _ = _rest_of_block(cfg, layer, x, att.reshape(R * P, -1), live.reshape(-1))
+    ends = x.reshape(R, P, -1)[jnp.arange(R), jnp.maximum(length - 1, 0)]
+    logits = _logits(cfg, params, ends)
+    return ((logits[0] if one else logits), LayerCache(tuple(ks), B),
             LayerCache(tuple(vs), B))
 
 

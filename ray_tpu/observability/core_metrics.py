@@ -204,10 +204,21 @@ def _build() -> dict:
             "prompt tokens computed by prefill calls (padding excluded)",
             tag_keys=("deployment",),
         ),
+        "serve_prefill_calls": Counter(
+            "rt_serve_prefill_calls_total",
+            "prefill calls dispatched",
+            tag_keys=("deployment",),
+        ),
+        "serve_prefill_rows": Counter(
+            "rt_serve_prefill_rows_total",
+            "rows of prefill calls that held a sequence's chunk (the rows "
+            "a call was padded with excluded)",
+            tag_keys=("deployment",),
+        ),
         "serve_prefill_width": Histogram(
             "rt_serve_prefill_width",
-            "padded token width of each prefill call (sum = padded "
-            "tokens computed, count = calls)",
+            "positions of each prefill call as dispatched, rows x padded "
+            "width (sum = positions paid for, count = calls)",
             boundaries=(16, 32, 64, 128, 256, 512, 1024),
             tag_keys=("deployment",),
         ),

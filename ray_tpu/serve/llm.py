@@ -199,6 +199,48 @@ def _upload(*host_arrays):
     return tuple(jnp.array(a) for a in host_arrays)
 
 
+def _plan_prefill_rows(pending, widths, row_counts, several: bool, limit: int):
+    """The rows of one prefill call of a decode module that takes rows.
+
+    ``pending`` is [(decode row, prefill position, prompt tokens left)] of
+    the admitted sequences that are not yet active, in row order;
+    ``widths`` the widths of a call and ``row_counts`` the row counts
+    compiled, both ascending; ``several`` says that consecutive chunks of
+    one sequence may be rows of one call (every layer paged: a later row
+    reads the earlier one's positions from the pages); a row holds at most
+    ``limit`` tokens, whatever its width.
+
+    Every width is tried: sequences in order, a row a chunk, until the
+    rows of the largest call are taken. A sequence whose tail takes several
+    rows takes them in one call or waits for the next: only the first may
+    be cut (a tail over a whole call), so that no rest of a few tokens is
+    left to read every weight for in a call of its own. The width kept is
+    the one that prefills the most tokens; among those the one whose call,
+    padded to a compiled row count, pays for the fewest positions; among
+    those the widest (fewer rows, each reading its prefix once). Returns
+    (rows of the call, width, [(decode row, start, tokens)])."""
+    most = row_counts[-1]
+    best = None
+    for P in widths:
+        a_row = min(P, limit)
+        rows: List[tuple] = []
+        for i, pos, left in pending:
+            takes = -(-left // a_row) if several else 1
+            if rows and len(rows) + takes > most:
+                continue
+            for _ in range(min(takes, most)):
+                n = min(left, a_row)
+                rows.append((i, pos, n))
+                pos, left = pos + n, left - n
+            if len(rows) == most:
+                break
+        R = next(r for r in row_counts if r >= len(rows))
+        key = (sum(n for _, _, n in rows), -R * P, P)
+        if best is None or key > best[0]:
+            best = (key, R, P, rows)
+    return best[1:]
+
+
 class LLMServer:
     """The deployment callable: continuous-batched decode over one paged
     KV pool, one chunk dispatched ahead of its harvest.
@@ -219,7 +261,7 @@ class LLMServer:
         from ray_tpu.utils.config import config as rtcfg
 
         require_leased_platform()
-        t_load = time.monotonic()
+        self._t_load = time.monotonic()
         self.cfg = config
         self.model_cfg, self._dec = models.resolve(config.model_id)
         self.params = self._dec.load_serving_params(
@@ -257,6 +299,7 @@ class LLMServer:
         self._started = threading.Event()
         self._start_error: Optional[BaseException] = None
         self._devices: List[Dict[str, Any]] = []
+        self._load_s = 0.0
         self._kv_pool_shape: List[Any] = []
         self._kv_pool_bytes = 0
         self._kv_bytes_by_kind: Dict[str, int] = {}
@@ -271,7 +314,6 @@ class LLMServer:
                 f"engine {config.model_id!r} failed to start: "
                 f"{type(self._start_error).__name__}: {self._start_error}"
             ) from self._start_error
-        self._load_s = round(time.monotonic() - t_load, 2)
 
     def _run_engine(self) -> None:
         try:
@@ -281,14 +323,16 @@ class LLMServer:
             self._started.set()
             raise
 
-    def _engine_started(self, cache_k, cache_v) -> None:
-        """Called by the engine loop once its allocations exist, before
-        its first round: waits for them (device allocation is
-        asynchronous), records the devices holding the parameters and
-        the KV pool, and releases __init__."""
+    def _engine_loaded(self, cache_k, cache_v) -> None:
+        """Called by the engine loop once its allocations exist: waits
+        for them (device allocation is asynchronous), and records the
+        seconds the load took and the devices holding the parameters and
+        the KV pool. The loop releases __init__ (``_started``) once the
+        programs no request alone would meet are compiled too."""
         import jax
 
         jax.block_until_ready((cache_k, cache_v))
+        self._load_s = round(time.monotonic() - self._t_load, 2)
         pool = jax.tree.leaves((cache_k, cache_v))
         held = {
             d for a in (*jax.tree.leaves(self.params), *pool)
@@ -318,7 +362,6 @@ class LLMServer:
             ntags = {"deployment": self.cfg.model_id, "node": self._node_tag}
             for kind, held in self._kv_bytes_by_kind.items():
                 getattr(core_metrics, f"serve_kv_{kind}_bytes").set(held, tags=ntags)
-        self._started.set()
 
     # -- request path ---------------------------------------------------
 
@@ -785,9 +828,25 @@ class LLMServer:
                 raise
             return True
 
-        def run_prefill() -> None:
-            """Chunked prefill: at most RT_SERVE_PREFILL_CHUNK_TOKENS
-            prompt tokens per engine round (0 = unchunked), so a long
+        def count_prefill(rows: int, tokens: int, positions: int) -> None:
+            if core_metrics.ENABLED:
+                core_metrics.serve_prefill_calls.inc(tags=dep_tags)
+                core_metrics.serve_prefill_rows.inc(rows, tags=dep_tags)
+                core_metrics.serve_prefill_tokens.inc(tokens, tags=dep_tags)
+                core_metrics.serve_prefill_width.observe(positions, tags=dep_tags)
+
+        def seal_prompt(s: _PagedSeq) -> None:
+            # full prompt blocks this sequence just wrote become
+            # shareable prefix pages: seal registers the page
+            # under its chain digest with NO copy
+            n_full = len(s.prompt) // B
+            for j in range(s.n_hit, min(n_full, len(s.digests))):
+                pool.seal(s.digests[j], int(s.pages[j]))
+
+        def prefill_a_sequence_a_call() -> None:
+            """Chunked prefill, one sequence's chunk a call: at most
+            RT_SERVE_PREFILL_CHUNK_TOKENS prompt tokens per engine round
+            (0 = unchunked) for all sequences together, so a long
             prompt prefills across rounds interleaved with decode steps
             and in-flight streams keep a bounded ITL."""
             nonlocal cache_k, cache_v
@@ -807,7 +866,7 @@ class LLMServer:
                     n = min(n, width)
                     # start and width say what a traced call was: a cold
                     # chunk, or a tail behind a prefix
-                    with tracing.span("rt/engine/prefill", start=start, width=width):
+                    with tracing.span("rt/engine/prefill", start=start, width=width, rows=1):
                         tok = np.zeros((1, width), np.int32)
                         tok[0, :n] = s.prompt[start : start + n]
                         logits, cache_k, cache_v = dec.prefill_paged(
@@ -816,27 +875,124 @@ class LLMServer:
                             cache_k, cache_v, jnp.asarray(s.table),
                             np.int32(i),
                         )
-                    if core_metrics.ENABLED:
-                        core_metrics.serve_prefill_tokens.inc(
-                            n, tags=dep_tags
-                        )
-                        core_metrics.serve_prefill_width.observe(
-                            width, tags=dep_tags
-                        )
+                    count_prefill(1, n, width)
                     s.prefill_pos = start + n
                     budget -= n
                 if s.prefill_pos >= len(s.prompt) and logits is not None:
-                    # full prompt blocks this sequence just wrote become
-                    # shareable prefix pages: seal registers the page
-                    # under its chain digest with NO copy
-                    n_full = len(s.prompt) // B
-                    for j in range(s.n_hit, min(n_full, len(s.digests))):
-                        pool.seal(s.digests[j], int(s.pages[j]))
+                    seal_prompt(s)
                     first = sync(
                         "rt/engine/first_token_sync", self._sample_one,
                         logits, s.req.temperature,
                     )
                     activate(i, s, int(first), len(s.prompt))
+
+        # what a prefill call of a module that takes rows looks like (the
+        # decode module's word): the row counts compiled, and the widths
+        # of a call that the context has room for
+        row_counts = tuple(dec.PREFILL_ROWS)
+        row_widths = tuple(
+            w for w in dec.PREFILL_ROW_WIDTHS if w <= max_pages * B
+        ) if row_counts[-1] > 1 else ()
+        # first tokens of a call of rows, sampled together
+        self._sample_rows = jax.jit(dec.sample)
+
+        def call_rows(R: int, P: int, rows: List[tuple]):
+            """Dispatch one ``prefill_paged`` of ``R`` rows ``P`` wide for
+            ``rows`` [(decode row, start, tokens)]; the rows behind them
+            have no length and write to the scratch page. Logits [R, V]."""
+            nonlocal cache_k, cache_v
+            tok = np.zeros((R, P), np.int32)
+            start = np.zeros((R,), np.int32)
+            length = np.zeros((R,), np.int32)
+            table = np.zeros((R, max_pages), np.int32)
+            at = np.zeros((R,), np.int32)
+            for r, (i, pos, n) in enumerate(rows):
+                tok[r, :n] = seqs[i].prompt[pos : pos + n]
+                start[r], length[r], at[r] = pos, n, i
+                table[r] = seqs[i].table
+            logits, cache_k, cache_v = dec.prefill_paged(
+                mcfg, self.params, jnp.asarray(tok), jnp.asarray(start),
+                jnp.asarray(length), cache_k, cache_v, jnp.asarray(table),
+                jnp.asarray(at),
+            )
+            return logits
+
+        def first_tokens(logits, temperatures: List[float]):
+            """One token a row of ``logits`` [R, V], sampled together on
+            the device; the rows behind ``temperatures`` are nobody's."""
+            temps_r = np.zeros((logits.shape[0],), np.float32)
+            temps_r[: len(temperatures)] = temperatures
+            self._rng, sub = jax.random.split(self._rng)
+            return self._sample_rows(
+                logits, jnp.asarray(np.maximum(temps_r, 1e-6)),
+                jnp.asarray(temps_r <= 0), sub,
+            )
+
+        def compile_calls_of_rows() -> None:
+            """Every (R, P) a round can dispatch, and the sampling behind
+            it, run once on the scratch page with rows of no length:
+            compiled (or loaded from the cache) before the engine reports
+            ready, because requests that arrive one at a time never meet
+            a call of several rows and a program first met under load
+            compiles there."""
+            for R in row_counts:
+                for P in row_widths:
+                    jax.block_until_ready(first_tokens(call_rows(R, P, []), []))
+
+        def prefill_rows() -> None:
+            """Chunked prefill of a decode module that takes rows: ONE
+            call a round, for the next chunk (at most
+            RT_SERVE_PREFILL_CHUNK_TOKENS tokens, a row) of every admitted
+            sequence that is not yet active, up to the rows a call takes;
+            where every layer is paged a sequence's tail takes several
+            rows of the call. The expert layers and every other weight are
+            read once for all of them. First tokens are sampled together
+            and fetched in one sync."""
+            pending = [
+                (i, s.prefill_pos, len(s.prompt) - s.prefill_pos)
+                for i, s in enumerate(seqs)
+                if s is not None and not s.active and not s.req.cancelled
+                and s.prefill_pos < len(s.prompt)
+            ]
+            if not pending:
+                return
+            chunk = int(config.serve_prefill_chunk_tokens)
+            R, P, rows = _plan_prefill_rows(
+                pending, row_widths, row_counts, dec.PREFIX_CACHE,
+                chunk if chunk > 0 else row_widths[-1],
+            )
+            # start (the first row's) and width say what a traced call
+            # was: a cold chunk, or tails behind their prefixes
+            with tracing.span("rt/engine/prefill", start=rows[0][1], width=P,
+                              rows=len(rows)):
+                logits = call_rows(R, P, rows)
+            count_prefill(len(rows), sum(n for _, _, n in rows), R * P)
+            for i, pos, n in rows:
+                seqs[i].prefill_pos = pos + n
+            # the rows that hold their prompt's end
+            done = [
+                (r, i) for r, (i, pos, n) in enumerate(rows)
+                if pos + n >= len(seqs[i].prompt)
+            ]
+            if not done:
+                return
+            for _, i in done:
+                seal_prompt(seqs[i])
+            ends = dict(done)
+            toks = sync(
+                "rt/engine/first_token_sync", np.asarray,
+                first_tokens(logits, [
+                    seqs[ends[r]].req.temperature if r in ends else 0.0
+                    for r in range(len(rows))
+                ]),
+            )
+            for r, i in done:
+                activate(i, seqs[i], int(toks[r]), len(seqs[i].prompt))
+
+        # separate paths by what the decode module declares, not one path
+        # with parameters: a module of one row makes the calls it always
+        # made, in the same order under the same tokens a round
+        run_prefill = prefill_rows if row_widths else prefill_a_sequence_a_call
 
         def complete(s: _PagedSeq) -> None:
             s.req.result = s.produced[: s.req.max_new]
@@ -1165,7 +1321,10 @@ class LLMServer:
                     time.monotonic() - t_round - blocked_s, tags=dep_tags
                 )
 
-        self._engine_started(cache_k, cache_v)
+        self._engine_loaded(cache_k, cache_v)
+        if row_widths:
+            compile_calls_of_rows()
+        self._started.set()
         while not self._stop.is_set():
             try:
                 one_round()

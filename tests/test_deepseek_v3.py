@@ -424,3 +424,69 @@ def test_the_float8_control_is_not_correct_by_the_harness_own_comparison(twin):
     assert len(control["problems"]) == 1 and "logits differ" in control["problems"][0]
     gaps = control["compared"]
     assert max(gaps["prefill_logit_gap"][0], gaps["decode_logit_gap"][0]) > 3 * cfg["check"]["logit_tolerance"]
+
+
+# -- a prefill call of several rows (PR 50) ----------------------------------
+
+
+# B = 16, rows 64 wide: (pages a sequence, prior, rows)
+LATENT_PACKS = {
+    "rows_of_different_start": (
+        [[1, 2, 3, 4, 5, 6, 7], [8, 9, 10, 11], [12, 13, 14, 15, 16]],
+        [(0, 0, 48), (2, 0, 7)],
+        [(0, 48, 50), (1, 0, 9), (2, 7, 64)]),
+    "a_row_of_no_length_in_the_middle": (
+        [[1, 2, 3, 4, 5, 6, 7], [8, 9, 10, 11], [12, 13, 14, 15, 16]],
+        [(0, 0, 40)],
+        [(0, 40, 33), (1, 0, 0), (2, 0, 64), (3, 0, 0)]),
+    # two pages that sequence 0 sealed stand under sequence 1 as well: its
+    # row starts behind them, reads them and writes neither
+    "a_prefix_hit_under_one_row": (
+        [[1, 2, 3, 4, 5], [1, 2, 6, 7, 8, 9]],
+        [(0, 0, 40)],
+        [(1, 32, 50), (0, 40, 20)]),
+    # one tail over a row's width: its second row reads the first one's
+    # positions from the pages both write in this call
+    "one_tail_as_two_rows": (
+        [[1, 2, 3, 4, 5, 6, 7, 8, 9]],
+        [(0, 0, 37)],
+        [(0, 37, 64), (0, 101, 16)]),
+    "two_tails_as_four_rows": (
+        [[1, 2, 3, 4, 5, 6, 7, 8, 9], [10, 11, 12, 13, 14, 15, 16, 17]],
+        [(0, 0, 37)],
+        [(0, 37, 64), (0, 101, 16), (1, 0, 64), (1, 64, 40)]),
+}
+
+
+@pytest.mark.parametrize("pack", sorted(LATENT_PACKS))
+def test_a_prefill_call_of_rows_is_the_same_chunks_one_a_call(tiny, pack):
+    """Logits and latent pools of a call of several rows against the same
+    chunks prefilled a call each, in the same order."""
+    from test_mimo_v2 import assert_the_same, packed_against_single
+
+    from ray_tpu.models import deepseek_v3 as dec
+
+    cfg, params, _ = tiny
+    pages, prior, rows = LATENT_PACKS[pack]
+    assert_the_same(*packed_against_single(dec, cfg, params, B=16, P=64, pages=pages,
+                                           prior=prior, rows=rows))
+
+
+def test_a_call_of_rows_as_served_agrees_to_bfloat16s_rounding(twin):
+    """As served, in bfloat16: tokens do not mix in the expert layer and
+    rows do not mix in attention, so a call of rows differs from a call a
+    row by the rounding of products of another shape and no more; and no
+    expert drops a token at the larger pair count (the sorted buffer is
+    sized by the call's tokens: a dropped pair moves a token's logits by an
+    expert's whole output)."""
+    from test_mimo_v2 import packed_against_single
+
+    from ray_tpu.models import deepseek_v3 as dec
+
+    _, mcfg, params = twin
+    pages, prior, rows = LATENT_PACKS["two_tails_as_four_rows"]
+    got, want, packed, single = packed_against_single(
+        dec, mcfg, params, B=16, P=64, pages=pages, prior=prior, rows=rows)
+    assert float(np.std(got)) > 0.3
+    assert max(np.abs(got[r] - w).max() for r, w in want.items()) < 0.1
+    assert max(np.abs(a - b).max() for a, b in zip(packed, single)) < 0.1

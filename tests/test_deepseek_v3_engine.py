@@ -173,3 +173,70 @@ def test_the_model_is_found_by_its_id_and_by_nothing_else():
     with open(os.path.join(ROOT, "ray_tpu/serve/llm.py")) as f:
         engine_source = f.read()
     assert "deepseek" not in engine_source and "kanana" not in engine_source
+
+
+# -- a prefill call of several rows (PR 50) ----------------------------------
+
+
+@pytest.fixture(scope="module")
+def packer():
+    import jax
+
+    jax.config.update("jax_platforms", "cpu")
+    from test_mimo_engine import packing_engine
+
+    srv = packing_engine("kanana-2-tiny")
+    yield srv
+    srv.unload()
+
+
+def test_a_tail_over_a_rows_width_is_the_rows_of_one_call(packer, monkeypatch):
+    """Every layer is paged (``PREFIX_CACHE``), so a later row reads an
+    earlier one from the pages: with 128 tokens a row a cold prompt of 300
+    is three rows of ONE call, the fourth nobody's, and a second turn
+    behind its sealed pages takes its tail of 200 as two rows beside
+    another sequence's one. The answers are the reference's."""
+    from test_llm_engine import record_prefill_calls
+    from test_mimo_engine import ask_together, calls_of_rows, tokens_a_row
+
+    from ray_tpu.models import deepseek_v3 as dec
+
+    seen = []
+    record_prefill_calls(monkeypatch, dec, seen)
+    calls, rows = (_series("rt_serve_prefill_calls_total"),
+                   _series("rt_serve_prefill_rows_total"))
+    with tokens_a_row(128):
+        (first,), (reply,) = ask_together(packer, (300,), max_new=6)
+        assert calls_of_rows(seen) == [(4, 128, [(0, 0, 128), (0, 128, 128), (0, 256, 44)])]
+        assert len(seen) == 1
+        assert _series("rt_serve_prefill_calls_total") - calls == 1
+        assert _series("rt_serve_prefill_rows_total") - rows == 3
+        assert_greedy_by_the_reference(packer, first, reply)
+        del seen[:]
+        rng = np.random.default_rng(4)
+        second = first + reply + list(map(int, rng.integers(0, 256, 150)))
+        other = list(map(int, rng.integers(0, 256, 70)))
+        from test_llm_engine import enqueue_together
+
+        reqs = enqueue_together(packer, [{"prompt_tokens": p, "max_new_tokens": 6}
+                                         for p in (second, other)])
+        for r in reqs:
+            assert r.event.wait(300) and r.error is None
+    # four sealed pages of 64 stand under the second turn: 256 of its 456 tokens
+    assert calls_of_rows(seen) == [(4, 128, [(0, 256, 128), (0, 384, 72), (1, 0, 70)])]
+    assert_greedy_by_the_reference(packer, second, reqs[0].result)
+    assert_greedy_by_the_reference(packer, other, reqs[1].result)
+
+
+def test_a_load_that_meets_every_call_of_rows_compiles_nothing(packer, monkeypatch):
+    from test_mimo_engine import meets_every_call_of_rows
+
+    from ray_tpu.models import deepseek_v3 as dec
+
+    # a tail of 600 is two rows of 512, and two such tails are four; three
+    # prompts under 256 are a row each, the fourth row nobody's
+    meets_every_call_of_rows(
+        packer, dec, monkeypatch,
+        [(100,), (200,), (400,), (100, 90), (100, 90, 80), (200, 150), (200, 150, 140),
+         (600,), (600, 520)],
+        widths=(128, 256, 512))

@@ -291,3 +291,126 @@ def test_config_refuses_the_removed_engines_by_name(kwargs, removed):
     (key, value), = kwargs.items()
     with pytest.raises(ValueError, match=f"{key}={value!r}.*{removed}"):
         LLMConfig(model_id="gpt2-tiny", **kwargs)
+
+
+def enqueue_together(srv, asks):
+    """``asks`` reach the engine's queue in one go, so that one round
+    admits them all; returns their requests (wait on ``event``)."""
+    reqs = [srv._parse(ask) for ask in asks]
+    with srv._lock:
+        srv._queue.extend(reqs)
+    srv._work.set()
+    return reqs
+
+
+def record_prefill_calls(monkeypatch, dec, seen):
+    """From here on every ``prefill_paged`` call leaves (tokens' shape,
+    start, length, the page table's shape, row) in ``seen``, arrays as
+    lists."""
+    real = dec.prefill_paged
+
+    def recorded(cfg, params, tokens, start, length, k, v, table, row=0):
+        seen.append((tuple(tokens.shape), np.asarray(start).tolist(),
+                     np.asarray(length).tolist(), tuple(table.shape),
+                     np.asarray(row).tolist()))
+        return real(cfg, params, tokens, start, length, k, v, table, row)
+
+    monkeypatch.setattr(dec, "prefill_paged", recorded)
+
+
+def test_a_module_of_one_row_makes_the_calls_it_always_made(monkeypatch):
+    """GPT-2's decode module says one row a prefill call
+    (``PREFILL_ROWS``), and the engine keeps it on the path of one call a
+    sequence and chunk: four prompts admitted in one round are prefilled
+    in the order of their rows under 64 tokens a round for all of them
+    together, each call [1, P] with scalar start, length and row and a
+    table of one row, as before PR 50."""
+    import jax
+
+    jax.config.update("jax_platforms", "cpu")
+    from ray_tpu.models import gpt2_decode as dec
+    from ray_tpu.observability import core_metrics
+    from ray_tpu.serve.llm import LLMConfig, LLMServer
+    from ray_tpu.utils.config import config
+
+    assert dec.PREFILL_ROWS == (1,)
+    keep = config.serve_prefix_block_tokens, config.serve_prefill_chunk_tokens
+    config.set("serve_prefix_block_tokens", 16)
+    config.set("serve_prefill_chunk_tokens", 64)
+    seen = []
+    try:
+        srv = LLMServer(LLMConfig(model_id="gpt2-tiny", max_batch_size=4))
+        record_prefill_calls(monkeypatch, dec, seen)
+        calls = core_metrics.serve_prefill_calls.snapshot()["series"]
+        before = sum(calls.values())
+        rng = np.random.RandomState(50)
+        reqs = enqueue_together(srv, [
+            {"prompt_tokens": [int(t) for t in rng.randint(0, 256, n)], "max_new_tokens": 3}
+            for n in (40, 10, 30, 5)])
+        for r in reqs:
+            assert r.event.wait(120) and r.error is None and len(r.result) == 3
+    finally:
+        config.set("serve_prefix_block_tokens", keep[0])
+        config.set("serve_prefill_chunk_tokens", keep[1])
+        srv._stop.set()
+    one_row = (8,)  # 128 positions in pages of 16
+    # round one: 40 of 64 tokens, 10, then 14 of the third prompt's 30;
+    # round two: its other 16, and the fourth prompt
+    assert seen == [
+        ((1, 64), 0, 40, one_row, 0), ((1, 16), 0, 10, one_row, 1),
+        ((1, 16), 0, 14, one_row, 2), ((1, 16), 14, 16, one_row, 2),
+        ((1, 16), 0, 5, one_row, 3)]
+    if core_metrics.ENABLED:
+        snap = lambda m: sum(m.snapshot()["series"].values())
+        assert snap(core_metrics.serve_prefill_calls) - before == 5
+
+
+W = (128, 256, 512)
+# (what waits: tokens left a sequence, row counts, several rows a sequence)
+# -> (rows of the call, width, tokens a row by sequence)
+PLANS = {
+    "a_lone_chunk_takes_the_narrowest_width_that_holds_it": (
+        (40,), (1, 4), True, (1, 128, [(0, 40)])),
+    "a_tail_under_a_rows_width_is_one_row_not_rows_of_less": (
+        (300,), (1, 2, 4), True, (1, 512, [(0, 300)])),
+    "a_tail_over_a_rows_width_is_rows_of_one_call": (
+        (600,), (1, 2, 4), True, (2, 512, [(0, 512), (0, 88)])),
+    "without_a_call_of_two_rows_the_tail_is_three_of_four_rows_of_half_the_width": (
+        (600,), (1, 4), True, (4, 256, [(0, 256), (0, 256), (0, 88)])),
+    "two_tails_share_a_call": (
+        (600, 600), (1, 4), True, (4, 512, [(0, 512), (0, 88), (1, 512), (1, 88)])),
+    "a_tail_that_does_not_fit_behind_the_others_waits_whole": (
+        (1100, 600, 90), (1, 4), True, (4, 512, [(0, 512), (0, 512), (0, 76), (2, 90)])),
+    "only_the_first_tail_is_ever_cut": (
+        (2100, 600), (1, 4), True, (4, 512, [(0, 512)] * 4)),
+    "rings_a_row_give_a_sequence_one_row_of_a_call": (
+        (600, 100), (1, 2, 4), False, (2, 512, [(0, 512), (1, 100)])),
+    "two_rows_of_no_length_cost_more_than_a_call_of_two": (
+        (300, 280), (1, 2, 4), False, (2, 512, [(0, 300), (1, 280)])),
+    "more_sequences_than_rows_wait_a_round": (
+        (90, 90, 90, 90, 90), (1, 2, 4), False, (4, 128, [(0, 90), (1, 90), (2, 90), (3, 90)])),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PLANS))
+def test_the_rows_of_a_prefill_call(case):
+    """``_plan_prefill_rows``: what one call takes of what waits."""
+    from ray_tpu.serve.llm import _plan_prefill_rows
+
+    left, row_counts, several, (R, P, taken) = PLANS[case]
+    pending = [(10 + i, 64 * i, n) for i, n in enumerate(left)]
+    got_R, got_P, rows = _plan_prefill_rows(pending, W, row_counts, several, 512)
+    assert (got_R, got_P) == (R, P)
+    assert [(i - 10, n) for i, _, n in rows] == taken
+    # a sequence's rows follow one another from where its prefill stands
+    at = {i: pos for i, pos, _ in pending}
+    for i, start, n in rows:
+        assert start == at[i]
+        at[i] += n
+
+
+def test_a_row_holds_at_most_the_tokens_a_round_allows_whatever_its_width():
+    from ray_tpu.serve.llm import _plan_prefill_rows
+
+    R, P, rows = _plan_prefill_rows([(0, 0, 300), (1, 0, 50)], W, (1, 2, 4), True, 64)
+    assert (R, P) == (4, 128) and [n for _, _, n in rows] == [64, 64, 64, 64]

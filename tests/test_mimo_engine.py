@@ -4,6 +4,7 @@ layer's counts fetched with the tokens, prefix hits and KV import refused
 by name; and a GPT-2 engine never imports the family.
 """
 
+import contextlib
 import os
 import subprocess
 import sys
@@ -207,3 +208,177 @@ def test_one_stream_sends_at_a_time_and_a_closed_one_lets_go(engine):
     assert [it["index"] for it in got] == list(range(10))
     assert [it["more"] for it in got] == [True] * 9 + [False]
     assert not engine._stream_turn.locked()
+
+
+# -- a prefill call of several rows (PR 50) ----------------------------------
+
+
+def packing_engine(model_id):
+    """An engine of ``model_id`` with room for four prompts side by side."""
+    from ray_tpu.serve.llm import LLMConfig, LLMServer
+
+    return LLMServer(LLMConfig(model_id=model_id, max_batch_size=4, max_new_tokens_cap=64))
+
+
+@contextlib.contextmanager
+def tokens_a_row(n):
+    """``serve_prefill_chunk_tokens``, which the engine reads every round."""
+    from ray_tpu.utils.config import config
+
+    keep = config.serve_prefill_chunk_tokens
+    config.set("serve_prefill_chunk_tokens", n)
+    try:
+        yield
+    finally:
+        config.set("serve_prefill_chunk_tokens", keep)
+
+
+def ask_together(srv, lengths, max_new=4, seed=0):
+    """Prompts of ``lengths`` tokens admitted in one round: (prompts, the
+    answers)."""
+    from test_llm_engine import enqueue_together
+
+    rng = np.random.default_rng(seed)
+    prompts = [list(map(int, rng.integers(0, 256, n))) for n in lengths]
+    reqs = enqueue_together(srv, [{"prompt_tokens": p, "max_new_tokens": max_new}
+                                  for p in prompts])
+    for r in reqs:
+        assert r.event.wait(300) and r.error is None, r.error
+    return prompts, [r.result for r in reqs]
+
+
+def calls_of_rows(seen):
+    """What ``record_prefill_calls`` saw: (R, P, [(decode row, start,
+    length) of the rows that have a length]) a call, each with a page
+    table a row."""
+    assert all(len(table) == 2 and table[0] == shape[0] for shape, _, _, table, _ in seen)
+    return [(shape[0], shape[1],
+             [(r, s, n) for r, s, n in zip(row, start, length) if n])
+            for shape, start, length, table, row in seen]
+
+
+@pytest.fixture(scope="module")
+def packer():
+    import jax
+
+    jax.config.update("jax_platforms", "cpu")
+    srv = packing_engine("mimo-v2-tiny")
+    yield srv
+    srv.unload()
+
+
+def test_prompts_admitted_together_share_a_call_a_row_each(packer, monkeypatch):
+    """Three prompts admitted in one round: two are the rows of one call
+    (the most this module compiles), the third goes alone in the next
+    round; no call holds two rows of one sequence (a window layer's ring
+    belongs to a decode row: ``PREFIX_CACHE`` is False); and every answer
+    is the reference's."""
+    from test_llm_engine import record_prefill_calls
+
+    from ray_tpu.models import mimo_v2 as dec
+
+    assert dec.PREFILL_ROWS == (1, 2)
+    seen = []
+    record_prefill_calls(monkeypatch, dec, seen)
+    calls = _series("rt_serve_prefill_calls_total")
+    rows = _series("rt_serve_prefill_rows_total")
+    positions = _series("rt_serve_prefill_tokens_total")
+    prompts, answers = ask_together(packer, (100, 40, 128), max_new=6)
+    for prompt, tokens in zip(prompts, answers):
+        assert_greedy_by_the_reference(packer, prompt, tokens)
+    assert calls_of_rows(seen) == [(2, 128, [(0, 0, 100), (1, 0, 40)]),
+                                   (1, 128, [(2, 0, 128)])]
+    assert _series("rt_serve_prefill_calls_total") - calls == 2
+    assert _series("rt_serve_prefill_rows_total") - rows == 3
+    assert _series("rt_serve_prefill_tokens_total") - positions == 268
+    del seen[:]
+    prompts, answers = ask_together(packer, (200, 70), max_new=5, seed=1)
+    for prompt, tokens in zip(prompts, answers):
+        assert_greedy_by_the_reference(packer, prompt, tokens)
+    assert calls_of_rows(seen) == [(2, 256, [(0, 0, 200), (1, 0, 70)])]
+    for R, P, live in calls_of_rows(seen):
+        assert len({r for r, _, _ in live}) == len(live)
+
+
+def test_a_prompt_over_a_rows_width_takes_a_row_a_round(packer, monkeypatch):
+    """With 128 tokens a row, a prompt of 200 beside one of 60: one row
+    each in the first call, and the long one's rest alone in the next
+    round, never two rows of one call."""
+    from test_llm_engine import record_prefill_calls
+
+    from ray_tpu.models import mimo_v2 as dec
+
+    seen = []
+    record_prefill_calls(monkeypatch, dec, seen)
+    with tokens_a_row(128):
+        prompts, answers = ask_together(packer, (200, 60), max_new=5, seed=2)
+    for prompt, tokens in zip(prompts, answers):
+        assert_greedy_by_the_reference(packer, prompt, tokens)
+    assert calls_of_rows(seen) == [(2, 128, [(0, 0, 128), (1, 0, 60)]),
+                                   (1, 128, [(0, 128, 72)])]
+
+
+def programs_compiled(srv, prefill_paged):
+    return prefill_paged._cache_size(), srv._sample_rows._cache_size()
+
+
+def meets_every_call_of_rows(srv, dec, monkeypatch, loads, widths=(128, 256)):
+    """After the engine has reported ready, ``loads`` (prompt lengths
+    admitted together) meet every (R, P) the engine can dispatch, and
+    neither ``prefill_paged`` nor the sampling behind it gains a compiled
+    program: requests that come one at a time never meet a call of several
+    rows, so the engine ran each on the scratch page before it took any."""
+    from test_llm_engine import record_prefill_calls
+
+    jitted = dec.prefill_paged
+    before = programs_compiled(srv, jitted)
+    seen = []
+    record_prefill_calls(monkeypatch, dec, seen)
+    for k, lengths in enumerate(loads):
+        ask_together(srv, lengths, max_new=2, seed=1000 + k)
+    assert programs_compiled(srv, jitted) == before
+    met = {(R, P) for R, P, _ in calls_of_rows(seen)}
+    assert met == {(R, P) for R in dec.PREFILL_ROWS for P in widths}
+
+
+def test_a_load_that_meets_every_call_of_rows_compiles_nothing(packer, monkeypatch):
+    from ray_tpu.models import mimo_v2 as dec
+
+    meets_every_call_of_rows(
+        packer, dec, monkeypatch,
+        [(100,), (200,), (100, 90), (200, 150), (200, 150, 140)])
+
+
+def test_a_sequence_cancelled_in_a_call_of_rows_gives_its_pages_back_once(packer, monkeypatch):
+    """A request abandoned while its chunk is a row of a call in flight is
+    reaped at the next round: its pages go back to the pool once, the
+    others answer, and the pool ends with every page free."""
+    from test_llm_engine import enqueue_together
+
+    from ray_tpu.models import mimo_v2 as dec
+
+    pool = packer._prefix_pool
+    released = []
+    real_release = pool.release_pages
+    monkeypatch.setattr(pool, "release_pages",
+                        lambda pages: (released.extend(pages), real_release(pages))[1])
+    rng = np.random.default_rng(9)
+    asks = [{"prompt_tokens": list(map(int, rng.integers(0, 256, n))), "max_new_tokens": 20}
+            for n in (90, 60, 30)]
+    real = dec.prefill_paged
+    reqs = []
+
+    def abandoned_in_flight(cfg, params, tokens, *rest):
+        if tokens.shape[0] > 1:
+            reqs[1].cancelled = True  # the client went away
+        return real(cfg, params, tokens, *rest)
+
+    monkeypatch.setattr(dec, "prefill_paged", abandoned_in_flight)
+    reqs.extend(enqueue_together(packer, asks))
+    for r in reqs:
+        assert r.event.wait(300)
+    assert [len(r.result or []) for r in (reqs[0], reqs[2])] == [20, 20]
+    assert reqs[1].result is None
+    assert len(released) == len(set(released)) > 0
+    stats = pool.stats()
+    assert stats["pages_occupied"] == 0 and pool.free_pages() == stats["pages_total"]

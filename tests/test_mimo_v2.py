@@ -505,3 +505,156 @@ def test_the_step_counts_what_its_full_layers_loops_covered(tiny):
     assert by_name["attn_context_tokens"] == int(sum(n + 1 for n in lens if n))
     assert by_name["attn_loop_tokens"] < 32 * turn * long  # what one loop would have covered
 
+
+
+# -- a prefill call of several rows (PR 50) ----------------------------------
+
+
+def packed_against_single(dec, cfg, params, *, B, P, pages, prior, rows, decode_rows=4,
+                          seed=0):
+    """The same chunks prefilled as the rows of ONE call and one a call.
+
+    ``pages`` gives every sequence's pages (sequences may share leading
+    pages: a prefix another one sealed); ``prior`` and ``rows`` are
+    [(sequence, start, tokens)], a sequence being its decode row too:
+    ``prior`` is prefilled first, a call a chunk, in both arms; ``rows``
+    are the call under test, ``tokens`` 0 for a row of no length. Returns
+    (the call's logits [R, V], the single calls' logits by row, and both
+    arms' caches as lists of arrays, a paged layer without its scratch
+    page)."""
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(seed)
+    max_pages = cfg.n_positions // B
+    tables = np.zeros((len(pages), max_pages), np.int32)
+    for s, own in enumerate(pages):
+        tables[s, :len(own)] = own
+    text = {s: rng.integers(0, cfg.vocab_size, max_pages * B, dtype=np.int32)
+            for s in range(len(pages))}
+    n_pages = 1 + max(max(own) for own in pages)
+
+    def alone(caches, seq, start, n):
+        tok = np.zeros((1, P), np.int32)
+        tok[0, :n] = text[seq][start:start + n]
+        logits, *caches = dec.prefill_paged(
+            cfg, params, jnp.asarray(tok), jnp.int32(start), jnp.int32(n), *caches,
+            jnp.asarray(tables[seq]), np.int32(seq))
+        return np.asarray(logits), caches
+
+    def arm(packed):
+        caches = list(dec.init_paged_cache(cfg, n_pages, B, decode_rows))
+        for seq, start, n in prior:
+            _, caches = alone(caches, seq, start, n)
+        if packed:
+            R = len(rows)
+            tok = np.zeros((R, P), np.int32)
+            for r, (seq, start, n) in enumerate(rows):
+                if n:
+                    tok[r, :n] = text[seq][start:start + n]
+            logits, *caches = dec.prefill_paged(
+                cfg, params, jnp.asarray(tok),
+                jnp.asarray([start for _, start, _ in rows], jnp.int32),
+                jnp.asarray([n for _, _, n in rows], jnp.int32), *caches,
+                jnp.asarray(np.stack([tables[seq] if n else 0 * tables[0]
+                                      for seq, _, n in rows])),
+                jnp.asarray([seq for seq, _, _ in rows], jnp.int32))
+            logits = np.asarray(logits)
+        else:
+            logits = {}
+            for r, (seq, start, n) in enumerate(rows):
+                if n:
+                    logits[r], caches = alone(caches, seq, start, n)
+        held = []
+        for cache in caches:
+            for a in cache.layers:
+                # a pool without its scratch page; a ring a row whole
+                held.append(np.asarray(a[1:] if a.shape[0] == n_pages else a, np.float32))
+        return logits, held
+
+    (got, packed), (want, single) = arm(True), arm(False)
+    return got, want, packed, single
+
+
+def assert_the_same(got, want, packed, single, tol=1e-4):
+    assert want and all(np.abs(got[r] - w).max() < tol for r, w in want.items())
+    assert float(np.std(got[list(want)])) > 0.3
+    assert len(packed) == len(single) > 0
+    for a, b in zip(packed, single):
+        assert a.shape == b.shape and np.abs(a - b).max() < tol
+    assert max(np.abs(a).max() for a in packed) > 0.1
+
+
+# B = 16, rows 64 wide, a window of 16: (pages a sequence, prior, rows)
+MIMO_PACKS = {
+    # sequence 0 continues behind three pages and its ring, 1 starts cold
+    # and is shorter than the window, 2 continues inside its first page
+    "rows_of_different_start": (
+        [[1, 2, 3, 4, 5, 6, 7], [8, 9, 10, 11], [12, 13, 14, 15, 16]],
+        [(0, 0, 48), (2, 0, 7)],
+        [(0, 48, 50), (1, 0, 9), (2, 7, 64)]),
+    "a_row_of_no_length_in_the_middle": (
+        [[1, 2, 3, 4, 5, 6, 7], [8, 9, 10, 11], [12, 13, 14, 15, 16]],
+        [(0, 0, 40)],
+        [(0, 40, 33), (1, 0, 0), (2, 0, 64), (3, 0, 0)]),
+    # two pages of sequence 0 stand under sequence 1 as well: the call
+    # reads them for it and writes neither
+    "a_prefix_under_one_row": (
+        [[1, 2, 3, 4, 5], [1, 2, 6, 7, 8, 9]],
+        [(0, 0, 40)],
+        [(1, 32, 50), (0, 40, 20)]),
+}
+
+
+@pytest.mark.parametrize("pack", sorted(MIMO_PACKS))
+def test_a_prefill_call_of_rows_is_the_same_chunks_one_a_call(tiny, pack):
+    """Logits, pools and rings of a call of several rows against the same
+    chunks prefilled a call each. A prefix under a row of this model has no
+    ring to restore, so its row attends as if the window began at its
+    start in both arms; the full layers read the shared pages."""
+    from ray_tpu.models import mimo_v2 as dec
+
+    cfg, params, _ = tiny
+    pages, prior, rows = MIMO_PACKS[pack]
+    assert len({seq for seq, _, n in rows if n}) == sum(1 for _, _, n in rows if n)
+    out = packed_against_single(dec, cfg, params, B=16, P=64, pages=pages, prior=prior,
+                                rows=rows)
+    assert_the_same(*out)
+
+
+def test_a_row_of_no_length_writes_no_ring_and_no_page_of_its_own(tiny):
+    """A call whose rows all have no length, as the engine compiles its
+    programs before it reports ready: the rings and every page but the
+    scratch page are as they were."""
+    import jax.numpy as jnp
+
+    from ray_tpu.models import mimo_v2 as dec
+
+    cfg, params, _ = tiny
+    B, R, P = 16, 4, 64
+    fill = lambda c: type(c)(tuple(a + 1 for a in c.layers), c.page_tokens)
+    k, v = map(fill, dec.init_paged_cache(cfg, 9, B, 4))
+    zeros = jnp.zeros((R,), jnp.int32)
+    logits, k2, v2 = dec.prefill_paged(
+        cfg, params, jnp.zeros((R, P), jnp.int32), zeros, zeros, k, v,
+        jnp.zeros((R, cfg.n_positions // B), jnp.int32), zeros)
+    assert logits.shape == (R, cfg.vocab_size) and bool(jnp.isfinite(logits).all())
+    for s, a in zip(dec.cache_spec(cfg), k2.layers + v2.layers):
+        kept = a if s["kind"] == "window" else a[1:]
+        assert bool((kept == 1).all())
+
+
+def test_a_call_of_rows_as_served_agrees_to_bfloat16s_rounding(twin):
+    """As served, in bfloat16: tokens do not mix in the expert layer and
+    rows do not mix in attention, so a call of rows differs from a call a
+    row by the rounding of products of another shape and no more (a pair
+    dropped from the sorted buffer, sized by the call's tokens, would move
+    a token by an expert's whole output)."""
+    from ray_tpu.models import mimo_v2 as dec
+
+    _, mcfg, params = twin
+    pages, prior, rows = MIMO_PACKS["rows_of_different_start"]
+    got, want, packed, single = packed_against_single(
+        dec, mcfg, params, B=16, P=64, pages=pages, prior=prior, rows=rows)
+    assert float(np.std(got)) > 0.3
+    assert max(np.abs(got[r] - w).max() for r, w in want.items()) < 0.1
+    assert max(np.abs(a - b).max() for a, b in zip(packed, single)) < 0.1

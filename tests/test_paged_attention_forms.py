@@ -509,6 +509,71 @@ def test_compiled_latent_programs_copy_no_pool_and_decode_builds_no_head(one_chi
             assert gathered - {N} == {S // G * C}, (name, gathered)
 
 
+def largest_prefill_of_rows(m, model_id, one_chip, family):
+    """The largest prefill call of several rows of a served model at its
+    cell's shapes (128 decode rows, 32 sequences' worth of pages of 64), as
+    the v5e's compiler writes it: (the config, what the tool counts in its
+    operations by label, its loops, its temporaries' bytes)."""
+    cfg = m.CONFIGS[model_id]
+    S, Bx = 128, 64
+    mp = -(-cfg.n_positions // Bx)
+    R, P = m.PREFILL_ROWS[-1], m.PREFILL_ROW_WIDTHS[-1]
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+
+    stored = jax.eval_shape(lambda: m.init_paged_cache(cfg, 32 * mp + 1, Bx, S))
+    k, v = on_chip(stored)
+    p = on_chip(jax.eval_shape(lambda: m.load_serving_params(cfg)))
+    a_row = jax.ShapeDtypeStruct((R,), jnp.int32, sharding=one_chip)
+    compiled = m.prefill_paged.lower(
+        cfg, p, jax.ShapeDtypeStruct((R, P), jnp.int32, sharding=one_chip), a_row, a_row,
+        k, v, jax.ShapeDtypeStruct((R, mp), jnp.int32, sharding=one_chip), a_row).compile()
+    text = compiled.as_text()
+    ops = [ln for ln in text.splitlines() if re.match(r"\s*(ROOT )?%\S+ = ", ln)]
+    _, watch, _ = family(cfg, m, stored[0], None)
+    return (cfg, {label: count(ops) for label, count in watch}, aot.loops_of(text),
+            compiled.memory_analysis().temp_size_in_bytes)
+
+
+def test_compiled_latent_prefill_of_rows_copies_no_pool(one_chip, time_limit):
+    """Kanana's prefill call of four rows of 512 (PR 50) beside 13 GB of
+    weights and pools: no copy of a layer's latent pool, temporaries under
+    half a gigabyte (0.24 GB; a call of one row 0.03), an attention loop a
+    row a layer, each stopping behind its own row, and ONE expert loop a
+    layer over the 2,048 tokens of all rows: the expert layers read their
+    weights once a call."""
+    from ray_tpu.models import deepseek_v3 as m
+
+    cfg, counted, loops, temps = largest_prefill_of_rows(
+        m, "kanana-2-30b-a3b", one_chip, aot.deepseek_v3_family)
+    assert (m.PREFILL_ROWS[-1], m.PREFILL_ROW_WIDTHS[-1]) == (4, 512)
+    assert counted["latent-pool copies"] == 0, counted
+    assert temps < 0.5e9, temps
+    H, D = cfg.num_attention_heads, cfg.hidden_size
+    assert loops == {f"(s32[],f32[{H},512],..)": 4 * cfg.n_layer,
+                     f"(s32[],f32[2048,{D}],..)": cfg.n_layer - cfg.first_k_dense_replace}, loops
+
+
+def test_compiled_mimo_prefill_of_rows_copies_no_pool_and_no_ring(one_chip, time_limit):
+    """MiMo's prefill call of two rows of 512 (PR 50): no copy of a full
+    layer's pool, none of a window layer's rings (the rows' rings are
+    gathered, and written back through a scatter that drops the rows of no
+    length), temporaries under half a gigabyte, one loop over the rows'
+    pages a full layer and one expert loop a layer over all 1,024 tokens."""
+    from ray_tpu.models import mimo_v2 as m
+
+    cfg, counted, loops, temps = largest_prefill_of_rows(
+        m, "mimo-v2.5", one_chip, aot.mimo_v2_family)
+    assert (m.PREFILL_ROWS[-1], m.PREFILL_ROW_WIDTHS[-1]) == (2, 512)
+    assert counted["whole-pool copies"] == 0 and counted["ring copies"] == 0, counted
+    assert temps < 0.5e9, temps
+    full = cfg.hybrid_layer_pattern.count(0)
+    assert loops == {f"(s32[],f32[2,{cfg.num_attention_heads},512],..)": full,
+                     f"(s32[],f32[1024,{cfg.hidden_size}],..)": sum(cfg.moe_layer_freq)}, loops
+
+
 @pytest.mark.parametrize(
     "shape", [(32, 1024, 12, 64), (4, 1024, 25, 64)], ids=["two-heads-a-block", "whole-row-of-25"]
 )

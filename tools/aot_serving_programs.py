@@ -42,7 +42,12 @@ PR 32 and PR 47); it says nothing about time. Run here, on the CPU:
     JAX_PLATFORMS=cpu python tools/aot_serving_programs.py --model mimo-v2.5 \\
         --max-batch-size 32 --prefill 512
     JAX_PLATFORMS=cpu python tools/aot_serving_programs.py --model kanana-2-30b-a3b \\
-        --max-batch-size 32 --prefill 512
+        --max-batch-size 32 --prefill 512 [--prefill-rows 4]
+
+``--prefill-rows R`` adds the prefill call of R rows (PR 50: the chunks a
+round has to prefill in one call, the expert layers once), with the same
+counts: its temporaries beside the one-row call's, no pool or ring copy,
+and an attention loop a row a layer in the latent family.
 """
 
 from __future__ import annotations
@@ -184,6 +189,9 @@ def main() -> None:
     ap.add_argument("--max-batch-size", type=int, default=6)
     ap.add_argument("--page-tokens", type=int, default=64)
     ap.add_argument("--prefill", type=int, default=128, help="prefill width")
+    ap.add_argument("--prefill-rows", type=int, default=1,
+                    help="rows of the prefill call (a decode module whose "
+                         "PREFILL_ROWS holds it): [R, P] tokens, a table a row")
     args = ap.parse_args()
 
     cfg, dec = models.resolve(args.model)
@@ -231,6 +239,15 @@ def main() -> None:
                 cache_v, sds((max_pages,), jnp.int32),
             ),
         }
+        R = args.prefill_rows
+        if R > 1:
+            if R not in dec.PREFILL_ROWS:
+                raise SystemExit(f"{args.model} takes {dec.PREFILL_ROWS} rows a prefill call")
+            a_row = sds((R,), jnp.int32)
+            programs[f"prefill_paged (R={R}, P={args.prefill})"] = dec.prefill_paged.lower(
+                cfg, params, sds((R, args.prefill), jnp.int32), a_row, a_row, cache_k,
+                cache_v, sds((R, max_pages), jnp.int32), a_row,
+            )
         for name, lowered in programs.items():
             report(name, lowered.compile(), watch, first_pool)
     if loader:
