@@ -430,7 +430,7 @@ def _train_loop(cfg: Dict[str, Any]) -> None:
     if interpret != (platform == "cpu"):
         raise RuntimeError(f"flash interpret={interpret} on platform {platform}")
 
-    # the one configuration a chip has run (PROFILE.md): flash attention,
+    # the configuration the training cells run (PERF.md §4): flash attention,
     # no remat, unrolled layer scan, fused CE head in chunks of 256
     mcfg = dataclasses.replace(
         gpt2.CONFIGS[cfg["model_id"]], attn_impl="flash", remat=False,

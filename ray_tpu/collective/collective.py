@@ -93,10 +93,10 @@ def _ns(group: _GroupState) -> str:
 def _active_p2p(group: _GroupState) -> Optional["p2p._P2PGroup"]:
     """The group's ring transport, when usable: rendezvoused at init AND
     the kill switch is on (checked per op so a process can flip
-    RT_COLLECTIVE_P2P / config.collective_p2p for A/B runs). Flips must
-    be applied to EVERY rank of a group, as bench_core's A/B does — a
-    one-rank mismatch diverges collective routing until the op deadline
-    (recv alone tolerates it: it dual-waits both transports)."""
+    RT_COLLECTIVE_P2P / config.collective_p2p). Flips must be applied
+    to EVERY rank of a group — a one-rank mismatch diverges collective
+    routing until the op deadline (recv alone tolerates it: it
+    dual-waits both transports)."""
     if group.world_size < 2 or not p2p.enabled():
         return None
     return p2p.group_for(group.name)
@@ -154,13 +154,16 @@ def destroy_collective_group(group_name: str = "default") -> None:
     group name can be REUSED — stale keys from a prior incarnation would
     otherwise satisfy the new group's rendezvous). The ring incarnation
     token dies with it, so in-flight deliveries from old peers are
-    dropped on arrival."""
+    dropped on arrival. The deletion is waited for: the head runs
+    requests on a pool, so one sent without waiting can be applied after
+    a later ``kv_put`` and take a re-formed group's fresh rendezvous
+    record with it."""
     group = _groups.pop(group_name, None)
     from ray_tpu.collective import bucketed  # local import — avoids cycle
     bucketed.shutdown_lane(group_name)
     p2p.drop_group(group_name)
     try:
-        _control().call_oneway("kv_del_prefix", ns=f"coll/{group_name}", prefix="")
+        _control().call("kv_del_prefix", ns=f"coll/{group_name}", prefix="")
     except Exception:  # noqa: BLE001 — cluster may already be down
         pass
 

@@ -67,10 +67,10 @@ from ray_tpu.utils import rpc as rpc_mod
 from ray_tpu.utils import serialization
 from ray_tpu.utils.config import config
 
-# Per-process transport statistics. Tests and bench_core read these
-# through actor methods (each rank is its own process) to pin wire-byte
-# claims — quantized vs f32, p2p-vs-KV routing — independent of the
-# metrics pipeline; core metrics mirror the send side when enabled.
+# Per-process transport statistics. Tests read these through actor
+# methods (each rank is its own process) to pin wire-byte claims —
+# quantized vs f32, p2p-vs-KV routing — independent of the metrics
+# pipeline; core metrics mirror the send side when enabled.
 stats = {"bytes_sent": 0, "bytes_recv": 0, "sends": 0, "delivers": 0,
          "bytes_sent_inter": 0}
 _stats_lock = threading.Lock()

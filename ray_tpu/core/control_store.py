@@ -899,8 +899,8 @@ class ControlStore:
 
     def rpc_capacity_freed(self, conn, node_id: str):
         """A lease was released on `node_id`: retry parked scheduling work
-        immediately instead of waiting out its backoff (ADVICE r4: pending
-        actors otherwise idle up to 2s after capacity frees). Coalesced:
+        immediately instead of waiting out its backoff (pending actors
+        otherwise idle up to 2s after capacity frees). Coalesced:
         on a busy cluster every release fires this, so kicks within 100ms
         collapse to one — a dropped kick only costs one short backoff step
         (heartbeat anti-entropy is the backstop)."""

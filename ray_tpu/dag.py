@@ -21,8 +21,8 @@ amortize all of it away. Compiling a DAG:
   results downstream — no scheduler, no lease, no RPC framing on the
   hot path;
 - ``dag.execute(x)`` = write the input channel(s), read the output
-  channel(s): µs-scale per call (bench_core.py measures the ratio vs
-  ``actor.f.remote()`` + ``get``).
+  channel(s): no task is submitted per call, where
+  ``actor.f.remote()`` + ``get`` submits one.
 
 Same-host only (shm channels), like the reference's default channel
 tier; the compiled loop occupies one executor slot on each actor until

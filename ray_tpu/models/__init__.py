@@ -12,8 +12,10 @@ names whichever model implements them (``models/gpt2_decode.py``,
     prefill_paged(cfg, params, tokens, start, length, k, v, page_table, row)
     decode_paged_and_sample(...), decode_multi_paged(...), MAX_DECODE_CHUNK
     update_rows_paged(...)
-    write_pages(...), read_pages(...)           only where KV_TRANSFER
-    PREFIX_CACHE, KV_TRANSFER                   what the cache can do
+    sample(logits, temps, greedy_mask, rng)     the first tokens of a
+                                                prefill call of rows
+    PREFIX_CACHE                                whether a sealed page may
+                                                be matched by a later prompt
     PREFILL_ROWS                                the rows a prefill call takes:
                                                 (1,), or the row counts the
                                                 engine compiles, and then

@@ -55,7 +55,6 @@ from ray_tpu.models.gpt2_decode import (  # noqa: F401 — the engine's interfac
 from ray_tpu.ops import moe, page_loops
 
 PREFIX_CACHE = True    # pages of one kind: a sealed page is all of its positions
-KV_TRANSFER = False    # a shipment of latent pages has no wire format yet
 DECODE_ATTENTION = "own_latent_pages"
 MAX_DECODE_CHUNK = 8
 # the rows a prefill call takes: the row counts the engine compiles (a call

@@ -260,7 +260,7 @@ def _fused_ce_fwd(x, wte, targets, n_chunks: int, vocab_size: int):
 
     lax.map (sequential) over chunks rather than vmap: it GUARANTEES one
     fp32 logits chunk live at a time (vmap leaves that to XLA fusion
-    luck) and measured 22ms/step FASTER on v5e (PROFILE.md)."""
+    luck)."""
     B, T, D = x.shape
     V = wte.shape[0]
     C = T // n_chunks

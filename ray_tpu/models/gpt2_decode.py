@@ -47,11 +47,9 @@ from ray_tpu.models.gpt2 import GPT2Config, _layernorm
 
 
 # what the engine asks of a decode module beside its programs
-# (``models/__init__.py``): sealed prefix pages may be matched, pages may
-# be shipped between replicas, and a decode program counts nothing
-# beside its tokens
+# (``models/__init__.py``): sealed prefix pages may be matched, and a
+# decode program counts nothing beside its tokens
 PREFIX_CACHE = True
-KV_TRANSFER = True
 STEP_COUNTERS = ()
 # a prefill call takes one sequence's chunk: the pool layer's select and
 # write-back are built around one row (ROADMAP S11), and the engine keeps
@@ -254,11 +252,11 @@ def _write_layer(data, layer_idx, new, sel, hit):
 
 @partial(jax.jit, donate_argnums=(2, 3))
 def write_pages(k_blocks, v_blocks, cache_k, cache_v, pages):
-    """Batched page import (disaggregated KV shipment): write
-    ``k_blocks``/``v_blocks`` [L, n, B, H, Dh] into physical pages
-    ``pages`` [n] of the pools, converting those few pages to the stored
-    shape. The ONLY block-copy path left in the paged engine — prefix
-    hits bump refcounts instead."""
+    """Plain blocks into the pools: write ``k_blocks``/``v_blocks``
+    [L, n, B, H, Dh] into physical pages ``pages`` [n], converting those
+    few pages to the stored shape. With ``read_pages`` this is how code
+    outside this module (the tests that build a pool from a reference's
+    K and V) stays ignorant of that shape; the engine never calls it."""
     B = cache_k.page_tokens
 
     def put(pool, blocks):
@@ -277,10 +275,9 @@ def write_pages(k_blocks, v_blocks, cache_k, cache_v, pages):
 
 @partial(jax.jit, static_argnums=(0,))
 def read_pages(cfg: GPT2Config, cache_k, cache_v, pages):
-    """Batched page export, ``write_pages``' inverse: physical pages
-    ``pages`` [n] of the pools as ``(k, v)`` blocks [L, n, B, H, Dh].
-    Converts those few pages, not the pool, and donates nothing: the
-    exporter goes on serving from its pools."""
+    """``write_pages``' inverse: physical pages ``pages`` [n] of the
+    pools as plain ``(k, v)`` blocks [L, n, B, H, Dh]. Converts those few
+    pages, not the pool, and donates nothing: the pools stay usable."""
     B = cache_k.page_tokens
 
     def get(pool):

@@ -33,7 +33,7 @@ however long the row grows. Decode reads a row's own pages in full layers
 (a loop over page-table columns that stops at the longest row) and the
 ring in window layers. A prefix hit would have to restore the rings, which
 nothing does yet: ``PREFIX_CACHE`` is False and the engine refuses hits by
-name, as it refuses KV transfer (``KV_TRANSFER``).
+name.
 
 The programs are the engine's interface, under the names GPT-2's have
 (``models/__init__.py``), and what a step counted rides beside its tokens
@@ -56,7 +56,6 @@ from ray_tpu.models.gpt2_decode import (  # noqa: F401 — the engine's interfac
 from ray_tpu.ops import moe, page_loops
 
 PREFIX_CACHE = False   # a hit would have to restore the window layers' rings
-KV_TRANSFER = False    # no write_pages / read_pages: a shipment is pages of one shape
 DECODE_ATTENTION = "own_pages_and_rings"
 MAX_DECODE_CHUNK = 8
 # the rows a prefill call takes: the row counts the engine compiles (a call

@@ -348,17 +348,6 @@ def _build() -> dict:
             "the live rows attended over is the useful part of it",
             tag_keys=("deployment",),
         ),
-        "serve_kv_block_copies": Counter(
-            "rt_serve_kv_block_copies_total",
-            "KV block copies performed at admission (prefix-pool copy "
-            "or KV import write); a paged prefix hit performs ZERO",
-            tag_keys=("deployment",),
-        ),
-        "serve_kv_transfer_bytes": Counter(
-            "rt_serve_kv_transfer_bytes_total",
-            "KV-cache bytes shipped prefill -> decode over rpc channels",
-            tag_keys=("deployment",),
-        ),
         "serve_multiplex_loads": Counter(
             "rt_serve_multiplex_loads_total",
             "per-model multiplex loads (cold model pulled into a replica)",

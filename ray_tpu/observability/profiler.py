@@ -20,7 +20,7 @@ Two modes share one sampling core:
   ``rt-prof``) whose per-subsystem shares feed
   ``rt_profile_samples_total{subsystem}`` so history/alerts can trend
   CPU attribution. Default off; ``RT_OBSERVABILITY_ENABLED=0`` means
-  zero extra threads (bench_obs pins this).
+  zero extra threads.
 
 Attribution walks each stack leaf -> root: the first frame inside a
 ``ray_tpu`` module maps through :data:`_FRAME_BUCKETS`
@@ -56,7 +56,6 @@ SAMPLER_THREAD_NAME = "rt-prof"
 _FRAME_BUCKETS: Tuple[Tuple[str, str], ...] = (
     ("ray_tpu/serve/llm", "engine"),
     ("ray_tpu/serve/models", "engine"),
-    ("ray_tpu/serve/kv_transfer", "engine"),
     ("ray_tpu/serve/prefix_cache", "engine"),
     ("ray_tpu/serve/", "serve"),
     ("ray_tpu/collective/", "collective"),
@@ -398,7 +397,7 @@ border-right:1px solid rgba(255,255,255,.4);cursor:default}}
 class ContinuousSampler(threading.Thread):
     """Low-rate per-process sampler feeding
     ``rt_profile_samples_total{subsystem}``. Tracks its own duty cycle
-    (sampling time / wall time) so bench_obs can pin overhead without
+    (sampling time / wall time), so its overhead can be read without
     relying on A/B wall-clock noise."""
 
     def __init__(self, hz: float):

@@ -72,8 +72,6 @@ ENGINE = "engine"
 ENGINE_QUEUE = "engine.queue"
 ENGINE_PREFILL = "engine.prefill"
 ENGINE_DECODE = "engine.decode"
-PREFILL = "prefill"
-TRANSFER = "transfer"
 
 # Wall-clock anchor: recorded once per process so every later stamp is
 # anchor + monotonic delta. An NTP step after import cannot reorder this

@@ -313,9 +313,8 @@ def _pick_block(t: int, target: int) -> int:
 
 def _block_sizes(T: int, width: int):
     """(bq, bk) for sequence length T and blocks `width` lanes wide.
-    1024x1024 measured fastest on v5e for the train step (PROFILE.md): the
-    [bq, bk] f32 score tile is 4MB of VMEM, large q tiles amortize the
-    [bq, Dh]-contraction's half-width MXU occupancy (Dh=64), and at
+    1024x1024 for the train step: the [bq, bk] f32 score tile is 4MB of
+    VMEM, large q tiles amortize the [bq, Dh]-contraction's half-width MXU occupancy (Dh=64), and at
     T<=1024 the kernel runs the one-shot softmax path (single K block, no
     online-softmax carries). VMEM stays bounded for long sequences (T=128k
     runs at the same tile size). RT_FLASH_BQ/BK (dynamic flags) override

@@ -1,7 +1,7 @@
 """Serving control loop — the acting half of the serving story.
 
-PR 15 built the sensing half (metrics history, SLO burn-rate alerts,
-``bench_serve.py``); this package closes the loop:
+PR 15 built the sensing half (metrics history, SLO burn-rate alerts);
+this package closes the loop:
 
 - ``policy``: the SLO-driven autoscaling policy. ``SignalCollector``
   reads windowed TTFT p95 / KV-page occupancy / queue depth from the

@@ -32,7 +32,7 @@ path), a ready result, or an awaitable resolving to a result. Raising
 ``FallbackToPool`` from the awaitable re-dispatches the request to the
 ordinary pool handler. The serve proxy uses this to issue the
 replica RPC asynchronously — the request then costs zero executor
-hops and no parked pool thread (PROFILE.md serve budget).
+hops and no parked pool thread.
 """
 
 from __future__ import annotations

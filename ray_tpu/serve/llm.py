@@ -80,7 +80,7 @@ class LLMConfig:
 class _Request:
     __slots__ = ("prompt", "max_new", "temperature", "event", "result",
                  "error", "token_q", "cancelled", "trace_id", "t_enqueue",
-                 "t_refused", "t0_us", "kv_import")
+                 "t_refused", "t0_us")
 
     def __init__(self, prompt, max_new, temperature, stream=False):
         self.prompt = prompt
@@ -89,11 +89,6 @@ class _Request:
         self.event = threading.Event()
         self.result: Optional[List[int]] = None
         self.error: Optional[BaseException] = None
-        # disaggregated decode: prefill already ran elsewhere and shipped
-        # {"k", "v", "first_token", "prompt_len"} over an RpcChannel
-        # (serve/kv_transfer.py) — admission imports the KV rows instead
-        # of prefilling
-        self.kv_import: Optional[Dict[str, Any]] = None
         # observability (set at enqueue only when the switches are on):
         # trace id propagated from the proxy, wall/monotonic enqueue
         # stamps for the engine span and the TTFT histogram
@@ -388,7 +383,6 @@ class LLMServer:
         temperature = float(request.get("temperature", 0.0))
         req = _Request(prompt, max_new, temperature, stream=stream)
         req.trace_id = trace_id
-        req.kv_import = request.get("kv_import")
         return req
 
     def __call__(self, request: Any):
@@ -700,57 +694,13 @@ class LLMServer:
                 # unrequested first token into the stream
                 s.req.token_q.put(first)
 
-        def import_kv(i: int, s: _PagedSeq, imp: Dict[str, Any]) -> None:
-            """Disaggregated decode: the prefill tier shipped this
-            prompt's KV rows + first token. Blocks already resident in
-            the pool were matched at admission (zero-copy ref bump);
-            only the rest is device-written, then full blocks seal so
-            the NEXT import of this prefix copies nothing at all."""
-            nonlocal cache_k, cache_v
-            n = min(int(imp["prompt_len"]), T_max - 1)
-            skip = min(s.cached_tokens, n)  # pool-resident prefix
-            if n > skip:
-                L, H, Dh = mcfg.n_layer, mcfg.n_head, mcfg.head_dim
-                nblk = -(-(n - skip) // B)
-                kb = np.zeros((L, nblk * B, H, Dh), np.float32)
-                vb = np.zeros((L, nblk * B, H, Dh), np.float32)
-                kb[:, : n - skip] = np.asarray(imp["k"])[:, skip:n]
-                vb[:, : n - skip] = np.asarray(imp["v"])[:, skip:n]
-                first_pg = skip // B
-                pages = np.asarray(
-                    s.pages[first_pg : first_pg + nblk], np.int32
-                )
-                cache_k, cache_v = dec.write_pages(
-                    jnp.asarray(kb.reshape(L, nblk, B, H, Dh)),
-                    jnp.asarray(vb.reshape(L, nblk, B, H, Dh)),
-                    cache_k, cache_v, jnp.asarray(pages),
-                )
-                pool.copies += nblk
-                if core_metrics.ENABLED:
-                    core_metrics.serve_kv_block_copies.inc(
-                        nblk, tags=dep_tags
-                    )
-                for j in range(first_pg, min(n // B, len(s.digests))):
-                    pool.seal(s.digests[j], int(s.pages[j]))
-            s.prefill_pos = len(s.prompt)
-            s.cached_tokens = n
-            activate(i, s, int(imp["first_token"]), n)
-
         def admit(i: int, req: _Request) -> bool:
             """Page-based admission: reserve EVERY page the sequence
             can ever touch up front (tables never change mid-flight,
             decode can never OOM mid-generation). Returns False — and
             takes nothing — when the pool can't cover the reservation:
             the caller requeues the request until pages free up."""
-            nonlocal cache_k, cache_v
             prompt = req.prompt[-(T_max - 1):]
-            if req.kv_import is not None and not dec.KV_TRANSFER:
-                self._fail_request(req, RuntimeError(
-                    f"model {self.cfg.model_id!r} takes no KV import: its "
-                    f"cache is not K and V pages of one shape, and a shipment "
-                    f"carries nothing else"
-                ))
-                return True  # consumed (failed); keep admitting
             use_prefix = bool(config.serve_prefix_cache)
             if use_prefix and not dec.PREFIX_CACHE:
                 # refused by name, never a silent wrong hit: a hit would
@@ -769,13 +719,11 @@ class LLMServer:
             digests = (
                 prefix_cache.hash_blocks(prompt, B) if use_prefix else []
             )
-            if req.kv_import is not None:
-                cap = int(req.kv_import["prompt_len"])
-            else:
-                # keep >=1 prompt token uncached: the tail prefill
-                # produces the first-token logits
-                cap = len(prompt) - 1
-            _, hit_pages = pool.match_pages(digests, max_tokens=cap)
+            # keep >=1 prompt token uncached: the tail prefill
+            # produces the first-token logits
+            _, hit_pages = pool.match_pages(
+                digests, max_tokens=len(prompt) - 1
+            )
             new_pages = pool.alloc(n_pages - len(hit_pages))
             if new_pages is None:
                 pool.release_pages(hit_pages)
@@ -816,16 +764,6 @@ class LLMServer:
                         if req.t_refused is not None else 0.0,
                         tags=dep_tags,
                     )
-            try:
-                if req.kv_import is not None:
-                    import_kv(i, s, req.kv_import)
-            except Exception as e:  # noqa: BLE001
-                retire(i)
-                self._fail_request(req, e)
-                # write_pages donates the caches: a post-dispatch
-                # failure here deleted them — propagate so the outer
-                # handler fails in-flight requests and rebuilds
-                raise
             return True
 
         def count_prefill(rows: int, tokens: int, positions: int) -> None:
@@ -1411,8 +1349,6 @@ def deploy(
     max_queued_requests: Optional[int] = None,
     wait_ready: bool = True,
     ready_timeout_s: float = 300.0,
-    disaggregated: bool = False,
-    prefill_replicas: int = 1,
 ):
     """Run the OpenAI-compatible front door (parity: the reference's
     ``serve.llm build_openai_app`` + ``serve.run``): a multi-replica
@@ -1432,33 +1368,10 @@ def deploy(
     to CPU replicas), and a replica whose constructor fails — leased a
     chip, found another platform — fails this call at once.
 
-    ``disaggregated=True`` additionally runs a ``<name>-prefill``
-    deployment (serve/kv_transfer.py): ingress replicas send every
-    prompt there for prefill and import the KV rows over an RpcChannel,
-    keeping only decode local (kill switch RT_SERVE_DISAGG=0 reverts to
-    local prefill without redeploying).
-
     Returns the DeploymentHandle."""
     from ray_tpu.serve.openai.ingress import build_openai_deployment
 
     ray_actor_options = _replica_resources(ray_actor_options)
-    prefill_name = None
-    if disaggregated:
-        from ray_tpu.serve.kv_transfer import PrefillServer
-
-        prefill_name = f"{name}-prefill"
-        prefill_dep = serve.deployment(
-            PrefillServer,
-            name=prefill_name,
-            num_replicas=prefill_replicas,
-            route_prefix=None,  # internal tier: no HTTP surface
-            max_concurrency=max_concurrency,
-            ray_actor_options=ray_actor_options,
-        ).bind(models, max_engines_per_replica=max_engines_per_replica)
-        serve.run(
-            prefill_dep, wait_ready=wait_ready,
-            ready_timeout_s=ready_timeout_s,
-        )
     app = build_openai_deployment(
         models,
         name=name,
@@ -1469,7 +1382,6 @@ def deploy(
         max_concurrency=max_concurrency,
         autoscaling_config=autoscaling_config,
         ray_actor_options=ray_actor_options,
-        prefill_deployment=prefill_name,
         max_queued_requests=max_queued_requests,
     )
     return serve.run(

@@ -69,8 +69,7 @@ class OpenAIServer:
     """The `/v1` deployment callable (one instance per replica)."""
 
     def __init__(self, models, tokenizer: Optional[str] = None,
-                 max_engines_per_replica: int = 2,
-                 prefill_deployment: Optional[str] = None):
+                 max_engines_per_replica: int = 2):
         from ray_tpu.accelerators.tpu import require_leased_platform
         from ray_tpu.serve import multiplex
 
@@ -80,9 +79,6 @@ class OpenAIServer:
         require_leased_platform()
         self._models = _normalize_models(models)
         self._tokenizer_name = tokenizer
-        # disaggregated serving: name of the prefill-tier deployment
-        # (serve/kv_transfer.py PrefillServer); None = monolithic
-        self._prefill_deployment = prefill_deployment
         # engines load lazily per model id and evict LRU — the multiplex
         # registry also feeds the replica's loaded-model stats, which the
         # router's warm-engine affinity reads
@@ -228,33 +224,7 @@ class OpenAIServer:
             # rides the engine-request dict: the proxy-minted trace id
             # reaches the engine span without a header-bearing object
             eng_req["trace_id"] = trace_id
-        return engine, self._maybe_disaggregate(model, engine, eng_req)
-
-    def _maybe_disaggregate(self, model: str, engine,
-                            eng_req: Dict[str, Any]) -> Dict[str, Any]:
-        """Disaggregated serving: run the prefill leg on the prefill
-        deployment and attach the shipped KV rows as ``kv_import`` so
-        the local engine only decodes. No-op without a prefill tier or
-        with RT_SERVE_DISAGG=0. A dead prefill tier fails the request
-        within RT_SERVE_DISAGG_TIMEOUT_S (never a decode hang)."""
-        from ray_tpu.utils.config import config
-
-        if self._prefill_deployment is None or not config.serve_disagg:
-            return eng_req
-        from ray_tpu.serve import kv_transfer
-
-        try:
-            imp = kv_transfer.prefill_remote(
-                self._prefill_deployment, model, eng_req, engine.model_cfg
-            )
-        except OpenAIError:
-            raise
-        except Exception as e:  # noqa: BLE001 — OpenAI-shaped surface
-            raise OpenAIError(
-                f"disaggregated prefill failed: {type(e).__name__}: {e}",
-                status=500, err_type="internal_error",
-            ) from e
-        return {**eng_req, "kv_import": imp}
+        return engine, eng_req
 
     # -- SSE streaming ---------------------------------------------------
 
@@ -386,7 +356,6 @@ def build_openai_deployment(
     max_concurrency: int = 16,
     autoscaling_config: Optional[Dict[str, Any]] = None,
     ray_actor_options: Optional[Dict[str, float]] = None,
-    prefill_deployment: Optional[str] = None,
     max_queued_requests: Optional[int] = None,
 ):
     """Bind the multi-replica OpenAI front door (use serve.llm.deploy to
@@ -407,5 +376,4 @@ def build_openai_deployment(
     return dep.bind(
         models, tokenizer=tokenizer,
         max_engines_per_replica=max_engines_per_replica,
-        prefill_deployment=prefill_deployment,
     )

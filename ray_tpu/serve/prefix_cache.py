@@ -18,8 +18,7 @@ pages are LRU-evictable; ``close()`` drops everything regardless of
 refcounts — a multiplex eviction must not strand resident pages (the
 pool is gone with the engine).
 
-Kill switch: RT_SERVE_PREFIX_CACHE=0 (checked at admission, so it
-doubles as bench_core's A/B lever at runtime).
+Kill switch: RT_SERVE_PREFIX_CACHE=0 (checked at admission).
 """
 
 from __future__ import annotations
@@ -122,10 +121,6 @@ class PagedKVPool:
         self.hits = 0
         self.misses = 0
         self.evictions = 0
-        # block copies performed at admission on this pool's behalf
-        # (KV-import page writes; a prefix hit must contribute ZERO) —
-        # incremented by the engine next to each device copy it issues
-        self.copies = 0
         with _POOLS_LOCK:
             _POOLS[id(self)] = self
 
@@ -283,7 +278,6 @@ class PagedKVPool:
                 "hits": self.hits,
                 "misses": self.misses,
                 "evictions": self.evictions,
-                "copies": self.copies,
                 "pages_total": self.num_pages - 1,  # scratch excluded
                 "pages_free": free,
                 "pages_occupied": self.num_pages - 1 - free,

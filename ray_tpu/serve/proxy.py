@@ -13,8 +13,7 @@ bounded pool; streamed responses ride chunked transfer encoding.
 Hot path: replica calls go over ONE direct RPC to the replica's hosting
 worker (router.call_direct → rpc_actor_direct_call) on the multi-segment
 wire format + cached dispatcher pool — no TaskSpec, no owner-side object
-store (PROFILE.md "Serve no-op front-door budget"). config.serve_direct_rpc
-switches the old actor-task path back on.
+store. config.serve_direct_rpc switches the old actor-task path back on.
 
 OpenAI front door: paths shaped like `/v1/completions`,
 `/v1/chat/completions` and `/v1/models` get a cheap body probe

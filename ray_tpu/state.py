@@ -498,10 +498,9 @@ def request_summary(address: Optional[str] = None) -> Dict[str, Any]:
     time-to-first-token by prefix-cache outcome (ttft_cached_s vs
     ttft_cold_s), the paged engine's phase spans give engine_queue_s
     (enqueue to pages reserved), page_wait_s (the part of it refused for
-    pages) and admit_to_first_s (pages reserved to first token), and
-    disaggregated deployments contribute prefill_s / transfer_s legs, so
-    a hot-vs-cold, queueing or remote-prefill regression is visible
-    without raw span spelunking."""
+    pages) and admit_to_first_s (pages reserved to first token), so a
+    hot-vs-cold or queueing regression is visible without raw span
+    spelunking."""
     events, dropped = _collect_task_events(address, types=["request"])
     per_dep: Dict[str, Dict[str, List[float]]] = {}
     for e in events:
@@ -530,10 +529,6 @@ def request_summary(address: Optional[str] = None) -> Dict[str, Any]:
             )
         elif comp == "engine.prefill":
             rec.setdefault("admit_to_first_s", []).append(dur_s)
-        elif comp == "prefill":
-            rec.setdefault("prefill_s", []).append(dur_s)
-        elif comp == "transfer":
-            rec.setdefault("transfer_s", []).append(dur_s)
     deployments = {}
     for dep, rec in sorted(per_dep.items()):
         deployments[dep] = _latency_entry(rec, "e2e_s")

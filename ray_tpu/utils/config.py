@@ -130,7 +130,7 @@ config.define("testing_rpc_failure", "")
 # Serve proxy → replica hot path: one direct RPC to the hosting worker
 # (rpc_actor_direct_call) instead of the actor-task machinery. Off =
 # every proxied request takes the ordinary submit/reply path (the
-# mixed-version escape hatch, and the A/B lever for bench_core).
+# mixed-version escape hatch).
 config.define("serve_direct_rpc", True)
 config.define("health_check_period_s", 1.0)
 config.define("health_check_timeout_s", 10.0)
@@ -145,10 +145,7 @@ config.define("object_transfer_window", 8)
 # Pulls at/above this size stream into a disk-backed mmap instead of a
 # heap bytearray (bounding worker RSS for huge objects).
 config.define("object_pull_disk_threshold", 256 * 1024 * 1024)
-config.define("worker_register_timeout_s", 30.0)
 config.define("worker_pool_prestart", 0)
-config.define("worker_idle_timeout_s", 600.0)
-config.define("scheduler_spread_threshold", 0.5)
 config.define("task_max_retries", 3)
 config.define("borrow_pin_ttl_s", 600.0)
 # Streaming generators: once the done-marker says item i exists, how long
@@ -207,7 +204,7 @@ config.define("lineage_max_bytes", 256 * 1024 * 1024)
 # Host collectives (collective/): peer-to-peer ring transport over the
 # worker<->worker multiseg RPC data plane. RT_COLLECTIVE_P2P=0 is the
 # kill switch — every collective byte rides the control-store KV again
-# (the pre-p2p path, and the A/B lever for bench_core).
+# (the pre-p2p path).
 config.define("collective_p2p", True)
 # Payloads below this ride the KV path even with p2p on: a tiny tensor's
 # ring handshake costs more than one head round trip.
@@ -257,8 +254,6 @@ config.define("rdt_d2h_chunk_bytes", 4 * 1024 * 1024)
 # Off = export lazily on the consumer's first get (the pre-overlap
 # behavior; saves the work when consumers are usually in-process).
 config.define("rdt_eager_export", True)
-config.define("actor_max_restarts", 0)
-config.define("log_to_driver", True)
 config.define("temp_dir", "/tmp/ray_tpu")
 # Observability (C18). trace_events gates task lifecycle span stamping
 # (RT_TRACE_EVENTS=0 disables); observability_enabled gates the built-in
@@ -271,17 +266,13 @@ config.define("observability_enabled", True)
 # prefix blocks stay resident as sealed pages of the engine's
 # refcounted, LRU-evicted page pool, and a request sharing the prefix
 # pins them at admission instead of re-running prefill over them.
-# RT_SERVE_PREFIX_CACHE=0 is the kill switch (and the A/B
-# lever for bench_core's TTFT rows): every admission pays full prefill.
+# RT_SERVE_PREFIX_CACHE=0 is the kill switch: every admission pays full
+# prefill.
 config.define("serve_prefix_cache", True)
 # Tokens per prefix block: the unit of hashing, refcounting and reuse.
 # Must be uniform across replicas of a deployment (the router's
 # prefix-hash hint assumes one block geometry).
 config.define("serve_prefix_block_tokens", 64)
-# Resident prefix pages the disaggregated prefill tier's pool holds
-# beyond one working sequence (serve/kv_transfer.PrefillEngine);
-# refcount-0 sealed pages evict LRU beyond this.
-config.define("serve_prefix_pool_blocks", 512)
 # The engine's page pool (serve/llm.py + prefix_cache.PagedKVPool):
 # generation KV and the prefix cache share ONE block-granular refcounted
 # pool — a prefix hit is a refcount bump (zero block copies), eviction
@@ -300,16 +291,6 @@ config.define("serve_paged_max_seqs", 0)
 # with decode steps (bounding in-flight streams' ITL and per-step
 # memory). 0 = unchunked (a prompt prefills in one round).
 config.define("serve_prefill_chunk_tokens", 512)
-# Disaggregated prefill/decode (serve/kv_transfer.py): the ingress
-# calls a separate prefill deployment which ships the prompt's KV rows
-# back over an RpcChannel (zero-copy multiseg frames); the local engine
-# imports them and only decodes. RT_SERVE_DISAGG=0 is the kill switch —
-# every request prefills in the decode replica even when a prefill
-# deployment exists.
-config.define("serve_disagg", True)
-# Budget for one prefill+transfer leg; a SIGKILLed prefill replica
-# surfaces as a request failure within this, never a decode hang.
-config.define("serve_disagg_timeout_s", 60.0)
 # Server-side slice cap for blocking rpc_* waits on the head (kv_wait,
 # wait_actor_alive, wait_placement_group): a handler never holds a
 # dispatcher thread longer than this per call — clients re-issue slices

@@ -180,7 +180,7 @@ def hist_quantile(
     The single shared implementation — state rollups, the ``rt top``
     renderer, the metrics-history store, and the alert engine all
     interpolate identically, so a client-vs-server percentile
-    comparison (bench_serve.py) never diverges on interpolation math.
+    comparison never diverges on interpolation math.
     """
     total = sum(buckets)
     if not bounds or not total:

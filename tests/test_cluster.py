@@ -64,8 +64,7 @@ def test_strict_spread_pg_across_nodes(cluster):
 
 def test_pg_bundle_task_on_remote_node(cluster):
     """Tasks pinned to a PG bundle hosted on a different node than the
-    caller's local agent must spill back to the bundle's node, not hang
-    (ADVICE r1 high finding)."""
+    caller's local agent must spill back to the bundle's node, not hang."""
     cluster.add_node(num_cpus=2)
     cluster.add_node(num_cpus=2)
     ray_tpu.init(address=cluster.address)
@@ -97,7 +96,7 @@ def test_pg_bundle_task_on_remote_node(cluster):
 def test_cross_node_large_object_get(cluster):
     """A borrower on a different host can read a >max_direct object: the
     owner's reply routes through the hosting agent's chunked read instead
-    of handing back a useless local shm path (ADVICE r1 medium finding)."""
+    of handing back a useless local shm path."""
     import numpy as np
 
     cluster.add_node(num_cpus=2, resources={"site_a": 1})

@@ -254,28 +254,3 @@ def test_channel_write_rule_only_applies_to_exec_loop_modules():
             self._f.write(serialization.dumps(value))
     """)
     assert not violations, violations
-
-
-# -- kv_transfer: the disaggregated prefill→decode KV handoff ------------
-
-
-def test_flags_packed_kv_shipment_write():
-    # a prefill replica joining the KV rows into one packed blob before
-    # the channel write would reintroduce the in-band memcpy per request
-    violations = _check_channel("""
-        def send_kv(handle, shipment, timeout_s):
-            chan = channels.open_channel(handle, "write")
-            chan.write(serialization.pack(shipment), timeout_s=timeout_s)
-    """, filename=os.path.join("ray_tpu", "serve", "kv_transfer.py"))
-    assert len(violations) == 1 and ".write()" in violations[0]
-
-
-def test_kv_shipment_write_value_is_clean():
-    # write_value serializes scatter-gather: the KV ndarrays ride as
-    # out-of-band segments — the shape kv_transfer.py actually ships
-    violations = _check_channel("""
-        def send_kv(handle, shipment, timeout_s):
-            chan = channels.open_channel(handle, "write")
-            chan.write_value(shipment, timeout_s=timeout_s)
-    """, filename=os.path.join("ray_tpu", "serve", "kv_transfer.py"))
-    assert not violations, violations

@@ -193,43 +193,6 @@ def test_engine_reports_the_pool_it_holds():
         srv._stop.set()
 
 
-def test_exported_kv_imported_by_the_engine_decodes_to_the_reference():
-    """A prompt's KV exported by ``PrefillEngine`` (``read_pages`` of its
-    pages, shipped as ``[L, T, H, Dh]`` rows) and imported by the engine
-    (``write_pages``) decodes to the full forward's greedy tokens: the
-    wire's form is the same whatever shape either side stores its pool
-    in."""
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
-    from _llm_reference import engine_reference
-
-    from ray_tpu.serve.kv_transfer import PrefillEngine
-    from ray_tpu.serve.llm import LLMConfig, LLMServer
-
-    rng = np.random.RandomState(35)
-    prompt = [int(t) for t in rng.randint(0, 256, 75)]
-    pre = PrefillEngine(LLMConfig(model_id="gpt2-tiny"))
-    try:
-        ship = pre.prefill(prompt, 0.0)
-    finally:
-        pre._pool.close()
-    mcfg = pre.model_cfg
-    assert ship["k"].shape == (mcfg.n_layer, 75, mcfg.n_head, mcfg.head_dim)
-    assert ship["v"].shape == ship["k"].shape
-    srv = LLMServer(LLMConfig(model_id="gpt2-tiny", max_batch_size=2))
-    try:
-        ref = engine_reference(srv, prompt, 10)
-        assert ship["first_token"] == ref[0]
-        c0 = srv._prefix_pool.stats()["copies"]
-        out = srv({"prompt_tokens": prompt, "max_new_tokens": 10,
-                   "temperature": 0.0, "kv_import": dict(ship)})
-        assert srv._prefix_pool.stats()["copies"] > c0  # it was imported
-        assert out["tokens"] == ref
-    finally:
-        srv._stop.set()
-
-
 def test_every_prefill_width_is_a_power_of_two(monkeypatch):
     """A tail deep in a context (80 tokens cached of 128, 40 to prefill:
     the next power of two, 64, would pass the 48 positions left) is

@@ -123,19 +123,6 @@ def test_a_prefix_hit_is_refused_by_name_not_served_wrong(engine):
     assert engine.batch_stats()["prefix"]["prefix_resident"] == 0
 
 
-def test_kv_import_and_the_prefill_tier_refuse_the_family_by_name(engine):
-    with pytest.raises(RuntimeError, match="takes no KV import"):
-        engine({"prompt_tokens": [1, 2, 3], "max_new_tokens": 4,
-                "kv_import": {"prompt_len": 3, "first_token": 1, "k": None, "v": None}})
-    from ray_tpu.serve import kv_transfer
-    from ray_tpu.serve.llm import LLMConfig
-
-    with pytest.raises(RuntimeError, match="mimo-v2-tiny.*KV transfer"):
-        kv_transfer.PrefillEngine(LLMConfig(model_id="mimo-v2-tiny"))
-    # and the engine still serves
-    assert len(engine({"prompt_tokens": [5], "max_new_tokens": 3})["tokens"]) == 3
-
-
 def test_a_gpt2_engine_never_imports_the_family():
     code = (
         "import sys, jax; jax.config.update('jax_platforms', 'cpu')\n"
@@ -144,7 +131,12 @@ def test_a_gpt2_engine_never_imports_the_family():
         "assert len(srv({'prompt_tokens': [1, 2, 3], 'max_new_tokens': 4})['tokens']) == 4\n"
         "loaded = [m for m in sys.modules if 'mimo' in m or m == 'ray_tpu.ops.moe']\n"
         "assert not loaded, loaded\n"
-        "srv.unload(); print('clean')\n"
+        "srv.unload()\n"
+        # unload() stops the engine thread and does not wait for it; the
+        # interpreter must not finalize with that thread inside a JAX call
+        "import threading\n"
+        "[t.join(60) for t in threading.enumerate() if t.name == 'llm-engine']\n"
+        "print('clean')\n"
     )
     env = {**os.environ, "JAX_PLATFORMS": "cpu", "PYTHONPATH": ROOT}
     proc = subprocess.run([sys.executable, "-c", code], env=env, cwd=ROOT,

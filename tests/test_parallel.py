@@ -134,7 +134,7 @@ def test_mlp(cpu_mesh_devices):
 
 
 def test_fused_ce_matches_reference():
-    """loss_impl="fused" (custom-vjp CE head, PROFILE.md) must match the
+    """loss_impl="fused" (custom-vjp CE head) must match the
     unchunked reference loss and gradients."""
     import dataclasses
 

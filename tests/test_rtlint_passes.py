@@ -205,12 +205,11 @@ def test_dispatcher_block_periodic_maintenance_is_clean():
 
 
 def test_resource_leak_flags_unclosed_channel():
-    # the exact shape of the send_kv leak fixed alongside this pass
     live, _ = _run("""
-        def send_kv(handle, shipment, timeout_s):
+        def send(handle, value, timeout_s):
             chan = channels.open_channel(handle, "write")
-            chan.write_value(shipment, timeout_s=timeout_s)
-    """, "resource-leak", "ray_tpu/serve/kv_transfer.py")
+            chan.write_value(value, timeout_s=timeout_s)
+    """, "resource-leak", "ray_tpu/x.py")
     assert len(live) == 1
     assert "never reaches close" in live[0].message
 
@@ -301,3 +300,45 @@ def test_config_hygiene_clean_negative():
             return home, chips, config.num_tpus
     """, "config-hygiene", "ray_tpu/x.py")
     assert not live, [f.format() for f in live]
+
+
+def _project_findings(tmp_path, config_src: str, reader_src: str):
+    """config-hygiene's project rules over a tree of three files: a
+    utils/config.py, one module that may read it, a README naming RT_A
+    and RT_B."""
+    from tools.rtlint.passes.config_hygiene import PASS
+
+    pkg = tmp_path / "ray_tpu"
+    (pkg / "utils").mkdir(parents=True)
+    (pkg / "utils" / "config.py").write_text(textwrap.dedent(config_src))
+    (pkg / "reader.py").write_text(textwrap.dedent(reader_src))
+    (tmp_path / "README.md").write_text("| `RT_A` | | `RT_B` |\n")
+    return PASS.project_check(str(tmp_path))
+
+
+def test_config_hygiene_flags_a_flag_nothing_reads(tmp_path):
+    # its own define, a set and a mention in a comment are not reads
+    findings = _project_findings(tmp_path, """
+        config.define("a", 1)
+        config.define("b", 2)
+    """, """
+        def f(config):
+            # b is the other one
+            return config.a
+    """)
+    assert [(f.line, f.suppressed) for f in findings] == [(3, False)]
+    assert "'b'" in findings[0].message and "read by no file" in findings[0].message
+
+
+def test_config_hygiene_read_flags_are_clean_and_unread_is_suppressible(tmp_path):
+    findings = _project_findings(tmp_path, """
+        config.define("a", 1)
+        config.define("b", 2)
+        config.define("c", 3)  # rtlint: ignore[config-hygiene] read by the native core, not by Python
+    """, """
+        def f(config):
+            return config.a, config.get("b")
+    """)
+    # c is neither documented nor read: both findings carry the reason
+    assert [f.line for f in findings] == [4, 4]
+    assert all(f.suppressed and f.reason for f in findings)

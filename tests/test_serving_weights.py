@@ -126,24 +126,10 @@ def test_a_started_engine_holds_gpt2_init_in_its_compute_type(jax_cpu, tiny):
         srv.unload()
 
 
-def test_a_prefill_engine_holds_the_same_tree(jax_cpu, tiny):
-    from ray_tpu.serve.kv_transfer import PrefillEngine
-    from ray_tpu.serve.llm import LLMConfig
-
-    cfg, init = tiny
-    pre = PrefillEngine(LLMConfig(model_id="gpt2-tiny"))
-    try:
-        _assert_is_init_cast_leaf_by_leaf(jax_cpu, cfg, init, pre.params)
-    finally:
-        pre._pool.close()
-
-
-@pytest.mark.parametrize("engine", ["LLMServer", "PrefillEngine"])
 def test_the_checkpoint_branch_casts_a_pickled_float32_tree(
-        jax_cpu, tiny, tmp_path, engine):
+        jax_cpu, tiny, tmp_path):
     """A pickled float32 tree (NumPy leaves, other values than
     ``gpt2.init``'s) is held as the init branch holds its own."""
-    from ray_tpu.serve.kv_transfer import PrefillEngine
     from ray_tpu.serve.llm import LLMConfig, LLMServer
 
     jax = jax_cpu
@@ -154,16 +140,11 @@ def test_the_checkpoint_branch_casts_a_pickled_float32_tree(
     path.write_bytes(pickle.dumps(tree))
     config = LLMConfig(model_id="gpt2-tiny", max_batch_size=2,
                        checkpoint_path=str(path))
-    if engine == "LLMServer":
-        srv = LLMServer(config)
-        try:
-            params = srv.params
-        finally:
-            srv.unload()
-    else:
-        pre = PrefillEngine(config)
-        params = pre.params
-        pre._pool.close()
+    srv = LLMServer(config)
+    try:
+        params = srv.params
+    finally:
+        srv.unload()
     as_jax = jax.tree.map(jax.numpy.asarray, tree)
     _assert_is_init_cast_leaf_by_leaf(jax, cfg, as_jax, params)
 

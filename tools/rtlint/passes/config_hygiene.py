@@ -1,5 +1,5 @@
 """config-hygiene pass: every RT_* env read goes through utils/config,
-and every registered flag is documented in README.
+and every registered flag is documented in README and read somewhere.
 
 ``ray_tpu/utils/config.py`` is the single place RT_* environment
 variables become configuration: ``config.define(name, default)``
@@ -15,9 +15,13 @@ the key a string literal or a module-level constant — outside
 ``utils/config.py`` is a violation.  Writes (``os.environ[k] = v``) are
 the runtime-env apply path and are not flagged.
 
-Project rule (uncached, anchored at the ``define`` line in
+Project rules (uncached, anchored at the ``define`` line in
 utils/config.py): every registered flag's ``RT_<NAME>`` must appear in
-README.md.  Suppress either with the usual ignore comment naming
+README.md, and every registered flag must be read by some file under
+``ray_tpu/`` other than utils/config.py (``config.<name>``, or its name
+as a string handed to ``config.get`` / ``getattr``): a flag nothing
+reads is a documented promise nothing keeps.  Suppress any with the
+usual ignore comment naming
 ``config-hygiene`` plus a reason (e.g. the worker/node/head boot
 protocol, which must read ``RT_CONFIG_SNAPSHOT`` before any config
 exists).
@@ -27,12 +31,13 @@ from __future__ import annotations
 
 import ast
 import os
-from typing import List, Optional, Tuple
+from typing import List, Optional, Set, Tuple
 
 from tools.rtlint.engine import (
     FileContext,
     Finding,
     LintPass,
+    _iter_py_files,
     parse_suppressions,
 )
 
@@ -135,11 +140,35 @@ def registered_flags(config_src: str) -> List[Tuple[int, str]]:
     return out
 
 
+def names_used(root: str) -> Set[str]:
+    """Every attribute name and every whole string constant of the files
+    under ``ray_tpu/`` but utils/config.py: what a flag's name must be
+    among to count as read."""
+    used: Set[str] = set()
+    for rel in _iter_py_files(root, ["ray_tpu"]):
+        if rel == CONFIG_RELPATH:
+            continue
+        try:
+            with open(os.path.join(root, rel)) as f:
+                tree = ast.parse(f.read())
+        except (OSError, SyntaxError):
+            continue  # the per-file passes report what does not parse
+        for node in ast.walk(tree):
+            if isinstance(node, ast.Attribute):
+                used.add(node.attr)
+            elif isinstance(node, ast.Constant) and isinstance(
+                node.value, str
+            ):
+                used.add(node.value)
+    return used
+
+
 class ConfigHygienePass(LintPass):
     id = "config-hygiene"
     title = "config hygiene"
     doc = ("RT_* env reads must go through utils/config registration; "
-           "every registered flag must be documented in README")
+           "every registered flag must be documented in README and read "
+           "under ray_tpu/")
 
     def select(self, relpath: str) -> bool:
         parts = relpath.split(os.sep)
@@ -149,8 +178,9 @@ class ConfigHygienePass(LintPass):
         return scan(ctx.tree, ctx.module_constants)
 
     def project_check(self, root: str) -> List[Finding]:
-        """Registered-flag ↔ README cross-check.  Runs uncached; honors
-        ``# rtlint: ignore[config-hygiene]`` on the define line."""
+        """Registered-flag ↔ README and ↔ reader cross-checks.  Runs
+        uncached; honors ``# rtlint: ignore[config-hygiene]`` on the
+        define line."""
         config_path = os.path.join(root, CONFIG_RELPATH)
         readme_path = os.path.join(root, "README.md")
         try:
@@ -164,25 +194,33 @@ class ConfigHygienePass(LintPass):
         except OSError:
             readme = ""
         sups = parse_suppressions(config_src.splitlines())
+        used = names_used(root)
         out: List[Finding] = []
         for lineno, name in registered_flags(config_src):
             env = ENV_PREFIX + name.upper()
-            if env in readme:
-                continue
-            finding = Finding(
-                file=CONFIG_RELPATH,
-                line=lineno,
-                pass_id=self.id,
-                message=(
+            messages = []
+            if env not in readme:
+                messages.append(
                     f"flag {name!r} ({env}) is not documented in "
                     f"README.md — add it to the configuration table"
-                ),
-            )
-            sup = sups.get(lineno)
-            if sup and self.id in sup.pass_ids and sup.reason:
-                finding.suppressed = True
-                finding.reason = sup.reason
-            out.append(finding)
+                )
+            if name not in used:
+                messages.append(
+                    f"flag {name!r} ({env}) is read by no file under "
+                    f"ray_tpu/ — delete it, or the code that was to read it"
+                )
+            for message in messages:
+                finding = Finding(
+                    file=CONFIG_RELPATH,
+                    line=lineno,
+                    pass_id=self.id,
+                    message=message,
+                )
+                sup = sups.get(lineno)
+                if sup and self.id in sup.pass_ids and sup.reason:
+                    finding.suppressed = True
+                    finding.reason = sup.reason
+                out.append(finding)
         return out
 
 

@@ -61,14 +61,8 @@ HOT_PATHS = (
     # activations move via channel writes — see CHANNEL_SEND_PATHS
     os.path.join("ray_tpu", "dag.py"),
     os.path.join("ray_tpu", "parallel", "pipeline.py"),
-    # disaggregated prefill→decode KV handoff: multi-MB KV rows per
-    # request must ride write_value's scatter-gather frames, never a
-    # packed in-band blob. With the paged pool the shipment is a device
-    # gather of whole pages — still ndarrays end to end.
-    os.path.join("ray_tpu", "serve", "kv_transfer.py"),
-    # paged KV engine: the decode-side page import/export path moves
-    # whole KV pages (multi-MB ndarrays) between the prefill tier and
-    # the pool; any send added here must pass the arrays themselves (or
+    # paged KV engine: what it holds is whole KV pages (multi-MB
+    # ndarrays); any send added here must pass the arrays themselves (or
     # Frame-wrapped packs), never pack(...) output in-band
     os.path.join("ray_tpu", "serve", "llm.py"),
 )
@@ -84,7 +78,6 @@ CHANNEL_SEND_METHODS = {"write"}
 CHANNEL_SEND_PATHS = (
     os.path.join("ray_tpu", "dag.py"),
     os.path.join("ray_tpu", "parallel", "pipeline.py"),
-    os.path.join("ray_tpu", "serve", "kv_transfer.py"),
 )
 
 
