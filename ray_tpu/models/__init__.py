@@ -3,7 +3,8 @@
 A served model is a config and a decode module, found by ``model_id``
 (``resolve``). The decode module is the engine's interface, the same
 names whichever model implements them (``models/gpt2_decode.py``,
-``models/mimo_v2.py`` and ``models/deepseek_v3.py`` do):
+``models/mimo_v2.py``, ``models/deepseek_v3.py`` and ``models/afmoe.py``
+do):
 
     load_serving_params(cfg, checkpoint_path)   the stored weights
     params_bytes(params)
@@ -46,6 +47,7 @@ FAMILIES = {
     "gpt2": ("ray_tpu.models.gpt2", "ray_tpu.models.gpt2_decode"),
     "mimo-v2": ("ray_tpu.models.mimo_v2", "ray_tpu.models.mimo_v2"),
     "kanana-2": ("ray_tpu.models.deepseek_v3", "ray_tpu.models.deepseek_v3"),
+    "trinity": ("ray_tpu.models.afmoe", "ray_tpu.models.afmoe"),
 }
 
 

@@ -53,7 +53,13 @@ from jax import lax
 from ray_tpu.models.gpt2_decode import (  # noqa: F401 — the engine's interface
     params_bytes, sample, update_rows_paged,
 )
-from ray_tpu.ops import moe, page_loops
+from ray_tpu.ops import cached_attention, moe, page_loops
+# attention over pages and rings, shared with the other family that has both
+# kinds of layer (models/afmoe.py)
+from ray_tpu.ops.cached_attention import (  # noqa: F401
+    LayerCache, paged_attend as _paged_attend, ring_positions as _ring_positions,
+    window_attend as _window_attend,
+)
 
 PREFIX_CACHE = False   # a hit would have to restore the window layers' rings
 DECODE_ATTENTION = "own_pages_and_rings"
@@ -228,49 +234,18 @@ def cache_spec(cfg: MiMoV2Config) -> List[Dict[str, Any]]:
              "v_size": cfg.v_head_dim} for l in range(cfg.n_layer)]
 
 
-@partial(jax.tree_util.register_dataclass, data_fields=["layers"],
-         meta_fields=["page_tokens"])
-@dataclasses.dataclass(frozen=True)
-class LayerCache:
-    """K or V of every layer, an array a layer by ``cache_spec``: a full
-    layer's ``[pages, B, kv_heads * size]``, a window layer's ``[rows,
-    sliding_window, kv_heads * size]``; and B beside them. The heads stay
-    merged in the last dimension at rest: split into ``[.., kv_heads,
-    192]`` the tiling pads 4 heads to 8 and 192 to 256, and every program
-    relaid the whole pool on the way in (0.49 s of a traced 4 s; PERF.md,
-    PR 46), and they stay merged through decode's attention too
-    (``_products``): a gathered span of pages is as costly to split."""
-
-    layers: Tuple[jax.Array, ...]
-    page_tokens: int
-
-
 def init_paged_cache(cfg: MiMoV2Config, num_pages: int, page_tokens: int,
                      rows: int = 1):
     """(k, v) caches, zeroed, for ``rows`` decode rows over ``num_pages``
-    pages: the one place that decides the stored shapes."""
-    def make(size_key):
-        return LayerCache(tuple(
-            jnp.zeros(
-                (rows, cfg.sliding_window, s["kv_heads"] * s[size_key])
-                if s["kind"] == "window"
-                else (num_pages, page_tokens, s["kv_heads"] * s[size_key]),
-                cfg.dtype)
-            for s in cache_spec(cfg)), page_tokens)
-
-    return make("k_size"), make("v_size")
+    pages (``ops.cached_attention.init_caches`` decides the stored shapes)."""
+    return cached_attention.init_caches(cache_spec(cfg), cfg.sliding_window, num_pages,
+                                        page_tokens, rows, cfg.dtype)
 
 
 def cache_layout(cfg: MiMoV2Config, cache_k: LayerCache, cache_v: LayerCache) -> Dict[str, Any]:
     """The stored shape of every layer's K and the bytes both caches hold
-    on the device, by kind (``batch_stats()["kv_pool_shape"]`` and the
-    ``rt_serve_kv_*_bytes`` gauges)."""
-    spec = cache_spec(cfg)
-    held = {"full": 0, "window": 0}
-    for s, k, v in zip(spec, cache_k.layers, cache_v.layers):
-        held[s["kind"]] += k.on_device_size_in_bytes() + v.on_device_size_in_bytes()
-    return {"shape": [[s["kind"], *k.shape] for s, k in zip(spec, cache_k.layers)],
-            "bytes": held}
+    on the device, by kind."""
+    return cached_attention.layout(cache_spec(cfg), cache_k, cache_v)
 
 
 # -- the block ------------------------------------------------------------
@@ -311,151 +286,6 @@ def _qkv(cfg: MiMoV2Config, l: int, attn, h, pos):
     k = _rope(k, pos, cfg.rotary_dim, theta)
     v = (v.astype(jnp.float32) * cfg.attention_value_scale).astype(dt)
     return q, k.reshape(T, -1), v.reshape(T, -1)
-
-
-def _products(q, kv_heads: int):
-    """The two products of attention for queries ``q`` [R, Q, H, Dk], as
-    functions of K and V with the heads merged in the minor dimension, as
-    the caches store them: ``scores(k [R, T, Hkv * Dk])`` -> [R, H, Q, T]
-    float32 and ``weighted(p [R, H, Q, T], v [R, T, Hkv * Dv])`` -> [R, H,
-    Q, Dv] float32; query head h reads K/V head ``h // (H / Hkv)``.
-
-    Split into ``[R, T, Hkv, size]`` a gathered span of pages or a ring is
-    relaid whole (4 or 8 heads are no multiple of 8 sublanes, 192 none of
-    128 lanes: every K and V byte a decode step reads written three more
-    times, a fifth of the step; PERF.md, PR 47), so the split is made on
-    the side that is small, and which side that is the queries a row say.
-    One query a row is small beside K and V: each head is spread once over
-    all Hkv * Dk columns, its own numbers in its K/V head's columns and
-    exact zeros in the others, which add exact zeros to a float32 sum; a
-    K/V head's values are a slice of columns (whole lanes at Dv 128), its
-    query heads' probabilities a slice of rows, a product a K/V head. A
-    chunk of queries is not small: Hkv times the operations would show (2
-    to 4 ms of a 512-wide prefill call's 24), and its few keys and values
-    are split."""
-    R, Q, H, Dk = q.shape
-    G = H // kv_heads
-    qg = q.reshape(R, Q, kv_heads, G, Dk)
-    scale = Dk ** -0.5
-    if Q == 1:
-        own = jnp.eye(kv_heads, dtype=q.dtype)[:, None, :, None]
-        spread = (qg[..., None, :] * own).reshape(R, Q, H, kv_heads * Dk)
-        # left to itself the compiler sinks the spreading into a loop over
-        # pages and makes the 12.6 MB anew every turn (0.7 ms a step)
-        spread = lax.optimization_barrier(spread)
-
-        def scores(k):
-            return scale * jnp.einsum("rqhc,rtc->rhqt", spread, k,
-                                      preferred_element_type=jnp.float32)
-
-        def weighted(p, v):
-            Dv = v.shape[2] // kv_heads
-            return jnp.concatenate([
-                jnp.einsum("rgqt,rtv->rgqv", p[:, j * G:(j + 1) * G],
-                           v[:, :, j * Dv:(j + 1) * Dv],
-                           preferred_element_type=jnp.float32)
-                for j in range(kv_heads)], axis=1)
-    else:
-        def scores(k):
-            return scale * jnp.einsum(
-                "rqjgd,rtjd->rjgqt", qg, k.reshape(R, -1, kv_heads, Dk),
-                preferred_element_type=jnp.float32).reshape(R, H, Q, -1)
-
-        def weighted(p, v):
-            T = v.shape[1]
-            return jnp.einsum(
-                "rjgqt,rtjv->rjgqv", p.reshape(R, kv_heads, G, Q, T),
-                v.reshape(R, T, kv_heads, -1),
-                preferred_element_type=jnp.float32).reshape(R, H, Q, -1)
-
-    return scores, weighted
-
-
-def _softmax_update(carry, scores, values, visible, weighted):
-    """One block of an online softmax: ``scores`` [R, H, Q, T] float32 of
-    ``_products``, ``visible`` broadcastable to them, ``values`` [R, T, Hkv
-    * Dv] for ``weighted`` of the same ``_products``."""
-    m, den, acc = carry
-    scores = jnp.where(visible, scores, -1e30)
-    m_new = jnp.maximum(m, scores.max(-1))
-    scale = jnp.exp(m - m_new)
-    p = jnp.where(visible, jnp.exp(scores - m_new[..., None]), 0.0)
-    acc = acc * scale[..., None] + weighted(p.astype(values.dtype), values)
-    return m_new, den * scale + p.sum(-1), acc
-
-
-def _finish(carry, sink=None):
-    """The softmax's quotient, [R, H, Q, Dv] -> [R, Q, H * Dv]. A ``sink``
-    [H], one learned logit a head, joins the denominator and nothing else."""
-    m, den, acc = carry
-    if sink is not None:
-        s = sink.astype(jnp.float32)[None, :, None]
-        m_new = jnp.maximum(m, s)
-        scale = jnp.exp(m - m_new)
-        den, acc = den * scale + jnp.exp(s - m_new), acc * scale[..., None]
-    out = acc / den[..., None]
-    R, H, Q, Dv = out.shape
-    return out.transpose(0, 2, 1, 3).reshape(R, Q, H * Dv)
-
-
-def _start(R, H, Q, Dv):
-    return (jnp.full((R, H, Q), -1e30, jnp.float32),
-            jnp.zeros((R, H, Q), jnp.float32),
-            jnp.zeros((R, H, Q, Dv), jnp.float32))
-
-
-def _paged_attend(q, k_pool, v_pool, tables, q_pos, kv_heads: int,
-                  loops: page_loops.Loops):
-    """Causal attention of ``q`` [R, Q, H, Dk] at positions ``q_pos`` [R, Q]
-    over each row's own pages of a full layer (``tables`` [R, MaxPages]):
-    ``loops`` (of the rows' last positions) over page-table columns, a few
-    a turn, each stopping behind the last position any query of its own
-    rows sees, so a step reads the live context and neither the table's
-    width nor the pool. The gathered pages go into the products as they
-    lie. Returns [R, Q, H * Dv]."""
-    B = k_pool.shape[1]
-    _, Q, H, _ = q.shape
-    span, C = loops.span, loops.span // B
-
-    def make_turn(own):
-        q, table, at = own  # [n, Q, H, Dk], [n, MaxPages], [n, Q]
-        n = at.shape[0]
-        scores, weighted = _products(q, kv_heads)
-
-        def turn(j, carry):
-            pages = lax.dynamic_slice_in_dim(table, j * C, C, axis=1)  # [n, C]
-            kc = k_pool[pages].reshape(n, span, -1)
-            vc = v_pool[pages].reshape(n, span, -1)
-            kv_pos = j * span + jnp.arange(span)
-            visible = kv_pos[None, None, :] <= at[:, :, None]  # [n, Q, T]
-            return _softmax_update(carry, scores(kc), vc, visible[:, None],
-                                   weighted)
-
-        return turn
-
-    return page_loops.run(
-        loops, (q, tables, q_pos), make_turn,
-        lambda n: _start(n, H, Q, v_pool.shape[2] // kv_heads), _finish)
-
-
-def _window_attend(q, keys, values, visible, kv_heads: int, sink):
-    """Attention of ``q`` [R, Q, H, Dk] over a window layer's keys and values
-    as they lie, [R, T, Hkv * size] (a ring, or a ring and the chunk behind
-    it), where ``visible`` [R, Q, T], the layer's ``sink`` [H] in the
-    denominator. Returns [R, Q, H * Dv]."""
-    R, Q, H, _ = q.shape
-    scores, weighted = _products(q, kv_heads)
-    carry = _softmax_update(_start(R, H, Q, values.shape[2] // kv_heads),
-                            scores(keys), values, visible[:, None], weighted)
-    return _finish(carry, sink)
-
-
-def _ring_positions(upto, size: int):
-    """The position each slot of a ring holds once positions 0 .. ``upto``
-    - 1 are written: for slot r the largest p < ``upto`` with p % size ==
-    r, negative where there is none. ``upto`` [...] -> [..., size]."""
-    last = upto[..., None] - 1
-    return last - (last - jnp.arange(size)) % size
 
 
 def _ffn(cfg: MiMoV2Config, layer, h, live):

@@ -348,6 +348,14 @@ def _build() -> dict:
             "the live rows attended over is the useful part of it",
             tag_keys=("deployment",),
         ),
+        "serve_window_context_tokens": Counter(
+            "rt_serve_window_context_tokens_total",
+            "positions the live rows of decode steps attended over in the "
+            "window-attention layers, min(position + 1, window) a row, "
+            "summed over rows and steps (once a step, not a layer); counted "
+            "on the device beside the sampled tokens",
+            tag_keys=("deployment",),
+        ),
         "serve_multiplex_loads": Counter(
             "rt_serve_multiplex_loads_total",
             "per-model multiplex loads (cold model pulled into a replica)",

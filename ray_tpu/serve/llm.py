@@ -270,7 +270,7 @@ class LLMServer:
         self._batch_sizes = collections.deque(maxlen=1000)
         self._total_batches = 0
         self._max_batch_seen = 0
-        self._occupied = 0  # decode rows live after the last engine round
+        self._occupied = 0  # decode rows held after the last engine round
         # per-process gauge label (the cluster merge keeps the latest
         # value PER SERIES; distinct tags keep every engine process)
         self._node_tag = f"pid{os.getpid()}"
@@ -1199,7 +1199,12 @@ class LLMServer:
                 i for i in range(S)
                 if seqs[i] is not None and seqs[i].active
             ]
-            self._occupied = len(active)
+            # rows held, live or still prefilling: a sequence whose client
+            # has gone is found out at its first token, so a queue of such
+            # prompts keeps rows held with none live for as long as they
+            # prefill, and a caller that waits for an idle engine must not
+            # take that for idle
+            self._occupied = sum(s is not None for s in seqs)
             if not active:
                 if inflight is not None:
                     # drain the lookahead before idling: its tokens are

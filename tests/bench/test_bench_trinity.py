@@ -1,0 +1,338 @@
+"""The cell ``trinity-mixed-lengths``: its configuration against the catalog
+row, its arithmetic, its traffic, the metrics PR 53 brought through their
+readers, and the whole command rehearsed on the CPU at the tiny twin."""
+
+import json
+import os
+from types import SimpleNamespace
+
+import bench_rehearsal_file
+import pytest
+from test_bench_engine_metrics import snap, through_its_reader
+from test_bench_rehearsal import rehearse, run
+
+from benchmark import harness, traffic
+from benchmark import trace as trace_mod
+from benchmark.readers import moe_roofline, window_roofline
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(os.path.dirname(HERE))
+CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
+NEW = ["window_context_share", "window_attn_roofline"]
+CELL = "trinity-mixed-lengths"
+CUT = ["layer_types", "max_position_embeddings", "num_dense_layers", "num_hidden_layers"]
+
+
+def load(path):
+    with open(os.path.join(ROOT, path)) as f:
+        return json.load(f)
+
+
+@pytest.fixture(scope="module")
+def cfg():
+    return load("benchmark/configs/trinity-mini-serve.json")
+
+
+def test_published_is_the_catalog_row_and_depth_alone_is_cut(cfg):
+    model, published = cfg["model"], cfg["published"]
+    cut = [k for k in model if model[k] != published[k]]
+    assert cut == cfg["reduced"] == CUT
+    assert (published["num_hidden_layers"], model["num_hidden_layers"]) == (32, 5)
+    assert (published["num_dense_layers"], model["num_dense_layers"]) == (2, 1)
+    assert model["layer_types"] == ["sliding_attention"] * 4 + ["full_attention"]
+    assert published["layer_types"] == (["sliding_attention"] * 3 + ["full_attention"]) * 8
+    assert all(cfg[k] == model[k] for k in model)  # the top level says what runs
+    kept = {"hidden_size": 2048, "num_attention_heads": 32, "num_key_value_heads": 4,
+            "head_dim": 128, "sliding_window": 2048, "intermediate_size": 6144,
+            "moe_intermediate_size": 1024, "num_experts": 128, "num_experts_per_tok": 8,
+            "num_shared_experts": 1, "route_scale": 2.826, "vocab_size": 200192,
+            "rope_theta": 10000, "rms_norm_eps": 1e-05, "mup_enabled": True}
+    for key, value in kept.items():
+        assert model[key] == published[key] == value, key
+    assert cfg["held"]["experts"] == [0, 128] and cfg["held"]["vocab_rows"] == [0, 200192]
+    assert "pipeline" in cfg["deployment"] and len(cfg["assumed"]) >= 10
+    if not os.path.exists(CATALOG):
+        pytest.skip("no catalog of architectures on this machine")
+    with open(CATALOG) as f:
+        row = next(r for r in map(json.loads, f) if r["source_url"] == cfg["source"])
+    assert row["name"] == "Trinity-Mini"
+    assert published == row["config"] and list(published) == list(row["config"])
+
+
+def test_the_program_runs_the_models_sizes_and_refuses_another_models(cfg):
+    from benchmark.families import afmoe as family
+
+    assert family.program_sizes(cfg["model_id"]) == cfg["model"]
+    tiny = load("tests/bench/configs/trinity-tiny-serve.json")
+    assert family.program_sizes(tiny["model_id"]) == tiny["model"]
+    assert list(tiny["model"]) == list(cfg["model"])
+    # what the program has no switch for stands as the source says it
+    for key, value in family.IMPLEMENTS.items():
+        assert cfg["published"][key] == value, key
+    assert (family.IMPLEMENTS["n_group"], family.IMPLEMENTS["num_expert_groups"],
+            family.IMPLEMENTS["topk_group"], family.IMPLEMENTS["rope_scaling"],
+            family.IMPLEMENTS["tie_word_embeddings"], family.IMPLEMENTS["score_func"]) == (
+        1, 1, 1, None, False, "sigmoid")
+
+
+@pytest.mark.parametrize("model_id", ["trinity-mini", "trinity-tiny"])
+def test_params_count_is_the_parameter_trees_size(model_id):
+    import jax
+
+    from benchmark.families import afmoe as family
+    from ray_tpu.models import afmoe
+
+    tree = jax.eval_shape(lambda: afmoe.load_serving_params(afmoe.CONFIGS[model_id]))
+    held = sum(a.size for a in jax.tree.leaves(tree))
+    assert family.params_count(family.program_sizes(model_id)) == held
+
+
+def test_the_cut_is_the_arithmetic_the_configuration_states(cfg):
+    from benchmark.families import afmoe as family
+
+    model = cfg["model"]
+    # q, output and gate 2048 x 4096 each, k and v 2048 x 512, two norms of 128
+    assert family.attention_params(model) == 3 * 2048 * 4096 + 2 * 2048 * 512 + 256
+    assert family.attention_params(model) == pytest.approx(27.26e6, rel=1e-3)
+    assert family.expert_params(model) == 3 * 2048 * 1024
+    assert family.params_count(model) == pytest.approx(4241.5e6, rel=1e-4)
+    assert "4,241.5 M" in cfg["memory"]["parameters"]
+    assert family.position_bytes(model) == 2048
+    # the full layer's pool: max_batch_size x the context's pages + the scratch page
+    pages = cfg["engine"]["max_batch_size"] * (model["max_position_embeddings"] // 64) + 1
+    assert pages == 8193 and "8,193 pages" in cfg["memory"]["pool"]
+    assert pages * 64 * 2048 == pytest.approx(1.07e9, rel=5e-3)
+    rows = 4 * cfg["engine"]["max_batch_size"]
+    assert rows * 2048 * 2048 * 4 == pytest.approx(2.15e9, rel=2e-3)
+    # a step at 128 rows: what lies outside the routed experts, every expert
+    # of four layers (all 128 are reached), and the cache the rows hold
+    outside = family.params_outside_experts(model)
+    assert outside == pytest.approx(610.3e6, rel=1e-3)
+    assert family.expected_experts_hit(model, 128) == pytest.approx(128.0, abs=0.05)
+    experts = 4 * 128 * 3 * 2048 * 1024
+    for context, seen in ((1000, 1000), (5000, 2048)):
+        cache = 128 * (4 * seen + context) * 2048
+        assert family.decode_step_bytes(model, 128, context) == pytest.approx(
+            2.0 * (outside + 128 * 2048 + experts) + cache, rel=5e-4)
+    assert family.decode_step_bytes(model, 128, 2700) == pytest.approx(10.52e9, rel=2e-3)
+    # past the window only the full layer grows
+    assert (family.decode_step_bytes(model, 128, 5000)
+            - family.decode_step_bytes(model, 128, 4000)) == 128 * 1000 * 2048
+    cost = family.window_cost(model, 128 * 2048)
+    assert cost == {"bytes": 128 * 2048 * 4 * 2048.0, "flops": 128 * 2048 * 4 * 32 * 2.0 * 256}
+    assert family.moe_cost(model, 128, 1024) == {"bytes": 2.0 * 128 * 3 * 2048 * 1024,
+                                                 "flops": 2.0 * 1024 * 3 * 2048 * 1024}
+
+
+def test_mixed_lengths_sizes_are_what_the_cell_says(cfg):
+    tr = load("benchmark/traffic/mixed-lengths.json")
+    assert (tr["users"], tr["system_prompt_tokens"], tr["max_turns"], tr["think_s"]) == (
+        160, 0, 1, 0)
+    assert (tr["endpoint"], tr["context_limit"], tr["session_pool"], tr["pool_seed"]) == (
+        "/v1/completions", 9216, 1024, 5301)
+    assert tr["turn_tokens"] == {"dist": "lognormal", "median": 1536, "sigma": 0.9,
+                                 "lo": 128, "hi": 8192}
+    assert tr["reply_tokens"] == {"dist": "uniform", "lo": 512, "hi": 1024}
+    assert 4 * cfg["engine"]["max_batch_size"] == 128 < tr["users"]  # a backlog from the start
+    assert cfg["engine"]["max_new_tokens_cap"] >= 1024
+    pool = traffic.session_pool(tr)
+    assert len(pool) == 1024 and all(len(script) == 1 for script in pool)
+    prompts = sorted(script[0]["prompt_tokens"] for script in pool)
+    assert 1400 < prompts[len(prompts) // 2] < 1700
+    assert 2000 < sum(prompts) / len(prompts) < 2400
+    over = lambda n: sum(p > n for p in prompts) / len(prompts)
+    assert 0.30 < over(2048) < 0.45 and 0.09 < over(4096) < 0.19 and 0.01 < over(8191) < 0.06
+    assert prompts[0] >= 128 and prompts[-1] == 8192
+    for script in pool:
+        turn = script[0]
+        assert 512 <= turn["reply_tokens"] <= 1024
+        assert turn["prompt_tokens"] + turn["reply_tokens"] <= 9216
+    assert tr["warm"]["decode_k"] == list(range(1, 9)) and tr["warm"]["seconds"] == 60
+    assert tr["warm"]["prefill_widths"] == [64, 128, 256, 512, 528, 544]
+    assert (tr["probe"], tr["trace_offset_s"], tr["trace_seconds"]) == (
+        {"prompt_tokens": 40, "max_tokens": 17}, 6, 4)
+
+
+@pytest.mark.parametrize("name", ["mixed-lengths", "tiny-mixed"])
+def test_the_new_mixes_are_reproducible_from_the_seed(name):
+    tr = load(("benchmark" if name == "mixed-lengths" else "tests/bench") + f"/traffic/{name}.json")
+    big = 3_000_000_019
+    a, b = traffic.plan(tr, big), traffic.plan(tr, big)
+    assert a == b and a != traffic.plan(tr, 11) and a["system"] is None
+    session = a["sessions"][0]
+    body = traffic.turn_request(tr, "m", a, session, 0)
+    assert len(body["prompt"].encode()) == session["script"][0]["prompt_tokens"]
+    assert body["max_tokens"] == session["script"][0]["reply_tokens"]
+
+
+COUNTED = {
+    "before": snap({"rt_serve_window_context_tokens_total": 3.0e6,
+                    "rt_serve_attn_context_tokens_total": 5.0e6}),
+    "after": snap({"rt_serve_window_context_tokens_total": 3.0e6 + 128 * 40 * 1600.0,
+                   "rt_serve_attn_context_tokens_total": 5.0e6 + 128 * 40 * 2500.0}),
+    "samples": [],
+}
+
+
+def test_window_context_share_reads_the_engines_series():
+    spec, got = through_its_reader("window_context_share", {"counters": COUNTED})
+    assert got == pytest.approx(64.0), spec
+    assert spec["reader"] == "counter_ratio"  # data only: no code of this PR reads it
+    for name in NEW:
+        entry = next(m for m in load("BENCHMARK.json")["per_layer"] if m["name"] == name)
+        spec = load(f"benchmark/metrics/{name}.json")
+        assert (entry["workloads"], entry["moves"], entry["unit"]) == ([CELL], "serve_tok_s", "%")
+        assert spec["unit"] == "%" and len(spec["reads"]) > 200
+
+
+@pytest.mark.parametrize("name", NEW)
+def test_a_program_without_the_series_reads_nothing(name):
+    """The parent's observations: counters that lack the window's series,
+    no trace directory. Nothing, and no exception."""
+    bare = {"counters": {"before": snap({"rt_serve_decode_steps_total": 1.0}),
+                         "after": snap({"rt_serve_decode_steps_total": 9.0}),
+                         "samples": [snap({"rt_serve_kv_pages_total": 97.0})]},
+            "trace_counters": {"before": {}, "after": {}, "seconds": 4.0},
+            "model": {"n_embd": 1600}, "trace_dir": None, "trace": None,
+            "device": {"kind": "TPU v5 lite"}}
+    for obs in (bare, {}, {"counters": None}):
+        _, got = through_its_reader(name, obs)
+        assert got is None
+
+
+def a_trace(rings=True):
+    """Two decode programs and a prefill; in each decode program loops over
+    ring blocks (the carry opens with the weighted sum) beside the full
+    layer's loop over pages, an expert layer's loop and the K-step loop."""
+    ring = "while (s32[],f32[32,32,1,128],..) 1in"
+    pages = "while (s32[],f32[32,32,1],..) 1in"
+    ops = [[ring, 1_000, 300_000],                                      # inside decode 1
+           ["fusion bf16[32,512,512] 2in", 2_000, 100_000],             # its body: not twice
+           [pages, 310_000, 80_000],                                    # the full layer's loop
+           ["while (s32[],f32[128,2048],..) 1in", 400_000, 100_000],    # an expert layer's loop
+           [ring, 600_000, 200_000],                                    # inside decode 1
+           ["while (s32[],f32[1,32,512],..) 1in", 2_100_000, 900_000],  # prefill's attention
+           [ring, 2_200_000, 50_000],                                   # inside the prefill
+           ["while (s32[],s32[128],..) 1in", 3_950_000, 900_000],       # the K-step loop
+           [ring, 4_000_000, 500_000]]                                  # inside decode 2
+    if not rings:
+        ops = [op for op in ops if op[0] != ring]
+    modules = [["jit_decode_paged_and_sample", 0, 1_000_000],
+               ["jit_prefill_paged", 2_000_000, 1_500_000],
+               ["jit_decode_multi_paged", 3_900_000, 1_000_000]]
+    return {"planes": [{"name": "/device:TPU:0", "lines": [
+        {"name": "XLA Ops", "events": ops}, {"name": "XLA Modules", "events": modules}]}]}
+
+
+def test_window_roofline_counts_the_ring_loops_inside_decode_programs_only(monkeypatch, cfg):
+    spec = load("benchmark/metrics/window_attn_roofline.json")
+    busy, window = moe_roofline.seconds_inside(a_trace(), spec["args"]["match"], spec["args"]["ops"])
+    assert busy == pytest.approx(1_000_000e-9)
+    assert window == pytest.approx(4_850_000e-9 - 1_000e-9)
+    # through the reader: 128 rows x 1,600 window positions a step, 40 steps a traced second
+    monkeypatch.setattr(trace_mod, "find_xplane", lambda d: "a.xplane.pb")
+    monkeypatch.setattr(trace_mod, "load_xplane", lambda p: a_trace())
+    held = 128 * 1600 * 40 * 4.0
+    obs = {"model": cfg["model"], "trace_dir": "somewhere", "device": {"kind": "TPU v5 lite"},
+           "trace_counters": {"before": snap({"rt_serve_window_context_tokens_total": 7.0}),
+                              "after": snap({"rt_serve_window_context_tokens_total": 7.0 + held}),
+                              "seconds": 4.0}}
+    ctx = SimpleNamespace(platform="tpu", family=harness.find(
+        load("BENCHMARK.json"), "families", "afmoe", ".py"))
+    got = window_roofline.read(obs, spec["args"], ctx)
+    # 2,048 B a position a window layer, four of them, at 819 GB/s, over the loops' share
+    assert got == pytest.approx(
+        100 * (128 * 1600 * 4 * 2048 * 40 / 819e9) / (busy / window), rel=1e-3)
+    assert 0 < got < 100
+    # a trace without ring operations (another family's, or a kernel in the loop's place)
+    monkeypatch.setattr(trace_mod, "load_xplane", lambda p: a_trace(rings=False))
+    assert window_roofline.read(obs, spec["args"], ctx) is None
+    monkeypatch.setattr(trace_mod, "load_xplane", lambda p: a_trace())
+    # a family that counts no such cost, and a program without the counter
+    assert window_roofline.read(obs, spec["args"], SimpleNamespace(platform="tpu")) is None
+    other = SimpleNamespace(platform="tpu", family=harness.find(
+        load("BENCHMARK.json"), "families", "mimo_v2", ".py"))
+    assert window_roofline.read(obs, spec["args"], other) is None
+    still = dict(obs, trace_counters=dict(obs["trace_counters"],
+                                          after=obs["trace_counters"]["before"]))
+    assert window_roofline.read(still, spec["args"], ctx) is None
+
+
+def test_the_pattern_is_the_ring_loops_name_and_no_other_loops():
+    """The name the chip's trace gives the ring loops is what
+    ``trace.short_op_name`` makes of their HLO line: the carry opens with
+    the weighted sum (``ops/cached_attention.paged_attend``, ``acc_first``)."""
+    import re
+
+    rx = re.compile(load("benchmark/metrics/window_attn_roofline.json")["args"]["ops"])
+    line = ("%while.27 = (s32[]{:T(128)}, f32[32,32,1,128]{3,1,0,2:T(8,128)S(1)}, "
+            "f32[32,32,1]{1,0,2:T(8,128)S(1)}, f32[32,32,1]{1,0,2:T(8,128)S(1)}, "
+            "bf16[512,512,512]{2,1,0:T(8,128)(2,1)}) while(%tuple.702), "
+            "condition=%wide.region_24.35, body=%wide.region_21.34.sunk")
+    assert rx.search(trace_mod.short_op_name(line))
+    for other in ("while (s32[],f32[32,32,1],..) 1in", "while (s32[],f32[128,2048],..) 1in",
+                  "while (s32[],f32[32,32],..) 1in", "while (s32[],s32[128],..) 1in",
+                  "while (s32[],f32[1,32,512],..) 1in", "fusion f32[32,32,1,128] 3in"):
+        assert not rx.search(other), other
+
+
+def test_the_cell_stands_on_the_lists_it_joined_and_on_no_pinned_one(cfg):
+    bench = load("BENCHMARK.json")
+    cell = next(w for w in bench["workloads"] if w["name"] == CELL)
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (
+        "trinity-mini-serve", "mixed-lengths", 1)
+    assert bench["workloads"][-1] == cell and len(cell["why"]) <= 200
+    on = {m["name"] for m in bench["per_layer"] if CELL in m.get("workloads", [])}
+    mimo = {m["name"] for m in bench["per_layer"] if "mimo-reason-decode" in m.get("workloads", [])}
+    # moe_load_skew's reader takes the held experts from a key this family's
+    # configuration does not have (n_routed_experts): it finds nothing to read
+    assert on == (mimo | set(NEW)) - {"moe_load_skew"}
+    # the lists a test of an earlier PR pins to one cell are left as they were
+    pinned = {"queue_wait_ms.decode", "page_wait_ms.decode", "engine_host_ms.decode",
+              "engine_blocked_ms.decode", "decode_step_counted_ms.decode", "prefill_ms.decode"}
+    assert not pinned & on
+    for m in bench["per_layer"] + bench["end_to_end"]:
+        if CELL in m.get("workloads", []):
+            assert m["workloads"][-1] == CELL, m["name"]  # appended, nothing else moved
+    serve = next(m for m in bench["end_to_end"] if m["name"] == "serve_tok_s")
+    assert CELL in serve["workloads"]
+    entry = next(c for c in bench["configs"] if c["name"] == cell["config"])
+    assert entry["reduced"] == CUT and entry["source"] == cfg["source"]
+    assert bench["configs"][-1] == entry
+
+
+@pytest.fixture(scope="module")
+def mixed(tmp_path_factory):
+    bench_file = bench_rehearsal_file.write(tmp_path_factory.mktemp("rehearsal-trinity"))
+    return rehearse(bench_file, "tiny-mixed", 1), bench_file
+
+
+def test_the_cell_rehearses_to_a_correct_line_with_its_counters_read(mixed):
+    (result, earlier), _ = mixed
+    assert result["correct"] is True and result["failed"] == 0 and result["attempted"] > 10
+    got = result["metrics"]
+    assert {"window_context_share", "kv_window_share", "attn_loop_useful_share",
+            "moe_tokens_per_expert", "moe_experts_hit", "prefill_rows_mean",
+            "batch_fill.decode", "kv_pages_used.decode", "compiles_in_window.decode",
+            "engine_load_s", "deploy_ready_s"} <= set(got)
+    # no device metric from a CPU run
+    assert not {"window_attn_roofline", "moe_roofline", "decode_roofline",
+                "decode_step_ms.decode", "hbm_used.decode", "device_idle.decode"} & set(got)
+    # prompts of ~30 and replies of 12-20 over a window of 16: most steps are past it
+    assert 20 < got["window_context_share"]["value"] < 90
+    # three rings of 16 positions a row beside one paged layer
+    assert 0 < got["kv_window_share"]["value"] < 100
+    assert 0 < got["moe_experts_hit"]["value"] <= 100
+    assert got["compiles_in_window.decode"]["value"] == 0.0
+    assert any("family afmoe" in line for line in earlier)
+    assert result["compared"]["decode_logit_gap"]["value"] <= result["compared"]["decode_logit_gap"]["limit"]
+
+
+def test_check_holds_the_tiny_twin_to_the_reference_through_its_family(mixed):
+    _, bench_file = mixed
+    proc = run(["--bench-file", bench_file, "--check", "trinity-tiny-serve", "--seed", "3000000019"])
+    assert proc.returncode == 0, proc.stdout[-2000:] + proc.stderr[-2000:]
+    out = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert out["ok"] is True and (out["rows"], out["decode_steps"]) == (3, 32)
+    assert 1e-4 < max(out["prefill_max_abs"], out["decode_max_abs"]) <= out["tolerance"]
+    assert out["decode_judged"] > out["tokens_compared"] / 4

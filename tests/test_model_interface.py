@@ -4,7 +4,7 @@
 has; ``serve/llm.py`` reads them at whatever round first needs one, so a
 family that lacks a name fails there, mid-traffic. Here each registered
 family is asked for all of them at once, at its tiny preset, on the CPU,
-with no engine thread and no program compiled. A fourth family adds its
+with no engine thread and no program compiled. A fifth family adds its
 tiny ``model_id`` to ``TINY`` and reads that docstring.
 """
 
@@ -14,7 +14,7 @@ import pytest
 
 from ray_tpu import models
 
-TINY = ("gpt2-tiny", "mimo-v2-tiny", "kanana-2-tiny")
+TINY = ("gpt2-tiny", "mimo-v2-tiny", "kanana-2-tiny", "trinity-tiny")
 
 
 def interface_names():

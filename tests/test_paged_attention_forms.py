@@ -574,6 +574,56 @@ def test_compiled_mimo_prefill_of_rows_copies_no_pool_and_no_ring(one_chip, time
                      f"(s32[],f32[1024,{cfg.hidden_size}],..)": sum(cfg.moe_layer_freq)}, loops
 
 
+def test_compiled_trinity_programs_copy_no_pool_and_no_ring(one_chip, time_limit):
+    """Trinity's decode step and its largest prefill call at the cell's
+    shapes (PR 53), beside 8.5 GB of weights, 2.15 GB of rings and 1.07 GB
+    of pool: no copy of the full layer's pool and none of a ring, neither
+    as it is stored ``[128, 2048, 512]`` nor as decode reads it in blocks
+    ``[512, 512, 512]`` (a ring is 0.27 GB for K alone: one copy a layer
+    would be a tenth of a step); decode holds four ring loops a sliding
+    layer, whose carry opens with the weighted sum (the name
+    ``window_attn_roofline`` matches), and four page loops in the full
+    layer, whose carry opens with the running maximum."""
+    from ray_tpu.models import afmoe as m
+
+    cfg, counted, loops, temps = largest_prefill_of_rows(
+        m, "trinity-mini", one_chip, aot.mimo_v2_family)
+    assert (m.PREFILL_ROWS[-1], m.PREFILL_ROW_WIDTHS[-1]) == (2, 512)
+    assert counted["whole-pool copies"] == 0 and counted["ring copies"] == 0, counted
+    assert temps < 0.5e9, temps
+    sliding = sum(cfg.sliding(l) for l in range(cfg.n_layer))
+    H, D = cfg.num_attention_heads, cfg.hidden_size
+    # a loop over the ring's blocks a sliding layer and one over the rows' pages
+    # in the full layer, both carrying [rows, heads, chunk]; an expert loop a layer
+    assert loops == {f"(s32[],f32[2,{H},512],..)": cfg.n_layer,
+                     f"(s32[],f32[1024,{D}],..)": cfg.n_layer - cfg.num_dense_layers}, loops
+
+    S, Bx = 128, 64
+    mp = cfg.n_positions // Bx
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    stored = jax.eval_shape(lambda: m.init_paged_cache(cfg, 32 * mp + 1, Bx, S))
+    compiled = m.decode_paged_and_sample.lower(
+        cfg, on_chip(jax.eval_shape(lambda: m.load_serving_params(cfg))),
+        sds((S,), jnp.int32), sds((S,), jnp.int32), *on_chip(stored), sds((S, mp), jnp.int32),
+        sds((S,), jnp.float32), sds((S,), jnp.bool_), sds((2,), jnp.uint32),
+        sds((), jnp.int32)).compile()
+    text = compiled.as_text()
+    ops = [ln for ln in text.splitlines() if re.match(r"\s*(ROOT )?%\S+ = ", ln)]
+    _, watch, _ = aot.mimo_v2_family(cfg, m, stored[0], None)
+    counted = {label: count(ops) for label, count in watch}
+    assert counted == {"whole-pool copies": 0, "ring copies": 0, "K/V split into heads": 0}, counted
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.3e9
+    assert aot.loops_of(text) == {
+        f"(s32[],f32[32,{H},1,{cfg.head_dim}],..)": 4 * sliding,
+        f"(s32[],f32[32,{H},1],..)": 4 * (cfg.n_layer - sliding),
+        f"(s32[],f32[128,{D}],..)": cfg.n_layer - cfg.num_dense_layers}
+
+
 @pytest.mark.parametrize(
     "shape", [(32, 1024, 12, 64), (4, 1024, 25, 64)], ids=["two-heads-a-block", "whole-row-of-25"]
 )

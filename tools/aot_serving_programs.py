@@ -26,6 +26,10 @@ anew in another layout):
   so that the heads split, which the decode programs hold none of since
   PR 47 and prefill, whose keys are few, still does).
 
+- Trinity (``afmoe``), the same two kinds of cache and the same counts: a
+  ring of 2,048 positions a row is 0.27 GB a layer for K alone, so a ring
+  copy in any program costs what a pool copy does.
+
 - ``deepseek_v3``, a latent pool a layer: copies of a layer's pool (6 GB
   in all at the benchmark's sizes: one copy does not fit beside it), and K
   or V a head of a cached span (any result ``[.., positions, heads, size]``
@@ -43,6 +47,8 @@ PR 32 and PR 47); it says nothing about time. Run here, on the CPU:
         --max-batch-size 32 --prefill 512
     JAX_PLATFORMS=cpu python tools/aot_serving_programs.py --model kanana-2-30b-a3b \\
         --max-batch-size 32 --prefill 512 [--prefill-rows 4]
+    JAX_PLATFORMS=cpu python tools/aot_serving_programs.py --model trinity-mini \\
+        --max-batch-size 32 --prefill 512 [--prefill-rows 2]
 
 ``--prefill-rows R`` adds the prefill call of R rows (PR 50: the chunks a
 round has to prefill in one call, the expert layers once), with the same
@@ -141,8 +147,12 @@ def gpt2_family(cfg, dec, stored_k, key):
 
 
 def mimo_v2_family(cfg, dec, stored_k, key):
-    """MiMo-V2's tree as an engine holds it and what to count: a cache is
-    an array a layer (``cache_spec``), a pool or a ring."""
+    """The tree of a family with full and window layers (MiMo-V2, Trinity)
+    as an engine holds it and what to count: a cache is an array a layer
+    (``cache_spec``), a pool or a ring; a ring also as the blocks decode may
+    read it in (``ops.cached_attention.ring_span``)."""
+    from ray_tpu.ops import cached_attention
+
     spec = dec.cache_spec(cfg)
     # a page of positions or more: q is [rows, kv_heads, group, size] too
     positions = rf"\d{{{len(str(stored_k.page_tokens))},}}"
@@ -150,11 +160,26 @@ def mimo_v2_family(cfg, dec, stored_k, key):
     for s, k in zip(spec, stored_k.layers):
         for size in (s["k_size"], s["v_size"]):
             by_kind[s["kind"]].add(k.shape[:2] + (s["kv_heads"] * size,))
+            if s["kind"] == "window":
+                span = cached_attention.ring_span(k.shape[1])
+                by_kind["window"].add((k.shape[0] * k.shape[1] // span, span,
+                                       s["kv_heads"] * size))
     split = {(r"\d+", positions, s["kv_heads"], size)
              for s in spec for size in (s["k_size"], s["v_size"])}
+    # every row's ring cut into its blocks at once: several results
+    # ``[rows, span, width]``, which is how a gather of whole rings of 2,048
+    # positions was compiled (PERF.md, PR 53)
+    cut = {(k.shape[0], cached_attention.ring_span(k.shape[1]), s["kv_heads"] * size)
+           for s, k in zip(spec, stored_k.layers) if s["kind"] == "window"
+           for size in (s["k_size"], s["v_size"])}
+
+    def rings_cut(ops):
+        return sum(bool(re.search(rf"= \(\w+\[{r},{b},{c}\]\S*, \w+\[{r},{b},{c}\]", ln))
+                   for ln in ops for r, b, c in cut)
     watch = [
         ("whole-pool copies", lambda ops: sum(results_of(ops, p) for p in by_kind["full"])),
-        ("ring copies", lambda ops: sum(results_of(ops, r) for r in by_kind["window"])),
+        ("ring copies", lambda ops: sum(results_of(ops, r) for r in by_kind["window"])
+         + rings_cut(ops)),
         ("K/V split into heads", lambda ops: sum(
             results_of(ops, s, "copy|reshape|transpose|fusion") for s in split)),
     ]
@@ -180,6 +205,7 @@ def deepseek_v3_family(cfg, dec, stored, key):
 
 FAMILIES = {"ray_tpu.models.gpt2_decode": gpt2_family,
             "ray_tpu.models.mimo_v2": mimo_v2_family,
+            "ray_tpu.models.afmoe": mimo_v2_family,
             "ray_tpu.models.deepseek_v3": deepseek_v3_family}
 
 
