@@ -245,6 +245,12 @@ def params_count(model: Dict[str, Any]) -> int:
             + expert_layers * int(model["num_experts"]) * expert_params(model))
 
 
+def held_experts(model: Dict[str, Any]) -> int:
+    """Routed experts the chip holds a layer (``moe_load_skew``'s mean is
+    over them): all of them, under this family's own key."""
+    return int(model["num_experts"])
+
+
 def expected_experts_hit(model: Dict[str, Any], rows: float) -> float:
     """Distinct experts that ``rows`` tokens reach in one layer under even
     routing: E * (1 - (1 - k / E) ** rows)."""

@@ -3,7 +3,7 @@
 A family file is everything of the benchmark that knows one model family:
 the harness finds it by the configuration's ``"family"``
 (``find(bench, "families", name, ".py")`` under every directory of
-``paths``) and asks it for the six names below and for nothing else, so a
+``paths``) and asks it for the seven names below and for nothing else, so a
 second family is a second file. The serving side only: the training
 generator, ``check.compare_step`` and ``train_mfu`` are GPT-2's by name
 (PERF.md, section 7).
@@ -16,7 +16,10 @@ generator, ``check.compare_step`` and ``train_mfu`` are GPT-2's by name
                                 against the plain reference, logits compared
     warm_row_updates(...)       the engine's row-update program, every count
     decode_step_bytes(...)      bytes one decode step has to read (optional:
-                                without it ``decode_roofline`` reads nothing)
+                                without it ``decode_step_mfu`` reads nothing)
+    held_experts(model)         routed experts the chip holds a layer (optional:
+                                without it ``moe_load_skew`` takes the
+                                configuration's ``n_routed_experts``)
 """
 
 from __future__ import annotations

@@ -54,9 +54,10 @@ def mfu(tokens_per_s: float, model: Dict[str, int], chips: int,
     )
 
 
-def decode_roofline(step_s: float, step_bytes: float, device_kind: str) -> float:
-    """Least time a decode step could take, which memory bandwidth sets
-    (one token a row: ~2 FLOPs a weight byte), over the time it took.
+def decode_step_mfu(step_s: float, step_bytes: float, device_kind: str) -> float:
+    """The whole decode step's share of the chip's peak, in percent: the
+    least time the step could take, which memory bandwidth sets (one token
+    a row: ~2 FLOPs a weight byte), over the time it took.
     ``step_bytes`` is what the step has to read, by the family's own
     ``decode_step_bytes`` (``benchmark/families/<family>.py``)."""
     least = step_bytes / peak(device_kind)["hbm_bytes_per_s"]

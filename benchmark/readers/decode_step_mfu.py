@@ -1,7 +1,9 @@
-"""The decode step's share of its roofline, in percent: the bytes a step
-has to read (by the family's ``decode_step_bytes``: weights once, live K/V
-of the active rows) over the chip's memory bandwidth, divided by the
-device time of a token-step. A family that counts no bytes reads nothing."""
+"""The whole decode step's share of the chip's peak, in percent: the bytes
+a step has to read (by the family's ``decode_step_bytes``: weights once,
+the live rows' caches) over the chip's memory bandwidth, which is the peak
+that binds at one token a row, divided by the device time of a token-step
+of the decode programs, whatever they are made of. A family that counts no
+bytes reads nothing."""
 
 from benchmark import harness, peaks
 from benchmark.readers import counter_ratio, decode_step
@@ -23,6 +25,6 @@ def read(obs, args, ctx):
         r["usage"]["prompt_tokens"] + r["usage"]["completion_tokens"] / 2.0
         for r in done
     ) / len(done)
-    return peaks.decode_roofline(
+    return peaks.decode_step_mfu(
         step_ms / 1000.0, step_bytes(obs["model"], rows, context), obs["device"]["kind"]
     )

@@ -1,15 +1,19 @@
 """The fullest held expert's tokens over the mean held expert's, over the
 window: ``{"fullest": counter, "pairs": counter}``. The fullest is summed
 over expert layers and decode steps, the pairs over the held experts too,
-so the mean takes the experts held (the configuration's
-``n_routed_experts``). Nothing where the counters are absent or still."""
+so the mean takes the experts held: the family's ``held_experts(model)``
+where its file has one (``afmoe``'s key is ``num_experts``), else the
+configuration's ``n_routed_experts``. Nothing where the counters are
+absent or still, or nobody says how many experts are held."""
 
+from benchmark import harness
 from benchmark.readers import counter_ratio
 
 
 def read(obs, args, ctx):
-    counters = obs.get("counters")
-    held = (obs.get("model") or {}).get("n_routed_experts")
+    counters, model = obs.get("counters"), obs.get("model") or {}
+    ask = getattr(harness.family(getattr(ctx, "family", None)), "held_experts", None)
+    held = ask(model) if ask else model.get("n_routed_experts")
     if not counters or not held:
         return None
     pairs = counter_ratio.delta(counters, [[args["pairs"], "value"]])

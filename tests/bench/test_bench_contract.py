@@ -87,6 +87,21 @@ def test_cells_configs_and_moves_hang_together(bench):
     assert all(len(spellings) == 1 for spellings in layers.values())
 
 
+@pytest.mark.parametrize("moved", ["serve_tok_s", "tpot_ms", "train_tok_s"])
+def test_one_share_of_the_whole_steps_peak_bounds_each_claimable_metric(bench, moved):
+    """Exactly one per-layer metric with ``mfu`` as a part of its name
+    moves each metric a PR can claim, and it lists every cell that reports
+    that metric: a kernel that silences its own ``<kernel>_roofline`` still
+    has the whole step's share to bound its claim, in whichever cell."""
+    cells = [w["name"] for w in bench["workloads"]]
+    entry = next(m for m in bench["end_to_end"] if m["name"] == moved)
+    whole = [m for m in bench["per_layer"]
+             if m["moves"] == moved and "mfu" in re.split(r"[._\-]", m["name"])]
+    assert len(whole) == 1, [m["name"] for m in whole]
+    assert (whole[0]["unit"], whole[0]["better"]) == ("%", "higher")
+    assert sorted(whole[0].get("workloads", cells)) == sorted(entry.get("workloads", cells))
+
+
 def test_every_metric_traffic_and_generator_has_its_file(bench):
     from benchmark import harness
 

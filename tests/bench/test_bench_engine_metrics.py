@@ -74,6 +74,30 @@ def bench():
         return json.load(f)
 
 
+def reporting(b, end_to_end):
+    """The accepted cells that report an end-to-end metric."""
+    entry = next(m for m in b["end_to_end"] if m["name"] == end_to_end)
+    return [w["name"] for w in b["workloads"]
+            if w["name"] in entry.get("workloads", [w["name"]])]
+
+
+# the engine's own series, which every family's rounds and admissions move
+# (decode_step_counted_ms.decode beside them is the device's: no CPU reading)
+ENGINE_SERIES = {"queue_wait_ms.decode", "page_wait_ms.decode", "engine_host_ms.decode",
+                 "engine_blocked_ms.decode"}
+
+
+def on_every_list_the_other_serving_cells_share(b, cell):
+    """``cell`` is judged on serve_tok_s and stands on every per-layer list
+    that every other such cell stands on, wherever in a list a later cell
+    is appended."""
+    serving = reporting(b, "serve_tok_s")
+    assert cell in serving
+    for m in b["per_layer"]:
+        if set(serving) - {cell} <= set(m.get("workloads", serving)):
+            assert cell in m.get("workloads", serving), m["name"]
+
+
 def through_its_reader(name, obs):
     b = bench()
     with open(harness.find(b, "metrics", name)) as f:
@@ -85,11 +109,17 @@ def through_its_reader(name, obs):
 def test_metric_file_reads_the_engines_series(name):
     spec, value = through_its_reader(name, observations())
     assert value == pytest.approx(EXPECTED[name])
-    entry = next(m for m in bench()["per_layer"] if m["name"] == name)
+    b = bench()
+    entry = next(m for m in b["per_layer"] if m["name"] == name)
     assert entry["unit"] == spec["unit"]
-    cell = {"decode": ["xl-batch-decode"], "chat": ["xl-chat-sessions"]}.get(
-        name.rsplit(".", 1)[-1], ["xl-chat-sessions"])
-    assert entry["workloads"] == cell
+    # the engine's series are every family's: a metric of them is listed
+    # in exactly the accepted cells that report what its suffix moves, so a
+    # cell a later PR appends to both lists fails nothing here
+    moves = {"decode": "serve_tok_s", "chat": "tpot_ms"}.get(name.rsplit(".", 1)[-1], "tpot_ms")
+    assert entry["moves"] == moves
+    first = {"serve_tok_s": "xl-batch-decode", "tpot_ms": "xl-chat-sessions"}[moves]
+    assert first in entry["workloads"]
+    assert sorted(entry["workloads"]) == sorted(reporting(b, moves))
 
 
 @pytest.mark.parametrize("name", sorted(EXPECTED))

@@ -8,7 +8,10 @@ from types import SimpleNamespace
 
 import bench_rehearsal_file
 import pytest
-from test_bench_engine_metrics import snap, through_its_reader
+from test_bench_engine_metrics import (
+    ENGINE_SERIES, on_every_list_the_other_serving_cells_share, reporting, snap,
+    through_its_reader,
+)
 from test_bench_rehearsal import rehearse, run
 
 from benchmark import harness, traffic
@@ -158,8 +161,10 @@ TRACED = {"window_s": 4.0, "modules": {"jit_prefill_paged": 0.9, "jit_decode_mul
 def test_the_new_metrics_read_the_engines_series(name, want):
     spec, got = through_its_reader(name, {"counters": COUNTED, "trace": TRACED})
     assert got == pytest.approx(want), spec
-    entry = next(m for m in load("BENCHMARK.json")["per_layer"] if m["name"] == name)
-    assert (entry["workloads"], entry["moves"], entry["unit"]) == ([CELL], "serve_tok_s", spec["unit"])
+    bench = load("BENCHMARK.json")
+    entry = next(m for m in bench["per_layer"] if m["name"] == name)
+    assert (entry["moves"], entry["unit"]) == ("serve_tok_s", spec["unit"])
+    assert CELL in entry["workloads"] and set(entry["workloads"]) <= set(reporting(bench, "serve_tok_s"))
 
 
 @pytest.mark.parametrize("name", NEW)
@@ -229,10 +234,12 @@ def test_the_cell_stands_on_mimos_lists_but_the_window_share(cfg):
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         "kanana-2-30b-a3b-serve", "agent-sessions", 1)
     on = {m["name"] for m in bench["per_layer"] if CELL in m.get("workloads", [])}
-    mimo = {m["name"] for m in bench["per_layer"] if "mimo-reason-decode" in m.get("workloads", [])}
-    assert mimo - on == {"kv_window_share"} and on - mimo == set(NEW)
-    serve = next(m for m in bench["end_to_end"] if m["name"] == "serve_tok_s")
-    assert serve["workloads"][-1] == CELL
+    # what PR 48 brought, the expert layer's lists and the page loops' that
+    # MiMo's cell opened, and no window's share: this family keeps no ring
+    assert set(NEW) <= on and "kv_window_share" not in on
+    assert {"moe_tokens_per_expert", "moe_experts_hit", "moe_load_skew", "moe_roofline",
+            "attn_loop_useful_share", "prefill_rows_mean"} <= on
+    on_every_list_the_other_serving_cells_share(bench, CELL)
     entry = next(c for c in bench["configs"] if c["name"] == cell["config"])
     assert entry["reduced"] == ["num_hidden_layers"] and entry["source"] == cfg["source"]
 
@@ -251,9 +258,11 @@ def test_the_cell_rehearses_to_a_correct_line_with_its_counters_read(agent):
             "moe_tokens_per_expert", "moe_experts_hit", "moe_load_skew",
             "batch_fill.decode", "kv_pages_used.decode", "compiles_in_window.decode",
             "engine_load_s", "deploy_ready_s"} <= set(got)
+    assert ENGINE_SERIES <= set(got)
     # no device metric from a CPU run
-    assert not {"mla_roofline", "prefill_ms.decode", "moe_roofline", "decode_roofline",
-                "decode_step_ms.decode", "hbm_used.decode", "device_idle.decode"} & set(got)
+    assert not {"mla_roofline", "prefill_ms.decode", "moe_roofline", "decode_step_mfu",
+                "decode_step_ms.decode", "decode_step_counted_ms.decode", "hbm_used.decode",
+                "device_idle.decode"} & set(got)
     # three layers of 40 numbers, stored 128 wide, in bfloat16
     assert got["kv_latent_token_bytes"]["value"] == pytest.approx(3 * 128 * 2)
     # the shared system prompt and the session's history are prefix hits on latent pages

@@ -7,7 +7,7 @@ import os
 
 import bench_rehearsal_file
 import pytest
-from test_bench_engine_metrics import snap, through_its_reader
+from test_bench_engine_metrics import ENGINE_SERIES, snap, through_its_reader
 from test_bench_rehearsal import rehearse, run
 
 from benchmark import traffic
@@ -166,9 +166,10 @@ def test_the_cell_rehearses_to_a_correct_line_with_its_counters_read(reason):
     assert {"moe_tokens_per_expert", "moe_experts_hit", "moe_load_skew", "kv_window_share",
             "batch_fill.decode", "kv_pages_used.decode", "compiles_in_window.decode",
             "engine_load_s", "deploy_ready_s"} <= set(got)
+    assert ENGINE_SERIES <= set(got)
     # no device metric from a CPU run
-    assert not {"moe_roofline", "decode_roofline", "decode_step_ms.decode", "hbm_used.decode",
-                "device_idle.decode"} & set(got)
+    assert not {"moe_roofline", "decode_step_mfu", "decode_step_ms.decode", "prefill_ms.decode",
+                "decode_step_counted_ms.decode", "hbm_used.decode", "device_idle.decode"} & set(got)
     assert 0 < got["moe_experts_hit"]["value"] <= 100
     assert got["moe_tokens_per_expert"]["value"] > 0 and got["moe_load_skew"]["value"] >= 1
     assert 0 < got["kv_window_share"]["value"] < 100

@@ -31,15 +31,15 @@ def test_mfu_by_hand():
     )
 
 
-def test_decode_roofline_by_hand():
+def test_decode_step_mfu_by_hand():
     # weights 2 x 1,557,611,200 = 3.115 GB; K and V of 24 rows at 150
     # tokens: 24 x 150 x 2 x 48 x 1600 x 2 = 1.106 GB; 4.221 GB / 819 GB/s
     step_bytes = family.decode_step_bytes(XL, 24, 150)
     assert step_bytes == pytest.approx(3_115_222_400 + 1_105_920_000)
     least = (3_115_222_400 + 1_105_920_000) / 819e9
     assert least == pytest.approx(5.154e-3, rel=1e-3)
-    assert peaks.decode_roofline(0.262, step_bytes, V5E) == pytest.approx(100 * least / 0.262)
-    assert peaks.decode_roofline(0.262, step_bytes, V5E) == pytest.approx(1.967, abs=0.01)
+    assert peaks.decode_step_mfu(0.262, step_bytes, V5E) == pytest.approx(100 * least / 0.262)
+    assert peaks.decode_step_mfu(0.262, step_bytes, V5E) == pytest.approx(1.967, abs=0.01)
 
 
 def test_flash_costs_by_hand():

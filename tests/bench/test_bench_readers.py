@@ -8,8 +8,8 @@ import pytest
 
 from benchmark import harness, peaks
 from benchmark.readers import (
-    compiles, counter_ratio, decode_roofline, decode_step, device_idle, exposed,
-    gauge_share, gen_lag, hbm_used, itl, observed, step_ms, sync_rate, token_rate,
+    compiles, counter_ratio, decode_step, decode_step_mfu, device_idle, exposed,
+    gauge_share, gen_lag, hbm_used, itl, moe_load_skew, observed, step_ms, sync_rate, token_rate,
     tpot, trace_time, train_mfu, ttft,
 )
 
@@ -139,30 +139,76 @@ def decode_obs():
     return obs, {"match": "^jit_decode_(paged_and_sample|multi_paged)$"}
 
 
-def test_decode_step_and_roofline_by_hand():
+def test_decode_step_and_its_share_of_the_peak_by_hand():
     obs, args = decode_obs()
     assert decode_step.token_steps_per_s(obs) == pytest.approx(20.0)
     assert decode_step.read(obs, args, TPU) == pytest.approx(1000 * (3.5 / 4.0) / 20.0)
     # 2 x 1,557,611,200 bytes of weights, K and V of 24 rows at 150 tokens
     step_bytes = harness.family(GPT2).decode_step_bytes(XL, 24.0, 150.0)
     assert step_bytes == pytest.approx(3_115_222_400 + 1_105_920_000)
-    assert decode_roofline.read(obs, args, TPU) == pytest.approx(
-        peaks.decode_roofline(0.04375, step_bytes, "TPU v5 lite")
+    assert decode_step_mfu.read(obs, args, TPU) == pytest.approx(
+        peaks.decode_step_mfu(0.04375, step_bytes, "TPU v5 lite")
     )
-    assert decode_roofline.read(obs, args, TPU) == pytest.approx(11.78, abs=0.01)
+    assert decode_step_mfu.read(obs, args, TPU) == pytest.approx(11.78, abs=0.01)
     assert decode_step.read({"trace": traced()}, args, TPU) is None
 
 
-def test_decode_roofline_reads_nothing_where_the_family_counts_no_bytes(tmp_path):
+def test_decode_step_mfu_reads_nothing_where_the_family_counts_no_bytes(tmp_path):
     """Nothing, not 0: a family file without ``decode_step_bytes``, and a
     configuration that names no family, leave the metric out of the line."""
     obs, args = decode_obs()
     bare = tmp_path / "bare.py"
     bare.write_text("def context(model):\n    return 128\n")
     assert harness.family(str(bare)).context({}) == 128
-    assert decode_roofline.read(obs, args, SimpleNamespace(platform="tpu", family=str(bare))) is None
-    assert decode_roofline.read(obs, args, SimpleNamespace(platform="tpu", family=None)) is None
-    assert decode_roofline.read(obs, args, SimpleNamespace(platform="tpu")) is None
+    assert decode_step_mfu.read(obs, args, SimpleNamespace(platform="tpu", family=str(bare))) is None
+    assert decode_step_mfu.read(obs, args, SimpleNamespace(platform="tpu", family=None)) is None
+    assert decode_step_mfu.read(obs, args, SimpleNamespace(platform="tpu")) is None
+
+
+def test_decode_step_mfu_is_left_out_of_a_line_off_the_chip():
+    """Through the command's own ``read_metrics``: the share is the
+    device's, so a run on the CPU prints none under its name (nor under
+    ``decode_step_mfu.chat``), and the same observations on the chip do."""
+    from benchmark import run
+
+    bench = harness.load_json(os.path.join(harness.ROOT, "BENCHMARK.json"))
+    obs, _ = decode_obs()
+    for cell in ("xl-batch-decode", "xl-chat-sessions"):
+        plan = run.resolve(bench, cell, True)
+        names = {m["name"] for m in plan["metrics"] if m["name"].startswith("decode_step_mfu")}
+        assert len(names) == 1
+        plan["metrics"] = [m for m in plan["metrics"] if m["name"] in names]
+        assert run.read_metrics(bench, plan, obs, CPU) == {}
+        (got,) = run.read_metrics(bench, plan, obs, TPU).values()
+        assert got == {"value": pytest.approx(11.78, abs=0.01), "unit": "%"}
+
+
+SKEW = {"fullest": "rt_serve_moe_max_load_total", "pairs": "rt_serve_moe_assignments_total"}
+
+
+@pytest.mark.parametrize("config,family,held", [
+    ("trinity-mini-serve", "afmoe", 128),            # the family answers from num_experts
+    ("kanana-2-30b-a3b-serve", "deepseek_v3", 128),  # no such function: n_routed_experts
+    ("mimo-v2.5-serve", "mimo_v2", 16),              # the 16 held of 256 routed over
+])
+def test_moe_load_skew_asks_the_family_for_the_experts_held(config, family, held):
+    bench = harness.load_json(os.path.join(harness.ROOT, "BENCHMARK.json"))
+    entry = next(c for c in bench["configs"] if c["name"] == config)
+    model = harness.load_json(os.path.join(harness.ROOT, entry["file"]))["model"]
+    ctx = SimpleNamespace(platform="tpu", family=harness.find(bench, "families", family, ".py"))
+    # 600 layer-steps: the fullest expert took 4,800 pairs of 38,400
+    counted = counters({"rt_serve_moe_max_load_total": 500.0, "rt_serve_moe_assignments_total": 1000.0},
+                       {"rt_serve_moe_max_load_total": 5300.0, "rt_serve_moe_assignments_total": 39400.0})
+    assert moe_load_skew.read({"counters": counted, "model": model}, SKEW, ctx) == pytest.approx(
+        4800.0 * held / 38400.0)
+    # nothing, and no exception: no counters, counters that did not move, a
+    # model nobody counts the experts of
+    still = counters({"rt_serve_moe_assignments_total": 7.0}, {"rt_serve_moe_assignments_total": 7.0})
+    assert moe_load_skew.read({"model": model}, SKEW, ctx) is None
+    assert moe_load_skew.read({"counters": None, "model": model}, SKEW, ctx) is None
+    assert moe_load_skew.read({"counters": still, "model": model}, SKEW, ctx) is None
+    assert moe_load_skew.read({"counters": counted, "model": XL}, SKEW, TPU) is None
+    assert moe_load_skew.read({"counters": counted}, SKEW, SimpleNamespace(platform="tpu")) is None
 
 
 def test_compiles_counts_cache_entries_and_compile_events():

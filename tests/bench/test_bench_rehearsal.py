@@ -304,7 +304,7 @@ def test_a_second_family_with_its_cell_config_mix_and_metric_is_files_alone(tmp_
         "layer": "OpenAI ingress, proxy, router", "moves": "serve_tok_s",
         "workloads": ["toy-small.decode"]})
     for m in bench["end_to_end"] + bench["per_layer"]:
-        if m["name"] in ("serve_tok_s", "decode_roofline"):
+        if m["name"] in ("serve_tok_s", "decode_step_mfu"):
             m["workloads"].append("toy-small.decode")
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench, indent=1))
 
@@ -331,7 +331,7 @@ def test_a_second_family_with_its_cell_config_mix_and_metric_is_files_alone(tmp_
     assert plan["traffic"]["users"] == 28
     assert plan["generator"] == "benchmark.generators.serve_sessions"
     assert plan["metrics"]["shed_share"] == "benchmark.readers.counter_ratio"
-    assert plan["metrics"]["decode_roofline"] == "benchmark.readers.decode_roofline"
+    assert plan["metrics"]["decode_step_mfu"] == "benchmark.readers.decode_step_mfu"
 
     spec = importlib.util.spec_from_file_location(
         "copied_rehearsal_file", tmp_path / "tests/bench/bench_rehearsal_file.py")
