@@ -17,14 +17,22 @@ takes no part in the gate), gates ``s_e / sum of the chosen s``, times a
 ``scale`` where the model has one (``routed_scaling_factor``).
 
 The product is grouped, not one-hot: the token-expert pairs that landed
-here are sorted by expert and multiplied by ``jax.lax.ragged_dot``, which
-reads an expert's weights once for all its rows and reads no weight of an
-expert that got none. There is no capacity: the sorted buffer has room for
-every pair that can land here (``tokens * min(k, held)``), and is worked
-through in blocks of ``block`` rows by a loop that stops after the last
-pair, because ``ragged_dot``'s time goes with the rows it is handed, not
-with the rows its groups cover (2.6 ms for 1,024 rows of which 64 were
-grouped, 1.3 ms for 64 rows, 16 experts of 4096 x 2048; PERF.md, PR 46).
+here are sorted by expert and go through ``ops/grouped_matmul.py``, a
+Pallas kernel that walks the (expert, row tile) pairs in which an expert
+has a row: it reads an expert's weights once for all its rows, the next
+expert's while this one's few rows are multiplied, and no weight of an
+expert that got none. Gate and up are one call (``silu(g) * u`` its
+epilogue), down another, on one grid computed once a layer. There is no
+capacity: the sorted buffer has room for every pair that can land here
+(``tokens * min(k, held)``) and is handed to the kernel whole, whose time
+goes with the pairs that landed and not with the room. What stands behind
+the kernel does go with the rows it is handed: the gates' product and the
+scatter-add run in blocks of ``_block_rows`` rows by a loop that stops
+after the last pair. Where a chip holds 16 experts of 256 the buffer is
+sixteen times the pairs expected, and a layer of 4096 x 2048 experts took
+3.32 ms with the whole buffer scattered and 1.96 ms in blocks at 1,024
+tokens, 1.41 and 1.32 ms at 128; where every expert is held the buffer is
+one block and the loop one turn (my chip run, PERF.md section 6, PR 55).
 """
 
 from __future__ import annotations
@@ -34,6 +42,8 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from ray_tpu.ops import grouped_matmul
 
 # what ``expert_layer`` counts, in the order of its fourth result
 STATS = ("assignments", "expert_steps", "experts_hit", "max_load")
@@ -56,15 +66,12 @@ def route(x: jax.Array, router: jax.Array, bias: jax.Array,
 
 
 def _block_rows(tokens: int, top_k: int, held: int, routed: int) -> int:
-    """Rows one turn of the loop multiplies: twice the pairs that land
-    here under even routing, as a power of two from 128, and no more than
-    can land here at all."""
-    most = tokens * min(top_k, held)
-    expected = tokens * top_k * held / routed
+    """Rows one turn of the loop behind the products takes: twice the pairs
+    that land here under even routing, as a power of two from 128."""
     rows = 128
-    while rows < 2 * expected:
+    while rows < 2 * tokens * top_k * held / routed:
         rows *= 2
-    return min(rows, most)
+    return rows
 
 
 def expert_layer(
@@ -83,7 +90,6 @@ def expert_layer(
     nowhere and count nowhere."""
     T, D = x.shape
     held = weights["gate"].shape[0]
-    routed = weights["router"].shape[1]
     with jax.named_scope("moe_experts"):
         chosen, gates = route(x, weights["router"], weights["bias"], top_k, scale)
         local = chosen - first
@@ -92,36 +98,33 @@ def expert_layer(
             here &= live[:, None]
         # pairs by expert, those for other chips' experts last
         group = jnp.where(here, local, held).reshape(-1)
-        rows = _block_rows(T, top_k, held, routed)
-        # room for every pair that can land here, in whole blocks
-        most = -(-T * min(top_k, held) // rows) * rows
+        # room for every pair that can land here, in whole blocks of whole
+        # row tiles (a block of 128 rows or more is row tiles of 128)
+        most = T * min(top_k, held)
+        tm = grouped_matmul.row_tile(most)
+        rows = _block_rows(T, top_k, held, weights["router"].shape[1])
+        if rows >= most:
+            rows = -(-most // tm) * tm
+        most = -(-most // rows) * rows
         order = jnp.argsort(group, stable=True)[:most]
         order = jnp.pad(order, (0, most - order.shape[0]))
         token = order // top_k
         sizes = jnp.zeros((held + 1,), jnp.int32).at[group].add(1)[:held]
-        ends = jnp.cumsum(sizes)
-        starts = ends - sizes
-        n_pairs = ends[-1]
+        n_pairs = sizes.sum()
         xs = x[token]                                           # [most, D]
         ws = jnp.where(jnp.arange(most) < n_pairs,
                        gates.reshape(-1)[order], 0.0)
+        out = grouped_matmul.swiglu(xs, weights["gate"], weights["up"],
+                                    weights["down"], sizes, tm=tm)
 
         def block(i, y):
             lo = i * rows
-            part = jnp.clip(ends, lo, lo + rows) - jnp.clip(starts, lo, lo + rows)
-            xb = lax.dynamic_slice_in_dim(xs, lo, rows)
-            g = lax.ragged_dot(xb, weights["gate"], part,
-                               preferred_element_type=jnp.float32)
-            u = lax.ragged_dot(xb, weights["up"], part,
-                               preferred_element_type=jnp.float32)
-            h = (jax.nn.silu(g) * u).astype(x.dtype)
-            out = lax.ragged_dot(h, weights["down"], part,
-                                 preferred_element_type=jnp.float32)
+            ob = lax.dynamic_slice_in_dim(out, lo, rows)
             wb = lax.dynamic_slice_in_dim(ws, lo, rows)
             # rows past the last pair belong to no group: whatever the
             # product left there is not added
-            out = jnp.where((wb > 0)[:, None], out * wb[:, None], 0.0)
-            return y.at[lax.dynamic_slice_in_dim(token, lo, rows)].add(out)
+            ob = jnp.where((wb > 0)[:, None], ob * wb[:, None], 0.0)
+            return y.at[lax.dynamic_slice_in_dim(token, lo, rows)].add(ob)
 
         y = lax.fori_loop(0, -(-n_pairs // rows), block,
                           jnp.zeros((T, D), jnp.float32))

@@ -303,6 +303,15 @@ def time_limit():
     signal.signal(signal.SIGALRM, old)
 
 
+@pytest.fixture
+def kernels_for_the_chip(monkeypatch):
+    """Pallas kernels are lowered for the chip the test compiles for, not
+    interpreted as this process's own backend, the CPU, would have them."""
+    from ray_tpu.ops import flash_attention as fa
+
+    monkeypatch.setattr(fa, "_interpret", lambda: False)
+
+
 def test_compiled_serving_programs_copy_no_whole_pool(one_chip, time_limit):
     """At gpt2-xl's serving shapes (S 24, 97 pages of 64, the weights as
     an engine holds them) none of the three programs, as the v5e's
@@ -443,7 +452,8 @@ def test_the_tool_names_a_loop_as_the_trace_does(case):
     assert aot.loops_of(text + "\n" + text) == {k: 2 * v for k, v in want.items()}
 
 
-def test_compiled_latent_programs_copy_no_pool_and_decode_builds_no_head(one_chip, time_limit):
+def test_compiled_latent_programs_copy_no_pool_and_decode_builds_no_head(
+        one_chip, time_limit, kernels_for_the_chip):
     """At ``kanana-agent-sessions``' shapes (128 rows, five pools of 16,385
     pages of 64 latent rows, 512 page-table columns, a prefill chunk of
     512; the weights as an engine holds them) none of the three programs,
@@ -474,25 +484,30 @@ def test_compiled_latent_programs_copy_no_pool_and_decode_builds_no_head(one_chi
     tail = (sds((S, mp), jnp.int32), sds((S,), jnp.float32), sds((S,), jnp.bool_),
             sds((2,), jnp.uint32))
     i32 = sds((), jnp.int32)
+
+    def compiled(lowered):
+        c = lowered.compile()
+        return c.as_text(), c.memory_analysis().temp_size_in_bytes
+
     programs = {
-        "decode_paged_and_sample": m.decode_paged_and_sample.lower(
-            cfg, p, *rows, pool, none, *tail, i32),
-        "decode_multi_paged": m.decode_multi_paged.lower(
-            cfg, p, *rows, pool, none, *tail, i32, i32),
-        "prefill_paged": m.prefill_paged.lower(
-            cfg, p, sds((1, 512), jnp.int32), i32, i32, pool, none, sds((mp,), jnp.int32)),
+        # these very shapes: compiled once for this file's tests
+        "decode_paged_and_sample": lambda: cell_program(
+            m, "kanana-2-30b-a3b", one_chip, "decode"),
+        "decode_multi_paged": lambda: compiled(m.decode_multi_paged.lower(
+            cfg, p, *rows, pool, none, *tail, i32, i32)),
+        "prefill_paged": lambda: compiled(m.prefill_paged.lower(
+            cfg, p, sds((1, 512), jnp.int32), i32, i32, pool, none, sds((mp,), jnp.int32))),
     }
     dims = ",".join(map(str, whole))
     H, sizes = cfg.num_attention_heads, f"(?:{cfg.qk_nope_head_dim}|{cfg.qk_head_dim})"
     a_head = rf"\d+,(?:\d{{2,}},{H}|{H},\d{{2,}}),{sizes}"
-    for name, lowered in programs.items():
-        compiled = lowered.compile()
-        text = compiled.as_text()
+    for name, program in programs.items():
+        text, temps = program()
         entry = re.search(r"entry_computation_layout=\{\((.*?)\)->", text).group(1)
         assert f"bf16[{dims}]{{2,1,0:" in entry, name  # as written, the row minor
         copies = re.findall(rf"= \w+\[{dims}\]\S* copy\(", text)
         assert not copies, (name, copies)
-        assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9, name
+        assert temps < 0.5e9, name
         if name.startswith("decode"):
             heads = re.findall(rf"= \w+\[{a_head}\]\S* [\w\-]+\(", text)
             assert not heads, (name, heads[:3])
@@ -509,32 +524,63 @@ def test_compiled_latent_programs_copy_no_pool_and_decode_builds_no_head(one_chi
             assert gathered - {N} == {S // G * C}, (name, gathered)
 
 
-def largest_prefill_of_rows(m, model_id, one_chip, family):
-    """The largest prefill call of several rows of a served model at its
-    cell's shapes (128 decode rows, 32 sequences' worth of pages of 64), as
-    the v5e's compiler writes it: (the config, what the tool counts in its
-    operations by label, its loops, its temporaries' bytes)."""
+_COMPILED = {}
+
+
+def cell_program(m, model_id, one_chip, which):
+    """``decode`` (``decode_paged_and_sample``) or ``prefill`` (the largest
+    prefill call of several rows) of a served MoE model at its cell's shapes
+    (128 decode rows, 32 sequences' worth of pages of 64, the weights as an
+    engine holds them), as the v5e's compiler writes it: (its text, its
+    temporaries' bytes). Compiled once a process, with the Pallas kernels
+    lowered for the chip and not interpreted."""
+    from unittest import mock
+
+    from ray_tpu.ops import flash_attention as fa
+
+    if (model_id, which) in _COMPILED:
+        return _COMPILED[model_id, which]
     cfg = m.CONFIGS[model_id]
     S, Bx = 128, 64
     mp = -(-cfg.n_positions // Bx)
     R, P = m.PREFILL_ROWS[-1], m.PREFILL_ROW_WIDTHS[-1]
 
-    def on_chip(tree):
-        return jax.tree.map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    stored = jax.eval_shape(lambda: m.init_paged_cache(cfg, 32 * mp + 1, Bx, S))
-    k, v = on_chip(stored)
+    def on_chip(tree):
+        return jax.tree.map(lambda a: sds(a.shape, a.dtype), tree)
+
+    k, v = on_chip(jax.eval_shape(lambda: m.init_paged_cache(cfg, 32 * mp + 1, Bx, S)))
     p = on_chip(jax.eval_shape(lambda: m.load_serving_params(cfg)))
-    a_row = jax.ShapeDtypeStruct((R,), jnp.int32, sharding=one_chip)
-    compiled = m.prefill_paged.lower(
-        cfg, p, jax.ShapeDtypeStruct((R, P), jnp.int32, sharding=one_chip), a_row, a_row,
-        k, v, jax.ShapeDtypeStruct((R, mp), jnp.int32, sharding=one_chip), a_row).compile()
-    text = compiled.as_text()
+    with mock.patch.object(fa, "_interpret", lambda: False):
+        if which == "decode":
+            lowered = m.decode_paged_and_sample.lower(
+                cfg, p, sds((S,), jnp.int32), sds((S,), jnp.int32), k, v,
+                sds((S, mp), jnp.int32), sds((S,), jnp.float32), sds((S,), jnp.bool_),
+                sds((2,), jnp.uint32), sds((), jnp.int32))
+        else:
+            a_row = sds((R,), jnp.int32)
+            lowered = m.prefill_paged.lower(
+                cfg, p, sds((R, P), jnp.int32), a_row, a_row, k, v,
+                sds((R, mp), jnp.int32), a_row)
+        compiled = lowered.compile()
+    _COMPILED[model_id, which] = compiled.as_text(), compiled.memory_analysis().temp_size_in_bytes
+    return _COMPILED[model_id, which]
+
+
+def largest_prefill_of_rows(m, model_id, one_chip, family):
+    """The largest prefill call of several rows of a served model at its
+    cell's shapes, as the v5e's compiler writes it: (the config, what the
+    tool counts in its operations by label, its loops, its temporaries'
+    bytes)."""
+    cfg = m.CONFIGS[model_id]
+    text, temps = cell_program(m, model_id, one_chip, "prefill")
     ops = [ln for ln in text.splitlines() if re.match(r"\s*(ROOT )?%\S+ = ", ln)]
+    stored = jax.eval_shape(
+        lambda: m.init_paged_cache(cfg, 32 * -(-cfg.n_positions // 64) + 1, 64, 128))
     _, watch, _ = family(cfg, m, stored[0], None)
-    return (cfg, {label: count(ops) for label, count in watch}, aot.loops_of(text),
-            compiled.memory_analysis().temp_size_in_bytes)
+    return cfg, {label: count(ops) for label, count in watch}, aot.loops_of(text), temps
 
 
 def test_compiled_latent_prefill_of_rows_copies_no_pool(one_chip, time_limit):
@@ -542,8 +588,9 @@ def test_compiled_latent_prefill_of_rows_copies_no_pool(one_chip, time_limit):
     weights and pools: no copy of a layer's latent pool, temporaries under
     half a gigabyte (0.24 GB; a call of one row 0.03), an attention loop a
     row a layer, each stopping behind its own row, and ONE expert loop a
-    layer over the 2,048 tokens of all rows: the expert layers read their
-    weights once a call."""
+    layer over the 2,048 tokens of all rows (since PR 55 the scatter-add
+    behind the grouped-product kernel's two calls, one turn where every
+    expert is held): the expert layers read their weights once a call."""
     from ray_tpu.models import deepseek_v3 as m
 
     cfg, counted, loops, temps = largest_prefill_of_rows(
@@ -561,7 +608,9 @@ def test_compiled_mimo_prefill_of_rows_copies_no_pool_and_no_ring(one_chip, time
     layer's pool, none of a window layer's rings (the rows' rings are
     gathered, and written back through a scatter that drops the rows of no
     length), temporaries under half a gigabyte, one loop over the rows'
-    pages a full layer and one expert loop a layer over all 1,024 tokens."""
+    pages a full layer and one expert loop a layer over all 1,024 tokens
+    (since PR 55 the scatter-add behind the kernel's two calls, in blocks
+    that stop after the last pair)."""
     from ray_tpu.models import mimo_v2 as m
 
     cfg, counted, loops, temps = largest_prefill_of_rows(
@@ -583,7 +632,8 @@ def test_compiled_trinity_programs_copy_no_pool_and_no_ring(one_chip, time_limit
     would be a tenth of a step); decode holds four ring loops a sliding
     layer, whose carry opens with the weighted sum (the name
     ``window_attn_roofline`` matches), and four page loops in the full
-    layer, whose carry opens with the running maximum."""
+    layer, whose carry opens with the running maximum; an expert layer
+    holds one loop, the scatter-add behind the kernel's two calls (PR 55)."""
     from ray_tpu.models import afmoe as m
 
     cfg, counted, loops, temps = largest_prefill_of_rows(
@@ -598,30 +648,59 @@ def test_compiled_trinity_programs_copy_no_pool_and_no_ring(one_chip, time_limit
     assert loops == {f"(s32[],f32[2,{H},512],..)": cfg.n_layer,
                      f"(s32[],f32[1024,{D}],..)": cfg.n_layer - cfg.num_dense_layers}, loops
 
-    S, Bx = 128, 64
-    mp = cfg.n_positions // Bx
-
-    def on_chip(tree):
-        return jax.tree.map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
-
-    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-    stored = jax.eval_shape(lambda: m.init_paged_cache(cfg, 32 * mp + 1, Bx, S))
-    compiled = m.decode_paged_and_sample.lower(
-        cfg, on_chip(jax.eval_shape(lambda: m.load_serving_params(cfg))),
-        sds((S,), jnp.int32), sds((S,), jnp.int32), *on_chip(stored), sds((S, mp), jnp.int32),
-        sds((S,), jnp.float32), sds((S,), jnp.bool_), sds((2,), jnp.uint32),
-        sds((), jnp.int32)).compile()
-    text = compiled.as_text()
+    text, temps = cell_program(m, "trinity-mini", one_chip, "decode")
     ops = [ln for ln in text.splitlines() if re.match(r"\s*(ROOT )?%\S+ = ", ln)]
+    stored = jax.eval_shape(
+        lambda: m.init_paged_cache(cfg, 32 * (cfg.n_positions // 64) + 1, 64, 128))
     _, watch, _ = aot.mimo_v2_family(cfg, m, stored[0], None)
     counted = {label: count(ops) for label, count in watch}
     assert counted == {"whole-pool copies": 0, "ring copies": 0, "K/V split into heads": 0}, counted
-    assert compiled.memory_analysis().temp_size_in_bytes < 0.3e9
+    assert temps < 0.3e9
     assert aot.loops_of(text) == {
         f"(s32[],f32[32,{H},1,{cfg.head_dim}],..)": 4 * sliding,
         f"(s32[],f32[32,{H},1],..)": 4 * (cfg.n_layer - sliding),
         f"(s32[],f32[128,{D}],..)": cfg.n_layer - cfg.num_dense_layers}
+
+
+MOE_CELLS = {"kanana-2-30b-a3b": "deepseek_v3", "mimo-v2.5": "mimo_v2", "trinity-mini": "afmoe"}
+
+
+@pytest.mark.parametrize("which", ["decode", "prefill"])
+@pytest.mark.parametrize("model_id", list(MOE_CELLS))
+def test_compiled_expert_layers_are_the_kernel_and_copy_no_stack(
+        one_chip, time_limit, model_id, which):
+    """The decode step and the largest prefill call of the three MoE
+    families at their cells' shapes, as the v5e's compiler writes them
+    (PR 55): no ``ragged-dot``; two calls of the grouped-product kernel an
+    expert layer (gate and up as one, down), which Mosaic accepted with
+    the blocks ``ops/grouped_matmul.py`` cuts at these widths inside the
+    VMEM the call asks for, itself under the chip's 128 MiB; and no expert
+    stack written anew in any layout: a stack relaid for the kernel would
+    cost its 0.4 to 0.8 GB a call."""
+    import importlib
+
+    from ray_tpu.ops import grouped_matmul as gm
+
+    m = importlib.import_module(f"ray_tpu.models.{MOE_CELLS[model_id]}")
+    cfg = m.CONFIGS[model_id]
+    text, _ = cell_program(m, model_id, one_chip, which)
+    tree = jax.eval_shape(lambda: m.load_serving_params(cfg))
+    expert_layers = sum("moe" in layer for layer in tree["layers"])
+    assert expert_layers and aot.expert_layer_counts(text, tree) == {
+        "ragged-dot": 0, "kernel calls": 2 * expert_layers, "expert-stack copies": 0}
+    # the trace names an operation by its instruction: what
+    # ``moe_gmm_roofline`` times is every kernel call and nothing else
+    calls = re.findall(r"%(\S+) = \S+ custom-call\(.*custom_call_target=\"tpu_custom_call\"", text)
+    with open(os.path.join(ROOT, "benchmark/metrics/moe_gmm_roofline.json")) as f:
+        named = json.load(f)["args"]["ops"]
+    assert len(calls) == 2 * expert_layers and all(re.search(named, c) for c in calls), calls
+    others = re.findall(r"%(\S+) = \S+ (?!custom-call)[\w\-]+\(", text)
+    assert not [o for o in others if re.search(named, o)]
+    _, K, N = tree["layers"][-1]["moe"]["gate"].shape
+    for k, n, matrices, out in ((K, N, 2, 2), (N, K, 1, 4)):
+        tn = gm._column_tile(k, n, matrices, 2)
+        assert n % tn == 0 and tn % 128 == 0
+        assert gm._vmem_bytes(128, k, tn, matrices, 2, out) < 64 << 20
 
 
 @pytest.mark.parametrize(

@@ -72,6 +72,7 @@ from jax.experimental import topologies
 from jax.sharding import SingleDeviceSharding
 
 from ray_tpu import models
+from ray_tpu.ops import flash_attention
 
 
 def results_of(ops, shape, kinds="copy") -> int:
@@ -105,14 +106,37 @@ def loops_of(text: str) -> dict:
     return found
 
 
-def report(name: str, compiled, watch, pool) -> None:
-    """A program's counts (``watch`` is (label, count of ``ops``) pairs) and
-    the loops it holds."""
+def expert_layer_counts(text: str, tree) -> dict:
+    """What a compiled program makes of the expert layers of ``tree`` (the
+    stacks ``[experts, .., ..]`` under a layer's ``moe``): its
+    ``ragged-dot`` operations, the calls of a Pallas kernel (the grouped
+    products of ``ops/grouped_matmul.py``, two an expert layer, where the
+    program holds no other kernel), and the stacks it writes anew, in any
+    layout: a stack relaid for a kernel costs its bytes a call."""
+    ops = [ln for ln in text.splitlines() if re.match(r"\s*(ROOT )?%\S+ = ", ln)]
+    stacks = {leaf.shape for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]
+              if "'moe'" in jax.tree_util.keystr(path) and len(leaf.shape) == 3}
+    if not stacks:
+        return {}
+    return {
+        "ragged-dot": sum(" ragged-dot(" in ln for ln in ops),
+        "kernel calls": text.count('custom_call_target="tpu_custom_call"'),
+        "expert-stack copies": sum(
+            results_of(ops, s, "copy|fusion|transpose|convert|reshape") for s in stacks),
+    }
+
+
+def report(name: str, compiled, watch, pool, tree=None) -> None:
+    """A program's counts (``watch`` is (label, count of ``ops``) pairs),
+    what it makes of the expert layers of ``tree``, and the loops it holds."""
     text = compiled.as_text()
     ops = [ln for ln in text.splitlines() if re.match(r"\s*(ROOT )?%\S+ = ", ln)]
     remat = sum("remat" in ln.split(" = ")[0] for ln in ops)
     mem = compiled.memory_analysis()
-    counts = "  ".join(f"{label} {count(ops):2d}" for label, count in watch)
+    counted = {label: count(ops) for label, count in watch}
+    if tree is not None:
+        counted.update(expert_layer_counts(text, tree))
+    counts = "  ".join(f"{label} {n:2d}" for label, n in counted.items())
     print(
         f"{name:38s} remat ops {remat:2d} ({text.count('remat'):2d} mentions)  {counts}  "
         f"arguments {mem.argument_size_in_bytes / 1e9:5.2f} GB  "
@@ -220,6 +244,9 @@ def main() -> None:
                          "PREFILL_ROWS holds it): [R, P] tokens, a table a row")
     args = ap.parse_args()
 
+    # this process is on the CPU and compiles for the chip: the Pallas
+    # kernels are lowered for it, not interpreted
+    flash_attention._interpret = lambda: False
     cfg, dec = models.resolve(args.model)
     topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     chip = SingleDeviceSharding(topo.devices[0])
@@ -275,7 +302,7 @@ def main() -> None:
                 cache_v, sds((R, max_pages), jnp.int32), a_row,
             )
         for name, lowered in programs.items():
-            report(name, lowered.compile(), watch, first_pool)
+            report(name, lowered.compile(), watch, first_pool, tree)
     if loader:
         print("-- the loader")
         report(loader[0], loader[1](), watch, first_pool)
