@@ -52,7 +52,7 @@ from jax import lax
 from ray_tpu.models.gpt2_decode import (  # noqa: F401 — the engine's interface
     params_bytes, sample, update_rows_paged,
 )
-from ray_tpu.ops import moe, page_loops
+from ray_tpu.ops import moe, page_loops, paged_latent_attention
 
 PREFIX_CACHE = True    # pages of one kind: a sealed page is all of its positions
 DECODE_ATTENTION = "own_latent_pages"
@@ -68,8 +68,8 @@ PREFILL_ROWS = (1, 4)
 PREFILL_ROW_WIDTHS = (128, 256, 512)
 # what a decode program counts beside its tokens: the expert layers' counts
 # summed over layers and steps, the positions its live rows attended over
-# and the positions the attention's loops covered for them, both summed
-# over steps (once a step, not a layer)
+# and the positions the attention's kernel read for them (whole turns, to
+# each row's own length), both summed over steps (once a step, not a layer)
 STEP_COUNTERS = (*(f"moe_{name}" for name in moe.STATS), "mla_context_tokens",
                  "attn_loop_tokens")
 
@@ -308,51 +308,26 @@ def _start(shape, width):
             jnp.zeros((*shape, width), jnp.float32))
 
 
-def _absorbed_attend(cfg: DeepseekV3Config, attn, q_nope, q_rope, pool, tables, pos,
-                     loops: page_loops.Loops):
+def _absorbed_attend(cfg: DeepseekV3Config, attn, q_nope, q_rope, pool, tables, pos):
     """Decode's attention, in the latent space: one query a row, ``q_nope``
     [S, H, N] and ``q_rope`` [S, H, R] at positions ``pos`` [S], over each
     row's own pages of ``pool`` (``tables`` [S, MaxPages]). ``W_kb`` goes
-    into the query, the pages' rows into both products as they lie, and
-    ``W_vb`` takes the weighted latent to the heads. ``loops`` (of ``pos``)
-    are the step's loops over page-table columns, a few a turn, each
-    stopping behind the longest of its own rows. Returns [S, H * V]."""
+    into the query, the pages' rows into both products as they lie in the
+    pool (``ops/paged_latent_attention.py``: one kernel a layer, each row
+    to its own length, no page gathered), and ``W_vb`` takes the weighted
+    latent to the heads. Returns [S, H * V]."""
     dt = cfg.dtype
     S, H, _ = q_nope.shape
-    B, W = pool.shape[1], pool.shape[2]
-    span, C = loops.span, loops.span // B
+    W = pool.shape[2]
     # a product a head (the CPU's compiler has no such product that widens
     # its result, and both results are wanted in ``dt``)
     q_lat = jnp.einsum("shn,hnc->shc", q_nope, attn["wkb"].astype(dt))
     q_cat = jnp.concatenate(
         [q_lat, q_rope, jnp.zeros((S, H, W - cfg.latent_width), dt)], axis=-1)
-    scale = cfg.qk_head_dim ** -0.5
-
-    def make_turn(own):
-        q, table, at = own  # [n, H, W], [n, MaxPages], [n]
-        n = at.shape[0]
-
-        def turn(j, carry):
-            pages = lax.dynamic_slice_in_dim(table, j * C, C, axis=1)  # [n, C]
-            rows = pool[pages].reshape(n, span, W)
-            scores = scale * jnp.einsum("shw,stw->sht", q, rows,
-                                        preferred_element_type=jnp.float32)
-            visible = (j * span + jnp.arange(span))[None, :] <= at[:, None]  # [n, T]
-            return _softmax_turn(
-                carry, scores, visible[:, None],
-                lambda p: jnp.einsum("sht,stw->shw", p.astype(dt), rows,
-                                     preferred_element_type=jnp.float32))
-
-        return turn
-
-    def finish(carry):
-        _, den, acc = carry
-        o_lat = (acc[..., :cfg.kv_lora_rank] / den[..., None]).astype(dt)
-        return jnp.einsum("shc,hcv->shv", o_lat, attn["wvb"].astype(dt)).reshape(
-            den.shape[0], -1)
-
-    return page_loops.run(loops, (q_cat, tables, pos), make_turn,
-                          lambda n: _start((n, H), W), finish)
+    weighted = paged_latent_attention.attend(q_cat, pool, tables, pos,
+                                             scale=cfg.qk_head_dim ** -0.5)
+    return jnp.einsum("shc,hcv->shv", weighted[..., :cfg.kv_lora_rank],
+                      attn["wvb"].astype(dt)).reshape(S, -1)
 
 
 def _expanded_attend(cfg: DeepseekV3Config, attn, q_nope, q_rope, pool, table, pos,
@@ -503,7 +478,6 @@ def _decode_paged_impl(cfg: DeepseekV3Config, params, last_tokens, lengths,
     live = lengths > 0
     x = params["embed"].astype(dt)[last_tokens].astype(jnp.float32)  # [S, D]
     page_of = page_tables[jnp.arange(S), pos // B]
-    loops = page_loops.for_decode(pos, page_tables, B)
     pools = list(cache.layers)
     stats = jnp.zeros((len(moe.STATS),), jnp.int32)
     for l, layer in enumerate(params["layers"]):
@@ -511,12 +485,13 @@ def _decode_paged_impl(cfg: DeepseekV3Config, params, last_tokens, lengths,
         q_nope, q_rope, rows = _queries_and_rows(cfg, layer["attn"], h, pos)
         pools[l] = pools[l].at[page_of, pos % B].set(rows)
         att = _absorbed_attend(cfg, layer["attn"], q_nope, q_rope, pools[l],
-                               page_tables, pos, loops)
+                               page_tables, pos)
         x, counted = _rest_of_block(cfg, layer, x, att, live)
         stats = stats + counted
     context = jnp.sum(jnp.where(live, pos + 1, 0), dtype=jnp.int32)
+    read = paged_latent_attention.positions_read(pos, live, page_tables.shape[1], B)
     return (_logits(cfg, params, x), LatentCache(tuple(pools), B), none,
-            jnp.concatenate([stats, context[None], loops.covered[None]]))
+            jnp.concatenate([stats, context[None], read[None]]))
 
 
 @partial(jax.jit, static_argnums=(0,), donate_argnums=(4, 5))

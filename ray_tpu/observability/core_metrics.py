@@ -344,8 +344,10 @@ def _build() -> dict:
             "rt_serve_attn_loop_tokens_total",
             "positions the decode steps' loops over page-table columns "
             "covered: rows of a group x its turns x positions a turn, "
-            "summed over groups and steps (once a step, not a layer); what "
-            "the live rows attended over is the useful part of it",
+            "summed over groups and steps (once a step, not a layer); in the "
+            "latent family, whose kernel walks each row's own turns, the "
+            "live rows' turns x positions a turn; what the live rows "
+            "attended over is the useful part of it",
             tag_keys=("deployment",),
         ),
         "serve_window_context_tokens": Counter(

@@ -1,6 +1,10 @@
 """The loop of paged attention over a row's page-table columns, a few
-pages a turn, for the models whose decode attends over each row's own pages
-(``models/deepseek_v3.py``, ``models/mimo_v2.py``).
+pages a turn, for the two families whose decode attends over each row's own
+pages of a K pool and a V pool (``models/mimo_v2.py`` and ``models/afmoe.py``,
+through ``ops/cached_attention.py``). The latent family's decode walks its
+one pool in a Pallas kernel since PR 56 (``ops/paged_latent_attention.py``),
+which keeps ``DECODE_PAGES`` as its turn; its prefill still takes
+``pages_a_turn`` from here.
 
 One loop over all rows runs to the longest row's context: every row
 gathers, multiplies and masks the turns behind its own length, which add

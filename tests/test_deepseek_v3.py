@@ -106,7 +106,7 @@ def test_absorbed_decode_is_expanded_attention_on_the_same_cache(tiny, monkeypat
     args = (cfg, params, last, jnp.asarray(lens, jnp.int32), cache, none, tables)
     absorbed = jax.jit(dec._decode_paged_impl, static_argnums=(0,))(*args)
 
-    def a_row_at_a_time(cfg, attn, q_nope, q_rope, pool, tables, pos, loops):
+    def a_row_at_a_time(cfg, attn, q_nope, q_rope, pool, tables, pos):
         """Decode's attention the expanded way: prefill's, each row its own
         sequence of one query."""
         return jnp.stack([
@@ -127,23 +127,21 @@ def test_absorbed_decode_is_expanded_attention_on_the_same_cache(tiny, monkeypat
     assert cache.layers[0].shape[-1] == cfg.stored_width == 128
     assert not np.asarray(absorbed[1].layers[0][..., cfg.latent_width:]).any()
     # what the step counted last: the live rows' contexts, the new position
-    # among them, and what one loop of one turn covered for three rows
+    # among them, and the one turn the kernel read for each of three rows
     turn = 16 * page_loops.DECODE_PAGES
     assert list(map(int, absorbed[3][-2:])) == [sum(n + 1 for n in lens), 3 * turn]
 
 
 @pytest.mark.parametrize("rows", [3, 16, 32, 40])
-def test_rows_taken_by_length_attend_as_one_loop_and_as_the_expanded_form(tiny, rows):
-    """The absorbed attention with the rows taken by length, a loop a
-    group, against the one loop over all rows (<= 1e-6 in float32: a turn
-    behind a row's length adds exact zeros) and against the expanded form a
-    row at a time, on rows of every length in shuffled order; and the rows
-    permuted give the same rows permuted. Three rows are fewer than two
-    groups and take the one loop."""
+def test_rows_of_every_length_attend_absorbed_as_in_the_expanded_form(tiny, rows):
+    """The absorbed attention through the kernel that walks each row's own
+    pages (``ops/paged_latent_attention.py``) against the expanded form a
+    row at a time, on rows of every length in shuffled order, their pages
+    scattered over the pool; and the rows permuted give the same rows
+    permuted, to the bit: a row's result does not know its neighbours."""
     import jax.numpy as jnp
 
     from ray_tpu.models import deepseek_v3 as dec
-    from ray_tpu.ops import page_loops
 
     cfg, params, _ = tiny
     attn = params["layers"][1]["attn"]
@@ -153,30 +151,24 @@ def test_rows_taken_by_length_attend_as_one_loop_and_as_the_expanded_form(tiny, 
     draw = lambda *shape: jnp.asarray(rng.normal(0, 1, shape), jnp.float32)
     q_nope, q_rope = draw(rows, H, cfg.qk_nope_head_dim), draw(rows, H, cfg.qk_rope_head_dim)
     pool = draw(N, B, cfg.stored_width).at[..., cfg.latent_width:].set(0.0)
-    pos, tables, span = jnp.asarray(pos, jnp.int32), jnp.asarray(tables), 4 * B
-    loops = page_loops.by_length(pos, span)
-    groups = {3: 1, 16: 2, 32: 4, 40: 4}[rows]
-    assert loops.turns.shape == (groups,) and (loops.order is None) == (groups == 1)
-    got = dec._absorbed_attend(cfg, attn, q_nope, q_rope, pool, tables, pos, loops)
-    one = dec._absorbed_attend(cfg, attn, q_nope, q_rope, pool, tables, pos,
-                               page_loops.one_loop(pos, span))
-    assert float(jnp.abs(one).max()) > 0.1
-    assert float(jnp.abs(got - one).max()) <= 1e-6
+    pos, tables = jnp.asarray(pos, jnp.int32), jnp.asarray(tables)
+    got = dec._absorbed_attend(cfg, attn, q_nope, q_rope, pool, tables, pos)
+    assert float(jnp.abs(got).max()) > 0.1
     expanded = jnp.stack([
         dec._expanded_attend(cfg, attn, q_nope[r:r + 1], q_rope[r:r + 1], pool,
                              tables[r], pos[r:r + 1])[0] for r in range(rows)])
     assert float(jnp.abs(got - expanded).max()) < 1e-4
     perm = np.random.default_rng(rows).permutation(rows)
     moved = dec._absorbed_attend(cfg, attn, q_nope[perm], q_rope[perm], pool, tables[perm],
-                                 pos[perm], page_loops.by_length(pos[perm], span))
-    assert float(jnp.abs(moved - got[perm]).max()) <= 1e-6
+                                 pos[perm])
+    assert np.array_equal(np.asarray(moved), np.asarray(got[perm]))
 
 
-def test_the_step_counts_what_its_loops_covered(tiny):
-    """``attn_loop_tokens`` is rows of a group x its turns x the positions a
-    turn, summed over the groups, written out by hand for 32 rows (four
-    groups of eight, by length); ``mla_context_tokens`` is what it was: the
-    live rows' positions, the new one among them."""
+def test_the_step_counts_what_its_kernel_read(tiny):
+    """``attn_loop_tokens`` is what the attention's kernel reads for the
+    live rows: each row's own turns x the positions a turn, written out by
+    hand for 32 rows; ``mla_context_tokens`` is what it was: the live rows'
+    positions, the new one among them."""
     import jax
     import jax.numpy as jnp
 
@@ -186,12 +178,13 @@ def test_the_step_counts_what_its_loops_covered(tiny):
     cfg, params, _ = tiny
     B, S = 16, 32
     turn = B * page_loops.DECODE_PAGES
-    # eight rows nobody holds, then three groups whose longest rows stand
-    # one short of a turn, at a turn and at 1,400: one turn, two, 1,400 // turn + 1
-    lens = ([0] * 8 + [1, 5, 9, 20, 33, 40, turn - 2, turn - 1] + [turn] * 8
-            + [turn + 1, 300, 500, 699, 700, 1000, 1399, 1400])
-    long = 1400 // turn + 1
-    lens = np.asarray(lens)[np.random.default_rng(2).permutation(S)]
+    # eight rows nobody holds, which count nowhere; rows inside their first
+    # turn, its last position among them; rows whose new position opens the
+    # second; and rows up to 1,400
+    short = [1, 5, 9, 20, 33, 40, turn - 2, turn - 1]
+    long = [turn + 1, 300, 500, 699, 700, 1000, 1399, 1400]
+    lens = np.asarray([0] * 8 + short + [turn] * 8 + long)
+    lens = lens[np.random.default_rng(2).permutation(S)]
     tables = np.zeros((S, cfg.n_positions // B), np.int32)
     at = 1
     for r, n in enumerate(lens):
@@ -203,10 +196,12 @@ def test_the_step_counts_what_its_loops_covered(tiny):
         cfg, params, jnp.zeros((S,), jnp.int32), jnp.asarray(lens, jnp.int32), cache, none,
         jnp.asarray(tables))
     by_name = dict(zip(dec.STEP_COUNTERS, map(int, out[3])))
-    assert by_name["attn_loop_tokens"] == 8 * turn * (1 + 1 + 2 + long)
+    assert by_name["attn_loop_tokens"] == turn * (8 * 1 + 8 * 2 + sum(n // turn + 1 for n in long))
     assert by_name["mla_context_tokens"] == int(sum(n + 1 for n in lens if n))
-    # one loop would have covered every row to the longest
-    assert by_name["attn_loop_tokens"] < 32 * turn * long
+    # four loops by length (PR 49) covered every row to its group's longest
+    assert by_name["attn_loop_tokens"] < 8 * turn * (1 + 1 + 2 + 1400 // turn + 1)
+    # and no row reads a whole turn behind its own length
+    assert by_name["attn_loop_tokens"] < by_name["mla_context_tokens"] + 24 * turn
 
 
 def test_a_chunk_of_k_steps_is_k_single_steps(tiny):

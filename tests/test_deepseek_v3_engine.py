@@ -89,12 +89,13 @@ def test_the_steps_counts_come_back_with_the_tokens(engine):
     # the row attends over 6, 7, .. 13 positions: the prompt, what it has
     # generated, the new position
     assert _series("rt_serve_mla_context_tokens_total") - context == sum(range(6, 14))
-    # what the one loop covered for them: 8 steps x the engine's 8 rows (one
-    # live, seven nobody's) x one turn of pages of 64
+    # what the attention's kernel read for them: 8 steps x one turn of pages
+    # of 64 for the one live row; the engine's seven rows nobody holds count
+    # nowhere
     from ray_tpu.ops import page_loops
 
     turn = 64 * page_loops.DECODE_PAGES
-    assert _series("rt_serve_attn_loop_tokens_total") - covered == 8 * 8 * turn
+    assert _series("rt_serve_attn_loop_tokens_total") - covered == 8 * turn
     assert _series("rt_serve_moe_assignments_total") > 0
     assert 0 < _series("rt_serve_moe_experts_hit_total") <= _series("rt_serve_moe_expert_steps_total")
 

@@ -427,10 +427,10 @@ def test_compiled_mimo_decode_attention_splits_no_cached_heads(one_chip, time_li
 
 HLO_LOOPS = {
     "a group's page loop": (
-        "  %while.27 = (s32[]{:T(128)}, f32[32,32]{1,0:T(8,128)S(1)}, f32[32,32]{1,0:T(8,128)}, "
-        "f32[32,32,640]{2,1,0:T(8,128)}, /*index=5*/s32[32,512]{1,0:T(8,128)}) "
+        "  %while.27 = (s32[]{:T(128)}, f32[32,32,1]{2,1,0:T(8,128)S(1)}, f32[32,32,1]{2,1,0:T(8,128)}, "
+        "f32[32,32,128]{2,1,0:T(8,128)}, /*index=5*/s32[32,512]{1,0:T(8,128)}) "
         "while(%tuple.474), condition=%c, body=%b",
-        {"(s32[],f32[32,32],..)": 1}),
+        {"(s32[],f32[32,32,1],..)": 1}),
     "an expert layer's loop": (
         "  %while.32 = (s32[]{:T(128)}, f32[128,2048]{1,0:T(8,128)}, s32[]{:T(128)}) "
         "while(%tuple.1), condition=%c, body=%b",
@@ -446,10 +446,39 @@ HLO_LOOPS = {
 def test_the_tool_names_a_loop_as_the_trace_does(case):
     """``tools/aot_serving_programs.loops_of`` reads a compiled program's
     ``while`` operations by how their carry opens, the name the chip's trace
-    gives them and ``mla_roofline`` matches (``benchmark/metrics/
-    mla_roofline.json``: ``^while \\(s32\\[\\],f32\\[\\d+,32\\],``)."""
+    gives them and ``window_attn_roofline`` matches a ring's by
+    (``benchmark/metrics/window_attn_roofline.json``)."""
     text, want = HLO_LOOPS[case]
     assert aot.loops_of(text + "\n" + text) == {k: 2 * v for k, v in want.items()}
+
+
+HLO_KERNELS = {
+    "the latent attention's, its result kept in fast memory": (
+        "  %paged_latent_attention.12 = bf16[128,32,640]{2,1,0:T(8,128)(2,1)S(1)} custom-call("
+        "%bitcast.221, %copy-done.89, /*index=5*/%fusion.47), "
+        'custom_call_target="tpu_custom_call", operand_layout_constraints={s32[65536]{0}}',
+        {"paged_latent_attention": 1}),
+    "an expert layer's two": (
+        "  %grouped_matmul.2 = bf16[768,768]{1,0:T(8,128)(2,1)} custom-call(%max.4, %g.1, %u.1), "
+        'custom_call_target="tpu_custom_call"\n'
+        "  ROOT %grouped_matmul = f32[768,2048]{1,0:T(8,128)} custom-call(%max.4, %d.1), "
+        'custom_call_target="tpu_custom_call"',
+        {"grouped_matmul": 2}),
+    "another custom call, and a fusion of a kernel's result": (
+        '  %custom-call.1 = s32[8192]{0} custom-call(%p), custom_call_target="AssumeGatherIndicesInBound"\n'
+        "  %fusion.3 = f32[768,2048]{1,0} fusion(%grouped_matmul.3), kind=kLoop",
+        {}),
+}
+
+
+@pytest.mark.parametrize("case", sorted(HLO_KERNELS))
+def test_the_tool_names_a_kernel_as_the_trace_does(case):
+    """``tools/aot_serving_programs.kernels_of`` reads a compiled program's
+    Pallas calls by the kernel's name, which is the operation's name in the
+    chip's trace and what ``mla_paged_roofline`` and ``moe_gmm_roofline``
+    match."""
+    text, want = HLO_KERNELS[case]
+    assert aot.kernels_of(text + "\n" + text) == {k: 2 * v for k, v in want.items()}
 
 
 def test_compiled_latent_programs_copy_no_pool_and_decode_builds_no_head(
@@ -462,9 +491,14 @@ def test_compiled_latent_programs_copy_no_pool_and_decode_builds_no_head(
     with the row left 576 wide the compiler laid the pools out pages-minor
     and a decode step held 20; PERF.md, PR 48), the pools enter in the
     layout ``init_paged_cache`` wrote, and the decode programs hold no K
-    or V a head of a cached span: decode attends in the latent space."""
+    or V a head of a cached span: decode attends in the latent space, in
+    one call of ``ops/paged_latent_attention.py``'s kernel a layer, which
+    takes the pool where it lies: no operation's result is a turn's pages
+    gathered, and no loop over page-table columns is left."""
+    from unittest import mock
+
     from ray_tpu.models import deepseek_v3 as m
-    from ray_tpu.ops import page_loops
+    from ray_tpu.ops import flash_attention as fa
 
     cfg = m.CONFIGS["kanana-2-30b-a3b"]
     S, N, Bx, mp = 128, 16385, 64, 512
@@ -485,17 +519,18 @@ def test_compiled_latent_programs_copy_no_pool_and_decode_builds_no_head(
             sds((2,), jnp.uint32))
     i32 = sds((), jnp.int32)
 
-    def compiled(lowered):
-        c = lowered.compile()
+    def compiled(lower):
+        with mock.patch.object(fa, "_interpret", lambda: False):  # for the chip
+            c = lower().compile()
         return c.as_text(), c.memory_analysis().temp_size_in_bytes
 
     programs = {
         # these very shapes: compiled once for this file's tests
         "decode_paged_and_sample": lambda: cell_program(
             m, "kanana-2-30b-a3b", one_chip, "decode"),
-        "decode_multi_paged": lambda: compiled(m.decode_multi_paged.lower(
+        "decode_multi_paged": lambda: compiled(lambda: m.decode_multi_paged.lower(
             cfg, p, *rows, pool, none, *tail, i32, i32)),
-        "prefill_paged": lambda: compiled(m.prefill_paged.lower(
+        "prefill_paged": lambda: compiled(lambda: m.prefill_paged.lower(
             cfg, p, sds((1, 512), jnp.int32), i32, i32, pool, none, sds((mp,), jnp.int32))),
     }
     dims = ",".join(map(str, whole))
@@ -511,17 +546,25 @@ def test_compiled_latent_programs_copy_no_pool_and_decode_builds_no_head(
         if name.startswith("decode"):
             heads = re.findall(rf"= \w+\[{a_head}\]\S* [\w\-]+\(", text)
             assert not heads, (name, heads[:3])
-            # a loop a group a layer, its carry opening with the group's
-            # running maximum a head (what ``mla_roofline`` knows it by), and
-            # no turn's pages gathered for more rows than a group's
-            G, C = page_loops.GROUPS, page_loops.DECODE_PAGES
-            loops = aot.loops_of(text)
-            assert loops.get(f"(s32[],f32[{S // G},{H}],..)") == G * cfg.n_layer, (name, loops)
-            with open(os.path.join(ROOT, "benchmark/metrics/mla_roofline.json")) as f:
+            # one call of the kernel a layer beside the expert layers' two,
+            # under the name ``mla_paged_roofline`` knows it by
+            experts = cfg.n_layer - cfg.first_k_dense_replace
+            assert aot.kernels_of(text) == {
+                "paged_latent_attention": cfg.n_layer, "grouped_matmul": 2 * experts}, name
+            with open(os.path.join(ROOT, "benchmark/metrics/mla_paged_roofline.json")) as f:
                 named = json.load(f)["args"]["ops"]
-            assert re.search(named, f"while (s32[],f32[{S // G},{H}],..) 1in"), named
-            gathered = set(map(int, re.findall(rf"= bf16\[(\d+),{Bx},{whole[2]}\]", text)))
-            assert gathered - {N} == {S // G * C}, (name, gathered)
+            assert re.search(named, "paged_latent_attention.11"), named
+            # the loops over page-table columns are gone, and what
+            # ``mla_roofline`` knew them by matches nothing
+            with open(os.path.join(ROOT, "benchmark/metrics/mla_roofline.json")) as f:
+                old = json.load(f)["args"]["ops"]
+            loops = aot.loops_of(text)
+            assert not [k for k in loops if re.search(old, f"while {k} 1in")], (name, loops)
+            # nothing [.., .., stored width] but the queries a head and the
+            # pools: no turn's pages gathered, as [rows, positions, width]
+            # or as [rows x pages, page, width]
+            rows_wide = set(re.findall(rf"= bf16\[(\d+,\d+),{whole[2]}\]", text))
+            assert rows_wide <= {f"{S},{H}", f"{N},{Bx}"}, (name, rows_wide)
 
 
 _COMPILED = {}
@@ -689,11 +732,13 @@ def test_compiled_expert_layers_are_the_kernel_and_copy_no_stack(
     assert expert_layers and aot.expert_layer_counts(text, tree) == {
         "ragged-dot": 0, "kernel calls": 2 * expert_layers, "expert-stack copies": 0}
     # the trace names an operation by its instruction: what
-    # ``moe_gmm_roofline`` times is every kernel call and nothing else
+    # ``moe_gmm_roofline`` times is every call of this kernel and nothing
+    # else (the latent family's decode holds its attention's kernel too)
     calls = re.findall(r"%(\S+) = \S+ custom-call\(.*custom_call_target=\"tpu_custom_call\"", text)
     with open(os.path.join(ROOT, "benchmark/metrics/moe_gmm_roofline.json")) as f:
         named = json.load(f)["args"]["ops"]
-    assert len(calls) == 2 * expert_layers and all(re.search(named, c) for c in calls), calls
+    calls = [c for c in calls if re.search(named, c)]
+    assert len(calls) == 2 * expert_layers == aot.kernels_of(text)["grouped_matmul"], calls
     others = re.findall(r"%(\S+) = \S+ (?!custom-call)[\w\-]+\(", text)
     assert not [o for o in others if re.search(named, o)]
     _, K, N = tree["layers"][-1]["moe"]["gate"].shape

@@ -5,9 +5,12 @@ Compiles ``decode_paged_and_sample``, ``decode_multi_paged`` and
 decode module) for a described v5e at the engine's own sizes, and prints
 for each: operations with ``remat`` in their name (and how often the text
 says the word), the relays its family watches for, ``memory_analysis()``'s
-arguments and temporaries, the layout the first pool enters in, and the
+arguments and temporaries, the layout the first pool enters in, the
 loops it holds by how their carry opens (the paged attention's loops over
-page-table columns, a loop a group of rows a layer: ``ops/page_loops.py``).
+page-table columns, a loop a group of rows a layer: ``ops/page_loops.py``)
+and the Pallas kernels it calls, by name (the latent family's decode
+attention is one a layer, ``ops/paged_latent_attention.py``, and holds no
+such loop).
 
 What a family watches for (a relay is an operation that writes an array
 anew in another layout):
@@ -106,13 +109,26 @@ def loops_of(text: str) -> dict:
     return found
 
 
+def kernels_of(text: str) -> dict:
+    """The calls of Pallas kernels in a compiled program, by the kernel's
+    name (the ``name`` of its ``pallas_call``, which the chip's trace shows
+    as the operation's: ``grouped_matmul.3``, ``paged_latent_attention.11``),
+    with how many of each the text holds."""
+    found = {}
+    for name in re.findall(
+            r"%([A-Za-z_]\w*?)(?:\.\d+)? = [^\n]*? custom-call\([^\n]*"
+            r'custom_call_target="tpu_custom_call"', text):
+        found[name] = found.get(name, 0) + 1
+    return found
+
+
 def expert_layer_counts(text: str, tree) -> dict:
     """What a compiled program makes of the expert layers of ``tree`` (the
     stacks ``[experts, .., ..]`` under a layer's ``moe``): its
-    ``ragged-dot`` operations, the calls of a Pallas kernel (the grouped
-    products of ``ops/grouped_matmul.py``, two an expert layer, where the
-    program holds no other kernel), and the stacks it writes anew, in any
-    layout: a stack relaid for a kernel costs its bytes a call."""
+    ``ragged-dot`` operations, the calls of the grouped products' kernel
+    (``ops/grouped_matmul.py``, two an expert layer), and the stacks it
+    writes anew, in any layout: a stack relaid for a kernel costs its bytes
+    a call."""
     ops = [ln for ln in text.splitlines() if re.match(r"\s*(ROOT )?%\S+ = ", ln)]
     stacks = {leaf.shape for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]
               if "'moe'" in jax.tree_util.keystr(path) and len(leaf.shape) == 3}
@@ -120,7 +136,7 @@ def expert_layer_counts(text: str, tree) -> dict:
         return {}
     return {
         "ragged-dot": sum(" ragged-dot(" in ln for ln in ops),
-        "kernel calls": text.count('custom_call_target="tpu_custom_call"'),
+        "kernel calls": kernels_of(text).get("grouped_matmul", 0),
         "expert-stack copies": sum(
             results_of(ops, s, "copy|fusion|transpose|convert|reshape") for s in stacks),
     }
@@ -143,8 +159,9 @@ def report(name: str, compiled, watch, pool, tree=None) -> None:
         f"temporaries {mem.temp_size_in_bytes / 1e9:5.2f} GB  "
         f"pools enter as {' '.join(entry_layouts(text, pool)) or '-'}"
     )
-    print(f"{'':38s} loops  " + ("  ".join(
-        f"{n} x {carry}" for carry, n in sorted(loops_of(text).items())) or "none"))
+    for what, found in (("loops", loops_of(text)), ("kernels", kernels_of(text))):
+        print(f"{'':38s} {what}  " + ("  ".join(
+            f"{n} x {name}" for name, n in sorted(found.items())) or "none"))
 
 
 def gpt2_family(cfg, dec, stored_k, key):
