@@ -3,8 +3,8 @@
 A served model is a config and a decode module, found by ``model_id``
 (``resolve``). The decode module is the engine's interface, the same
 names whichever model implements them (``models/gpt2_decode.py``,
-``models/mimo_v2.py``, ``models/deepseek_v3.py`` and ``models/afmoe.py``
-do):
+``models/mimo_v2.py``, ``models/deepseek_v3.py``, ``models/afmoe.py`` and
+``models/phi4flash.py`` do):
 
     load_serving_params(cfg, checkpoint_path)   the stored weights
     params_bytes(params)
@@ -32,6 +32,11 @@ do):
                                                 program counts beside its
                                                 tokens (its last result)
 
+A module whose prompt positions stop short of its last layers also says
+how many of a prefill call's went through them all
+(``prefill_cross_positions(rows, tokens)``, ``models/phi4flash.py``: the
+engine counts them where the name is there).
+
 A family's modules are imported when a model of it is first resolved and
 not before: a replica that serves GPT-2 never imports another family.
 """
@@ -48,6 +53,7 @@ FAMILIES = {
     "mimo-v2": ("ray_tpu.models.mimo_v2", "ray_tpu.models.mimo_v2"),
     "kanana-2": ("ray_tpu.models.deepseek_v3", "ray_tpu.models.deepseek_v3"),
     "trinity": ("ray_tpu.models.afmoe", "ray_tpu.models.afmoe"),
+    "phi-4-mini-flash": ("ray_tpu.models.phi4flash", "ray_tpu.models.phi4flash"),
 }
 
 

@@ -36,7 +36,11 @@ _LATENCY_BOUNDS = (
 _BATCH_BOUNDS = (1, 2, 4, 8, 16, 32, 64, 128)
 # the kinds of layer a serving cache is made of, a ``serve_kv_<kind>_bytes``
 # gauge each: what a model's ``cache_layout`` names some of
-KV_KINDS = ("full", "window", "latent")
+KV_KINDS = ("full", "window", "latent", "state")
+# the kinds every engine reports, zero where its model has none of them;
+# ``state`` came later (PR 57) and is reported by the models that name it,
+# so that what another family's ``kv_bytes_by_kind`` reads did not change
+KV_KINDS_EVERY_MODEL = KV_KINDS[:3]
 
 
 def _build() -> dict:
@@ -204,6 +208,14 @@ def _build() -> dict:
             "prompt tokens computed by prefill calls (padding excluded)",
             tag_keys=("deployment",),
         ),
+        "serve_prefill_cross_positions": Counter(
+            "rt_serve_prefill_cross_positions_total",
+            "prompt positions of prefill calls that went through every "
+            "layer, in a model whose other prompt positions stop short of "
+            "its last layers (models/phi4flash.py: the cross-decoder runs "
+            "on a row's last position alone); no series from another model",
+            tag_keys=("deployment",),
+        ),
         "serve_prefill_calls": Counter(
             "rt_serve_prefill_calls_total",
             "prefill calls dispatched",
@@ -274,7 +286,8 @@ def _build() -> dict:
         # what the cache holds by kind of layer (``KV_KINDS``): a full
         # layer's pages, a window layer's ring a decode row
         # (models/mimo_v2.py), a latent layer's pages
-        # (models/deepseek_v3.py); a model reads 0 under the kinds it has
+        # (models/deepseek_v3.py), a recurrent layer's state a decode row
+        # (models/phi4flash.py); a model reads 0 under the kinds it has
         # none of
         "serve_kv_full_bytes": Gauge(
             "rt_serve_kv_full_bytes",
@@ -292,6 +305,13 @@ def _build() -> dict:
             "rt_serve_kv_latent_bytes",
             "bytes of latent rows the device holds for paged latent-"
             "attention layers (models/deepseek_v3.py), per engine process",
+            tag_keys=("deployment", "node"),
+        ),
+        "serve_kv_state_bytes": Gauge(
+            "rt_serve_kv_state_bytes",
+            "bytes the device holds for recurrent layers (a state and the "
+            "convolution's last inputs a decode row: models/phi4flash.py), "
+            "per engine process",
             tag_keys=("deployment", "node"),
         ),
         "serve_prefix_refused": Counter(

@@ -1,13 +1,19 @@
 """Attention over a serving cache that keeps K and V a head: a full layer's
 pages and a window layer's ring a decode row, for the families that have
-both kinds of layer (``models/mimo_v2.py``, ``models/afmoe.py``).
+both kinds of layer (``models/mimo_v2.py``, ``models/afmoe.py``,
+``models/phi4flash.py``).
 
 The cache has a spec a layer (the family's ``cache_spec``: kind, K/V heads
 and sizes): a full layer's K and V are paged like GPT-2's, ``[pages, B,
 kv_heads * size]`` with a sequence's pages named by its page table; a
 window layer's are a ring a decode row, ``[rows, window, kv_heads *
 size]``, position p in slot ``p % window``, so that its bytes and its reads
-are the window's however long the row grows.
+are the window's however long the row grows. Two kinds hold no K or V: a
+``state`` layer keeps two arrays a decode row of the shapes and types its
+spec gives (a recurrent layer's state and its convolution's last inputs:
+``ops/selective_scan.py``), and a ``none`` layer keeps nothing (it carries
+nothing from token to token, or attends over another layer's pages, which
+its spec names under ``reads``).
 
 Everything here takes K and V with the heads merged in the minor
 dimension, as the caches store them (``products``). A full layer attends
@@ -25,7 +31,7 @@ from __future__ import annotations
 
 import dataclasses
 from functools import partial
-from typing import Any, Dict, Sequence, Tuple
+from typing import Any, Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -42,7 +48,9 @@ RING_TURNS = 4  # the blocks a ring is read in, where it is read in blocks
 class LayerCache:
     """K or V of every layer, an array a layer by the family's
     ``cache_spec``: a full layer's ``[pages, B, kv_heads * size]``, a window
-    layer's ``[rows, window, kv_heads * size]``; and B beside them. The
+    layer's ``[rows, window, kv_heads * size]``, a state layer's ``[rows,
+    ...]`` as its spec says, an empty array for a layer that keeps nothing;
+    and B beside them. The
     heads stay merged in the last dimension at rest: split into ``[..,
     kv_heads, 192]`` the tiling pads 4 heads to 8 and 192 to 256, and every
     program relaid the whole pool on the way in (0.49 s of a traced 4 s;
@@ -57,16 +65,19 @@ def init_caches(spec: Sequence[Dict[str, Any]], window: int, num_pages: int,
                 page_tokens: int, rows: int, dtype):
     """(k, v) caches, zeroed, for ``rows`` decode rows over ``num_pages``
     pages: the one place that decides the stored shapes."""
-    def make(size_key):
-        return LayerCache(tuple(
-            jnp.zeros(
-                (rows, window, s["kv_heads"] * s[size_key])
-                if s["kind"] == "window"
-                else (num_pages, page_tokens, s["kv_heads"] * s[size_key]),
-                dtype)
-            for s in spec), page_tokens)
+    def make(which):
+        def one(s):
+            if s["kind"] == "none":
+                return jnp.zeros((0,), dtype)
+            if s["kind"] == "state":
+                return jnp.zeros((rows, *s[f"{which}_row"]), s[f"{which}_dtype"])
+            width = s["kv_heads"] * s[f"{which}_size"]
+            return jnp.zeros((rows, window, width) if s["kind"] == "window"
+                             else (num_pages, page_tokens, width), dtype)
 
-    return make("k_size"), make("v_size")
+        return LayerCache(tuple(one(s) for s in spec), page_tokens)
+
+    return make("k"), make("v")
 
 
 def layout(spec: Sequence[Dict[str, Any]], cache_k: LayerCache,
@@ -76,17 +87,23 @@ def layout(spec: Sequence[Dict[str, Any]], cache_k: LayerCache,
     ``rt_serve_kv_*_bytes`` gauges)."""
     held = {"full": 0, "window": 0}
     for s, k, v in zip(spec, cache_k.layers, cache_v.layers):
-        held[s["kind"]] += k.on_device_size_in_bytes() + v.on_device_size_in_bytes()
+        if s["kind"] != "none":
+            held[s["kind"]] = (held.get(s["kind"], 0) + k.on_device_size_in_bytes()
+                               + v.on_device_size_in_bytes())
     return {"shape": [[s["kind"], *k.shape] for s, k in zip(spec, cache_k.layers)],
             "bytes": held}
 
 
-def products(q, kv_heads: int):
+def products(q, kv_heads: int, v_heads: Optional[int] = None):
     """The two products of attention for queries ``q`` [R, Q, H, Dk], as
     functions of K and V with the heads merged in the minor dimension, as
     the caches store them: ``scores(k [R, T, Hkv * Dk])`` -> [R, H, Q, T]
     float32 and ``weighted(p [R, H, Q, T], v [R, T, Hkv * Dv])`` -> [R, H,
-    Q, Dv] float32; query head h reads K/V head ``h // (H / Hkv)``.
+    Q, Dv] float32; query head h reads K/V head ``h // (H / Hkv)``. With
+    ``v_heads`` the values are taken as that many heads instead (query head
+    h weighs value head ``h // (H / v_heads)``, ``Dv`` the wider for it):
+    differential attention's pair of K/V heads, whose values lie side by
+    side as the cache stores them (``models/phi4flash.py``).
 
     Split into ``[R, T, Hkv, size]`` a gathered span of pages or a ring is
     relaid whole (4 or 8 heads are no multiple of 8 sublanes, 192 none of
@@ -103,6 +120,8 @@ def products(q, kv_heads: int):
     are split."""
     R, Q, H, Dk = q.shape
     G = H // kv_heads
+    v_heads = v_heads or kv_heads
+    Gv = H // v_heads
     qg = q.reshape(R, Q, kv_heads, G, Dk)
     scale = Dk ** -0.5
     if Q == 1:
@@ -117,12 +136,12 @@ def products(q, kv_heads: int):
                                       preferred_element_type=jnp.float32)
 
         def weighted(p, v):
-            Dv = v.shape[2] // kv_heads
+            Dv = v.shape[2] // v_heads
             return jnp.concatenate([
-                jnp.einsum("rgqt,rtv->rgqv", p[:, j * G:(j + 1) * G],
+                jnp.einsum("rgqt,rtv->rgqv", p[:, j * Gv:(j + 1) * Gv],
                            v[:, :, j * Dv:(j + 1) * Dv],
                            preferred_element_type=jnp.float32)
-                for j in range(kv_heads)], axis=1)
+                for j in range(v_heads)], axis=1)
     else:
         def scores(k):
             return scale * jnp.einsum(
@@ -132,8 +151,8 @@ def products(q, kv_heads: int):
         def weighted(p, v):
             T = v.shape[1]
             return jnp.einsum(
-                "rjgqt,rtjv->rjgqv", p.reshape(R, kv_heads, G, Q, T),
-                v.reshape(R, T, kv_heads, -1),
+                "rjgqt,rtjv->rjgqv", p.reshape(R, v_heads, Gv, Q, T),
+                v.reshape(R, T, v_heads, -1),
                 preferred_element_type=jnp.float32).reshape(R, H, Q, -1)
 
     return scores, weighted
@@ -173,7 +192,8 @@ def start(R, H, Q, Dv):
 
 
 def paged_attend(q, k_pool, v_pool, tables, q_pos, kv_heads: int,
-                 loops: page_loops.Loops, acc_first: bool = False):
+                 loops: page_loops.Loops, acc_first: bool = False,
+                 v_heads: Optional[int] = None):
     """Causal attention of ``q`` [R, Q, H, Dk] at positions ``q_pos`` [R, Q]
     over each row's own pages of a full layer (``tables`` [R, MaxPages]):
     ``loops`` (of the rows' last positions) over page-table columns, a few
@@ -195,7 +215,7 @@ def paged_attend(q, k_pool, v_pool, tables, q_pos, kv_heads: int,
     def make_turn(own):
         q, table, at = own  # [n, Q, H, Dk], [n, MaxPages], [n, Q]
         n = at.shape[0]
-        scores, weighted = products(q, kv_heads)
+        scores, weighted = products(q, kv_heads, v_heads)
 
         def turn(j, carry):
             pages = lax.dynamic_slice_in_dim(table, j * C, C, axis=1)  # [n, C]
@@ -210,7 +230,7 @@ def paged_attend(q, k_pool, v_pool, tables, q_pos, kv_heads: int,
 
     return page_loops.run(
         loops, (q, tables, q_pos), make_turn,
-        lambda n: turned(start(n, H, Q, v_pool.shape[2] // kv_heads)),
+        lambda n: turned(start(n, H, Q, v_pool.shape[2] // (v_heads or kv_heads))),
         lambda carry: finish(turned(carry)))
 
 
@@ -250,7 +270,7 @@ def ring_loops(pos, window: int) -> page_loops.Loops:
 
 
 def ring_decode_attend(q, ring_k, ring_v, pos, kv_heads: int,
-                       loops: page_loops.Loops):
+                       loops: page_loops.Loops, v_heads: Optional[int] = None):
     """One query a row, ``q`` [S, 1, H, Dk] at ``pos`` [S] (already written
     to its slot), over the row's ring ``[S, window, Hkv * size]`` under
     ``ring_loops``: the ring read as ``RING_TURNS`` pages of its row (a
@@ -265,11 +285,11 @@ def ring_decode_attend(q, ring_k, ring_v, pos, kv_heads: int,
     last = jnp.minimum(pos, W - 1)
     return paged_attend(q, ring_k.reshape(S * n, span, -1),
                         ring_v.reshape(S * n, span, -1), tables, last[:, None],
-                        kv_heads, loops, acc_first=True)
+                        kv_heads, loops, acc_first=True, v_heads=v_heads)
 
 
 def ring_chunk_attend(q, ring_k, ring_v, k, v, first, pos, window: int,
-                      kv_heads: int):
+                      kv_heads: int, v_heads: Optional[int] = None):
     """A prefill chunk in a window layer: ``q`` [R, P, H, Dk] at ``pos``
     [R, P] over the rows' rings ``[R, window, Hkv * size]`` as the earlier
     chunks left them (positions 0 .. ``first`` [R] - 1 written) and the
@@ -279,7 +299,7 @@ def ring_chunk_attend(q, ring_k, ring_v, k, v, first, pos, window: int,
     then the chunk, under one online softmax. Returns [R, P, H * Dv]."""
     R, P, H, _ = q.shape
     span = ring_span(window)
-    scores, weighted = products(q, kv_heads)
+    scores, weighted = products(q, kv_heads, v_heads)
     held = ring_positions(first, window)                          # [R, W]
 
     def visible(k_pos):  # [R, T] -> [R, 1, P, T]
@@ -294,7 +314,7 @@ def ring_chunk_attend(q, ring_k, ring_v, k, v, first, pos, window: int,
 
     filled = jnp.minimum(jnp.max(first), window)
     carry = lax.fori_loop(0, (filled + span - 1) // span, turn,
-                          start(R, H, P, v.shape[2] // kv_heads))
+                          start(R, H, P, v.shape[2] // (v_heads or kv_heads)))
     return finish(softmax_update(carry, scores(k), v, visible(pos), weighted))
 
 

@@ -344,13 +344,15 @@ class LLMServer:
         ]
         # the caches as init_paged_cache stored them and as the device
         # holds them, tiling's padding included, by the kind of layer
-        # (paged full layers, a window layer's ring a row, paged latent rows)
+        # (paged full layers, a window layer's ring a row, paged latent rows,
+        # a recurrent layer's state a row)
         layout = self._dec.cache_layout(self.model_cfg, cache_k, cache_v)
         self._kv_pool_shape = layout["shape"]
-        # every kind there is a gauge for, zero where this model's layout
-        # names none of it
+        # every kind this model's layout names, and zero under the kinds
+        # every engine reports
         self._kv_bytes_by_kind = {
             kind: int(layout["bytes"].get(kind, 0)) for kind in core_metrics.KV_KINDS
+            if kind in layout["bytes"] or kind in core_metrics.KV_KINDS_EVERY_MODEL
         }
         self._kv_pool_bytes = sum(layout["bytes"].values())
         if core_metrics.ENABLED:
@@ -766,11 +768,18 @@ class LLMServer:
                     )
             return True
 
+        # where a model's prompt positions stop short of its last layers,
+        # its word for how many of a call's went through them all
+        cross_positions = getattr(dec, "prefill_cross_positions", None)
+
         def count_prefill(rows: int, tokens: int, positions: int) -> None:
             if core_metrics.ENABLED:
                 core_metrics.serve_prefill_calls.inc(tags=dep_tags)
                 core_metrics.serve_prefill_rows.inc(rows, tags=dep_tags)
                 core_metrics.serve_prefill_tokens.inc(tokens, tags=dep_tags)
+                if cross_positions is not None:
+                    core_metrics.serve_prefill_cross_positions.inc(
+                        cross_positions(rows, tokens), tags=dep_tags)
                 core_metrics.serve_prefill_width.observe(positions, tags=dep_tags)
 
         def seal_prompt(s: _PagedSeq) -> None:

@@ -14,7 +14,8 @@ import pytest
 
 from ray_tpu import models
 
-TINY = ("gpt2-tiny", "mimo-v2-tiny", "kanana-2-tiny", "trinity-tiny")
+TINY = ("gpt2-tiny", "mimo-v2-tiny", "kanana-2-tiny", "trinity-tiny",
+        "phi-4-mini-flash-tiny")
 
 
 def interface_names():

@@ -800,3 +800,57 @@ def test_compiled_train_step_copies_no_attention_operand(one_chip, time_limit, m
     q = r"32,1024,768|32,1024,12,64|32,12,1024,64|384,1024,64"
     copies = re.findall(rf"= (?:bf16|f32)\[(?:{q})\]\S* copy\(", text)
     assert not copies, copies
+
+
+def test_compiled_phi4flash_programs_copy_no_pool_no_ring_and_no_state(one_chip, time_limit):
+    """Phi-4-mini-flash's decode step and its largest prefill call at the
+    cell's shapes (PR 57), beside 7.7 GB of weights, 2.68 GB of rings, 2.68
+    GB of pool and 0.41 GB of states: no copy of the full layer's pool,
+    none of a ring (stored ``[128, 512, 1280]`` or read in blocks ``[512,
+    128, 1280]``) and none of a Mamba layer's state ``[128, 16, 5120]`` (read
+    and written every step: a relay would double its bytes). Decode holds
+    four ring loops a window layer, whose carry opens with the weighted sum
+    (the name ``window_attn_roofline`` matches), and four page loops in EACH
+    of the eight layers that attend over layer 17's pages, whose carry
+    opens with the running maximum (the name ``shared_kv_roofline``
+    matches); prefill holds a scan a Mamba layer, a loop a window layer and
+    one in the full layer over the chunk, and one a cross layer over one
+    position a row."""
+    from benchmark import trace as trace_mod
+    from ray_tpu.models import phi4flash as m
+
+    cfg, counted, loops, temps = largest_prefill_of_rows(
+        m, "phi-4-mini-flash-reasoning", one_chip, aot.phi4flash_family)
+    R, P = m.PREFILL_ROWS[-1], m.PREFILL_ROW_WIDTHS[-1]
+    assert counted["whole-pool copies"] == 0 and counted["ring copies"] == 0, counted
+    assert counted["state copies"] == 0, counted
+    assert temps < 0.5e9, temps
+    kinds = [cfg.kind(l) for l in range(cfg.n_layer)]
+    H = cfg.num_attention_heads
+    assert loops == {
+        f"(s32[],f32[{R},{cfg.mamba_d_state},{cfg.d_inner}],..)": kinds.count("mamba"),
+        f"(s32[],f32[{R},{H},{P}],..)": kinds.count("window") + 1,
+        f"(s32[],f32[{R},{H},1],..)": kinds.count("cross")}, loops
+
+    text, temps = cell_program(m, "phi-4-mini-flash-reasoning", one_chip, "decode")
+    ops = [ln for ln in text.splitlines() if re.match(r"\s*(ROOT )?%\S+ = ", ln)]
+    stored = jax.eval_shape(
+        lambda: m.init_paged_cache(cfg, 32 * (cfg.n_positions // 64) + 1, 64, 128))
+    _, watch, _ = aot.phi4flash_family(cfg, m, stored[0], None)
+    counted = {label: count(ops) for label, count in watch}
+    assert counted == {"whole-pool copies": 0, "ring copies": 0, "K/V split into heads": 0,
+                       "state copies": 0}, counted
+    assert temps < 0.3e9
+    readers = kinds.count("full") + kinds.count("cross")
+    assert aot.loops_of(text) == {
+        f"(s32[],f32[32,{H},1,{2 * cfg.head_dim}],..)": 4 * kinds.count("window"),
+        f"(s32[],f32[32,{H},1],..)": 4 * readers}
+    # the trace's names for them are what the two roofline metrics match
+    with open(os.path.join(ROOT, "benchmark/metrics/shared_kv_roofline.json")) as f:
+        pages = re.compile(json.load(f)["args"]["ops"])
+    with open(os.path.join(ROOT, "benchmark/metrics/window_attn_roofline.json")) as f:
+        rings = re.compile(json.load(f)["args"]["ops"])
+    whiles = [trace_mod.short_op_name(ln.strip()) for ln in ops if ") while(" in ln]
+    assert sum(bool(pages.search(w)) for w in whiles) == 4 * readers
+    assert sum(bool(rings.search(w)) for w in whiles) == 4 * kinds.count("window")
+    assert not [w for w in whiles if pages.search(w) and rings.search(w)]

@@ -33,6 +33,11 @@ anew in another layout):
   ring of 2,048 positions a row is 0.27 GB a layer for K alone, so a ring
   copy in any program costs what a pool copy does.
 
+- Phi-4-mini-flash (``phi4flash``), a cache of three kinds: the one paged
+  layer's pool and the eight rings counted as above, and copies of a Mamba
+  layer's state whole (``state copies``): the state is read and written
+  every step and a relay would double its bytes.
+
 - ``deepseek_v3``, a latent pool a layer: copies of a layer's pool (6 GB
   in all at the benchmark's sizes: one copy does not fit beside it), and K
   or V a head of a cached span (any result ``[.., positions, heads, size]``
@@ -52,6 +57,8 @@ PR 32 and PR 47); it says nothing about time. Run here, on the CPU:
         --max-batch-size 32 --prefill 512 [--prefill-rows 4]
     JAX_PLATFORMS=cpu python tools/aot_serving_programs.py --model trinity-mini \\
         --max-batch-size 32 --prefill 512 [--prefill-rows 2]
+    JAX_PLATFORMS=cpu python tools/aot_serving_programs.py \\
+        --model phi-4-mini-flash-reasoning --max-batch-size 32 --prefill 512 [--prefill-rows 2]
 
 ``--prefill-rows R`` adds the prefill call of R rows (PR 50: the chunks a
 round has to prefill in one call, the expert layers once), with the same
@@ -194,11 +201,13 @@ def mimo_v2_family(cfg, dec, stored_k, key):
     read it in (``ops.cached_attention.ring_span``)."""
     from ray_tpu.ops import cached_attention
 
-    spec = dec.cache_spec(cfg)
+    kept = [(s, k) for s, k in zip(dec.cache_spec(cfg), stored_k.layers)
+            if s["kind"] in ("full", "window")]  # the layers that keep K and V
+    spec = [s for s, _ in kept]
     # a page of positions or more: q is [rows, kv_heads, group, size] too
     positions = rf"\d{{{len(str(stored_k.page_tokens))},}}"
     by_kind = {"full": set(), "window": set()}
-    for s, k in zip(spec, stored_k.layers):
+    for s, k in kept:
         for size in (s["k_size"], s["v_size"]):
             by_kind[s["kind"]].add(k.shape[:2] + (s["kv_heads"] * size,))
             if s["kind"] == "window":
@@ -211,7 +220,7 @@ def mimo_v2_family(cfg, dec, stored_k, key):
     # ``[rows, span, width]``, which is how a gather of whole rings of 2,048
     # positions was compiled (PERF.md, PR 53)
     cut = {(k.shape[0], cached_attention.ring_span(k.shape[1]), s["kv_heads"] * size)
-           for s, k in zip(spec, stored_k.layers) if s["kind"] == "window"
+           for s, k in kept if s["kind"] == "window"
            for size in (s["k_size"], s["v_size"])}
 
     def rings_cut(ops):
@@ -226,6 +235,19 @@ def mimo_v2_family(cfg, dec, stored_k, key):
     ]
     as_held = jax.eval_shape(lambda: dec.load_serving_params(cfg))
     return [("load_serving_params", as_held)], watch, None
+
+
+def phi4flash_family(cfg, dec, stored_k, key):
+    """Phi-4-mini-flash: the pool and the rings as ``mimo_v2_family`` counts
+    them, and copies of a Mamba layer's state whole (``[rows, d_state,
+    d_inner]`` float32, 42 MB a layer at 128 rows: a state relaid every
+    step would double its bytes; the update itself is a fusion and is not
+    counted, nor are the convolution's inputs, a hundredth of the state,
+    which the compiler copies into faster memory ahead of their use)."""
+    trees, watch, loader = mimo_v2_family(cfg, dec, stored_k, key)
+    states = {(r"\d+", *s["k_row"]) for s in dec.cache_spec(cfg) if s["kind"] == "state"}
+    watch.append(("state copies", lambda ops: sum(results_of(ops, dims) for dims in states)))
+    return trees, watch, loader
 
 
 def deepseek_v3_family(cfg, dec, stored, key):
@@ -247,6 +269,7 @@ def deepseek_v3_family(cfg, dec, stored, key):
 FAMILIES = {"ray_tpu.models.gpt2_decode": gpt2_family,
             "ray_tpu.models.mimo_v2": mimo_v2_family,
             "ray_tpu.models.afmoe": mimo_v2_family,
+            "ray_tpu.models.phi4flash": phi4flash_family,
             "ray_tpu.models.deepseek_v3": deepseek_v3_family}
 
 
