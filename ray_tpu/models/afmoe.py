@@ -29,10 +29,11 @@ The cache has a spec a layer (``cache_spec``) and lives in
 and V paged, a sliding layer's a ring a decode row, position p in slot ``p
 % sliding_window``. The ring is 2,048 positions, four prefill chunks: a
 prefill chunk meets the ring a block at a time as far as it is filled and
-then itself; decode reads a row's ring under the same loops as its pages,
-the rows taken by what their rings hold, so a row inside its window reads
-what it has written. A prefix hit would have to restore the rings, which
-nothing does yet: ``PREFIX_CACHE`` is False.
+then itself; decode reads a row's ring in the same kernel as its pages
+(``ops/paged_kv_attention.py``), a block a turn as far as the ring is
+filled, so a row inside its window reads what it has written. A prefix hit
+would have to restore the rings, which nothing does yet: ``PREFIX_CACHE``
+is False.
 
 The programs are the engine's interface, under the names GPT-2's have
 (``models/__init__.py``), and what a step counted rides beside its tokens
@@ -53,7 +54,7 @@ from ray_tpu.models.gpt2_decode import (  # noqa: F401 — the engine's interfac
     params_bytes, sample, update_rows_paged,
 )
 from ray_tpu.ops import cached_attention as ca
-from ray_tpu.ops import moe, page_loops
+from ray_tpu.ops import moe, page_loops, paged_kv_attention
 from ray_tpu.ops.cached_attention import LayerCache
 
 PREFIX_CACHE = False   # a hit would have to restore the sliding layers' rings
@@ -66,7 +67,8 @@ PREFILL_ROWS = (1, 2)
 PREFILL_ROW_WIDTHS = (128, 256, 512)
 # what a decode program counts beside its tokens: the expert layers' counts
 # summed over layers and steps; the positions its live rows attended over in
-# the full layers and the positions those layers' loops covered for them; and
+# the full layers and the positions the kernel read for them (the live rows'
+# pages x positions a page); and
 # the positions the rows' sliding layers attended over, min(p + 1, window);
 # all summed over steps, once a step and not a layer
 STEP_COUNTERS = (*(f"moe_{name}" for name in moe.STATS), "attn_context_tokens",
@@ -373,11 +375,11 @@ def _decode_paged_impl(cfg: AfmoeConfig, params, last_tokens, lengths,
     """One token for every row: [S] last tokens at positions ``lengths``
     write their K/V (full layers through ``page_tables`` [S, MaxPages],
     sliding layers into their row's ring) and attend, full layers over the
-    row's own pages, sliding layers over the ring, both with the rows taken
-    by length. A row of length 0 is nobody's: its full-layer write lands in
-    the scratch page, it writes no ring, and the experts do not see it.
-    Returns logits [S, vocab], the caches and what the step counted
-    (``STEP_COUNTERS``)."""
+    row's own pages, sliding layers over the ring, each row to its own
+    length (``ops/paged_kv_attention.py``). A row of length 0 is nobody's:
+    its full-layer write lands in the scratch page, it writes no ring, and
+    the experts do not see it. Returns logits [S, vocab], the caches and
+    what the step counted (``STEP_COUNTERS``)."""
     dt, Hkv = cfg.dtype, cfg.num_key_value_heads
     S = last_tokens.shape[0]
     B = cache_k.page_tokens
@@ -389,8 +391,8 @@ def _decode_paged_impl(cfg: AfmoeConfig, params, last_tokens, lengths,
     x = _embed(cfg, params, last_tokens)                              # [S, D]
     page_of = page_tables[rows, pos // B]
     slot = jnp.where(live, pos % W, W)  # W is no slot: the write is dropped
-    loops = page_loops.for_decode(pos, page_tables, B)
-    in_rings = ca.ring_loops(pos, W)
+    walk = paged_kv_attention.page_visits(pos, page_tables.shape[1], B)
+    in_rings = ca.ring_visits(pos, W)
     ks, vs = list(cache_k.layers), list(cache_v.layers)
     stats = jnp.zeros((len(moe.STATS),), jnp.int32)
     for l, layer in enumerate(params["layers"]):
@@ -404,14 +406,15 @@ def _decode_paged_impl(cfg: AfmoeConfig, params, last_tokens, lengths,
             ks[l] = ks[l].at[page_of, pos % B].set(k)
             vs[l] = vs[l].at[page_of, pos % B].set(v)
             att = ca.paged_attend(q[:, None], ks[l], vs[l], page_tables,
-                                  pos[:, None], Hkv, loops)[:, 0]
+                                  pos[:, None], Hkv, walk)[:, 0]
         x, counted = _rest_of_block(cfg, layer, x, att, g, live)
         stats = stats + counted
     context = jnp.sum(jnp.where(live, pos + 1, 0), dtype=jnp.int32)
+    read = paged_kv_attention.positions_read(pos, live, B)
     in_window = jnp.sum(jnp.where(live, jnp.minimum(pos + 1, W), 0), dtype=jnp.int32)
     return (_logits(cfg, params, x), LayerCache(tuple(ks), B),
             LayerCache(tuple(vs), B),
-            jnp.concatenate([stats, context[None], loops.covered[None], in_window[None]]))
+            jnp.concatenate([stats, context[None], read[None], in_window[None]]))
 
 
 @partial(jax.jit, static_argnums=(0,), donate_argnums=(4, 5))

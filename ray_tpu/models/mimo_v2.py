@@ -53,7 +53,7 @@ from jax import lax
 from ray_tpu.models.gpt2_decode import (  # noqa: F401 — the engine's interface
     params_bytes, sample, update_rows_paged,
 )
-from ray_tpu.ops import cached_attention, moe, page_loops
+from ray_tpu.ops import cached_attention, moe, page_loops, paged_kv_attention
 # attention over pages and rings, shared with the other family that has both
 # kinds of layer (models/afmoe.py)
 from ray_tpu.ops.cached_attention import (  # noqa: F401
@@ -79,9 +79,9 @@ PREFILL_ROWS = (1, 2)
 PREFILL_ROW_WIDTHS = (128, 256, 512)
 # what a decode program counts beside its tokens: the expert layers' counts
 # summed over layers and steps, the positions its live rows attended over in
-# a full layer and the positions the full layers' loops covered for them,
-# both summed over steps (once a step, not a layer); the engine adds each to
-# its ``rt_serve_<name>_total``
+# a full layer and the positions the kernel read for them (the live rows'
+# pages x positions a page), both summed over steps (once a step, not a
+# layer); the engine adds each to its ``rt_serve_<name>_total``
 STEP_COUNTERS = (*(f"moe_{name}" for name in moe.STATS), "attn_context_tokens",
                  "attn_loop_tokens")
 
@@ -421,7 +421,7 @@ def _decode_paged_impl(cfg: MiMoV2Config, params, last_tokens, lengths,
     page_of = page_tables[rows, pos // B]
     slot = jnp.where(live, pos % W, W)  # W is no slot: the write is dropped
     in_ring = _ring_positions(pos + 1, W) >= 0  # [S, W]
-    loops = page_loops.for_decode(pos, page_tables, B)
+    walk = paged_kv_attention.page_visits(pos, page_tables.shape[1], B)
     ks, vs = list(cache_k.layers), list(cache_v.layers)
     stats = jnp.zeros((len(moe.STATS),), jnp.int32)
     for l, layer in enumerate(params["layers"]):
@@ -437,13 +437,14 @@ def _decode_paged_impl(cfg: MiMoV2Config, params, last_tokens, lengths,
             ks[l] = ks[l].at[page_of, pos % B].set(k)
             vs[l] = vs[l].at[page_of, pos % B].set(v)
             att = _paged_attend(q[:, None], ks[l], vs[l], page_tables,
-                                pos[:, None], Hkv, loops)[:, 0]
+                                pos[:, None], Hkv, walk)[:, 0]
         x, counted = _rest_of_block(cfg, layer, x, att, live)
         stats = stats + counted
     context = jnp.sum(jnp.where(live, pos + 1, 0), dtype=jnp.int32)
+    read = paged_kv_attention.positions_read(pos, live, B)
     return (_logits(cfg, params, x), LayerCache(tuple(ks), B),
             LayerCache(tuple(vs), B),
-            jnp.concatenate([stats, context[None], loops.covered[None]]))
+            jnp.concatenate([stats, context[None], read[None]]))
 
 
 @partial(jax.jit, static_argnums=(0,), donate_argnums=(4, 5))

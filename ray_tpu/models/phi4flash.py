@@ -36,7 +36,7 @@ knows (head h reads h // r). The query heads are therefore taken in another
 order (``_pairs_first``): then the usual grouping holds for the keys, and the
 values, taken as Hkv / 2 heads of 2 Dh as they lie in the cache
 (``v_heads``), fall to the right heads too. Two softmaxes over H heads of Dh
-keys and 2 Dh values: the loops over pages and rings are the other families'.
+keys and 2 Dh values: the walks over pages and rings are the other families'.
 
 The cache has a spec a layer (``cache_spec``): a Mamba layer keeps a
 ``state`` a decode row (the recurrent state in float32, because it is
@@ -66,7 +66,7 @@ from ray_tpu.models.gpt2_decode import (  # noqa: F401 — the engine's interfac
     params_bytes, sample, update_rows_paged,
 )
 from ray_tpu.ops import cached_attention as ca
-from ray_tpu.ops import page_loops, selective_scan
+from ray_tpu.ops import page_loops, paged_kv_attention, selective_scan
 from ray_tpu.ops.cached_attention import LayerCache
 
 PREFIX_CACHE = False   # a hit would have to restore the rings and the states
@@ -78,9 +78,9 @@ PREFILL_ROWS = (1, 2)
 PREFILL_ROW_WIDTHS = (128, 256, 512)
 # what a decode program counts beside its tokens, summed over steps, once a
 # step and not a layer: the positions its live rows attended over in the full
-# layer (every cross layer attends over the same), the positions the loops
-# over pages covered for them, and the positions the window layers attended
-# over, min(p + 1, window)
+# layer (every cross layer attends over the same), the positions the kernel
+# read for them (the live rows' pages x positions a page), and the positions
+# the window layers attended over, min(p + 1, window)
 STEP_COUNTERS = ("attn_context_tokens", "attn_loop_tokens", "window_context_tokens")
 
 MAMBA, WINDOW, FULL, GMU, CROSS = "mamba", "window", "full", "gmu", "cross"
@@ -468,12 +468,13 @@ def _decode_paged_impl(cfg: Phi4FlashConfig, params, last_tokens, lengths,
     advance their Mamba layers' states, write their K/V (the full layer
     through ``page_tables`` [S, MaxPages], window layers into their row's
     ring) and attend: window layers over the ring, the full layer and every
-    cross layer over the row's own pages of the full layer, with the rows
-    taken by length. A row of length 0 is nobody's (a free row, or one
-    whose sequence is still being prefilled): its full-layer write lands in
-    the scratch page, and its rings, states and convolution inputs stay as
-    they are. Returns logits [S, vocab], the caches and what the step
-    counted (``STEP_COUNTERS``)."""
+    cross layer over the row's own pages of the full layer, each row to its
+    own length, under one numbering of the step's visits
+    (``ops/paged_kv_attention.py``). A row of length 0 is nobody's (a free
+    row, or one whose sequence is still being prefilled): its full-layer
+    write lands in the scratch page, and its rings, states and convolution
+    inputs stay as they are. Returns logits [S, vocab], the caches and what
+    the step counted (``STEP_COUNTERS``)."""
     dt, eps, Hkv, W = cfg.dtype, cfg.layer_norm_eps, cfg.num_key_value_heads, cfg.sliding_window
     S = last_tokens.shape[0]
     B = cache_k.page_tokens
@@ -484,8 +485,8 @@ def _decode_paged_impl(cfg: Phi4FlashConfig, params, last_tokens, lengths,
     x = params["embed"][last_tokens].astype(jnp.float32)              # [S, D]
     page_of = page_tables[rows, pos // B]
     slot = jnp.where(live, pos % W, W)  # W is no slot: the write is dropped
-    loops = page_loops.for_decode(pos, page_tables, B)
-    in_rings = ca.ring_loops(pos, W)
+    walk = paged_kv_attention.page_visits(pos, page_tables.shape[1], B)
+    in_rings = ca.ring_visits(pos, W)
     ks, vs = list(cache_k.layers), list(cache_v.layers)
     memory = None
     for l in range(cfg.full_layer + 1):
@@ -510,20 +511,21 @@ def _decode_paged_impl(cfg: Phi4FlashConfig, params, last_tokens, lengths,
                 ks[l] = ks[l].at[page_of, pos % B].set(k)
                 vs[l] = vs[l].at[page_of, pos % B].set(v)
                 att = ca.paged_attend(q[:, None], ks[l], vs[l], page_tables,
-                                      pos[:, None], Hkv, loops, v_heads=Hkv // 2)[:, 0]
+                                      pos[:, None], Hkv, walk, v_heads=Hkv // 2)[:, 0]
             x = x + _differential(cfg, l, mix, att)
         x = x + _mlp(cfg, layer["mlp"], _layernorm(x, layer["norm2"], eps).astype(dt))
     pool_k, pool_v = ks[cfg.full_layer], vs[cfg.full_layer]
 
     def attend(q):
-        return ca.paged_attend(q, pool_k, pool_v, page_tables, pos[:, None], Hkv, loops,
+        return ca.paged_attend(q, pool_k, pool_v, page_tables, pos[:, None], Hkv, walk,
                                v_heads=Hkv // 2)
 
     x = _cross_decoder(cfg, params, x, memory, attend)
     context = jnp.sum(jnp.where(live, pos + 1, 0), dtype=jnp.int32)
+    read = paged_kv_attention.positions_read(pos, live, B)
     in_window = jnp.sum(jnp.where(live, jnp.minimum(pos + 1, W), 0), dtype=jnp.int32)
     return (_logits(cfg, params, x), LayerCache(tuple(ks), B), LayerCache(tuple(vs), B),
-            jnp.stack([context, loops.covered, in_window]))
+            jnp.stack([context, read, in_window]))
 
 
 @partial(jax.jit, static_argnums=(0,), donate_argnums=(4, 5))

@@ -362,12 +362,13 @@ def _build() -> dict:
         ),
         "serve_attn_loop_tokens": Counter(
             "rt_serve_attn_loop_tokens_total",
-            "positions the decode steps' loops over page-table columns "
-            "covered: rows of a group x its turns x positions a turn, "
-            "summed over groups and steps (once a step, not a layer); in the "
-            "latent family, whose kernel walks each row's own turns, the "
-            "live rows' turns x positions a turn; what the live rows "
-            "attended over is the useful part of it",
+            "positions the decode steps' attention kernels read over "
+            "page-table columns, each live row walking its own turns: "
+            "where K and V are pools the row's pages x positions a page (a "
+            "last turn is read as far as the row's last page), in the "
+            "latent family its turns x positions a turn; summed over steps "
+            "(once a step, not a layer); what the live rows attended over "
+            "is the useful part of it",
             tag_keys=("deployment",),
         ),
         "serve_window_context_tokens": Counter(

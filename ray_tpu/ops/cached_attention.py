@@ -17,13 +17,15 @@ its spec names under ``reads``).
 
 Everything here takes K and V with the heads merged in the minor
 dimension, as the caches store them (``products``). A full layer attends
-over each row's own pages (``paged_attend``, under ``ops/page_loops.py``'s
-loops). A window layer attends in one of three ways, by the size of the
-ring beside the queries: in one block over keys as they lie
+over each row's own pages (``paged_attend``): a prefill chunk under one XLA
+loop over page-table columns (``ops/page_loops.py``), decode's one query a
+row in the Pallas kernel of ``ops/paged_kv_attention.py``, which reads the
+pages where they lie. A window layer attends in one of three ways, by the
+size of the ring beside the queries: in one block over keys as they lie
 (``window_attend``: a ring smaller than a prefill chunk), a decode row's
-ring a few blocks a turn under the same loops as the pages, the rows taken
-by what their rings hold (``ring_decode_attend``), and a prefill chunk over
-the blocks of the ring the earlier chunks filled and then over itself
+ring a block a turn in the same kernel as the pages, each row as far as its
+ring is filled (``ring_decode_attend``), and a prefill chunk over the blocks
+of the ring the earlier chunks filled and then over itself
 (``ring_chunk_attend``).
 """
 
@@ -37,7 +39,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.ops import page_loops
+from ray_tpu.ops import page_loops, paged_kv_attention
 
 RING_TURNS = 4  # the blocks a ring is read in, where it is read in blocks
 
@@ -191,47 +193,42 @@ def start(R, H, Q, Dv):
             jnp.zeros((R, H, Q, Dv), jnp.float32))
 
 
-def paged_attend(q, k_pool, v_pool, tables, q_pos, kv_heads: int,
-                 loops: page_loops.Loops, acc_first: bool = False,
-                 v_heads: Optional[int] = None):
+def paged_attend(q, k_pool, v_pool, tables, q_pos, kv_heads: int, loops,
+                 v_heads: Optional[int] = None, name: str = "paged_kv_attention"):
     """Causal attention of ``q`` [R, Q, H, Dk] at positions ``q_pos`` [R, Q]
-    over each row's own pages of a full layer (``tables`` [R, MaxPages]):
-    ``loops`` (of the rows' last positions) over page-table columns, a few
-    a turn, each stopping behind the last position any query of its own
-    rows sees, so a step reads the live context and neither the table's
-    width nor the pool. The gathered pages go into the products as they
-    lie. Returns [R, Q, H * Dv].
+    over each row's own pages of a full layer (``tables`` [R, MaxPages]),
+    in one of two forms by what ``loops`` is. Returns [R, Q, H * Dv] float32.
 
-    ``acc_first`` turns the loops' carry round, the weighted sum before
-    the running maximum: the chip's trace names a loop by how its carry
-    opens, and that is all that tells a loop over a ring's blocks
-    (``(s32[],f32[rows,heads,1,size],..)``) from one over a pool's pages
-    (``(s32[],f32[rows,heads,1],..)``)."""
+    A prefill chunk takes ``page_loops.one_loop`` of the rows' last
+    positions: one loop over page-table columns, a few a turn, that stops
+    behind the last position any query sees, so a call reads the longest
+    row's context and neither the table's width nor the pool; the gathered
+    pages go into the products as they lie.
+
+    Decode's one query a row takes the step's ``paged_kv_attention.Visits``:
+    the same products and the same online softmax, turn for turn, in the
+    kernel that reads each row's pages where they lie and gathers nothing,
+    under ``name`` in the chip's trace."""
+    if isinstance(loops, paged_kv_attention.Visits):
+        out = paged_kv_attention.attend(
+            q[:, 0], k_pool, v_pool, tables, q_pos[:, 0], loops, kv_heads=kv_heads,
+            v_heads=v_heads or kv_heads, name=name)
+        return out.reshape(out.shape[0], 1, -1)
+    R, Q, H, _ = q.shape
     B = k_pool.shape[1]
-    turned = (lambda c: c[::-1]) if acc_first else (lambda c: c)
-    _, Q, H, _ = q.shape
     span, C = loops.span, loops.span // B
+    scores, weighted = products(q, kv_heads, v_heads)
 
-    def make_turn(own):
-        q, table, at = own  # [n, Q, H, Dk], [n, MaxPages], [n, Q]
-        n = at.shape[0]
-        scores, weighted = products(q, kv_heads, v_heads)
+    def turn(j, carry):
+        pages = lax.dynamic_slice_in_dim(tables, j * C, C, axis=1)  # [R, C]
+        kc = k_pool[pages].reshape(R, span, -1)
+        vc = v_pool[pages].reshape(R, span, -1)
+        kv_pos = j * span + jnp.arange(span)
+        visible = kv_pos[None, None, :] <= q_pos[:, :, None]  # [R, Q, T]
+        return softmax_update(carry, scores(kc), vc, visible[:, None], weighted)
 
-        def turn(j, carry):
-            pages = lax.dynamic_slice_in_dim(table, j * C, C, axis=1)  # [n, C]
-            kc = k_pool[pages].reshape(n, span, -1)
-            vc = v_pool[pages].reshape(n, span, -1)
-            kv_pos = j * span + jnp.arange(span)
-            visible = kv_pos[None, None, :] <= at[:, :, None]  # [n, Q, T]
-            return turned(softmax_update(turned(carry), scores(kc), vc,
-                                         visible[:, None], weighted))
-
-        return turn
-
-    return page_loops.run(
-        loops, (q, tables, q_pos), make_turn,
-        lambda n: turned(start(n, H, Q, v_pool.shape[2] // (v_heads or kv_heads))),
-        lambda carry: finish(turned(carry)))
+    return finish(lax.fori_loop(
+        0, loops.turns, turn, start(R, H, Q, v_pool.shape[2] // (v_heads or kv_heads))))
 
 
 def window_attend(q, keys, values, visible, kv_heads: int, sink):
@@ -260,32 +257,31 @@ def ring_span(window: int) -> int:
     return window // RING_TURNS if window % RING_TURNS == 0 else window
 
 
-def ring_loops(pos, window: int) -> page_loops.Loops:
-    """A decode step's loops over its rows' rings, the same in every window
-    layer: a row at ``pos`` [S] holds slots 0 .. min(pos, window - 1), so
-    the rows are taken by that, a loop a group, a block of the ring a turn
-    (``page_loops.by_length``): a row inside its window reads what it has
-    written and a group of such rows stops there."""
-    return page_loops.by_length(jnp.minimum(pos, window - 1), ring_span(window))
+def ring_visits(pos, window: int) -> paged_kv_attention.Visits:
+    """A decode step's visits of its rows' rings, the same in every window
+    layer: a row at ``pos`` [S] holds slots 0 .. min(pos, window - 1) and
+    walks the blocks up to that one, so a row inside its window reads what
+    it has written and a row past it all of its ring."""
+    span = ring_span(window)
+    return paged_kv_attention.visits(jnp.minimum(pos, window - 1), span, window // span)
 
 
 def ring_decode_attend(q, ring_k, ring_v, pos, kv_heads: int,
-                       loops: page_loops.Loops, v_heads: Optional[int] = None):
+                       walk: paged_kv_attention.Visits, v_heads: Optional[int] = None):
     """One query a row, ``q`` [S, 1, H, Dk] at ``pos`` [S] (already written
     to its slot), over the row's ring ``[S, window, Hkv * size]`` under
-    ``ring_loops``: the ring read as ``RING_TURNS`` pages of its row (a
+    ``ring_visits``: the ring read as the pages of its row, a block each (a
     reshape of the array as it lies), every slot up to min(pos, window - 1)
     visible, whatever position it holds: before the ring wraps those are
     the positions written, after it all of them, each within the window.
     Returns [S, 1, H * Dv]."""
     S, W, _ = ring_k.shape
-    span = loops.span
-    n = W // span
+    n = W // walk.span
     tables = jnp.arange(S, dtype=jnp.int32)[:, None] * n + jnp.arange(n, dtype=jnp.int32)
     last = jnp.minimum(pos, W - 1)
-    return paged_attend(q, ring_k.reshape(S * n, span, -1),
-                        ring_v.reshape(S * n, span, -1), tables, last[:, None],
-                        kv_heads, loops, acc_first=True, v_heads=v_heads)
+    return paged_attend(q, ring_k.reshape(S * n, walk.span, -1),
+                        ring_v.reshape(S * n, walk.span, -1), tables, last[:, None],
+                        kv_heads, walk, v_heads=v_heads, name="ring_kv_attention")
 
 
 def ring_chunk_attend(q, ring_k, ring_v, k, v, first, pos, window: int,

@@ -180,10 +180,10 @@ def test_a_chunk_of_k_steps_is_k_single_steps(tiny):
 
 
 def test_the_step_counts_once_a_step_what_rings_and_pages_held(tiny):
-    """32 rows, four groups of eight by length in the full layer's loops and
-    in the rings' (by what a ring holds): ``window_context_tokens`` is
-    min(p + 1, 16) a live row, ``attn_context_tokens`` p + 1, and the full
-    layer's loops cover what ``attn_loop_tokens`` says."""
+    """32 rows of every length, eight of them nobody's: ``window_context_tokens``
+    is min(p + 1, 16) a live row, ``attn_context_tokens`` p + 1, and
+    ``attn_loop_tokens`` what the full layer's kernel reads for the live
+    rows, each row's own pages x the positions a page."""
     import jax
     import jax.numpy as jnp
 
@@ -193,9 +193,8 @@ def test_the_step_counts_once_a_step_what_rings_and_pages_held(tiny):
     cfg, params, _ = tiny
     B, S = 4, 32
     turn = B * page_loops.DECODE_PAGES
-    lens = ([0] * 8 + [1, 2, 3, 4, 5, 6, turn - 2, turn - 1] + [turn] * 8
-            + [turn + 1, 100, 120, 150, 180, 200, 249, 250])
-    long = 250 // turn + 1
+    long = [turn + 1, 100, 120, 150, 180, 200, 249, 250]
+    lens = [0] * 8 + [1, 2, 3, 4, 5, 6, turn - 2, turn - 1] + [turn] * 8 + long
     lens = np.asarray(lens)[np.random.default_rng(2).permutation(S)]
     tables = np.zeros((S, cfg.n_positions // B), np.int32)
     at = 1
@@ -207,7 +206,7 @@ def test_the_step_counts_once_a_step_what_rings_and_pages_held(tiny):
         cfg, params, jnp.zeros((S,), jnp.int32), jnp.asarray(lens, jnp.int32),
         *dec.init_paged_cache(cfg, at, B, S), jnp.asarray(tables))
     by_name = dict(zip(dec.STEP_COUNTERS, map(int, out[3])))
-    assert by_name["attn_loop_tokens"] == 8 * turn * (1 + 1 + 2 + long)
+    assert by_name["attn_loop_tokens"] == B * sum(n // B + 1 for n in lens if n)
     assert by_name["attn_context_tokens"] == int(sum(n + 1 for n in lens if n))
     assert by_name["window_context_tokens"] == int(sum(min(n + 1, 16) for n in lens if n))
     assert bool(jnp.isfinite(out[0]).all())

@@ -2,14 +2,14 @@
 held to a plain softmax over the visible positions at both families' tiny
 presets: MiMo-V2's (a sink a head, K of 24 and V of 16, 1 or 2 K/V heads)
 and Trinity's (no sink, 2 K/V heads of 16). The same functions, the same
-cases: pages, a ring in one block, a ring under decode's loops and a ring
+cases: pages, a ring in one block, a ring in decode's kernel and a ring
 met by a prefill chunk, each with the ring wrapped and not, one query a row
 and several.
 """
 
 import numpy as np
 import pytest
-from test_mimo_v2 import plain_attention  # a softmax a head, written plainly
+from test_mimo_v2 import plain_attention, walk_of  # a softmax a head, written plainly
 
 FAMILIES = ("mimo-v2-tiny", "trinity-tiny")
 
@@ -53,7 +53,6 @@ def test_pages_and_a_ring_in_one_block_are_a_softmax_a_head(model_id, Q):
     import jax.numpy as jnp
 
     from ray_tpu.ops import cached_attention as ca
-    from ray_tpu.ops import page_loops
 
     H, (Hkv, Dk, Dv), W, has_sink = shapes(model_id)
     rng = np.random.default_rng(Q)
@@ -70,7 +69,7 @@ def test_pages_and_a_ring_in_one_block_are_a_softmax_a_head(model_id, Q):
     k_pool, v_pool = draw(N, B, Hkv * Dk), draw(N, B, Hkv * Dv)
     got = ca.paged_attend(q, k_pool, v_pool, jnp.asarray(tables),
                           jnp.asarray(q_pos, jnp.int32), Hkv,
-                          page_loops.by_length(jnp.asarray(last), 2 * B))
+                          walk_of(Q, last, 2 * B, max_pages // 2))
     T = max_pages * B
     visible = np.arange(T)[None, None, :] <= q_pos[:, :, None]
     want = plain_attention(q, k_pool[tables].reshape(R, T, -1),
@@ -90,12 +89,12 @@ def test_pages_and_a_ring_in_one_block_are_a_softmax_a_head(model_id, Q):
 
 @pytest.mark.parametrize("rows", [5, 16, 32])
 @pytest.mark.parametrize("model_id", FAMILIES)
-def test_a_decode_rows_ring_under_the_loops_is_a_softmax_over_its_window(model_id, rows):
+def test_a_decode_rows_ring_in_the_kernel_is_a_softmax_over_its_window(model_id, rows):
     """One query a row at positions inside the window, at its edge and far
     past it (the ring wrapped many times), rows of no length among them,
-    in shuffled order: the ring read a block a turn, the rows taken by what
-    their rings hold (16 rows: two groups, 32: four, 5: one loop), against
-    a plain softmax over the last ``window`` positions of the sequence."""
+    in shuffled order: the ring read a block a turn in decode's kernel, each
+    row as far as its ring is filled, against a plain softmax over the last
+    ``window`` positions of the sequence."""
     import jax.numpy as jnp
 
     from ray_tpu.ops import cached_attention as ca
@@ -109,21 +108,23 @@ def test_a_decode_rows_ring_under_the_loops_is_a_softmax_over_its_window(model_i
     ring_k = np.stack([ring_of(ks[r], pos[r] + 1, W) for r in range(rows)])
     ring_v = np.stack([ring_of(vs[r], pos[r] + 1, W) for r in range(rows)])
     q = jnp.asarray(rng.normal(0, 1, (rows, 1, H, Dk)), jnp.float32)
-    loops = ca.ring_loops(jnp.asarray(pos, jnp.int32), W)
-    groups = {5: 1, 16: 2, 32: 4}[rows]
-    assert loops.turns.shape == (groups,) and loops.span == ca.ring_span(W) == W // 4
+    walk = ca.ring_visits(jnp.asarray(pos, jnp.int32), W)
+    assert walk.span == ca.ring_span(W) == W // 4
     got = ca.ring_decode_attend(q, jnp.asarray(ring_k), jnp.asarray(ring_v),
-                                jnp.asarray(pos, jnp.int32), Hkv, loops)
+                                jnp.asarray(pos, jnp.int32), Hkv, walk)
     T = ks.shape[1]
     at = np.arange(T)[None, None, :]
     visible = (at <= pos[:, None, None]) & (pos[:, None, None] - at < W)
     want = plain_attention(q, ks, vs, visible, Hkv)
     assert got.shape == (rows, 1, H * Dv) and float(jnp.abs(want).max()) > 0.5
     assert float(jnp.abs(got - want).max()) < 1e-5
-    assert int(loops.turns.max()) == 4  # a row past its window reads all of its ring
+    # a row past its window reads all of its ring, a row inside it the
+    # blocks it has written
+    turns = np.diff(np.asarray(walk.first))
+    assert turns.max() == 4 and list(turns) == list(np.minimum(pos, W - 1) // (W // 4) + 1)
     # rows inside a quarter of their window read a quarter of their rings
-    short = ca.ring_loops(jnp.asarray(pos % (W // 4), jnp.int32), W)
-    assert [int(t) for t in short.turns] == [1] * groups
+    short = ca.ring_visits(jnp.asarray(pos % (W // 4), jnp.int32), W)
+    assert int(short.first[-1]) == rows
 
 
 @pytest.mark.parametrize("P", [1, 4, 24])

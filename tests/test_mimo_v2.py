@@ -345,6 +345,22 @@ def plain_attention(q, k, v, visible, kv_heads, sink=None):
     return jnp.stack(heads, axis=2).reshape(R, Q, H * Dv)
 
 
+def walk_of(Q, last, span, most):
+    """How ``paged_attend`` takes its rows' pages for ``Q`` queries a row
+    whose last positions are ``last``: decode's one query in the kernel
+    (``ops/paged_kv_attention.py``: the step's visits, no row walking more
+    than ``most`` turns of ``span`` positions), a chunk of several under
+    prefill's one loop."""
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import page_loops, paged_kv_attention
+
+    last = jnp.asarray(last, jnp.int32)
+    if Q == 1:
+        return paged_kv_attention.visits(last, span, most)
+    return page_loops.one_loop(last, span)
+
+
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("Q", [1, 5])
 @pytest.mark.parametrize("sizes", [(24, 16), (192, 128)], ids=["tiny", "published"])
@@ -355,13 +371,13 @@ def test_attention_over_merged_heads_is_a_softmax_a_head(kv_heads, sizes, Q, dty
     page table (a row of length 0, rows that end mid-page, pages in no
     order, noise in the pages a row does not own) and a window layer (a
     sink a head, a ring not yet full), one query a row (q spread over the
-    K/V heads' columns) and a chunk of five (the keys split)."""
+    K/V heads' columns: the pages in decode's kernel, the ring in one
+    block) and a chunk of five (the keys split)."""
     import jax
     import jax.numpy as jnp
 
     jax.config.update("jax_platforms", "cpu")
     from ray_tpu.models import mimo_v2 as m
-    from ray_tpu.ops import page_loops
 
     dt = jnp.dtype(dtype)
     # bfloat16: the probabilities are rounded to 2**-8 before the second
@@ -389,7 +405,7 @@ def test_attention_over_merged_heads_is_a_softmax_a_head(kv_heads, sizes, Q, dty
     k_pool, v_pool = draw(N, B, kv_heads * Dk), draw(N, B, kv_heads * Dv)
     got = m._paged_attend(q, k_pool, v_pool, jnp.asarray(tables),
                           jnp.asarray(q_pos, jnp.int32), kv_heads,
-                          page_loops.by_length(jnp.asarray(last), 2 * B))
+                          walk_of(Q, last, 2 * B, max_pages // 2))
     T = max_pages * B
     visible = np.arange(T)[None, None, :] <= q_pos[:, :, None]
     want = plain_attention(q, k_pool[tables].reshape(R, T, -1),
@@ -432,13 +448,12 @@ def rows_of_every_length(rng, rows, B, max_pages):
 
 @pytest.mark.parametrize("kv_heads", [1, 4])
 @pytest.mark.parametrize("rows", [3, 16, 32, 40])
-def test_rows_taken_by_length_attend_as_one_loop_and_as_a_softmax_a_head(rows, kv_heads):
-    """A full layer's decode attention with the rows taken by length, a
-    loop a group, against the one loop over all rows (<= 1e-6 in float32: a
-    turn behind a row's length adds exact zeros) and against a plain
-    softmax a head, on rows of every length in shuffled order; the rows
-    permuted give the same rows permuted. Three rows are fewer than two
-    groups and take the one loop, as prefill's one row does."""
+def test_rows_each_to_their_own_length_attend_as_one_loop_and_as_a_softmax_a_head(rows, kv_heads):
+    """A full layer's decode attention in the kernel, each row walking its
+    own turns, against the one loop over all rows that prefill keeps (<=
+    1e-6 in float32: a turn behind a row's length adds exact zeros) and
+    against a plain softmax a head, on rows of every length in shuffled
+    order; the rows permuted give the same rows permuted, to the bit."""
     import jax
     import jax.numpy as jnp
 
@@ -453,10 +468,9 @@ def test_rows_taken_by_length_attend_as_one_loop_and_as_a_softmax_a_head(rows, k
     q, k_pool, v_pool = draw(rows, 1, H, Dk), draw(N, B, kv_heads * Dk), draw(N, B, kv_heads * Dv)
     pos, tables = jnp.asarray(pos, jnp.int32), jnp.asarray(tables)
     T, span = max_pages * B, 4 * B
-    loops = page_loops.by_length(pos, span)
-    groups = {3: 1, 16: 2, 32: 4, 40: 4}[rows]
-    assert loops.turns.shape == (groups,) and (loops.order is None) == (groups == 1)
-    got = m._paged_attend(q, k_pool, v_pool, tables, pos[:, None], kv_heads, loops)
+    walk = walk_of(1, pos, span, T // span)
+    assert int(walk.first[-1]) == int((pos // span + 1).sum())  # no turn behind a row's own
+    got = m._paged_attend(q, k_pool, v_pool, tables, pos[:, None], kv_heads, walk)
     one = m._paged_attend(q, k_pool, v_pool, tables, pos[:, None], kv_heads,
                           page_loops.one_loop(pos, span))
     assert float(jnp.abs(got - one).max()) <= 1e-6
@@ -467,15 +481,15 @@ def test_rows_taken_by_length_attend_as_one_loop_and_as_a_softmax_a_head(rows, k
     assert float(jnp.abs(got - want).max()) < 1e-5
     perm = np.random.default_rng(rows).permutation(rows)
     moved = m._paged_attend(q[perm], k_pool, v_pool, tables[perm], pos[perm][:, None],
-                            kv_heads, page_loops.by_length(pos[perm], span))
-    assert float(jnp.abs(moved - got[perm]).max()) <= 1e-6
+                            kv_heads, walk_of(1, pos[perm], span, T // span))
+    assert np.array_equal(np.asarray(moved), np.asarray(got[perm]))
 
 
-def test_the_step_counts_what_its_full_layers_loops_covered(tiny):
-    """``attn_loop_tokens`` is rows of a group x its turns x the positions a
-    turn, summed over the groups, written out by hand for 32 rows (four
-    groups of eight, by length), and ``attn_context_tokens`` the live rows'
-    positions, the new one among them; once a step, not a layer."""
+def test_the_step_counts_what_its_full_layers_kernel_read(tiny):
+    """``attn_loop_tokens`` is what the full layers' kernel reads for the
+    live rows, each row's own pages x the positions a page, for 32 rows of
+    every length, and ``attn_context_tokens`` the live rows' positions,
+    the new one among them; once a step, not a layer."""
     import jax
     import jax.numpy as jnp
 
@@ -485,11 +499,11 @@ def test_the_step_counts_what_its_full_layers_loops_covered(tiny):
     cfg, params, _ = tiny
     B, S = 4, 32
     turn = B * page_loops.DECODE_PAGES
-    # eight rows nobody holds, then three groups whose longest rows stand
-    # one short of a turn, at a turn and at 250: one turn, two, 250 // turn + 1
-    lens = ([0] * 8 + [1, 2, 3, 4, 5, 6, turn - 2, turn - 1] + [turn] * 8
-            + [turn + 1, 100, 120, 150, 180, 200, 249, 250])
-    long = 250 // turn + 1
+    # eight rows nobody holds, which count nowhere; rows inside their first
+    # turn, its last position among them; rows whose new position opens the
+    # second; and rows up to 250
+    long = [turn + 1, 100, 120, 150, 180, 200, 249, 250]
+    lens = [0] * 8 + [1, 2, 3, 4, 5, 6, turn - 2, turn - 1] + [turn] * 8 + long
     lens = np.asarray(lens)[np.random.default_rng(2).permutation(S)]
     tables = np.zeros((S, cfg.n_positions // B), np.int32)
     at = 1
@@ -501,9 +515,12 @@ def test_the_step_counts_what_its_full_layers_loops_covered(tiny):
         cfg, params, jnp.zeros((S,), jnp.int32), jnp.asarray(lens, jnp.int32),
         *dec.init_paged_cache(cfg, at, B, S), jnp.asarray(tables))
     by_name = dict(zip(dec.STEP_COUNTERS, map(int, out[3])))
-    assert by_name["attn_loop_tokens"] == 8 * turn * (1 + 1 + 2 + long)
+    assert by_name["attn_loop_tokens"] == B * sum(n // B + 1 for n in lens if n)
     assert by_name["attn_context_tokens"] == int(sum(n + 1 for n in lens if n))
-    assert by_name["attn_loop_tokens"] < 32 * turn * long  # what one loop would have covered
+    # four loops by length (PR 49) covered every row to its group's longest
+    assert by_name["attn_loop_tokens"] < 8 * turn * (1 + 1 + 2 + 250 // turn + 1)
+    # and no row reads a whole page behind its own length
+    assert by_name["attn_loop_tokens"] < by_name["attn_context_tokens"] + 24 * B
 
 
 

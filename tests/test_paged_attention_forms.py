@@ -362,10 +362,11 @@ def test_compiled_serving_programs_copy_no_whole_pool(one_chip, time_limit):
 
 
 @pytest.mark.parametrize("kind", ["full", "window"])
-def test_compiled_mimo_decode_attention_splits_no_cached_heads(one_chip, time_limit, kind):
+def test_compiled_mimo_decode_attention_splits_no_cached_heads(
+        one_chip, time_limit, kind, kernels_for_the_chip):
     """At ``mimo-reason-decode``'s shapes (128 rows of one query, 64 heads
     of 192 over 4 K/V heads in a full layer and 8 in a window layer, V of
-    128; pools of 2,049 pages of 64, four pages a turn; rings of 128) a
+    128; pools of 2,049 pages of 64, eight pages a turn; rings of 128) a
     layer's decode attention, as the v5e's compiler writes it, takes K and
     V with the heads merged as ``init_paged_cache`` stores them: no
     ``copy``, ``reshape``, ``transpose`` or fusion whose result is a
@@ -373,12 +374,15 @@ def test_compiled_mimo_decode_attention_splits_no_cached_heads(one_chip, time_li
     Split, 4 or 8 heads are no multiple of 8 sublanes and 192 none of 128
     lanes, and every K and V byte the step reads was written three more
     times on the way (``reshape bf16[128,256,4,192]`` alone 13.8% of the
-    step on the chip; PERF.md, PR 47)."""
+    step on the chip; PERF.md, PR 47). A full layer's is one call of
+    ``ops/paged_kv_attention.py``'s kernel since PR 58, which takes both
+    pools where they lie: no loop over page-table columns and no gathered
+    span of any rows is left."""
     from ray_tpu.models import mimo_v2 as m
-    from ray_tpu.ops import page_loops
+    from ray_tpu.ops import paged_kv_attention
 
     cfg = m.CONFIGS["mimo-v2.5"]
-    S, N, Bx, mp, C = 128, 2049, 64, 64, page_loops.DECODE_PAGES
+    S, N, Bx, mp = 128, 2049, 64, 64
     H, Dv, W = cfg.num_attention_heads, cfg.v_head_dim, cfg.sliding_window
     l = cfg.hybrid_layer_pattern.index(kind == "window")
     Hkv = cfg.kv_heads(l)
@@ -391,38 +395,36 @@ def test_compiled_mimo_decode_attention_splits_no_cached_heads(one_chip, time_li
     q = sds((S, 1, H, cfg.head_dim))
     if kind == "full":
         assert (k.shape, v.shape) == ((N, Bx, 768), (N, Bx, 512))
-        span = C * Bx
-        rows = S // page_loops.GROUPS  # the rows are taken by length, a loop a group
 
         def attend(q, k, v, tables, q_pos):
-            return m._paged_attend(q, k, v, tables, q_pos, Hkv,
-                                   page_loops.by_length(q_pos[:, 0], span))
+            walk = paged_kv_attention.page_visits(q_pos[:, 0], mp, Bx)
+            return m._paged_attend(q, k, v, tables, q_pos, Hkv, walk)
 
+        span = paged_kv_attention.page_visits(jnp.zeros((S,), jnp.int32), mp, Bx).span
         lowered = jax.jit(attend).lower(
             q, k, v, sds((S, mp), jnp.int32), sds((S, 1), jnp.int32))
     else:
         assert (k.shape, v.shape) == ((S, W, 1536), (S, W, 1024))
-        span, rows = W, S
+        span = W
 
         lowered = jax.jit(m._window_attend, static_argnums=4).lower(
             q, k, v, sds((S, 1, W), jnp.bool_), Hkv, sds((H,), jnp.float32))
     text = lowered.compile().as_text()
-    merged = rf"{rows},{span},(?:{k.shape[2]}|{v.shape[2]})"
-    assert re.search(rf"= bf16\[{merged}\]", text)  # K and V are there, merged
     split = rf"\d+,{span},{Hkv},(?:{cfg.head_dim}|{Dv})"
     relays = re.findall(
         rf"= \w+\[(?:{split})\]\S* (?:copy|reshape|transpose|fusion)\(", text)
     relays += re.findall(rf"= \w+\[{S},{W},\d+\]\S* copy\(", text)
     assert not relays, relays
     if kind == "full":
-        # a loop a group, its carry opening with the group's running maximum
-        # a head, and no span gathered for more rows than a group's
-        loops = aot.loops_of(text)
-        assert loops == {f"(s32[],f32[{rows},{H},1],..)": page_loops.GROUPS}, loops
-        wider = [n for n in map(int, re.findall(
-            rf"= bf16\[(\d+),(?:{span},|{Bx},)(?:{k.shape[2]}|{v.shape[2]})\]", text))
-            if n not in (rows, rows * C, N)]
-        assert not wider, wider
+        # one call of the kernel, no loop, and nothing [.., .., stored
+        # width] but the pools themselves: no turn's pages gathered
+        assert aot.kernels_of(text) == {"paged_kv_attention": 1}
+        assert not aot.loops_of(text)
+        wide = set(re.findall(rf"= bf16\[(\d+,\d+),(?:{k.shape[2]}|{v.shape[2]})\]", text))
+        assert wide <= {f"{N},{Bx}"}, wide
+    else:
+        # the rings are there, merged, and go into the products as they lie
+        assert re.search(rf"= bf16\[{S},{W},(?:{k.shape[2]}|{v.shape[2]})\]", text)
 
 
 HLO_LOOPS = {
@@ -458,6 +460,13 @@ HLO_KERNELS = {
         "%bitcast.221, %copy-done.89, /*index=5*/%fusion.47), "
         'custom_call_target="tpu_custom_call", operand_layout_constraints={s32[65536]{0}}',
         {"paged_latent_attention": 1}),
+    "decode's attention over a K pool and a V pool: a layer's pages, a layer's ring": (
+        "  %paged_kv_attention.7 = f32[128,40,128]{2,1,0:T(8,128)} custom-call(%bitcast.9, "
+        "%fusion.12, /*index=5*/%param.3, %param.4), "
+        'custom_call_target="tpu_custom_call", operand_layout_constraints={s32[32768]{0}}\n'
+        "  %ring_kv_attention.3 = f32[128,40,128]{2,1,0:T(8,128)} custom-call(%bitcast.2, "
+        '%bitcast.5, %bitcast.6), custom_call_target="tpu_custom_call"',
+        {"paged_kv_attention": 1, "ring_kv_attention": 1}),
     "an expert layer's two": (
         "  %grouped_matmul.2 = bf16[768,768]{1,0:T(8,128)(2,1)} custom-call(%max.4, %g.1, %u.1), "
         'custom_call_target="tpu_custom_call"\n'
@@ -476,7 +485,8 @@ def test_the_tool_names_a_kernel_as_the_trace_does(case):
     """``tools/aot_serving_programs.kernels_of`` reads a compiled program's
     Pallas calls by the kernel's name, which is the operation's name in the
     chip's trace and what ``mla_paged_roofline`` and ``moe_gmm_roofline``
-    match."""
+    match (``paged_kv_attention`` and ``ring_kv_attention`` are the names a
+    roofline of PR 58's kernel would match: PERF.md §7)."""
     text, want = HLO_KERNELS[case]
     assert aot.kernels_of(text + "\n" + text) == {k: 2 * v for k, v in want.items()}
 
@@ -672,11 +682,13 @@ def test_compiled_trinity_programs_copy_no_pool_and_no_ring(one_chip, time_limit
     of pool: no copy of the full layer's pool and none of a ring, neither
     as it is stored ``[128, 2048, 512]`` nor as decode reads it in blocks
     ``[512, 512, 512]`` (a ring is 0.27 GB for K alone: one copy a layer
-    would be a tenth of a step); decode holds four ring loops a sliding
-    layer, whose carry opens with the weighted sum (the name
-    ``window_attn_roofline`` matches), and four page loops in the full
-    layer, whose carry opens with the running maximum; an expert layer
-    holds one loop, the scatter-add behind the kernel's two calls (PR 55)."""
+    would be a tenth of a step); decode holds one call of
+    ``ops/paged_kv_attention.py``'s kernel a sliding layer over its ring's
+    blocks where they lie (``ring_kv_attention``) and one in the full layer
+    over its pages (``paged_kv_attention``), and since PR 58 no loop over
+    either and no gathered span; an expert layer holds one loop, the
+    scatter-add behind the kernel's two calls (PR 55). Prefill keeps its
+    loops."""
     from ray_tpu.models import afmoe as m
 
     cfg, counted, loops, temps = largest_prefill_of_rows(
@@ -700,9 +712,12 @@ def test_compiled_trinity_programs_copy_no_pool_and_no_ring(one_chip, time_limit
     assert counted == {"whole-pool copies": 0, "ring copies": 0, "K/V split into heads": 0}, counted
     assert temps < 0.3e9
     assert aot.loops_of(text) == {
-        f"(s32[],f32[32,{H},1,{cfg.head_dim}],..)": 4 * sliding,
-        f"(s32[],f32[32,{H},1],..)": 4 * (cfg.n_layer - sliding),
         f"(s32[],f32[128,{D}],..)": cfg.n_layer - cfg.num_dense_layers}
+    N = 32 * (cfg.n_positions // 64) + 1
+    assert_decode_attends_in_the_kernel(
+        text, pages=cfg.n_layer - sliding, rings=sliding,
+        stored={f"{N},64", "128,2048", "512,512"},
+        width=cfg.num_key_value_heads * cfg.head_dim)
 
 
 MOE_CELLS = {"kanana-2-30b-a3b": "deepseek_v3", "mimo-v2.5": "mimo_v2", "trinity-mini": "afmoe"}
@@ -809,13 +824,15 @@ def test_compiled_phi4flash_programs_copy_no_pool_no_ring_and_no_state(one_chip,
     none of a ring (stored ``[128, 512, 1280]`` or read in blocks ``[512,
     128, 1280]``) and none of a Mamba layer's state ``[128, 16, 5120]`` (read
     and written every step: a relay would double its bytes). Decode holds
-    four ring loops a window layer, whose carry opens with the weighted sum
-    (the name ``window_attn_roofline`` matches), and four page loops in EACH
-    of the eight layers that attend over layer 17's pages, whose carry
-    opens with the running maximum (the name ``shared_kv_roofline``
-    matches); prefill holds a scan a Mamba layer, a loop a window layer and
-    one in the full layer over the chunk, and one a cross layer over one
-    position a row."""
+    one call of ``ops/paged_kv_attention.py``'s kernel a window layer, over
+    the ring's blocks where they lie (``ring_kv_attention``), and one in
+    EACH of the eight layers that attend over layer 17's pages
+    (``paged_kv_attention``): since PR 58 no loop over page-table
+    columns or ring blocks, and no turn's pages or blocks gathered for any
+    group of rows; what ``shared_kv_roofline`` and ``window_attn_roofline``
+    knew the loops by matches nothing. Prefill keeps its loops: a scan a
+    Mamba layer, a loop a window layer and one in the full layer over the
+    chunk, and one a cross layer over one position a row."""
     from benchmark import trace as trace_mod
     from ray_tpu.models import phi4flash as m
 
@@ -842,15 +859,35 @@ def test_compiled_phi4flash_programs_copy_no_pool_no_ring_and_no_state(one_chip,
                        "state copies": 0}, counted
     assert temps < 0.3e9
     readers = kinds.count("full") + kinds.count("cross")
-    assert aot.loops_of(text) == {
-        f"(s32[],f32[32,{H},1,{2 * cfg.head_dim}],..)": 4 * kinds.count("window"),
-        f"(s32[],f32[32,{H},1],..)": 4 * readers}
-    # the trace's names for them are what the two roofline metrics match
-    with open(os.path.join(ROOT, "benchmark/metrics/shared_kv_roofline.json")) as f:
-        pages = re.compile(json.load(f)["args"]["ops"])
-    with open(os.path.join(ROOT, "benchmark/metrics/window_attn_roofline.json")) as f:
-        rings = re.compile(json.load(f)["args"]["ops"])
+    assert_decode_attends_in_the_kernel(
+        text, pages=readers, rings=kinds.count("window"),
+        stored={"8193,64", "128,512", "512,128"}, width=cfg.num_key_value_heads * cfg.head_dim)
+
+
+def assert_decode_attends_in_the_kernel(text, pages, rings, stored, width):
+    """A compiled decode program attends over ``pages`` layers' pages and
+    ``rings`` layers' rings in ``ops/paged_kv_attention.py``'s kernel alone:
+    so many calls under the names the trace gives them, pages apart from
+    rings; no loop whose carry opens ``f32[rows, heads, 1]`` or ``f32[rows,
+    heads, 1, size]``, the names ``shared_kv_roofline`` and
+    ``window_attn_roofline`` knew the loops by; and no bfloat16 array
+    ``[.., .., width]`` (K or V with the heads merged) but those in
+    ``stored`` (the pool, the rings, and the rings cut into blocks where
+    they lie): no turn's pages or blocks gathered for a group of rows."""
+    from benchmark import trace as trace_mod
+
+    kernels = aot.kernels_of(text)
+    attention = {k: n for k, n in kernels.items() if k.endswith("kv_attention")}
+    assert attention == {k: n for k, n in (("paged_kv_attention", pages),
+                                           ("ring_kv_attention", rings)) if n}, kernels
+    metrics = os.path.join(ROOT, "benchmark/metrics")
+    loops = aot.loops_of(text)
+    assert not [k for k in loops if re.search(r"f32\[\d+,\d+,1(,\d+)?\]", k)], loops
+    ops = [ln for ln in text.splitlines() if re.match(r"\s*(ROOT )?%\S+ = ", ln)]
     whiles = [trace_mod.short_op_name(ln.strip()) for ln in ops if ") while(" in ln]
-    assert sum(bool(pages.search(w)) for w in whiles) == 4 * readers
-    assert sum(bool(rings.search(w)) for w in whiles) == 4 * kinds.count("window")
-    assert not [w for w in whiles if pages.search(w) and rings.search(w)]
+    for metric in ("shared_kv_roofline", "window_attn_roofline"):
+        with open(os.path.join(metrics, metric + ".json")) as f:
+            old = re.compile(json.load(f)["args"]["ops"])
+        assert not [w for w in whiles if old.search(w)], metric
+    wide = set(re.findall(rf"= bf16\[(\d+,\d+),{width}\]", text))
+    assert wide <= stored, wide

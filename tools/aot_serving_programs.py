@@ -6,11 +6,14 @@ decode module) for a described v5e at the engine's own sizes, and prints
 for each: operations with ``remat`` in their name (and how often the text
 says the word), the relays its family watches for, ``memory_analysis()``'s
 arguments and temporaries, the layout the first pool enters in, the
-loops it holds by how their carry opens (the paged attention's loops over
-page-table columns, a loop a group of rows a layer: ``ops/page_loops.py``)
-and the Pallas kernels it calls, by name (the latent family's decode
-attention is one a layer, ``ops/paged_latent_attention.py``, and holds no
-such loop).
+loops it holds by how their carry opens (prefill's one loop over its rows'
+page-table columns a layer, ``ops/page_loops.py``; the expert layers'; the
+K-step loop) and the Pallas kernels it calls, by name: decode's attention
+is one call a layer and holds no loop, ``paged_kv_attention`` over a
+layer's pages and ``ring_kv_attention`` over a layer's rings in the
+families with a K and a V pool (``ops/paged_kv_attention.py``, PR 58),
+``paged_latent_attention`` in the latent one
+(``ops/paged_latent_attention.py``, PR 56).
 
 What a family watches for (a relay is an operation that writes an array
 anew in another layout):
@@ -105,8 +108,9 @@ def loops_of(text: str) -> dict:
     """The ``while`` operations of a compiled program by how their carry
     opens, as the trace names them (``(s32[],f32[32,32],..)``: the counter
     and the first array carried), with how many of each the text holds. A
-    loop over a row's page-table columns carries its running maximum
-    first, ``f32[rows of a group, heads]``."""
+    prefill call's loop over its rows' page-table columns carries its
+    running maximum first, ``f32[rows, heads, chunk]``; decode's programs
+    hold none over pages or rings since PR 58."""
     found = {}
     for ln in text.splitlines():
         carry = re.search(r"= \(s32\[\][^,]*, (\w+\[[\d,]*\]).*\) while\(", ln)
@@ -119,7 +123,8 @@ def loops_of(text: str) -> dict:
 def kernels_of(text: str) -> dict:
     """The calls of Pallas kernels in a compiled program, by the kernel's
     name (the ``name`` of its ``pallas_call``, which the chip's trace shows
-    as the operation's: ``grouped_matmul.3``, ``paged_latent_attention.11``),
+    as the operation's: ``grouped_matmul.3``, ``paged_latent_attention.11``,
+    ``paged_kv_attention.7``, ``ring_kv_attention.3``),
     with how many of each the text holds."""
     found = {}
     for name in re.findall(
