@@ -32,6 +32,10 @@ def _fmt_table(rows: List[dict], columns: List[str]) -> str:
 # Single shared interpolation (utils/metrics.py): the renderer, state
 # rollups, history store, and alert engine must all agree on quantile
 # math.
+from ray_tpu.observability.core_metrics import (  # noqa: E402
+    ENGINE_HOST_PHASES,
+    ENGINE_PHASES,
+)
 from ray_tpu.utils.metrics import hist_quantile as _hist_quantile  # noqa: E402
 
 _SPARK_GLYPHS = "▁▂▃▄▅▆▇█"
@@ -240,10 +244,25 @@ def _render_top(mx: dict, reqs: dict, qps: Optional[dict],
         )
     for dep, h in hist_by_tag("rt_serve_engine_round_host_s",
                               "deployment").items():
-        # mean engine-thread time per round in its own code (admit,
-        # prefill build, dispatch, harvest), device syncs excluded
+        # mean engine-thread time per round in its own code
+        # (core_metrics.ENGINE_HOST_PHASES), device syncs excluded
         if h["count"]:
             row(dep)["host_ms"] = ms(h["sum"] / h["count"])
+    # of the seconds of the engine thread's working rounds, the share in
+    # which it knew the device had nothing to run: how far the host holds
+    # the chip back, with no trace (a lower bound)
+    def seconds_by_dep(names) -> dict:
+        out: dict = {}
+        for name in names:
+            for dep, h in hist_by_tag(name, "deployment").items():
+                out[dep] = out.get(dep, 0.0) + h["sum"]
+        return out
+
+    dry = seconds_by_dep(f"rt_serve_engine_dry_{p}_s" for p in ENGINE_HOST_PHASES)
+    working = seconds_by_dep(f"rt_serve_engine_{p}_s" for p in ENGINE_PHASES)
+    for dep, s in working.items():
+        if s > 0:
+            row(dep)["dry%"] = f"{100.0 * dry.get(dep, 0.0) / s:.1f}"
     for dep, h in hist_by_tag("rt_serve_batch_fill", "deployment").items():
         if h["count"]:
             row(dep)["batch_fill"] = f"{h['sum'] / h['count']:.1f}"
@@ -281,7 +300,7 @@ def _render_top(mx: dict, reqs: dict, qps: Optional[dict],
             f"{qps.get(dep, 0.0):.1f}" if qps is not None else "-"
         )
     columns = ["deployment", "replicas", "reqs", "qps", "ttft_p50_ms",
-               "ttft_p95_ms", "itl_p50_ms", "host_ms", "tokens",
+               "ttft_p95_ms", "itl_p50_ms", "host_ms", "dry%", "tokens",
                "kv_pages", "queued", "shed", "batch_fill",
                "cache_hit",
                "page_hit", "last_scale"]

@@ -41,6 +41,42 @@ KV_KINDS = ("full", "window", "latent", "state")
 # ``state`` came later (PR 57) and is reported by the models that name it,
 # so that what another family's ``kv_bytes_by_kind`` reads did not change
 KV_KINDS_EVERY_MODEL = KV_KINDS[:3]
+# the phases of a round of the paged engine that worked, which are its
+# ``rt/engine/<phase>`` spans (serve/llm.py ``_RoundAccount``): a
+# ``serve_engine_<phase>_s`` histogram each. ``other`` is what the round's
+# span covers outside every inner one. A round that only parked in the idle
+# wait (``rt/engine/idle``) is no phase and observes nothing
+ENGINE_PHASES = (
+    "admit", "prefill", "first_token_sync", "dispatch", "harvest_sync", "harvest", "other",
+)
+# blocked on the device; together ``serve_engine_round_blocked_s``
+ENGINE_BLOCKED_PHASES = tuple(p for p in ENGINE_PHASES if p.endswith("_sync"))
+# the thread's own code; together ``serve_engine_round_host_s``, and a
+# ``serve_engine_dry_<phase>_s`` histogram each
+ENGINE_HOST_PHASES = tuple(p for p in ENGINE_PHASES if p not in ENGINE_BLOCKED_PHASES)
+
+
+def _engine_phase_series() -> dict:
+    series = {}
+    for phase in ENGINE_PHASES:
+        series[f"serve_engine_{phase}_s"] = Histogram(
+            f"rt_serve_engine_{phase}_s",
+            f"engine-thread time per working round in its phase {phase!r} "
+            "(zeros included: sum/count is seconds a round)",
+            boundaries=_LATENCY_BOUNDS,
+            tag_keys=("deployment",),
+        )
+    for phase in ENGINE_HOST_PHASES:
+        series[f"serve_engine_dry_{phase}_s"] = Histogram(
+            f"rt_serve_engine_dry_{phase}_s",
+            f"of the phase {phase!r}, the time per working round from where "
+            "the engine thread learned that the device had run all it was "
+            "handed to the thread's next program (a lower bound of the "
+            "device's idle time; zeros included)",
+            boundaries=_LATENCY_BOUNDS,
+            tag_keys=("deployment",),
+        )
+    return series
 
 
 def _build() -> dict:
@@ -169,18 +205,19 @@ def _build() -> dict:
         "serve_engine_round_host_s": Histogram(
             "rt_serve_engine_round_host_s",
             "engine-thread time per working round in its own code "
-            "(admit, prefill build, dispatch, harvest); device syncs and "
-            "idle waits excluded",
+            "(ENGINE_HOST_PHASES); device syncs and idle waits excluded",
             boundaries=_LATENCY_BOUNDS,
             tag_keys=("deployment",),
         ),
         "serve_engine_round_blocked_s": Histogram(
             "rt_serve_engine_round_blocked_s",
             "engine-thread time per working round blocked on the device "
-            "(first-token sample, harvest of the in-flight chunk)",
+            "(ENGINE_BLOCKED_PHASES: first-token sample, harvest of the "
+            "in-flight chunk)",
             boundaries=_LATENCY_BOUNDS,
             tag_keys=("deployment",),
         ),
+        **_engine_phase_series(),
         "serve_decode_steps": Counter(
             "rt_serve_decode_steps_total",
             "decode token-steps executed (K per harvested chunk)",

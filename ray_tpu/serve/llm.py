@@ -183,6 +183,145 @@ class _PagedSeq:
         self.budget_left = 0
 
 
+class _RoundAccount:
+    """The engine thread's account of its own round, kept by the thread
+    itself: the seconds of each phase (``core_metrics.ENGINE_PHASES``,
+    which are the ``rt/engine/<phase>`` spans), and of those the seconds
+    in which it KNEW the device dry.
+
+    The engine hands the device its programs in order and one device runs
+    them in order, so the result of the last program handed over (a
+    prefill call's logits or the first tokens sampled from them, a decode
+    call's tokens) is the ``tail``: once it is done nothing is left to run.
+    The thread learns that where a phase that blocks on the tail returns,
+    or where ``tail.is_ready()`` is true at a phase boundary, and from that
+    instant until it hands the device its next program of any kind
+    (``device_call``: a prefill or decode call, the scatter of changed rows
+    before one, the sampling of first tokens) the device is known dry. Those
+    seconds go to the phase they pass in. A lower bound of the seconds the
+    device ran no program of the engine's: the stretch begins after the
+    device's last operation and ends before the next is handed over, and
+    what passes between that operation and the boundary is missed.
+
+    Reads no clock and asks no array anything in a round that began with
+    ``core_metrics.ENABLED`` false."""
+
+    clock = staticmethod(time.monotonic)
+    # (phase, the attribute of core_metrics that holds its series): looked
+    # up by name each round, because a registry reset rebinds them
+    _SERIES = tuple((p, f"serve_engine_{p}_s") for p in core_metrics.ENGINE_PHASES)
+    _DRY_SERIES = tuple((p, f"serve_engine_dry_{p}_s") for p in core_metrics.ENGINE_HOST_PHASES)
+
+    def __init__(self, tags: Dict[str, str]):
+        self.tags = tags
+        self.on = False  # this round is timed: the switch as the round began
+        self.seconds = dict.fromkeys(core_metrics.ENGINE_PHASES, 0.0)
+        self.dry = dict.fromkeys(core_metrics.ENGINE_HOST_PHASES, 0.0)
+        self.current = "other"  # the phase the thread is in
+        self.t_round = 0.0
+        self.tail = None  # outstanding and not yet known done
+        self.dry_since: Optional[float] = None
+
+    def phase(self, name: str, waits_for=None, **args):
+        """Context manager: the span ``rt/engine/<name>`` with ``args``
+        and, in a timed round, its seconds charged to the phase.
+        ``waits_for`` is the result a blocking phase waits for."""
+        span = tracing.span("rt/engine/" + name, **args)
+        return _Phase(self, name, span, waits_for) if self.on else span
+
+    def boundary(self, passed_in: Optional[str]) -> float:
+        """A phase begins or ends: the clock's reading, with the dry
+        seconds since the last boundary charged to the phase they passed
+        in (to none where it is not the host's own code), or a dry stretch
+        begun here if the tail is found done."""
+        t = self.clock()
+        if self.dry_since is not None:
+            if passed_in in self.dry:
+                self.dry[passed_in] += t - self.dry_since
+            self.dry_since = t
+        elif self.tail is not None and self.tail.is_ready():
+            self.tail = None
+            self.dry_since = t
+        return t
+
+    def device_call(self) -> None:
+        """The thread is about to hand the device a program: it has
+        something to run again."""
+        if self.dry_since is not None:
+            self.dry[self.current] += self.clock() - self.dry_since
+            self.dry_since = None
+
+    def handed(self, tail) -> None:
+        """``tail`` is the result of the last program handed over since
+        ``device_call``."""
+        if self.on:
+            self.tail = tail
+
+    def forget(self) -> None:
+        self.tail = self.dry_since = None
+
+    def begin(self) -> None:
+        self.on = core_metrics.ENABLED
+        if not self.on:
+            self.forget()
+            return
+        for name in self.seconds:
+            self.seconds[name] = 0.0
+        for name in self.dry:
+            self.dry[name] = 0.0
+        self.current = "other"
+        # what passed since the last round ended is no round's
+        self.t_round = self.boundary(None)
+
+    def end(self, worked: bool) -> None:
+        """Observe the round: every phase and every phase's dry seconds,
+        zeros included, so that each series counts the rounds that worked
+        and sum / count is seconds a round; nothing for a round that only
+        parked (no row was live: its dry seconds are nobody's). Host and
+        blocked are sums of the same readings."""
+        if not (self.on and worked):
+            return
+        sec, tags = self.seconds, self.tags
+        total = self.boundary("other") - self.t_round
+        if not core_metrics.ENABLED:
+            return
+        blocked = sum(sec[p] for p in core_metrics.ENGINE_BLOCKED_PHASES)
+        sec["other"] = total - sum(sec.values())
+        core_metrics.serve_engine_round_blocked_s.observe(blocked, tags=tags)
+        core_metrics.serve_engine_round_host_s.observe(total - blocked, tags=tags)
+        for name, series in self._SERIES:
+            getattr(core_metrics, series).observe(sec[name], tags=tags)
+        for name, series in self._DRY_SERIES:
+            getattr(core_metrics, series).observe(self.dry[name], tags=tags)
+
+
+class _Phase:
+    """One phase of a timed round: its span, and its seconds on the
+    account's clock, read inside the span."""
+
+    __slots__ = ("acct", "name", "span", "waits_for", "t0")
+
+    def __init__(self, acct: _RoundAccount, name: str, span, waits_for):
+        self.acct, self.name, self.span, self.waits_for = acct, name, span, waits_for
+
+    def __enter__(self):
+        self.span.__enter__()
+        self.t0 = self.acct.boundary("other")
+        self.acct.current = self.name
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        acct = self.acct
+        t = acct.boundary(self.name)
+        acct.seconds[self.name] += t - self.t0
+        acct.current = "other"
+        if exc_type is None and self.waits_for is not None and self.waits_for is acct.tail:
+            # the tail is on the host: nothing is left on the device
+            acct.tail = None
+            acct.dry_since = t
+        return self.span.__exit__(exc_type, exc, tb)
+
+
 def _upload(*host_arrays):
     """Device copies of the engine's long-lived host mirrors. On the CPU
     backend ``jnp.asarray`` of a 64-byte-aligned NumPy array SHARES its
@@ -601,22 +740,10 @@ class LLMServer:
         # chunk; None when the pipeline is drained
         inflight: Optional[_Chunk] = None
         dep_tags = {"deployment": self.cfg.model_id}
-        # seconds of the current round this thread spent blocked on the
-        # device (rt_serve_engine_round_blocked_s)
-        blocked_s = 0.0
-
-        def sync(name: str, fn, *args):
-            """Run a call that blocks on the device, under its span, and
-            add its wall time to the round's blocked seconds."""
-            nonlocal blocked_s
-            with tracing.span(name):
-                if not core_metrics.ENABLED:
-                    return fn(*args)
-                t = time.monotonic()
-                try:
-                    return fn(*args)
-                finally:
-                    blocked_s += time.monotonic() - t
+        # the thread's account of its round; every rt/engine/* span but
+        # the round's own is opened through it
+        acct = _RoundAccount(dep_tags)
+        phase = acct.phase
 
         def _bucket(n: int, cap: int) -> int:
             """Width of a prefill call for ``n`` tokens with ``cap``
@@ -813,24 +940,24 @@ class LLMServer:
                     n = min(n, width)
                     # start and width say what a traced call was: a cold
                     # chunk, or a tail behind a prefix
-                    with tracing.span("rt/engine/prefill", start=start, width=width, rows=1):
+                    with phase("prefill", start=start, width=width, rows=1):
                         tok = np.zeros((1, width), np.int32)
                         tok[0, :n] = s.prompt[start : start + n]
+                        acct.device_call()
                         logits, cache_k, cache_v = dec.prefill_paged(
                             mcfg, self.params, jnp.asarray(tok),
                             jnp.int32(start), jnp.int32(n),
                             cache_k, cache_v, jnp.asarray(s.table),
                             np.int32(i),
                         )
+                        acct.handed(logits)
                     count_prefill(1, n, width)
                     s.prefill_pos = start + n
                     budget -= n
                 if s.prefill_pos >= len(s.prompt) and logits is not None:
                     seal_prompt(s)
-                    first = sync(
-                        "rt/engine/first_token_sync", self._sample_one,
-                        logits, s.req.temperature,
-                    )
+                    with phase("first_token_sync", waits_for=logits):
+                        first = self._sample_one(logits, s.req.temperature)
                     activate(i, s, int(first), len(s.prompt))
 
         # what a prefill call of a module that takes rows looks like (the
@@ -857,11 +984,13 @@ class LLMServer:
                 tok[r, :n] = seqs[i].prompt[pos : pos + n]
                 start[r], length[r], at[r] = pos, n, i
                 table[r] = seqs[i].table
+            acct.device_call()
             logits, cache_k, cache_v = dec.prefill_paged(
                 mcfg, self.params, jnp.asarray(tok), jnp.asarray(start),
                 jnp.asarray(length), cache_k, cache_v, jnp.asarray(table),
                 jnp.asarray(at),
             )
+            acct.handed(logits)
             return logits
 
         def first_tokens(logits, temperatures: List[float]):
@@ -910,8 +1039,7 @@ class LLMServer:
             )
             # start (the first row's) and width say what a traced call
             # was: a cold chunk, or tails behind their prefixes
-            with tracing.span("rt/engine/prefill", start=rows[0][1], width=P,
-                              rows=len(rows)):
+            with phase("prefill", start=rows[0][1], width=P, rows=len(rows)):
                 logits = call_rows(R, P, rows)
             count_prefill(len(rows), sum(n for _, _, n in rows), R * P)
             for i, pos, n in rows:
@@ -926,13 +1054,14 @@ class LLMServer:
             for _, i in done:
                 seal_prompt(seqs[i])
             ends = dict(done)
-            toks = sync(
-                "rt/engine/first_token_sync", np.asarray,
-                first_tokens(logits, [
-                    seqs[ends[r]].req.temperature if r in ends else 0.0
-                    for r in range(len(rows))
-                ]),
-            )
+            acct.device_call()
+            firsts_dev = first_tokens(logits, [
+                seqs[ends[r]].req.temperature if r in ends else 0.0
+                for r in range(len(rows))
+            ])
+            acct.handed(firsts_dev)
+            with phase("first_token_sync", waits_for=firsts_dev):
+                toks = np.asarray(firsts_dev)
             for r, i in done:
                 activate(i, seqs[i], int(toks[r]), len(seqs[i].prompt))
 
@@ -1008,18 +1137,16 @@ class LLMServer:
             completions, deferred page frees. This executes while the
             NEXT chunk (already dispatched) keeps the device busy —
             np.asarray is the only sync point."""
-            if rec.counted_dev is None:
-                toks = sync("rt/engine/harvest_sync", np.asarray, rec.toks_dev)
-            else:
-                # the chunk's counts came with its tokens: one sync
-                toks, counted = sync(
-                    "rt/engine/harvest_sync", jax.device_get,
-                    (rec.toks_dev, rec.counted_dev),
-                )
-                if core_metrics.ENABLED:
-                    for name, n in zip(dec.STEP_COUNTERS, counted):
-                        getattr(core_metrics, f"serve_{name}").inc(int(n), tags=dep_tags)
-            with tracing.span("rt/engine/harvest"):
+            with phase("harvest_sync", waits_for=rec.toks_dev):
+                if rec.counted_dev is None:
+                    toks = np.asarray(rec.toks_dev)
+                else:
+                    # the chunk's counts came with its tokens: one sync
+                    toks, counted = jax.device_get((rec.toks_dev, rec.counted_dev))
+            if rec.counted_dev is not None and core_metrics.ENABLED:
+                for name, n in zip(dec.STEP_COUNTERS, counted):
+                    getattr(core_metrics, f"serve_{name}").inc(int(n), tags=dep_tags)
+            with phase("harvest"):
                 deliver(rec, toks)
 
         def deliver(rec: _Chunk, toks) -> None:
@@ -1081,16 +1208,16 @@ class LLMServer:
                 # instead of re-uploading all five arrays (a fancy index
                 # is a fresh array: nothing below shares a mirror)
                 idx = np.asarray(sorted(dirty), np.int32)
-                d_last, d_len, d_temps, d_greedy, d_tables = dev_state
-                dev_state = dec.update_rows_paged(
-                    d_last, d_len, d_temps, d_greedy, d_tables,
-                    jnp.asarray(idx), jnp.asarray(last[idx]),
-                    jnp.asarray(lengths[idx]), jnp.asarray(temps[idx]),
-                    jnp.asarray(greedy[idx]), jnp.asarray(tables[idx]),
-                )
+                changed = [jnp.asarray(a) for a in (
+                    idx, last[idx], lengths[idx], temps[idx], greedy[idx], tables[idx],
+                )]
+                # uploaded: the scatter is the device's next program
+                acct.device_call()
+                dev_state = dec.update_rows_paged(*dev_state, *changed)
                 dirty.clear()
             d_last, d_len, d_temps, d_greedy, d_tables = dev_state
             self._record_step_paged(len(active), pool.stats())
+            acct.device_call()
             # a module with STEP_COUNTERS returns their counts last
             if K > 1:
                 toks_dev, d_last2, d_len, cache_k, cache_v, *counted = (
@@ -1112,6 +1239,7 @@ class LLMServer:
                     )
                 )
                 dev_state = (toks_dev, d_len, d_temps, d_greedy, d_tables)
+            acct.handed(toks_dev)
             rec = _Chunk(toks_dev, K, counted[0] if counted else None)
             for i in active:
                 s = seqs[i]
@@ -1189,7 +1317,7 @@ class LLMServer:
             # wait-then-clear order could eat exactly that wakeup — up
             # to 500 ms of TTFT on an idle engine)
             self._work.clear()
-            with tracing.span("rt/engine/admit"):
+            with phase("admit"):
                 admitted = admit_waiting()
             run_prefill()
             prefilling = any(
@@ -1236,7 +1364,7 @@ class LLMServer:
                 K = max(1, min(dec.MAX_DECODE_CHUNK, min(
                     seqs[i].budget_left for i in active
                 )))
-            with tracing.span("rt/engine/dispatch", k=K, rows=len(active)):
+            with phase("dispatch", k=K, rows=len(active)):
                 rec = dispatch(active, K)
             # one-step lookahead: chunk N+1 is on the device; run chunk
             # N's host bookkeeping underneath it
@@ -1247,14 +1375,11 @@ class LLMServer:
 
         def one_round() -> None:
             """One round under its span, and the engine thread's account
-            of it: seconds in its own code and seconds blocked on the
-            device. A round that only parked in the idle wait is in
-            neither, so over a window host + blocked + idle is the
-            window."""
-            nonlocal blocked_s
-            timed = core_metrics.ENABLED
-            t_round = time.monotonic() if timed else 0.0
-            blocked_s = 0.0
+            of it (``_RoundAccount``): seconds by phase, which add up to
+            seconds in its own code and seconds blocked on the device. A
+            round that only parked in the idle wait is in neither, so over
+            a window host + blocked + idle is the window."""
+            acct.begin()
             with tracing.span(
                 "rt/engine/round",
                 # the chunk in flight as the round starts, whose harvest
@@ -1265,13 +1390,7 @@ class LLMServer:
                 ts_us=tracing.now_us() if tracing.ENABLED else 0,
             ):
                 worked = run_round()
-            if core_metrics.ENABLED and timed and worked:
-                core_metrics.serve_engine_round_blocked_s.observe(
-                    blocked_s, tags=dep_tags
-                )
-                core_metrics.serve_engine_round_host_s.observe(
-                    time.monotonic() - t_round - blocked_s, tags=dep_tags
-                )
+            acct.end(worked)
 
         self._engine_loaded(cache_k, cache_v)
         if row_widths:
@@ -1290,6 +1409,7 @@ class LLMServer:
                 fail_inflight(e)
                 dev_state = None
                 dirty.clear()
+                acct.forget()
                 # prefill/decode/write donate the caches: an exception
                 # after dispatch leaves them deleted — mark for rebuild
                 # (done inside the next round's try, with a pool.reset

@@ -21,12 +21,15 @@ SHARED = list(range(100, 100 + 2 * B))  # two full pages every "C" prompt shares
 MAX_NEW = 80
 SPANS = ("round", "admit", "prefill", "first_token_sync", "dispatch", "harvest_sync",
          "harvest", "idle")
+HOST, BLOCKED = core_metrics.ENGINE_HOST_PHASES, core_metrics.ENGINE_BLOCKED_PHASES
+PHASE_SERIES = tuple(f"serve_engine_{p}_s" for p in core_metrics.ENGINE_PHASES)
+DRY_SERIES = tuple(f"serve_engine_dry_{p}_s" for p in HOST)
 SERIES = (
     "serve_engine_queue_wait_s", "serve_engine_page_wait_s", "serve_engine_first_token_s",
     "serve_engine_round_host_s", "serve_engine_round_blocked_s", "serve_decode_steps",
     "serve_decode_row_steps", "serve_prompt_tokens", "serve_prefix_tokens_reused",
     "serve_prefill_tokens", "serve_prefill_width", "serve_tokens_generated", "serve_ttft_s",
-    "serve_prefix_cache_hits", "serve_batch_fill",
+    "serve_prefix_cache_hits", "serve_batch_fill", *PHASE_SERIES, *DRY_SERIES,
 )
 
 
@@ -208,6 +211,201 @@ def test_round_time_splits_into_host_and_blocked(mix):
     assert host_s + blocked_s <= mix["wall"]
 
 
+def test_a_phase_is_a_span_and_the_rounds_self_time_is_other():
+    # every span under the round's but ``idle``, which is a round that
+    # only parked and observes nothing
+    assert core_metrics.ENGINE_PHASES == (*SPANS[1:-1], "other")
+    assert BLOCKED == ("first_token_sync", "harvest_sync")
+    assert HOST == ("admit", "prefill", "dispatch", "harvest", "other")
+
+
+def test_phases_add_up_to_host_and_blocked_and_dry_is_within_host(mix):
+    first, last = mix["snaps"][0], mix["snaps"][-1]
+    host_s, rounds = delta(first, last, "serve_engine_round_host_s")
+    blocked_s = delta(first, last, "serve_engine_round_blocked_s")[0]
+    by_phase = {p: delta(first, last, f"serve_engine_{p}_s") for p in HOST + BLOCKED}
+    dry = {p: delta(first, last, f"serve_engine_dry_{p}_s") for p in HOST}
+    # zeros included: every series counts the rounds that worked
+    assert {n for _, n in by_phase.values()} == {n for _, n in dry.values()} == {rounds}
+    assert all(s >= 0 for s, _ in by_phase.values()) and all(s >= 0 for s, _ in dry.values())
+    assert sum(by_phase[p][0] for p in HOST) == pytest.approx(host_s, rel=1e-6)
+    assert sum(by_phase[p][0] for p in BLOCKED) == pytest.approx(blocked_s, rel=1e-6)
+    # a phase's dry seconds are seconds of that phase; every first token
+    # was fetched, and the device had nothing until the decode call
+    assert all(dry[p][0] <= by_phase[p][0] + 1e-9 for p in HOST)
+    assert 0 < sum(s for s, _ in dry.values()) <= host_s
+
+
+class Result:
+    """What a program handed to the device returns, as the account sees it."""
+
+    def __init__(self, ready=False):
+        self.ready, self.asked = ready, 0
+
+    def is_ready(self):
+        self.asked += 1
+        return self.ready
+
+
+class Clock:
+    def __init__(self):
+        self.t, self.reads = 100.0, 0
+
+    def __call__(self):
+        self.reads += 1
+        return self.t
+
+
+def account_case(case, monkeypatch):
+    """One round, or two, of a ``_RoundAccount`` under a stub clock that
+    the case moves by hand; the account, and the seconds by phase and dry
+    seconds by phase its rounds should have left in the series."""
+    from ray_tpu.serve.llm import _RoundAccount
+
+    clock = Clock()
+    monkeypatch.setattr(_RoundAccount, "clock", staticmethod(clock))
+    acct = _RoundAccount({"deployment": f"account-{case}"})
+
+    def spend(s):
+        clock.t += s
+
+    def in_phase(name, s, waits_for=None, call=None):
+        """``s`` seconds in a phase; ``call`` is handed over after half of them."""
+        with acct.phase(name, waits_for=waits_for):
+            spend(s / 2)
+            if call is not None:
+                acct.device_call()
+                acct.handed(call)
+            spend(s / 2)
+
+    chunk, prefill, firsts, nxt = Result(), Result(), Result(), Result()
+    acct.begin()
+    spend(1)  # other
+    if case == "sync_on_the_tail":
+        # a prefill call behind the chunk in flight, its first tokens
+        # fetched: dry from there, through other and half the dispatch
+        acct.handed(chunk)
+        in_phase("prefill", 2, call=prefill)
+        spend(1)
+        acct.device_call()
+        acct.handed(firsts)  # sampled from the call's logits: the tail now
+        in_phase("first_token_sync", 8, waits_for=firsts)
+        spend(3)
+        in_phase("dispatch", 4, call=nxt)
+        in_phase("harvest_sync", 1, waits_for=chunk)  # not the tail: nxt is
+        in_phase("harvest", 2)
+        acct.end(True)
+        want = {"dry_other": 3, "dry_dispatch": 2, "other": 5, "dispatch": 4, "prefill": 2,
+                "first_token_sync": 8, "harvest_sync": 1, "harvest": 2}
+        assert acct.tail is nxt and acct.dry_since is None and nxt.asked > 0
+    elif case == "tail_not_ready":
+        acct.handed(chunk)
+        in_phase("admit", 2)
+        in_phase("dispatch", 4, call=nxt)
+        in_phase("harvest", 2)
+        acct.end(True)
+        want = {"other": 1, "admit": 2, "dispatch": 4, "harvest": 2}
+        assert chunk.asked > 0 and acct.dry_since is None
+    elif case == "tail_found_ready_at_a_boundary":
+        acct.handed(chunk)
+        in_phase("admit", 2)
+        chunk.ready = True  # the device ran out somewhere in here ...
+        spend(5)
+        in_phase("harvest", 2)  # ... and the thread learns it as this begins
+        in_phase("dispatch", 4, call=nxt)
+        acct.end(True)
+        want = {"other": 6, "admit": 2, "harvest": 2, "dispatch": 4,
+                "dry_harvest": 2, "dry_dispatch": 2}
+        assert acct.tail is nxt and chunk.asked > 0
+    elif case == "sync_on_a_chunk_that_is_not_the_tail":
+        acct.handed(nxt)
+        in_phase("harvest_sync", 6, waits_for=chunk)
+        in_phase("harvest", 2)
+        acct.end(True)
+        want = {"other": 1, "harvest_sync": 6, "harvest": 2}
+        assert acct.tail is nxt and acct.dry_since is None
+    elif case == "parked_round":
+        # the last chunk harvested, then a round that only parks: it
+        # observes nothing, and the dry seconds that passed in it are nobody's
+        acct.handed(chunk)
+        in_phase("harvest_sync", 1, waits_for=chunk)
+        acct.end(True)
+        acct.begin()
+        in_phase("admit", 1)
+        spend(500)  # under rt/engine/idle, which is no phase
+        acct.end(False)
+        acct.begin()
+        spend(2)
+        in_phase("prefill", 2, call=prefill)
+        acct.end(True)
+        want = {"other": 1, "harvest_sync": 1, "rounds": 2,
+                "then_other": 2, "then_prefill": 2, "then_dry_other": 2, "then_dry_prefill": 1}
+    elif case == "any_program_ends_the_stretch":
+        # dry from the harvest's sync; the scatter of the changed rows, a
+        # second into the dispatch, is the device's next program, not the
+        # decode call two seconds behind it
+        acct.handed(chunk)
+        in_phase("harvest_sync", 1, waits_for=chunk)
+        in_phase("admit", 2)
+        with acct.phase("dispatch"):
+            spend(1)
+            acct.device_call()
+            spend(2)
+            acct.device_call()
+            acct.handed(nxt)
+            spend(1)
+        acct.end(True)
+        want = {"other": 1, "harvest_sync": 1, "admit": 2, "dispatch": 4,
+                "dry_admit": 2, "dry_dispatch": 1}
+        assert acct.tail is nxt and acct.dry_since is None
+    elif case == "exception_forgets_the_tail":
+        acct.handed(chunk)
+        with pytest.raises(RuntimeError):
+            with acct.phase("harvest_sync", waits_for=chunk):
+                spend(1)
+                raise RuntimeError("the device fell over")
+        assert acct.tail is chunk and acct.dry_since is None  # a failed sync teaches nothing
+        acct.forget()  # what the loop does with dev_state
+        asked = chunk.asked
+        acct.begin()
+        in_phase("admit", 1)
+        in_phase("dispatch", 2, call=nxt)
+        acct.end(True)
+        assert chunk.asked == asked and acct.tail is nxt
+        want = {"admit": 1, "dispatch": 2}
+    return acct, want
+
+
+ACCOUNT_CASES = ("sync_on_the_tail", "tail_not_ready", "tail_found_ready_at_a_boundary",
+                 "sync_on_a_chunk_that_is_not_the_tail", "parked_round",
+                 "any_program_ends_the_stretch", "exception_forgets_the_tail")
+
+
+@pytest.mark.parametrize("case", ACCOUNT_CASES)
+def test_the_account_of_a_round_under_a_stub_clock(case, monkeypatch):
+    def read(key):
+        for snap_key, s in getattr(core_metrics, key).snapshot()["series"].items():
+            if f"account-{case}" in str(snap_key):
+                return s["sum"], s["count"]
+        return 0.0, 0
+
+    acct, want = account_case(case, monkeypatch)
+    rounds = want.pop("rounds", 1)
+    then = {k[len("then_"):]: want.pop(k) for k in list(want) if k.startswith("then_")}
+    both = {**want, **{k: want.get(k, 0) + v for k, v in then.items()}}
+    for phase in core_metrics.ENGINE_PHASES:
+        total, n = read(f"serve_engine_{phase}_s")
+        # a round that worked observes every phase, a parked round none
+        assert (total, n) == (pytest.approx(both.get(phase, 0)), rounds), phase
+    for phase in HOST:
+        assert read(f"serve_engine_dry_{phase}_s") == (
+            pytest.approx(both.get(f"dry_{phase}", 0)), rounds), phase
+    host = read("serve_engine_round_host_s")
+    blocked = read("serve_engine_round_blocked_s")
+    assert host == (pytest.approx(sum(both.get(p, 0) for p in HOST)), rounds)
+    assert blocked == (pytest.approx(sum(both.get(p, 0) for p in BLOCKED)), rounds)
+
+
 def test_phases_tile_the_engine_span(mix):
     by_trace = {}
     for e in mix["events"]:
@@ -275,15 +473,26 @@ def test_switched_off_nothing_is_stamped(srv, ring, monkeypatch):
             return False
 
     monkeypatch.setattr(jax.profiler, "TraceAnnotation", Counting)
+    # the account's clock, and what it asks a result the device owes it
+    from ray_tpu.serve.llm import _RoundAccount
+
+    reads, asked = [], []
+    monkeypatch.setattr(_RoundAccount, "clock",
+                        staticmethod(lambda: reads.append(1) or time.monotonic()))
+    array, is_ready = type(jax.numpy.zeros(())), type(jax.numpy.zeros(())).is_ready
+    monkeypatch.setattr(array, "is_ready", lambda self: asked.append(1) or is_ready(self))
     tracing.set_enabled(False)
     core_metrics.set_enabled(False)
     try:
+        time.sleep(0.6)  # the round that was parked as the switch went off
+        del reads[:], asked[:], built[:]
         before = totals()
         out = ask(srv, SHARED + tail(9), trace_id="off", max_new=12)
         time.sleep(0.05)
         assert len(out) == 12
         assert totals() == before
         assert ring == [] and built == []
+        assert reads == [] and asked == []
     finally:
         tracing.set_enabled(True)
         core_metrics.set_enabled(True)
@@ -293,6 +502,7 @@ def test_switched_off_nothing_is_stamped(srv, ring, monkeypatch):
     while not ring and time.monotonic() < deadline:
         time.sleep(0.01)
     assert {e["component"] for e in ring} >= {"engine", "engine.queue"} and built
+    assert reads and asked
 
 
 def test_span_without_jax_imports_no_jax():
@@ -309,3 +519,30 @@ def test_span_without_jax_imports_no_jax():
     proc = subprocess.run([sys.executable, "-c", code], capture_output=True, text=True,
                           timeout=120, cwd=root)
     assert proc.returncode == 0 and proc.stdout.strip() == "ok", proc.stderr[-2000:]
+
+
+def test_rt_top_shows_the_dry_share_beside_host_ms():
+    """Sum of the dry seconds over the sum of every working phase's, per
+    deployment, from the series alone; no column where nothing worked."""
+    from ray_tpu.cli import _render_top
+
+    def hist(total, count, dep="m"):
+        return {"kind": "histogram", "tag_keys": ("deployment",), "boundaries": (),
+                "series": {(dep,): {"sum": total, "count": count, "buckets": []}}}
+
+    mx = {f"rt_serve_engine_{p}_s": hist(1.0, 10) for p in core_metrics.ENGINE_PHASES}
+    mx.update({f"rt_serve_engine_dry_{p}_s": hist(0.0, 10) for p in HOST})
+    mx["rt_serve_engine_dry_dispatch_s"] = hist(0.5, 10)
+    mx["rt_serve_engine_dry_other_s"] = hist(0.2, 10)
+    mx["rt_serve_engine_round_host_s"] = hist(5.0, 10)
+    def cell(frame, column):
+        lines = frame.splitlines()
+        head = next(i for i, line in enumerate(lines) if "HOST_MS" in line)
+        at = lines[head].index(column)
+        return lines[head + 2][at:at + len(column) + 2].split()
+
+    frame = _render_top(mx, {}, None)
+    assert cell(frame, "HOST_MS") == ["500.0"]
+    assert cell(frame, "DRY%") == ["10.0"]  # 0.7 s of the seven phases' 7
+    assert cell(_render_top({"rt_serve_engine_round_host_s": hist(5.0, 10)}, {}, None),
+                "DRY%") == []

@@ -9,6 +9,7 @@ import os
 import subprocess
 import sys
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -107,6 +108,31 @@ def test_expert_counts_come_back_with_the_tokens(engine):
     assert 0 < _series("rt_serve_moe_experts_hit_total") <= _series("rt_serve_moe_expert_steps_total")
     assert _series("rt_serve_moe_max_load_total") >= 1
     assert _series("rt_serve_kv_window_bytes") > 0 and _series("rt_serve_kv_full_bytes") > 0
+
+
+def test_the_account_of_a_round_adds_up_where_a_call_takes_rows(engine):
+    """A module that takes rows hands the device the sampling of a call's
+    first tokens between its prefill call and their sync, outside every
+    inner span: the round's ``other``. The phases of the thread's own code
+    still add up to its host time, and no dry second is outside them."""
+    from ray_tpu.observability import core_metrics
+
+    if not core_metrics.ENABLED:
+        pytest.skip("observability is off")
+
+    def seconds(key):
+        series = getattr(core_metrics, key).snapshot()["series"]
+        return sum(s["sum"] for k, s in series.items() if "mimo-v2-tiny" in str(k))
+
+    keys = [f"serve_engine_{p}_s" for p in core_metrics.ENGINE_HOST_PHASES]
+    dry = [f"serve_engine_dry_{p}_s" for p in core_metrics.ENGINE_HOST_PHASES]
+    before = {k: seconds(k) for k in [*keys, *dry, "serve_engine_round_host_s"]}
+    engine({"prompt_tokens": [5, 4, 3, 2, 1], "max_new_tokens": 6})
+    time.sleep(0.05)  # the last round's own stamps
+    spent = {k: seconds(k) - before[k] for k in before}
+    assert sum(spent[k] for k in keys) == pytest.approx(
+        spent["serve_engine_round_host_s"], rel=1e-6)
+    assert all(0 <= spent[d] <= spent[k] + 1e-9 for d, k in zip(dry, keys))
 
 
 def test_a_prefix_hit_is_refused_by_name_not_served_wrong(engine):
