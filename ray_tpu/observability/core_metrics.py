@@ -264,6 +264,17 @@ def _build() -> dict:
             "a call was padded with excluded)",
             tag_keys=("deployment",),
         ),
+        "serve_first_tokens_ahead": Counter(
+            "rt_serve_first_tokens_ahead_total",
+            "first tokens that reached a decode call from the device, "
+            "where the sampling program left them, before the host had "
+            "fetched them; counted where that decode call is handed over. "
+            "Beside rt_serve_ttft_s's count, the share of first tokens "
+            "whose wait lay behind a decode call. No series from a decode "
+            "module of one row, whose first token is fetched where its "
+            "prompt ends",
+            tag_keys=("deployment",),
+        ),
         "serve_prefill_width": Histogram(
             "rt_serve_prefill_width",
             "positions of each prefill call as dispatched, rows x padded "

@@ -27,7 +27,7 @@ import collections
 import os
 import threading
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, NamedTuple, Optional
 
 from ray_tpu import serve
 from ray_tpu.observability import core_metrics, tracing
@@ -136,6 +136,15 @@ class _Chunk:
         self.by_row: Dict[int, Any] = {}
         self.dropped: set = set()  # rows cancelled mid-flight
         self.free_after: List[int] = []  # pages released at harvest
+
+
+class _FirstTokens(NamedTuple):
+    """First tokens sampled on the device and not yet on the host. The
+    decode call that carries them takes them from the vector on the
+    device; the host fetches them behind it."""
+
+    dev: Any  # what ``dec.sample`` left: one token a row of the prefill call
+    rows: List[tuple]  # (position in ``dev``, decode row, sequence) of each prompt that ended
 
 
 class _PagedSeq:
@@ -323,14 +332,18 @@ class _Phase:
 
 
 def _upload(*host_arrays):
-    """Device copies of the engine's long-lived host mirrors. On the CPU
-    backend ``jnp.asarray`` of a 64-byte-aligned NumPy array SHARES its
-    memory, and the loop writes those mirrors again (``retire()`` zeroes
-    a row at dispatch) before the dispatched program has read them; a
-    TPU copies on transfer, so only the copy here differs by platform."""
+    """Device copies of the engine's long-lived host mirrors, each made
+    from a private copy on the host. On the CPU backend ``jnp.asarray`` of
+    a 64-byte-aligned NumPy array SHARES its memory, and ``jnp.array`` of
+    one shares it too until a copy that is queued behind the programs in
+    flight has run; the loop writes those mirrors again (``retire()``
+    zeroes a row at dispatch) before then, and since the decode call goes
+    over behind a prefill call still running, a row that ended in its first
+    chunk decoded over an all-zero page table. A TPU copies during the
+    call, so only the host's copy here differs by platform."""
     import jax.numpy as jnp
 
-    return tuple(jnp.array(a) for a in host_arrays)
+    return tuple(jnp.asarray(a.copy()) for a in host_arrays)
 
 
 def _plan_prefill_rows(pending, widths, row_counts, several: bool, limit: int):
@@ -789,20 +802,31 @@ class LLMServer:
             elif pages:
                 pool.release_pages(pages)
 
-        def activate(i: int, s: _PagedSeq, first: int, kv_len: int) -> None:
-            """Prefill (or import) complete: the sequence joins the
-            decode batch at position ``kv_len`` with ``first`` sampled."""
+        def activate(i: int, s: _PagedSeq, kv_len: int) -> None:
+            """Prefill complete: the sequence joins the decode batch at
+            position ``kv_len`` with all the host knows of it; its first
+            token reaches ``last`` from wherever it was sampled. A sequence
+            whose budget ends at that token (one token asked or none, or
+            the context full) is live in no decode call and changes no row."""
             s.active = True
             s.length = kv_len
-            s.produced = [first]
-            s.last_token = first
             s.budget_left = min(s.req.max_new - 1, T_max - 1 - kv_len)
+            if s.budget_left <= 0:
+                return
             tables[i] = s.table
-            last[i] = first
             lengths[i] = kv_len
             temps[i] = max(s.req.temperature, 1e-6)
             greedy[i] = s.req.temperature <= 0
             dirty.add(i)
+
+        def first_token_on_host(i: int, s: _PagedSeq, first: int) -> None:
+            """The sequence's first token is on the host: kept, stamped
+            and sent on."""
+            s.produced = [first]
+            s.last_token = first
+            if seqs[i] is s and s.budget_left > 0:
+                # the host mirror, for a full rebuild of the step state
+                last[i] = first
             if core_metrics.ENABLED or tracing.ENABLED:
                 now = s.t_first = time.monotonic()
                 if tracing.ENABLED and s.req.t0_us:
@@ -922,7 +946,8 @@ class LLMServer:
             RT_SERVE_PREFILL_CHUNK_TOKENS prompt tokens per engine round
             (0 = unchunked) for all sequences together, so a long
             prompt prefills across rounds interleaved with decode steps
-            and in-flight streams keep a bounded ITL."""
+            and in-flight streams keep a bounded ITL. A first token is
+            fetched where its prompt ends: none is left on the device."""
             nonlocal cache_k, cache_v
             chunk = int(config.serve_prefill_chunk_tokens)
             budget = chunk if chunk > 0 else (1 << 30)
@@ -958,7 +983,8 @@ class LLMServer:
                     seal_prompt(s)
                     with phase("first_token_sync", waits_for=logits):
                         first = self._sample_one(logits, s.req.temperature)
-                    activate(i, s, int(first), len(s.prompt))
+                    activate(i, s, len(s.prompt))
+                    first_token_on_host(i, s, int(first))
 
         # what a prefill call of a module that takes rows looks like (the
         # decode module's word): the row counts compiled, and the widths
@@ -967,8 +993,14 @@ class LLMServer:
         row_widths = tuple(
             w for w in dec.PREFILL_ROW_WIDTHS if w <= max_pages * B
         ) if row_counts[-1] > 1 else ()
-        # first tokens of a call of rows, sampled together
+        # first tokens of a call of rows, sampled together, and the
+        # program that writes them into the step state's last tokens where
+        # they lie: ``rows`` [R] names the decode row of each, and a
+        # position past the last row is nobody's and dropped
         self._sample_rows = jax.jit(dec.sample)
+        self._place_rows = jax.jit(
+            lambda last_tokens, firsts, rows: last_tokens.at[rows].set(firsts, mode="drop")
+        )
 
         def call_rows(R: int, P: int, rows: List[tuple]):
             """Dispatch one ``prefill_paged`` of ``R`` rows ``P`` wide for
@@ -1005,17 +1037,22 @@ class LLMServer:
             )
 
         def compile_calls_of_rows() -> None:
-            """Every (R, P) a round can dispatch, and the sampling behind
-            it, run once on the scratch page with rows of no length:
+            """Every (R, P) a round can dispatch, the sampling behind it
+            and the placing of its first tokens, run once on the scratch
+            page with rows of no length:
             compiled (or loaded from the cache) before the engine reports
             ready, because requests that arrive one at a time never meet
             a call of several rows and a program first met under load
             compiles there."""
+            nobody = jnp.zeros((S,), jnp.int32)
             for R in row_counts:
                 for P in row_widths:
-                    jax.block_until_ready(first_tokens(call_rows(R, P, []), []))
+                    firsts = first_tokens(call_rows(R, P, []), [])
+                jax.block_until_ready(
+                    self._place_rows(nobody, firsts, jnp.full((R,), S, jnp.int32))
+                )
 
-        def prefill_rows() -> None:
+        def prefill_rows() -> Optional[_FirstTokens]:
             """Chunked prefill of a decode module that takes rows: ONE
             call a round, for the next chunk (at most
             RT_SERVE_PREFILL_CHUNK_TOKENS tokens, a row) of every admitted
@@ -1023,7 +1060,9 @@ class LLMServer:
             where every layer is paged a sequence's tail takes several
             rows of the call. The expert layers and every other weight are
             read once for all of them. First tokens are sampled together
-            and fetched in one sync."""
+            and STAY on the device: returned where a prompt ended in the
+            call, for the round to hand its decode call over before it
+            waits for them (``land_first_tokens``)."""
             pending = [
                 (i, s.prefill_pos, len(s.prompt) - s.prefill_pos)
                 for i, s in enumerate(seqs)
@@ -1031,7 +1070,7 @@ class LLMServer:
                 and s.prefill_pos < len(s.prompt)
             ]
             if not pending:
-                return
+                return None
             chunk = int(config.serve_prefill_chunk_tokens)
             R, P, rows = _plan_prefill_rows(
                 pending, row_widths, row_counts, dec.PREFIX_CACHE,
@@ -1050,7 +1089,7 @@ class LLMServer:
                 if pos + n >= len(seqs[i].prompt)
             ]
             if not done:
-                return
+                return None
             for _, i in done:
                 seal_prompt(seqs[i])
             ends = dict(done)
@@ -1060,10 +1099,21 @@ class LLMServer:
                 for r in range(len(rows))
             ])
             acct.handed(firsts_dev)
-            with phase("first_token_sync", waits_for=firsts_dev):
-                toks = np.asarray(firsts_dev)
-            for r, i in done:
-                activate(i, seqs[i], int(toks[r]), len(seqs[i].prompt))
+            for _, i in done:
+                activate(i, seqs[i], len(seqs[i].prompt))
+            return _FirstTokens(firsts_dev, [(r, i, seqs[i]) for r, i in done])
+
+        def land_first_tokens(ahead: _FirstTokens, ended: List[int]) -> None:
+            """The one wait for a call's first tokens, behind the decode
+            call that carries them where the round had one to hand over:
+            each is delivered as soon as it is ON THE HOST, and a sequence
+            that ends at its first token (``ended``, rows) is answered."""
+            with phase("first_token_sync", waits_for=ahead.dev):
+                toks = np.asarray(ahead.dev)
+            for r, i, s in ahead.rows:
+                first_token_on_host(i, s, int(toks[r]))
+            for i in ended:
+                finish(i)
 
         # separate paths by what the decode module declares, not one path
         # with parameters: a module of one row makes the calls it always
@@ -1118,18 +1168,21 @@ class LLMServer:
                     retire(i)
                     self._fail_request(s.req, e)
             if inflight is not None:
-                # the lookahead chunk dies unharvested: release its
-                # deferred pages (the pool resets with the cache rebuild
-                # anyway — this keeps occupancy honest even if the
-                # rebuild itself keeps failing) and fail the requests
-                # whose finish was scheduled at its dispatch
                 rec, inflight = inflight, None
-                if rec.free_after:
-                    pool.release_pages(rec.free_after)
-                    rec.free_after = []
-                for _i, s, fin in rec.rows:
-                    if fin:
-                        self._fail_request(s.req, e)
+                fail_chunk(rec, e)
+
+        def fail_chunk(rec: _Chunk, e: BaseException) -> None:
+            # a dispatched chunk dies unharvested: release its deferred
+            # pages (the pool resets with the cache rebuild anyway — this
+            # keeps occupancy honest even if the rebuild itself keeps
+            # failing) and fail the requests whose finish was scheduled
+            # at its dispatch
+            if rec.free_after:
+                pool.release_pages(rec.free_after)
+                rec.free_after = []
+            for _i, s, fin in rec.rows:
+                if fin:
+                    self._fail_request(s.req, e)
 
         def harvest(rec: _Chunk) -> None:
             """Materialize a dispatched chunk's tokens and run all its
@@ -1179,7 +1232,7 @@ class LLMServer:
                     if (
                         s.req.token_q is not None
                         and not s.req.cancelled
-                        and len(s.produced) > 1  # first sent at activate
+                        and len(s.produced) > 1  # the first was sent where it landed
                         and len(s.produced) <= s.req.max_new
                     ):
                         s.req.token_q.put(s.last_token)
@@ -1197,7 +1250,12 @@ class LLMServer:
                 pool.release_pages(rec.free_after)
                 rec.free_after = []
 
-        def dispatch(active: List[int], K: int) -> _Chunk:
+        def dispatch(active: List[int], K: int,
+                     ahead: Optional[_FirstTokens] = None) -> _Chunk:
+            """Hand the device the next decode call of ``K`` steps for the
+            rows ``active``, behind the scatter of the rows that changed;
+            the first tokens of ``ahead`` go from the sampled vector into
+            the call's last tokens on the device, no host in between."""
             nonlocal cache_k, cache_v, dev_state, step_no
             if dev_state is None:
                 dev_state = _upload(last, lengths, temps, greedy, tables)
@@ -1216,6 +1274,15 @@ class LLMServer:
                 dev_state = dec.update_rows_paged(*dev_state, *changed)
                 dirty.clear()
             d_last, d_len, d_temps, d_greedy, d_tables = dev_state
+            # a sequence that ended at its first token is in no call: its
+            # position stays past the last row, like a padded one's
+            carried = [(r, i) for r, i, s in ahead.rows if s.budget_left > 0] if ahead else []
+            if carried:
+                at = np.full(ahead.dev.shape, S, np.int32)
+                for r, i in carried:
+                    at[r] = i
+                acct.device_call()
+                d_last = self._place_rows(d_last, ahead.dev, jnp.asarray(at))
             self._record_step_paged(len(active), pool.stats())
             acct.device_call()
             # a module with STEP_COUNTERS returns their counts last
@@ -1240,6 +1307,8 @@ class LLMServer:
                 )
                 dev_state = (toks_dev, d_len, d_temps, d_greedy, d_tables)
             acct.handed(toks_dev)
+            if carried and core_metrics.ENABLED:
+                core_metrics.serve_first_tokens_ahead.inc(len(carried), tags=dep_tags)
             rec = _Chunk(toks_dev, K, counted[0] if counted else None)
             for i in active:
                 s = seqs[i]
@@ -1319,23 +1388,23 @@ class LLMServer:
             self._work.clear()
             with phase("admit"):
                 admitted = admit_waiting()
-            run_prefill()
+            ahead = run_prefill()
             prefilling = any(
                 s is not None and not s.active for s in seqs
             )
-            active = [
+            live = [
                 i for i in range(S)
                 if seqs[i] is not None and seqs[i].active
             ]
-            # single-token answers (and 0-token asks) finish immediately
-            for i in list(active):
-                s = seqs[i]
-                if len(s.produced) >= s.req.max_new or s.length >= T_max - 1:
+            # single-token answers (and 0-token asks, and a context
+            # filled by its prompt) end at their first token and enter no
+            # decode call: finished as soon as that token is on the host,
+            # which for a module of one row it is
+            ended = [i for i in live if seqs[i].budget_left <= 0]
+            active = [i for i in live if seqs[i].budget_left > 0]
+            if ahead is None:
+                for i in ended:
                     finish(i)
-            active = [
-                i for i in range(S)
-                if seqs[i] is not None and seqs[i].active
-            ]
             # rows held, live or still prefilling: a sequence whose client
             # has gone is found out at its first token, so a queue of such
             # prompts keeps rows held with none live for as long as they
@@ -1343,6 +1412,10 @@ class LLMServer:
             # take that for idle
             self._occupied = sum(s is not None for s in seqs)
             if not active:
+                if ahead is not None:
+                    # no decode call to hand over: the wait is for the tail
+                    land_first_tokens(ahead, ended)
+                    self._occupied = sum(s is not None for s in seqs)
                 if inflight is not None:
                     # drain the lookahead before idling: its tokens are
                     # real and its pending finishes must complete
@@ -1365,10 +1438,19 @@ class LLMServer:
                     seqs[i].budget_left for i in active
                 )))
             with phase("dispatch", k=K, rows=len(active)):
-                rec = dispatch(active, K)
-            # one-step lookahead: chunk N+1 is on the device; run chunk
-            # N's host bookkeeping underneath it
+                rec = dispatch(active, K, ahead)
+            # one-step lookahead: chunk N+1 is on the device; fetch the
+            # first tokens it carries and run chunk N's host bookkeeping
+            # underneath it
             prev, inflight = inflight, rec
+            if ahead is not None:
+                try:
+                    land_first_tokens(ahead, ended)
+                except Exception as e:
+                    if prev is not None:
+                        # neither in flight nor harvested: nobody else's
+                        fail_chunk(prev, e)
+                    raise
             if prev is not None:
                 harvest(prev)
             return True

@@ -24,6 +24,7 @@ Kill switch: RT_SERVE_PREFIX_CACHE=0 (checked at admission).
 from __future__ import annotations
 
 import hashlib
+import heapq
 import threading
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
@@ -98,7 +99,18 @@ class PagedKVPool:
     resident as cache and is reclaimed by global LRU only when ``alloc``
     runs dry — that residency IS the prefix cache, and eviction order is
     strictly least-recently-matched over everything not pinned by a
-    live request."""
+    live request: the victim is the ref-0 sealed page of the lowest
+    ``tick``, and a page's tick is written where it is allocated, sealed
+    or matched, never where it is released.
+
+    The evictable pages are kept in that order (``_evictable``: a heap of
+    ``(tick, idx)``, pushed where a sealed page's refs reach 0), so one
+    eviction is O(log n) and ``alloc`` O(pages asked), not a scan of
+    every sealed page a page evicted. A page matched, sealed anew or
+    evicted since its entry was pushed has another tick, pins or no
+    digest: the entry is stale and dropped where it is popped; the heap
+    is rebuilt from the live entries once the stale ones outnumber
+    them, so it stays O(sealed pages)."""
 
     def __init__(self, model_id: str, num_pages: int,
                  page_tokens: Optional[int] = None):
@@ -114,6 +126,9 @@ class PagedKVPool:
         # page 0 reserved as scratch: never on the free list
         self._free: List[int] = list(range(self.num_pages - 1, 0, -1))
         self._sealed: Dict[str, int] = {}  # digest -> page idx
+        # (tick, idx) of the sealed pages whose refs reached 0, stale
+        # entries among them (see the class's word on the order)
+        self._evictable: List[Tuple[int, int]] = []
         self._tick = 0
         self._closed = False
         # plain counters independent of the metrics kill switch, for
@@ -150,18 +165,34 @@ class PagedKVPool:
             return out
 
     def _evict_one_locked(self) -> bool:
-        victim: Optional[_Page] = None
-        for d, idx in self._sealed.items():
-            pg = self._pages[idx]
-            if pg.refs == 0 and (victim is None or pg.tick < victim.tick):
-                victim = pg
-        if victim is None:
-            return False  # every sealed page pinned by a live request
-        del self._sealed[victim.digest]
-        victim.digest = None
-        self._free.append(victim.idx)
-        self.evictions += 1
-        return True
+        heap = self._evictable
+        while heap:
+            tick, idx = heapq.heappop(heap)
+            victim = self._pages[idx]
+            if not self._still_evictable(victim, tick):
+                continue
+            del self._sealed[victim.digest]
+            victim.digest = None
+            self._free.append(idx)
+            self.evictions += 1
+            return True
+        return False  # every sealed page pinned by a live request
+
+    @staticmethod
+    def _still_evictable(pg: _Page, tick: int) -> bool:
+        """Is the heap's entry ``(tick, pg.idx)`` still the page's? Not if
+        it was matched, sealed anew or evicted since the entry was pushed."""
+        return pg.digest is not None and not pg.refs and pg.tick == tick
+
+    def _evictable_locked(self, pg: _Page) -> None:
+        """A sealed page no request pins: it takes its place in the
+        eviction order under the tick it has now."""
+        heap = self._evictable
+        heapq.heappush(heap, (pg.tick, pg.idx))
+        if len(heap) > 2 * len(self._sealed) + 64:
+            pages = self._pages
+            heap[:] = [(t, i) for t, i in heap if self._still_evictable(pages[i], t)]
+            heapq.heapify(heap)
 
     # -- prefix matching / sealing ------------------------------------
 
@@ -217,6 +248,8 @@ class PagedKVPool:
             self._sealed[digest] = page
             self._tick += 1
             pg.tick = self._tick
+            if pg.refs == 0:
+                self._evictable_locked(pg)
             return True
 
     # -- release / maintenance ----------------------------------------
@@ -230,10 +263,15 @@ class PagedKVPool:
         with self._lock:
             for idx in pages:
                 pg = self._pages[idx]
-                if pg.refs > 0:
+                pinned = pg.refs > 0
+                if pinned:
                     pg.refs -= 1
-                if pg.refs == 0 and pg.digest is None and not self._closed:
+                if pg.refs or self._closed:
+                    continue
+                if pg.digest is None:
                     self._free.append(idx)
+                elif pinned:
+                    self._evictable_locked(pg)
 
     def reset(self) -> None:
         """Drop ALL metadata (a poisoned engine round rebuilt the device
@@ -246,6 +284,7 @@ class PagedKVPool:
                 pg.digest = None
                 pg.tick = 0
             self._sealed.clear()
+            self._evictable.clear()
             self._free = list(range(self.num_pages - 1, 0, -1))
             self._tick = 0
 
@@ -292,6 +331,7 @@ class PagedKVPool:
                 pg.refs = 0
                 pg.digest = None
             self._sealed.clear()
+            self._evictable.clear()
             self._free = []
             self._closed = True
         with _POOLS_LOCK:

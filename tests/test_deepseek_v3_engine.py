@@ -230,3 +230,77 @@ def test_a_load_that_meets_every_call_of_rows_compiles_nothing(packer, monkeypat
         [(100,), (200,), (400,), (100, 90), (100, 90, 80), (200, 150), (200, 150, 140),
          (600,), (600, 520)],
         widths=(128, 256, 512))
+
+
+# -- the decode call before the wait for first tokens (PR 61) -----------------
+
+
+@pytest.fixture(scope="module")
+def streams():
+    """The seeded cases on an engine nobody has asked anything."""
+    import _engine_streams
+
+    return _engine_streams.fresh_streams("kanana-2-tiny")
+
+
+@pytest.mark.parametrize("case", ["greedy_cold", "sampled_cold", "greedy_second_turn",
+                                  "sampled_second_turn", "together", "one_token"])
+def test_seeded_requests_get_the_tokens_the_parent_gave(streams, case):
+    """Second turns stand behind sealed latent pages here: the first token
+    of a tail goes to its decode call on the device like a cold prompt's."""
+    import _engine_streams
+
+    assert streams[case] == _engine_streams.expected("kanana-2-tiny")[case]
+
+
+def test_a_tail_behind_its_prefix_hands_the_decode_call_over_before_the_wait(packer):
+    """A second turn behind four sealed pages beside a cold prompt, at a
+    temperature and greedy: one prefill call of rows, the sampling, the
+    scatter of the changed rows, the first tokens placed on the device, the
+    decode call, and only then the wait, which the dispatch span lies
+    before; both first tokens are counted ahead, and each request's first
+    token is what the decode call was given for its row."""
+    import _engine_streams
+    from test_llm_engine import enqueue_together
+    from test_mimo_engine import ask_together
+
+    (first,), (reply,) = ask_together(packer, (300,), max_new=6, seed=611)
+    rng = np.random.default_rng(612)
+    second = first + reply + list(map(int, rng.integers(0, 256, 90)))
+    other = list(map(int, rng.integers(0, 256, 50)))
+    ahead = _series("rt_serve_first_tokens_ahead_total")
+    reused = _series("rt_serve_prefix_tokens_reused_total")
+    seen, given = [], []
+    real_place = packer._place_rows
+
+    def placed(last_tokens, firsts, rows):
+        out = real_place(last_tokens, firsts, rows)
+        given.append((np.asarray(rows).tolist(), np.asarray(firsts).tolist(),
+                      np.asarray(out).tolist()))
+        return out
+
+    packer._place_rows = placed
+    try:
+        with _engine_streams.watch_the_round(packer, seen):
+            reqs = enqueue_together(packer, [
+                {"prompt_tokens": second, "max_new_tokens": 7, "temperature": 0.8},
+                {"prompt_tokens": other, "max_new_tokens": 7}])
+            for r in reqs:
+                assert r.event.wait(300) and r.error is None
+    finally:
+        packer._place_rows = real_place
+    assert _series("rt_serve_prefix_tokens_reused_total") - reused == 256
+    assert _series("rt_serve_first_tokens_ahead_total") - ahead == 2
+    assert_greedy_by_the_reference(packer, other, reqs[1].result)
+    ending = [r for r in _engine_streams.rounds_of(seen) if ("call", "sample") in r]
+    assert len(ending) == 1
+    assert [name for kind, name in ending[0] if kind == "call"] == [
+        "prefill", "sample", "scatter", "place", "decode"]
+    at = ending[0].index
+    assert at(("end", "dispatch")) < at(("span", "first_token_sync"))
+    # the rows of the call that held a prompt's end, and what the decode call took
+    (rows, firsts, last_tokens), = given
+    live = [(i, t) for i, t in zip(rows, firsts) if i < len(last_tokens)]
+    assert sorted(i for i, _ in live) == [0, 1] and len(rows) == 4
+    assert [last_tokens[i] for i, _ in live] == [t for _, t in live]
+    assert sorted(t for _, t in live) == sorted(r.result[0] for r in reqs)

@@ -358,6 +358,39 @@ def account_case(case, monkeypatch):
         want = {"other": 1, "harvest_sync": 1, "admit": 2, "dispatch": 4,
                 "dry_admit": 2, "dry_dispatch": 1}
         assert acct.tail is nxt and acct.dry_since is None
+    elif case == "first_sync_under_a_decode_call":
+        # a round that ends a prompt since PR 61: prefill call, sampling,
+        # then the scatter and the decode call go over inside the dispatch,
+        # and the wait for the first tokens comes behind it. Its return says
+        # nothing of the device: the decode call is the tail, and runs
+        acct.handed(chunk)
+        in_phase("admit", 2)
+        in_phase("prefill", 2, call=prefill)
+        spend(1)
+        acct.device_call()
+        acct.handed(firsts)
+        spend(1)
+        in_phase("dispatch", 4, call=nxt)
+        in_phase("first_token_sync", 8, waits_for=firsts)
+        assert acct.tail is nxt and acct.dry_since is None
+        in_phase("harvest_sync", 1, waits_for=chunk)
+        in_phase("harvest", 2)
+        acct.end(True)
+        want = {"other": 3, "admit": 2, "prefill": 2, "dispatch": 4, "first_token_sync": 8,
+                "harvest_sync": 1, "harvest": 2}
+        assert acct.tail is nxt and acct.dry_since is None
+    elif case == "first_sync_with_no_decode_call_behind":
+        # every sequence of the call ended at its first token and no row is
+        # live: nothing goes over, the first tokens are the tail and their
+        # sync's return opens a stretch, as before
+        in_phase("prefill", 2, call=prefill)
+        acct.device_call()
+        acct.handed(firsts)
+        in_phase("first_token_sync", 8, waits_for=firsts)
+        spend(3)
+        acct.end(True)
+        want = {"other": 4, "prefill": 2, "first_token_sync": 8, "dry_other": 3}
+        assert acct.tail is None and acct.dry_since is not None
     elif case == "exception_forgets_the_tail":
         acct.handed(chunk)
         with pytest.raises(RuntimeError):
@@ -378,7 +411,8 @@ def account_case(case, monkeypatch):
 
 ACCOUNT_CASES = ("sync_on_the_tail", "tail_not_ready", "tail_found_ready_at_a_boundary",
                  "sync_on_a_chunk_that_is_not_the_tail", "parked_round",
-                 "any_program_ends_the_stretch", "exception_forgets_the_tail")
+                 "any_program_ends_the_stretch", "exception_forgets_the_tail",
+                 "first_sync_under_a_decode_call", "first_sync_with_no_decode_call_behind")
 
 
 @pytest.mark.parametrize("case", ACCOUNT_CASES)
@@ -546,3 +580,69 @@ def test_rt_top_shows_the_dry_share_beside_host_ms():
     assert cell(frame, "DRY%") == ["10.0"]  # 0.7 s of the seven phases' 7
     assert cell(_render_top({"rt_serve_engine_round_host_s": hist(5.0, 10)}, {}, None),
                 "DRY%") == []
+
+
+# -- the wait for first tokens behind the decode call (PR 61) ----------------
+
+
+@pytest.fixture(scope="module")
+def streams():
+    """The seeded cases on an engine nobody has asked anything."""
+    import _engine_streams
+
+    return _engine_streams.fresh_streams("gpt2-tiny")
+
+
+@pytest.mark.parametrize("case", ["greedy_cold", "sampled_cold", "greedy_second_turn",
+                                  "sampled_second_turn", "together", "one_token",
+                                  "context_full"])
+def test_seeded_requests_get_the_tokens_the_parent_gave(streams, case):
+    """GPT-2's module of one row is on the path it was on: its tokens are
+    the parent's because its calls are."""
+    import _engine_streams
+
+    assert streams[case] == _engine_streams.expected("gpt2-tiny")[case]
+
+
+def test_a_module_of_one_row_waits_where_it_waited_and_counts_no_token_ahead(srv):
+    """``prefill_a_sequence_a_call``: the first token is fetched where its
+    prompt ends, before the dispatch, no sampling program and no placing on
+    the device, and ``rt_serve_first_tokens_ahead_total`` does not move."""
+    import _engine_streams
+
+    def ahead():
+        return sum(core_metrics.serve_first_tokens_ahead.snapshot()["series"].values())
+
+    ask(srv, [3, 1, 4, 1, 5], max_new=4)  # the step state is on the device
+    before, firsts = ahead(), totals()["serve_ttft_s"][1]
+    seen = []
+    with _engine_streams.watch_the_round(srv, seen):
+        tokens = ask(srv, [9, 2, 6, 5, 3, 5, 8], max_new=6)
+    assert len(tokens) == 6
+    assert totals()["serve_ttft_s"][1] - firsts == 1 and ahead() == before
+    ending = [r for r in _engine_streams.rounds_of(seen) if ("span", "first_token_sync") in r]
+    assert len(ending) == 1
+    assert [name for kind, name in ending[0] if kind == "call"] == ["prefill", "scatter", "decode"]
+    at = ending[0].index
+    assert at(("call", "prefill")) < at(("end", "first_token_sync")) < at(("span", "dispatch"))
+    assert not hasattr(srv._dec, "PREFILL_ROW_WIDTHS")
+
+
+def test_the_step_state_is_uploaded_from_a_copy_the_loop_cannot_write():
+    """``_upload``: the loop writes its mirrors again right behind the
+    upload (``retire()`` zeroes a row at dispatch) and, since PR 61, while a
+    prefill call still runs in front of the decode call. On the CPU
+    ``jnp.array`` of an aligned mirror read it after the write."""
+    import numpy as np
+
+    from ray_tpu.serve.llm import _upload
+
+    raw = np.zeros((4096 + 16,), np.int32)
+    start = (-raw.ctypes.data % 64) // 4
+    mirror = raw[start:start + 4096]
+    assert mirror.ctypes.data % 64 == 0
+    for _ in range(8):
+        mirror[:] = 7
+        (held,) = _upload(mirror)
+        mirror[:] = 0
+        assert int(np.asarray(held).sum()) == 7 * 4096

@@ -336,6 +336,16 @@ def test_a_prompt_over_a_rows_width_takes_a_row_a_round(packer, monkeypatch):
                                    (1, 128, [(0, 128, 72)])]
 
 
+def record_releases(monkeypatch, srv):
+    """From here on every page the engine's pool is given back lands in the
+    list returned: given back once means no page twice."""
+    pool, released = srv._prefix_pool, []
+    real_release = pool.release_pages
+    monkeypatch.setattr(pool, "release_pages",
+                        lambda pages: (released.extend(pages), real_release(pages))[1])
+    return released
+
+
 def programs_compiled(srv, prefill_paged):
     return prefill_paged._cache_size(), srv._sample_rows._cache_size()
 
@@ -375,11 +385,7 @@ def test_a_sequence_cancelled_in_a_call_of_rows_gives_its_pages_back_once(packer
 
     from ray_tpu.models import mimo_v2 as dec
 
-    pool = packer._prefix_pool
-    released = []
-    real_release = pool.release_pages
-    monkeypatch.setattr(pool, "release_pages",
-                        lambda pages: (released.extend(pages), real_release(pages))[1])
+    pool, released = packer._prefix_pool, record_releases(monkeypatch, packer)
     rng = np.random.default_rng(9)
     asks = [{"prompt_tokens": list(map(int, rng.integers(0, 256, n))), "max_new_tokens": 20}
             for n in (90, 60, 30)]
@@ -400,3 +406,161 @@ def test_a_sequence_cancelled_in_a_call_of_rows_gives_its_pages_back_once(packer
     assert len(released) == len(set(released)) > 0
     stats = pool.stats()
     assert stats["pages_occupied"] == 0 and pool.free_pages() == stats["pages_total"]
+
+
+# -- the decode call before the wait for first tokens (PR 61) -----------------
+
+
+@pytest.fixture(scope="module")
+def streams():
+    """The seeded cases on an engine nobody has asked anything."""
+    import _engine_streams
+
+    return _engine_streams.fresh_streams("mimo-v2-tiny")
+
+
+@pytest.mark.parametrize("case", ["greedy_cold", "sampled_cold", "greedy_second_turn",
+                                  "sampled_second_turn", "together", "one_token",
+                                  "context_full"])
+def test_seeded_requests_get_the_tokens_the_parent_gave(streams, case):
+    import _engine_streams
+
+    assert streams[case] == _engine_streams.expected("mimo-v2-tiny")[case]
+
+
+def test_a_round_that_ends_a_prompt_hands_the_decode_call_over_before_it_waits(packer):
+    """The device's calls of such a round are prefill, sample, the scatter
+    of the changed rows, the placing of the first tokens and decode, and
+    the ``first_token_sync`` span opens behind the ``dispatch`` span's end:
+    the wait lies under a running step. One first token is counted ahead
+    a sequence, and the chunk in flight is harvested behind the wait."""
+    import _engine_streams
+
+    ask_together(packer, (20,), max_new=2, seed=60)  # the step state is on the device
+    ahead = _series("rt_serve_first_tokens_ahead_total")
+    firsts = _series("rt_serve_tokens_generated_total")
+    seen = []
+    with _engine_streams.watch_the_round(packer, seen):
+        prompts, answers = ask_together(packer, (100, 40), max_new=6, seed=61)
+        time.sleep(0.1)
+    for prompt, tokens in zip(prompts, answers):
+        assert_greedy_by_the_reference(packer, prompt, tokens)
+    assert _series("rt_serve_first_tokens_ahead_total") - ahead == 2
+    assert _series("rt_serve_tokens_generated_total") - firsts == 12
+    rounds = _engine_streams.rounds_of(seen)
+    ending = [r for r in rounds if ("call", "sample") in r]
+    assert len(ending) == 1
+    calls = [name for kind, name in ending[0] if kind == "call"]
+    assert calls == ["prefill", "sample", "scatter", "place", "decode"]
+    order = [ev for ev in ending[0] if ev[0] != "call"]
+    assert order == [("span", "admit"), ("end", "admit"), ("span", "prefill"), ("end", "prefill"),
+                     ("span", "dispatch"), ("end", "dispatch"),
+                     ("span", "first_token_sync"), ("end", "first_token_sync")]
+    # inside the dispatch span: everything from the scatter on
+    at = ending[0].index
+    assert at(("span", "dispatch")) < at(("call", "scatter")) < at(("call", "decode")) < at(
+        ("end", "dispatch"))
+    # the next round harvests the chunk that carried them and waits for no first token
+    after = rounds[rounds.index(ending[0]) + 1]
+    assert ("span", "harvest_sync") in after and ("span", "first_token_sync") not in after
+
+
+def test_an_answer_of_one_token_enters_no_decode_call_and_frees_its_pages_once(packer, monkeypatch):
+    """``max_new`` of 1 (and of 0) ends at the first token: no row changes,
+    no decode call is handed over for it, it is answered all the same, and
+    its pages go back once."""
+    import _engine_streams
+    from test_llm_engine import enqueue_together
+
+    pool, released = packer._prefix_pool, record_releases(monkeypatch, packer)
+    ahead = _series("rt_serve_first_tokens_ahead_total")
+    rng = np.random.default_rng(7)
+    prompts = [list(map(int, rng.integers(0, 256, n))) for n in (50, 30)]
+    seen = []
+    with _engine_streams.watch_the_round(packer, seen):
+        reqs = enqueue_together(packer, [
+            {"prompt_tokens": prompts[0], "max_new_tokens": 1},
+            {"prompt_tokens": prompts[1], "max_new_tokens": 0, "stream": True}])
+        for r in reqs:
+            assert r.event.wait(300) and r.error is None
+        time.sleep(0.1)
+    assert len(reqs[0].result) == 1 and reqs[1].result == []
+    assert_greedy_by_the_reference(packer, prompts[0], reqs[0].result)
+    assert reqs[1].token_q.get(timeout=5) is None  # the token nobody asked for is not sent
+    calls = [name for kind, name in seen if kind == "call"]
+    assert calls == ["prefill", "sample"]
+    assert _series("rt_serve_first_tokens_ahead_total") == ahead
+    assert len(released) == len(set(released)) > 0
+    assert pool.stats()["pages_occupied"] == 0
+
+
+def test_a_round_that_raises_behind_its_decode_call_fails_every_request_and_frees_once(
+        packer, monkeypatch):
+    """The wait for first tokens now stands between the decode call's
+    hand-over and the harvest of the chunk before it. Where it raises, the
+    sequences in rows, the chunk in flight and the chunk that was neither in
+    flight nor harvested are all failed (nobody waits out a timeout), every
+    page goes back once, and the engine answers the next request."""
+    from test_llm_engine import enqueue_together
+
+    pool, released = packer._prefix_pool, record_releases(monkeypatch, packer)
+
+    class Broken:
+        def put(self, tok):
+            if tok is not None:
+                raise RuntimeError("the stream's queue is gone")
+
+    rng = np.random.default_rng(8)
+    short, long = (list(map(int, rng.integers(0, 256, n))) for n in (40, 200))
+    with tokens_a_row(128):
+        # the short prompt ends in the first round and its last step is the
+        # chunk in flight when the long one's first token lands, a round later
+        reqs = [packer._parse({"prompt_tokens": short, "max_new_tokens": 2}),
+                packer._parse({"prompt_tokens": long, "max_new_tokens": 9, "stream": True})]
+        real_q, reqs[1].token_q = reqs[1].token_q, Broken()
+        with packer._lock:
+            packer._queue.extend(reqs)
+        packer._work.set()
+        for r in reqs:
+            assert r.event.wait(20), "a request was left waiting"
+    assert all(isinstance(r.error, RuntimeError) for r in reqs), [r.error for r in reqs]
+    assert len(released) == len(set(released)) > 0
+    deadline = time.monotonic() + 20
+    while pool.stats()["pages_occupied"] and time.monotonic() < deadline:
+        time.sleep(0.01)
+    assert pool.stats()["pages_occupied"] == 0
+    reqs[1].token_q = real_q
+    (prompt,), (answer,) = ask_together(packer, (60,), max_new=5, seed=62)
+    assert_greedy_by_the_reference(packer, prompt, answer)
+
+
+def test_a_request_cancelled_with_its_first_token_on_the_device_frees_its_pages_once(
+        packer, monkeypatch):
+    """Cancelled between the sampling of its first token and the wait for
+    it: the decode call carries the row, the token is still delivered, the
+    next round reaps the row and drops its tokens of the chunk in flight, and
+    the pages go back once that chunk is harvested."""
+    from test_llm_engine import enqueue_together
+
+    pool, released = packer._prefix_pool, record_releases(monkeypatch, packer)
+    rng = np.random.default_rng(10)
+    asks = [{"prompt_tokens": list(map(int, rng.integers(0, 256, n))), "max_new_tokens": 12,
+             "stream": True} for n in (70, 45)]
+    real = packer._sample_rows
+    reqs = []
+
+    def gone_as_it_is_sampled(*args):
+        reqs[0].cancelled = True
+        return real(*args)
+
+    monkeypatch.setattr(packer, "_sample_rows", gone_as_it_is_sampled)
+    reqs.extend(enqueue_together(packer, asks))
+    for r in reqs:
+        assert r.event.wait(300)
+    assert reqs[0].result is None and len(reqs[1].result) == 12
+    assert isinstance(reqs[0].token_q.get(timeout=5), int)  # its first token came
+    assert len(released) == len(set(released)) > 0
+    deadline = time.monotonic() + 20
+    while pool.stats()["pages_occupied"] and time.monotonic() < deadline:
+        time.sleep(0.01)
+    assert pool.stats()["pages_occupied"] == 0 and pool.free_pages() == pool.stats()["pages_total"]

@@ -15,7 +15,7 @@ import numpy as np
 import pytest
 from _llm_reference import engine_reference
 
-from ray_tpu.serve.prefix_cache import hash_blocks
+from ray_tpu.serve.prefix_cache import PagedKVPool, hash_blocks
 
 
 # ---------------------------------------------------------------------------
@@ -54,6 +54,191 @@ def test_hash_blocks_deterministic_across_processes():
         capture_output=True, text=True, check=True,
     )
     assert json.loads(out.stdout) == hash_blocks(tokens, 64)
+
+
+# ---------------------------------------------------------------------------
+# the pool's eviction order: the pages kept in order against the scan
+# ---------------------------------------------------------------------------
+
+
+class ScanningPool(PagedKVPool):
+    """The reference: the pool as it evicted before its evictable pages were
+    kept in order, one scan of every sealed page for the ref-0 one of the
+    lowest tick a page evicted."""
+
+    def _evict_one_locked(self):
+        victim = None
+        for idx in self._sealed.values():
+            pg = self._pages[idx]
+            if pg.refs == 0 and (victim is None or pg.tick < victim.tick):
+                victim = pg
+        if victim is None:
+            return False
+        del self._sealed[victim.digest]
+        victim.digest = None
+        self._free.append(victim.idx)
+        self.evictions += 1
+        return True
+
+
+def churn(pool, seed, steps=600):
+    """A seeded walk of requests over ``pool``: admissions that match a
+    chain's resident prefix and allocate the rest (refused whole where the
+    pool cannot cover them), seals of what an admitted request wrote,
+    releases in any order, now and then a reset. What every call returned,
+    the pool's stats after each, and the (digest, page) pairs in the order
+    they were evicted."""
+    rng = np.random.RandomState(seed)
+    chains = [[f"c{c}-{j}" for j in range(12)] for c in range(10)]
+    held, log, evicted = [], [], []
+    evict_one = pool._evict_one_locked
+
+    def watched():
+        before = dict(pool._sealed)
+        found = evict_one()
+        evicted.extend((d, i) for d, i in before.items() if d not in pool._sealed)
+        return found
+
+    pool._evict_one_locked = watched
+    for _ in range(steps):
+        op = rng.choice(["admit", "seal", "release", "reset"], p=[0.36, 0.3, 0.33, 0.01])
+        if op == "admit":
+            chain = chains[rng.randint(len(chains))]
+            want = int(rng.randint(1, len(chain) + 1))
+            _, hits = pool.match_pages(chain[:want], max_tokens=want * pool.page_tokens)
+            fresh = pool.alloc(want - len(hits) + int(rng.randint(0, 3)))
+            if fresh is None:
+                pool.release_pages(hits)
+            else:
+                held.append({"chain": chain, "pages": hits + fresh, "sealed": len(hits),
+                             "want": want})
+            log.append(("admit", hits, fresh))
+        elif op == "seal" and held:
+            req = held[rng.randint(len(held))]
+            upto = int(rng.randint(req["sealed"], req["want"] + 1))
+            log.append(("seal", [pool.seal(req["chain"][j], req["pages"][j])
+                                 for j in range(req["sealed"], upto)]))
+            req["sealed"] = upto
+        elif op == "release" and held:
+            req = held.pop(rng.randint(len(held)))
+            pages = list(req["pages"])
+            rng.shuffle(pages)
+            pool.release_pages(pages)
+            log.append(("release", pages))
+        elif op == "reset":
+            pool.reset()
+            held.clear()
+            log.append(("reset",))
+        log.append(pool.stats())
+    return log, evicted
+
+
+@pytest.mark.parametrize("seed", range(8))
+def test_the_pool_evicts_the_scans_victims_in_the_scans_order(seed):
+    """Same victims, same order, same answers and stats as the scan gave,
+    over seeded walks on a pool small enough that most admissions evict."""
+    kept, scan = PagedKVPool(f"kept-{seed}", 64, 4), ScanningPool(f"scan-{seed}", 64, 4)
+    try:
+        got, want = churn(kept, seed), churn(scan, seed)
+        assert got == want
+        assert kept.stats()["evictions"] == scan.stats()["evictions"] > 50
+        assert kept.stats()["hits"] > 100
+        # and page for page the two pools hold the same
+        assert [(p.refs, p.digest, p.tick) for p in kept._pages] == [
+            (p.refs, p.digest, p.tick) for p in scan._pages]
+        assert kept._free == scan._free and kept._sealed == scan._sealed
+    finally:
+        kept.close()
+        scan.close()
+
+
+class CountedPages(list):
+    """A pool's pages, counting every look at one."""
+
+    visits = 0
+
+    def __getitem__(self, i):
+        self.visits += 1
+        return list.__getitem__(self, i)
+
+    def __iter__(self):
+        for i in range(len(self)):
+            yield self[i]
+
+
+def full_pool(n_pages, name):
+    """Every page sealed and released: the free list dry, all evictable."""
+    pool = PagedKVPool(name, n_pages + 1, 4)
+    pages = pool.alloc(n_pages)
+    for j, page in enumerate(pages):
+        assert pool.seal(f"d{j}", page)
+    pool.release_pages(pages)
+    assert pool.free_pages() == 0 and pool.resident() == n_pages
+    return pool, pages
+
+
+@pytest.mark.parametrize("n", [1, 10, 80])
+def test_an_alloc_on_a_full_pool_looks_at_the_pages_it_takes(n):
+    """Counted in looks at a page, never by a clock: evicting n pages of
+    2,000 sealed ones looks at n, and handing them out at n more; the scan
+    looked at 2,000 a page evicted."""
+    pool, pages = full_pool(2000, f"full-{n}")
+    try:
+        pool._pages = counted = CountedPages(pool._pages)
+        got = pool.alloc(n)
+        assert got is not None and sorted(got) == sorted(pages[:n])  # the oldest n
+        assert counted.visits <= 2 * n
+        assert pool.stats()["evictions"] == n
+    finally:
+        pool.close()
+
+
+def test_entries_gone_stale_are_paid_for_once_and_the_heap_stays_small():
+    """A hot prefix matched and released a thousand times leaves a stale
+    entry each time. They never outnumber the sealed pages by more than
+    the rebuild's slack, and the looks of all allocs together stay within
+    a few a page asked, released or matched."""
+    pool, pages = full_pool(200, "stale")
+    try:
+        pool._pages = counted = CountedPages(pool._pages)
+        hot = [f"d{j}" for j in range(8)]
+        asked = 0
+        for turn in range(1000):
+            _, hit = pool.match_pages(hot, max_tokens=8 * 4)
+            assert len(hit) == 8
+            pool.release_pages(hit)
+            assert len(pool._evictable) <= 2 * pool.resident() + 64 + 1
+            if turn % 10 == 0:
+                got = pool.alloc(3)  # never a hot page: they were matched last
+                assert got is not None and not set(got) & set(pages[:8])
+                asked += 3
+                for j, page in enumerate(got):
+                    assert pool.seal(f"t{turn}-{j}", page)
+                pool.release_pages(got)
+        moved = asked + 2 * 8 * 1000  # pages asked, matched and released
+        assert counted.visits <= 4 * moved
+        assert pool.resident() == 200 and pool.stats()["evictions"] == asked
+    finally:
+        pool.close()
+
+
+def test_alloc_takes_nothing_where_eviction_cannot_cover_the_ask():
+    pool, pages = full_pool(20, "short")
+    try:
+        _, pinned = pool.match_pages([f"d{j}" for j in range(15)], max_tokens=15 * 4)
+        before = pool.stats()
+        assert pool.alloc(6) is None  # five evictable, six asked
+        after = pool.stats()
+        # what it evicted on the way stays evicted and free, as the scan left it
+        assert after["evictions"] - before["evictions"] == 5 and after["pages_free"] == 5
+        assert sorted(pool.alloc(5)) == sorted(pages[15:])
+        pool.release_pages(pinned)
+        pool.reset()
+        assert pool._evictable == [] and pool.free_pages() == 20 and pool.resident() == 0
+        pool.close()
+        assert pool._evictable == [] and pool.alloc(1) is None
+    finally:
+        pool.close()
 
 
 # ---------------------------------------------------------------------------
