@@ -10,7 +10,7 @@ and no other. Every routed expert and the whole vocabulary are held, so
 
 from __future__ import annotations
 
-from typing import Any, Dict, List, Sequence
+from typing import Any, Dict, List, Optional, Sequence
 
 from benchmark.families.gpt2 import warm_row_updates  # noqa: F401 — the engine's
 # row-update program is one program for every model (``update_rows_paged``)
@@ -258,16 +258,20 @@ def expected_experts_hit(model: Dict[str, Any], rows: float) -> float:
     return e * (1.0 - (1.0 - float(model["num_experts_per_tok"]) / e) ** rows)
 
 
-def decode_step_bytes(model: Dict[str, Any], rows: float,
-                      mean_context: float) -> float:
+def decode_step_bytes(model: Dict[str, Any], rows: float, mean_context: float,
+                      experts_hit: Optional[float] = None) -> float:
     """Bytes one decode step has to read and no more: the weights outside
     the routed experts once, the rows' embedding vectors, the weights of
-    the distinct experts the rows are expected to reach, the live K/V of
-    the full layers and the window's K/V of the sliding layers."""
+    the distinct experts the rows reached a layer-step where the decode
+    programs counted them (``experts_hit``, from ``decode_step_mfu``'s
+    reader: the kernel visits no expert without a row) and of the experts
+    expected under even routing where they did not, the live K/V of the
+    full layers and the window's K/V of the sliding layers."""
+    hit = expected_experts_hit(model, rows) if experts_hit is None else experts_hit
     total = BYTES * (params_outside_experts(model) + rows * int(model["hidden_size"]))
     for sliding, experts_here in _kinds(model):
         if experts_here:
-            total += BYTES * expected_experts_hit(model, rows) * expert_params(model)
+            total += BYTES * hit * expert_params(model)
         seen = min(mean_context, float(model["sliding_window"])) if sliding else mean_context
         total += rows * seen * position_bytes(model)
     return total

@@ -15,11 +15,40 @@ generator, ``check.compare_step`` and ``train_mfu`` are GPT-2's by name
     compare_serve(...)          prefill and decode through the paged cache
                                 against the plain reference, logits compared
     warm_row_updates(...)       the engine's row-update program, every count
-    decode_step_bytes(...)      bytes one decode step has to read (optional:
-                                without it ``decode_step_mfu`` reads nothing)
+    decode_step_bytes(model, rows, mean_context[, experts_hit])
+                                bytes one decode step has to read (optional:
+                                without it ``decode_step_mfu`` reads nothing).
+                                A family of routed experts takes the fourth
+                                argument, the experts the rows reached a
+                                layer-step as the program counted them, and
+                                falls back to the experts expected under
+                                even routing where it is not given
     held_experts(model)         routed experts the chip holds a layer (optional:
                                 without it ``moe_load_skew`` takes the
                                 configuration's ``n_routed_experts``)
+
+What a cell brings beside its family file, and how its test may hold it.
+A PR that adds a cell APPENDS: its configuration to ``configs``, its cell to
+``workloads``, its metrics to ``per_layer``, and the cell's name to the
+``workloads`` of every accepted metric it reports; it moves and edits no
+accepted entry (the driver reads an entry put in the middle as a change to
+the one it displaced, and refuses it). So the cell's test file under
+``tests/bench/`` holds its entries BY NAME AND BY MEMBERSHIP, NEVER BY
+PLACE: the cell is in ``workloads`` once and its configuration in
+``configs`` once (``test_bench_engine_metrics.listed_once``), its metrics'
+names are a subset of the lists it stands on, and no ``[-1]``, ``[-N:]`` or
+number indexes ``workloads``, ``configs``, ``per_layer`` or ``end_to_end``
+of the real file. The test file gives that check as a function of a
+``bench`` dictionary under the name ``the_cell_stands_on_its_lists`` and
+calls it with the real file;
+``tests/bench/test_bench_contract.py`` finds it by that name and calls it,
+with every rule of the contract, on a copy of ``BENCHMARK.json`` that has
+one more configuration, cell and per-layer entry appended. That test
+(``test_a_copy_with_a_cell_a_configuration_and_a_metric_appended_keeps_every_rule``
+and the one behind it) is the definition of "the harness takes a cell
+without an edit", and every PR that adds a cell runs it before it hands in:
+three accepted PRs (58, 59, 61) could list nothing while one cell's test
+pinned the ends of the lists.
 """
 
 from __future__ import annotations

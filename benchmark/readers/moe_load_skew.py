@@ -10,10 +10,16 @@ from benchmark import harness
 from benchmark.readers import counter_ratio
 
 
+def held_experts(model, ctx):
+    """Routed experts the chip holds a layer, by the family or by the
+    configuration's own key; None where neither says."""
+    ask = getattr(harness.family(getattr(ctx, "family", None)), "held_experts", None)
+    return ask(model) if ask else model.get("n_routed_experts")
+
+
 def read(obs, args, ctx):
     counters, model = obs.get("counters"), obs.get("model") or {}
-    ask = getattr(harness.family(getattr(ctx, "family", None)), "held_experts", None)
-    held = ask(model) if ask else model.get("n_routed_experts")
+    held = held_experts(model, ctx)
     if not counters or not held:
         return None
     pairs = counter_ratio.delta(counters, [[args["pairs"], "value"]])

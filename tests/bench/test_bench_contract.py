@@ -1,5 +1,8 @@
 """BENCHMARK.json against the contract, and against the benchmark's files."""
 
+import copy
+import glob
+import importlib
 import json
 import os
 import re
@@ -173,3 +176,121 @@ def test_configurations_keep_their_published_widths(label, bench, entry):
     family = harness.family(harness.find(bench, "families", cfg["family"], ".py"))
     assert family.program_sizes(cfg.get("model_id") or cfg["train"]["model_id"]) == model
     assert family.context(model) > 0
+
+
+# -- the lists take an addition ---------------------------------------------
+
+APPENDED = {"config": "appended-tiny-serve", "cell": "appended-tiny-decode",
+            "metric": "appended_share"}
+RULES = ["test_top_level_keys_and_limits", "test_names_units_and_entries",
+         "test_cells_configs_and_moves_hang_together",
+         "test_one_share_of_the_whole_steps_peak_bounds_each_claimable_metric",
+         "test_every_metric_traffic_and_generator_has_its_file",
+         "test_configurations_keep_their_published_widths"]
+LIST_CHECK = "the_cell_stands_on_its_lists"
+
+
+def appended(bench):
+    """A copy of ``bench`` as the next ``model_config`` PR would leave it: a
+    configuration, a cell and a per-layer entry APPENDED to their lists
+    (standing on the rehearsal's tiny files and on a metric file no
+    BENCHMARK.json lists), and the cell appended to the list of
+    ``serve_tok_s`` and to every per-layer list that all the cells on
+    ``serve_tok_s`` already share (the engine's series are every family's;
+    ``decode_step_mfu`` is among them). No accepted entry moves or changes
+    otherwise."""
+    b = copy.deepcopy(bench)
+    serving = set(next(m for m in b["end_to_end"] if m["name"] == "serve_tok_s")["workloads"])
+    for m in b["end_to_end"] + b["per_layer"]:
+        if serving <= set(m.get("workloads", [])):
+            m["workloads"].append(APPENDED["cell"])
+    b["configs"].append({"name": APPENDED["config"], "source": "tests", "reduced": [],
+                         "file": "tests/bench/configs/gpt2-tiny-serve.json",
+                         "why": "a configuration a later PR appends"})
+    b["workloads"].append({"name": APPENDED["cell"], "config": APPENDED["config"],
+                           "traffic": "tiny-decode", "chips": 1,
+                           "why": "a cell a later PR appends"})
+    b["per_layer"].append({"name": APPENDED["metric"], "unit": "%", "better": "lower",
+                           "source": "program_counter", "moves": "serve_tok_s",
+                           "layer": "OpenAI ingress, proxy, router",
+                           "workloads": [APPENDED["cell"]]})
+    return b
+
+
+def list_checks():
+    """Every family's ``the_cell_stands_on_its_lists(bench)``, found by that
+    name in the test files beside this one: a cell's test file brings its
+    own, and nothing here is edited for it."""
+    found = []
+    for path in sorted(glob.glob(os.path.join(HERE, "test_bench_*.py"))):
+        with open(path) as f:
+            if f"def {LIST_CHECK}(" not in f.read():
+                continue
+        module = importlib.import_module(os.path.basename(path)[: -len(".py")])
+        found.append(pytest.param(getattr(module, LIST_CHECK), id=module.CELL))
+    return found
+
+
+@pytest.fixture(scope="module")
+def appended_bench(tmp_path_factory):
+    """Written out and read back, as the driver would meet it."""
+    path = tmp_path_factory.mktemp("appended") / "BENCHMARK.json"
+    path.write_text(json.dumps(appended(load("BENCHMARK.json")), indent=1))
+    assert os.path.getsize(path) < 64 * 1024
+    return load(str(path))
+
+
+def test_the_copy_appends_and_changes_no_accepted_entry(appended_bench):
+    real = load("BENCHMARK.json")
+    for kind in ("configs", "workloads", "end_to_end", "per_layer"):
+        was, now = real[kind], appended_bench[kind]
+        grown = len(now) - len(was)
+        assert grown == (0 if kind == "end_to_end" else 1), kind
+        for old, new in zip(was, now):  # the accepted entries, in their order
+            assert {k: v for k, v in new.items() if k != "workloads"} == {
+                k: v for k, v in old.items() if k != "workloads"}
+            assert new.get("workloads", [])[: len(old.get("workloads", []))] == old.get(
+                "workloads", [])
+    assert [appended_bench[k][-1]["name"] for k in ("configs", "workloads", "per_layer")] == [
+        APPENDED["config"], APPENDED["cell"], APPENDED["metric"]]
+
+
+@pytest.mark.parametrize("rule", RULES)
+def test_a_copy_with_a_cell_a_configuration_and_a_metric_appended_keeps_every_rule(
+        rule, appended_bench):
+    """THE DEFINITION of "the harness takes a cell without an edit": every
+    rule of this file, run against ``appended(BENCHMARK.json)``. With the
+    cases of the next test it is what a PR that adds a cell runs before it
+    hands in (``benchmark/families/gpt2.py``, PERF.md section 4): where it
+    fails, some file of the benchmark holds an entry by its place, and the
+    PR could not have appended its own."""
+    check = globals()[rule]
+    if rule == "test_one_share_of_the_whole_steps_peak_bounds_each_claimable_metric":
+        for moved in ("serve_tok_s", "tpot_ms", "train_tok_s"):
+            check(appended_bench, moved)
+    elif rule == "test_configurations_keep_their_published_widths":
+        for entry in appended_bench["configs"]:
+            label = "appended" if entry["name"] == APPENDED["config"] else "BENCHMARK.json"
+            check(label, appended_bench, entry)
+    else:
+        check(appended_bench)
+
+
+@pytest.mark.parametrize("check", list_checks())
+def test_every_familys_cell_still_stands_on_its_lists_in_that_copy(check, appended_bench):
+    """Each family's "the cell stands on every list it reports and on none
+    it is kept off", a function of a ``bench`` that its own test calls with
+    the real file, called here with the copy: a cell's test holds its
+    entries by name and by membership, never by place."""
+    check(appended_bench)
+
+
+def test_no_file_of_the_benchmarks_tests_indexes_the_real_lists_by_position():
+    """``[-1]``, ``[-N:]`` or a number on ``workloads``, ``configs``,
+    ``per_layer`` or ``end_to_end`` of a loaded BENCHMARK.json is the habit
+    that kept three accepted PRs from appending (PR 58, 59, 61)."""
+    habit = re.compile(r"""\[["'](workloads|configs|per_layer|end_to_end)["']\]\s*\[\s*[-\d:]""")
+    for path in sorted(glob.glob(os.path.join(HERE, "*.py"))):
+        with open(path) as f:
+            for n, line in enumerate(f, 1):
+                assert not habit.search(line), f"{os.path.basename(path)}:{n}: {line.strip()}"

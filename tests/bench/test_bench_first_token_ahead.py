@@ -2,11 +2,9 @@
 reached their decode call from the device, before the host had fetched them.
 The metric's file through its reader on counters made by hand, nothing (not
 0) from a program or a decode module that never moves the counter,
-``BENCHMARK.json`` with the entry of ``data/first_token_ahead_entry.json``
-appended behind PR 59's sixteen held to the contract and resolved by
-``run.py --bench-file``, and the tiny rehearsal's lines. The entry is written
-and not listed, like the sixteen: the next ``benchmark`` PR appends it as it
-stands (PERF.md section 7)."""
+``BENCHMARK.json``, which lists the entry since PR 62 (until then it stood
+in ``data/first_token_ahead_entry.json``, written and not listed), held to
+the contract and resolved by ``run.py``, and the tiny rehearsal's lines."""
 
 import json
 import os
@@ -14,7 +12,6 @@ import os
 import pytest
 import test_bench_contract as contract
 import test_bench_engine_metrics as engine_metrics
-import test_bench_round_phases as round_phases
 
 from benchmark import harness, run
 
@@ -25,27 +22,14 @@ ROWS = ("mimo-reason-decode", "kanana-agent-sessions", "trinity-mixed-lengths",
 ONE_ROW = ("xl-batch-decode", "xl-chat-sessions")  # GPT-2's
 
 
-def entry():
-    with open(os.path.join(HERE, "data", "first_token_ahead_entry.json")) as f:
-        (e,) = json.load(f)
+def entry(b=None):
+    """The entry as ``BENCHMARK.json`` lists it: once, by its name."""
+    (e,) = [m for m in (b or engine_metrics.bench())["per_layer"] if m["name"] == NAME]
     return e
 
 
-def merged():
-    b = round_phases.merged()
-    b["per_layer"] = b["per_layer"] + [entry()]
-    return b
-
-
-@pytest.fixture(scope="module")
-def merged_file(tmp_path_factory):
-    path = tmp_path_factory.mktemp("bench") / "BENCHMARK.json"
-    path.write_text(json.dumps(merged(), indent=1))
-    return str(path)
-
-
 def through_its_reader(obs):
-    b = merged()
+    b = engine_metrics.bench()
     spec = harness.load_json(harness.find(b, "metrics", NAME))
     value = harness.module(b, "readers", spec["reader"]).read(
         obs, spec.get("args", {}), engine_metrics.TPU)
@@ -76,7 +60,7 @@ def test_the_file_reads_the_share_of_first_tokens_that_went_ahead():
     b = engine_metrics.bench()
     assert sorted(e["workloads"]) == sorted(ROWS)
     assert set(engine_metrics.reporting(b, "serve_tok_s")) - set(ROWS) == {"xl-batch-decode"}
-    assert NAME not in {m["name"] for m in b["per_layer"]}, "listed now: take the data file out"
+    assert not os.path.exists(os.path.join(HERE, "data", "first_token_ahead_entry.json"))
 
 
 @pytest.mark.parametrize("obs", [counters(None, 200), counters(0, 200), counters(5, 0), {}],
@@ -92,22 +76,23 @@ def test_a_program_that_sends_no_token_ahead_reads_nothing_not_zero(obs):
     "test_cells_configs_and_moves_hang_together",
     "test_every_metric_traffic_and_generator_has_its_file",
 ])
-def test_benchmark_json_with_the_entry_keeps_the_contract(rule, merged_file):
-    b = harness.load_json(merged_file)
-    assert os.path.getsize(merged_file) < 64 * 1024 and len(b["per_layer"]) <= 128
-    assert b["per_layer"][-1] == entry()  # appended, nothing before it moved
+def test_benchmark_json_with_the_entry_keeps_the_contract(rule):
+    path = os.path.join(harness.ROOT, "BENCHMARK.json")
+    b = harness.load_json(path)
+    assert os.path.getsize(path) < 64 * 1024 and len(b["per_layer"]) <= 128
+    assert entry(b)["name"] == NAME  # listed once, wherever in the list
     getattr(contract, rule)(b)
 
 
 @pytest.mark.parametrize("cell", ROWS + ONE_ROW)
-def test_run_resolves_it_in_the_cells_of_rows_and_in_no_other(cell, merged_file, capsys):
-    assert run.main(["--bench-file", merged_file, "--workload", cell, "--trace", "1", "--dry"]) == 0
+def test_run_resolves_it_in_the_cells_of_rows_and_in_no_other(cell, capsys):
+    assert run.main(["--workload", cell, "--trace", "1", "--dry"]) == 0
     plan = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert (NAME in plan["metrics"]) == (cell in ROWS)
     if cell in ROWS:
         assert plan["metrics"][NAME] == "benchmark.readers.counter_ratio_moved"
-    # the file the driver reads knows nothing of it
-    assert run.main(["--workload", cell, "--trace", "1", "--dry"]) == 0
+    # an untraced run resolves no per-layer metric
+    assert run.main(["--workload", cell, "--trace", "0", "--dry"]) == 0
     assert NAME not in json.loads(capsys.readouterr().out.strip().splitlines()[-1])["metrics"]
 
 
@@ -120,8 +105,9 @@ def rehearsed(tmp_path_factory):
     import test_bench_rehearsal as rehearsal
 
     b, to = bench_rehearsal_file.build(), bench_rehearsal_file.names()["workloads"]
-    b["per_layer"].append({**entry(), "workloads": [to["mimo-reason-decode"],
-                                                    to["xl-batch-decode"]]})
+    listed = entry(b)  # the rehearsal's file is the real one renamed: the cells of rows
+    assert to["mimo-reason-decode"] in listed["workloads"]
+    listed["workloads"] = listed["workloads"] + [to["xl-batch-decode"]]
     path = tmp_path_factory.mktemp("rehearsal-ahead") / "BENCHMARK.json"
     path.write_text(json.dumps(b, indent=1))
     return {cell: rehearsal.rehearse(str(path), to[cell], 1)[0]

@@ -9,7 +9,7 @@ from types import SimpleNamespace
 import bench_rehearsal_file
 import pytest
 from test_bench_engine_metrics import (
-    ENGINE_SERIES, on_every_list_the_other_serving_cells_share, reporting, snap,
+    ENGINE_SERIES, listed_once, on_every_list_the_other_serving_cells_share, reporting, snap,
     through_its_reader,
 )
 from test_bench_rehearsal import rehearse, run
@@ -21,7 +21,9 @@ from benchmark.readers import mla_roofline, moe_roofline
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(HERE))
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
-NEW = ["mla_roofline", "mla_context_mean", "kv_latent_token_bytes",
+# what PR 48 brought; the attention's roofline under the name of the kernel
+# that replaced its loops in PR 56 (``mla_roofline`` went out in PR 62)
+NEW = ["mla_paged_roofline", "mla_context_mean", "kv_latent_token_bytes",
        "prefix_token_share.decode", "prefill_ms.decode"]
 CELL = "kanana-agent-sessions"
 
@@ -167,32 +169,21 @@ def test_the_new_metrics_read_the_engines_series(name, want):
     assert CELL in entry["workloads"] and set(entry["workloads"]) <= set(reporting(bench, "serve_tok_s"))
 
 
-@pytest.mark.parametrize("name", NEW)
-def test_a_program_without_the_series_reads_nothing(name):
-    """The parent's observations: counters that lack the latent series, no
-    trace directory, no prefill in the trace. Nothing, and no exception."""
-    bare = {"counters": {"before": snap({"rt_serve_decode_steps_total": 1.0}),
-                         "after": snap({"rt_serve_decode_steps_total": 9.0}),
-                         "samples": [snap({"rt_serve_kv_pages_total": 97.0})]},
-            "trace_counters": {"before": {}, "after": {}, "seconds": 4.0},
-            "model": {"n_embd": 1600}, "trace_dir": None, "trace": None,
-            "device": {"kind": "TPU v5 lite"}}
-    for obs in (bare, {}, {"counters": None}):
-        _, got = through_its_reader(name, obs)
-        assert got is None
-
-
-def a_trace():
-    """Two decode programs and a prefill, each with a loop whose carry
-    opens with a running maximum a head; an expert layer's loop and the
-    K-step loop beside them."""
-    attend = "while (s32[],f32[128,32],..) 1in"
+def a_trace(kernel=True):
+    """Two decode programs and a prefill as the chip's trace names them
+    since PR 56 (ledger, PR 61, ``breakdown``): the latent attention's
+    kernel a layer in the decode programs, prefill's loops over rows, an
+    expert layer's kernel and the K-step loop beside them; ``kernel=False``
+    is the tree before PR 56, the loops whose carry opens with a running
+    maximum a head in the kernel's place."""
+    attend = ("paged_latent_attention bf16[128,32,640] 6in" if kernel
+              else "while (s32[],f32[128,32],..) 1in")
     ops = [[attend, 1_000, 300_000],                                    # inside decode 1
-           ["fusion bf16[512,64,640] 2in", 2_000, 100_000],             # its body: not twice
-           ["while (s32[],f32[128,2048],..) 1in", 400_000, 100_000],    # an expert layer's loop
+           ["fusion bf16[512,64,640] 2in", 302_000, 90_000],            # beside it: not its time
+           ["grouped_matmul bf16[768,768] 7in", 400_000, 100_000],      # an expert layer's kernel
            [attend, 600_000, 200_000],                                  # inside decode 1
            ["while (s32[],f32[32,512],..) 1in", 2_100_000, 900_000],    # prefill's attention
-           [attend, 2_200_000, 50_000],                                 # inside the prefill
+           [attend, 3_050_000, 50_000],                                 # inside the prefill
            ["while (s32[],s32[128],..) 1in", 3_950_000, 900_000],       # the K-step loop
            [attend, 4_000_000, 500_000]]                                # inside decode 2
     modules = [["jit_decode_paged_and_sample", 0, 1_000_000],
@@ -202,8 +193,15 @@ def a_trace():
         {"name": "XLA Ops", "events": ops}, {"name": "XLA Modules", "events": modules}]}]}
 
 
-def test_mla_roofline_counts_the_attention_loops_inside_decode_programs_only(monkeypatch, cfg):
-    spec = load("benchmark/metrics/mla_roofline.json")
+def test_mla_paged_roofline_counts_the_kernels_calls_inside_decode_programs_only(monkeypatch, cfg):
+    """(``test_mla_roofline_counts_the_attention_loops_...`` until PR 62:
+    the living file, and the kernel's name in the trace.)"""
+    spec = load("benchmark/metrics/mla_paged_roofline.json")
+    assert spec["reader"] == "mla_roofline" and spec["args"]["ops"] == "^paged_latent_attention"
+    # mla_roofline.json itself stays, listed by nothing, while a test outside the
+    # benchmark's directories opens it (tests/test_paged_attention_forms.py: the old
+    # pattern matches no loop of the compiled programs); its entry is out
+    assert "mla_roofline" not in {m["name"] for m in load("BENCHMARK.json")["per_layer"]}
     busy, window = moe_roofline.seconds_inside(a_trace(), spec["args"]["match"], spec["args"]["ops"])
     assert busy == pytest.approx(1_000_000e-9)
     assert window == pytest.approx(4_850_000e-9 - 1_000e-9)
@@ -221,6 +219,10 @@ def test_mla_roofline_counts_the_attention_loops_inside_decode_programs_only(mon
     # 3.83 GB a step x 20 steps a second at 819 GB/s, over the loops' share of the window
     assert got == pytest.approx(100 * (128 * 5200 * 5 * 1152 * 20 / 819e9) / (busy / window), rel=1e-3)
     assert 0 < got < 100
+    # a tree whose decode programs hold the loops and no kernel (before PR 56)
+    monkeypatch.setattr(trace_mod, "load_xplane", lambda p: a_trace(kernel=False))
+    assert mla_roofline.read(obs, spec["args"], ctx) is None
+    monkeypatch.setattr(trace_mod, "load_xplane", lambda p: a_trace())
     # a family that counts no such cost, and a program without the counter
     assert mla_roofline.read(obs, spec["args"], SimpleNamespace(platform="tpu")) is None
     still = dict(obs, trace_counters=dict(obs["trace_counters"],
@@ -228,20 +230,29 @@ def test_mla_roofline_counts_the_attention_loops_inside_decode_programs_only(mon
     assert mla_roofline.read(still, spec["args"], ctx) is None
 
 
-def test_the_cell_stands_on_mimos_lists_but_the_window_share(cfg):
-    bench = load("BENCHMARK.json")
-    cell = next(w for w in bench["workloads"] if w["name"] == CELL)
+def the_cell_stands_on_its_lists(bench):
+    """Of any ``bench``: the real file, and the copy with a cell appended
+    that ``test_bench_contract.py`` makes. By name and by membership."""
+    cell, entry = listed_once(bench, CELL)
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         "kanana-2-30b-a3b-serve", "agent-sessions", 1)
     on = {m["name"] for m in bench["per_layer"] if CELL in m.get("workloads", [])}
     # what PR 48 brought, the expert layer's lists and the page loops' that
     # MiMo's cell opened, and no window's share: this family keeps no ring
     assert set(NEW) <= on and "kv_window_share" not in on
-    assert {"moe_tokens_per_expert", "moe_experts_hit", "moe_load_skew", "moe_roofline",
+    assert {"moe_tokens_per_expert", "moe_experts_hit", "moe_load_skew", "moe_gmm_roofline",
             "attn_loop_useful_share", "prefill_rows_mean"} <= on
+    # the two names that read nothing since PR 55 and PR 56 are on no list
+    assert not {"moe_roofline", "mla_roofline"} & {m["name"] for m in bench["per_layer"]}
     on_every_list_the_other_serving_cells_share(bench, CELL)
-    entry = next(c for c in bench["configs"] if c["name"] == cell["config"])
-    assert entry["reduced"] == ["num_hidden_layers"] and entry["source"] == cfg["source"]
+    assert entry["reduced"] == ["num_hidden_layers"]
+    assert entry["source"] == load(entry["file"])["source"]
+
+
+def test_the_cell_stands_on_mimos_lists_but_the_window_share(cfg):
+    bench = load("BENCHMARK.json")
+    the_cell_stands_on_its_lists(bench)
+    assert listed_once(bench, CELL)[1]["source"] == cfg["source"]
 
 
 @pytest.fixture(scope="module")
@@ -260,7 +271,7 @@ def test_the_cell_rehearses_to_a_correct_line_with_its_counters_read(agent):
             "engine_load_s", "deploy_ready_s"} <= set(got)
     assert ENGINE_SERIES <= set(got)
     # no device metric from a CPU run
-    assert not {"mla_roofline", "prefill_ms.decode", "moe_roofline", "decode_step_mfu",
+    assert not {"mla_paged_roofline", "prefill_ms.decode", "moe_gmm_roofline", "decode_step_mfu",
                 "decode_step_ms.decode", "decode_step_counted_ms.decode", "hbm_used.decode",
                 "device_idle.decode"} & set(got)
     # three layers of 40 numbers, stored 128 wide, in bfloat16

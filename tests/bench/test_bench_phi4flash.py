@@ -11,7 +11,8 @@ from types import SimpleNamespace
 import bench_rehearsal_file
 import pytest
 from test_bench_engine_metrics import (
-    ENGINE_SERIES, on_every_list_the_other_serving_cells_share, snap, through_its_reader,
+    ENGINE_SERIES, listed_once, on_every_list_the_other_serving_cells_share, snap,
+    through_its_reader,
 )
 from test_bench_rehearsal import rehearse, run
 
@@ -32,9 +33,9 @@ JOINED = {"deploy_ready_s", "engine_load_s", "batch_fill.decode", "kv_pages_used
           "page_wait_ms.decode", "engine_host_ms.decode", "engine_blocked_ms.decode",
           "prefill_ms.decode", "prefill_rows_mean", "kv_window_share", "window_context_share",
           "attn_loop_useful_share"}
-EXCLUDED = {"moe_tokens_per_expert", "moe_experts_hit", "moe_load_skew", "moe_roofline",
-            "moe_gmm_roofline", "mla_roofline", "mla_context_mean", "mla_paged_roofline",
-            "kv_latent_token_bytes", "prefix_token_share.decode"}
+EXCLUDED = {"moe_tokens_per_expert", "moe_experts_hit", "moe_load_skew", "moe_gmm_roofline",
+            "mla_context_mean", "mla_paged_roofline", "kv_latent_token_bytes",
+            "prefix_token_share.decode"}
 
 
 def load(path):
@@ -190,37 +191,24 @@ def test_the_data_only_metrics_read_the_engines_series():
         assert spec["unit"] == "%" and len(spec["reads"]) > 200
 
 
-@pytest.mark.parametrize("name", NEW)
-def test_a_program_without_the_series_reads_nothing(name):
-    """The parent's observations: counters that lack this PR's series, no
-    trace directory. Nothing, and no exception."""
-    bare = {"counters": {"before": snap({"rt_serve_decode_steps_total": 1.0}),
-                         "after": snap({"rt_serve_decode_steps_total": 9.0}),
-                         "samples": [snap({"rt_serve_kv_pages_total": 97.0})]},
-            "trace_counters": {"before": {}, "after": {}, "seconds": 4.0},
-            "model": {"n_embd": 1600}, "trace_dir": None, "trace": None,
-            "device": {"kind": "TPU v5 lite"}}
-    for obs in (bare, {}, {"counters": None}):
-        _, got = through_its_reader(name, obs)
-        assert got is None
-
-
 def a_trace(pages=True):
-    """Two decode programs and a prefill; in each decode program loops over
-    pages (the carry opens with the running maximum) beside a window
-    layer's loop over its ring's blocks and the K-step loop."""
-    page = "while (s32[],f32[32,40,1],..) 1in"
-    ring = "while (s32[],f32[32,40,1,128],..) 1in"
+    """Two decode programs and a prefill as the chip's trace names them
+    since PR 58 (ledger, PR 61, ``breakdown``): in each decode program the
+    kernel's calls over layer 17's pages, one a reading layer, beside a
+    window layer's call of the same kernel under the ring's name and the
+    K-step loop; ``pages=False`` is the tree before PR 58, the loops over
+    pages (the carry opens with the running maximum) in the kernel's place."""
+    page = ("paged_kv_attention f32[128,40,128] 9in" if pages
+            else "while (s32[],f32[32,40,1],..) 1in")
+    ring = "ring_kv_attention f32[128,40,128] 9in"
     ops = [[page, 1_000, 300_000],                                      # inside decode 1
-           ["fusion bf16[32,512,1280] 2in", 2_000, 100_000],            # its body: not twice
-           [ring, 310_000, 80_000],                                     # a window layer's loop
+           ["fusion bf16[32,512,1280] 2in", 302_000, 6_000],            # beside it: not its time
+           [ring, 310_000, 80_000],                                     # a window layer's call
            [page, 600_000, 200_000],                                    # inside decode 1
            ["while (s32[],f32[2,40,512],..) 1in", 2_100_000, 900_000],  # prefill's attention
            ["while (s32[],f32[2,40,1],..) 1in", 3_050_000, 50_000],     # prefill's cross-decoder
            ["while (s32[],s32[128],..) 1in", 3_950_000, 900_000],       # the K-step loop
            [page, 4_000_000, 500_000]]                                  # inside decode 2
-    if not pages:
-        ops = [op for op in ops if op[0] != page]
     modules = [["jit_decode_paged_and_sample", 0, 1_000_000],
                ["jit_prefill_paged", 2_000_000, 1_500_000],
                ["jit_decode_multi_paged", 3_900_000, 1_000_000]]
@@ -228,11 +216,17 @@ def a_trace(pages=True):
         {"name": "XLA Ops", "events": ops}, {"name": "XLA Modules", "events": modules}]}]}
 
 
-def test_shared_kv_roofline_counts_the_page_loops_inside_decode_programs_only(monkeypatch, cfg):
+def test_shared_kv_roofline_counts_the_page_kernels_calls_inside_decode_programs_only(
+        monkeypatch, cfg):
+    """(``..._counts_the_page_loops_...`` until PR 62 pointed the file's
+    ``ops`` at the kernel that replaced the loops in PR 58.)"""
     spec = load("benchmark/metrics/shared_kv_roofline.json")
+    assert (spec["reader"], spec["args"]["ops"]) == ("shared_kv_roofline", "^paged_kv_attention")
+    assert spec["args"]["context"] == "rt_serve_attn_context_tokens_total"
+    assert "PR 62" in spec["reads"] and "mimo_v2, afmoe" in spec["reads"]
     busy, window = moe_roofline.seconds_inside(a_trace(), spec["args"]["match"],
                                                spec["args"]["ops"])
-    assert busy == pytest.approx(1_000_000e-9)  # not the prefill's loop of the same carry
+    assert busy == pytest.approx(1_000_000e-9)  # not the ring's calls, not the prefill's loops
     assert window == pytest.approx(4_850_000e-9 - 1_000e-9)
     # through the reader: 128 rows x 1,250 positions a step, 40 steps a traced second
     monkeypatch.setattr(trace_mod, "find_xplane", lambda d: "a.xplane.pb")
@@ -249,7 +243,7 @@ def test_shared_kv_roofline_counts_the_page_loops_inside_decode_programs_only(mo
     assert got == pytest.approx(
         100 * (128 * 1250 * 8 * 5120 * 40 / 819e9) / (busy / window), rel=1e-3)
     assert 0 < got
-    # a trace without such loops (a kernel in the loop's place)
+    # a trace without the kernel (the loops of a tree before PR 58)
     monkeypatch.setattr(trace_mod, "load_xplane", lambda p: a_trace(pages=False))
     assert shared_kv_roofline.read(obs, spec["args"], ctx) is None
     monkeypatch.setattr(trace_mod, "load_xplane", lambda p: a_trace())
@@ -263,30 +257,42 @@ def test_shared_kv_roofline_counts_the_page_loops_inside_decode_programs_only(mo
     assert shared_kv_roofline.read(still, spec["args"], ctx) is None
 
 
-def test_the_pattern_is_the_page_loops_name_and_no_other_loops():
-    """The name the chip's trace gives the loops over layer 17's pages is
-    what ``trace.short_op_name`` makes of their HLO line: the carry opens
-    with the running maximum (``ops/cached_attention.paged_attend``)."""
+def test_the_pattern_is_the_page_kernels_name_and_no_other_operations():
+    """(``test_the_pattern_is_the_page_loops_name_and_no_other_loops`` until
+    PR 62.) The name the chip's trace gives the kernel's calls over layer
+    17's pages is what ``trace.short_op_name`` makes of their HLO line, a
+    custom call under the kernel's own name
+    (``ops/cached_attention.paged_attend``'s one-query form); the window
+    layers' call of the same kernel under the ring's name, the loops of the
+    tree before and prefill's loops are not matched."""
     rx = re.compile(load("benchmark/metrics/shared_kv_roofline.json")["args"]["ops"])
-    line = ("%while.31 = (s32[]{:T(128)}, f32[32,40,1]{1,0,2:T(8,128)S(1)}, "
-            "f32[32,40,1]{1,0,2:T(8,128)S(1)}, f32[32,40,1,128]{3,1,0,2:T(8,128)S(1)}, "
-            "s32[]{:T(128)}, /*index=5*/s32[32,256]{1,0:T(8,128)S(1)}, "
-            "bf16[8193,64,1280]{2,1,0:T(8,128)(2,1)}) while(%tuple.702), "
-            "condition=%wide.region_24.35, body=%wide.region_21.34.sunk")
+    line = ("%paged_kv_attention.9 = f32[128,40,128]{2,1,0:T(8,128)} custom-call("
+            "s32[32768]{0:T(1024)} %bitcast.21, s32[128]{0:T(128)} %clamp.3, "
+            "s32[129]{0:T(256)} %concatenate.5, s32[4096]{0:T(1024)} %fusion.91, "
+            "bf16[128,40,64]{2,1,0:T(8,128)(2,1)} %fusion.402, "
+            "bf16[64,1280]{1,0:T(8,128)(2,1)} %fusion.17, f32[40,1280]{1,0:T(8,128)} %fusion.18, "
+            "bf16[8193,64,1280]{2,1,0:T(8,128)(2,1)} %get-tuple-element.61, "
+            "bf16[8193,64,1280]{2,1,0:T(8,128)(2,1)} %get-tuple-element.62), "
+            "custom_call_target=\"tpu_custom_call\", operand_layout_constraints={}")
+    assert trace_mod.short_op_name(line) == "paged_kv_attention f32[128,40,128] 9in"
     assert rx.search(trace_mod.short_op_name(line))
-    for other in ("while (s32[],f32[32,40,1,128],..) 1in", "while (s32[],s32[128],..) 1in",
-                  "while (s32[],f32[2,40,512],..) 1in", "while (s32[],f32[2,16,5120],..) 1in",
-                  "fusion f32[32,40,1] 3in"):
+    for other in ("ring_kv_attention f32[128,40,128] 9in", "while (s32[],f32[32,40,1],..) 1in",
+                  "while (s32[],f32[32,40,1,128],..) 1in", "while (s32[],s32[128],..) 1in",
+                  "while (s32[],f32[2,40,512],..) 1in", "paged_latent_attention bf16[128,32,640] 6in",
+                  "fusion f32[128,40,128] 3in"):
         assert not rx.search(other), other
 
 
-def test_the_cell_stands_on_every_list_it_reports_and_on_none_it_is_kept_off(cfg):
-    bench = load("BENCHMARK.json")
-    cell = next(w for w in bench["workloads"] if w["name"] == CELL)
+def the_cell_stands_on_its_lists(bench):
+    """Of any ``bench``: the real file, and the copy with a cell appended
+    that ``test_bench_contract.py`` makes. The cell is in ``workloads``
+    once and its configuration in ``configs`` once, wherever: by name and
+    by membership, never by place (PR 58 and PR 59 could append nothing to
+    ``per_layer`` while this test pinned the lists' ends; PR 62)."""
+    cell, entry = listed_once(bench, CELL)
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         "phi-4-mini-flash-serve", "long-reasoning", 1)
     assert len(cell["why"]) <= 200
-    assert bench["workloads"][-1] == cell and bench["configs"][-1]["name"] == cell["config"]
     on = {m["name"] for m in bench["per_layer"] if CELL in m.get("workloads", [])}
     assert set(NEW) <= on and JOINED <= on
     assert not EXCLUDED & on
@@ -295,10 +301,16 @@ def test_the_cell_stands_on_every_list_it_reports_and_on_none_it_is_kept_off(cfg
     assert "window_attn_roofline" in on
     assert CELL in next(m for m in bench["end_to_end"] if m["name"] == "serve_tok_s")["workloads"]
     on_every_list_the_other_serving_cells_share(bench, CELL)  # the engine's series among them
-    entry = next(c for c in bench["configs"] if c["name"] == cell["config"])
-    assert entry["reduced"] == CUT and entry["source"] == cfg["source"]
-    # the new entries stand at the end of the list, in the order they came
-    assert [m["name"] for m in bench["per_layer"][-3:]] == NEW
+    assert entry["reduced"] == CUT and entry["source"] == load(entry["file"])["source"]
+    # each of the entries PR 57 brought is listed once
+    names = [m["name"] for m in bench["per_layer"]]
+    assert all(names.count(name) == 1 for name in NEW)
+
+
+def test_the_cell_stands_on_every_list_it_reports_and_on_none_it_is_kept_off(cfg):
+    bench = load("BENCHMARK.json")
+    the_cell_stands_on_its_lists(bench)
+    assert listed_once(bench, CELL)[1]["source"] == cfg["source"]
 
 
 @pytest.fixture(scope="module")

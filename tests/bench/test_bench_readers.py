@@ -1,7 +1,9 @@
 """Each reader on observations made by hand: what it reads, and that it
 returns nothing where there is nothing to read."""
 
+import json
 import os
+import re
 from types import SimpleNamespace
 
 import pytest
@@ -13,6 +15,7 @@ from benchmark.readers import (
     tpot, trace_time, train_mfu, ttft,
 )
 
+BENCH = harness.load_json(os.path.join(harness.ROOT, "BENCHMARK.json"))
 GPT2 = os.path.join(os.path.dirname(peaks.__file__), "families", "gpt2.py")
 TPU = SimpleNamespace(platform="tpu", family=GPT2)
 CPU = SimpleNamespace(platform="cpu", family=GPT2)
@@ -183,6 +186,58 @@ def test_decode_step_mfu_is_left_out_of_a_line_off_the_chip():
         assert got == {"value": pytest.approx(11.78, abs=0.01), "unit": "%"}
 
 
+MOE = [("trinity-mini-serve", "afmoe", 128, 127.97), ("kanana-2-30b-a3b-serve", "deepseek_v3", 128, 127.7),
+       ("mimo-v2.5-serve", "mimo_v2", 16, 15.7)]
+
+
+def moe_cell(config, family):
+    """(model, family module, context) of a configuration of BENCHMARK.json."""
+    entry = next(c for c in BENCH["configs"] if c["name"] == config)
+    model = harness.load_json(os.path.join(harness.ROOT, entry["file"]))["model"]
+    path = harness.find(BENCH, "families", family, ".py")
+    return model, harness.family(path), SimpleNamespace(platform="tpu", family=path)
+
+
+@pytest.mark.parametrize("config,family,held,expected", MOE)
+def test_decode_step_mfu_counts_the_experts_the_rows_reached(config, family, held, expected):
+    """The step's bytes take the experts HIT, as the decode programs
+    counted them over the traced seconds (69% of those held: 960 layer-steps
+    of ``held`` experts, 0.69 of them reached), and the experts EXPECTED
+    under even routing only where the program has no such series."""
+    model, fam, ctx = moe_cell(config, family)
+    obs, args = decode_obs()
+    obs["model"] = model
+    even = decode_step_mfu.read(obs, args, ctx)
+    assert fam.expected_experts_hit(model, 24.0) == pytest.approx(
+        held * (1 - (1 - fam.expected_experts_hit(model, 1.0) / held) ** 24))
+    assert even == pytest.approx(peaks.decode_step_mfu(
+        0.04375, fam.decode_step_bytes(model, 24.0, 150.0), "TPU v5 lite"))
+    for side, hit in (("before", 100.0), ("after", 100.0 + 0.69 * 960 * held)):
+        obs["trace_counters"][side].update(
+            {"rt_serve_moe_experts_hit_total": {"value": hit},
+             "rt_serve_moe_expert_steps_total": {"value": 100.0 + (side == "after") * 960 * held}})
+    assert decode_step_mfu.experts_hit(obs["trace_counters"], model, ctx) == pytest.approx(0.69 * held)
+    counted = decode_step_mfu.read(obs, args, ctx)
+    assert counted == pytest.approx(peaks.decode_step_mfu(
+        0.04375, fam.decode_step_bytes(model, 24.0, 150.0, experts_hit=0.69 * held), "TPU v5 lite"))
+    one_expert = 2.0 * 3 * model["hidden_size"] * model["moe_intermediate_size"]
+    layers = {"afmoe": 4, "deepseek_v3": 4, "mimo_v2": 6}[family]
+    gone = fam.expected_experts_hit(model, 24.0) - 0.69 * held
+    assert (fam.decode_step_bytes(model, 24.0, 150.0)
+            - fam.decode_step_bytes(model, 24.0, 150.0, experts_hit=0.69 * held)) == pytest.approx(
+                layers * gone * one_expert)
+    assert (counted < even) == (gone > 0)
+    # at the cell's 127 rows even routing expects all but a sliver of the held experts
+    assert fam.expected_experts_hit(model, 127.0) == pytest.approx(expected, rel=2e-3)
+    # a series that stood, and a family without experts beside moving series: as before
+    obs["trace_counters"]["after"]["rt_serve_moe_experts_hit_total"] = {"value": 100.0}
+    assert decode_step_mfu.read(obs, args, ctx) == pytest.approx(even)
+    obs["trace_counters"]["after"]["rt_serve_moe_experts_hit_total"] = {"value": 100.0 + 960 * held}
+    obs["model"] = XL
+    assert decode_step_mfu.experts_hit(obs["trace_counters"], XL, TPU) is None
+    assert decode_step_mfu.read(obs, args, TPU) == pytest.approx(11.78, abs=0.01)
+
+
 SKEW = {"fullest": "rt_serve_moe_max_load_total", "pairs": "rt_serve_moe_assignments_total"}
 
 
@@ -192,10 +247,7 @@ SKEW = {"fullest": "rt_serve_moe_max_load_total", "pairs": "rt_serve_moe_assignm
     ("mimo-v2.5-serve", "mimo_v2", 16),              # the 16 held of 256 routed over
 ])
 def test_moe_load_skew_asks_the_family_for_the_experts_held(config, family, held):
-    bench = harness.load_json(os.path.join(harness.ROOT, "BENCHMARK.json"))
-    entry = next(c for c in bench["configs"] if c["name"] == config)
-    model = harness.load_json(os.path.join(harness.ROOT, entry["file"]))["model"]
-    ctx = SimpleNamespace(platform="tpu", family=harness.find(bench, "families", family, ".py"))
+    model, _, ctx = moe_cell(config, family)
     # 600 layer-steps: the fullest expert took 4,800 pairs of 38,400
     counted = counters({"rt_serve_moe_max_load_total": 500.0, "rt_serve_moe_assignments_total": 1000.0},
                        {"rt_serve_moe_max_load_total": 5300.0, "rt_serve_moe_assignments_total": 39400.0})
@@ -237,3 +289,122 @@ def test_train_readers():
     assert observed.read(obs, {"key": "setup_s"}, TPU) == 25.5
     assert observed.read(obs, {"key": "missing"}, TPU) is None
     assert sync_rate.read({"syncs": [1.0]}, {}, TPU) is None
+
+
+# -- a program without the series reads nothing ---------------------------
+#
+# One case a metric of BENCHMARK.json, so a metric a later PR appends is a
+# case from the day it is listed. Three refusals (PR 28, 41 and 43) came
+# from a reader that raised on a tree without its span or counter: the
+# driver measures the parent with the change's benchmark files, and the
+# parent is such a tree.
+
+# readers of what the generator itself observed (the clients' records, the
+# trainer's syncs, the compile cache, the allocator): no series of the
+# program's is theirs to miss, so they are held to raising nothing
+GENERATORS_OWN = {"observed", "compiles", "hbm_used", "ttft", "itl", "gen_lag", "tpot",
+                  "token_rate", "step_ms", "sync_rate", "train_mfu"}
+# series a reader reads by a name of its own and not by its file's arguments
+READ_BY_NAME = {
+    "decode_step": {"rt_serve_tokens_generated_total", "rt_serve_ttft_s", "rt_serve_batch_fill"},
+    "decode_step_mfu": {"rt_serve_tokens_generated_total", "rt_serve_ttft_s",
+                        "rt_serve_batch_fill"},
+}
+# the programs the benchmark has measured as parents, by the series they had
+OLDER_PROGRAMS = {
+    "before_pr24": ({"rt_serve_batch_fill": (900.0, 100), "rt_serve_tokens_generated_total": 5.0},
+                    {"rt_serve_batch_fill": (3600.0, 400),
+                     "rt_serve_tokens_generated_total": 900.0}, {}),
+    "before_pr46": ({"rt_serve_decode_steps_total": 1.0}, {"rt_serve_decode_steps_total": 9.0},
+                    {"rt_serve_kv_pages_total": 97.0}),
+    "before_pr59": ({"rt_serve_engine_round_host_s": (0.5, 100),
+                     "rt_serve_engine_round_blocked_s": (20.0, 100)},
+                    {"rt_serve_engine_round_host_s": (2.5, 500),
+                     "rt_serve_engine_round_blocked_s": (100.0, 500)}, {}),
+}
+
+
+def generators_side(model):
+    """What a generator observes itself whatever the program counts: no
+    request finished, no sync stamped, nothing compiled, no trace."""
+    return {"records": [], "t0": 10.0, "t1": 20.0, "traffic": {}, "model": model,
+            "cache_entries": {"start": 5, "t0": 5, "t1": 5}, "syncs": [], "steps_per_sync": 8,
+            "tokens_per_sync": 8 * 32 * 1024, "traced_steps": 0, "trace": None, "trace_dir": None,
+            "attention_shape": {"bh": 384, "t": 1024, "d": 64},
+            "device": {"kind": "TPU v5 lite", "count": 1, "memory_peak_bytes": 0,
+                       "memory_limit_bytes": 0}}
+
+
+def series_read(spec):
+    return (set(re.findall(r"rt_\w+", json.dumps(spec.get("args", {}))))
+            | READ_BY_NAME.get(spec["reader"], set()))
+
+
+def observations_without(spec, model):
+    """(label, observations) whose program lacks every series ``spec``
+    reads: no counters at all, counters that are None, and each older
+    program that had none of them."""
+    side = generators_side(model)
+    yield "no_counters", side
+    yield "counters_none", {**side, "counters": None, "trace_counters": None}
+    for label, (before, after, sample) in OLDER_PROGRAMS.items():
+        if series_read(spec) & (set(before) | set(sample)):
+            continue
+        moved = {**counters(before, after), "samples": [counters(sample, {})["before"]]}
+        yield label, {**side, "counters": moved, "trace_counters": {**moved, "seconds": 4.5}}
+        yield label + "_untraced", {**side, "counters": moved,
+                                    "trace_counters": {"before": {}, "after": {}, "seconds": 4.0}}
+
+
+def contexts(entry):
+    """(family path, model) of every configuration a cell of the metric's
+    list runs, and no family at all."""
+    cells = [w for w in BENCH["workloads"]
+             if w["name"] in entry.get("workloads", [w["name"]])]
+    seen = {(None, None): (SimpleNamespace(platform="tpu"), XL)}
+    for name in sorted({w["config"] for w in cells}):
+        cfg = harness.load_json(os.path.join(
+            harness.ROOT, next(c["file"] for c in BENCH["configs"] if c["name"] == name)))
+        path = harness.find(BENCH, "families", cfg["family"], ".py")
+        seen[path, name] = (SimpleNamespace(platform="tpu", family=path), cfg["model"])
+    return list(seen.values())
+
+
+@pytest.mark.parametrize("name", [m["name"] for m in BENCH["end_to_end"] + BENCH["per_layer"]])
+def test_a_program_without_the_series_reads_nothing(name):
+    """Every listed metric through its reader, in every family its cells
+    run, on a trace and counters that lack what it reads: nothing (never
+    0) and no exception."""
+    entry = next(m for m in BENCH["end_to_end"] + BENCH["per_layer"] if m["name"] == name)
+    spec = harness.load_json(harness.find(BENCH, "metrics", name))
+    reader = harness.module(BENCH, "readers", spec["reader"])
+    tried = 0
+    for ctx, model in contexts(entry):
+        for label, obs in observations_without(spec, model):
+            got = reader.read(obs, spec.get("args", {}), ctx)
+            tried += 1
+            if spec["reader"] not in GENERATORS_OWN:
+                assert got is None, (label, getattr(ctx, "family", None), got)
+    assert tried >= 4
+
+
+def test_every_reader_file_is_some_listed_metrics():
+    """So the test above runs every reader under ``benchmark/readers/``."""
+    used = {harness.load_json(harness.find(BENCH, "metrics", m["name"]))["reader"]
+            for m in BENCH["end_to_end"] + BENCH["per_layer"]}
+    readers = {f[:-3] for f in os.listdir(os.path.join(harness.ROOT, "benchmark", "readers"))
+               if f.endswith(".py") and f != "__init__.py"}
+    assert readers == used
+
+
+def test_a_program_that_counts_steps_and_no_row_steps_reads_no_rows():
+    """``decode_rows_mean`` divides a series of PR 24 by one the engine
+    had before it: on a tree between the two the ratio of what moved is 0,
+    and a step of no rows does not exist, so the file takes the reader
+    that wants its numerator to have moved."""
+    spec = harness.load_json(harness.find(BENCH, "metrics", "decode_rows_mean"))
+    reader = harness.module(BENCH, "readers", spec["reader"])
+    before, after, _ = OLDER_PROGRAMS["before_pr46"]
+    assert reader.read({"counters": counters(before, after)}, spec["args"], TPU) is None
+    after = {**after, "rt_serve_decode_row_steps_total": 40.0}
+    assert reader.read({"counters": counters(before, after)}, spec["args"], TPU) == 5.0

@@ -152,6 +152,36 @@ def test_one_train_step_against_the_reference(tiny_step):
     assert got["loss_reference"] - got["loss_after_reference"] > 10 * TINY_TOLERANCE["loss_after"]
 
 
+def test_the_float8_control_and_a_state_left_unchanged_read_over_the_program(tiny_step):
+    """The readings a training configuration's ``reference_tolerance`` is set
+    between, by the tool that reads them on the chip
+    (``benchmark/tools/step_control.py``), at the tiny preset: the control
+    (the plain reference with its matrices rounded to float8_e4m3fn in the
+    program's place) reads its gradient several times farther from the
+    reference than the program does and fails the tolerance a size of this
+    kind is held to on the chip; a step that hands its state back unchanged
+    reports the right loss, a gradient that is all missing and the loss's
+    whole fall."""
+    import jax
+
+    from benchmark.reference import check
+    from benchmark.tools import step_control
+
+    opt = tiny_step["opt"]
+    program = step_control.gaps(compare(tiny_step, tiny_step["step"]))
+    control = step_control.gaps(compare(tiny_step, step_control.float8_step(MODEL, opt, 4)))
+    unchanged = compare(tiny_step, step_control.unchanged(jax.jit(tiny_step["step"])))
+    assert check.step_problems(unchanged, TINY_TOLERANCE)
+    unchanged = step_control.gaps(unchanged)
+    assert program["grad_rel_gap"] < 0.02 and control["grad_rel_gap"] > 3 * program["grad_rel_gap"]
+    assert control["grad_rel_gap"] > 0.04  # gpt2-small-train's limit is 0.06 for 0.0125 read
+    # (the loss after the step is not the control's to fail: at gpt2-small's size it
+    # read 5.0e-4 on one seed of three where the program reads up to 2.26e-3; PERF.md)
+    assert unchanged["grad_rel_gap"] == pytest.approx(1.0)
+    assert unchanged["loss_gap"] == pytest.approx(program["loss_gap"])
+    assert unchanged["loss_after_gap"] > 10 * TINY_TOLERANCE["loss_after"]
+
+
 @pytest.mark.parametrize("fault", ["mlp_gradient_dropped", "gradient_not_averaged",
                                    "update_not_applied_to_blocks"])
 def test_a_wrong_train_step_is_caught(tiny_step, fault):

@@ -1,10 +1,10 @@
 """The metrics that read the engine's round by phase and the seconds it
 knew the device dry (PR 59): each of the sixteen files through its reader
-on counters made by hand, nothing from a program without the series, and
-``BENCHMARK.json`` with the entries of ``data/round_phases_entries.json``
-appended held to the contract and resolved by ``run.py --bench-file``.
-The entries are written and not listed: the next ``benchmark`` PR appends
-them as they stand (PERF.md section 7)."""
+on counters made by hand, and ``BENCHMARK.json``, which lists them since
+PR 62 (until then they stood in ``data/round_phases_entries.json``, written
+and not listed), held to the contract and resolved by ``run.py``. (Nothing
+from a program without the series: one case a listed metric in
+``test_bench_readers.py``.)"""
 
 import json
 import os
@@ -63,34 +63,21 @@ def observations():
                                "seconds": 4.5}}
 
 
-def entries():
-    with open(os.path.join(HERE, "data", "round_phases_entries.json")) as f:
-        return json.load(f)
-
-
-def merged():
-    b = engine_metrics.bench()
-    b["per_layer"] = b["per_layer"] + entries()
-    return b
-
-
-@pytest.fixture(scope="module")
-def merged_file(tmp_path_factory):
-    path = tmp_path_factory.mktemp("bench") / "BENCHMARK.json"
-    path.write_text(json.dumps(merged(), indent=1))
-    return str(path)
+def entries(b=None):
+    """The sixteen entries as ``BENCHMARK.json`` lists them, by name."""
+    return [m for m in (b or engine_metrics.bench())["per_layer"] if m["name"] in EXPECTED]
 
 
 def through_its_reader(name, obs):
-    b = merged()
+    b = engine_metrics.bench()
     spec = harness.load_json(harness.find(b, "metrics", name))
     return spec, harness.module(b, "readers", spec["reader"]).read(obs, spec.get("args", {}), TPU)
 
 
-def test_the_data_file_holds_the_sixteen_and_no_other():
+def test_benchmark_json_lists_the_sixteen_once_each_and_the_data_file_is_gone():
+    """(``test_the_data_file_holds_the_sixteen_and_no_other`` until PR 62.)"""
     assert sorted(e["name"] for e in entries()) == sorted(EXPECTED)
-    listed = {m["name"] for m in engine_metrics.bench()["per_layer"]}
-    assert not listed & set(EXPECTED), "listed now: take them out of the data file"
+    assert not os.path.exists(os.path.join(HERE, "data", "round_phases_entries.json"))
 
 
 @pytest.mark.parametrize("name", sorted(EXPECTED))
@@ -108,20 +95,6 @@ def test_metric_file_reads_the_accounts_series(name):
     moves = {"decode": "serve_tok_s", "chat": "tpot_ms"}[name.rsplit(".", 1)[-1]]
     assert entry["moves"] == moves
     assert sorted(entry["workloads"]) == sorted(engine_metrics.reporting(b, moves))
-
-
-@pytest.mark.parametrize("name", sorted(EXPECTED))
-def test_a_program_without_the_series_reads_nothing(name):
-    """The parent has the round's two histograms and none of these: the
-    line leaves the metric out, it does not read 0."""
-    old_before = {"rt_serve_engine_round_host_s": (0.5, 100),
-                  "rt_serve_engine_round_blocked_s": (20.0, 100)}
-    old_after = {"rt_serve_engine_round_host_s": (2.5, 500),
-                 "rt_serve_engine_round_blocked_s": (100.0, 500)}
-    old = {"before": engine_metrics.snap(old_before), "after": engine_metrics.snap(old_after)}
-    obs = {"counters": old, "trace_counters": {**old, "seconds": 4.5}}
-    assert through_its_reader(name, obs)[1] is None
-    assert through_its_reader(name, {})[1] is None
 
 
 def test_the_phases_add_up_to_what_the_accepted_metrics_read():
@@ -148,26 +121,28 @@ def test_the_phases_add_up_to_what_the_accepted_metrics_read():
     "test_top_level_keys_and_limits", "test_names_units_and_entries",
     "test_cells_configs_and_moves_hang_together", "test_every_metric_traffic_and_generator_has_its_file",
 ])
-def test_benchmark_json_with_the_entries_keeps_the_contract(rule, merged_file):
-    b = harness.load_json(merged_file)
-    assert os.path.getsize(merged_file) < 64 * 1024 and len(b["per_layer"]) <= 128
-    assert b["per_layer"][-len(EXPECTED):] == entries()  # appended, nothing before them moved
+def test_benchmark_json_with_the_entries_keeps_the_contract(rule):
+    path = os.path.join(ROOT, "BENCHMARK.json")
+    b = harness.load_json(path)
+    assert os.path.getsize(path) < 64 * 1024 and len(b["per_layer"]) <= 128
+    assert len(entries(b)) == len(EXPECTED)  # each listed once, wherever in the list
     getattr(contract, rule)(b)
 
 
 @pytest.mark.parametrize("cell", SERVING)
-def test_run_resolves_every_serving_cell_of_the_merged_file(cell, merged_file, capsys):
-    assert run.main(["--bench-file", merged_file, "--workload", cell, "--trace", "1", "--dry"]) == 0
+def test_run_resolves_them_in_every_serving_cell(cell, capsys):
+    """(``test_run_resolves_every_serving_cell_of_the_merged_file`` until
+    PR 62: the file the driver reads is the one that lists them.)"""
+    assert run.main(["--workload", cell, "--trace", "1", "--dry"]) == 0
     plan = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     new = {n for n in plan["metrics"] if n in EXPECTED}
     suffix = ".chat" if cell == "xl-chat-sessions" else ".decode"
     assert new == {n for n in EXPECTED if n.endswith(suffix)}
     assert all(plan["metrics"][n] == "benchmark.readers.counter_ratio" for n in new)
-    # and the file the driver reads resolves what it resolved
-    assert run.main(["--workload", cell, "--trace", "1", "--dry"]) == 0
+    # an untraced run resolves none of them: they are per-layer metrics
+    assert run.main(["--workload", cell, "--trace", "0", "--dry"]) == 0
     own = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert not set(own["metrics"]) & set(EXPECTED)
-    assert set(plan["metrics"]) - new == set(own["metrics"])
 
 
 def test_round_phases_splits_a_gap_at_span_boundaries():
@@ -215,14 +190,8 @@ def test_a_rehearsed_traced_line_carries_the_new_names_and_they_add_up(tmp_path)
     import bench_rehearsal_file
     import test_bench_rehearsal as rehearsal
 
-    b, to = bench_rehearsal_file.build(), bench_rehearsal_file.names()["workloads"]
-    for e in entries():
-        cells = [to[w] for w in e["workloads"] if w in to]
-        if cells:
-            b["per_layer"].append({**e, "workloads": cells})
-    path = tmp_path / "BENCHMARK.json"
-    path.write_text(json.dumps(b, indent=1))
-    result, _ = rehearsal.rehearse(str(path), "tiny-decode", 1)
+    # the rehearsal's file is the real one renamed: it lists them too
+    result, _ = rehearsal.rehearse(bench_rehearsal_file.write(tmp_path), "tiny-decode", 1)
     got = {n: m["value"] for n, m in result["metrics"].items()}
     assert {n for n in EXPECTED if n.endswith(".decode")} <= set(got)
     assert sum(got[f"round_{p}_ms.decode"] for p in HOST) == pytest.approx(

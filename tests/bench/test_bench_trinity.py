@@ -9,7 +9,8 @@ from types import SimpleNamespace
 import bench_rehearsal_file
 import pytest
 from test_bench_engine_metrics import (
-    ENGINE_SERIES, on_every_list_the_other_serving_cells_share, snap, through_its_reader,
+    ENGINE_SERIES, listed_once, on_every_list_the_other_serving_cells_share, snap,
+    through_its_reader,
 )
 from test_bench_rehearsal import rehearse, run
 
@@ -188,38 +189,25 @@ def test_window_context_share_reads_the_engines_series():
         assert spec["unit"] == "%" and len(spec["reads"]) > 200
 
 
-@pytest.mark.parametrize("name", NEW)
-def test_a_program_without_the_series_reads_nothing(name):
-    """The parent's observations: counters that lack the window's series,
-    no trace directory. Nothing, and no exception."""
-    bare = {"counters": {"before": snap({"rt_serve_decode_steps_total": 1.0}),
-                         "after": snap({"rt_serve_decode_steps_total": 9.0}),
-                         "samples": [snap({"rt_serve_kv_pages_total": 97.0})]},
-            "trace_counters": {"before": {}, "after": {}, "seconds": 4.0},
-            "model": {"n_embd": 1600}, "trace_dir": None, "trace": None,
-            "device": {"kind": "TPU v5 lite"}}
-    for obs in (bare, {}, {"counters": None}):
-        _, got = through_its_reader(name, obs)
-        assert got is None
-
-
 def a_trace(rings=True):
-    """Two decode programs and a prefill; in each decode program loops over
-    ring blocks (the carry opens with the weighted sum) beside the full
-    layer's loop over pages, an expert layer's loop and the K-step loop."""
-    ring = "while (s32[],f32[32,32,1,128],..) 1in"
-    pages = "while (s32[],f32[32,32,1],..) 1in"
+    """Two decode programs and a prefill as the chip's trace names them
+    since PR 58 (ledger, PR 61, ``breakdown``): in each decode program the
+    ring kernel's calls, one a window layer, beside the full layer's call of
+    the same kernel under its own name, an expert layer's kernel and the
+    K-step loop; ``rings=False`` is the tree before PR 58, the loops over
+    ring blocks (the carry opens with the weighted sum) in the kernel's place."""
+    ring = ("ring_kv_attention f32[128,32,128] 9in" if rings
+            else "while (s32[],f32[32,32,1,128],..) 1in")
+    pages = "paged_kv_attention f32[128,32,128] 9in"
     ops = [[ring, 1_000, 300_000],                                      # inside decode 1
-           ["fusion bf16[32,512,512] 2in", 2_000, 100_000],             # its body: not twice
-           [pages, 310_000, 80_000],                                    # the full layer's loop
-           ["while (s32[],f32[128,2048],..) 1in", 400_000, 100_000],    # an expert layer's loop
+           ["fusion bf16[32,512,512] 2in", 302_000, 6_000],             # beside it: not its time
+           [pages, 310_000, 80_000],                                    # the full layer's call
+           ["grouped_matmul bf16[1024,1024] 7in", 400_000, 100_000],    # an expert layer's kernel
            [ring, 600_000, 200_000],                                    # inside decode 1
            ["while (s32[],f32[1,32,512],..) 1in", 2_100_000, 900_000],  # prefill's attention
-           [ring, 2_200_000, 50_000],                                   # inside the prefill
+           [ring, 3_050_000, 50_000],                                   # inside the prefill
            ["while (s32[],s32[128],..) 1in", 3_950_000, 900_000],       # the K-step loop
            [ring, 4_000_000, 500_000]]                                  # inside decode 2
-    if not rings:
-        ops = [op for op in ops if op[0] != ring]
     modules = [["jit_decode_paged_and_sample", 0, 1_000_000],
                ["jit_prefill_paged", 2_000_000, 1_500_000],
                ["jit_decode_multi_paged", 3_900_000, 1_000_000]]
@@ -227,8 +215,14 @@ def a_trace(rings=True):
         {"name": "XLA Ops", "events": ops}, {"name": "XLA Modules", "events": modules}]}]}
 
 
-def test_window_roofline_counts_the_ring_loops_inside_decode_programs_only(monkeypatch, cfg):
+def test_window_roofline_counts_the_ring_kernels_calls_inside_decode_programs_only(
+        monkeypatch, cfg):
+    """(``..._counts_the_ring_loops_...`` until PR 62 pointed the file's
+    ``ops`` at the kernel that replaced the loops in PR 58.)"""
     spec = load("benchmark/metrics/window_attn_roofline.json")
+    assert (spec["reader"], spec["args"]["ops"]) == ("window_roofline", "^ring_kv_attention")
+    assert spec["args"]["context"] == "rt_serve_window_context_tokens_total"
+    assert len(spec["reads"]) > 200 and "PR 62" in spec["reads"]
     busy, window = moe_roofline.seconds_inside(a_trace(), spec["args"]["match"], spec["args"]["ops"])
     assert busy == pytest.approx(1_000_000e-9)
     assert window == pytest.approx(4_850_000e-9 - 1_000e-9)
@@ -247,7 +241,7 @@ def test_window_roofline_counts_the_ring_loops_inside_decode_programs_only(monke
     assert got == pytest.approx(
         100 * (128 * 1600 * 4 * 2048 * 40 / 819e9) / (busy / window), rel=1e-3)
     assert 0 < got < 100
-    # a trace without ring operations (another family's, or a kernel in the loop's place)
+    # a trace without the kernel (another family's, or the loops of a tree before PR 58)
     monkeypatch.setattr(trace_mod, "load_xplane", lambda p: a_trace(rings=False))
     assert window_roofline.read(obs, spec["args"], ctx) is None
     monkeypatch.setattr(trace_mod, "load_xplane", lambda p: a_trace())
@@ -261,27 +255,37 @@ def test_window_roofline_counts_the_ring_loops_inside_decode_programs_only(monke
     assert window_roofline.read(still, spec["args"], ctx) is None
 
 
-def test_the_pattern_is_the_ring_loops_name_and_no_other_loops():
-    """The name the chip's trace gives the ring loops is what
-    ``trace.short_op_name`` makes of their HLO line: the carry opens with
-    the weighted sum (``ops/cached_attention.paged_attend``, ``acc_first``)."""
+def test_the_pattern_is_the_ring_kernels_name_and_no_other_operations():
+    """(``test_the_pattern_is_the_ring_loops_name_and_no_other_loops`` until
+    PR 62.) The name the chip's trace gives the kernel's calls is what
+    ``trace.short_op_name`` makes of their HLO line, a custom call under the
+    name ``ops/cached_attention.ring_decode_attend`` gives the kernel; the
+    full layer's call of the same kernel, the loops of the tree before and
+    every other kernel are not matched."""
     import re
 
     rx = re.compile(load("benchmark/metrics/window_attn_roofline.json")["args"]["ops"])
-    line = ("%while.27 = (s32[]{:T(128)}, f32[32,32,1,128]{3,1,0,2:T(8,128)S(1)}, "
-            "f32[32,32,1]{1,0,2:T(8,128)S(1)}, f32[32,32,1]{1,0,2:T(8,128)S(1)}, "
-            "bf16[512,512,512]{2,1,0:T(8,128)(2,1)}) while(%tuple.702), "
-            "condition=%wide.region_24.35, body=%wide.region_21.34.sunk")
+    line = ("%ring_kv_attention.7 = f32[128,32,128]{2,1,0:T(8,128)} custom-call("
+            "s32[4096]{0:T(1024)} %bitcast.11, s32[128]{0:T(128)} %clamp.3, "
+            "s32[129]{0:T(256)} %concatenate.5, s32[512]{0:T(512)} %fusion.91, "
+            "bf16[128,32,128]{2,1,0:T(8,128)(2,1)} %fusion.402, "
+            "bf16[128,512]{1,0:T(8,128)(2,1)} %fusion.17, f32[32,512]{1,0:T(8,128)} %fusion.18, "
+            "bf16[512,512,512]{2,1,0:T(8,128)(2,1)} %get-tuple-element.61, "
+            "bf16[512,512,512]{2,1,0:T(8,128)(2,1)} %get-tuple-element.62), "
+            "custom_call_target=\"tpu_custom_call\", operand_layout_constraints={}")
+    assert trace_mod.short_op_name(line) == "ring_kv_attention f32[128,32,128] 9in"
     assert rx.search(trace_mod.short_op_name(line))
-    for other in ("while (s32[],f32[32,32,1],..) 1in", "while (s32[],f32[128,2048],..) 1in",
-                  "while (s32[],f32[32,32],..) 1in", "while (s32[],s32[128],..) 1in",
-                  "while (s32[],f32[1,32,512],..) 1in", "fusion f32[32,32,1,128] 3in"):
+    for other in ("paged_kv_attention f32[128,32,128] 9in", "while (s32[],f32[32,32,1,128],..) 1in",
+                  "while (s32[],f32[32,32,1],..) 1in", "grouped_matmul bf16[1024,1024] 7in",
+                  "paged_latent_attention bf16[128,32,640] 6in", "while (s32[],s32[128],..) 1in",
+                  "fusion f32[128,32,128] 3in"):
         assert not rx.search(other), other
 
 
-def test_the_cell_stands_on_every_list_it_reports(cfg):
-    bench = load("BENCHMARK.json")
-    cell = next(w for w in bench["workloads"] if w["name"] == CELL)
+def the_cell_stands_on_its_lists(bench):
+    """Of any ``bench``: the real file, and the copy with a cell appended
+    that ``test_bench_contract.py`` makes. By name and by membership."""
+    cell, entry = listed_once(bench, CELL)
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         "trinity-mini-serve", "mixed-lengths", 1)
     assert len(cell["why"]) <= 200
@@ -290,15 +294,22 @@ def test_the_cell_stands_on_every_list_it_reports(cfg):
     # family's held_experts: this configuration's key is num_experts), the
     # page loops' and the rows of a prefill call
     assert set(NEW) <= on
-    assert {"moe_tokens_per_expert", "moe_experts_hit", "moe_load_skew", "moe_roofline",
+    assert {"moe_tokens_per_expert", "moe_experts_hit", "moe_load_skew", "moe_gmm_roofline",
             "kv_window_share", "attn_loop_useful_share", "prefill_rows_mean",
             "prefill_ms.decode"} <= on
-    # no latent cache and no prefix hits in this family
-    assert not {"mla_roofline", "mla_context_mean", "kv_latent_token_bytes",
-                "prefix_token_share.decode"} & on
+    # no latent cache and no prefix hits in this family; its full layer calls
+    # paged_kv_attention too, and afmoe counts no shared_kv_cost for it
+    assert not {"mla_paged_roofline", "mla_context_mean", "kv_latent_token_bytes",
+                "prefix_token_share.decode", "shared_kv_roofline"} & on
+    assert not {"moe_roofline", "mla_roofline"} & {m["name"] for m in bench["per_layer"]}
     on_every_list_the_other_serving_cells_share(bench, CELL)  # the engine's series among them
-    entry = next(c for c in bench["configs"] if c["name"] == cell["config"])
-    assert entry["reduced"] == CUT and entry["source"] == cfg["source"]
+    assert entry["reduced"] == CUT and entry["source"] == load(entry["file"])["source"]
+
+
+def test_the_cell_stands_on_every_list_it_reports(cfg):
+    bench = load("BENCHMARK.json")
+    the_cell_stands_on_its_lists(bench)
+    assert listed_once(bench, CELL)[1]["source"] == cfg["source"]
 
 
 @pytest.fixture(scope="module")
@@ -319,7 +330,7 @@ def test_the_cell_rehearses_to_a_correct_line_with_its_counters_read(mixed):
     assert 1 <= got["moe_load_skew"]["value"] <= 16
     assert ENGINE_SERIES <= set(got)
     # no device metric from a CPU run
-    assert not {"window_attn_roofline", "moe_roofline", "decode_step_mfu", "prefill_ms.decode",
+    assert not {"window_attn_roofline", "moe_gmm_roofline", "decode_step_mfu", "prefill_ms.decode",
                 "decode_step_ms.decode", "decode_step_counted_ms.decode", "hbm_used.decode",
                 "device_idle.decode"} & set(got)
     # prompts of ~30 and replies of 12-20 over a window of 16: most steps are past it
