@@ -3,8 +3,8 @@
 A served model is a config and a decode module, found by ``model_id``
 (``resolve``). The decode module is the engine's interface, the same
 names whichever model implements them (``models/gpt2_decode.py``,
-``models/mimo_v2.py``, ``models/deepseek_v3.py``, ``models/afmoe.py`` and
-``models/phi4flash.py`` do):
+``models/mimo_v2.py``, ``models/deepseek_v3.py``, ``models/afmoe.py``,
+``models/phi4flash.py`` and ``models/qwen3_next.py`` do):
 
     load_serving_params(cfg, checkpoint_path)   the stored weights
     params_bytes(params)
@@ -54,6 +54,7 @@ FAMILIES = {
     "kanana-2": ("ray_tpu.models.deepseek_v3", "ray_tpu.models.deepseek_v3"),
     "trinity": ("ray_tpu.models.afmoe", "ray_tpu.models.afmoe"),
     "phi-4-mini-flash": ("ray_tpu.models.phi4flash", "ray_tpu.models.phi4flash"),
+    "qwen3-next": ("ray_tpu.models.qwen3_next", "ray_tpu.models.qwen3_next"),
 }
 
 

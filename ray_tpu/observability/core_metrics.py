@@ -335,8 +335,8 @@ def _build() -> dict:
         # layer's pages, a window layer's ring a decode row
         # (models/mimo_v2.py), a latent layer's pages
         # (models/deepseek_v3.py), a recurrent layer's state a decode row
-        # (models/phi4flash.py); a model reads 0 under the kinds it has
-        # none of
+        # (models/phi4flash.py, models/qwen3_next.py); a model reads 0
+        # under the kinds it has none of
         "serve_kv_full_bytes": Gauge(
             "rt_serve_kv_full_bytes",
             "bytes of K and V the device holds for paged full-attention "
@@ -358,8 +358,9 @@ def _build() -> dict:
         "serve_kv_state_bytes": Gauge(
             "rt_serve_kv_state_bytes",
             "bytes the device holds for recurrent layers (a state and the "
-            "convolution's last inputs a decode row: models/phi4flash.py), "
-            "per engine process",
+            "convolution's last inputs a decode row: a Mamba layer's of "
+            "models/phi4flash.py, a gated delta-rule layer's matrix a head "
+            "of models/qwen3_next.py), per engine process",
             tag_keys=("deployment", "node"),
         ),
         "serve_prefix_refused": Counter(

@@ -11,10 +11,13 @@ experts, held or not: the shares of all chips add up to the whole layer
 and on one chip the layer runs without its exchange; nothing here stands
 in for the absent chips.
 
-Routing is the sigmoid kind (``noaux_tc``): scores ``s = sigmoid(W_r x)``
-in float32, the top ``k`` of ``s + b`` chosen (``b`` a selection bias that
-takes no part in the gate), gates ``s_e / sum of the chosen s``, times a
-``scale`` where the model has one (``routed_scaling_factor``).
+Routing is one of two kinds (``route``'s ``scoring``). ``"sigmoid"``
+(``noaux_tc``): scores ``s = sigmoid(W_r x)`` in float32, the top ``k`` of
+``s + b`` chosen (``b`` a selection bias that takes no part in the gate),
+gates ``s_e / sum of the chosen s``, times a ``scale`` where the model has
+one (``routed_scaling_factor``). ``"softmax"`` (``norm_topk_prob``):
+``s = softmax(W_r x)`` over all the experts in float32, the top ``k`` of
+``s``, the same renormalised gates; a model of this kind has no bias.
 
 The product is grouped, not one-hot: the token-expert pairs that landed
 here are sorted by expert and go through ``ops/grouped_matmul.py``, a
@@ -49,16 +52,27 @@ from ray_tpu.ops import grouped_matmul
 STATS = ("assignments", "expert_steps", "experts_hit", "max_load")
 
 
-def route(x: jax.Array, router: jax.Array, bias: jax.Array,
-          top_k: int, scale: float = 1.0) -> Tuple[jax.Array, jax.Array]:
-    """Sigmoid routing of ``x`` [T, D] over ``router`` [D, E] in float32:
-    (chosen experts [T, k], their gates [T, k]). The bias moves the choice
-    and not the gate; the gates of one token sum to ``scale``."""
-    scores = jax.nn.sigmoid(jnp.dot(
+SCORINGS = ("sigmoid", "softmax")
+
+
+def route(x: jax.Array, router: jax.Array, bias: Optional[jax.Array],
+          top_k: int, scale: float = 1.0,
+          scoring: str = "sigmoid") -> Tuple[jax.Array, jax.Array]:
+    """Routing of ``x`` [T, D] over ``router`` [D, E] in float32, the scores
+    a sigmoid an expert or a softmax over all of them (``scoring``):
+    (chosen experts [T, k], their gates [T, k]). The bias (None: the model
+    has none) moves the choice and not the gate; the gates of one token sum
+    to ``scale``."""
+    if scoring not in SCORINGS:
+        raise ValueError(f"scoring {scoring!r}: not one of {SCORINGS}")
+    logits = jnp.dot(
         x.astype(jnp.float32), router.astype(jnp.float32),
         precision=lax.Precision.HIGHEST,
-    ))
-    _, chosen = lax.top_k(scores + bias.astype(jnp.float32), top_k)
+    )
+    scores = (jax.nn.sigmoid(logits) if scoring == "sigmoid"
+              else jax.nn.softmax(logits, axis=-1))
+    _, chosen = lax.top_k(
+        scores if bias is None else scores + bias.astype(jnp.float32), top_k)
     picked = jnp.take_along_axis(scores, chosen, axis=1)
     gates = picked / picked.sum(-1, keepdims=True)
     # no operation where there is no scale: such a model's program is as it was
@@ -76,12 +90,13 @@ def _block_rows(tokens: int, top_k: int, held: int, routed: int) -> int:
 
 def expert_layer(
     x: jax.Array,                      # [T, D]
-    weights: Dict[str, jax.Array],     # router [D, E], bias [E], gate/up [El, D, F], down [El, F, D]
+    weights: Dict[str, jax.Array],     # router [D, E], bias [E] (or none), gate/up [El, D, F], down [El, F, D]
     *,
     first: int,                        # the first expert held here
     top_k: int,
     live: Optional[jax.Array] = None,  # [T] bool: rows that are real tokens
     scale: float = 1.0,                # the gates of one token sum to it
+    scoring: str = "sigmoid",          # how the router scores (``route``)
 ) -> Tuple[jax.Array, jax.Array]:
     """The held experts' part of the layer for ``x``, float32 [T, D], and
     what it counted (``STATS``, int32 [4]): token-expert pairs that landed
@@ -91,7 +106,8 @@ def expert_layer(
     T, D = x.shape
     held = weights["gate"].shape[0]
     with jax.named_scope("moe_experts"):
-        chosen, gates = route(x, weights["router"], weights["bias"], top_k, scale)
+        chosen, gates = route(x, weights["router"], weights.get("bias"), top_k, scale,
+                              scoring)
         local = chosen - first
         here = (local >= 0) & (local < held)
         if live is not None:

@@ -15,7 +15,7 @@ import pytest
 from ray_tpu import models
 
 TINY = ("gpt2-tiny", "mimo-v2-tiny", "kanana-2-tiny", "trinity-tiny",
-        "phi-4-mini-flash-tiny")
+        "phi-4-mini-flash-tiny", "qwen3-next-tiny")
 
 
 def interface_names():

@@ -114,24 +114,91 @@ def test_no_token_is_dropped_when_every_token_chooses_one_expert(layer, tokens):
     assert float(np.abs(np.asarray(y5[5:])).max()) == 0.0
 
 
+def test_sigmoid_routing_gives_the_bits_it_gave(layer):
+    """``route`` took a ``scoring`` in PR 63. Asked for the sigmoid, by name
+    or by default, it gives what the function before it gave (written out
+    here as it stood), to the bit: the sigmoid families' programs are the
+    operations they were."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    from ray_tpu.ops import moe
+
+    def as_it_stood(x, router, bias, top_k, scale=1.0):
+        scores = jax.nn.sigmoid(jnp.dot(
+            x.astype(jnp.float32), router.astype(jnp.float32),
+            precision=lax.Precision.HIGHEST,
+        ))
+        _, chosen = lax.top_k(scores + bias.astype(jnp.float32), top_k)
+        picked = jnp.take_along_axis(scores, chosen, axis=1)
+        gates = picked / picked.sum(-1, keepdims=True)
+        return chosen, gates if scale == 1.0 else gates * scale
+
+    weights, x, _ = layer
+    for scale in (1.0, 2.448):
+        want = as_it_stood(x, weights["router"], weights["bias"], 4, scale)
+        for got in (moe.route(x, weights["router"], weights["bias"], 4, scale),
+                    moe.route(x, weights["router"], weights["bias"], 4, scale, "sigmoid")):
+            assert np.array_equal(np.asarray(got[0]), np.asarray(want[0]))
+            assert np.array_equal(np.asarray(got[1]).view(np.uint32),
+                                  np.asarray(want[1]).view(np.uint32))
+        # and the lowered programs are the same operations
+        new = jax.jit(lambda a: moe.route(a, weights["router"], weights["bias"], 4, scale))
+        old = jax.jit(lambda a: as_it_stood(a, weights["router"], weights["bias"], 4, scale))
+        ops = lambda f: [ln.split(" = ")[1].split("(")[0].split()[-1]  # noqa: E731
+                         for ln in f.lower(x).as_text().splitlines() if " = " in ln]
+        assert ops(new) == ops(old)
+    with pytest.raises(ValueError, match="scoring"):
+        moe.route(x, weights["router"], weights["bias"], 4, scoring="softmax2")
+
+
+def test_softmax_routing_is_top_k_of_the_softmax_renormalised_with_no_bias(layer):
+    import jax
+
+    from benchmark.reference import qwen3_next_ref
+    from ray_tpu.ops import moe
+
+    weights, x, model = layer
+    chosen, gates = moe.route(x, weights["router"], None, 4, scoring="softmax")
+    p = np.asarray(jax.nn.softmax(x @ weights["router"], axis=-1))
+    want = np.argsort(-p, axis=1)[:, :4]
+    assert np.array_equal(np.sort(np.asarray(chosen), 1), np.sort(want, 1))
+    picked = np.take_along_axis(p, np.asarray(chosen), 1)
+    np.testing.assert_allclose(np.asarray(gates), picked / picked.sum(1, keepdims=True), rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(gates).sum(1), 1.0, rtol=1e-6)
+    ref_chosen, ref_gates, _ = qwen3_next_ref.routing(x, weights, model)
+    assert np.array_equal(np.asarray(ref_chosen), np.asarray(chosen))
+    np.testing.assert_allclose(np.asarray(ref_gates), np.asarray(gates), rtol=1e-5)
+    # other experts than the sigmoid's biased choice, for some token
+    biased, _ = moe.route(x, weights["router"], weights["bias"], 4)
+    assert not np.array_equal(np.sort(np.asarray(biased), 1), np.sort(np.asarray(chosen), 1))
+
+
 @pytest.mark.parametrize("holders", [1, 2, 4, 8])
-def test_routed_shares_and_the_shared_expert_once_add_up_at_the_gates_scale(holders):
-    """128 experts, top 6, gates that sum to ``routed_scaling_factor``
-    2.448, beside a shared expert that every token passes (the layer of
-    ``benchmark/reference/deepseek_v3_ref.py``), split over 1, 2, 4 and 8
-    holders: each holder routes over all 128 and computes its own experts'
-    part, the parts add up, and with the shared expert counted ONCE (not
-    once a holder) they are the uncut reference layer. ``held == routed``
-    gives the whole routed sum in one share."""
+@pytest.mark.parametrize("scoring", ["sigmoid", "softmax"])
+def test_routed_shares_and_the_shared_expert_once_add_up_at_the_gates_scale(scoring, holders):
+    """128 experts, top 6, beside a shared expert that every token passes,
+    split over 1, 2, 4 and 8 holders: each holder routes over all 128 and
+    computes its own experts' part, the parts add up, and with the shared
+    expert counted ONCE (not once a holder) they are the uncut reference
+    layer. ``held == routed`` gives the whole routed sum in one share.
+    ``sigmoid``: gates that sum to ``routed_scaling_factor`` 2.448 and a
+    selection bias, the layer of ``benchmark/reference/deepseek_v3_ref.py``;
+    ``softmax``: scores a softmax over all 128, gates that sum to one, no
+    bias, and the shared expert behind a sigmoid gate of its own, the layer
+    of ``benchmark/reference/qwen3_next_ref.py`` (at four holders: the
+    deployment of ``qwen3-next-80b-a3b-serve``)."""
     import jax
     import jax.numpy as jnp
 
     jax.config.update("jax_platforms", "cpu")
-    from benchmark.reference import deepseek_v3_ref
+    from benchmark.reference import deepseek_v3_ref, qwen3_next_ref
     from ray_tpu.ops import moe
 
-    D, F, E, T, K, scale = 32, 16, 128, 40, 6, 2.448
-    ks = jax.random.split(jax.random.PRNGKey(48), 9)
+    D, F, E, T, K = 32, 16, 128, 40, 6
+    scale = 2.448 if scoring == "sigmoid" else 1.0
+    ks = jax.random.split(jax.random.PRNGKey(48), 10)
     weights = {
         "router": jax.random.normal(ks[0], (D, E)) / D ** 0.5,
         "bias": 0.02 * jax.random.normal(ks[1], (E,)),
@@ -145,16 +212,23 @@ def test_routed_shares_and_the_shared_expert_once_add_up_at_the_gates_scale(hold
     x = jax.random.normal(ks[8], (T, D), jnp.float32)
     model = {"num_experts_per_tok": K, "norm_topk_prob": True, "n_routed_experts": E}
     with jax.default_matmul_precision("highest"):
-        once = deepseek_v3_ref.swiglu(x, *shared)
-        want = scale * deepseek_v3_ref.experts(x, weights, model) + once
+        if scoring == "sigmoid":
+            once = deepseek_v3_ref.swiglu(x, *shared)
+            want = scale * deepseek_v3_ref.experts(x, weights, model) + once
+        else:
+            del weights["bias"]
+            once = qwen3_next_ref.shared_expert(x, dict(
+                zip(("gate", "up", "down"), shared), gate_w=jax.random.normal(ks[9], (D,))))
+            want = qwen3_next_ref.experts(x, weights, model, (0, E)) + once
         each = E // holders
-        parts = [moe.expert_layer(x, share(weights, f, each), first=f, top_k=K, scale=scale)
+        parts = [moe.expert_layer(x, share(weights, f, each), first=f, top_k=K, scale=scale,
+                                  scoring=scoring)
                  for f in range(0, E, each)]
         got = sum(y for y, _ in parts) + once
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-4)
     # every pair landed on exactly one holder; the gates of a token sum to the scale
     assert sum(int(s[0]) for _, s in parts) == T * K
-    _, gates = moe.route(x, weights["router"], weights["bias"], K, scale)
+    _, gates = moe.route(x, weights["router"], weights.get("bias"), K, scale, scoring)
     np.testing.assert_allclose(np.asarray(gates).sum(1), scale, rtol=1e-6)
     # the shared expert counted with every share would be off by (holders - 1) of it
     assert holders == 1 or float(np.abs(np.asarray(once)).max()) > 0.1

@@ -41,6 +41,10 @@ anew in another layout):
   layer's state whole (``state copies``): the state is read and written
   every step and a relay would double its bytes.
 
+- Qwen3-Next (``qwen3_next``), states and pages: the two paged layers'
+  pools and copies of a delta-rule layer's state whole (``[rows, 32, 128,
+  128]`` float32, 268 MB a layer at 128 rows), counted as Phi's are.
+
 - ``deepseek_v3``, a latent pool a layer: copies of a layer's pool (6 GB
   in all at the benchmark's sizes: one copy does not fit beside it), and K
   or V a head of a cached span (any result ``[.., positions, heads, size]``
@@ -62,6 +66,8 @@ PR 32 and PR 47); it says nothing about time. Run here, on the CPU:
         --max-batch-size 32 --prefill 512 [--prefill-rows 2]
     JAX_PLATFORMS=cpu python tools/aot_serving_programs.py \\
         --model phi-4-mini-flash-reasoning --max-batch-size 32 --prefill 512 [--prefill-rows 2]
+    JAX_PLATFORMS=cpu python tools/aot_serving_programs.py \\
+        --model qwen3-next-80b-a3b --max-batch-size 32 --prefill 512 [--prefill-rows 2]
 
 ``--prefill-rows R`` adds the prefill call of R rows (PR 50: the chunks a
 round has to prefill in one call, the expert layers once), with the same
@@ -275,6 +281,7 @@ FAMILIES = {"ray_tpu.models.gpt2_decode": gpt2_family,
             "ray_tpu.models.mimo_v2": mimo_v2_family,
             "ray_tpu.models.afmoe": mimo_v2_family,
             "ray_tpu.models.phi4flash": phi4flash_family,
+            "ray_tpu.models.qwen3_next": phi4flash_family,
             "ray_tpu.models.deepseek_v3": deepseek_v3_family}
 
 
