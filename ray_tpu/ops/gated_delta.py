@@ -30,7 +30,7 @@ bfloat16.
 position to t and ``S_0`` the state the block met:
 
     A[t, s] = beta_t exp(G_t - G_s) (k_t . k_s), s < t           (strictly lower)
-    T = (I + A)^-1                                               (forward substitution)
+    T = (I + A)^-1                                               (block substitution)
     U = T (beta v),  W = T (beta exp(G) k)                       (all blocks at once)
     D = U - W S_0                                                (the d of every position)
     O = exp(G) (Q S_0) + (exp(G_t - G_s) (q_t . k_s), s <= t) D
@@ -38,9 +38,15 @@ position to t and ``S_0`` the state the block met:
 
 Everything down to U and W is computed for all blocks of a call in
 parallel; ``lax.scan`` carries the state from block to block. The inverse is
-built row by row (row i of T needs rows 0 .. i - 1), not as a product of
-powers of A: the keys of neighbouring positions are alike, A's entries are
-not small, and its powers cancel catastrophically before they vanish.
+block forward substitution. The diagonal sub-blocks of ``SUB`` positions are
+inverted row by row (row i of a sub-block's inverse needs its rows 0 .. i -
+1), all sub-blocks of all blocks at once with the batch in the lanes: 15
+turns over 2 MB at the engine's shape. Neighbouring diagonal blocks are then
+merged upward, 16 to 32 to 64, by the exact formula for a lower triangular
+matrix, ``[[X, 0], [-Y A21 X, Y]]`` with X and Y the inverses of the two
+halves. It is never a product of powers of A (``(I - A)(I + A^2)(I + A^4)
+..``): the keys of neighbouring positions are alike, A's entries are not
+small, and its powers cancel catastrophically before they vanish.
 
 A position that is not real (a chunk's padding, a decode row nobody holds)
 gets ``g = 0`` and ``beta = 0``: ``exp(0) S + k 0' = S``, and both functions
@@ -57,6 +63,7 @@ import jax.numpy as jnp
 from jax import lax
 
 BLOCK = 64     # positions of a block of the chunked form
+SUB = 16       # of a diagonal sub-block of it, inverted by substitution
 EPS = 1e-6     # of the l2 norms of q and k
 _HI = lax.Precision.HIGHEST
 
@@ -111,19 +118,51 @@ def step(params: Dict[str, Any], c, a, b, state: Tuple[jax.Array, jax.Array], li
     return o, (s_new, conv_new)
 
 
-def _inverse(a):
-    """(I + a)^-1 for ``a`` [..., C, C] strictly lower triangular, by
-    forward substitution: row i of the inverse's strictly lower part is
-    -a_i - a_i T, which reads rows 0 .. i - 1 of T alone (a_i is zero from
-    column i on)."""
-    C = a.shape[-1]
+def _substituted(d):
+    """The strictly lower part of (I + d)^-1 for ``d`` [w, w, B], strictly
+    lower triangular in its first two axes: row i is -d_i - sum over j < i of
+    d_ij row_j (d_i is zero from column i on, so the sum may run over every
+    j). A turn is an elementwise pass over [w, w, B] with the batch in the
+    lanes, where a row of w alone would fill an eighth of them. The w - 1
+    turns are a loop and not written out: written out they ran no faster on
+    the chip, and the long-documents cell's set-up took 13% longer with
+    every program in the compile cache, the six prefill programs being
+    slower to trace, lower and load (PERF.md section 6, PR 64)."""
 
     def row(i, t):
-        r = lax.dynamic_index_in_dim(t, i, axis=-2, keepdims=False)
-        r = r + jnp.einsum("...j,...jc->...c", r, t, precision=_HI)
-        return lax.dynamic_update_index_in_dim(t, r, i, axis=-2)
+        r = lax.dynamic_index_in_dim(t, i, axis=0, keepdims=False)
+        r = r + jnp.sum(r[:, None] * t, axis=0)
+        return lax.dynamic_update_index_in_dim(t, r, i, axis=0)
 
-    return lax.fori_loop(1, C, row, -a) + jnp.eye(C, dtype=a.dtype)
+    return lax.fori_loop(1, d.shape[0], row, -d)
+
+
+def _inverse(a):
+    """(I + a)^-1 for ``a`` [..., C, C] strictly lower triangular, by block
+    forward substitution: the diagonal sub-blocks of ``SUB`` by rows
+    (``_substituted``), then neighbouring diagonal blocks of width w merged
+    into one of 2 w, [[X, 0], [-Y a21 X, Y]], until one is left. Every pair
+    of every block is merged in the same two products over the whole width:
+    a21 is ``a`` with all but the pairs' lower left quarters zeroed, and the
+    inverse so far is zero off its diagonal blocks, so the zeros multiply to
+    exact zeros. A width that ``SUB`` does not divide is one sub-block."""
+    C = a.shape[-1]
+    w = SUB if C % SUB == 0 else C
+    starts = range(0, C, w)
+    d = jnp.stack([a[..., p:p + w, p:p + w] for p in starts])   # [C / w, ..., w, w]
+    low = _substituted(jnp.moveaxis(d.reshape(-1, w, w), 0, -1))
+    low = jnp.moveaxis(low, -1, 0).reshape(d.shape)
+    lead = [(0, 0)] * (a.ndim - 1)
+    t = jnp.eye(C, dtype=a.dtype) + jnp.concatenate(
+        [jnp.pad(x, lead + [(p, C - w - p)]) for p, x in zip(starts, low)], axis=-2)
+    row, col = jnp.arange(C)[:, None], jnp.arange(C)[None, :]
+    while w < C:
+        a21 = jnp.where((row // (2 * w) == col // (2 * w)) & (row // w != col // w), a, 0.0)
+        t = t - jnp.einsum("...ij,...jk->...ik", t,
+                           jnp.einsum("...ij,...jk->...ik", a21, t, precision=_HI),
+                           precision=_HI)
+        w *= 2
+    return t
 
 
 def chunk_scan(params: Dict[str, Any], c, a, b, state: Tuple[jax.Array, jax.Array],

@@ -85,17 +85,19 @@ def test_step_after_step_is_the_loop_written_out(params):
     np.testing.assert_allclose(np.asarray(state[1][0]), tail, atol=1e-6)
 
 
-@pytest.mark.parametrize("block", [4, 5, 16, 64])
-def test_a_chunk_is_the_steps_whatever_the_block(params, block):
+@pytest.mark.parametrize("block, T", [(4, 13), (5, 13), (16, 13), (64, 13), (32, 70), (48, 70)])
+def test_a_chunk_is_the_steps_whatever_the_block(params, block, T):
     """13 positions in blocks of 4 and 5 (neither divides 13: the last block
-    is padded), of 16 (one block, padded) and of 64 (cut to the width), from
-    a state and last inputs that are not zero."""
-    c, a, b = inputs(2, 2, 13)
+    is padded), of 16 (one block, padded) and of 64 (cut to the width), and
+    70 in blocks of 32 and 48, which the inverse solves in two and three
+    sub-blocks and merges (48: a pair, then the pair with the third), from a
+    state and last inputs that are not zero."""
+    c, a, b = inputs(2, 2, T)
     rng = np.random.default_rng(3)
     s0 = jnp.asarray(rng.normal(size=(2, HV, DK, DV)), jnp.float32)
     tail0 = jnp.asarray(rng.normal(size=(2, (TAPS - 1) * CHANNELS)), jnp.float32)
     o, (s, tail) = gated_delta.chunk_scan(
-        params, c, a, b, (s0, tail0), jnp.array([7, 20]), jnp.array([13, 13]), block=block)
+        params, c, a, b, (s0, tail0), jnp.array([7, 20]), jnp.array([T, T]), block=block)
     for r in range(2):
         want, s_want, tail_want = by_hand(params, c[r], a[r], b[r], s0[r],
                                           np.asarray(tail0[r]).reshape(TAPS - 1, CHANNELS))
@@ -104,9 +106,9 @@ def test_a_chunk_is_the_steps_whatever_the_block(params, block):
         np.testing.assert_allclose(np.asarray(tail[r]), tail_want, atol=1e-6)
     # and the steps themselves, from the same state
     state = (s0, tail0)
-    for t in range(13):
-        o_t, state = gated_delta.step(params, c[:, t], a[:, t], b[:, t], state,
-                                      jnp.array([True, True]))
+    step = jax.jit(gated_delta.step)
+    for t in range(T):
+        o_t, state = step(params, c[:, t], a[:, t], b[:, t], state, jnp.array([True, True]))
         np.testing.assert_allclose(np.asarray(o_t), np.asarray(o[:, t]), atol=1e-5)
     np.testing.assert_allclose(np.asarray(state[0]), np.asarray(s), atol=1e-5)
 
@@ -207,3 +209,121 @@ def test_keys_that_lie_close_do_not_break_the_inverse(params):
     want, s_want, _ = by_hand(params, c[0], a[0], b[0])
     np.testing.assert_allclose(np.asarray(o[0]), want, atol=1e-4)
     np.testing.assert_allclose(np.asarray(s[0]), s_want, atol=1e-4)
+
+
+# the engine's call: 2 rows of 512 positions, 32 value heads of 128 x 128 over
+# 16 key heads; the heads cut to what a CPU runs in seconds, the positions not
+ENGINE_ROWS, ENGINE_WIDTH = 2, 512
+
+
+def engine_shaped(hk=1, hv=2, dk=16, dv=16, seed=11):
+    """(params, c, a, b, state) of a call of the engine's rows and width, the
+    decays drawn as the configuration's seeded weights draw them."""
+    rng = np.random.default_rng(seed)
+    channels = 2 * hk * dk + hv * dv
+    step = np.exp(rng.uniform(np.log(0.001), np.log(0.1), size=hv))
+    p = {"conv_w": jnp.asarray(rng.normal(size=(TAPS, channels)) / np.sqrt(2), jnp.float32),
+         "A_log": jnp.asarray(np.log(rng.uniform(0.5, 4.0, size=hv)), jnp.float32),
+         "dt_bias": jnp.asarray(step + np.log(-np.expm1(-step)), jnp.float32)}
+    lead = (ENGINE_ROWS, ENGINE_WIDTH)
+    c, a, b = (jnp.asarray(rng.normal(size=(*lead, n)), jnp.float32) for n in (channels, hv, hv))
+    state = (jnp.asarray(rng.normal(size=(ENGINE_ROWS, hv, dk, dv)), jnp.float32),
+             jnp.asarray(rng.normal(size=(ENGINE_ROWS, (TAPS - 1) * channels)), jnp.float32))
+    return p, c, a, b, state
+
+
+def test_a_chunk_at_the_engines_shape_is_the_steps():
+    """Eight blocks of 64 a row, each inverted in four sub-blocks and two
+    merges, the second row's last block half padding: what the steps give
+    from the same state, position by position."""
+    p, c, a, b, state = engine_shaped()
+    length = jnp.array([ENGINE_WIDTH, ENGINE_WIDTH - 30])
+    o, (s, tail) = jax.jit(gated_delta.chunk_scan)(p, c, a, b, state, jnp.array([512, 64]), length)
+    step = jax.jit(gated_delta.step)
+    for t in range(ENGINE_WIDTH):
+        live = t < length
+        o_t, state = step(p, c[:, t], a[:, t], b[:, t], state, live)
+        np.testing.assert_allclose(np.asarray(o_t)[np.asarray(live)],
+                                   np.asarray(o[:, t])[np.asarray(live)], atol=2e-5)
+    np.testing.assert_allclose(np.asarray(state[0]), np.asarray(s), atol=2e-5)
+    np.testing.assert_array_equal(np.asarray(state[1]), np.asarray(tail))
+
+
+def blocks_of_keys(alike, blocks=64, C=64, dk=128, seed=12):
+    """``A`` [blocks, C, C] of the chunked form in float64: keys that share
+    ``alike`` of their squared length, decays of the configuration's range
+    (``models/qwen3_next.init``'s draws at gate logits of size one), beta a
+    sigmoid of the same."""
+    rng = np.random.default_rng(seed)
+    k = (np.sqrt(alike) * rng.normal(size=(blocks, 1, dk))
+         + np.sqrt(1.0 - alike) * rng.normal(size=(blocks, C, dk)))
+    k /= np.linalg.norm(k, axis=-1, keepdims=True)
+    step = np.exp(rng.uniform(np.log(0.001), np.log(0.1), size=(blocks, 1)))
+    g = -rng.uniform(0.5, 4.0, size=(blocks, 1)) * np.log1p(
+        np.exp(rng.normal(size=(blocks, C)) + step + np.log(-np.expm1(-step))))
+    beta = 1.0 / (1.0 + np.exp(-rng.normal(size=(blocks, C))))
+    G = np.cumsum(g, axis=-1)
+    t, src = np.arange(C)[:, None], np.arange(C)[None, :]
+    decay = np.exp(np.where(src <= t, G[:, :, None] - G[:, None, :], -np.inf))
+    return np.where(src < t, beta[:, :, None] * decay * (k @ k.transpose(0, 2, 1)), 0.0)
+
+
+def row_by_row(a):
+    """(I + a)^-1 by forward substitution over all C rows in float32: the
+    form the blocked inverse replaced, and the error it is held to."""
+    t = -np.asarray(a, np.float32)
+    for i in range(1, t.shape[-1]):
+        t[:, i] += np.einsum("bj,bjc->bc", t[:, i], t)
+    return t + np.eye(t.shape[-1], dtype=np.float32)
+
+
+@pytest.mark.parametrize("alike", [0.0, 0.9, 0.99])
+def test_the_blocked_inverse_is_as_near_the_float64_inverse_as_row_by_row(alike):
+    """At the engine's block of 64, against ``numpy.linalg.inv`` of the same
+    I + A in float64: the relative error of the worst of 64 blocks is no
+    larger than twice what substitution over all 63 rows leaves, and tiny.
+    Keys that are alike put A's entries near one, where a sum of its powers
+    cancels; substitution and the block formula do not."""
+    a = blocks_of_keys(alike).astype(np.float32)
+    want = np.linalg.inv(np.eye(64) + a.astype(np.float64))
+
+    def error(got):
+        return np.max(np.linalg.norm(np.asarray(got, np.float64) - want, axis=(1, 2))
+                      / np.linalg.norm(want, axis=(1, 2)))
+
+    blocked, rows = error(jax.jit(gated_delta._inverse)(jnp.asarray(a))), error(row_by_row(a))
+    assert blocked <= max(2.0 * rows, 1e-7), (blocked, rows)
+    assert blocked < 1e-6, blocked
+
+
+def loops(jaxpr):
+    """(primitive, turns) of every loop in a jaxpr and the jaxprs inside it;
+    a ``while`` has no count to read and comes back with None."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            found.append(("scan", eqn.params["length"]))
+        elif eqn.primitive.name == "while":
+            found.append(("while", None))
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    found += loops(sub)
+    return found
+
+
+def test_the_engines_chunk_holds_no_loop_but_the_one_over_its_blocks():
+    """The inverse was a loop of 63 turns a block, each over the whole of T,
+    and the largest operation of the long-documents cell (PERF.md section 6,
+    PR 64). At the engine's shape the traced program holds the scan over the
+    eight blocks and no other loop of more than ``SUB`` turns, and no while
+    at all, whose turns nothing here could count."""
+    p, c, a, b, state = engine_shaped()
+    traced = jax.make_jaxpr(gated_delta.chunk_scan)(
+        p, c, a, b, state, jnp.array([0, 0]), jnp.array([ENGINE_WIDTH, ENGINE_WIDTH]))
+    found = loops(traced.jaxpr)
+    over_blocks = ("scan", ENGINE_WIDTH // gated_delta.BLOCK)
+    assert found.count(over_blocks) == 1, found
+    found.remove(over_blocks)
+    assert all(kind == "scan" and turns <= gated_delta.SUB for kind, turns in found), found
